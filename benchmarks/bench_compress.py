@@ -172,9 +172,9 @@ def main() -> int:
     args = ap.parse_args()
     quick = args.quick or args.smoke
 
-    from distlr_tpu.utils.backend import probe_default_backend_ex  # noqa: PLC0415
+    from distlr_tpu.utils.backend import start_benchmark  # noqa: PLC0415
 
-    backend, _detail = probe_default_backend_ex()
+    dev = start_benchmark("bench_compress.py", full_size=not quick)
     kw = dict(
         d=args.d,
         n_train=1024 if quick else 4096,
@@ -205,7 +205,7 @@ def main() -> int:
                    f"chaos link"),
         "value": round(reduction, 2),
         "unit": "x",
-        "backend": backend,
+        **dev,
         "D": args.d,
         "throttle_bytes_per_sec": args.throttle,
         # the ROADMAP acceptance, evaluated right here: >= 8x fewer

@@ -36,7 +36,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
 sys.path.insert(0, REPO)
 
-from distlr_tpu.utils.backend import force_cpu, probe_default_backend_ex  # noqa: E402
+from distlr_tpu.utils.backend import start_benchmark  # noqa: E402
 
 #: tracks the banked fleet flamegraph must carry (role prefixes of the
 #: <role>-<rank> journal stems) — the ISSUE-9 acceptance list
@@ -265,13 +265,7 @@ def main() -> int:
                     "measured at (default 19)")
     args = ap.parse_args()
 
-    status, probed = probe_default_backend_ex(
-        float(os.environ.get("DISTLR_PROBE_TIMEOUT_S", "60")))
-    if probed is None or probed[0] == "cpu":
-        force_cpu()
-        backend = "cpu"
-    else:
-        backend = probed[0]
+    dev = start_benchmark("bench_prof.py", full_size=not args.smoke)
 
     if args.smoke:
         d, slice_s, rounds, loop_requests = 4096, 0.3, 12, 8
@@ -306,8 +300,7 @@ def main() -> int:
         "metric": (f"serve QPS overhead at --prof-hz {args.hz:g}, D={d}"),
         "value": over["overhead_default_pct"],
         "unit": "percent",
-        "backend": backend,
-        "probe_status": status,
+        **dev,
         "D": d,
         **over,
         **fleet,

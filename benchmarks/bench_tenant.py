@@ -33,7 +33,7 @@ REPO = os.path.dirname(HERE)
 sys.path.insert(0, REPO)
 
 from distlr_tpu.obs.tracing import get_tracer  # noqa: E402
-from distlr_tpu.utils.backend import force_cpu, probe_default_backend_ex  # noqa: E402
+from distlr_tpu.utils.backend import start_benchmark  # noqa: E402
 
 
 def _resilience() -> dict:
@@ -197,13 +197,7 @@ def main() -> int:
     if args.smoke:
         args.quick = True
 
-    status, probed = probe_default_backend_ex(
-        float(os.environ.get("DISTLR_PROBE_TIMEOUT_S", "60")))
-    if probed is None or probed[0] == "cpu":
-        force_cpu()
-        backend = "cpu"
-    else:
-        backend = probed[0]
+    dev = start_benchmark("bench_tenant.py", full_size=not args.quick)
 
     if args.quick:
         d, clients, duration, rounds = 4096, 4, 0.4, 2
@@ -236,9 +230,8 @@ def main() -> int:
                   "N models one router",
         "value": baseline["qps"] if baseline else None,
         "unit": "requests/sec",
-        "backend": backend,
+        **dev,
         "D": d,
-        "probe_status": status,
         "phase_breakdown": {"phases": get_tracer().breakdown()},
         "resilience": _resilience(),
         **subs,

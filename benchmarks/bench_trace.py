@@ -32,7 +32,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
 sys.path.insert(0, REPO)
 
-from distlr_tpu.utils.backend import force_cpu, probe_default_backend_ex  # noqa: E402
+from distlr_tpu.utils.backend import start_benchmark  # noqa: E402
 
 
 def _make_lines(n: int, d: int, nnz: int, seed: int = 0) -> list[str]:
@@ -156,13 +156,7 @@ def main() -> int:
                     "is measured at (default 0.01)")
     args = ap.parse_args()
 
-    status, probed = probe_default_backend_ex(
-        float(os.environ.get("DISTLR_PROBE_TIMEOUT_S", "60")))
-    if probed is None or probed[0] == "cpu":
-        force_cpu()
-        backend = "cpu"
-    else:
-        backend = probed[0]
+    dev = start_benchmark("bench_trace.py", full_size=not args.smoke)
 
     if args.smoke:
         d, duration, loop_requests = 4096, 0.5, 8
@@ -206,8 +200,7 @@ def main() -> int:
                    f"D={d}"),
         "value": round(overhead_default, 2),
         "unit": "percent",
-        "backend": backend,
-        "probe_status": status,
+        **dev,
         "D": d,
         "qps_untraced": round(qps_off, 1),
         "qps_default_sample": round(qps_default, 1),

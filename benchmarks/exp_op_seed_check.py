@@ -22,12 +22,6 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))
 
-from distlr_tpu.utils.backend import force_cpu, probe_default_backend  # noqa: E402
-
-_probed = probe_default_backend()
-if _probed is None or _probed[0] == "cpu":
-    force_cpu()
-
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
@@ -94,7 +88,10 @@ def main() -> int:
         "what": ("seed replication of the operating-point claim: "
                  "single-group R=32 vs scalar hashing, correlated-tuples "
                  "regime (512 tuples), dc=1M (row load 0.016)"),
+        # an accuracy replication, not a device measurement: it runs on
+        # the default backend and names it
         "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
         "shapes": {"fields": FIELDS, "dc": DC, "n_train": N_TR,
                    "n_test": N_TE, "steps": STEPS},
         "rows": rows,

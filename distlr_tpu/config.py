@@ -84,9 +84,8 @@ class Config:
     # layout).  G > that splits the fields near-equally into G groups of
     # <= block_size lanes each (data/hashing.split_field_groups): one
     # extra row gather per extra group buys tuple spaces small enough to
-    # recur — measured (FRONTIER_TPU.json operating_point) R=32 G=3
-    # holds within 0.3pt of scalar hashing on low-cardinality iid
-    # fields where the single-group layout loses ~28pt.
+    # recur: on low-cardinality iid fields the single-group layout loses
+    # accuracy that several narrower groups keep.
     block_groups: int = 0
     # blocked_lr from disk: number of raw categorical fields per row in
     # raw-CTR shards (data/hashing.write_raw_ctr_shards).  0 = read it
@@ -99,19 +98,18 @@ class Config:
     dtype: str = "float32"            # accumulation dtype
     compute_dtype: str = "bfloat16"   # matmul dtype on TPU (MXU-friendly)
     # Device-resident storage dtype of DENSE feature matrices. The dense
-    # D=1M step is HBM-bound on the feature stream (benchmarks/ROOFLINE.md):
+    # D=1M step streams the whole feature matrix from HBM twice:
     # "bfloat16" halves the bytes, "int8" quarters them (symmetric
     # per-dataset quantization; the scale folds into the model as
-    # feature_scale, measured +11% step rate here and 2x the max resident
-    # dataset).  Dense models only; sparse vals stay float32.
+    # feature_scale; 2x the max resident dataset).  Dense models only;
+    # sparse vals stay float32.
     # "int8_dot" additionally keeps BOTH matmul operands int8 (native
     # int8 x int8 -> int32 MXU contraction with dynamic per-step scales
     # for w and the residual) instead of converting the (B, D) tile to
-    # bfloat16 — the convert is the measured wall (~165k samples/s at
-    # D=1M); the native dot measured ~170k, 1.55x bf16
-    # (benchmarks/exp_int8_dot.py; the shipped unrolled-chunk form
-    # measured 271.5k on-chip, 1.64x bf16).  Dense models (binary_lr and
-    # softmax), single-device or feature-sharded; sparse/blocked reject.
+    # bfloat16, so the VPU convert of the tile is not in the way
+    # (benchmarks/exp_int8_dot.py; rates not measured on today's code).
+    # Dense models (binary_lr and softmax), single-device or
+    # feature-sharded; sparse/blocked reject.
     feature_dtype: str = "float32"    # float32 | bfloat16 | int8 | int8_dot
 
     # ---- parity / compat with reference quirks (SURVEY.md §3.5) ----

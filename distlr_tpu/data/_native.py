@@ -8,10 +8,11 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 
 import numpy as np
+
+from distlr_tpu.utils.native_build import ensure_built
 
 _DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "native")
 _SO = os.path.join(_DIR, "libdistlr_libsvm.so")
@@ -24,12 +25,7 @@ def _load():
     if _lib is None:
         with _lock:
             if _lib is None:
-                if not os.path.exists(_SO):
-                    proc = subprocess.run(
-                        ["make", "-C", _DIR], capture_output=True, text=True
-                    )
-                    if proc.returncode != 0:
-                        raise RuntimeError(f"libsvm native build failed: {proc.stderr}")
+                ensure_built(_DIR, [_SO])
                 lib = ctypes.CDLL(_SO)
                 lib.libsvm_count.restype = ctypes.c_int
                 lib.libsvm_count.argtypes = [

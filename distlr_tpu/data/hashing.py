@@ -91,9 +91,8 @@ def hash_group_blocks(raw_ids, field_groups, num_blocks: int, *, seed: int = 0,
                       raw_vals=None):
     """Row-aligned ("blocked") hashing: field groups -> block-row ids.
 
-    TPU gathers amortize their per-index cost over contiguous elements
-    (benchmarks/ROOFLINE.md: rows-of-8 move 3.4x the bytes/s of scalar
-    gathers), but that only pays off if the fetched lanes are all used —
+    TPU gathers amortize their per-index cost over contiguous elements,
+    but that only pays off if the fetched lanes are all used —
     which requires co-locating several of a sample's features in ONE
     table row.  Per-field buckets cannot co-locate (each field's value
     picks an independent bucket), so this scheme hashes a GROUP of R
@@ -173,12 +172,11 @@ def split_field_groups(num_fields: int, block_size: int,
     garbage).  Larger ``num_groups=G`` splits the fields into G
     near-equal consecutive groups, each padded to R lanes: the
     intermediate groupings between ceil(F/R) chunks and one all-fields
-    conjunction.  Measured motivation (r5 operating-point
-    sweep, ``benchmarks/FRONTIER_TPU.json``): on low-cardinality i.i.d.
-    fields the single-group R=32 layout loses ~28pt (21-field tuples
-    never recur) while the SAME R at G=3 holds within 0.3pt of scalar
-    hashing — extra groups trade one extra row gather per sample for
-    tuple spaces small enough to recur.
+    conjunction.  Motivation (bench_configs.py's operating-point
+    sweep): on low-cardinality i.i.d. fields the single-group R=32
+    layout loses accuracy (21-field tuples never recur) while the SAME R
+    at G=3 stays close to scalar hashing — extra groups trade one extra
+    row gather per sample for tuple spaces small enough to recur.
     """
     g_min = -(-num_fields // block_size)
     if num_groups in (0, None) or num_groups == g_min:
@@ -704,8 +702,7 @@ def write_raw_ctr_shards(
     into the bytes on disk), this format is **hash-scheme agnostic**: the
     same shard trains the scalar one-hot path (`hash_buckets` at load
     time) or the row-blocked path (`hash_group_blocks`) — the hashing is
-    a load-time choice, exactly like the encoder split the roofline study
-    calls for (benchmarks/ROOFLINE.md, row-blocked section).  Labels come
+    a load-time choice.  Labels come
     from the same hashed-ground-truth logistic model as
     :func:`make_ctr_dataset`, so signal recovery stays assertable.
 

@@ -159,13 +159,12 @@ class BinaryLR:
     feature_scale: float = 1.0
     # Native int8 x int8 -> int32 MXU contraction (cfg.feature_dtype=
     # "int8_dot").  The plain int8 storage path converts the whole (B, D)
-    # tile to bfloat16 before the dot — a VPU-bound convert wall measured
-    # at ~151-165k samples/s at D=1M (benchmarks/ROOFLINE.md,
-    # exp_int8_dot.py).  This path instead quantizes the SMALL per-step
-    # operands — w over D for the forward, the residual over B for the
-    # backward — with dynamic symmetric scales and feeds both dots int8
-    # operands end to end (~170k measured, 1.55x bf16).  Requires X to
-    # be int8 (the trainer's feature quantization guarantees it).
+    # tile to bfloat16 before the dot — a VPU-bound convert
+    # (benchmarks/exp_int8_dot.py).  This path instead quantizes the
+    # SMALL per-step operands — w over D for the forward, the residual
+    # over B for the backward — with dynamic symmetric scales and feeds
+    # both dots int8 operands end to end.  Requires X to be int8 (the
+    # trainer's feature quantization guarantees it).
     int8_dot: bool = False
 
     @property
@@ -505,8 +504,7 @@ class BlockedSparseLR:
     ``(blocks, lane_vals, y, mask)`` with ``blocks`` of shape (B, G) and
     ``lane_vals`` of shape (B, G, R): each sample gathers G contiguous
     R-wide rows instead of G*R scalars, which amortizes the TPU gather
-    unit's per-index cost (benchmarks/ROOFLINE.md: 3.4x the bytes/s of
-    scalar gathers); the gradient scatter is a ``segment_sum`` of R-wide
+    unit's per-index cost; the gradient scatter is a ``segment_sum`` of R-wide
     rows, blocked the same way.  Logit = sum over groups of
     ``T[block_g] . lane_vals_g`` — with lane_vals the one-hot/raw values
     of the group's member fields, this is per-(conjunction, field)

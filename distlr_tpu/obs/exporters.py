@@ -16,8 +16,8 @@ server can re-serve a merged fleet view that is rebuilt every scrape.
 Port 0 binds an OS-assigned ephemeral port (announced by the launcher as
 ``METRICS host:port``, same contract as ``SERVING``/``HOSTS``).  The
 ``DISTLR_METRICS_SNAPSHOT=<path>`` env hook writes the registry to a
-file at interpreter exit — how one-shot processes (``bench.py`` under
-``capture_all_tpu.sh``) bank their metrics without holding a port open.
+file at interpreter exit — how one-shot processes (a ``bench.py`` run)
+bank their metrics without holding a port open.
 Paths ending ``.json`` bank the machine-readable JSON snapshot (what the
 fleet aggregator merges); anything else banks Prometheus text.  Several
 ``os.pathsep``-separated paths may be given to bank both forms at once.
@@ -140,7 +140,7 @@ def write_metrics_snapshot(path: str,
                            registry: MetricsRegistry | None = None) -> str:
     """Write the registry to ``path`` (atomic).  A ``.json`` path banks
     the JSON snapshot (the machine-readable twin the fleet aggregator
-    and ``capture_all_tpu.sh`` consume); any other extension banks the
+    consumes); any other extension banks the
     Prometheus text exposition."""
     registry = registry or get_registry()
     d = os.path.dirname(os.path.abspath(path))
@@ -162,8 +162,8 @@ _snapshot_installed = False
 def snapshot_env_paths(value: str | None = None) -> list[str]:
     """Parse ``DISTLR_METRICS_SNAPSHOT`` into its target paths: one
     file, or several ``os.pathsep``-separated ones (``a.prom:b.json``
-    banks both the text AND the JSON form — ``capture_all_tpu.sh``
-    feeds the second to the fleet aggregator's ``snapshots/`` dir)."""
+    banks both the text AND the JSON form — the second is what the
+    fleet aggregator's ``snapshots/`` dir takes)."""
     if value is None:
         value = os.environ.get("DISTLR_METRICS_SNAPSHOT", "")
     return [p for p in value.split(os.pathsep) if p]

@@ -68,10 +68,7 @@ class JitCacheProbe:
         self._seen = self._size()
 
     def _size(self) -> int:
-        try:
-            return int(self._fn._cache_size())
-        except Exception:  # noqa: BLE001 — private API; absent = opt out
-            return 0
+        return int(self._fn._cache_size())
 
     def tick(self, bucket: str | int = "-") -> int:
         """Record compiles since the last tick under ``bucket``;

@@ -26,7 +26,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distlr_tpu.config import Config
-from distlr_tpu.parallel.mesh import DATA_AXIS, axis_size, shard_map
+from distlr_tpu.parallel.mesh import DATA_AXIS
 
 
 def _batch_spec(batch) -> tuple:
@@ -48,7 +48,7 @@ def make_sync_train_step(model, cfg: Config, mesh: Mesh, *, with_metrics: bool =
         if cfg.sync_last_gradient:
             # Q1 compat: psum of (g_i masked to the top rank) == g_last;
             # the reference then divides by the number of workers.
-            n_shards = axis_size(DATA_AXIS)
+            n_shards = lax.axis_size(DATA_AXIS)
             is_last = (lax.axis_index(DATA_AXIS) == n_shards - 1)
             g = lax.psum(jax.tree.map(lambda t: t * is_last, g_local), DATA_AXIS)
             g = jax.tree.map(lambda t: t / n_shards, g)
@@ -66,7 +66,7 @@ def make_sync_train_step(model, cfg: Config, mesh: Mesh, *, with_metrics: bool =
         return w_new, metrics
 
     def step(w, batch):
-        return shard_map(
+        return jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(P(), _batch_spec(batch)),
@@ -100,7 +100,7 @@ def make_eval_step(model, mesh: Mesh):
         }
 
     def evaluate(w, batch):
-        return shard_map(
+        return jax.shard_map(
             local_eval,
             mesh=mesh,
             in_specs=(P(), _batch_spec(batch)),

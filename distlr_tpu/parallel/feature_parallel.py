@@ -36,7 +36,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from distlr_tpu.config import Config
 from distlr_tpu.models import BinaryLR, SoftmaxRegression
 from distlr_tpu.models.linear import _int8_contract, quantize_sym
-from distlr_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, shard_map
+from distlr_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 
 def _check_mesh(mesh: Mesh, num_features: int) -> None:
@@ -157,7 +157,7 @@ def make_feature_sharded_train_step(model, cfg: Config, mesh: Mesh, *, with_metr
 
     def step(w, batch):
         X, y, mask = batch
-        return shard_map(
+        return jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(w_spec, x_spec, P(DATA_AXIS), P(DATA_AXIS)),
@@ -194,7 +194,7 @@ def make_feature_sharded_eval_step(model, mesh: Mesh):
 
     def evaluate(w, batch):
         X, y, mask = batch
-        return shard_map(
+        return jax.shard_map(
             local_eval,
             mesh=mesh,
             in_specs=(w_spec, P(DATA_AXIS, MODEL_AXIS), P(DATA_AXIS), P(DATA_AXIS)),

@@ -23,40 +23,8 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # single shim point for the whole package (and tests)
-    _shard_map_impl = jax.shard_map
-except AttributeError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_impl  # type: ignore
-
-import inspect as _inspect
-
-_SM_PARAMS = frozenset(_inspect.signature(_shard_map_impl).parameters)
-
-
-def shard_map(f, **kw):
-    """``jax.shard_map`` with the replication-check kwarg normalized:
-    newer jax renamed ``check_rep`` to ``check_vma`` — accept either and
-    pass whichever the installed version understands."""
-    if "check_vma" in kw and "check_vma" not in _SM_PARAMS:
-        kw["check_rep"] = kw.pop("check_vma")
-    elif "check_rep" in kw and "check_rep" not in _SM_PARAMS:
-        kw["check_vma"] = kw.pop("check_rep")
-    return _shard_map_impl(f, **kw)
-
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
-
-
-def axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis, from inside a shard_map body.
-
-    ``jax.lax.axis_size`` only exists on newer jax; ``lax.psum(1, name)``
-    const-folds to a Python int at trace time on every version this
-    package supports, so callers that need a STATIC size (loop bounds,
-    permutation tables) can rely on it."""
-    if hasattr(jax.lax, "axis_size"):  # pragma: no cover — newer jax
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 def make_mesh(shape: dict | None = None, *, devices=None) -> Mesh:

@@ -36,7 +36,7 @@ from distlr_tpu.parallel.feature_parallel import (
     resid_grad,
     partial_logits,
 )
-from distlr_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, axis_size, shard_map
+from distlr_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 
 def _ring_perm(s: int, reverse: bool = False):
@@ -53,7 +53,7 @@ def ring_reduce_scatter(x, axis_name: str):
     device ``i`` owns chunk ``(i + 1) % s`` of the padded input.  S-1
     neighbor hops, each carrying one chunk.
     """
-    s = axis_size(axis_name)
+    s = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     n = x.shape[0]
     chunk = -(-n // s)
@@ -81,7 +81,7 @@ def ring_all_gather(chunk, axis_name: str, *, owner_offset: int = 0):
     device ``i`` contributes the chunk logically numbered ``(i + k) % s``
     (reduce-scatter above leaves ownership rotated by one).  S-1 hops.
     """
-    s = axis_size(axis_name)
+    s = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     out = jnp.zeros((s,) + chunk.shape, chunk.dtype)
     own = (idx + owner_offset) % s
@@ -149,7 +149,7 @@ def make_ring_train_step(model, cfg: Config, mesh: Mesh, *, with_metrics: bool =
 
     def step(w, batch):
         X, y, mask = batch
-        return shard_map(
+        return jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(P(MODEL_AXIS), P(DATA_AXIS, MODEL_AXIS), P(DATA_AXIS), P(DATA_AXIS)),

@@ -28,13 +28,10 @@ or explicitly audited.
 
 from __future__ import annotations
 
-import contextlib
 import os
 import re
-import subprocess
-import threading
 
-_lock = threading.Lock()
+from distlr_tpu.utils.native_build import ensure_built
 
 #: sanitizer variant -> (make target, server suffix, options env var)
 _VARIANTS = {
@@ -60,11 +57,11 @@ def native_variant() -> str:
     return v
 
 
-def server_binary() -> str:
-    """The KV server binary honoring the active variant — which is what
-    routes every ServerGroup spawn (and the e2e suites riding them)
-    onto the instrumented build."""
-    v = native_variant()
+def server_binary(variant: str | None = None) -> str:
+    """The KV server binary of ``variant`` (default: the active one) —
+    which is what routes every ServerGroup spawn (and the e2e suites
+    riding them) onto the instrumented build."""
+    v = native_variant() if variant is None else variant
     suffix = _VARIANTS[v][1] if v else ""
     return os.path.join(native_dir(), f"distlr_kv_server{suffix}")
 
@@ -140,71 +137,21 @@ def sanitizer_environ(base: dict | None = None) -> dict | None:
     return env
 
 
-def _outputs() -> list[str]:
-    outs = [os.path.join(native_dir(), "distlr_kv_server"),
-            os.path.join(native_dir(), "libdistlr_kv.so")]
-    v = native_variant()
-    if v:
-        outs.append(server_binary())
-        if v == "tsan":
+def _outputs(variant: str) -> list[str]:
+    outs = [server_binary(""), os.path.join(native_dir(), "libdistlr_kv.so")]
+    if variant:
+        outs.append(server_binary(variant))
+        if variant == "tsan":
             outs.append(os.path.join(native_dir(), "libdistlr_kv_tsan.so"))
     return outs
 
 
-def _artifacts_fresh() -> bool:
-    """True when every needed output exists and is newer than every
-    source — lets prebuilt deployment images run without a make/C++
-    toolchain."""
-    outs = _outputs()
-    if not all(os.path.exists(o) for o in outs):
-        return False
-    srcs = [
-        os.path.join(native_dir(), f)
-        for f in os.listdir(native_dir())
-        if f.endswith((".cc", ".h")) or f == "Makefile"
-    ]
-    if not srcs:  # sources stripped from the image: artifacts are all there is
-        return True
-    newest_src = max(os.path.getmtime(s) for s in srcs)
-    return min(os.path.getmtime(o) for o in outs) >= newest_src
-
-
-@contextlib.contextmanager
-def _file_lock():
-    """Serialize concurrent builds across processes (fcntl advisory lock;
-    worker processes on one host may race the same .so outputs)."""
-    import fcntl  # noqa: PLC0415  (POSIX-only, like the native build itself)
-
-    path = os.path.join(native_dir(), ".build.lock")
-    with open(path, "w") as f:
-        fcntl.flock(f, fcntl.LOCK_EX)
-        try:
-            yield
-        finally:
-            fcntl.flock(f, fcntl.LOCK_UN)
-
-
-def build_native(force: bool = False) -> None:
-    """Idempotently ``make`` the native components (plus the active
-    sanitizer variant's targets); no-op (and toolchain-free) when the
-    built artifacts are already newer than the sources."""
-    with _lock:
-        if not force and _artifacts_fresh():
-            return
-        with _file_lock():
-            if not force and _artifacts_fresh():  # built while we waited
-                return
-            targets = ["all"]
-            v = native_variant()
-            if v:
-                targets.append(_VARIANTS[v][0])
-            proc = subprocess.run(
-                ["make", "-C", native_dir()]
-                + ((["clean"] if force else []) + targets),
-                capture_output=True,
-                text=True,
-            )
-            if proc.returncode != 0:
-                raise RuntimeError(
-                    f"native PS build failed:\n{proc.stdout}\n{proc.stderr}"
-                )
+def build_native(force: bool = False, variant: str | None = None) -> None:
+    """Idempotently ``make`` the native components plus a sanitizer
+    variant's (default: the active ``DISTLR_NATIVE_VARIANT``).  Freshness
+    is the stamp rule of :mod:`distlr_tpu.utils.native_build`: no-op when
+    every artifact was built from the sources as they are now, rebuild
+    otherwise."""
+    if variant is None:
+        variant = native_variant()
+    ensure_built(native_dir(), _outputs(variant), force=force)

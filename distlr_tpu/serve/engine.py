@@ -21,9 +21,6 @@ Design constraints, in order:
   against the weights it read at entry (a Python reference read — no
   torn state is observable), so a trainer can publish continuously while
   requests stream (see :mod:`distlr_tpu.serve.reload`).
-* **Donated batch buffers.** The padded feature arrays are fresh per
-  call and donated to the jitted program, so steady-state serving does
-  not double-buffer every request batch in HBM.
 """
 
 from __future__ import annotations
@@ -93,43 +90,23 @@ def _next_pow2(n: int) -> int:
 # ONE jitted scorer for the whole process, keyed on the (frozen,
 # hashable) model value — engines over the same model share compiled
 # programs.  Returns (labels, scores): scores is P(y=1) for binary
-# families and the max class probability for softmax families.  On
-# accelerators the batch leaves are donated — they are padded copies
-# made in score(), never caller memory — so steady-state serving does
-# not double-buffer every request batch in HBM; the CPU backend (which
-# can't consume these donations and would warn per compile) gets the
-# plain variant.  Resolved lazily so importing the serve package never
-# touches the backend (bench-probe hygiene).
-def _score_body(model, w, rows):
+# families and the max class probability for softmax families.  The
+# batch leaves are not donated: the outputs are (B,)-shaped, so no
+# output can reuse a (B, D) input buffer and a donation only earns a
+# "donated buffers were not usable" warning per bucket; the padded copy
+# made in score() is dropped as soon as the call returns.
+@functools.partial(jax.jit, static_argnums=0)
+def _jit_score(model, w, rows):
     labels = model.predict(w, *rows)
     p = model.proba(w, *rows)
     scores = p if p.ndim == 1 else p.max(axis=-1)
     return labels, scores
 
 
-_jit_score_donating = functools.partial(
-    jax.jit, static_argnums=0, donate_argnums=2)(_score_body)
-_jit_score_plain = functools.partial(jax.jit, static_argnums=0)(_score_body)
-_jit_score = None
-
-
-_jit_score_probe = None
-
-
-def _resolve_jit_score():
-    global _jit_score, _jit_score_probe
-    if _jit_score is None:
-        fn = (_jit_score_plain if jax.default_backend() == "cpu"
-              else _jit_score_donating)
-        # runtime introspection (obs.jaxrt): per-bucket compile counts —
-        # one probe for the process-shared scorer, so every engine's
-        # recompiles land in distlr_jax_compiles_total{site="serve.engine"}.
-        # Probe published BEFORE the fn: a second thread races past the
-        # None check only once _jit_score is set, by which point the
-        # probe it will tick already exists.
-        _jit_score_probe = jaxrt.JitCacheProbe(fn, "serve.engine")
-        _jit_score = fn
-    return _jit_score
+# runtime introspection (obs.jaxrt): per-bucket compile counts — one
+# probe for the process-shared scorer, so every engine's recompiles land
+# in distlr_jax_compiles_total{site="serve.engine"}
+_jit_score_probe = jaxrt.JitCacheProbe(_jit_score, "serve.engine")
 
 
 class ScoringEngine:
@@ -292,7 +269,7 @@ class ScoringEngine:
         self._bucket_hits[bucket] = self._bucket_hits.get(bucket, 0) + 1
         _BUCKET_HITS.labels(bucket=bucket).inc()
         w = self._weights  # atomic reference read — the swap point
-        labels, scores = _resolve_jit_score()(
+        labels, scores = _jit_score(
             self.model, w, self._pad_rows(rows, bucket))
         # attribute any cache growth to the bucket that just ran — the
         # "bucket B keeps recompiling" signal `launch top` surfaces

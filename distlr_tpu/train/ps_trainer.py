@@ -219,12 +219,17 @@ def ps_compute_device(cfg: Config, rows: int | None = None):
         return "numpy"
     if jax.default_backend() == "cpu" or work >= _PS_AUTO_CPU_THRESHOLD:
         return None
-    try:
-        return jax.devices("cpu")[0]
-    except RuntimeError:
-        # JAX_PLATFORMS=tpu (no cpu backend initialized): degrade to the
-        # default backend rather than abort — "auto" is best-effort.
-        return None
+    # raises when JAX_PLATFORMS names no cpu backend: the operator
+    # excluded the host, so "auto" says so rather than pick for them
+    return jax.devices("cpu")[0]
+
+
+def _describe_compute_device(device) -> str:
+    """Log form of a :func:`ps_compute_device` choice."""
+    if device == "numpy":
+        return "numpy (host, no jax)"
+    d = jax.devices()[0] if device is None else device
+    return f"{d.platform}:{d.device_kind} (id {d.id})"
 
 
 def _np_dense_grad(w, X, y, mask, l2_c, l2_scale_by_batch, num_classes=None):
@@ -828,6 +833,12 @@ class PSWorker:
             train_rows = cfg.batch_size if cfg.batch_size > 0 else train.num_samples
             step_dev = ps_compute_device(cfg, train_rows)
             eval_dev = ps_compute_device(cfg, test.num_samples) if test is not None else None
+            log.info(
+                "rank %d dense steps pinned: train -> %s%s (ps_compute_backend=%s)",
+                self.rank, _describe_compute_device(step_dev),
+                "" if test is None
+                else f", eval -> {_describe_compute_device(eval_dev)}",
+                cfg.ps_compute_backend)
             K = cfg.num_classes if cfg.model == "softmax" else None
             if step_dev == "numpy":
                 def compute_g(wf, X, y, mask):
@@ -839,6 +850,10 @@ class PSWorker:
                 def compute_g(wf, X, y, mask):
                     return np.asarray(self._grad_fn(*self._place(
                         step_dev, self._shape_params(wf), X, y, mask))).reshape(-1)
+        else:
+            log.info("rank %d %s steps and eval run in numpy on the host "
+                     "(keyed models never use the accelerator)",
+                     self.rank, cfg.model)
         w = w0
         for epoch in range(start_epoch, cfg.num_iteration):
             train.reset()

@@ -2,8 +2,8 @@
 
 The blocked layout trades per-field bucket weights for per-(conjunction,
 field) row lanes so one R-wide row gather replaces R scalar gathers
-(benchmarks/ROOFLINE.md's 3.4x byte-rate finding; perf measured on-chip
-by benchmarks/exp_blocked.py).  These tests pin the semantics and the
+(row gathers amortize the per-index cost; benchmarks/exp_blocked.py
+measures it on the chip).  These tests pin the semantics and the
 statistical gate: on low-cardinality fields (recurring tuples) the
 blocked model must recover the oracle signal as well as the scalar-hash
 sparse path does.
@@ -515,8 +515,8 @@ class TestSuggestBlockSize:
 
 class TestBlockGroups:
     """cfg.block_groups / --block-groups: explicit conjunction-group
-    counts (r5).  The measured motivation lives in FRONTIER_TPU.json's
-    operating_point section; these tests pin the layout, the statistical
+    counts (r5).  The motivation is bench_configs.py's operating-point
+    sweep; these tests pin the layout, the statistical
     direction, and the end-to-end plumbing."""
 
     def test_split_field_groups_layouts(self):

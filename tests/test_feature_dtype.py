@@ -1,8 +1,7 @@
 """Reduced-precision dense feature storage (cfg.feature_dtype).
 
-The dense D=1M step is HBM-bound on the feature stream
-(benchmarks/ROOFLINE.md): bfloat16 halves the bytes, int8 quarters them
-via symmetric per-dataset quantization with the scale folded into the
+The dense D=1M step streams the feature matrix from HBM twice:
+bfloat16 halves the bytes, int8 quarters them via symmetric per-dataset quantization with the scale folded into the
 model (``feature_scale``).  These tests pin the numerics.
 """
 

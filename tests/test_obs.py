@@ -257,7 +257,7 @@ class TestExporters:
 
     def test_write_snapshot_json_twin(self, tmp_path):
         """A .json path banks the machine-readable registry snapshot —
-        what the fleet aggregator and capture_all_tpu.sh consume."""
+        what the fleet aggregator consumes."""
         reg = MetricsRegistry()
         reg.counter("c_total").inc(3)
         path = str(tmp_path / "metrics.json")

@@ -351,7 +351,7 @@ class TestFleetScraper:
 
     def test_snapshot_file_source_merges(self, tmp_path):
         """Portless one-shot processes federate through banked
-        snapshots/<role>-<rank>.json files (the capture_all_tpu path)."""
+        snapshots/<role>-<rank>.json files."""
         from distlr_tpu.obs import write_metrics_snapshot
 
         run = str(tmp_path)

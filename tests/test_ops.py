@@ -58,6 +58,38 @@ class TestFusedLRGrad:
         assert not fused_lr_supported(64, 100, 16)   # D not mult of 128
         assert not fused_lr_supported(64, 128, 8)    # tile not mult of 16
 
+    def test_supported_tracks_what_mosaic_compiled_on_the_chip(self):
+        """The budget counts the float32 copy of the X tile: these shapes
+        all passed the old (w + g + 2 bf16 tiles) estimate and Mosaic
+        refused every one on a v5e ("scoped allocation ... exceeded
+        scoped vmem limit", 16.02M to 25.33M against 16.00M); the last
+        three are the widest the budget admits per tile and compiled
+        there (chip_smoke.py's kernel leg compiles them on every run)."""
+        for shape in ((128, 32768, 64), (128, 49152, 64), (256, 24576, 128),
+                      (64, 196608, 16)):
+            assert not fused_lr_supported(*shape), shape
+        for shape in ((32, 116480, 16), (128, 31744, 64), (256, 16128, 128)):
+            assert fused_lr_supported(*shape), shape
+
+    @pytest.mark.parametrize("shape", [(512, 16384, 64), (4096, 16384, 64)])
+    def test_lowers_to_mosaic_for_tpu(self, shape):
+        """The chip smoke's two shapes lower for platforms=['tpu'] from
+        the CPU (lowering only: compiling needs the chip)."""
+        import functools
+
+        import jax
+        from jax import export
+
+        B, D, tile = shape
+        fn = functools.partial(fused_lr_grad, batch_tile=tile, interpret=False)
+        exported = export.export(jax.jit(fn), platforms=["tpu"])(
+            jax.ShapeDtypeStruct((D,), jnp.float32),
+            jax.ShapeDtypeStruct((B, D), jnp.bfloat16),
+            jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.float32),
+        )
+        assert "tpu_custom_call" in exported.mlir_module()
+
     def test_unsupported_raises(self):
         with pytest.raises(ValueError, match="unsupported"):
             fused_lr_grad(

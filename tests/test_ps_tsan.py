@@ -20,23 +20,18 @@ from __future__ import annotations
 import glob
 import os
 import shutil
-import subprocess
 import threading
 
 import numpy as np
 import pytest
 
 from distlr_tpu.ps import KVWorker, MembershipCoordinator, ServerGroup
-from distlr_tpu.ps.build import native_dir
+from distlr_tpu.ps.build import build_native, native_dir
 
 
 def _build_tsan() -> str:
-    binary = os.path.join(native_dir(), "distlr_kv_server_tsan")
-    subprocess.run(
-        ["make", "-C", native_dir(), "tsan"],
-        check=True, capture_output=True, text=True,
-    )
-    return binary
+    build_native(variant="tsan")
+    return os.path.join(native_dir(), "distlr_kv_server_tsan")
 
 
 needs_toolchain = pytest.mark.skipif(

@@ -3,7 +3,7 @@
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from distlr_tpu import Config
@@ -14,7 +14,6 @@ from distlr_tpu.parallel.feature_parallel import (
     shard_batch_2d,
     shard_weights,
 )
-from distlr_tpu.parallel.mesh import shard_map
 from distlr_tpu.parallel.ring import make_ring_train_step, ring_all_gather, ring_psum
 
 

@@ -47,10 +47,11 @@ def _libtsan() -> str | None:
 
 
 def _build(variant: str) -> None:
-    subprocess.run(
-        ["make", "-C", os.path.join(REPO, "distlr_tpu", "ps", "native"),
-         variant],
-        check=True, capture_output=True, text=True)
+    # through the stamp rule, so the workload subprocess (which builds
+    # on demand under DISTLR_NATIVE_VARIANT) finds it fresh
+    from distlr_tpu.ps.build import build_native
+
+    build_native(variant=variant)
 
 
 def _host_supp() -> str:
