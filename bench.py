@@ -409,7 +409,8 @@ def main():
     value = _bench_tpu(d, b, steps, lr, l2)
     headline_wall = time.perf_counter() - t_headline
     phases = tracer.breakdown()
-    covered = sum(p["seconds"] for p in phases.values())
+    # self seconds: a phase nested in another is counted once
+    covered = sum(p["self_seconds"] for p in phases.values())
     phase_breakdown = {
         "phases": phases,
         "wall_s": round(headline_wall, 6),

@@ -87,30 +87,6 @@ SPARSE_FIELDS, SPARSE_VOCAB, SPARSE_LR = 21, 1000, 100.0
 MESH_LR, MESH_EPOCHS, MESH_RTOL, MESH_ATOL = 0.2, 2, 2e-5, 1e-5
 
 
-class _Compiles:
-    """Seconds spent in XLA compilation (a persistent-cache hit counts
-    its retrieval time) and cache hits/misses, from jax.monitoring."""
-
-    def __init__(self):
-        import jax
-
-        self.seconds = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-
 class _LogCapture(logging.Handler):
     def __init__(self):
         super().__init__(logging.INFO)
@@ -144,10 +120,14 @@ class Smoke:
     def __init__(self, sizes: Sizes, tmp: str, dev: dict):
         import jax
 
+        from distlr_tpu.obs import jaxrt
+
+        # compile seconds and cache hits, from the program's own registry
+        # (distlr_jax_compile_seconds_total, distlr_jax_compile_cache_total)
+        self.compile_totals = jaxrt.compile_totals
         self.s = sizes
         self.tmp = tmp
         self.dev = dev  # backend.device_summary()
-        self.compiles = _Compiles()
         self.on_tpu = dev["platform"] == "tpu"
         self.devices = jax.devices()
         # shared between legs
@@ -157,7 +137,7 @@ class Smoke:
 
     # -- plumbing -----------------------------------------------------------
     def run_leg(self, name: str, fn) -> None:
-        c0, t0 = self.compiles.seconds, time.perf_counter()
+        c0, t0 = self.compile_totals()["seconds"], time.perf_counter()
         try:
             fields = fn()
         except BaseException:
@@ -170,7 +150,7 @@ class Smoke:
             "steps": fields.pop("steps", "na"),
             "loss_first": fields.pop("loss_first", "na"),
             "loss_last": fields.pop("loss_last", "na"),
-            "compile_s": f"{self.compiles.seconds - c0:.2f}",
+            "compile_s": f"{self.compile_totals()['seconds'] - c0:.2f}",
             "wall_s": f"{time.perf_counter() - t0:.2f}",
             "host_rss_mib": "{}(peak {})".format(*_rss_mib()),
         }
@@ -564,9 +544,10 @@ def main(argv=None) -> int:
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
 
+    compiled = smoke.compile_totals()
     print(f"SMOKE total wall_s={time.perf_counter() - t0:.1f} "
-          f"compile_s={smoke.compiles.seconds:.1f} "
-          f"cache_hits={smoke.compiles.hits} cache_misses={smoke.compiles.misses} "
+          f"compile_s={compiled['seconds']:.1f} "
+          f"cache_hits={compiled['hits']} cache_misses={compiled['misses']} "
           f"cache_entries_before={entries_before} "
           f"cache_entries_after={_cache_entries(cache_dir)} "
           f"host_rss_peak_mib={_rss_mib()[1]}", flush=True)

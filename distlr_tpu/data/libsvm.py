@@ -24,6 +24,7 @@ import numpy as np
 __all__ = [
     "parse_libsvm_lines",
     "parse_libsvm_file",
+    "densify_csr",
     "write_libsvm",
     "native_available",
 ]
@@ -102,8 +103,9 @@ def _parse_csr(text_or_lines, multiclass: bool):
     return _parse_python(text_or_lines, multiclass)
 
 
-def _densify(labels, row_ptr, cols, vals, num_features: int):
-    n = len(labels)
+def densify_csr(row_ptr, cols, vals, num_features: int):
+    """CSR rows as one ``(N, D)`` float32 matrix."""
+    n = len(row_ptr) - 1
     X = np.zeros((n, num_features), dtype=np.float32)
     keep = (cols >= 0) & (cols < num_features)  # out-of-range features dropped, not UB
     rows = np.repeat(np.arange(n), np.diff(row_ptr))
@@ -134,7 +136,7 @@ def parse_libsvm_lines(
     if dense:
         if num_features is None:
             raise ValueError("num_features is required for dense parsing")
-        return _densify(labels, row_ptr, cols, vals, num_features), labels
+        return densify_csr(row_ptr, cols, vals, num_features), labels
     if num_features is not None:
         keep = (cols >= 0) & (cols < num_features)
         if not keep.all():

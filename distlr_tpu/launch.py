@@ -579,9 +579,13 @@ def cmd_sync(args: argparse.Namespace) -> int:
         trainer = Trainer(cfg).load_data()
         trainer.fit(resume=args.resume)
         path = trainer.save_model()
+        # the rate is rows over the wall of fit; the rate inside steps
+        # alone (StepTimer) is the device step's, named for what it is
         log.info(
-            "final accuracy %.4f, %.0f samples/sec, model -> %s",
-            trainer.evaluate(), trainer.timer.samples_per_sec, path,
+            "final accuracy %.4f, %.0f samples/sec (%.0f step_samples/sec "
+            "inside steps), model -> %s",
+            trainer.evaluate(), trainer.fit_samples_per_sec,
+            trainer.timer.samples_per_sec, path,
         )
     return 0
 

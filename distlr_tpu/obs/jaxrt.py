@@ -4,7 +4,7 @@ The sampling profiler (:mod:`distlr_tpu.obs.profile`) sees Python
 frames; what it cannot see is the JAX runtime underneath them — a
 recompile storm (every new batch shape costs a fresh XLA compile) reads
 as "time in ``jit`` dispatch", and HBM pressure is invisible entirely.
-This module exports the two runtime signals that close that gap:
+This module exports the runtime signals that close that gap:
 
 * **compile / trace-cache misses** — :class:`JitCacheProbe` wraps one
   jitted callable's executable cache (``_cache_size()``) and diffs it
@@ -12,6 +12,14 @@ This module exports the two runtime signals that close that gap:
   ticking counter IS the recompile storm (the serving engine labels the
   batch bucket that triggered each one, so "bucket 1024 keeps
   recompiling" is one scrape away).
+* **compile seconds and persistent-cache outcomes** — what
+  ``jax.monitoring`` reports for the whole process, whichever call site
+  compiled: ``distlr_jax_compile_seconds_total`` (a hit in the
+  persistent cache counts its retrieval) and
+  ``distlr_jax_compile_cache_total{result}``.  The listeners are
+  registered when this module is imported, which every JAX role does
+  before its first compile; :func:`compile_totals` reads them back
+  (``chip_smoke.py`` prints a leg's share from it).
 * **live device buffers** — :func:`sample_device_bytes` sums
   ``jax.live_arrays()`` into ``distlr_jax_device_buffer_bytes`` /
   ``distlr_jax_live_buffers`` gauges.  Walking every live array has a
@@ -40,6 +48,17 @@ _COMPILES = _reg.counter(
     "triggered each one",
     labelnames=("site", "bucket"),
 )
+_COMPILE_SECONDS = _reg.counter(
+    "distlr_jax_compile_seconds_total",
+    "seconds in XLA backend compilation, process-wide, from "
+    "jax.monitoring (a persistent-cache hit counts its retrieval)",
+)
+_COMPILE_CACHE = _reg.counter(
+    "distlr_jax_compile_cache_total",
+    "persistent compilation cache lookups by result (hit / miss), "
+    "process-wide, from jax.monitoring",
+    labelnames=("result",),
+)
 _DEVICE_BYTES = _reg.gauge(
     "distlr_jax_device_buffer_bytes",
     "bytes held by live jax arrays at the last introspection walk "
@@ -52,6 +71,35 @@ _LIVE_BUFFERS = _reg.gauge(
 
 _lock = threading.Lock()
 _last_walk = 0.0
+
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+
+
+def _on_duration(event: str, duration: float, **_) -> None:
+    if event == "/jax/core/compile/backend_compile_duration":
+        _COMPILE_SECONDS.inc(duration)
+
+
+def _on_event(event: str, **_) -> None:
+    result = _CACHE_EVENTS.get(event)
+    if result is not None:
+        _COMPILE_CACHE.labels(result=result).inc()
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+jax.monitoring.register_event_listener(_on_event)
+
+
+def compile_totals() -> dict:
+    """``{"seconds", "hits", "misses"}`` of this process so far."""
+    return {
+        "seconds": _COMPILE_SECONDS.value,
+        "hits": int(_COMPILE_CACHE.labels(result="hit").value),
+        "misses": int(_COMPILE_CACHE.labels(result="miss").value),
+    }
 
 
 class JitCacheProbe:

@@ -6,9 +6,10 @@ every perf question — "why is the async run slower?", "did the prefetch
 actually overlap?" — is a question about where the time went *between*
 them.  ``trace_phase("pull")`` wraps a block; each span is
 
-* accumulated into a per-phase (total seconds, count) breakdown that
-  survives any event-buffer cap — this is what ``bench.py``'s
-  ``phase_breakdown`` and the ROADMAP's on-chip captures report; and
+* accumulated into a per-phase (total seconds, self seconds, count)
+  breakdown that survives any event-buffer cap — this is what
+  ``bench.py``'s ``phase_breakdown`` and the on-chip benchmark's
+  per-layer readers (``chipbench/layer_metrics``) report; and
 * recorded into the registry histogram ``distlr_phase_seconds{phase=}``
   so the /metrics scrape carries the same story; and
 * appended (bounded) as a Chrome trace event, dumpable as JSON that
@@ -17,11 +18,21 @@ them.  ``trace_phase("pull")`` wraps a block; each span is
 Spans may run concurrently on many threads (prefetch producer, PS comm
 thread, microbatch flusher, N Hogwild workers); each event carries its
 thread id so the trace shows real overlap, not an interleaved fiction.
+
+A span knows what caused it: the span open on the same thread when it
+started is its ``parent`` (``with trace_phase("data_load"): with
+trace_phase("h2d_wait"): ...``), and a span's *self* time is its
+duration less what its children cover, so a sum of self seconds counts
+no interval twice.  ``trace_phase(name, step=n)`` tags the span with the
+unit of work it belongs to: the producer thread's spans for batch *n*
+and the consumer's for step *n* share the id, which is how one step is
+followed across threads in the dumped trace.
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import os
 import threading
@@ -44,10 +55,15 @@ class PhaseTracer:
         self._registry = registry or get_registry()
         self._max_events = max_events
         self._lock = threading.Lock()
-        self._events: list[tuple[str, int, float, float]] = []
+        # (name, tid, start, duration, span id, parent id, step)
+        self._events: list[tuple] = []
         self._dropped = 0
-        self._totals: dict[str, list] = {}  # phase -> [seconds, count]
+        self._totals: dict[str, list] = {}  # phase -> [seconds, count, self]
         self._epoch = time.perf_counter()
+        self._ids = itertools.count(1)
+        # per thread: the open spans, innermost last, each
+        # [span id, seconds its finished children took]
+        self._open = threading.local()
         self._hist = self._registry.histogram(
             "distlr_phase_seconds",
             "wall seconds spent per pipeline phase",
@@ -55,33 +71,50 @@ class PhaseTracer:
         )
 
     @contextlib.contextmanager
-    def phase(self, name: str):
+    def phase(self, name: str, step: int | None = None):
+        try:
+            stack = self._open.stack
+        except AttributeError:
+            stack = self._open.stack = []
+        frame = [next(self._ids), 0.0]
+        parent = stack[-1][0] if stack else None
+        stack.append(frame)
         t0 = time.perf_counter()
         try:
             yield
         finally:
             t1 = time.perf_counter()
             dur = t1 - t0
+            stack.pop()
+            if stack:
+                stack[-1][1] += dur
+            own = max(dur - frame[1], 0.0)
             self._hist.labels(phase=name).observe(dur)
             tid = threading.get_ident()
             with self._lock:
                 tot = self._totals.get(name)
                 if tot is None:
-                    self._totals[name] = [dur, 1]
+                    self._totals[name] = [dur, 1, own]
                 else:
                     tot[0] += dur
                     tot[1] += 1
+                    tot[2] += own
                 if len(self._events) < self._max_events:
-                    self._events.append((name, tid, t0 - self._epoch, dur))
+                    self._events.append(
+                        (name, tid, t0 - self._epoch, dur, frame[0], parent,
+                         step))
                 else:
                     self._dropped += 1
 
     def breakdown(self) -> dict[str, dict]:
-        """``{phase: {"seconds", "count"}}`` accumulated since reset."""
+        """``{phase: {"seconds", "count", "self_seconds"}}`` accumulated
+        since reset.  ``self_seconds`` leaves out what the phase's child
+        spans cover: sum that, not ``seconds``, across nested phases."""
         with self._lock:
             return {
-                name: {"seconds": round(sec, 6), "count": count}
-                for name, (sec, count) in sorted(self._totals.items())
+                name: {"seconds": round(sec, 6), "count": count,
+                       "self_seconds": round(own, 6)}
+                for name, (sec, count, own) in sorted(self._totals.items())
             }
 
     def phase_names(self) -> set[str]:
@@ -98,22 +131,30 @@ class PhaseTracer:
     # -- Chrome trace-event export ---------------------------------------
     def chrome_trace(self) -> dict:
         """Trace-event JSON object (``ph: "X"`` complete events, us
-        timestamps) — loadable in Perfetto / chrome://tracing."""
+        timestamps) — loadable in Perfetto / chrome://tracing.  Each
+        event's ``args`` hold its span ``id`` and, where it has them, its
+        ``parent`` span's id and its ``step``."""
         pid = os.getpid()
         with self._lock:
-            events = [
-                {
-                    "name": name,
-                    "cat": "phase",
-                    "ph": "X",
-                    "pid": pid,
-                    "tid": tid,
-                    "ts": round(t0 * 1e6, 3),
-                    "dur": round(dur * 1e6, 3),
-                }
-                for name, tid, t0, dur in self._events
-            ]
+            recorded = list(self._events)
             dropped = self._dropped
+        events = []
+        for name, tid, t0, dur, span_id, parent, step in recorded:
+            args = {"id": span_id}
+            if parent is not None:
+                args["parent"] = parent
+            if step is not None:
+                args["step"] = step
+            events.append({
+                "name": name,
+                "cat": "phase",
+                "ph": "X",
+                "pid": pid,
+                "tid": tid,
+                "ts": round(t0 * 1e6, 3),
+                "dur": round(dur * 1e6, 3),
+                "args": args,
+            })
         doc = {
             "traceEvents": events,
             "displayTimeUnit": "ms",
@@ -140,6 +181,6 @@ def get_tracer() -> PhaseTracer:
     return _TRACER
 
 
-def trace_phase(name: str):
-    """``with trace_phase("compute"): ...`` on the default tracer."""
-    return _TRACER.phase(name)
+def trace_phase(name: str, step: int | None = None):
+    """``with trace_phase("compute", step=n): ...`` on the default tracer."""
+    return _TRACER.phase(name, step)

@@ -28,6 +28,10 @@ from distlr_tpu.obs.registry import get_registry as _get_registry
 class StepTimer:
     """Wall-clock step timer with samples/sec accounting.
 
+    ``samples_per_sec`` divides by the time between ``start`` and
+    ``stop`` only, so it is a step rate, not a run's throughput: what a
+    loop does between its steps (waiting for input, evals) is not in it.
+
     Note: callers must block on device results (``jax.block_until_ready``)
     before ``stop`` for honest timings — JAX dispatch is async.
 
@@ -60,8 +64,9 @@ class StepTimer:
         ).labels(loop=loop)
         self._rate_g = reg.gauge(
             "distlr_train_samples_per_second",
-            "cumulative training throughput per timer (sum instances for "
-            "process throughput)", ("loop", "instance"),
+            "samples over seconds INSIDE timed steps, cumulative per timer "
+            "(a step rate: waits for input between steps are not in the "
+            "divisor; sum instances across workers)", ("loop", "instance"),
         ).labels(loop=loop, instance=instance)
 
     def start(self):

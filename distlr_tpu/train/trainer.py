@@ -23,12 +23,14 @@ import dataclasses
 import os
 import queue
 import threading
+import time
 
 import jax
 import numpy as np
 
 from distlr_tpu.config import Config
 from distlr_tpu.data import DataIter, parse_libsvm_file
+from distlr_tpu.data.libsvm import densify_csr
 from distlr_tpu.data.sharding import part_name
 from distlr_tpu.models import get_model
 from distlr_tpu.obs import jaxrt
@@ -45,6 +47,24 @@ from distlr_tpu.train.metrics import MetricsLogger, StepTimer
 from distlr_tpu.utils.logging import get_logger, log_eval_line
 
 log = get_logger(__name__)
+
+
+@contextlib.contextmanager
+def _loop_span(name: str, step: int | None = None, *, marks_step: bool = False):
+    """One span of the training loop, on both records: the process's
+    ``PhaseTracer`` and, while a ``jax.profiler`` trace is being taken
+    (``cfg.profile_dir``, or a caller's own ``jax.profiler.trace``), the
+    host lines of the same ``.xplane.pb`` as the device operations, so
+    that the two share a clock.  An annotation records nothing while no
+    trace is open.  ``marks_step`` makes it the step marker the
+    profiler's tools group device work by."""
+    if marks_step:
+        annotation = jax.profiler.StepTraceAnnotation(name, step_num=step)
+    else:
+        stats = {} if step is None else {"step": step}
+        annotation = jax.profiler.TraceAnnotation(name, **stats)
+    with trace_phase(name, step), annotation:
+        yield
 
 
 class GlobalShardedData:
@@ -124,17 +144,25 @@ class GlobalShardedData:
         paths = cls._discover_parts(data_dir, split)
         parts = []
         for p in paths:
-            if sparse:
-                from distlr_tpu.data.hashing import csr_to_padded_coo  # noqa: PLC0415
-
+            # once a file: the read and the tokenizer, then the rows in
+            # the layout the model family takes
+            with trace_phase("load_parse"):
                 (row_ptr, cols, vals), y = parse_libsvm_file(
                     p, num_features, dense=False, multiclass=multiclass
                 )
-                pc, pv = csr_to_padded_coo(row_ptr, cols, vals, nnz_max=nnz_max)
+            if sparse:
+                from distlr_tpu.data.hashing import csr_to_padded_coo  # noqa: PLC0415
+
+                with trace_phase("load_coo"):
+                    pc, pv = csr_to_padded_coo(row_ptr, cols, vals, nnz_max=nnz_max)
                 parts.append((pc, pv, y))
             else:
-                parts.append(parse_libsvm_file(p, num_features, multiclass=multiclass))
-        return cls._from_parts(parts, num_shards)
+                with trace_phase("load_densify"):
+                    parts.append((densify_csr(row_ptr, cols, vals, num_features), y))
+        # once a split: the redistribution onto mesh slots and the
+        # constructor's copy into the padded (W, n_pad, ...) arrays
+        with trace_phase("load_pack"):
+            return cls._from_parts(parts, num_shards)
 
     @staticmethod
     def _discover_parts(data_dir: str, split: str) -> list[str]:
@@ -173,7 +201,8 @@ class GlobalShardedData:
                 num_groups=cfg.block_groups,
             )
             parts.append((blocks, lane_vals, y))
-        return cls._from_parts(parts, num_shards)
+        with trace_phase("load_pack"):
+            return cls._from_parts(parts, num_shards)
 
     @classmethod
     def _from_parts(cls, parts, num_shards: int):
@@ -204,6 +233,11 @@ class GlobalShardedData:
     @property
     def num_samples(self) -> int:
         return int(sum(self.shard_sizes))
+
+    def num_batches(self, per_worker_batch: int) -> int:
+        """How many batches :meth:`batches` yields an epoch."""
+        b = self.n_pad if per_worker_batch == -1 else min(per_worker_batch, self.n_pad)
+        return -(-self.n_pad // b)
 
     def batches(self, per_worker_batch: int, *, wrap: bool = False):
         """One epoch of lockstep global batches ``(*feats, y, mask)``
@@ -262,8 +296,8 @@ class GlobalShardedData:
 
 
 def _prefetch_to_device(shard_fn, host_batches, depth: int):
-    """Double-buffered host->device streaming: yield
-    ``(host_batch, device_batch)`` pairs with up to ``depth`` batches
+    """Double-buffered host->device streaming: yield ``shard_fn(item)``
+    for each item of ``host_batches``, with up to ``depth`` of them
     sliced + ``device_put`` ahead of the consumer, from a background
     thread.
 
@@ -275,6 +309,10 @@ def _prefetch_to_device(shard_fn, host_batches, depth: int):
     step paid the slice + dispatch latency serially
     (SURVEY.md §7 hard part (d); VERDICT r3 item 3).
 
+    The producer never waits for a transfer to land: how many copies are
+    in flight is set by ``depth`` alone, and the consumer does the
+    waiting (``h2d_wait`` in :meth:`Trainer.fit`).
+
     Safe because :meth:`GlobalShardedData.batches` yields independent
     arrays (fancy-indexed / reshaped slices, never a reused buffer).
     """
@@ -285,10 +323,10 @@ def _prefetch_to_device(shard_fn, host_batches, depth: int):
 
     def produce():
         try:
-            for hb in host_batches:
+            for item in host_batches:
                 if stop.is_set():
                     return
-                q.put((hb, shard_fn(hb)))
+                q.put(shard_fn(item))
         except BaseException as e:  # propagate to the consumer
             errs.append(e)
         q.put(end)
@@ -342,6 +380,11 @@ class Trainer:
             )
         self._build_steps()
         self.timer = StepTimer()
+        #: batches this trainer's loop has taken: the ``step`` id its
+        #: spans carry, so ids do not repeat across ``fit`` calls
+        self.batches_taken = 0
+        #: rows over wall of the last ``fit`` call (0 before the first)
+        self.fit_samples_per_sec = 0.0
         self.weights = None
         self._train_data: GlobalShardedData | None = None
         self._test_data: GlobalShardedData | None = None
@@ -436,7 +479,17 @@ class Trainer:
         train split entirely (eval-only workflows: the train ingest is
         the dominant I/O cost and evaluate_metrics never touches it) —
         float32 features only, since quantized dtypes derive their scale
-        from the train split."""
+        from the train split.
+
+        One ``load_data`` span covers the call; inside it each file is a
+        ``load_parse`` and a ``load_densify`` (or ``load_coo``), each
+        split a ``load_pack``, and a quantized ``feature_dtype`` one
+        ``load_cast``."""
+        with trace_phase("load_data"):
+            self._load_splits(train, test, test_only)
+        return self
+
+    def _load_splits(self, train, test, test_only: bool) -> None:
         if test_only:
             if train is not None:
                 raise ValueError("test_only=True contradicts passing train data")
@@ -453,17 +506,17 @@ class Trainer:
                 self.cfg.data_dir, "test", W, self.cfg
             )
             if test_only:
-                return self
+                return
             self._train_data = train or GlobalShardedData.from_raw_ctr_dir(
                 self.cfg.data_dir, "train", W, self.cfg
             )
-            return self
+            return
         if test_only:
             self._test_data = test or GlobalShardedData.from_data_dir(
                 self.cfg.data_dir, "test", W, self.cfg.num_feature_dim,
                 multiclass=multiclass, sparse=sparse, nnz_max=self.cfg.nnz_max,
             )
-            return self
+            return
         self._train_data = train or GlobalShardedData.from_data_dir(
             self.cfg.data_dir, "train", W, self.cfg.num_feature_dim,
             multiclass=multiclass, sparse=sparse, nnz_max=self.cfg.nnz_max,
@@ -473,7 +526,8 @@ class Trainer:
             multiclass=multiclass, sparse=sparse, nnz_max=self.cfg.nnz_max,
         )
         if self.cfg.feature_dtype != "float32" and not sparse:
-            self._quantize_features()
+            with trace_phase("load_cast"):
+                self._quantize_features()
         elif any(
             getattr(d, "_quant_dtype", None)
             for d in (self._train_data, self._test_data)
@@ -483,7 +537,6 @@ class Trainer:
                 "feature_dtype='float32' run would train on raw quantized "
                 "ints — reload the data or match feature_dtype"
             )
-        return self
 
     # -- training -----------------------------------------------------------
     def init_weights(self):
@@ -499,6 +552,15 @@ class Trainer:
         latest saved epoch (the load path the reference never had).
         """
         cfg = self.cfg
+        t_fit, samples_at_start = time.perf_counter(), self.timer.samples
+
+        def fit_rate() -> float:
+            """Rows this call has trained on over its wall so far, waits
+            for data, evals and checkpoints included: the throughput a
+            run is billed by (``StepTimer`` divides by time inside steps)."""
+            wall = time.perf_counter() - t_fit
+            return (self.timer.samples - samples_at_start) / wall if wall > 0 else 0.0
+
         if self._train_data is None:
             self.load_data()
 
@@ -523,7 +585,8 @@ class Trainer:
         epochs = cfg.num_iteration if epochs is None else epochs
         test_batch = None
         if self._test_data is not None:
-            test_batch = self._shard_batch(self._test_data.full_batch())
+            with _loop_span("eval_put"):
+                test_batch = self._shard_batch(self._test_data.full_batch())
 
         # exceptions mid-training must not leak the profiler trace or the
         # checkpoint manager (pending async saves)
@@ -533,22 +596,44 @@ class Trainer:
             if ckpt is not None:
                 stack.callback(ckpt.close)
 
-            def shard_traced(hb):
-                with trace_phase("h2d"):
-                    return self._shard_batch(hb)
+            def sliced(host_iter, ids):
+                """``(step id, host batch)``: ``batch_slice`` is the numpy
+                slice, pad and reshape of the next batch (a view on one
+                chip, a copy on a mesh), on whichever thread pulls."""
+                for n in ids:
+                    with _loop_span("batch_slice", n):
+                        hb = next(host_iter)
+                    yield n, hb
 
+            def put(item):
+                n, hb = item
+                # h2d is the host's synchronous part of device_put: the
+                # call returns with the copy still in flight, and the
+                # rest of it is waited for in the consumer's h2d_wait
+                with _loop_span("h2d", n):
+                    return hb, self._shard_batch(hb)
+
+            # an epoch's batches are counted ahead, so that no thread
+            # pulls once more to find the epoch over: every span of the
+            # loop belongs to a batch, and no step id is used twice
+            steps_per_epoch = self._train_data.num_batches(cfg.batch_size)
             for epoch in range(start_epoch, epochs):
-                host_iter = self._train_data.batches(
-                    cfg.batch_size, wrap=bool(cfg.wrap_final_batch)
+                ids = range(self.batches_taken,
+                            self.batches_taken + steps_per_epoch)
+                host_batches = sliced(
+                    self._train_data.batches(
+                        cfg.batch_size, wrap=bool(cfg.wrap_final_batch)),
+                    ids,
                 )
                 if cfg.prefetch > 1:
-                    # h2d spans land on the producer thread's timeline —
+                    # batch_slice and h2d land on the producer thread's
+                    # timeline under the step id of the batch they make —
                     # the trace shows the overlap the prefetch buys
                     pairs = _prefetch_to_device(
-                        shard_traced, host_iter, cfg.prefetch - 1
+                        put, host_batches, cfg.prefetch - 1
                     )
                 else:  # prefetch=1: the strictly-serial reference shape
-                    pairs = ((hb, shard_traced(hb)) for hb in host_iter)
+                    pairs = (put(item) for item in host_batches)
                 # closing() runs the generator's finally DETERMINISTICALLY
                 # when a step raises — relying on GC leaves the producer
                 # thread blocked on the queue for as long as the caller
@@ -557,22 +642,33 @@ class Trainer:
                 # producer on top.
                 with contextlib.closing(pairs):
                     it = iter(pairs)
-                    while True:
+                    for n in ids:
                         # data_load = time this consumer spent WAITING for
-                        # the next device-ready batch (0-ish when prefetch
-                        # keeps up; the ingest wall when it does not)
-                        with trace_phase("data_load"):
-                            pair = next(it, None)
-                        if pair is None:
-                            break
-                        host_batch, batch = pair
+                        # the next batch to be ON THE DEVICE: queue_wait
+                        # until the producer hands it over (with
+                        # prefetch=1, the slice and the dispatch
+                        # themselves), h2d_wait until its copy has landed.
+                        with _loop_span("data_load", n):
+                            with _loop_span("queue_wait", n):
+                                host_batch, batch = next(it)
+                            with _loop_span("h2d_wait", n):
+                                jax.block_until_ready(batch)
+                        self.batches_taken = n + 1
+                        # compute = dispatch of the step to its weights
+                        # being ready, with the step's own batch resident.
+                        # That is the step PLUS whatever the launch waits
+                        # for on the device's side: on the TPU runtime a
+                        # launch queues behind the copies put before it
+                        # (the batches the producer is ahead by), so with
+                        # copies in flight part of the wait for input is
+                        # still in here (PERF.md section 5)
                         self.timer.start()
-                        with trace_phase("compute"):
+                        with _loop_span("compute", n, marks_step=True):
                             self.weights, step_metrics = self.train_step(self.weights, batch)
                             jax.block_until_ready(self.weights)
                         self.timer.stop(int(host_batch[-1].sum()))
                 if test_batch is not None and cfg.test_interval > 0 and (epoch + 1) % cfg.test_interval == 0:
-                    with trace_phase("eval"):
+                    with _loop_span("eval"):
                         em = self.eval_step(self.weights, test_batch)
                         acc = float(em["accuracy"])
                     self.metrics.log(
@@ -582,7 +678,8 @@ class Trainer:
                         # epochs-to-logloss), logged at every eval
                         test_logloss=float(em["logloss"]),
                         loss=float(step_metrics["loss"]),
-                        samples_per_sec=self.timer.samples_per_sec,
+                        samples_per_sec=fit_rate(),
+                        step_samples_per_sec=self.timer.samples_per_sec,
                     )
                     if eval_fn is not None:
                         eval_fn(epoch + 1, acc)
@@ -593,7 +690,7 @@ class Trainer:
                     and cfg.checkpoint_interval > 0
                     and (epoch + 1) % cfg.checkpoint_interval == 0
                 ):
-                    with trace_phase("checkpoint"):
+                    with _loop_span("checkpoint"):
                         ckpt.save(epoch + 1, self.weights, extra={"epoch": epoch + 1})
                 # runtime introspection (obs.jaxrt): epoch-end compile-
                 # cache deltas + throttled live device-buffer gauges
@@ -602,8 +699,9 @@ class Trainer:
                 jaxrt.maybe_sample_device_bytes()
 
             if ckpt is not None and epochs > start_epoch and ckpt.latest_step() != epochs:
-                with trace_phase("checkpoint"):
+                with _loop_span("checkpoint"):
                     ckpt.save(epochs, self.weights, extra={"epoch": epochs})
+        self.fit_samples_per_sec = fit_rate()
         return self.weights
 
     def evaluate(self) -> float:

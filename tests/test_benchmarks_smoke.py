@@ -245,7 +245,8 @@ def test_bench_smoke_phase_breakdown_sums_to_wall():
     # the measured loop's spans are present with real counts
     assert phases["compute"]["count"] >= 1
     assert "warmup_compile" in phases and "data_gen" in phases
-    covered = sum(p["seconds"] for p in phases.values())
+    # self seconds, so that a phase nested in another counts once
+    covered = sum(p["self_seconds"] for p in phases.values())
     assert pb["wall_s"] > 0
     assert abs(covered / pb["wall_s"] - 1.0) <= 0.2, pb
     assert pb["coverage"] == pytest.approx(covered / pb["wall_s"], abs=1e-3)

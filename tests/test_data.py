@@ -219,7 +219,7 @@ class TestExternalA9aFormatIngestion:
 
     def test_prepare_parse_train(self, tmp_path):
         from distlr_tpu.config import Config
-        from distlr_tpu.data.libsvm import _densify, _parse_python, native_available
+        from distlr_tpu.data.libsvm import _parse_python, densify_csr, native_available
         from distlr_tpu.data.sharding import prepare_data_dir
         from distlr_tpu.train import Trainer
 
@@ -254,7 +254,7 @@ class TestExternalA9aFormatIngestion:
         X, y = parse_libsvm_file(manifest["train_parts"][0], self.D)
         assert native_available()  # this environment builds the fast path
         np.testing.assert_array_equal(y, labels_py)
-        Xp = _densify(labels_py, rp_py, cols_py, vals_py, self.D)
+        Xp = densify_csr(rp_py, cols_py, vals_py, self.D)
         np.testing.assert_array_equal(X, Xp)
         assert set(np.unique(y)) == {0, 1}  # ±1 -> 0/1 (Q7 rule)
         assert X.max() == 1.0 and X.min() == 0.0
