@@ -23,9 +23,10 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from distlr_tpu.config import Config
+from distlr_tpu.parallel import feed
 from distlr_tpu.parallel.mesh import DATA_AXIS
 
 
@@ -115,7 +116,17 @@ def shard_batch(batch, mesh: Mesh):
 
     Host->HBM streaming: the successor of the reference's per-step
     ``DataIter`` -> ``Push``/``Pull`` flow (``include/data_iter.h`` +
-    ``src/lr.cc:116-132``)."""
-    return jax.tree.map(
-        lambda x: jax.device_put(x, NamedSharding(mesh, P(DATA_AXIS))), batch
-    )
+    ``src/lr.cc:116-132``).
+
+    What a plain ``device_put`` costs, read from a trace on the v5e: the
+    runtime relays the whole array into the device's layout on its own
+    host threads, and issues the DMA only when that is done; for a dense
+    ``(rows, D)`` matrix whose rows are a multiple of 128 and whose D is
+    not, that relayout is a transpose (5-7 GB/s, in series with a 14
+    GB/s DMA).  Each leaf therefore goes through :func:`feed.place`,
+    which hands a large dense matrix over as the row-major bytes the
+    host holds and restores shape, dtype and layout on the device; every
+    other leaf is put as it always was.  Either way a leaf comes back
+    with the values, shape, dtype and ``P(data)`` sharding of a plain
+    ``device_put``."""
+    return jax.tree.map(lambda x: feed.place(x, mesh), batch)
