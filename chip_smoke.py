@@ -264,6 +264,7 @@ class Smoke:
 
         from distlr_tpu import Config
         from distlr_tpu.obs.registry import get_registry
+        from distlr_tpu.obs.tracing import get_tracer
         from distlr_tpu.train import ps_trainer
         from distlr_tpu.utils.logging import log_eval_line
 
@@ -292,12 +293,15 @@ class Smoke:
             accs.append(acc)
             log_eval_line(epoch, acc)
 
+        tracer = get_tracer()
+        wire_before = tracer.breakdown().get("wire", {"count": 0})["count"]
         before = acked_pushes()
         try:
             weights = ps_trainer.run_ps_local(cfg, eval_fn=on_eval, save=True)
         finally:
             logger.removeHandler(capture)
         pushes = acked_pushes() - before
+        spans = tracer.breakdown()
 
         pinned = [ln for ln in capture.lines if "dense steps pinned" in ln]
         _check(len(pinned) == cfg.num_workers, f"one device line per worker: {pinned}")
@@ -315,6 +319,11 @@ class Smoke:
         # every acknowledged dense push ticks the group's push clock by one
         _check(pushes >= cfg.num_workers * steps_per_worker,
                f"push clock advanced by {pushes}")
+        # minibatch workers stream: a pipelined exchange a step on the
+        # comm thread, and no shard placed ahead
+        _check(spans["wire"]["count"] - wire_before
+               == cfg.num_workers * steps_per_worker, f"wire spans {spans['wire']}")
+        _check("shard_put" not in spans, "a minibatch worker placed its shard")
         w = np.asarray(weights[0])
         _check(w.shape == (self.s.d,) and np.isfinite(w).all(), "pulled weights finite")
         _check(np.count_nonzero(w) > 0, "pulled weights non-zero")
@@ -322,6 +331,7 @@ class Smoke:
         return {
             "steps": cfg.num_workers * steps_per_worker,
             "push_clock": f"+{pushes}", "acc": f"{accs[-1]:.4f}",
+            "wire_ms": f"{1e3 * spans['wire']['seconds'] / spans['wire']['count']:.2f}",
             "step_device": json.dumps(
                 max(pinned, key=len).split("pinned: ")[1]),
         }

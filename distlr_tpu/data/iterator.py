@@ -93,6 +93,15 @@ class DataIter:
         idx, mask = self._next_idx()
         return self.X[idx], self.y[idx], mask
 
+    def whole_shard(self):
+        """``(X, y, mask)`` of the one batch an epoch has, as the arrays
+        this iterator holds and no copy of them; None where a batch is
+        anything but the whole shard in the order it is held."""
+        n = self.num_samples
+        if self.batch_size != n or not np.array_equal(self._order, np.arange(n)):
+            return None
+        return self.X, self.y, np.ones(n, dtype=bool)
+
     def __iter__(self):
         while self.has_next():
             yield self.next_batch()

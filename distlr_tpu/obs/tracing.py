@@ -26,7 +26,13 @@ duration less what its children cover, so a sum of self seconds counts
 no interval twice.  ``trace_phase(name, step=n)`` tags the span with the
 unit of work it belongs to: the producer thread's spans for batch *n*
 and the consumer's for step *n* share the id, which is how one step is
-followed across threads in the dumped trace.
+followed across threads in the dumped trace.  ``rank=r`` says whose span
+it is where several workers are threads of one process (the PS plane's
+Hogwild workers and their comm threads): a label on the event, so the
+breakdown and ``distlr_phase_seconds{phase}`` keep one row a phase.
+
+:func:`loop_span` is the form the training loops use: the same span
+also written into a ``jax.profiler`` trace while one is taken.
 """
 
 from __future__ import annotations
@@ -55,7 +61,7 @@ class PhaseTracer:
         self._registry = registry or get_registry()
         self._max_events = max_events
         self._lock = threading.Lock()
-        # (name, tid, start, duration, span id, parent id, step)
+        # (name, tid, start, duration, span id, parent id, step, rank)
         self._events: list[tuple] = []
         self._dropped = 0
         self._totals: dict[str, list] = {}  # phase -> [seconds, count, self]
@@ -71,7 +77,8 @@ class PhaseTracer:
         )
 
     @contextlib.contextmanager
-    def phase(self, name: str, step: int | None = None):
+    def phase(self, name: str, step: int | None = None,
+              rank: int | None = None):
         try:
             stack = self._open.stack
         except AttributeError:
@@ -102,7 +109,7 @@ class PhaseTracer:
                 if len(self._events) < self._max_events:
                     self._events.append(
                         (name, tid, t0 - self._epoch, dur, frame[0], parent,
-                         step))
+                         step, rank))
                 else:
                     self._dropped += 1
 
@@ -133,18 +140,20 @@ class PhaseTracer:
         """Trace-event JSON object (``ph: "X"`` complete events, us
         timestamps) — loadable in Perfetto / chrome://tracing.  Each
         event's ``args`` hold its span ``id`` and, where it has them, its
-        ``parent`` span's id and its ``step``."""
+        ``parent`` span's id, its ``step`` and its ``rank``."""
         pid = os.getpid()
         with self._lock:
             recorded = list(self._events)
             dropped = self._dropped
         events = []
-        for name, tid, t0, dur, span_id, parent, step in recorded:
+        for name, tid, t0, dur, span_id, parent, step, rank in recorded:
             args = {"id": span_id}
             if parent is not None:
                 args["parent"] = parent
             if step is not None:
                 args["step"] = step
+            if rank is not None:
+                args["rank"] = rank
             events.append({
                 "name": name,
                 "cat": "phase",
@@ -181,6 +190,32 @@ def get_tracer() -> PhaseTracer:
     return _TRACER
 
 
-def trace_phase(name: str, step: int | None = None):
+def trace_phase(name: str, step: int | None = None,
+                rank: int | None = None):
     """``with trace_phase("compute", step=n): ...`` on the default tracer."""
-    return _TRACER.phase(name, step)
+    return _TRACER.phase(name, step, rank)
+
+
+@contextlib.contextmanager
+def loop_span(name: str, step: int | None = None, *,
+              rank: int | None = None, marks_step: bool = False):
+    """One span of a training loop, on both records: the process's
+    ``PhaseTracer`` and, while a ``jax.profiler`` trace is being taken
+    (``cfg.profile_dir``, or a caller's own ``jax.profiler.trace``), the
+    host lines of the same ``.xplane.pb`` as the device operations, so
+    that the two share a clock.  An annotation records nothing while no
+    trace is open.  ``marks_step`` makes it the step marker the
+    profiler's tools group device work by.  JAX is imported here, by the
+    loops that have it already, and not with this module."""
+    import jax  # noqa: PLC0415
+
+    stats = {k: v for k, v in (("step", step), ("rank", rank))
+             if v is not None}
+    if marks_step:
+        stats.pop("step", None)
+        annotation = jax.profiler.StepTraceAnnotation(
+            name, step_num=step, **stats)
+    else:
+        annotation = jax.profiler.TraceAnnotation(name, **stats)
+    with trace_phase(name, step, rank), annotation:
+        yield

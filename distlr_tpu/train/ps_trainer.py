@@ -43,7 +43,9 @@ from distlr_tpu.data.sharding import part_name
 from distlr_tpu.models import get_model
 from distlr_tpu.obs import dtrace, jaxrt
 from distlr_tpu.obs.registry import COUNT_BUCKETS, get_registry
-from distlr_tpu.obs.tracing import trace_phase
+from distlr_tpu.obs.tracing import loop_span
+from distlr_tpu.parallel import feed
+from distlr_tpu.parallel.mesh import make_mesh
 from distlr_tpu.ps import KVWorker, RetryPolicy, ServerGroup
 from distlr_tpu.train.export import save_model_text
 from distlr_tpu.train.metrics import MetricsLogger, StepTimer
@@ -101,6 +103,16 @@ _ACCUM_K = get_registry().gauge(
     "distlr_train_accum_batches",
     "current AdaBatch accumulation span of the PS worker loop "
     "(batches per push)",
+    labelnames=("rank",),
+)
+
+
+#: What a whole-shard dense worker keeps on its step's device (features,
+#: labels, mask), placed once by :meth:`PSWorker.load_data`; a streaming
+#: worker's series stays absent.
+_RESIDENT_BYTES = get_registry().gauge(
+    "distlr_ps_resident_bytes",
+    "bytes of a PS worker's whole-shard batch held on its step's device",
     labelnames=("rank",),
 )
 
@@ -296,7 +308,13 @@ def _compiled_fns(model, l2_c: float, l2_scale_by_batch: bool):
     a model that grows a new cfg dependency fails loudly here with
     AttributeError."""
     gcfg = types.SimpleNamespace(l2_c=l2_c, l2_scale_by_batch=l2_scale_by_batch)
-    return jax.jit(lambda w, X, y, mask: model.grad(w, (X, y, mask), gcfg))
+
+    # a name of its own: a trace shows the program as ``jit_ps_grad_step``,
+    # which no other jitted function of the process shares
+    def ps_grad_step(w, X, y, mask):
+        return model.grad(w, (X, y, mask), gcfg)
+
+    return jax.jit(ps_grad_step)
 
 
 @functools.lru_cache(maxsize=None)
@@ -494,6 +512,35 @@ class PSWorker:
     exercises — its key set is always dense 0..D-1, ``src/lr.cc:117-121``):
     each batch pulls and pushes only its unique touched columns, so a
     D=1M-bucket CTR model ships KBs per step instead of 12 MB.
+
+    Where the data lives.  A dense worker whose batch is its whole shard
+    (the reference's ``BATCH_SIZE=-1``: the same rows every iteration)
+    and whose step runs on a jax device keeps that batch **resident**:
+    :meth:`load_data` places ``X``, ``y`` and ``mask`` on the step's
+    device once (``shard_put``, through ``parallel.feed.place``) and
+    every round computes on those arrays; a round then moves only the
+    weights in and the gradient out.  It is chosen from what the worker
+    sees (one batch an epoch that is the shard; a step device other than
+    ``"numpy"``).  Minibatch workers, and every keyed model, stream numpy
+    batches from host RAM, one ``device_put`` a step.
+
+    ``run()`` is ``load_data()`` (iterators, the device choice, the
+    placement; once), ``start()`` (seed push, start barrier), ``fit()``
+    (the epochs) and ``finish()`` (final pull, export, exit barrier,
+    retiring the group).  ``fit(epochs=E)`` can be called again on the
+    same worker: it loads, places and compiles nothing.
+
+    Spans (``obs.tracing.loop_span``: ``PhaseTracer`` and, while a
+    profiler trace is taken, a ``TraceAnnotation``) carry ``step`` = the
+    worker's round count (:attr:`rounds`) and ``rank``: ``load_data`` and
+    ``shard_put`` once; a round: ``data_load`` (fetching the batch: the
+    numpy slice, nothing for a resident shard), ``h2d`` (a streamed
+    batch's put, where the step's device is named), ``w_put`` (weights to
+    the device), ``compute`` (dispatch to the gradient ready on the
+    device), ``grad_d2h`` (readback), ``push`` (the loop blocked on its
+    exchange), ``pull``; ``wire`` on the comm thread (a pipelined fused
+    push-pull, send to reply, with the step that submitted it);
+    ``barrier_wait``, ``eval``, ``checkpoint``.
     """
 
     def __init__(self, cfg: Config, rank: int, hosts: str, *, train_iter=None, test_iter=None):
@@ -501,10 +548,11 @@ class PSWorker:
         self.rank = rank
         self.model = get_model(cfg)
         if cfg.feature_dtype != "float32":
-            # PS workers stream numpy batches from host RAM per step —
-            # there is no resident device feature matrix whose HBM
-            # footprint quantization would shrink. Reject rather than
-            # silently ignore the documented +11%/2x expectation.
+            # PS workers stream float32 numpy batches from host RAM per
+            # step, and a whole-shard worker's resident batch is that
+            # same float32 array placed once: no quantized path exists
+            # on this plane. Reject rather than silently ignore the
+            # documented +11%/2x expectation.
             raise ValueError(
                 "feature_dtype quantization applies to the sync SPMD "
                 "trainer's device-resident features; PS mode streams "
@@ -571,6 +619,19 @@ class PSWorker:
         self.final_weights: np.ndarray | None = None
         self._barrier_base = 0
         self._sidecar_attempt = 0
+        self._starts = 0
+        #: batches this worker has taken: the ``step`` of its spans
+        self.rounds = 0
+        #: epochs finished; ``fit`` goes on from here
+        self.epochs_done = 0
+        # bound once by load_data()
+        self._train = self._test = None
+        self._eval_dev = None
+        self._resident = None  # (X, y, mask) on the step's device
+        self._resident_rows = 0
+        #: dense models: ``(flat weights, batch) -> flat float32
+        #: gradient`` on the device load_data() picked
+        self.grad_step = None
         # pipelined dense path state: last fused-reply weights, and a
         # single comm thread (KV ops must never overlap on one connection)
         self._w_cache: np.ndarray | None = None
@@ -693,19 +754,119 @@ class PSWorker:
         return DataIter.from_file(path, self.cfg.num_feature_dim, -1,
                                   multiclass=self.cfg.model == "softmax")
 
-    def run(self, *, eval_fn=None, save=True, resume=False,
-            rejoin=False) -> np.ndarray:
-        cfg = self.cfg
-        train = self._train_iter if self._train_iter is not None else self._load_train_iter()
-        test = self._test_iter if self._test_iter is not None else (
-            self._load_test_iter() if self.rank == 0 else None
-        )
+    def _span(self, name: str, *, marks_step: bool = False):
+        """A span of this worker's loop: its round count and its rank."""
+        return loop_span(name, self.rounds, rank=self.rank,
+                         marks_step=marks_step)
 
+    def load_data(self) -> None:
+        """Once a worker: bind the iterators (parsing the shards where
+        none were handed in), pick the device of the dense step and of
+        the eval, and place a whole-shard batch there (class docstring).
+        A second call does nothing."""
+        if self._train is not None:
+            return
+        with self._span("load_data"):
+            train = (self._train_iter if self._train_iter is not None
+                     else self._load_train_iter())
+            test = self._test_iter if self._test_iter is not None else (
+                self._load_test_iter() if self.rank == 0 else None)
+            if self._grad_fn is None:
+                log.info("rank %d %s steps and eval run in numpy on the "
+                         "host (keyed models never use the accelerator)",
+                         self.rank, self.cfg.model)
+            else:
+                self._bind_dense_step(train, test)
+        self._train, self._test = train, test
+
+    def _bind_dense_step(self, train, test) -> None:
+        cfg = self.cfg
+        # Committed inputs pin each jitted step to its device; jax.jit
+        # keys its executable cache on input placement, so both
+        # backends can coexist in one process.  Train and eval steps
+        # size their choice independently (a tiny minibatch must not
+        # drag a huge full-test-set eval onto the host CPU).
+        train_rows = cfg.batch_size if cfg.batch_size > 0 else train.num_samples
+        step_dev = ps_compute_device(cfg, train_rows)
+        self._eval_dev = (ps_compute_device(cfg, test.num_samples)
+                          if test is not None else None)
+        log.info(
+            "rank %d dense steps pinned: train -> %s%s (ps_compute_backend=%s)",
+            self.rank, _describe_compute_device(step_dev),
+            "" if test is None
+            else f", eval -> {_describe_compute_device(self._eval_dev)}",
+            cfg.ps_compute_backend)
+        K = cfg.num_classes if cfg.model == "softmax" else None
+        if step_dev == "numpy":
+            def grad_step(wf, batch):
+                W = wf.reshape(cfg.num_feature_dim, K) if K else wf
+                with self._span("compute", marks_step=True):
+                    return _np_dense_grad(
+                        W, *batch, cfg.l2_c, bool(cfg.l2_scale_by_batch), K
+                    ).reshape(-1)
+        else:
+            self._resident = self._place_shard(train, step_dev)
+
+            def grad_step(wf, batch):
+                if batch is not self._resident:
+                    with self._span("h2d"):
+                        batch = self._place(step_dev, *batch)
+                with self._span("w_put"):
+                    w = jax.block_until_ready(
+                        jax.device_put(self._shape_params(wf), step_dev))
+                with self._span("compute", marks_step=True):
+                    g = jax.block_until_ready(self._grad_fn(w, *batch))
+                with self._span("grad_d2h"):
+                    # one copy, device to host; the reshape is a view and
+                    # the client sends from this buffer
+                    return np.asarray(g).reshape(-1)
+        self.grad_step = grad_step
+
+    def _place_shard(self, train, step_dev):
+        """``(X, y, mask)`` of a whole-shard batch on the step's device,
+        or None where the worker streams: every epoch of such an iterator
+        yields these same rows, so they cross to the device once.  Each
+        leaf goes through ``feed.place``, which picks the layout it is
+        handed over in and counts it in ``distlr_h2d_bytes_total``."""
+        if train.num_batches != 1 or train.batch_size != train.num_samples:
+            return None
+        # the arrays the iterator holds where the batch is just those (no
+        # 1.5 GB gather of every row in turn, as ``next_batch`` makes)
+        batch = train.whole_shard()
+        if batch is None:
+            train.reset()
+            batch = train.next_batch()
+        mesh = make_mesh(devices=[step_dev or jax.devices()[0]])
+        with self._span("shard_put"):
+            placed = jax.block_until_ready(
+                tuple(feed.place(a, mesh) for a in batch))
+        self._resident_rows = int(batch[-1].sum())
+        _RESIDENT_BYTES.labels(rank=str(self.rank)).set(
+            sum(a.nbytes for a in batch))
+        return placed
+
+    def _batches(self, train):
+        """An epoch's dense batches, each beside its count of real rows;
+        ``data_load`` is what fetching one cost the loop: the numpy slice
+        of a streamed batch, nothing for a resident shard."""
+        resident = self._resident
+        for _ in range(train.num_batches):
+            self.rounds += 1
+            with self._span("data_load"):
+                batch = resident if resident is not None else train.next_batch()
+            yield batch, (self._resident_rows if resident is not None
+                          else int(batch[-1].sum()))
+
+    def start(self, *, resume=False, rejoin=False) -> None:
+        """Seed the group (rank 0) and meet the peers at the start
+        barrier; on ``resume``, from the checkpoint's epoch."""
+        cfg = self.cfg
         start_epoch = 0
         restored = None
         attempt = None
         if resume and cfg.checkpoint_dir:
             start_epoch, restored, attempt = _ps_resume_state(cfg, self.rank)
+        self.epochs_done = max(self.epochs_done, start_epoch)
 
         # Identical deterministic init on every worker (Q2); only rank 0
         # pushes — via the IDEMPOTENT init op, so a restarted rank 0
@@ -723,10 +884,11 @@ class PSWorker:
         # lands.  All ranks read the same sidecar, so they agree; late
         # re-votes of a released generation (worker rejoin) still return
         # immediately, so a restarted worker neither hangs nor pairs
-        # with peers' exit votes.
-        w0 = (restored if restored is not None
-              else np.asarray(self.model.init(cfg)).reshape(-1))
+        # with peers' exit votes.  A worker started again (a second
+        # ``start`` on this object) takes the next pair by the same rule.
         if self.rank == 0:
+            w0 = (restored if restored is not None
+                  else np.asarray(self.model.init(cfg)).reshape(-1))
             # force on resume: against a SURVIVING (already-initialized)
             # server group the restored checkpoint — or, when the crash
             # predated the first checkpoint, the fresh epoch-0 init —
@@ -735,12 +897,20 @@ class PSWorker:
             # wrong state.  A restarted worker (rejoin) must NOT force:
             # it would roll peers back mid-run.
             force = resume and not rejoin
-            with trace_phase("push"):
+            with self._span("push"):
                 self.kv.wait(self.kv.push_init(w0, force=force))
-        self._barrier_base = 0 if attempt is None else 2 * (attempt + 1)
+        self._barrier_base = ((0 if attempt is None else 2 * (attempt + 1))
+                              + 2 * self._starts)
+        self._starts += 1
         self._sidecar_attempt = 0 if attempt is None else attempt
-        with trace_phase("barrier_wait"):
+        with self._span("barrier_wait"):
             self.kv.barrier(self._barrier_base)
+
+    def run(self, *, eval_fn=None, save=True, resume=False,
+            rejoin=False) -> np.ndarray:
+        cfg = self.cfg
+        self.load_data()
+        self.start(resume=resume, rejoin=rejoin)
 
         ckpt = None
         if self.rank == 0 and cfg.checkpoint_dir:
@@ -755,10 +925,8 @@ class PSWorker:
                 stack.enter_context(jax.profiler.trace(cfg.profile_dir))
             if ckpt is not None:
                 stack.callback(ckpt.close)
-            return self._run_epochs(
-                start_epoch, w0, train, test, ckpt,
-                eval_fn=eval_fn, save=save,
-            )
+            self.fit(eval_fn=eval_fn, ckpt=ckpt)
+            return self.finish(save=save)
 
     def _checkpoint(self, ckpt, epoch: int) -> None:
         """Rank 0: snapshot the servers' weights + the epoch sidecar
@@ -784,7 +952,7 @@ class PSWorker:
         rows, vals = res
         if rows.size == 0 and not self.cfg.sync_mode:
             return
-        with trace_phase("push"):
+        with self._span("push"):
             self.kv.wait(self.kv.push(vals, keys=rows, vals_per_key=vpk))
 
     def _flush_dense_accum(self, accum: GradientAccumulator) -> None:
@@ -796,11 +964,17 @@ class PSWorker:
             _STALENESS.labels(rank=self.rank).set(
                 time.perf_counter() - self._w_time)
             self._record_pushes_behind(self._w_pushes)
-        with trace_phase("push"):
+        with self._span("push"):
             self.kv.wait(self.kv.push(g))
 
-    def _run_epochs(self, start_epoch, w0, train, test, ckpt, *, eval_fn, save):
+    def fit(self, epochs: int | None = None, *, eval_fn=None,
+            ckpt=None) -> None:
+        """Run ``epochs`` more epochs (default: what is left of
+        ``cfg.num_iteration``) from :attr:`epochs_done`, against a group
+        :meth:`start` has seeded.  Leaves no exchange in flight."""
         cfg = self.cfg
+        self.load_data()
+        train, test = self._train, self._test
 
         # AdaBatch local accumulation (--accum-start/--accum-max): push
         # the span's MEAN every k batches, k growing on the schedule —
@@ -824,38 +998,10 @@ class PSWorker:
         row_width = (cfg.block_size if blocked
                      else cfg.num_classes if cfg.model == "sparse_softmax"
                      else 1)
-        if not (sparse or blocked):
-            # Committed inputs pin each jitted step to its device; jax.jit
-            # keys its executable cache on input placement, so both
-            # backends can coexist in one process.  Train and eval steps
-            # size their choice independently (a tiny minibatch must not
-            # drag a huge full-test-set eval onto the host CPU).
-            train_rows = cfg.batch_size if cfg.batch_size > 0 else train.num_samples
-            step_dev = ps_compute_device(cfg, train_rows)
-            eval_dev = ps_compute_device(cfg, test.num_samples) if test is not None else None
-            log.info(
-                "rank %d dense steps pinned: train -> %s%s (ps_compute_backend=%s)",
-                self.rank, _describe_compute_device(step_dev),
-                "" if test is None
-                else f", eval -> {_describe_compute_device(eval_dev)}",
-                cfg.ps_compute_backend)
-            K = cfg.num_classes if cfg.model == "softmax" else None
-            if step_dev == "numpy":
-                def compute_g(wf, X, y, mask):
-                    W = wf.reshape(cfg.num_feature_dim, K) if K else wf
-                    return _np_dense_grad(
-                        W, X, y, mask, cfg.l2_c, bool(cfg.l2_scale_by_batch), K
-                    ).reshape(-1)
-            else:
-                def compute_g(wf, X, y, mask):
-                    return np.asarray(self._grad_fn(*self._place(
-                        step_dev, self._shape_params(wf), X, y, mask))).reshape(-1)
-        else:
-            log.info("rank %d %s steps and eval run in numpy on the host "
-                     "(keyed models never use the accelerator)",
-                     self.rank, cfg.model)
-        w = w0
-        for epoch in range(start_epoch, cfg.num_iteration):
+        compute_g = self.grad_step
+        first = self.epochs_done
+        last = cfg.num_iteration if epochs is None else first + epochs
+        for epoch in range(first, last):
             train.reset()
             if sparse or blocked:
                 # Keyed Push/Pull: only the batch's unique touched columns
@@ -873,7 +1019,7 @@ class PSWorker:
                        if row_width > 1 and self.kv.supports_vals_per_key(
                            row_width)
                        else 1)
-                if row_width > 1 and epoch == start_epoch:
+                if row_width > 1 and epoch == first:
                     # visible (and test-assertable) record of which wire
                     # encoding the keyed rounds actually used
                     log.info(
@@ -919,14 +1065,15 @@ class PSWorker:
                 # dense path, there is no fused op here to REMOVE a round
                 # trip (pull and push key sets differ per batch).
                 for b in train:
+                    self.rounds += 1
                     self.timer.start()
-                    with trace_phase("data_load"):
+                    with self._span("data_load"):
                         keys, rest = prep(b)
                     t_pull = time.perf_counter()
-                    with trace_phase("pull"):
+                    with self._span("pull"):
                         w_u = self.kv.pull(keys=keys, vals_per_key=vpk)
                     p0 = None if cfg.sync_mode else self._sample_push_clock()
-                    with trace_phase("compute"):
+                    with self._span("compute", marks_step=True):
                         g = kgrad(w_u, rest)
                     if not cfg.sync_mode:
                         _STALENESS.labels(rank=self.rank).set(
@@ -944,7 +1091,7 @@ class PSWorker:
                         if accum.ready:
                             self._flush_keyed_accum(accum, vpk)
                     else:
-                        with trace_phase("push"):
+                        with self._span("push"):
                             self.kv.wait(self.kv.push(g, keys=keys,
                                                       vals_per_key=vpk))
                     self.timer.stop(int(b[-1].sum()))
@@ -959,54 +1106,50 @@ class PSWorker:
                 # bypassed: the span already removes k-1 of every k
                 # round trips, which is the same wall-clock win
                 # pipelining buys, without overlapping state.
-                for X, y, mask in train:
+                for batch, n_real in self._batches(train):
                     self.timer.start()
                     if accum.batches == 0:
-                        with trace_phase("pull"):
+                        with self._span("pull"):
                             self._w_cache = self.kv.pull()
                         self._w_time = time.perf_counter()
                         self._w_pushes = (None if cfg.sync_mode
                                           else self._sample_push_clock())
-                    with trace_phase("compute"):
-                        g = compute_g(self._w_cache, X, y, mask)
-                    accum.add(g)
+                    accum.add(compute_g(self._w_cache, batch))
                     if accum.ready:
                         self._flush_dense_accum(accum)
-                    self.timer.stop(int(mask.sum()))
+                    self.timer.stop(n_real)
                 self._flush_dense_accum(accum)
             elif not cfg.ps_pipeline:
                 # Reference-faithful serialized protocol: two blocking
                 # round trips per batch (src/lr.cc:116-132).
-                for X, y, mask in train:
+                for batch, n_real in self._batches(train):
                     self.timer.start()
                     t_pull = time.perf_counter()
-                    with trace_phase("pull"):
+                    with self._span("pull"):
                         w = self.kv.pull()
                     p0 = None if cfg.sync_mode else self._sample_push_clock()
-                    with trace_phase("compute"):
-                        g = compute_g(w, X, y, mask)
+                    g = compute_g(w, batch)
                     if not cfg.sync_mode:
                         _STALENESS.labels(rank=self.rank).set(
                             time.perf_counter() - t_pull)
                         self._record_pushes_behind(p0)
-                    with trace_phase("push"):
+                    with self._span("push"):
                         self.kv.wait(self.kv.push(g))
-                    self.timer.stop(int(mask.sum()))
+                    self.timer.stop(n_real)
             elif cfg.sync_mode:
                 # Fused BSP: ONE deferred round trip per batch; the reply
                 # is the post-round weights = what the next pull would
                 # return (rounds totally ordered -> bit-identical
                 # trajectory, pinned by the oracle parity tests).
                 if self._w_cache is None:
-                    with trace_phase("pull"):
+                    with self._span("pull"):
                         self._w_cache = self.kv.pull()
-                for X, y, mask in train:
+                for batch, n_real in self._batches(train):
                     self.timer.start()
-                    with trace_phase("compute"):
-                        g = compute_g(self._w_cache, X, y, mask)
-                    with trace_phase("push"):
+                    g = compute_g(self._w_cache, batch)
+                    with self._span("push"):
                         self._w_cache = self.kv.push_pull(g)
-                    self.timer.stop(int(mask.sum()))
+                    self.timer.stop(n_real)
             else:
                 # Pipelined async (Hogwild): fused round trips double-
                 # buffered against compute — batch k+1's gradient is
@@ -1015,15 +1158,14 @@ class PSWorker:
                 # push; KV ops stay serialized on the comm thread (one
                 # connection, never two ops concurrently).
                 if self._w_cache is None:
-                    with trace_phase("pull"):
+                    with self._span("pull"):
                         self._w_cache = self.kv.pull()
                     self._w_time = time.perf_counter()
                     self._w_pushes = self._sample_push_clock()
                 fut = None
-                for X, y, mask in train:
+                for batch, n_real in self._batches(train):
                     self.timer.start()
-                    with trace_phase("compute"):
-                        g = compute_g(self._w_cache, X, y, mask)
+                    g = compute_g(self._w_cache, batch)
                     # g rides weights pulled at _w_time; its round trip
                     # starts now — the age at landing is ~this (+ one
                     # in-flight RTT, bounded by the next result() wait)
@@ -1035,18 +1177,20 @@ class PSWorker:
                     # behind the weights under this gradient are
                     self._record_pushes_behind(self._w_pushes)
                     if fut is not None:
-                        with trace_phase("push"):
+                        with self._span("push"):
                             self._w_cache = fut.result()
                         self._w_time = time.perf_counter()
                         self._w_pushes = self._sample_push_clock()
-                    # the step's dtrace context rides along explicitly:
-                    # the comm thread is a different thread, and the
-                    # fused op belongs to the step that SUBMITTED it
+                    # the step's dtrace context and its round count ride
+                    # along explicitly: the comm thread is a different
+                    # thread, and the fused op belongs to the step that
+                    # SUBMITTED it
                     fut = self._comm_pool().submit(
-                        self._traced_push_pull, g, dtrace.current())
-                    self.timer.stop(int(mask.sum()))
+                        self._traced_push_pull, g, dtrace.current(),
+                        self.rounds)
+                    self.timer.stop(n_real)
                 if fut is not None:
-                    with trace_phase("push"):
+                    with self._span("push"):
                         self._w_cache = fut.result()
                     self._w_time = time.perf_counter()
                     self._w_pushes = self._sample_push_clock()
@@ -1062,24 +1206,8 @@ class PSWorker:
                 and cfg.test_interval > 0
                 and (epoch + 1) % cfg.test_interval == 0
             ):
-                with trace_phase("eval"):
-                    if cfg.model == "sparse_softmax":
-                        acc, test_ll = self._sparse_softmax_eval(test)
-                    elif sparse:
-                        acc, test_ll = self._sparse_eval(test)
-                    elif blocked:
-                        acc, test_ll = self._blocked_eval(test)
-                    else:
-                        w = self.kv.pull()
-                        test.reset()
-                        Xt, yt, mt = test.next_batch()
-                        if eval_dev == "numpy":
-                            acc, test_ll = _np_dense_eval(
-                                w.reshape(cfg.num_feature_dim, K) if K else w,
-                                Xt, yt, mt.astype(np.float32), K)
-                        else:
-                            a, ll = self._acc_fn(*self._place(eval_dev, self._shape_params(w), Xt, yt, mt))
-                            acc, test_ll = float(a), float(ll)
+                with self._span("eval"):
+                    acc, test_ll = self.evaluate()
                 self.metrics.log(epoch=epoch + 1, accuracy=acc,
                                  test_logloss=test_ll,
                                  samples_per_sec=self.timer.samples_per_sec)
@@ -1092,18 +1220,44 @@ class PSWorker:
                 and cfg.checkpoint_interval > 0
                 and (epoch + 1) % cfg.checkpoint_interval == 0
             ):
-                with trace_phase("checkpoint"):
+                with self._span("checkpoint"):
                     self._checkpoint(ckpt, epoch + 1)
 
-        if (
-            ckpt is not None
-            and cfg.num_iteration > start_epoch
-            and ckpt.latest_step() != cfg.num_iteration
-        ):
-            with trace_phase("checkpoint"):
-                self._checkpoint(ckpt, cfg.num_iteration)
+            self.epochs_done = epoch + 1
 
-        with trace_phase("pull"):
+        if ckpt is not None and last > first and ckpt.latest_step() != last:
+            with self._span("checkpoint"):
+                self._checkpoint(ckpt, last)
+
+    def evaluate(self, w: np.ndarray | None = None) -> tuple[float, float]:
+        """``(accuracy, logloss)`` on the test split: of what the servers
+        hold now or, for a dense model, of the flat weights ``w``.  The
+        eval the epoch loop runs on rank 0."""
+        cfg, test = self.cfg, self._test
+        if cfg.model == "sparse_softmax":
+            return self._sparse_softmax_eval(test)
+        if cfg.model == "sparse_lr":
+            return self._sparse_eval(test)
+        if cfg.model == "blocked_lr":
+            return self._blocked_eval(test)
+        if w is None:
+            w = self.kv.pull()
+        K = cfg.num_classes if cfg.model == "softmax" else None
+        test.reset()
+        Xt, yt, mt = test.next_batch()
+        if self._eval_dev == "numpy":
+            return _np_dense_eval(
+                w.reshape(cfg.num_feature_dim, K) if K else w,
+                Xt, yt, mt.astype(np.float32), K)
+        a, ll = self._acc_fn(*self._place(
+            self._eval_dev, self._shape_params(w), Xt, yt, mt))
+        return float(a), float(ll)
+
+    def finish(self, *, save=True) -> np.ndarray:
+        """Pull the final weights, export them, meet the peers at the
+        exit barrier and (rank 0) retire the group."""
+        cfg = self.cfg
+        with self._span("pull"):
             self.final_weights = self.kv.pull()
         if save:
             path = os.path.join(cfg.data_dir, "models", part_name(self.rank))
@@ -1115,7 +1269,7 @@ class PSWorker:
         # group — this is what lets foreground `launch ps-server` hosts
         # exit when training is done (local mode: ServerGroup.stop()
         # finds the procs exited).
-        with trace_phase("barrier_wait"):
+        with self._span("barrier_wait"):
             self.kv.barrier(self._barrier_base + 1)
         if self.rank == 0:
             self.kv.shutdown_servers()
@@ -1199,11 +1353,13 @@ class PSWorker:
             )
         return self._comm
 
-    def _traced_push_pull(self, g, ctx):
+    def _traced_push_pull(self, g, ctx, step):
         """Comm-thread half of the pipelined fused op: re-install the
         submitting step's distributed-trace context (thread-local, so it
-        doesn't cross the executor by itself) before issuing."""
-        with dtrace.use(ctx):
+        doesn't cross the executor by itself) before issuing.  ``wire``
+        is the exchange itself, send to reply, under the submitter's
+        step."""
+        with dtrace.use(ctx), loop_span("wire", step, rank=self.rank):
             return self.kv.push_pull(g)
 
     def close(self, *, wait: bool = True):
@@ -1330,21 +1486,11 @@ def ps_param_dim(cfg: Config) -> int:
         cfg.num_classes if cfg.model in ("softmax", "sparse_softmax") else 1)
 
 
-def run_ps_local(cfg: Config, *, eval_fn=None, save=False, resume=False,
-                 max_restarts=0, supervise_servers=False):
-    """Single-host PS run: native server subprocesses + threaded workers.
-
-    The local-mode successor of ``examples/local.sh`` for the PS path
-    (the scheduler role is gone — rendezvous is just TCP connect).
-    Multi-host deployments start servers with ``launch ps-server`` and
-    per-host workers with :func:`run_ps_workers` instead.
-
-    ``supervise_servers`` (async mode only) attaches a
-    :class:`distlr_tpu.ps.ServerSupervisor`: dead server ranks are
-    respawned and re-seeded from a rolling snapshot, completing the
-    two-sided §5.3 recovery story (pair it with ``max_restarts > 0`` so
-    workers whose stream broke rejoin).
-    """
+def server_group(cfg: Config) -> ServerGroup:
+    """The local native server group a config asks for, not yet started
+    (``with server_group(cfg) as group``): what :func:`run_ps_local`
+    spawns, for callers that drive their own :class:`PSWorker` threads
+    against it."""
     via_chaos = None
     if cfg.chaos_plan:
         from distlr_tpu.chaos import load_plan  # noqa: PLC0415
@@ -1352,7 +1498,7 @@ def run_ps_local(cfg: Config, *, eval_fn=None, save=False, resume=False,
         # parsed HERE, before any server spawns: a malformed plan must
         # fail the launch, not leak a fault-free run that looks chaotic
         via_chaos = load_plan(cfg.chaos_plan, seed=cfg.chaos_seed)
-    group = ServerGroup(
+    return ServerGroup(
         cfg.num_servers,
         cfg.num_workers,
         ps_param_dim(cfg),
@@ -1386,6 +1532,24 @@ def run_ps_local(cfg: Config, *, eval_fn=None, save=False, resume=False,
         store_wal=cfg.ps_store_wal,
         store_wal_fsync_s=cfg.ps_store_wal_fsync_s,
     )
+
+
+def run_ps_local(cfg: Config, *, eval_fn=None, save=False, resume=False,
+                 max_restarts=0, supervise_servers=False):
+    """Single-host PS run: native server subprocesses + threaded workers.
+
+    The local-mode successor of ``examples/local.sh`` for the PS path
+    (the scheduler role is gone — rendezvous is just TCP connect).
+    Multi-host deployments start servers with ``launch ps-server`` and
+    per-host workers with :func:`run_ps_workers` instead.
+
+    ``supervise_servers`` (async mode only) attaches a
+    :class:`distlr_tpu.ps.ServerSupervisor`: dead server ranks are
+    respawned and re-seeded from a rolling snapshot, completing the
+    two-sided §5.3 recovery story (pair it with ``max_restarts > 0`` so
+    workers whose stream broke rejoin).
+    """
+    group = server_group(cfg)
     with contextlib.ExitStack() as stack:
         stack.enter_context(group)
         if supervise_servers:

@@ -34,7 +34,7 @@ from distlr_tpu.data.libsvm import densify_csr
 from distlr_tpu.data.sharding import part_name
 from distlr_tpu.models import get_model
 from distlr_tpu.obs import jaxrt
-from distlr_tpu.obs.tracing import trace_phase
+from distlr_tpu.obs.tracing import loop_span as _loop_span, trace_phase
 from distlr_tpu.parallel import (
     make_eval_step,
     make_mesh,
@@ -47,24 +47,6 @@ from distlr_tpu.train.metrics import MetricsLogger, StepTimer
 from distlr_tpu.utils.logging import get_logger, log_eval_line
 
 log = get_logger(__name__)
-
-
-@contextlib.contextmanager
-def _loop_span(name: str, step: int | None = None, *, marks_step: bool = False):
-    """One span of the training loop, on both records: the process's
-    ``PhaseTracer`` and, while a ``jax.profiler`` trace is being taken
-    (``cfg.profile_dir``, or a caller's own ``jax.profiler.trace``), the
-    host lines of the same ``.xplane.pb`` as the device operations, so
-    that the two share a clock.  An annotation records nothing while no
-    trace is open.  ``marks_step`` makes it the step marker the
-    profiler's tools group device work by."""
-    if marks_step:
-        annotation = jax.profiler.StepTraceAnnotation(name, step_num=step)
-    else:
-        stats = {} if step is None else {"step": step}
-        annotation = jax.profiler.TraceAnnotation(name, **stats)
-    with trace_phase(name, step), annotation:
-        yield
 
 
 class GlobalShardedData:

@@ -1,0 +1,205 @@
+"""A dense PS worker whose batch is its whole shard keeps it on the
+step's device: placed once, the same gradients as a streamed batch bit
+for bit, and a second ``fit`` that loads, places and compiles nothing.
+Minibatch workers stream as before."""
+
+import threading
+
+import numpy as np
+import pytest
+
+from distlr_tpu.config import Config
+from distlr_tpu.data.synthetic import write_synthetic_shards
+from distlr_tpu.obs.registry import family_total, get_registry
+from distlr_tpu.ps import ServerGroup
+from distlr_tpu.train.ps_trainer import PSWorker, ps_param_dim
+
+DIM, CLASSES = 24, 3
+H2D = "distlr_h2d_bytes_total"
+
+
+def _job(tmp_path, model, num_workers, **kw):
+    d = str(tmp_path / f"{model}-{num_workers}")
+    write_synthetic_shards(d, 100 * num_workers, DIM, num_parts=num_workers,
+                           seed=5, sparsity=0.0,
+                           num_classes=CLASSES if model == "softmax" else 2)
+    base = dict(
+        data_dir=d, num_feature_dim=DIM, model=model,
+        num_classes=CLASSES if model == "softmax" else 2,
+        num_workers=num_workers, num_servers=2, sync_mode=False,
+        batch_size=-1, num_iteration=5, learning_rate=0.2, l2_c=0.0,
+        test_interval=0,
+        # the jitted step on the default backend: "auto" would take these
+        # tiny steps to numpy, where nothing is placed
+        ps_compute_backend="default")
+    return Config(**{**base, **kw})
+
+
+def _group(cfg):
+    return ServerGroup(cfg.num_servers, cfg.num_workers, ps_param_dim(cfg),
+                       learning_rate=cfg.learning_rate, sync=cfg.sync_mode)
+
+
+def _in_threads(workers, call):
+    errors = []
+
+    def one(w):
+        try:
+            call(w)
+        except Exception as e:  # surfaced below
+            errors.append(e)
+
+    threads = [threading.Thread(target=one, args=(w,)) for w in workers]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors
+
+
+def _shard_bytes(worker):
+    it = worker._train
+    it.reset()
+    batch = it.next_batch()
+    it.reset()
+    return sum(a.nbytes for a in batch)
+
+
+class _Recorder:
+    def __init__(self, step, keep=3):
+        self.step, self.keep, self.seen = step, keep, []
+
+    def __call__(self, wf, batch):
+        g = self.step(wf, batch)
+        if len(self.seen) < self.keep:
+            self.seen.append((np.array(wf), np.array(g)))
+        return g
+
+
+@pytest.mark.parametrize("model", ["binary_lr", "softmax"])
+@pytest.mark.parametrize("num_workers", [1, 4])
+def test_whole_shard_is_placed_once_and_gives_the_streamed_gradients(
+        tmp_path, monkeypatch, model, num_workers):
+    cfg = _job(tmp_path, model, num_workers)
+    before = family_total(H2D)
+    with _group(cfg) as group:
+        workers = [PSWorker(cfg, r, group.hosts) for r in range(num_workers)]
+        with monkeypatch.context() as m:
+            m.setattr(PSWorker, "_place_shard", lambda self, train, dev: None)
+            twins = [PSWorker(cfg, r, group.hosts) for r in range(num_workers)]
+            for t in twins:
+                t.load_data()
+        try:
+            assert family_total(H2D) == before  # a streaming worker places nothing ahead
+            for w in workers:
+                w.load_data()
+            shards = sum(_shard_bytes(w) for w in workers)
+            assert family_total(H2D) - before == shards
+            gauge = get_registry().get("distlr_ps_resident_bytes")
+            for w, t in zip(workers, twins):
+                assert w._resident is not None and t._resident is None
+                assert (gauge.labels(rank=str(w.rank)).value
+                        == _shard_bytes(w))
+                w.grad_step = _Recorder(w.grad_step)
+            _in_threads(workers, lambda w: w.run(save=False))
+            # five iterations later the one placement is all that crossed
+            assert family_total(H2D) - before == shards
+            for w, t in zip(workers, twins):
+                assert w.rounds == cfg.num_iteration == w.timer.steps
+                assert len(w.grad_step.seen) == 3
+                for weights, pushed in w.grad_step.seen:
+                    t._train.reset()
+                    streamed = t.grad_step(weights, t._train.next_batch())
+                    assert pushed.dtype == streamed.dtype == np.float32
+                    assert np.array_equal(pushed, streamed)
+                    assert np.count_nonzero(pushed)
+        finally:
+            for w in (*workers, *twins):
+                w.close()
+
+
+def test_a_minibatch_worker_still_streams(tmp_path):
+    cfg = _job(tmp_path, "binary_lr", 1, batch_size=16, num_iteration=2)
+    before = family_total(H2D)
+    with _group(cfg) as group:
+        w = PSWorker(cfg, 0, group.hosts)
+        try:
+            w.load_data()
+            assert w._resident is None
+            final = w.run(save=False)
+        finally:
+            w.close()
+    assert family_total(H2D) == before
+    assert w.rounds == 2 * w._train.num_batches > 2
+    assert np.isfinite(final).all() and np.count_nonzero(final)
+
+
+def test_numpy_steps_place_nothing(tmp_path):
+    """``auto`` takes a step this small to numpy: no device, no shard."""
+    cfg = _job(tmp_path, "binary_lr", 1, ps_compute_backend="auto")
+    before = family_total(H2D)
+    with _group(cfg) as group:
+        w = PSWorker(cfg, 0, group.hosts)
+        try:
+            w.load_data()
+            assert w._resident is None
+            w.run(save=False)
+        finally:
+            w.close()
+    assert family_total(H2D) == before
+
+
+def test_a_second_fit_loads_places_and_compiles_nothing(tmp_path, monkeypatch):
+    cfg = _job(tmp_path, "binary_lr", 1)
+    with _group(cfg) as group:
+        w = PSWorker(cfg, 0, group.hosts)
+        try:
+            w.load_data()
+            w.start()
+            w.fit(epochs=2)
+            assert (w.epochs_done, w.rounds, w._barrier_base) == (2, 2, 0)
+            placed = family_total(H2D)
+            compiled = w._grad_fn._cache_size()
+            held = w.kv.pull()
+
+            def refuse(*a, **kw):
+                raise AssertionError("loaded or placed again")
+
+            monkeypatch.setattr(PSWorker, "_load_train_iter", refuse)
+            monkeypatch.setattr(PSWorker, "_place_shard", refuse)
+            w.load_data()
+            w.start()  # again: the next pair of barrier generations,
+            assert np.array_equal(w.kv.pull(), held)  # the init a no-op
+            w.fit(epochs=3)
+            assert (w.epochs_done, w.rounds, w._barrier_base) == (5, 5, 2)
+            assert w.timer.steps == 5
+            assert family_total(H2D) == placed
+            assert w._grad_fn._cache_size() == compiled
+            w.fit()  # nothing is left of num_iteration
+            assert w.rounds == 5
+            final = w.finish(save=False)
+        finally:
+            w.close()
+    assert np.isfinite(final).all()
+
+
+def test_whole_shard_hands_over_the_arrays_an_iterator_holds():
+    from distlr_tpu.data.iterator import DataIter
+
+    X = np.arange(12, dtype=np.float32).reshape(6, 2)
+    y = np.arange(6) % 2
+    got = DataIter(X, y, -1).whole_shard()
+    assert got[0] is not None and np.shares_memory(got[0], X)
+    assert np.shares_memory(got[1], y) and got[2].all() and len(got[2]) == 6
+    # and the batch next_batch would gather, row for row
+    want = DataIter(X, y, -1).next_batch()
+    assert all(np.array_equal(a, b) for a, b in zip(got, want))
+
+
+@pytest.mark.parametrize("kw", [{"batch_size": 4}, {"shuffle": True, "seed": 3}])
+def test_whole_shard_is_nothing_where_a_batch_is_anything_else(kw):
+    from distlr_tpu.data.iterator import DataIter
+
+    X = np.arange(12, dtype=np.float32).reshape(6, 2)
+    assert DataIter(X, np.zeros(6), **{"batch_size": -1, **kw}).whole_shard() is None

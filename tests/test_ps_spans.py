@@ -1,0 +1,163 @@
+"""The PS worker loop's spans: each with the worker's round count, its
+rank and the span it lies in; ``wire``, on the comm thread, under the
+step that submitted it; four workers' spans kept apart."""
+
+import collections
+
+import jax
+import pytest
+
+from distlr_tpu.config import Config
+from distlr_tpu.data.synthetic import write_synthetic_shards
+from distlr_tpu.obs.tracing import get_tracer
+from distlr_tpu.train.ps_trainer import run_ps_local
+
+DIM, WORKERS, ITERATIONS = 24, 4, 3
+ROUND = ("data_load", "w_put", "compute", "grad_d2h", "push")
+
+
+@pytest.fixture(scope="module")
+def data_dir(tmp_path_factory):
+    d = str(tmp_path_factory.mktemp("ps-spans"))
+    write_synthetic_shards(d, 100 * WORKERS, DIM, num_parts=WORKERS, seed=9,
+                           sparsity=0.0)
+    return d
+
+
+def _cfg(data_dir, **kw):
+    base = dict(data_dir=data_dir, num_feature_dim=DIM, model="binary_lr",
+                num_workers=WORKERS, num_servers=2, sync_mode=False,
+                batch_size=-1, num_iteration=ITERATIONS, learning_rate=0.2,
+                l2_c=0.0, test_interval=0, ps_compute_backend="default")
+    return Config(**{**base, **kw})
+
+
+def _events(cfg):
+    tracer = get_tracer()
+    tracer.reset()
+    run_ps_local(cfg, save=False)
+    return tracer.chrome_trace()["traceEvents"]
+
+
+def _by(events, key):
+    out = collections.defaultdict(list)
+    for e in events:
+        out[key(e)].append(e)
+    return out
+
+
+def test_every_span_has_its_step_its_rank_and_its_parent(data_dir):
+    events = _events(_cfg(data_dir))
+    names = _by(events, lambda e: e["name"])
+    assert {"load_data", "shard_put", "pull", "wire", "barrier_wait",
+            *ROUND} <= set(names)
+    for e in events:
+        assert e["args"]["rank"] in range(WORKERS), e
+        assert 0 <= e["args"]["step"] <= ITERATIONS, e
+    per_rank = _by(events, lambda e: (e["name"], e["args"]["rank"]))
+    for rank in range(WORKERS):
+        assert len(per_rank["load_data", rank]) == 1
+        assert len(per_rank["shard_put", rank]) == 1
+        assert len(per_rank["barrier_wait", rank]) == 2  # start and exit
+        for name in (*ROUND, "wire"):
+            # step 0 is before the first round: rank 0's seed push
+            got = sorted(e["args"]["step"] for e in per_rank[name, rank]
+                         if e["args"]["step"])
+            assert got == list(range(1, ITERATIONS + 1)), (name, rank, got)
+    assert [e["args"]["rank"] for e in names["push"]
+            if e["args"]["step"] == 0] == [0]
+    # the one nesting there is: the placement inside the load
+    ids = {e["args"]["id"]: e for e in events}
+    nested = [e for e in events if "parent" in e["args"]]
+    assert sorted(e["name"] for e in nested) == ["shard_put"] * WORKERS
+    for e in nested:
+        parent = ids[e["args"]["parent"]]
+        assert parent["name"] == "load_data"
+        assert parent["args"]["rank"] == e["args"]["rank"]
+
+
+def test_wire_runs_on_the_comm_thread_under_its_submitters_step(data_dir):
+    events = _events(_cfg(data_dir))
+    per_rank = _by(events, lambda e: (e["name"], e["args"]["rank"]))
+    for rank in range(WORKERS):
+        loop_tids = {e["tid"] for name in ROUND for e in per_rank[name, rank]}
+        wire_tids = {e["tid"] for e in per_rank["wire", rank]}
+        assert len(loop_tids) == 1 and len(wire_tids) == 1
+        assert loop_tids != wire_tids
+        computed = {e["args"]["step"]: e for e in per_rank["compute", rank]}
+        pushed = {e["args"]["step"]: e for e in per_rank["push", rank]}
+        for e in per_rank["wire", rank]:
+            step = e["args"]["step"]
+            # submitted when the step's gradient was ready, answered
+            # before the loop's wait for it ended
+            assert e["ts"] >= computed[step]["ts"] + computed[step]["dur"] - 1
+            assert (e["ts"] + e["dur"]
+                    <= pushed[step]["ts"] + pushed[step]["dur"] + 1)
+
+
+def test_spans_of_four_threads_do_not_nest_into_each_other(data_dir):
+    """A span's parent is the span open on its own thread: with four
+    workers and four comm threads at once none is taken for a child of
+    another's, and no thread holds two ranks."""
+    events = _events(_cfg(data_dir))
+    ids = {e["args"]["id"]: e for e in events}
+    for e in events:
+        parent = ids.get(e["args"].get("parent"))
+        if parent is not None:
+            assert parent["tid"] == e["tid"]
+            assert parent["ts"] <= e["ts"]
+            assert parent["ts"] + parent["dur"] >= e["ts"] + e["dur"]
+    ranks_of = _by(events, lambda e: e["tid"])
+    assert len(ranks_of) == 2 * WORKERS
+    for tid, evs in ranks_of.items():
+        assert len({e["args"]["rank"] for e in evs}) == 1, tid
+
+
+@pytest.mark.parametrize("mode,kw,has,lacks", [
+    ("serialized", dict(ps_pipeline=False), {"pull", "push"}, {"wire"}),
+    ("fused-bsp", dict(sync_mode=True), {"push"}, {"wire"}),
+    ("minibatch", dict(batch_size=32), {"h2d", "wire"}, {"shard_put"}),
+    ("numpy", dict(ps_compute_backend="numpy"), {"compute", "wire"},
+     {"shard_put", "w_put", "grad_d2h", "h2d"}),
+    ("accumulated", dict(ps_accum_max=2, batch_size=32), {"pull", "push"},
+     {"wire", "shard_put"}),
+])
+def test_each_loop_variant_records_the_spans_it_has(data_dir, mode, kw, has,
+                                                    lacks):
+    events = _events(_cfg(data_dir, **kw))
+    names = {e["name"] for e in events}
+    assert {"data_load", "compute", "load_data", "barrier_wait"} | has <= names
+    assert not (lacks & names), mode
+    assert all("rank" in e["args"] and "step" in e["args"] for e in events)
+    steps = _by([e for e in events if e["name"] == "compute"],
+                lambda e: e["args"]["rank"])
+    for rank in range(WORKERS):
+        got = sorted(e["args"]["step"] for e in steps[rank])
+        assert got == list(range(1, len(got) + 1)) and got
+
+
+def test_a_profiler_trace_holds_the_workers_spans(data_dir, tmp_path):
+    from jax.profiler import ProfileData
+
+    from chipbench import trace_reduce
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=options):
+        run_ps_local(_cfg(data_dir), save=False)
+    found = collections.defaultdict(list)
+    path = trace_reduce.find_xplane(str(tmp_path))
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                stats = dict(ev.stats)
+                seed_push = ev.name == "push" and stats.get("step") == 0
+                if ev.name in ("shard_put", "wire", *ROUND) and not seed_push:
+                    found[ev.name].append(stats)
+    for name in ("wire", *ROUND):
+        assert len(found[name]) == WORKERS * ITERATIONS, (name, len(found[name]))
+        assert {int(s["rank"]) for s in found[name]} == set(range(WORKERS))
+    assert len(found["shard_put"]) == WORKERS
+    # step_num on the step marker, step elsewhere
+    assert {int(s["step_num"]) for s in found["compute"]} == {1, 2, 3}
+    assert {int(s["step"]) for s in found["wire"]} == {1, 2, 3}
