@@ -43,6 +43,14 @@ _BYTES_TOTAL = _reg.counter(
     "key+value payload bytes moved by native KV ops",
     labelnames=("op", "direction"),
 )
+_DENSE_FRAMES = _reg.counter(
+    "distlr_ps_dense_frames_total",
+    "default-key (keys=None) ops that succeeded, by how the dense key "
+    "space crossed the wire: rows = runs of vals_per_key values under "
+    "one u64 row key, flat = one u64 key beside every value (no "
+    "vals_per_key divides dim and the handle's range boundaries)",
+    labelnames=("op", "encoding"),
+)
 _CHUNKED_PULLS = _reg.counter(
     "distlr_ps_client_chunked_pulls_total",
     "pull_chunked calls (serving-tier bounded reads)",
@@ -85,13 +93,14 @@ _CLIENT_EPOCH = _reg.gauge(
     "membership epoch this process's most recently (re)connected "
     "epoch-announced KV client is at (0 = no epoch announced)",
 )
-#: Push-byte accounting (ISSUE 7): raw = the dense-f32 encoding the
-#: same frame would have cost before codecs (uncompressed keys + 4
-#: bytes/value), wire = what actually left the kernel (headers + keys +
-#: coded payload, summed over servers).  Both count DELIVERED pushes
-#: exactly once: a failed attempt contributes nothing, its successful
-#: re-issue counts once, and an absorbed unknown-outcome push counts
-#: zero — so the ratio can never be inflated by retries.
+#: Push-byte accounting (ISSUE 7): raw = what the same frame costs as
+#: f32 with no codec (the key frame AS SENT + 4 bytes/value — so the
+#: ratio is the codec's own saving; the bytes a dense op's row keys save
+#: are an uncompressed push's too), wire = what actually left the kernel
+#: (headers + keys + coded payload, summed over servers).  Both count
+#: DELIVERED pushes exactly once: a failed attempt contributes nothing,
+#: its successful re-issue counts once, and an absorbed unknown-outcome
+#: push counts zero — so the ratio can never be inflated by retries.
 _PUSH_RAW = _reg.counter(
     "distlr_ps_push_bytes_raw_total",
     "dense-f32-equivalent bytes of delivered gradient pushes "
@@ -119,12 +128,15 @@ def _account_push_bytes(raw: int, wire: int) -> None:
 
 
 @contextlib.contextmanager
-def _observe_op(op: str, *, sent=0, received: int = 0):
+def _observe_op(op: str, *, sent=0, received: int = 0,
+                dense: str | None = None):
     """Record one op's latency, outcome, and payload bytes.  Timeouts are
     distinguished from hard failures (a wedged barrier vs a dead peer
     read very differently on a dashboard).  ``sent`` may be a callable
     evaluated on success — for ops whose wire size is only known after
-    the native call (compressed pushes)."""
+    the native call (compressed pushes).  ``dense`` is the encoding a
+    default-key op resolved to (``"rows"``/``"flat"``; None for an op
+    that passed its own keys)."""
     t0 = time.perf_counter()
     try:
         yield
@@ -136,6 +148,8 @@ def _observe_op(op: str, *, sent=0, received: int = 0):
         raise
     _OP_SECONDS.labels(op=op).observe(time.perf_counter() - t0)
     _OPS_TOTAL.labels(op=op, status="ok").inc()
+    if dense is not None:
+        _DENSE_FRAMES.labels(op=op, encoding=dense).inc()
     sent = sent() if callable(sent) else sent
     if sent:
         _BYTES_TOTAL.labels(op=op, direction="sent").inc(sent)
@@ -371,10 +385,10 @@ def _load():
                 [ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
                  ctypes.c_void_p, ctypes.c_uint64, ctypes.c_uint64]
             )
-        lib.kv_push_init.restype = ctypes.c_int
-        lib.kv_push_init.argtypes = [
+        lib.kv_push_init_vpk.restype = ctypes.c_int
+        lib.kv_push_init_vpk.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_uint64, ctypes.c_int,
+            ctypes.c_uint64, ctypes.c_int, ctypes.c_uint64,
         ]
         lib.kv_barrier.restype = ctypes.c_int
         lib.kv_barrier.argtypes = [ctypes.c_void_p, ctypes.c_uint32]
@@ -521,7 +535,7 @@ class KVWorker:
         self.trace_active = False
         # one-time sparse-gradient sanity check on the first sign push
         self._sign_zero_checked = False
-        # dense-default row encoding under compression (lazy): (keys, vpk)
+        # how default-key ops address the key space (lazy): (keys, vpk)
         self._dense_rows: tuple[np.ndarray, int] | None = None
         self._h = None
         if route is None:
@@ -727,8 +741,8 @@ class KVWorker:
         self._hosts = hosts
         self.num_servers = hosts.count(",") + 1
         self._epoch = epoch
-        # range boundaries moved: the cached dense row encoding (keyed
-        # vpk re-rowing under compression) must re-derive
+        # range boundaries moved: the cached dense row encoding must
+        # re-derive
         self._dense_rows = None
 
     # -- in-place retry (RetryPolicy) -------------------------------------
@@ -991,30 +1005,47 @@ class KVWorker:
         return all((self.dim * s // self.num_servers) % vpk == 0
                    for s in range(1, self.num_servers))
 
-    def _default_or_validated(self, keys, vpk: int) -> np.ndarray:
-        """Resolve the keys argument: the dense default 0..D-1 set is a
-        FLAT key set — combining it with ``vals_per_key > 1`` would
-        silently reinterpret flat ids as row ids (most falling outside
-        every server's row range and never being sent), so that
-        combination is rejected rather than returning garbage."""
-        if keys is None:
-            if vpk != 1:
-                raise ValueError(
-                    "vals_per_key > 1 requires explicit row keys (the "
-                    "dense default key set is flat ids, not rows)")
-            return self._all_keys
-        return self._validate_keys(keys, vpk)
+    def _resolve_keys(self, keys, vpk: int, vals: np.ndarray | None = None):
+        """THE resolver of a keyed op's ``(keys, vals_per_key, dense)``.
+        ``keys=None`` addresses the whole dense key space 0..D-1 and
+        crosses the wire in the row encoding :meth:`_dense_row_encoding`
+        gives (``dense`` says which: ``"rows"`` or ``"flat"``); explicit
+        keys are validated and sent as given (``dense`` None).  The
+        default set is FLAT ids by contract — combining it with a
+        caller's ``vals_per_key > 1`` would silently reinterpret flat
+        ids as row ids, so that combination is rejected rather than
+        returning garbage.  ``vals``, where the op carries any, is held
+        to the size the keys address."""
+        if keys is not None:
+            keys, dense = self._validate_keys(keys, vpk), None
+        elif vpk != 1:
+            raise ValueError(
+                "vals_per_key > 1 requires explicit row keys (the "
+                "dense default key set is flat ids, not rows)")
+        else:
+            keys, vpk = self._dense_row_encoding()
+            dense = "rows" if vpk > 1 else "flat"
+        if vals is not None and vals.shape[0] != keys.shape[0] * vpk:
+            raise ValueError(
+                f"{vals.shape[0]} vals vs "
+                + (f"the {self.dim} default keys" if dense is not None
+                   else f"{keys.shape[0]} keys x vals_per_key {vpk}"))
+        return keys, vpk, dense
 
     def _dense_row_encoding(self) -> tuple[np.ndarray, int]:
-        """Row encoding for DENSE default-key pushes under an active
-        codec: the largest ``vpk`` (<= the protocol cap) that divides
-        ``dim`` and aligns with the group's range boundaries, so the
-        key frame shrinks from ``dim`` u64s to ``dim/vpk`` — at D=1M an
-        8 MB key frame becomes ~2 KB, without which value compression
-        would be hidden behind uncompressed keys.  Compression mode
-        only: the uncompressed path keeps the flat dense key set so its
-        wire bytes stay identical to every earlier round.  Falls back
-        to the flat keys when no divisor aligns."""
+        """How a default-key op addresses the dense key space: as runs
+        of ``vpk`` values under one u64 row key, with the largest
+        ``vpk`` (<= the protocol cap) that divides ``dim`` and every
+        range boundary this handle has, so no run straddles two
+        servers.  The key frame shrinks from ``dim`` u64s to
+        ``dim/vpk`` — at D=1M over two servers vpk = 4,000: 1 KB of
+        keys a server where the flat set is 4 MB, beside 2 MB of
+        values.  The server expands the rows at its parsing layer
+        (kv_protocol.h), so what it applies and replies is what the
+        flat keys would have got, bit for bit.  ``(all flat keys, 1)``
+        when no divisor aligns (a prime D above the cap, three servers
+        over a D that 3 does not divide).  Cached; a re-route drops the
+        cache (:meth:`_apply_layout`)."""
         if self._dense_rows is None:
             best = 1
             for v in range(min(wire.MAX_VALS_PER_KEY, self.dim), 1, -1):
@@ -1026,13 +1057,20 @@ class KVWorker:
             self._dense_rows = (keys, best)
         return self._dense_rows
 
+    def _frame_now(self, frame):
+        """``frame`` (what :meth:`_resolve_keys` gave) as THIS attempt
+        sends it.  A default-key op derives its encoding again: a
+        re-route between two attempts (an epoch fence, a retired rank)
+        moves the range boundaries the rows were cut to, and rows of the
+        old layout would straddle the new servers.  Explicit keys are
+        the caller's and stay."""
+        return frame if frame[2] is None else self._resolve_keys(None, 1)
+
     def _push_frame(self, keys: np.ndarray | None, vpk: int,
                     vals: np.ndarray):
-        """Resolve a push's (raw_bytes, keys, vpk): raw is the
-        dense-f32 encoding THIS push would have cost uncompressed (the
-        as-given key frame + 4 bytes/value — the compression-ratio
-        numerator), and dense default pushes re-row their key frame
-        when a codec is active (see :meth:`_dense_row_encoding`)."""
+        """A gradient push's ``(keys, vpk, dense)``
+        (:meth:`_resolve_keys`), after the codec's one-time check of
+        what it is asked to code."""
         if self.compress_active == "signsgd" and not self._sign_zero_checked:
             # 1-bit signSGD has no abstention: an exact zero votes -1,
             # so a mostly-zero gradient (sparse data pushed full-width)
@@ -1047,18 +1085,7 @@ class KVWorker:
                     "keys only, or use compress='int8' for sparse "
                     "gradients", vals.size - np.count_nonzero(vals),
                     vals.size)
-        if keys is None and vpk == 1 and self.compress_active != "none":
-            raw = self._all_keys.nbytes + vals.nbytes
-            keys, vpk = self._dense_row_encoding()
-            keys = self._validate_keys(keys, vpk)
-        else:
-            keys = self._default_or_validated(keys, vpk)
-            raw = keys.nbytes + vals.nbytes
-        if vals.shape[0] != keys.shape[0] * vpk:
-            raise ValueError(
-                f"{vals.shape[0]} vals vs {keys.shape[0]} keys "
-                f"x vals_per_key {vpk}")
-        return raw, keys, vpk
+        return self._resolve_keys(keys, vpk, vals)
 
     def push(self, vals: np.ndarray, keys: np.ndarray | None = None,
              *, vals_per_key: int = 1) -> int:
@@ -1076,11 +1103,13 @@ class KVWorker:
         ``distlr_ps_push_bytes_{raw,wire}_total`` counters exactly once
         each (a retried attempt counts only on its successful issue)."""
         vals = np.ascontiguousarray(vals, dtype=np.float32).reshape(-1)
-        raw, keys, vpk = self._push_frame(keys, int(vals_per_key), vals)
+        frame = self._push_frame(keys, int(vals_per_key), vals)
 
         def _issue():
+            keys, vpk, dense = self._frame_now(frame)
             with _observe_op(
-                    "push", sent=lambda: self._lib.kv_last_wire_sent(self._h)):
+                    "push", sent=lambda: self._lib.kv_last_wire_sent(self._h),
+                    dense=dense):
                 ts = self._lib.kv_push_vpk(
                     self._h,
                     keys.ctypes.data_as(ctypes.c_void_p),
@@ -1088,7 +1117,8 @@ class KVWorker:
                     keys.shape[0], vpk,
                 )
                 self._check(ts, "push")
-                _account_push_bytes(raw, self._lib.kv_last_wire_sent(self._h))
+                _account_push_bytes(keys.nbytes + vals.nbytes,
+                                    self._lib.kv_last_wire_sent(self._h))
                 return ts
 
         with self._trace_op("push"):
@@ -1102,18 +1132,18 @@ class KVWorker:
         overwrites live weights (kForceInit): checkpoint resume against a
         surviving group; restarted workers must NOT use it."""
         vals = np.ascontiguousarray(vals, dtype=np.float32)
-        keys = self._all_keys if keys is None else self._validate_keys(keys)
-        if vals.shape[0] != keys.shape[0]:
-            raise ValueError(f"{vals.shape[0]} vals vs {keys.shape[0]} keys")
+        frame = self._resolve_keys(keys, 1, vals)
 
         def _issue():
-            with _observe_op("push_init", sent=keys.nbytes + vals.nbytes):
-                ts = self._lib.kv_push_init(
+            keys, vpk, dense = self._frame_now(frame)
+            with _observe_op("push_init", sent=keys.nbytes + vals.nbytes,
+                             dense=dense):
+                ts = self._lib.kv_push_init_vpk(
                     self._h,
                     keys.ctypes.data_as(ctypes.c_void_p),
                     vals.ctypes.data_as(ctypes.c_void_p),
                     keys.shape[0],
-                    1 if force else 0,
+                    1 if force else 0, vpk,
                 )
                 return self._check(ts, "push_init")
 
@@ -1132,14 +1162,15 @@ class KVWorker:
         pull that would have followed.  ``vals_per_key``: see
         :meth:`push`."""
         vals = np.ascontiguousarray(vals, dtype=np.float32).reshape(-1)
-        raw, keys, vpk = self._push_frame(keys, int(vals_per_key), vals)
-        out = np.empty(keys.shape[0] * vpk, dtype=np.float32)
+        frame = self._push_frame(keys, int(vals_per_key), vals)
+        out = np.empty_like(vals)
 
         def _issue():
+            keys, vpk, dense = self._frame_now(frame)
             with _observe_op(
                     "push_pull",
                     sent=lambda: self._lib.kv_last_wire_sent(self._h),
-                    received=out.nbytes):
+                    received=out.nbytes, dense=dense):
                 ts = self._lib.kv_push_pull_vpk(
                     self._h,
                     keys.ctypes.data_as(ctypes.c_void_p),
@@ -1148,27 +1179,33 @@ class KVWorker:
                     keys.shape[0], vpk,
                 )
                 self._check(ts, "push_pull")
-                _account_push_bytes(raw, self._lib.kv_last_wire_sent(self._h))
+                _account_push_bytes(keys.nbytes + vals.nbytes,
+                                    self._lib.kv_last_wire_sent(self._h))
             return out
+
+        def _repull():
+            keys, vpk, dense = frame
+            return (self.pull() if dense is not None
+                    else self.pull(keys=keys, vals_per_key=vpk))
 
         # Unknown push outcome: the gradient is lost-or-applied-once
         # (counted), and the PULL half is re-issued idempotently so the
         # caller still gets current weights for the same keys.
         with self._trace_op("push_pull"):
-            return self._push_with_retry(
-                "push_pull", _issue,
-                on_unknown=lambda: self.pull(keys=keys, vals_per_key=vpk))
+            return self._push_with_retry("push_pull", _issue,
+                                         on_unknown=_repull)
 
     def pull(self, keys: np.ndarray | None = None,
              *, vals_per_key: int = 1) -> np.ndarray:
         """Blocking pull.  ``vals_per_key=R``: keys are row ids and the
         result holds ``len(keys)*R`` floats row-major (see :meth:`push`)."""
-        vpk = int(vals_per_key)
-        keys = self._default_or_validated(keys, vpk)
-        out = np.empty(keys.shape[0] * vpk, dtype=np.float32)
+        frame = self._resolve_keys(keys, int(vals_per_key))
+        out = np.empty(frame[0].shape[0] * frame[1], dtype=np.float32)
 
         def _issue():
-            with _observe_op("pull", sent=keys.nbytes, received=out.nbytes):
+            keys, vpk, dense = self._frame_now(frame)
+            with _observe_op("pull", sent=keys.nbytes, received=out.nbytes,
+                             dense=dense):
                 ts = self._lib.kv_pull_vpk(
                     self._h,
                     keys.ctypes.data_as(ctypes.c_void_p),
@@ -1187,10 +1224,12 @@ class KVWorker:
         """Pull a large key set as a sequence of bounded keyed pulls.
 
         The serving-tier read path (:mod:`distlr_tpu.serve.reload`): a
-        D=1M CTR table pulled as ONE dense op ships an 8 MB key frame +
-        4 MB value frame in a single message; chunking caps the per-op
-        frame at ``chunk_rows`` rows (keys stay the implicit range ids,
-        one u64 per ``vals_per_key`` floats), so a periodic weight
+        D=1M CTR table pulled as ONE dense op is answered by a 2 MB
+        value frame a server in a single message; chunking caps the
+        per-op frame at ``chunk_rows`` rows (keys stay the implicit
+        range ids, one u64 per ``vals_per_key`` floats — the caller's
+        ``vals_per_key``, not the row runs of :meth:`pull`: this
+        default is a row space of its own), so a periodic weight
         refresh never monopolizes a server's receive loop against the
         trainer pushing to the same group.  ``keys=None`` pulls the full
         row space ``0..dim/vals_per_key``; an explicit ascending ``keys``

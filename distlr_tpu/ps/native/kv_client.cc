@@ -502,13 +502,20 @@ int kv_push(void* handle, const uint64_t* keys, const float* vals, uint64_t n) {
 // an uninitialized server group, no-ops otherwise — safe for a restarted
 // worker to re-send.  force != 0 adds kForceInit (overwrite live
 // weights; the checkpoint-resume path — see kv_protocol.h).
-int kv_push_init(void* handle, const uint64_t* keys, const float* vals,
-                 uint64_t n, int force) {
+// vpk: vals_per_key, as in kv_push_vpk below (a dense seed addresses its
+// values as row runs like every other default-key op).
+int kv_push_init_vpk(void* handle, const uint64_t* keys, const float* vals,
+                     uint64_t n, int force, uint64_t vpk) {
   auto* c = static_cast<distlr::Client*>(handle);
   const uint8_t flags = force ? (distlr::kInitPush | distlr::kForceInit)
                               : distlr::kInitPush;
   return distlr::RoundTrip(c, distlr::Op::kPush, keys, vals, nullptr, n,
-                           flags);
+                           flags, 0, vpk);
+}
+
+int kv_push_init(void* handle, const uint64_t* keys, const float* vals,
+                 uint64_t n, int force) {
+  return kv_push_init_vpk(handle, keys, vals, n, force, 1);
 }
 
 int kv_pull(void* handle, const uint64_t* keys, float* out_vals, uint64_t n) {

@@ -18,12 +18,17 @@
 // 0 == 1 == legacy scalar keys): each key addresses vals_per_key
 // CONSECUTIVE slots of the flat parameter space, starting at
 // key * vals_per_key — ps-lite's KVPairs.lens capability (uniform
-// lens), which the row-blocked CTR path uses to ship one u64 row id
+// lens).  Two users: the row-blocked CTR path ships one u64 row id
 // per R-lane table row instead of R expanded keys (the expanded
 // encoding spends 8 bytes of key per 4 bytes of value; at R=32 the
-// multi-val encoding cuts keyed wire bytes ~2.7x).  The server
-// expands at the parsing layer, so merge/barrier/rollback semantics
-// are byte-identical to a client that expanded the keys itself.
+// multi-val encoding cuts keyed wire bytes ~2.7x), and the client's
+// default-key ops (a dense push, pull, push-pull or seed of the whole
+// key space) address it as runs of the largest vals_per_key that
+// divides dim and every range boundary (ps/client.py
+// _dense_row_encoding; at D=1M over two servers 125 keys a server
+// where the flat frame has 500,000).  The server expands at the
+// parsing layer, so merge/barrier/rollback semantics are
+// byte-identical to a client that expanded the keys itself.
 //
 // Semantics mirror the reference server handle (src/main.cc:41-96):
 //   * first PUSH initializes server weights (src/main.cc:50-56)

@@ -195,10 +195,11 @@ class TestFaultKinds:
                     assert time.perf_counter() - t0 >= 0.055
 
     def test_throttle_paces_bytes(self):
-        # 4 KB/s over a ~4.1 KB pull (keys 8B + vals 4B per slot * 512
-        # each way) must take >= ~1 s; data integrity must hold
+        # 2 KB/s over a ~2.1 KB pull (a 32 B request: header + the one
+        # row key of a 512-value run; a 24 B + 512 * 4 B reply) must
+        # take >= ~1 s; data integrity must hold
         plan = parse_plan({"faults": [
-            {"kind": "throttle", "bytes_per_sec": 4096}]})
+            {"kind": "throttle", "bytes_per_sec": 2048}]})
         with ServerGroup(1, 1, dim=512, sync=False) as g:
             with ChaosFabric(g.direct_hosts, plan) as fab:
                 with KVWorker(fab.hosts, 512, timeout_ms=20_000,
@@ -215,15 +216,15 @@ class TestFaultKinds:
         push (it sees an incomplete frame then EOF), and the client's
         next op rides a reconnect."""
         plan = parse_plan({"faults": [
-            {"kind": "reset", "after_bytes": 3000}]})
+            {"kind": "reset", "after_bytes": 1000}]})
         with ServerGroup(1, 1, dim=64, sync=False) as g:
             with ChaosFabric(g.direct_hosts, plan) as fab:
                 kv = KVWorker(fab.hosts, 64, timeout_ms=2000,
                               sync_group=False,
                               retry=RetryPolicy(attempts=4, backoff_ms=10))
-                kv.push_init(np.zeros(64, np.float32))  # 64*12+24 = 792 B
+                kv.push_init(np.zeros(64, np.float32))  # 24+8+64*4 = 288 B
                 issued = 0
-                for _ in range(6):       # each push frame is 792 bytes
+                for _ in range(6):       # each push frame is 288 bytes
                     kv.push(np.ones(64, np.float32))
                     issued += 1
                 w = kv.pull()

@@ -391,6 +391,14 @@ class TestNegotiation:
 # push-byte accounting
 # ---------------------------------------------------------------------------
 
+#: key bytes of one dense default-key op in the tests below: the keys
+#: cross as row runs of v values under one u64 (v the largest count
+#: <= MAX_VALS_PER_KEY that divides dim and every range boundary;
+#: tests/test_ps_dense_frames.py holds the resolver to that), and every
+#: d here is <= 4096 on one server, so v = d: ONE row key
+ONE_ROW_KEY = 8
+
+
 def _push_byte_deltas():
     return (_counter_total("distlr_ps_push_bytes_raw_total"),
             _counter_total("distlr_ps_push_bytes_wire_total"))
@@ -409,12 +417,14 @@ class TestByteAccounting:
             for i in range(3):
                 kv.wait(kv.push(np.full(d, float(i + 1), np.float32)))
         raw1, wire1 = _push_byte_deltas()
-        per_raw = d * 8 + d * 4          # dense keys + f32 vals
-        # dense re-rowing: 512 == one vpk=512 row == ONE u64 key
+        # the dense default keys cross as row runs, coded or not:
+        # d = 512 on one server is one vpk=512 row == ONE u64 key
+        per_raw = ONE_ROW_KEY + d * 4   # row key + f32 vals
         per_wire = 24 + 8 + payload_bytes("int8", d)
         assert raw1 - raw0 == 3 * per_raw
         assert wire1 - wire0 == 3 * per_wire
-        assert (raw1 - raw0) / (wire1 - wire0) > 8.0
+        # the ratio is the codec's own saving (int8: 4 B -> ~1 B a value)
+        assert (raw1 - raw0) / (wire1 - wire0) > 3.5
 
     def test_none_counters_wire_equals_raw_plus_headers(self):
         d = 128
@@ -424,8 +434,9 @@ class TestByteAccounting:
             kv.push_init(np.zeros(d, np.float32))
             kv.wait(kv.push(np.ones(d, np.float32)))
         raw1, wire1 = _push_byte_deltas()
-        assert raw1 - raw0 == d * 12
-        assert wire1 - wire0 == d * 12 + 24
+        per_raw = ONE_ROW_KEY + d * 4
+        assert raw1 - raw0 == per_raw
+        assert wire1 - wire0 == per_raw + 24
 
     def test_no_double_count_under_chaos_retries(self):
         """Retried and absorbed pushes cannot inflate the ratio: raw
@@ -453,7 +464,7 @@ class TestByteAccounting:
             _counter_total("distlr_ps_push_outcome_unknown_total")
             - unknown0)
         delivered = issued - unknowns
-        per_raw = d * 12
+        per_raw = ONE_ROW_KEY + d * 4
         per_wire = 24 + 8 + payload_bytes("int8", d)
         assert raw1 - raw0 == delivered * per_raw
         assert wire1 - wire0 == delivered * per_wire
@@ -739,27 +750,39 @@ class TestTrainerIntegration:
 # ---------------------------------------------------------------------------
 
 class TestAcceptanceSmoke:
-    def test_d1m_throttled_8x_reduction_at_half_point_quality(self):
-        """>= 8x push-byte reduction at <= 0.5pt accuracy cost at the
+    def test_d1m_throttled_reduction_at_half_point_quality(self):
+        """The push-byte reduction at <= 0.5pt accuracy cost at the
         D=1M operating point, dense full-width gradient pushes through
         the chaos proxy's THROTTLE mode (the DCN stand-in; localhost
         alone won't show the win) — same data, same seed, same update
-        structure for both codecs."""
+        structure for both codecs.  The ROADMAP's >= 8x is against the
+        frame it was written for, a u64 key beside every float32 (12 B
+        a value); the dense frame is row runs now, coded or not, so the
+        codec's own share — int8 against the uncompressed frame as it
+        is sent — is ~3.9x, and both are held."""
         from bench_compress import run_compressed_ps
 
+        d = 1 << 20
         kw = dict(n_train=2048, n_test=1024, batch=128, epochs=1,
                   lr=10.0, throttle_bytes_per_sec=32 << 20,
                   num_servers=2, seed=0)
         faults0 = _counter_total("distlr_chaos_faults_total")
-        dense = run_compressed_ps(1 << 20, "none", **kw)
-        int8 = run_compressed_ps(1 << 20, "int8", **kw)
+        dense = run_compressed_ps(d, "none", **kw)
+        int8 = run_compressed_ps(d, "int8", **kw)
         # the throttle really paced the links
         assert _counter_total("distlr_chaos_faults_total") > faults0
+        # the uncompressed frame is exactly headers + row keys + f32:
+        # 2^20 over two servers goes as runs of 4096, 128 keys a server
+        per_dense = 2 * 24 + d // 4096 * 8 + d * 4
+        assert dense["push_bytes_wire"] == dense["pushes"] * per_dense
+        assert dense["push_bytes_raw"] == int8["push_bytes_raw"]
         reduction = dense["push_bytes_wire"] / int8["push_bytes_wire"]
-        assert reduction >= 8.0, (dense, int8)
+        assert reduction >= 3.5, (dense, int8)
+        flat_keyed = int8["pushes"] * d * 12
+        assert flat_keyed / int8["push_bytes_wire"] >= 8.0, (dense, int8)
         # both runs actually learned (not a trivial-quality comparison)
         assert dense["acc"] > 0.70 and int8["acc"] > 0.70, (dense, int8)
         assert abs(dense["acc"] - int8["acc"]) <= 0.005, (dense, int8)
         # fewer wire bytes through the same paced link = faster wall
-        # clock (pacing dominates both runs; int8 ships ~12x less c2s)
+        # clock (pacing dominates both runs; int8 ships ~4x less c2s)
         assert int8["wall_s"] < dense["wall_s"], (dense, int8)

@@ -291,18 +291,24 @@ def _wire_sent(w: KVWorker) -> int:
     return int(w._lib.kv_last_wire_sent(w._h))
 
 
+#: one dense default-key push to the one server these tests spawn: the
+#: header, one u64 row key for the run of D values (D <= the protocol's
+#: vals_per_key cap, so the whole vector is one row), 4 B a value
+DENSE_PUSH = 24 + 8 + D * 4
+
+
 class TestKVWire:
     def test_sample_zero_wire_byte_identical(self, tmp_path):
         """The regression pin: with tracing off (unconfigured, or
         ``--trace-sample 0``) every push frame is exactly the pre-trace
-        protocol — header(24) + 8/key + 4 B/val, nothing else."""
+        protocol — header(24) + 8 B a row key + 4 B/val, nothing else."""
         with ServerGroup(1, 1, D, sync=False) as group:
             w = KVWorker(group.hosts, D, client_id=1, timeout_ms=10_000,
                          sync_group=False)
             try:
                 w.push_init(np.zeros(D, np.float32))
                 w.wait(w.push(np.ones(D, np.float32)))
-                assert _wire_sent(w) == 24 + D * 8 + D * 4
+                assert _wire_sent(w) == DENSE_PUSH
                 assert not w.trace_active
             finally:
                 w.close()
@@ -315,7 +321,7 @@ class TestKVWire:
                 ctx = dtrace.new_trace()
                 with dtrace.use(ctx):
                     w.wait(w.push(np.ones(D, np.float32)))
-                assert _wire_sent(w) == 24 + D * 8 + D * 4
+                assert _wire_sent(w) == DENSE_PUSH
             finally:
                 w.close()
 
@@ -332,11 +338,11 @@ class TestKVWire:
                 assert w.trace_active
                 w.push_init(np.zeros(D, np.float32))
                 base = _wire_sent(w)  # untraced op: no trailer
-                assert base == 24 + D * 8 + D * 4
+                assert base == DENSE_PUSH
                 ctx = dtrace.new_trace()
                 with dtrace.use(ctx):
                     w.wait(w.push(np.ones(D, np.float32)))
-                    assert _wire_sent(w) == 24 + 16 + D * 8 + D * 4
+                    assert _wire_sent(w) == DENSE_PUSH + 16
                     out = w.pull()
                 assert out.shape == (D,)  # the stamped pull round-tripped
             finally:
@@ -384,7 +390,7 @@ class TestKVWire:
                 with dtrace.use(ctx):
                     w.wait(w.push(np.ones(D, np.float32)))
                 # no trailer on the wire against an old server
-                assert _wire_sent(w) == 24 + D * 8 + D * 4
+                assert _wire_sent(w) == DENSE_PUSH
             finally:
                 w.close()
         dtrace.flush()
