@@ -23,7 +23,6 @@
 #                         # scenario (the pytest `slow` twin)
 #   make sanitizers       # build the native TSan/ASan/UBSan matrix
 #   make sanitizer-smoke  # fast TSan-client + TSan-server e2e
-#                         # (delegates to benchmarks/Makefile)
 #
 # The lint passes are tier-1-enforced through tests/test_analysis.py
 # (the protocol pass through tests/test_protocol_model.py); these
@@ -64,7 +63,8 @@ sanitizers:
 	$(MAKE) -C distlr_tpu/ps/native sanitizers
 
 sanitizer-smoke:
-	$(MAKE) -C benchmarks sanitizer-smoke
+	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_sanitizer_matrix.py \
+	  -m 'not slow' -q -p no:cacheprovider
 
 .PHONY: lint lint-docs verify-protocol verify-protocol-full \
 	verify-sched verify-sched-full verify-fleetsim \

@@ -2,7 +2,7 @@
 
 One runner (``python -m distlr_tpu.analysis``, ``make lint``;
 ``--only <pass>`` runs one in isolation, ``--list-passes`` lists
-them), six passes, each tier-1-enforced the way the PR-8 metrics-doc
+them), and its passes, each tier-1-enforced the way the PR-8 metrics-doc
 lint made metric drift impossible:
 
 * **wire parity** (:mod:`distlr_tpu.analysis.wire_parity`) — parse
@@ -26,6 +26,9 @@ lint made metric drift impossible:
 * **metrics doc** — the PR-8 :mod:`distlr_tpu.obs.metrics_doc` drift
   lint, folded under this runner so ``make lint`` is the single entry
   point (``tests/test_metrics_doc.py`` stays as the tier-1 shim).
+* **document paths** (:mod:`distlr_tpu.analysis.doc_paths`) — a path
+  under one of this repo's directories, or a ``make`` target, that a
+  document's code names must exist.
 * **protocol model checking** (:mod:`distlr_tpu.analysis.protocol`) —
   the SEMANTIC pass: an executable small-step spec of the KV state
   machine, exhaustive interleaving search with invariant checks,

@@ -1,9 +1,9 @@
 """distlr-lint runner: ``python -m distlr_tpu.analysis`` / ``make lint``.
 
 Runs every pass (wire parity, concurrency, config/CLI/docs parity, the
-folded-in metrics-doc lint, the protocol model-checking pass, the
-schedcheck interleaving pass, and the fleetsim scenario pass), prints
-findings as
+folded-in metrics-doc lint, the document path lint, the protocol
+model-checking pass, the schedcheck interleaving pass, and the fleetsim
+scenario pass), prints findings as
 ``[pass] key: message (file:line ...)``, and exits non-zero when any
 survive the audited baselines — the single static-analysis entry point
 tier-1 enforces through ``tests/test_analysis.py``.
@@ -23,7 +23,7 @@ import sys
 
 from distlr_tpu.analysis.report import Finding
 
-PASSES = ("wire", "concurrency", "config", "metrics", "printban",
+PASSES = ("wire", "concurrency", "config", "metrics", "docs", "printban",
           "protocol", "sched", "fleetsim")
 
 #: one-line summaries for --list-passes (kept here, not in the pass
@@ -37,6 +37,8 @@ PASS_SUMMARIES = {
               "(analysis/config_doc.py)",
     "metrics": "metric-series <-> docs/METRICS.md drift "
                "(obs/metrics_doc.py)",
+    "docs": "paths and make targets the documents name exist "
+            "(analysis/doc_paths.py)",
     "printban": "bare print()/sys.stderr.write outside the audited "
                 "CLI-output allowlist (analysis/printban.py)",
     "protocol": "KV state-machine model checking + mutants + trace "
@@ -58,6 +60,9 @@ def run_pass(name: str) -> list[Finding]:
     if name == "config":
         from distlr_tpu.analysis import config_doc
         return config_doc.check()
+    if name == "docs":
+        from distlr_tpu.analysis import doc_paths
+        return doc_paths.check()
     if name == "printban":
         # ISSUE 18: structured-log coverage can't silently regress —
         # daemon narrative must flow through get_logger (where the
@@ -105,16 +110,16 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m distlr_tpu.analysis",
         description="distlr-lint: wire parity, concurrency, "
-                    "config/docs parity, metrics doc, protocol model "
-                    "checking, schedcheck interleavings, fleetsim "
-                    "scenarios")
+                    "config/docs parity, metrics doc, document paths, "
+                    "protocol model checking, schedcheck "
+                    "interleavings, fleetsim scenarios")
     ap.add_argument("--pass", dest="passes", action="append",
                     choices=PASSES,
                     help="run only this pass (repeatable; default all)")
     ap.add_argument("--only", dest="passes", action="append",
                     choices=PASSES, metavar="PASS",
                     help="alias of --pass: run one pass in isolation "
-                    "(the now-eight-pass runner takes a while end to "
+                    "(the whole runner takes a while end to "
                     "end; see --list-passes)")
     ap.add_argument("--list-passes", action="store_true",
                     help="list the passes with one-line summaries, "

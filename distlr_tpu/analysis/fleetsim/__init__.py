@@ -30,7 +30,7 @@ What is REAL (imported, not reimplemented):
   :class:`~distlr_tpu.feedback.join.LabelJoiner` — the delayed-label
   window machinery, driven with virtual timestamps;
 * :mod:`distlr_tpu.traffic` — the same diurnal/Zipf/label-delay
-  arithmetic ``benchmarks/loadgen.py`` drives real sockets with.
+  arithmetic ``distlr_tpu/serve/loadgen.py`` drives real sockets with.
 
 What is MODELED: engines (capacity/latency as fluid queues), workers
 (join/leave/push rates), PS migration time, the standby pool.  Models

@@ -37,7 +37,7 @@ Entry points: the ``protocol`` pass of ``python -m distlr_tpu.analysis``
 (bounded exploration + mutant rediscovery + fixture conformance, fast
 enough for tier-1), ``make verify-protocol`` /
 ``python -m distlr_tpu.analysis.protocol`` (full-depth, prints
-schedules), and ``make -C benchmarks protocol-smoke``.  Everything here
+schedules).  Everything here
 is jax-free and import-light, like the rest of ``analysis/``.
 """
 

@@ -106,8 +106,8 @@ class Config:
     # "int8_dot" additionally keeps BOTH matmul operands int8 (native
     # int8 x int8 -> int32 MXU contraction with dynamic per-step scales
     # for w and the residual) instead of converting the (B, D) tile to
-    # bfloat16, so the VPU convert of the tile is not in the way
-    # (benchmarks/exp_int8_dot.py; rates not measured on today's code).
+    # bfloat16, so the VPU convert of the tile is not in the way (rates
+    # not measured on today's code).
     # Dense models (binary_lr and softmax), single-device or
     # feature-sharded; sparse/blocked reject.
     feature_dtype: str = "float32"    # float32 | bfloat16 | int8 | int8_dot

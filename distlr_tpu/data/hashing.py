@@ -172,8 +172,8 @@ def split_field_groups(num_fields: int, block_size: int,
     garbage).  Larger ``num_groups=G`` splits the fields into G
     near-equal consecutive groups, each padded to R lanes: the
     intermediate groupings between ceil(F/R) chunks and one all-fields
-    conjunction.  Motivation (bench_configs.py's operating-point
-    sweep): on low-cardinality i.i.d. fields the single-group R=32
+    conjunction.  Motivation (an operating-point sweep of the earlier
+    capture, not measured since): on low-cardinality i.i.d. fields the single-group R=32
     layout loses accuracy (21-field tuples never recur) while the SAME R
     at G=3 stays close to scalar hashing — extra groups trade one extra
     row gather per sample for tuple spaces small enough to recur.
@@ -208,8 +208,8 @@ def suggest_block_size(raw_ids, num_buckets: int,
 
     Row-blocked hashing (:func:`hash_group_blocks`) keys table rows per
     (field-group, value-tuple), so it only learns where tuples recur
-    and rows don't collide.  The measured frontier
-    (``bench_configs.py`` ``blocked_frontier``, on-chip): at 512
+    and rows don't collide.  The frontier an earlier capture
+    measured (its script and records are gone; not measured since): at 512
     distinct tuples recurring ~96x, R=16 holds accuracy within 0.4pt
     of scalar hashing at 3.4x its throughput, while R=32 loses ~9pt
     because 512 tuples into D/32 rows is load factor 1 (birthday

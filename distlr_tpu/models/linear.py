@@ -88,7 +88,7 @@ def _int8_contract(a, b, a_axis: int) -> jnp.ndarray:
     D=1M step vs ~165k for both the unrolled form and the (unsafe)
     unchunked dot — the batched dot forces a bad layout on the (B, D)
     operand, while column slices keep each chunk a plain MXU matmul
-    (benchmarks/exp_int8_chunk.py, on-chip).  When the length is
+    (an earlier on-chip capture; not measured since).  When the length is
     awkward — no divisor <= the bound, or only divisors small enough
     that the unroll would exceed ``_INT8_MAX_CHUNKS`` dots — the
     bfloat16-convert formulation is used instead: slower, never wrong.
@@ -159,8 +159,7 @@ class BinaryLR:
     feature_scale: float = 1.0
     # Native int8 x int8 -> int32 MXU contraction (cfg.feature_dtype=
     # "int8_dot").  The plain int8 storage path converts the whole (B, D)
-    # tile to bfloat16 before the dot — a VPU-bound convert
-    # (benchmarks/exp_int8_dot.py).  This path instead quantizes the
+    # tile to bfloat16 before the dot — a VPU-bound convert.  This path instead quantizes the
     # SMALL per-step operands — w over D for the forward, the residual
     # over B for the backward — with dynamic symmetric scales and feeds
     # both dots int8 operands end to end.  Requires X to be int8 (the

@@ -65,6 +65,6 @@ from distlr_tpu.obs.tracing import (  # noqa: F401
     trace_phase,
 )
 
-# One-shot processes (a bench.py run) bank their metrics via
+# One-shot processes (a benchmark run) bank their metrics via
 # DISTLR_METRICS_SNAPSHOT=<path> instead of holding a port.
 install_snapshot_atexit()

@@ -16,7 +16,7 @@ server can re-serve a merged fleet view that is rebuilt every scrape.
 Port 0 binds an OS-assigned ephemeral port (announced by the launcher as
 ``METRICS host:port``, same contract as ``SERVING``/``HOSTS``).  The
 ``DISTLR_METRICS_SNAPSHOT=<path>`` env hook writes the registry to a
-file at interpreter exit — how one-shot processes (a ``bench.py`` run)
+file at interpreter exit — how one-shot processes (a benchmark run)
 bank their metrics without holding a port open.
 Paths ending ``.json`` bank the machine-readable JSON snapshot (what the
 fleet aggregator merges); anything else banks Prometheus text.  Several

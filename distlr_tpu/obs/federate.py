@@ -10,7 +10,7 @@ module is the fleet layer on top of those per-process endpoints:
   .json`` (role, rank, host, port, pid) next to its ``METRICS
   host:port`` stdout announcement; :func:`discover_endpoints` re-lists
   the directory every poll, so late joiners appear without restarts.
-  One-shot processes that cannot hold a port (a ``bench.py`` run)
+  One-shot processes that cannot hold a port (a benchmark run)
   instead bank a JSON registry snapshot under
   ``<run_dir>/snapshots/<role>-<rank>.json`` (the
   ``DISTLR_METRICS_SNAPSHOT`` twin) — the scraper merges both sources.

@@ -14,9 +14,8 @@ the sampler itself knows nothing about roles.
 
 Two capture regimes:
 
-* **always-on** — the default ~19 Hz costs well under the 3% QPS
-  overhead budget (``benchmarks/bench_prof.py`` enforces it) and runs
-  for the life of the process, journaling one window doc per
+* **always-on** — the default ~19 Hz (its QPS overhead: not measured)
+  runs for the life of the process, journaling one window doc per
   ``window_s``;
 * **burst** — the SAME edge-triggered trigger file the flight recorder
   uses (``<run_dir>/flightrec/TRIGGER.json``, dropped by ``launch

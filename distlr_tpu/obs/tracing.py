@@ -8,8 +8,8 @@ them.  ``trace_phase("pull")`` wraps a block; each span is
 
 * accumulated into a per-phase (total seconds, self seconds, count)
   breakdown that survives any event-buffer cap — this is what
-  ``bench.py``'s ``phase_breakdown`` and the on-chip benchmark's
-  per-layer readers (``chipbench/layer_metrics``) report; and
+  the on-chip benchmark's per-layer readers
+  (``chipbench/layer_metrics``) report; and
 * recorded into the registry histogram ``distlr_phase_seconds{phase=}``
   so the /metrics scrape carries the same story; and
 * appended (bounded) as a Chrome trace event, dumpable as JSON that

@@ -3,7 +3,8 @@
 The XLA path computes ``g = X^T (sigmoid(X w) - y)`` as two matmuls, so
 the (B, D) feature matrix streams HBM -> MXU **twice** per step; for the
 wide-feature workloads this framework targets, that HBM traffic IS the
-step time (see bench.py).  This kernel streams X exactly once:
+step time (PERF.md, ``step_hbm_roofline``).  This kernel streams X
+exactly once:
 
 * the weight vector ``w`` (bf16) and a float32 gradient accumulator live
   in VMEM for the whole kernel,

@@ -5,9 +5,3 @@ from distlr_tpu.parallel.mesh import (  # noqa: F401
     feature_sharding,
 )
 from distlr_tpu.parallel.data_parallel import make_sync_train_step, make_eval_step  # noqa: F401
-from distlr_tpu.parallel.ring import (  # noqa: F401
-    make_ring_train_step,
-    ring_all_gather,
-    ring_psum,
-    ring_reduce_scatter,
-)

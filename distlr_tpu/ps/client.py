@@ -366,15 +366,6 @@ def _load():
         lib = ctypes.CDLL(client_lib())
         lib.kv_connect.restype = ctypes.c_void_p
         lib.kv_connect.argtypes = [ctypes.c_char_p, ctypes.c_uint64, ctypes.c_uint32]
-        lib.kv_push.restype = ctypes.c_int
-        lib.kv_push.argtypes = [ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64]
-        lib.kv_pull.restype = ctypes.c_int
-        lib.kv_pull.argtypes = [ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64]
-        lib.kv_push_pull.restype = ctypes.c_int
-        lib.kv_push_pull.argtypes = [
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_void_p, ctypes.c_uint64,
-        ]
         for name in ("kv_push_vpk", "kv_pull_vpk", "kv_push_pull_vpk"):
             fn = getattr(lib, name)
             fn.restype = ctypes.c_int

@@ -9,11 +9,11 @@
 // server-local key — the client-side mirror of DecodeKey
 // (src/main.cc:98-101).
 //
-// Blocking semantics: kv_push/kv_pull send the request to every
+// Blocking semantics: kv_push_vpk/kv_pull_vpk send the request to every
 // involved server, then block until all responses arrive.  The reference
 // always pairs Push/Pull with an immediate Wait (src/lr.cc:122,131,
 // src/main.cc:147), so a blocking call is semantically identical — and
-// in sync mode the server's deferred reply makes kv_push the BSP
+// in sync mode the server's deferred reply makes kv_push_vpk the BSP
 // barrier, same as the reference.  kv_wait exists for API parity and is
 // a no-op.
 
@@ -492,12 +492,8 @@ void* kv_connect(const char* hosts, uint64_t dim, uint32_t client_id) {
   return c;
 }
 
-// keys must be sorted ascending global ids; returns ts >= 0, or -1.
-int kv_push(void* handle, const uint64_t* keys, const float* vals, uint64_t n) {
-  auto* c = static_cast<distlr::Client*>(handle);
-  return distlr::RoundTrip(c, distlr::Op::kPush, keys, vals, nullptr, n);
-}
-
+// Every op: keys must be sorted ascending global ids; returns ts >= 0, or -1.
+//
 // Idempotent weight-seeding push (kInitPush, kv_protocol.h): seeds only
 // an uninitialized server group, no-ops otherwise — safe for a restarted
 // worker to re-send.  force != 0 adds kForceInit (overwrite live
@@ -513,28 +509,7 @@ int kv_push_init_vpk(void* handle, const uint64_t* keys, const float* vals,
                            flags, 0, vpk);
 }
 
-int kv_push_init(void* handle, const uint64_t* keys, const float* vals,
-                 uint64_t n, int force) {
-  return kv_push_init_vpk(handle, keys, vals, n, force, 1);
-}
-
-int kv_pull(void* handle, const uint64_t* keys, float* out_vals, uint64_t n) {
-  auto* c = static_cast<distlr::Client*>(handle);
-  return distlr::RoundTrip(c, distlr::Op::kPull, keys, nullptr, out_vals, n);
-}
-
-// Fused push+pull (kv_protocol.h kPushPull): pushes `vals` and receives
-// the post-update weights for the same keys into out_vals — ONE round
-// trip per server where the reference protocol takes two per batch.  In
-// sync mode the reply is deferred with the BSP round and carries the
-// post-round weights (trajectory-identical to pull-then-push).
-int kv_push_pull(void* handle, const uint64_t* keys, const float* vals,
-                 float* out_vals, uint64_t n) {
-  auto* c = static_cast<distlr::Client*>(handle);
-  return distlr::RoundTrip(c, distlr::Op::kPushPull, keys, vals, out_vals, n);
-}
-
-// --- vals_per_key variants (ps-lite KVPairs.lens, uniform): each key
+// --- vals_per_key ops (ps-lite KVPairs.lens, uniform): each key
 // addresses `vpk` consecutive flat slots starting at key*vpk; keys are
 // in row units, vals/out_vals hold n*vpk floats in row-major order.
 // The row-blocked CTR path ships one u64 per R-lane table row this way
@@ -556,6 +531,11 @@ int kv_pull_vpk(void* handle, const uint64_t* keys, float* out_vals,
                            distlr::kNone, 0, vpk);
 }
 
+// Fused push+pull (kv_protocol.h kPushPull): pushes `vals` and receives
+// the post-update weights for the same keys into out_vals — ONE round
+// trip per server where the reference protocol takes two per batch.  In
+// sync mode the reply is deferred with the BSP round and carries the
+// post-round weights (trajectory-identical to pull-then-push).
 int kv_push_pull_vpk(void* handle, const uint64_t* keys, const float* vals,
                      float* out_vals, uint64_t n, uint64_t vpk) {
   auto* c = static_cast<distlr::Client*>(handle);
@@ -994,7 +974,7 @@ int kv_barrier(void* handle, uint32_t barrier_id) {
                            static_cast<uint16_t>(barrier_id));
 }
 
-// No-op: kv_push/kv_pull already block until completion (see header
+// No-op: every op already blocks until completion (see header
 // comment); kept so the Python surface mirrors KVWorker::Wait.
 int kv_wait(void* handle, int ts) {
   (void)handle;

@@ -1075,7 +1075,7 @@ class KVServer {
         // the same "last = rank W-1" convention the SPMD Q1 gate uses —
         // any fixed arrival order is a valid reference execution, and a
         // deterministic one is testable against the trajectory oracle
-        // (benchmarks/reference_oracle.cc).  Keyed rounds can end on an
+        // (tests/oracle/reference_oracle.cc).  Keyed rounds can end on an
         // empty "present" vote; the quirk means the last worker that
         // pushed DATA, so empty votes never win the pick.
         const PendingPush* pick = nullptr;

@@ -950,6 +950,10 @@ class ServerSupervisor:
             self._opt_z = np.zeros(self._group.dim, np.float32)
             self._opt_n = np.zeros(self._group.dim, np.float32)
         for r in range(self._group.num_servers):
+            # a capture is as old as its first read: pushes that land
+            # while it is pulled are in it or not, so stamping it after
+            # the pull would promise updates the slice may not hold
+            began = time.monotonic()
             try:
                 with self._probe_rank(r) as kv:
                     # An UNINITIALIZED server serves zeros from
@@ -966,7 +970,7 @@ class ServerSupervisor:
                         # untouched since its last capture: the stored
                         # slice is still the live state — refresh its
                         # timestamp without moving any bytes
-                        self._snap_at[r] = time.monotonic()
+                        self._snap_at[r] = began
                         continue
                     vals = kv.pull()
                     lo, hi = self._group.key_range(r)
@@ -1000,7 +1004,7 @@ class ServerSupervisor:
                     # cycle, never a stale slice treated as current).
                     self._snap_pushes[r] = s["total_pushes"]
                     self._snap_valid[r] = True
-                    self._snap_at[r] = time.monotonic()
+                    self._snap_at[r] = began
             except Exception:
                 # this rank is down or wedged; the respawn pass handles
                 # it — its previously captured slice stays authoritative,

@@ -4,10 +4,9 @@ Drives a ``launch route`` front-end (or a single engine listener — the
 line protocol is identical) with a request rate that follows one
 diurnal cycle: a raised-cosine ramp from ``base_qps`` up to
 ``peak_qps`` and back over ``period_s``.  This is the traffic shape
-the fleet autopilot is tested against (``bench_autopilot.py``, the
-``test_autopilot`` acceptance e2e): a controller that can follow one
-synthetic day can breathe capacity up into the peak and back down the
-far side.
+the fleet autopilot is tested against (the ``test_autopilot``
+acceptance e2e): a controller that can follow one synthetic day can
+breathe capacity up into the peak and back down the far side.
 
 The curve/arrival math lives in :mod:`distlr_tpu.traffic` — ONE
 traffic model shared with the fleetsim discrete-event simulator
@@ -53,13 +52,14 @@ reflect the live fleet, of course.)
 
 Library use::
 
-    from loadgen import run_load
+    from distlr_tpu.serve.loadgen import run_load
     summary = run_load("127.0.0.1:7000", base_qps=20, peak_qps=120,
                        period_s=30, dim=1024, seed=7)
 
-CLI: ``python benchmarks/loadgen.py --addr H:P [--base-qps ...]``
-prints the same summary as ONE JSON line (scriptable, like every
-bench in this directory).
+CLI: ``python -m distlr_tpu.serve.loadgen --addr H:P [--base-qps ...]``
+prints the same summary as ONE JSON line.  The module imports no jax
+(``serve/__init__`` is lazy for that reason): a sender never takes a
+chip.
 """
 
 from __future__ import annotations
@@ -67,7 +67,6 @@ from __future__ import annotations
 import argparse
 import bisect
 import json
-import os
 import queue
 import random
 import socket
@@ -75,13 +74,9 @@ import sys
 import threading
 import time
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-REPO = os.path.dirname(HERE)
-sys.path.insert(0, REPO)
-
-# the shared traffic model (re-exported: `from loadgen import qps_at,
-# schedule` is the pinned import contract of tests and benches)
-from distlr_tpu.traffic import (  # noqa: E402
+# the shared traffic model (re-exported: tests import `qps_at` and
+# `schedule` from here)
+from distlr_tpu.traffic import (
     LabelDelay,
     ZipfSampler,
     parse_tenant_mix,
@@ -376,7 +371,7 @@ def main(argv=None) -> int:
                        label_frac=args.label_frac,
                        label_delay_p50_s=args.label_delay_p50_s,
                        label_delay_p95_s=args.label_delay_p95_s)
-    # ONE JSON line, the directory's scriptable contract
+    # ONE JSON line: the CLI's scriptable contract
     print(json.dumps(summary))
     return 0 if summary["err"] == 0 else 1
 

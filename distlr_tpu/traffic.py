@@ -1,6 +1,6 @@
 """ONE traffic model for the serving tier — shared math, two drivers.
 
-``benchmarks/loadgen.py`` (real sockets against a ``launch route``
+:mod:`distlr_tpu.serve.loadgen` (real sockets against a ``launch route``
 front-end) and :mod:`distlr_tpu.analysis.fleetsim` (simulated arrivals
 against modeled engines) must stress the control plane with the SAME
 offered-load shape, or a policy tuned against one lies about the

@@ -157,8 +157,8 @@ class _StepTrace:
 # Below this many per-batch elements (param_dim * batch), the gradient
 # step is cheaper on the host CPU backend than the accelerator's dispatch
 # latency (~0.1 ms of math vs 1-80 ms of round trip for reference-scale
-# D=123 steps; measured in benchmarks/exp_sparse.py context — the config-2
-# PS bench went dispatch-bound without this).  2^25 elements ≈ 5-10 ms of
+# D=123 steps: the 4-worker async run at the reference's width went
+# dispatch-bound without this).  2^25 elements ≈ 5-10 ms of
 # CPU math — the crossover against typical remote-dispatch cost.
 _PS_AUTO_CPU_THRESHOLD = 1 << 25
 # Below this, even the jitted host-CPU step is dominated by jax dispatch

@@ -89,14 +89,14 @@ def require_tpu(what: str) -> dict:
             f"{what}: full-size mode measures the TPU; found "
             f"platform={d['platform']} device_kind={d['kind']} "
             f"devices={d['count']}. Run it on the chip, or use the "
-            "script's --quick/--smoke mode.")
+            "script's --smoke mode.")
     return d
 
 
 def start_benchmark(what: str, *, full_size: bool) -> dict:
     """What every benchmark main does first: place the compile cache and
     take the devices — a TPU or a non-zero exit for a full-size run,
-    wherever JAX lands for a ``--quick``/``--smoke`` one.  Returns the
+    wherever JAX lands for a ``--smoke`` one.  Returns the
     fields every row carries so that it says where it ran:
     ``{"backend", "device_kind", "devices"}``."""
     configure_compile_cache()

@@ -23,7 +23,7 @@ import time
 
 import pytest
 
-from distlr_tpu.analysis import baseline, concurrency, config_doc, wire_parity
+from distlr_tpu.analysis import baseline, concurrency, config_doc, doc_paths, wire_parity
 from distlr_tpu.analysis.__main__ import main as lint_main
 from distlr_tpu.analysis.report import repo_root
 
@@ -303,6 +303,28 @@ class TestConfigDocLint:
         dests = config_doc.launch_dests()
         for field in ("random_seed", "ps_timeout_ms", "prefetch"):
             assert field in dests, field
+
+
+class TestDocPathsLint:
+    @pytest.mark.parametrize("doc", doc_paths.DOCS)
+    def test_document_names_what_exists(self, doc):
+        """Every path under this repo's directories and every `make`
+        target the document's code names is there."""
+        assert [f.render() for f in doc_paths.check_doc(doc)] == []
+
+    def test_a_mistyped_path_or_target_is_found(self, tmp_path):
+        (tmp_path / "distlr_tpu").mkdir()
+        (tmp_path / "distlr_tpu" / "sync.py").write_text("")
+        (tmp_path / "Makefile").write_text("lint:\n\ttrue\n")
+        (tmp_path / "DOC.md").write_text(
+            "`distlr_tpu/sync` and `distlr_tpu/sync.py:12` and\n"
+            "`distlr_tpu/*.py` exist; `src/lr.cc` is the reference's.\n"
+            "`distlr_tpu/synk.py` does not, nor `make -C tests lint`.\n"
+            "```bash\nmake lint\nmake lint-all  # no such target\n```\n")
+        keys = [f.key for f in doc_paths.check_doc("DOC.md", str(tmp_path))]
+        assert keys == ["missing-target:DOC.md:.:lint-all",
+                        "missing-path:DOC.md:distlr_tpu/synk.py",
+                        "missing-target:DOC.md:tests:lint"]
 
 
 class TestRunner:

@@ -20,7 +20,7 @@ The tentpole contract under test:
   ADDREPLICA/DELREPLICA promote/demote over a standby pool, worker
   subprocess spawn/retire;
 * the acceptance e2e: a real router + standby engine replicas under
-  ``benchmarks/loadgen.py``'s diurnal cycle — the autopilot breathes
+  ``distlr_tpu/serve/loadgen.py``'s diurnal cycle — the autopilot breathes
   capacity up into the peak and back down, zero failed accepted
   requests, every action journaled, and fewer replica-seconds burned
   than static-peak provisioning.
@@ -30,7 +30,6 @@ from __future__ import annotations
 
 import http.server
 import json
-import os
 import subprocess
 import sys
 import threading
@@ -61,11 +60,7 @@ from distlr_tpu.ps import (
     MembershipServer,
     ServerGroup,
 )
-
-sys.path.insert(0, os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "benchmarks"))
-from loadgen import make_payloads, qps_at, run_load, schedule  # noqa: E402
+from distlr_tpu.serve.loadgen import make_payloads, qps_at, run_load, schedule
 
 D = 32
 
@@ -817,7 +812,7 @@ class TestAutopilotAcceptance:
             eng.set_weights(w)
             # the ~20ms microbatch floor makes the diurnal peak saturate
             # max_inflight=1 and shed — the signal the engine band
-            # scales on (same tuning as benchmarks/bench_autopilot.py)
+            # scales on
             servers.append(ScoringServer(eng, max_wait_ms=20.0).start())
         addrs = [f"{s.host}:{s.port}" for s in servers]
         router = ScoringRouter([addrs[0]], max_inflight=1).start()

@@ -59,12 +59,35 @@ class TestDeviceRule:
             backend.require_tpu("some_bench.py")
         assert "platform=cpu" in str(e.value) and e.value.code != 0
 
+    def test_chip_smoke_fails_without_a_chip(self):
+        """chip_smoke.py is the chip's check: on the CPU it exits non-zero
+        before any leg and prints neither PASS nor a result line."""
+        r = subprocess.run(
+            [sys.executable, "chip_smoke.py"], cwd=REPO, capture_output=True,
+            text=True, timeout=120,
+            env={**os.environ, "JAX_PLATFORMS": "cpu", "DISTLR_CPU_DEVICES": "1"})
+        assert r.returncode != 0
+        assert "PASS" not in r.stdout and "PASS" not in r.stderr
+        assert r.stdout.strip() == ""
+        assert "no TPU" in r.stderr
+
     def test_summary_is_what_jax_reports(self):
         import jax
 
         d = backend.device_summary()
         assert d == {"platform": "cpu", "kind": jax.devices()[0].device_kind,
                      "count": len(jax.devices())}
+
+    @pytest.mark.parametrize("n", [1, 2, 4, 8])
+    def test_dryrun_multichip_on_virtual_devices(self, n, capsys):
+        """The driver's entry point raises unless one sharded step on an
+        n-device mesh equals the single-device step, in every family."""
+        import __graft_entry__
+
+        __graft_entry__.dryrun_multichip(n)
+        mesh = ({"data": n // 2, "model": 2} if n > 1 and n % 2 == 0
+                else {"data": n})
+        assert f"dryrun_multichip({n}): mesh={mesh} " in capsys.readouterr().out
 
     def test_dryrun_does_not_invent_devices(self):
         import jax
@@ -84,6 +107,16 @@ class TestDeviceRule:
              "distlr_tpu.feedback.online\n"
              "from jax._src import xla_bridge\n"
              "assert not xla_bridge.backends_are_initialized()\n"],
+            cwd=REPO, capture_output=True, text=True, timeout=120)
+        assert r.returncode == 0, r.stderr[-2000:]
+
+    def test_load_generator_imports_without_jax(self):
+        """A sender must never take a chip: its import pulls in no jax."""
+        r = subprocess.run(
+            [sys.executable, "-c",
+             "import sys, distlr_tpu.serve.loadgen as lg\n"
+             "assert callable(lg.run_load) and callable(lg.make_payloads)\n"
+             "assert 'jax' not in sys.modules, 'jax imported'\n"],
             cwd=REPO, capture_output=True, text=True, timeout=120)
         assert r.returncode == 0, r.stderr[-2000:]
 

@@ -2,8 +2,7 @@
 
 The blocked layout trades per-field bucket weights for per-(conjunction,
 field) row lanes so one R-wide row gather replaces R scalar gathers
-(row gathers amortize the per-index cost; benchmarks/exp_blocked.py
-measures it on the chip).  These tests pin the semantics and the
+(row gathers amortize the per-index cost).  These tests pin the semantics and the
 statistical gate: on low-cardinality fields (recurring tuples) the
 blocked model must recover the oracle signal as well as the scalar-hash
 sparse path does.
@@ -396,8 +395,8 @@ class TestBlockedEndToEnd:
 
 
 class TestSuggestBlockSize:
-    """The data-driven advisor distilled from the measured frontier
-    (bench_configs.py blocked_frontier, on-chip): every case below is
+    """The data-driven advisor distilled from the frontier an earlier capture
+    measured (its script is gone): every case below is
     one of the frontier's regimes, asserted to land where the
     measurement said quality lands."""
 
@@ -515,8 +514,8 @@ class TestSuggestBlockSize:
 
 class TestBlockGroups:
     """cfg.block_groups / --block-groups: explicit conjunction-group
-    counts (r5).  The motivation is bench_configs.py's operating-point
-    sweep; these tests pin the layout, the statistical
+    counts (r5).  The motivation is an operating-point sweep of the
+    earlier capture; these tests pin the layout, the statistical
     direction, and the end-to-end plumbing."""
 
     def test_split_field_groups_layouts(self):

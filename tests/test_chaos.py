@@ -603,18 +603,6 @@ class TestLaunchWiring:
                                       ps_retry_attempts=3)) is None
         assert ps_retry_policy(Config(sync_mode=False)) is None
 
-    def test_bench_resilience_snapshot_schema(self):
-        import sys
-
-        sys.path.insert(0, os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        from bench import resilience_snapshot
-
-        snap = resilience_snapshot()
-        assert set(snap) == {"retries", "reconnects",
-                             "push_outcome_unknown", "chaos_faults"}
-        assert all(isinstance(v, int) for v in snap.values())
-
 
 # ---------------------------------------------------------------------------
 # the capstone soak: training through faults, zero restarts

@@ -21,14 +21,13 @@ Covers the codec subsystem end to end:
   Hogwild through ``run_ps_local``;
 * the ROADMAP acceptance, tier-1-runnable: >= 8x push-byte reduction
   at <= 0.5pt accuracy cost at D=1M, dense gradient pushes through the
-  chaos proxy's throttle mode (``benchmarks/bench_compress.py``).
+  chaos proxy's throttle mode.
 """
 
 import argparse
 import contextlib
 import logging
 import os
-import sys
 import threading
 
 import numpy as np
@@ -48,11 +47,6 @@ from distlr_tpu.compress import (
 )
 from distlr_tpu.config import Config
 from distlr_tpu.ps import KVWorker, RetryPolicy, ServerGroup
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-for _p in (REPO, os.path.join(REPO, "benchmarks")):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
 
 
 def _counter_total(name: str) -> float:
@@ -632,22 +626,6 @@ class TestConfigWiring:
         assert g._args["optimizer"] == "sgd"
         assert g._args["compress"] is True
 
-    def test_bench_compression_snapshot_schema(self):
-        # NOT raw >= wire: the process-global registry also holds every
-        # DENSE push earlier tests issued, and an uncompressed frame's
-        # wire bytes exceed its raw value bytes by the header + key
-        # overhead.  The per-push inequality is asserted where a fresh
-        # registry makes it meaningful (counter-accounting tests).
-        from bench import compression_snapshot
-
-        snap = compression_snapshot()
-        assert set(snap) == {"push_bytes_raw", "push_bytes_wire",
-                             "compress_ratio"}
-        raw, wire = snap["push_bytes_raw"], snap["push_bytes_wire"]
-        assert raw >= 0 and wire >= 0
-        expect = round(raw / wire, 3) if wire else 1.0
-        assert snap["compress_ratio"] == expect
-
 
 # ---------------------------------------------------------------------------
 # trainer integration: both codecs, both paths
@@ -749,6 +727,56 @@ class TestTrainerIntegration:
 # the ROADMAP acceptance, tier-1-runnable
 # ---------------------------------------------------------------------------
 
+def _run_compressed_ps(d, codec, *, n_train, n_test, batch, lr,
+                       throttle_bytes_per_sec, num_servers, seed,
+                       pool=1024, nnz=8) -> dict:
+    """One epoch of dense full-width ``push_pull`` rounds at dim ``d``
+    through a throttled chaos link, the same data and order for every
+    codec; the ``pool`` of active columns is spread over ``[0, d)`` so
+    that every quant block and server slice sees traffic."""
+    import time
+
+    from distlr_tpu.chaos import ChaosFabric, parse_plan
+
+    rng = np.random.default_rng(seed)
+    w_true = rng.normal(size=pool).astype(np.float32)
+
+    def draw(n):
+        cols = rng.integers(0, pool, size=(n, nnz))
+        y = (w_true[cols].sum(axis=1) > 0).astype(np.float32)
+        return cols * max(1, d // pool), y
+
+    tr_c, tr_y = draw(n_train)
+    te_c, te_y = draw(n_test)
+    plan = parse_plan({"faults": [
+        {"kind": "throttle", "bytes_per_sec": int(throttle_bytes_per_sec)}]})
+    raw0, wire0 = _push_byte_deltas()
+    t0 = time.perf_counter()
+    with ServerGroup(num_servers, 1, d, sync=False, learning_rate=lr) as sg, \
+            ChaosFabric(sg.direct_hosts, plan) as fab, \
+            KVWorker(fab.hosts, d, timeout_ms=120_000, sync_group=False,
+                     compress=codec) as kv:
+        assert codec in ("none", kv.compress_active), kv.compress_active
+        kv.push_init(np.zeros(d, np.float32))
+        w = np.zeros(d, np.float32)
+        pushes = 0
+        for lo in range(0, n_train, batch):
+            cols, y = tr_c[lo:lo + batch], tr_y[lo:lo + batch]
+            p = 1.0 / (1.0 + np.exp(-w[cols].sum(axis=1)))
+            r = ((p - y) / np.float32(len(y))).astype(np.float32)
+            g = np.zeros(d, np.float32)
+            np.add.at(g, cols.reshape(-1), np.repeat(r, cols.shape[1]))
+            w = kv.push_pull(g)
+            pushes += 1
+        kv.shutdown_servers()
+    wall_s = time.perf_counter() - t0
+    raw1, wire1 = _push_byte_deltas()
+    acc = float(((w[te_c].sum(axis=1) > 0) == (te_y > 0)).mean())
+    return {"codec": codec, "acc": acc, "pushes": pushes, "wall_s": wall_s,
+            "push_bytes_raw": int(raw1 - raw0),
+            "push_bytes_wire": int(wire1 - wire0)}
+
+
 class TestAcceptanceSmoke:
     def test_d1m_throttled_reduction_at_half_point_quality(self):
         """The push-byte reduction at <= 0.5pt accuracy cost at the
@@ -760,15 +788,13 @@ class TestAcceptanceSmoke:
         a value); the dense frame is row runs now, coded or not, so the
         codec's own share — int8 against the uncompressed frame as it
         is sent — is ~3.9x, and both are held."""
-        from bench_compress import run_compressed_ps
-
         d = 1 << 20
-        kw = dict(n_train=2048, n_test=1024, batch=128, epochs=1,
+        kw = dict(n_train=2048, n_test=1024, batch=128,
                   lr=10.0, throttle_bytes_per_sec=32 << 20,
                   num_servers=2, seed=0)
         faults0 = _counter_total("distlr_chaos_faults_total")
-        dense = run_compressed_ps(d, "none", **kw)
-        int8 = run_compressed_ps(d, "int8", **kw)
+        dense = _run_compressed_ps(d, "none", **kw)
+        int8 = _run_compressed_ps(d, "int8", **kw)
         # the throttle really paced the links
         assert _counter_total("distlr_chaos_faults_total") > faults0
         # the uncompressed frame is exactly headers + row keys + f32:

@@ -29,17 +29,21 @@ def batch(n=32, d=16, seed=0):
 
 
 class TestFeatureShardedBinaryLR:
-    def test_matches_unsharded_step(self, mesh42):
+    # the four-chip host's shapes and their neighbours, beside this file's 4x2
+    @pytest.mark.parametrize("data,model_shards", [
+        (4, 2), (1, 2), (2, 2), (1, 4), (2, 4), (1, 8)])
+    def test_matches_unsharded_step(self, data, model_shards):
         """2D-parallel step == single-device full-batch step: sharding the
-        feature axis must not change the math."""
+        feature axis must not change the math, whatever the mesh."""
+        mesh = make_mesh({"data": data, "model": model_shards})
         cfg = Config(learning_rate=0.2, l2_c=0.4, num_feature_dim=16)
         model = BinaryLR(16)
         X, y, mask = batch()
         w0 = np.random.default_rng(1).standard_normal(16).astype(np.float32)
 
-        step = make_feature_sharded_train_step(model, cfg, mesh42)
-        w_sh = shard_weights(jnp.asarray(w0), mesh42)
-        b_sh = shard_batch_2d((jnp.asarray(X), jnp.asarray(y), jnp.asarray(mask)), mesh42)
+        step = make_feature_sharded_train_step(model, cfg, mesh)
+        w_sh = shard_weights(jnp.asarray(w0), mesh)
+        b_sh = shard_batch_2d((jnp.asarray(X), jnp.asarray(y), jnp.asarray(mask)), mesh)
         w1, metrics = step(w_sh, b_sh)
 
         g_ref = model.grad(jnp.asarray(w0), (jnp.asarray(X), jnp.asarray(y), jnp.asarray(mask)), cfg)
@@ -124,33 +128,6 @@ class TestFeatureShardedInt8Dot:
         w1_ref = w0 - 0.2 * np.asarray(g_ref)
         np.testing.assert_allclose(np.asarray(w1), w1_ref, atol=5e-4)
         assert np.isfinite(float(metrics["loss"]))
-
-    def test_ring_variant_matches_too(self, mesh42):
-        import dataclasses
-
-        from distlr_tpu.parallel.ring import make_ring_train_step
-
-        d = 16
-        cfg = Config(learning_rate=0.2, l2_c=0.0, num_feature_dim=d,
-                     feature_dtype="int8_dot", feature_shards=2)
-        model = dataclasses.replace(
-            BinaryLR(d, int8_dot=True), feature_scale=1.0 / 127.0)
-        rng = np.random.default_rng(4)
-        X = rng.integers(-127, 128, (32, d)).astype(np.int8)
-        y = rng.integers(0, 2, 32).astype(np.int32)
-        mask = np.ones(32, np.float32)
-        w0 = (0.1 * rng.standard_normal(d)).astype(np.float32)
-
-        step = make_ring_train_step(model, cfg, mesh42)
-        w1, _ = step(
-            shard_weights(jnp.asarray(w0), mesh42),
-            shard_batch_2d(
-                (jnp.asarray(X), jnp.asarray(y), jnp.asarray(mask)), mesh42))
-        g_ref = model.grad(
-            jnp.asarray(w0),
-            (jnp.asarray(X), jnp.asarray(y), jnp.asarray(mask)), cfg)
-        np.testing.assert_allclose(
-            np.asarray(w1), w0 - 0.2 * np.asarray(g_ref), atol=5e-4)
 
 
 class TestFeatureShardedSoftmax:

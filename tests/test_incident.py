@@ -711,10 +711,16 @@ class TestIncidentAcceptance:
 
                 scraper = FleetScraper(run, thresholds=quiet,
                                        incident_settle_s=2.5)
+                # only the worker band may act: the engine band outranks it
+                # and rides a cumulative p99, so warm-up requests that ran
+                # slow beside busy test workers latched it and it took
+                # every tick (up to 8, down, up, ...)
                 daemon = AutopilotDaemon(
                     PolicyEngine(PolicyConfig(
                         hysteresis_ticks=1, cooldown_s=0.0,
-                        rollback_window_s=600.0, lag_high=3.0)),
+                        rollback_window_s=600.0, lag_high=3.0,
+                        route_p99_high_ms=1e9, shed_rate_high=1e9,
+                        staleness_high=1e9, push_rate_high=1e9)),
                     _ScriptActuators({"ps": 1, "engine": 1, "worker": 1}),
                     fetch=scraper.fleet_json,
                     alert_poll=lambda: [
@@ -732,20 +738,26 @@ class TestIncidentAcceptance:
                 with open(orphan, "w") as f:
                     f.write("1 1:0.5 2:0.25\n")
                 os.utime(orphan, (time.time() - 3600, time.time() - 3600))
-                # a backlog the trainer cannot out-consume: a big batch
-                # plus a steady trickle, so the shard_lag gauge holds a
-                # nonzero scan value across scrape cycles
-                _plant_shards(shards, 0, 60)
-                planted = 60
+                # a backlog held there by looking: the lag gauge is a count
+                # taken at each of the trainer's scans, and a fixed trickle
+                # that it out-consumes reads 0 once the first batch is gone
+                backlog = 60
+
+                def unclaimed() -> int:
+                    return sum(n.endswith(".libsvm") for n in os.listdir(shards))
+
+                planted = 0
                 decision = None
                 deadline = time.monotonic() + 60
                 while time.monotonic() < deadline:
+                    short = backlog - unclaimed()
+                    if short > 0:
+                        _plant_shards(shards, planted, short)
+                        planted += short
                     scraper.scrape_once()
                     decision = daemon.tick_once()
                     if decision.rule == "worker_up":
                         break
-                    _plant_shards(shards, planted, 2)
-                    planted += 2
                     time.sleep(0.3)
                 assert decision is not None \
                     and decision.rule == "worker_up", (

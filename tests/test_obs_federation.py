@@ -448,7 +448,7 @@ def _poll_fleet(url, predicate, deadline_s=45) -> str:
 
 
 class TestFleetSmoke:
-    """The `make -C benchmarks obs-smoke` fleet half: two dummy
+    """The fleet half of the observability e2e: two dummy
     metric-emitting processes + the real aggregator CLI, one merged
     scrape with both ranks and at least one derived alert gauge."""
 

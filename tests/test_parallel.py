@@ -54,16 +54,18 @@ class TestMesh:
 
 
 class TestSyncStep:
-    def test_psum_equals_single_device_fullbatch(self, mesh8):
+    @pytest.mark.parametrize("n_data", [8, 2, 4])
+    def test_psum_equals_single_device_fullbatch(self, n_data):
         """The distributed mean gradient must equal the single-device
         full-batch gradient: the collective is exact, not approximate."""
+        mesh = make_mesh({"data": n_data})
         cfg = Config(learning_rate=0.1, l2_c=0.5)
         model = BinaryLR(16)
         batch = global_batch()
         w0 = jnp.asarray(np.random.default_rng(1).standard_normal(16), dtype=jnp.float32)
 
-        step = make_sync_train_step(model, cfg, mesh8)
-        w1, metrics = step(jnp.array(w0), shard_batch(batch, mesh8))
+        step = make_sync_train_step(model, cfg, mesh)
+        w1, metrics = step(jnp.array(w0), shard_batch(batch, mesh))
 
         g_ref = model.grad(w0, batch, cfg)
         w1_ref = w0 - 0.1 * g_ref

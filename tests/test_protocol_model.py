@@ -402,12 +402,6 @@ class TestRunnerWiring:
         assert "verify-protocol:" in text
         assert "distlr_tpu.analysis.protocol" in text
 
-    def test_benchmarks_protocol_smoke_exists(self):
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        with open(os.path.join(root, "benchmarks", "Makefile")) as f:
-            text = f.read()
-        assert "protocol-smoke:" in text
-
     def test_verify_protocol_cli_green(self, capsys):
         from distlr_tpu.analysis.protocol.__main__ import main
         assert main(["--mutants"]) == 0

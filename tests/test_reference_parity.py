@@ -1,7 +1,7 @@
 """Epochs-to-accuracy parity against an INDEPENDENT implementation of the
 reference training protocol (VERDICT r1 #2).
 
-``benchmarks/reference_oracle.cc`` reimplements the reference job —
+``tests/oracle/reference_oracle.cc`` reimplements the reference job —
 Q2 ``srand(0)`` init (src/lr.cc:92-98), Q4 L2/B gradient (src/lr.cc:40),
 Q5 wraparound batches (data_iter.h:44-56), Q1 last-gradient sync merge
 (src/main.cc:66-75, deterministically refined to "highest rank wins"),
@@ -23,8 +23,7 @@ from distlr_tpu.config import Config
 from distlr_tpu.data.synthetic import write_synthetic_shards
 from distlr_tpu.train.ps_trainer import run_ps_local
 
-BENCH_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                         "benchmarks")
+ORACLE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "oracle")
 
 
 def _runs_here(path: str) -> bool:
@@ -42,8 +41,8 @@ def _runs_here(path: str) -> bool:
 
 @pytest.fixture(scope="module")
 def oracle_bin():
-    path = os.path.join(BENCH_DIR, "reference_oracle")
-    make_args = ["make", "-C", BENCH_DIR, "reference_oracle"]
+    path = os.path.join(ORACLE_DIR, "reference_oracle")
+    make_args = ["make", "-C", ORACLE_DIR, "reference_oracle"]
     r = subprocess.run(make_args, capture_output=True, text=True)
     if r.returncode == 0 and os.path.exists(path) and not _runs_here(path):
         # stale foreign-toolchain artifact: force a local rebuild

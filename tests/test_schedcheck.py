@@ -437,9 +437,6 @@ class TestRunnerWiring:
         with open(f"{REPO}/Makefile") as f:
             mk = f.read()
         assert "verify-sched:" in mk and "verify-sched-full:" in mk
-        with open(f"{REPO}/benchmarks/Makefile") as f:
-            bmk = f.read()
-        assert "schedcheck-smoke:" in bmk
 
 
 # ---------------------------------------------------------------------------

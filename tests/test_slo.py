@@ -51,10 +51,9 @@ from distlr_tpu.obs.tsdb import (
     load_history,
 )
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from distlr_tpu.serve.loadgen import run_load
 
-sys.path.insert(0, os.path.join(REPO, "benchmarks"))
-from loadgen import run_load  # noqa: E402
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _frame(t: float, req: float, shed: float = 0.0) -> dict:

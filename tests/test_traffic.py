@@ -1,7 +1,7 @@
 """Unit table for the shared traffic model (ISSUE 19 satellite).
 
 :mod:`distlr_tpu.traffic` is the ONE offered-load model both
-``benchmarks/loadgen.py`` (real sockets) and fleetsim (simulated
+``distlr_tpu/serve/loadgen.py`` (real sockets) and fleetsim (simulated
 arrivals) drive — these tests pin the arithmetic both drivers now
 share: the diurnal curve and its deterministic send schedule, Zipf
 popularity (sampling AND the closed-form ``mass`` the

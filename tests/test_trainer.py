@@ -368,15 +368,14 @@ class TestGoldenModelFormat:
     def test_roundtrip_reference_written_file(self, tmp_path):
         import subprocess
 
-        bench = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "benchmarks")
+        src = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "oracle", "reference_oracle.cc")
         # build into tmp_path: never touch the tracked binary in-place,
         # and a missing compiler skips instead of erroring
         oracle = str(tmp_path / "reference_oracle")
         try:
             r = subprocess.run(
-                ["g++", "-O2", "-std=c++17", "-o", oracle,
-                 os.path.join(bench, "reference_oracle.cc")],
+                ["g++", "-O2", "-std=c++17", "-o", oracle, src],
                 capture_output=True, text=True,
             )
         except OSError as e:
