@@ -46,7 +46,7 @@ class Sizes:
     sparse_epochs: int
     ps_batch: int
     ps_epochs: int
-    kernel_shapes: tuple   # (B, D, batch_tile)
+    kernel_rows: int       # of the row-panel kernel's shard, D = d wide
     kernel_interpret: bool
     timing_steps: int
 
@@ -58,7 +58,7 @@ FULL = Sizes(
     # 1e6 * 64 = 6.4e7 >= ps_trainer._PS_AUTO_CPU_THRESHOLD (2**25), so
     # ps_compute_backend=auto must put the step on the accelerator
     ps_batch=64, ps_epochs=2,
-    kernel_shapes=((512, 16384, 64), (4096, 16384, 64)),
+    kernel_rows=384,       # a worker's shard in dense-ps-async-1chip
     kernel_interpret=False,
     timing_steps=20,
 )
@@ -67,7 +67,7 @@ REHEARSAL = Sizes(
     dense_samples=640, dense_batch=64, dense_epochs=6,
     sparse_samples=2560, sparse_batch=1024, sparse_epochs=4,
     ps_batch=16, ps_epochs=2,
-    kernel_shapes=((256, 1024, 16),),
+    kernel_rows=16,
     kernel_interpret=True,
     timing_steps=5,
 )
@@ -422,48 +422,54 @@ class Smoke:
         }
 
     def kernel(self) -> dict:
+        """The row-panel kernel (``ops/pallas_lr.py``) at a PS worker's
+        shard: one read of X against ``BinaryLR.grad``'s two."""
         import jax
         import jax.numpy as jnp
-        import numpy as np
 
         from distlr_tpu import Config
         from distlr_tpu.models import BinaryLR
-        from distlr_tpu.ops import fused_lr_grad, fused_lr_supported
+        from distlr_tpu.ops import pad_columns, panel_plan
 
-        shapes = list(self.s.kernel_shapes)
-        if not self.s.kernel_interpret:
-            # the widest shape fused_lr_supported admits at each tile:
-            # "supported" must mean "Mosaic compiles it"
-            for tile in (16, 64, 128):
-                d = 128
-                while fused_lr_supported(2 * tile, d + 128, tile):
-                    d += 128
-                shapes.append((2 * tile, d, tile))
-        worst = 0.0
-        for b, d, tile in shapes:
-            _check(fused_lr_supported(b, d, tile), f"supported: {(b, d, tile)}")
-            rng = np.random.default_rng(0)
-            X = jnp.asarray(rng.standard_normal((b, d)), jnp.bfloat16)
-            w = jnp.asarray(0.05 * rng.standard_normal(d), jnp.float32)
-            y = jnp.asarray(rng.integers(0, 2, b), jnp.int32)
-            mask = jnp.ones(b, jnp.float32)
-            g = jax.block_until_ready(fused_lr_grad(
-                w, X, y, mask, batch_tile=tile,
-                interpret=self.s.kernel_interpret))
-            # BinaryLR.grad is the mean gradient; the kernel's is the sum
-            ref = BinaryLR(d).grad(
-                w, (X, y, mask), Config(num_feature_dim=d, l2_c=0.0)) * b
-            _check(g.shape == (d,) and bool(jnp.isfinite(g).all()), "finite")
-            # bf16 tolerance: the XLA path rounds the residual to bf16
-            # (8 mantissa bits) before the backward matmul, the kernel
-            # keeps it float32
-            err = float(jnp.max(jnp.abs(g - ref)) / jnp.max(jnp.abs(ref)))
-            _check(err <= 2.0 ** -7, f"{(b, d, tile)}: rel err {err:.3g}")
-            worst = max(worst, err)
+        rows, d = self.s.kernel_rows, self.s.d
+        plan = panel_plan(rows, d)
+        _check(plan is not None, f"a plan for {rows}x{d}")
+        cfg = Config(num_feature_dim=d, l2_c=0.5)
+        # float32 matmuls on both sides: on the CPU XLA rounds the default
+        # bfloat16 operands, on the chip it keeps float32 either way
+        model = BinaryLR(d, compute_dtype="float32")
+        kx, km, ky, kw = jax.random.split(jax.random.PRNGKey(0), 4)
+        # about 40 non-zeros a row, made on the device
+        X = jax.jit(lambda: jax.random.normal(kx, (rows, d), jnp.float32)
+                    * (jax.random.uniform(km, (rows, d)) < 40 / d))()
+        y = (jax.random.uniform(ky, (rows,)) < 0.3).astype(jnp.int32)
+        mask = jnp.ones(rows, jnp.float32).at[-3:].set(0)
+        w = 0.05 * jax.random.normal(kw, (d,), jnp.float32)
+        Xp = jax.jit(lambda X: pad_columns(X, plan))(X)
+        two = jax.jit(lambda w, X: model.grad(w, (X, y, mask), cfg))
+        one = jax.jit(lambda w, Xp: model.grad_panels(
+            w, (Xp, y, mask), cfg, plan, interpret=self.s.kernel_interpret))
+
+        def timed(fn, *args):
+            out = jax.block_until_ready(fn(*args))   # compiles
+            t0 = time.perf_counter()
+            for _ in range(self.s.timing_steps):
+                out = fn(*args)
+            jax.block_until_ready(out)
+            return out, 1e3 * (time.perf_counter() - t0) / self.s.timing_steps
+
+        ref, two_ms = timed(two, w, X)
+        g, one_ms = timed(one, w, Xp)
+        _check(g.shape == (d,) and bool(jnp.isfinite(g).all()), "finite")
+        err = float(jnp.linalg.norm(g - ref) / jnp.linalg.norm(ref))
+        _check(err <= 5e-6, f"{rows}x{d}: rel err {err:.3g}")
         return {
-            "steps": len(shapes),
-            "shapes": "+".join("x".join(map(str, s)) for s in shapes),
-            "interpret": self.s.kernel_interpret, "max_rel_err": f"{worst:.2e}",
+            "steps": 2 * (1 + self.s.timing_steps),
+            "shape": f"{rows}x{d}", "padded_to": plan.dim_padded,
+            "vmem_limit_bytes": plan.vmem_limit,
+            "f": f"{plan.held_share:.3f}", "chunks": plan.chunks,
+            "interpret": self.s.kernel_interpret, "rel_err": f"{err:.2e}",
+            "one_pass_ms": f"{one_ms:.3f}", "two_pass_ms": f"{two_ms:.3f}",
         }
 
     def mesh(self) -> dict:

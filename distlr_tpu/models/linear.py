@@ -227,6 +227,24 @@ class BinaryLR:
             g = g * self.feature_scale
         return g + _l2_grad(w, cfg, n)
 
+    def grad_panels(self, w, batch, cfg: Config, plan, *, interpret=False):
+        """:meth:`grad` from one HBM read of the features, for a batch
+        whose ``X`` is held as ``ops.pallas_lr.pad_columns(X, plan)``:
+        the row-panel kernel gives ``X^T r`` in float32 whatever
+        ``compute_dtype`` says (XLA's two fusions agree with float32 to
+        1.5e-7 on the chip as well: PERF.md section 2); the mean, the L2
+        term and ``feature_scale`` are this method's, as in ``grad``."""
+        from distlr_tpu.ops.pallas_lr import lr_grad_panels  # noqa: PLC0415
+
+        Xp, y, mask = batch
+        n = jnp.maximum(jnp.sum(mask), 1).astype(jnp.float32)
+        scaled = self.feature_scale != 1.0
+        g = lr_grad_panels(w * self.feature_scale if scaled else w,
+                           Xp, y, mask, plan, interpret=interpret) / n
+        if scaled:
+            g = g * self.feature_scale
+        return g + _l2_grad(w, cfg, n)
+
     def predict(self, w, X):
         # Reference decision rule: z > 0 (src/lr.cc:100-106).
         return (self.logits(w, X) > 0).astype(jnp.int32)

@@ -1,1 +1,6 @@
-from distlr_tpu.ops.pallas_lr import fused_lr_grad, fused_lr_supported  # noqa: F401
+from distlr_tpu.ops.pallas_lr import (  # noqa: F401
+    PanelPlan,
+    lr_grad_panels,
+    pad_columns,
+    panel_plan,
+)

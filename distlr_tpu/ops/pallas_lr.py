@@ -1,139 +1,290 @@
-"""Pallas TPU kernel: single-HBM-pass fused logistic-regression gradient.
+"""Pallas TPU kernel: the logistic gradient from one HBM read of X.
 
-The XLA path computes ``g = X^T (sigmoid(X w) - y)`` as two matmuls, so
-the (B, D) feature matrix streams HBM -> MXU **twice** per step; for the
-wide-feature workloads this framework targets, that HBM traffic IS the
-step time (PERF.md, ``step_hbm_roofline``).  This kernel streams X
-exactly once:
+XLA computes ``g = X^T ((sigmoid(X w) - y) * mask)`` as two fusions, the
+forward ``X w`` and the backward ``X^T r``, and each streams the whole
+feature matrix out of HBM; for a wide resident shard (a PS worker's
+384 x 1,000,000 float32: 1.5 GB) that traffic is the step
+(``step_hbm_roofline`` counts the matrix once and read 46%: PERF.md
+section 5).  Nothing on the chip holds 1.5 GB, but the forward only has
+to finish for *a row* before that row's backward can run, so the matrix
+is walked in **row panels**: the eight rows of one sublane group, every
+column, 32 MB at D = 1M, which the v5e's 128 MiB of VMEM does hold.
 
-* the weight vector ``w`` (bf16) and a float32 gradient accumulator live
-  in VMEM for the whole kernel,
-* the grid walks batch tiles; each (BT, D) tile of X is DMA'd in once,
-  used for the forward matvec ``z_t = X_t @ w``, turned into the residual
-  ``r_t = (sigmoid(z_t) - y_t) * mask_t`` on the VPU, and immediately
-  re-used (still in VMEM) for the backward rank-BT update
-  ``g += r_t @ X_t`` on the MXU,
-* the final grid step writes the accumulator out.
+What that asks of the layout: the rows' *columns* in the lanes
+(``float32[rows, Dp]`` with ``Dp`` a multiple of 128, the device's
+default row-major tiling ``T(8,128)``), so that a panel is one
+contiguous run of HBM.  ``float32[384, 1000000]`` is not held that way
+by default (1,000,000 is no multiple of 128, so the device puts the rows
+in the lanes): :func:`pad_columns` is the relayout, paid once by whoever
+keeps the matrix on the device (``PSWorker._place_shard``).
 
-In theory halved HBM traffic -> up to 2x step throughput while the VMEM
-working set fits the 16 MB scoped-VMEM limit.  ``fused_lr_supported``
-reports the budget check; callers fall back to the XLA two-matmul path
-above it.
+The kernel, float32 throughout, both sweeps on the VPU (an M = 1 forward
+or a K = 8 backward wastes the MXU, and float32 costs it several
+passes):
 
-Whether the single pass beats the XLA path on the chip is not measured
-on today's code (``chip_smoke.py`` only proves the kernel compiles under
-Mosaic and agrees with :meth:`BinaryLR.grad`); :class:`BinaryLR` keeps
-the XLA path, and the kernel stays as the reference implementation of
-the fused formulation (grid pipelining, VMEM accumulators, ``pl.when``
-epilogues).
+* X stays in HBM; a panel is fetched as ``chunks`` column chunks of
+  about a megabyte by ``make_async_copy`` into VMEM slots;
+* sweep 1 accumulates ``x * w`` elementwise over a panel's column tiles
+  as the chunks land (one lane reduction at the end gives ``z`` for the
+  eight rows), then ``r = (sigmoid(z) - y) * mask``;
+* sweep 2 accumulates ``x * r`` into eight sublane partials of the
+  gradient from the same VMEM bytes, and as it leaves a chunk's slot it
+  starts the fetch of the next panel's chunk into it, so HBM is never
+  waiting on a whole panel of arithmetic;
+* where VMEM holds only ``held`` of a panel's ``chunks`` (a share
+  ``f = held / chunks``), the others go through a ring of two slots in
+  both sweeps: the matrix is read ``2 - f`` times, not twice.
+  :func:`panel_plan` works ``f`` out from the shape and the VMEM limit.
 
-This is the TPU-native answer to the reference's O(B*D^2) scalar hot
-loop (``src/lr.cc:35-41``) at the opposite end of the efficiency scale.
+The eight partials are summed, cut to ``D``, and turned into the mean
+gradient with its L2 term by plain ``jnp`` around the call
+(:meth:`BinaryLR.grad_panels`), in the caller's jitted function.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Scoped VMEM the kernel may use; passed to Mosaic as its limit, so the
-# budget below and the compiler count against the same number (it is
-# also libtpu's default).
-_VMEM_LIMIT = 16 * 1024 * 1024
+_LANES = 128
+_SUBLANES = 8   # rows of a panel: one float32 sublane group
+#: column tiles of a chunk, about: 256 tiles x 8 rows x 512 B = 1 MB, large
+#: enough that a DMA runs at HBM's rate and small enough that the two
+#: chunks of arithmetic between a panel's last fetch landing and the next
+#: panel's first fetch starting are a few percent of a panel's 40 us
+_CHUNK_TILES = 256
+#: slots the chunks that do not stay go through, in turn
+_RING = 2
+#: VMEM left to Mosaic's own scratch and the labels' and masks' blocks
+_VMEM_SLACK = 4 << 20
+#: The scoped-VMEM limit handed to Mosaic.  A v5e core has 128 MiB
+#: (``pltpu.get_tpu_info().vmem_capacity_bytes``).  Mosaic does not check
+#: the limit itself, only what it allocates against the core's capacity:
+#: under 126 MiB a plan that this module counts at 125.5 MiB compiled and
+#: ran on the chip, and 130.4 MiB of allocations are refused (PERF.md
+#: section 7).  What is left goes to the operands XLA keeps in VMEM.
+VMEM_LIMIT_BYTES = 120 << 20
 
 
-def fused_lr_supported(batch: int, dim: int, batch_tile: int = 64) -> bool:
-    """True where the kernel compiles: shape constraints, and a working
-    set inside ``_VMEM_LIMIT``.  Per tile element Mosaic holds the
-    double-buffered bf16 X tile and ONE float32 copy of it (the products
-    fuse into their reductions); per column the bf16 ``w`` and float32
-    ``g`` blocks, both double-buffered, and the float32 accumulator.
-    Checked on a v5e against the allocator's own figures: the estimate
-    runs 3-6% above them, never below."""
-    if batch % batch_tile != 0 or dim % 128 != 0 or batch_tile % 16 != 0:
-        return False
-    working_set = (
-        2 * batch_tile * dim * 2  # double-buffered bf16 X tile
-        + batch_tile * dim * 4    # x.astype(f32)
-        + dim * (2 * 2 + 2 * 4 + 4)  # w, g (x2 buffers each), accumulator
-    )
-    return working_set <= _VMEM_LIMIT
+@dataclasses.dataclass(frozen=True)
+class PanelPlan:
+    """How a ``float32[rows, dim]`` matrix is walked."""
+
+    rows: int
+    dim: int            # the real columns
+    chunk_tiles: int    # 128-column tiles of a chunk
+    chunks: int         # chunks of a panel
+    held: int           # of them, those that stay in VMEM between the sweeps
+    vmem_limit: int
+
+    @property
+    def dim_padded(self) -> int:
+        return self.chunks * self.chunk_tiles * _LANES
+
+    @property
+    def chunk_cols(self) -> int:
+        return self.chunk_tiles * _LANES
+
+    @property
+    def weight_rows(self) -> int:
+        """Rows a chunk's weights take, one tile a row, whole groups."""
+        return pl.cdiv(self.chunk_tiles, _SUBLANES) * _SUBLANES
+
+    @property
+    def held_share(self) -> float:
+        """``f``: the matrix crosses HBM ``2 - f`` times a gradient."""
+        return self.held / self.chunks
+
+    @property
+    def slots(self) -> int:
+        return self.held + (_RING if self.held < self.chunks else 0)
 
 
-def _kernel(x_ref, y_ref, mask_ref, w_ref, g_ref, acc_ref):
-    # Matvec-shaped contractions (N=1 / M=1) waste 127/128 of the MXU, so
-    # both directions run on the VPU as broadcast-multiply + axis
-    # reduction — that keeps the kernel DMA-bound instead of
-    # degenerate-matmul-bound.
-    t = pl.program_id(0)
+def panel_plan(rows: int, dim: int, *, vmem_limit: int = VMEM_LIMIT_BYTES,
+               chunk_tiles: int = _CHUNK_TILES) -> PanelPlan | None:
+    """The plan for a ``float32[rows, dim]`` matrix under ``vmem_limit``
+    bytes of VMEM, or None where the kernel cannot run: rows that are not
+    whole sublane groups, or a limit that leaves no chunk of a panel in
+    place once the gradient's partials and the weights are counted."""
+    if rows <= 0 or dim <= 0 or rows % _SUBLANES:
+        return None
+    tiles = pl.cdiv(dim, _LANES)
+    chunks = pl.cdiv(tiles, chunk_tiles)
+    plan = PanelPlan(rows, dim, pl.cdiv(tiles, chunks), chunks, chunks,
+                     vmem_limit)
+    # beside the slots: the eight partials of g, and w
+    fixed = (_SUBLANES * plan.dim_padded * 4
+             + chunks * plan.weight_rows * _LANES * 4 + _VMEM_SLACK)
+    slots = (vmem_limit - fixed) // (_SUBLANES * plan.chunk_cols * 4)
+    if slots >= chunks:
+        return plan
+    if slots - _RING < 1:
+        return None
+    return dataclasses.replace(plan, held=slots - _RING)
 
-    @pl.when(t == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[:].astype(jnp.float32)  # (BT, D); the only HBM read of this tile
-    w = w_ref[:].astype(jnp.float32)  # (1, D), VMEM-resident across the grid
-    z = jnp.sum(x * w, axis=1, keepdims=True)  # (BT, 1) forward matvec
-    r = (jax.nn.sigmoid(z) - y_ref[:]) * mask_ref[:]  # (BT, 1)
-    # backward re-uses the SAME VMEM tile: outer-product accumulation
-    acc_ref[:] += jnp.sum(x * r, axis=0, keepdims=True)  # (1, D)
-
-    @pl.when(t == pl.num_programs(0) - 1)
-    def _flush():
-        g_ref[:] = acc_ref[:]
+def pad_columns(X, plan: PanelPlan):
+    """``X`` as the kernel reads it: ``float32[rows, dim_padded]``, the
+    pad columns zero.  On a TPU this is the relayout to row-major
+    (module docstring); call it once for a matrix that stays."""
+    return jnp.pad(X.astype(jnp.float32),
+                   ((0, 0), (0, plan.dim_padded - plan.dim)))
 
 
-@functools.partial(jax.jit, static_argnames=("batch_tile", "interpret"))
-def fused_lr_grad(
-    w,
-    X,
-    y,
-    mask,
-    *,
-    batch_tile: int = 64,
-    interpret: bool = False,
-):
-    """Unnormalized logistic gradient ``X^T ((sigmoid(Xw) - y) * mask)``.
+def _kernel(plan: PanelPlan, x_hbm, w_ref, y_ref, mask_ref, g_ref, buf, sems):
+    """``x_hbm``: ``f32[rows, dim_padded]`` in HBM; ``w_ref``:
+    ``f32[chunks, weight_rows, 128]``, chunk ``k``'s tiles one a row;
+    ``y_ref``, ``mask_ref``: ``f32[rows, 1]``; ``g_ref``: ``f32[8,
+    dim_padded]``, the sublane partials; ``buf``: ``f32[slots, 8,
+    chunk_cols]``; ``sems``: a DMA semaphore a slot."""
+    panels = plan.rows // _SUBLANES
+    tiles, cols = plan.chunk_tiles, plan.chunk_cols
+    held, streamed = plan.held, plan.chunks - plan.held
+    ring_fetches = 2 * streamed * panels
 
-    One HBM pass over ``X``.  Caller divides by the batch size and adds
-    the L2 term (matching :meth:`BinaryLR.grad` semantics).
+    def loop(n, body):
+        """``body(k)`` for each ``k < n``, for what it does."""
+        def step(k, carry):
+            body(k)
+            return carry
 
-    Args:
-      w: (D,) float32/bfloat16 weights. D must be a multiple of 128.
-      X: (B, D) features (cast to bf16 for the MXU). B must be a
-        multiple of ``batch_tile`` (pad + mask).
-      y: (B,) labels; mask: (B,) validity.
-      batch_tile: rows per grid step (multiple of 16 for bf16 tiling).
-    """
-    B, D = X.shape
-    if not fused_lr_supported(B, D, batch_tile):
+        lax.fori_loop(0, n, step, 0)
+
+    def copy(panel, chunk, slot):
+        return pltpu.make_async_copy(
+            x_hbm.at[pl.ds(pl.multiple_of(panel * _SUBLANES, _SUBLANES),
+                           _SUBLANES),
+                     pl.ds(pl.multiple_of(chunk * cols, _LANES), cols)],
+            buf.at[slot], sems.at[slot])
+
+    def ring_copy(q):
+        """The ``q``-th fetch through the ring: the streamed chunks of
+        panel 0 for sweep 1, again for sweep 2, then panel 1's."""
+        return copy(q // (2 * streamed), held + q % streamed,
+                    held + q % _RING)
+
+    def start_ring(q):
+        pl.when(q < ring_fetches)(lambda: ring_copy(q).start())
+
+    def forward(slot, chunk, acc):
+        """Sweep 1 over the chunk in ``slot``: ``acc[u] += x * w``, a
+        tile each, eight independent chains."""
+        def group(i, acc, count=_SUBLANES):
+            """``count`` tiles from tile ``8 i`` on; their weights are one
+            aligned (8, 128) load, a tile's a row of it."""
+            first = pl.multiple_of(i * _SUBLANES, _SUBLANES)
+            w8 = w_ref[chunk, pl.ds(first, _SUBLANES), :]
+            acc = list(acc)
+            for u in range(count):
+                col = pl.multiple_of((first + u) * _LANES, _LANES)
+                acc[u] += buf[slot, :, pl.ds(col, _LANES)] * w8[u:u + 1, :]
+            return tuple(acc)
+
+        groups = tiles // _SUBLANES
+        acc = lax.fori_loop(0, groups, group, acc)
+        if tiles % _SUBLANES:
+            acc = group(groups, acc, tiles % _SUBLANES)
+        return acc
+
+    def backward(slot, chunk, r):
+        """Sweep 2 over the chunk in ``slot``: ``g[:, cols] += x * r``."""
+        base = chunk * cols
+
+        def add(col, width):
+            at = pl.ds(pl.multiple_of(base + col, _LANES), width)
+            g_ref[:, at] += buf[slot, :, pl.ds(col, width)] * r[:, :width]
+
+        wide = _SUBLANES * _LANES
+        if tiles >= _SUBLANES:
+            loop(tiles // _SUBLANES,
+                 lambda i: add(pl.multiple_of(i * wide, wide), wide))
+        full = tiles // _SUBLANES * wide
+        if cols > full:
+            add(full, cols - full)
+
+    g_ref[...] = jnp.zeros_like(g_ref)
+    loop(held, lambda k: copy(0, k, k).start())
+    if streamed:
+        for q in range(_RING):
+            start_ring(q)
+
+    def panel(p):
+        acc = (jnp.zeros((_SUBLANES, _LANES), jnp.float32),) * _SUBLANES
+
+        def held_forward(k, acc):
+            copy(p, k, k).wait()
+            return forward(k, k, acc)
+
+        acc = lax.fori_loop(0, held, held_forward, acc)
+
+        def ring_pass(first, body, carry):
+            """The streamed chunks once, each from its ring slot, the
+            slot's next fetch started as soon as it is read."""
+            def step(j, carry):
+                q = first + j
+                ring_copy(q).wait()
+                carry = body(held + q % _RING, held + j, carry)
+                start_ring(q + _RING)
+                return carry
+
+            return lax.fori_loop(0, streamed, step, carry)
+
+        if streamed:
+            acc = ring_pass(2 * streamed * p, forward, acc)
+        z = jnp.sum(functools.reduce(jnp.add, acc), axis=1, keepdims=True)
+        at = pl.ds(pl.multiple_of(p * _SUBLANES, _SUBLANES), _SUBLANES)
+        r = (jax.nn.sigmoid(z) - y_ref[at, :]) * mask_ref[at, :]
+        r = jnp.broadcast_to(r, (_SUBLANES, _SUBLANES * _LANES))
+
+        def held_backward(k):
+            backward(k, k, r)
+            pl.when(p + 1 < panels)(lambda: copy(p + 1, k, k).start())
+
+        def ring_backward(slot, chunk, carry):
+            backward(slot, chunk, r)
+            return carry
+
+        loop(held, held_backward)
+        if streamed:
+            ring_pass(2 * streamed * p + streamed, ring_backward, 0)
+
+    loop(panels, panel)
+
+
+def lr_grad_panels(w, Xp, y, mask, plan: PanelPlan, *, interpret: bool = False):
+    """``X^T ((sigmoid(X w) - y) * mask)``, ``float32[dim]``, from
+    ``Xp = pad_columns(X, plan)``: the unnormalised logistic gradient."""
+    if Xp.shape != (plan.rows, plan.dim_padded) or Xp.dtype != jnp.float32:
         raise ValueError(
-            f"fused kernel unsupported for B={B} D={D} batch_tile={batch_tile}; "
-            "use the XLA path (BinaryLR.grad)"
-        )
-    grid = (B // batch_tile,)
-    g = pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((batch_tile, D), lambda t: (t, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((batch_tile, 1), lambda t: (t, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((batch_tile, 1), lambda t: (t, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, D), lambda t: (0, 0), memory_space=pltpu.VMEM),
+            f"the kernel reads float32{[plan.rows, plan.dim_padded]} "
+            f"(pad_columns), not {Xp.dtype}{list(Xp.shape)}")
+    # chunk k's tiles one a row, each chunk's rows padded to whole groups:
+    # a group of eight tiles' weights is one aligned (8, 128) load
+    w3 = jnp.pad(
+        jnp.pad(w.astype(jnp.float32), (0, plan.dim_padded - plan.dim))
+        .reshape(plan.chunks, plan.chunk_tiles, _LANES),
+        ((0, 0), (0, plan.weight_rows - plan.chunk_tiles), (0, 0)))
+    column = lambda v: v.astype(jnp.float32).reshape(plan.rows, 1)  # noqa: E731
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    partials = pl.pallas_call(
+        functools.partial(_kernel, plan),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY), vmem, vmem, vmem],
+        out_specs=vmem,
+        out_shape=jax.ShapeDtypeStruct((_SUBLANES, plan.dim_padded),
+                                       jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((plan.slots, _SUBLANES, plan.chunk_cols), jnp.float32),
+            pltpu.SemaphoreType.DMA((plan.slots,)),
         ],
-        out_specs=pl.BlockSpec((1, D), lambda t: (0, 0), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((1, D), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((1, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=plan.vmem_limit),
+        name="lr_grad_panels",
         interpret=interpret,
-    )(
-        X.astype(jnp.bfloat16),
-        y.astype(jnp.float32).reshape(B, 1),
-        mask.astype(jnp.float32).reshape(B, 1),
-        w.astype(jnp.bfloat16).reshape(1, D),
-    )
-    return g.reshape(D)
+    )(Xp, w3, column(y), column(mask))
+    return jnp.sum(partials, axis=0)[:plan.dim]

@@ -21,7 +21,11 @@ link is never idle (the trace still names the copy's chunks
 kernels were compiled for are restored on the device, at HBM speed, by
 a small program of the feed's own (``jit_feed_restore``), dispatched by
 the thread that did the put.  What the caller gets back is equal in
-values, shape, dtype and sharding to a plain ``device_put``.
+values, shape, dtype and sharding to a plain ``device_put``, and so in
+the device's default layout (for ``[rows, 1000000]`` the rows in the
+lanes): a caller that wants the columns there, as a PS worker's resident
+shard does for its one-pass step, relays it once on the device itself
+(``PSWorker._place_shard``).
 
 It engages per leaf, on what it can observe: a C-contiguous numpy
 matrix of one of the dense feature dtypes, large enough that the

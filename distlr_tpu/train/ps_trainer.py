@@ -41,6 +41,7 @@ from distlr_tpu.data import DataIter
 from distlr_tpu.data.iterator import SparseDataIter
 from distlr_tpu.data.sharding import part_name
 from distlr_tpu.models import get_model
+from distlr_tpu.models.linear import BinaryLR
 from distlr_tpu.obs import dtrace, jaxrt
 from distlr_tpu.obs.registry import COUNT_BUCKETS, get_registry
 from distlr_tpu.obs.tracing import loop_span
@@ -113,6 +114,25 @@ _ACCUM_K = get_registry().gauge(
 _RESIDENT_BYTES = get_registry().gauge(
     "distlr_ps_resident_bytes",
     "bytes of a PS worker's whole-shard batch held on its step's device",
+    labelnames=("rank",),
+)
+
+
+#: Which program a dense worker's step on a jax device ran, a round:
+#: ``one_pass`` is the row-panel kernel over a resident row-major shard
+#: (``ops/pallas_lr.py``), ``two_pass`` is ``model.grad`` under XLA, whose
+#: forward and backward each stream the features.
+_GRAD_ROUNDS = get_registry().counter(
+    "distlr_ps_grad_rounds_total",
+    "rounds of a PS worker's dense step on a jax device, by how often the "
+    "program reads the features out of HBM",
+    labelnames=("rank", "path"),
+)
+_PANEL_HELD = get_registry().gauge(
+    "distlr_ps_grad_panel_held",
+    "share f of a row panel the one-pass step keeps in VMEM between its "
+    "forward and backward sweeps: X crosses HBM 2 - f times a round "
+    "(0 = the two-pass program)",
     labelnames=("rank",),
 )
 
@@ -310,11 +330,49 @@ def _compiled_fns(model, l2_c: float, l2_scale_by_batch: bool):
     gcfg = types.SimpleNamespace(l2_c=l2_c, l2_scale_by_batch=l2_scale_by_batch)
 
     # a name of its own: a trace shows the program as ``jit_ps_grad_step``,
-    # which no other jitted function of the process shares
-    def ps_grad_step(w, X, y, mask):
-        return model.grad(w, (X, y, mask), gcfg)
+    # which no other jitted function of the process shares.  ``panels``
+    # (static) is the plan of a resident row-major ``X``: the one-pass
+    # program of ``_one_pass_plan``, in this same jitted function.
+    def ps_grad_step(w, X, y, mask, panels=None, interpret=False):
+        if panels is None:
+            return model.grad(w, (X, y, mask), gcfg)
+        return model.grad_panels(w, (X, y, mask), gcfg, panels,
+                                 interpret=interpret)
 
-    return jax.jit(ps_grad_step)
+    return jax.jit(ps_grad_step, static_argnames=("panels", "interpret"))
+
+
+#: platforms on which a resident shard's step is the one-pass program
+#: (elsewhere the kernel runs only interpreted, in tests)
+_ONE_PASS_PLATFORMS = ("tpu",)
+
+
+def _one_pass_plan(model, rows: int, dim: int, device):
+    """The row-panel plan for a resident ``float32[rows, dim]`` shard
+    whose step runs on ``device`` (``ops.pallas_lr.panel_plan``), or None
+    where the step stays ``model.grad`` under XLA: any model but a
+    ``BinaryLR`` without ``int8_dot``, a device that is no TPU, rows that
+    are not whole sublane groups, a panel of which VMEM holds nothing."""
+    if not isinstance(model, BinaryLR) or model.int8_dot:
+        return None
+    if device.platform not in _ONE_PASS_PLATFORMS:
+        return None
+    from distlr_tpu.ops.pallas_lr import panel_plan  # noqa: PLC0415
+
+    return panel_plan(rows, dim)
+
+
+@functools.lru_cache(maxsize=None)
+def _row_major_program(plan):
+    """The jitted relayout of a placed shard's features to what the
+    one-pass step reads (``ops.pallas_lr.pad_columns``).  Its name
+    carries no ``step``: the benchmark finds the step's runs by that."""
+    from distlr_tpu.ops.pallas_lr import pad_columns  # noqa: PLC0415
+
+    def ps_shard_row_major(X):
+        return pad_columns(X, plan)
+
+    return jax.jit(ps_shard_row_major)
 
 
 @functools.lru_cache(maxsize=None)
@@ -524,6 +582,20 @@ class PSWorker:
     ``"numpy"``).  Minibatch workers, and every keyed model, stream numpy
     batches from host RAM, one ``device_put`` a step.
 
+    How a resident shard is held, and what reads it.  Where the model is
+    a ``BinaryLR`` without ``int8_dot``, the device a TPU, the rows whole
+    groups of eight and VMEM holds at least a part of a row panel
+    (``_one_pass_plan``: no option), ``X`` is relaid once, inside
+    ``shard_put``, to ``float32[rows, Dp]`` with the columns in the lanes
+    and zero pad columns, and ``jit_ps_grad_step`` is the row-panel
+    kernel of ``ops/pallas_lr.py``: the gradient from ONE read of ``X``
+    out of HBM (XLA's forward and backward fusions each stream it).
+    Everything else (streamed batches, which would pay the relayout every
+    round; ``softmax``; the CPU) keeps the device's default layout and
+    ``model.grad`` under XLA.  ``distlr_ps_grad_rounds_total{rank, path}``
+    counts the rounds of each, ``distlr_ps_grad_panel_held{rank}`` is the
+    share of a panel VMEM holds.
+
     ``run()`` is ``load_data()`` (iterators, the device choice, the
     placement; once), ``start()`` (seed push, start barrier), ``fit()``
     (the epochs) and ``finish()`` (final pull, export, exit barrier,
@@ -629,6 +701,8 @@ class PSWorker:
         self._eval_dev = None
         self._resident = None  # (X, y, mask) on the step's device
         self._resident_rows = 0
+        #: the one-pass step's plan where the resident X is held for it
+        self._panels = None
         #: dense models: ``(flat weights, batch) -> flat float32
         #: gradient`` on the device load_data() picked
         self.grad_step = None
@@ -806,8 +880,18 @@ class PSWorker:
                     ).reshape(-1)
         else:
             self._resident = self._place_shard(train, step_dev)
+            rank = str(self.rank)
+            plan = self._panels
+            _PANEL_HELD.labels(rank=rank).set(plan.held_share if plan else 0.0)
+            # the kernel is interpreted off the TPU (tests)
+            one_pass = {} if plan is None else dict(
+                panels=plan,
+                interpret=(step_dev or jax.devices()[0]).platform != "tpu")
 
             def grad_step(wf, batch):
+                # the plan goes with the resident batch alone: its X is
+                # held for it (``_place_shard``)
+                how = one_pass if batch is self._resident else {}
                 if batch is not self._resident:
                     with self._span("h2d"):
                         batch = self._place(step_dev, *batch)
@@ -815,7 +899,10 @@ class PSWorker:
                     w = jax.block_until_ready(
                         jax.device_put(self._shape_params(wf), step_dev))
                 with self._span("compute", marks_step=True):
-                    g = jax.block_until_ready(self._grad_fn(w, *batch))
+                    g = jax.block_until_ready(
+                        self._grad_fn(w, *batch, **how))
+                _GRAD_ROUNDS.labels(
+                    rank=rank, path="one_pass" if how else "two_pass").inc()
                 with self._span("grad_d2h"):
                     # one copy, device to host; the reshape is a view and
                     # the client sends from this buffer
@@ -827,7 +914,9 @@ class PSWorker:
         or None where the worker streams: every epoch of such an iterator
         yields these same rows, so they cross to the device once.  Each
         leaf goes through ``feed.place``, which picks the layout it is
-        handed over in and counts it in ``distlr_h2d_bytes_total``."""
+        handed over in and counts it in ``distlr_h2d_bytes_total``; where
+        the one-pass step will read them (``_one_pass_plan``), the
+        features are then relaid on the device, row-major and padded."""
         if train.num_batches != 1 or train.batch_size != train.num_samples:
             return None
         # the arrays the iterator holds where the batch is just those (no
@@ -836,10 +925,15 @@ class PSWorker:
         if batch is None:
             train.reset()
             batch = train.next_batch()
-        mesh = make_mesh(devices=[step_dev or jax.devices()[0]])
+        device = step_dev or jax.devices()[0]
+        mesh = make_mesh(devices=[device])
+        self._panels = _one_pass_plan(self.model, *batch[0].shape, device)
         with self._span("shard_put"):
-            placed = jax.block_until_ready(
-                tuple(feed.place(a, mesh) for a in batch))
+            X, *rest = (feed.place(a, mesh) for a in batch)
+            if self._panels is not None:
+                # once, on the device: the columns into the lanes
+                X = _row_major_program(self._panels)(X)
+            placed = jax.block_until_ready((X, *rest))
         self._resident_rows = int(batch[-1].sum())
         _RESIDENT_BYTES.labels(rank=str(self.rank)).set(
             sum(a.nbytes for a in batch))

@@ -1,98 +1,204 @@
+"""The row-panel kernel (``ops/pallas_lr.py``), interpreted on the CPU:
+equal to ``BinaryLR.grad`` in float32 whatever share of a panel VMEM
+holds, and compiled for a described v5e at the cell's size."""
+
+import re
+import types
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distlr_tpu.ops import fused_lr_grad, fused_lr_supported
+from distlr_tpu.models.linear import BinaryLR
+from distlr_tpu.ops import PanelPlan, lr_grad_panels, pad_columns, panel_plan
+from distlr_tpu.ops import pallas_lr
 
 
-def _reference_grad(w, X, y, mask):
-    z = X.astype(np.float64) @ w
-    sig = 1.0 / (1.0 + np.exp(-z))
-    return ((sig - y) * mask) @ X
+def _problem(rows, dim, seed=0, masked=0):
+    rng = np.random.default_rng(seed)
+    # about 40 non-zeros a row, as a densified click log has: sums short
+    # enough that two float32 orders of summation agree to 1e-6
+    X = (rng.standard_normal((rows, dim))
+         * (rng.random((rows, dim)) < 40 / dim)).astype(np.float32)
+    y = rng.integers(0, 2, rows).astype(np.int32)
+    mask = np.ones(rows, np.float32)
+    if masked:
+        mask[-masked:] = 0
+    w = (rng.standard_normal(dim) * 0.1).astype(np.float32)
+    return tuple(jnp.asarray(a) for a in (w, X, y, mask))
 
 
-class TestFusedLRGrad:
-    def test_matches_reference_interpret(self):
-        """Run the kernel in interpreter mode (works on CPU) against a
-        float64 numpy oracle; bf16 inputs bound the tolerance."""
-        rng = np.random.default_rng(0)
-        B, D = 64, 256
-        X = rng.standard_normal((B, D)).astype(np.float32)
-        y = rng.integers(0, 2, B).astype(np.float64)
-        mask = np.ones(B)
-        mask[-10:] = 0
-        w = (rng.standard_normal(D) * 0.1).astype(np.float32)
-        g = np.asarray(
-            fused_lr_grad(
-                jnp.asarray(w), jnp.asarray(X), jnp.asarray(y.astype(np.int32)),
-                jnp.asarray(mask.astype(np.float32)), batch_tile=16, interpret=True,
-            )
-        )
-        g_ref = _reference_grad(w, X, y, mask)
-        rel = np.abs(g - g_ref).max() / np.abs(g_ref).max()
-        assert rel < 5e-2, f"rel err {rel}"
+def _rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return np.linalg.norm(a - b) / np.linalg.norm(b)
 
-    def test_accumulates_across_tiles(self):
-        """Gradient must equal the sum over batch tiles (grid revisiting
-        the same output block accumulates, not overwrites)."""
-        rng = np.random.default_rng(1)
-        B, D = 64, 128
-        X = rng.standard_normal((B, D)).astype(np.float32)
-        y = rng.integers(0, 2, B).astype(np.int32)
-        mask = np.ones(B, np.float32)
-        w = np.zeros(D, np.float32)
-        g_4tiles = np.asarray(
-            fused_lr_grad(jnp.asarray(w), jnp.asarray(X), jnp.asarray(y), jnp.asarray(mask),
-                          batch_tile=16, interpret=True)
-        )
-        g_1tile = np.asarray(
-            fused_lr_grad(jnp.asarray(w), jnp.asarray(X), jnp.asarray(y), jnp.asarray(mask),
-                          batch_tile=64, interpret=True)
-        )
-        np.testing.assert_allclose(g_4tiles, g_1tile, rtol=1e-3, atol=1e-3)
 
-    def test_supported_predicate(self):
-        assert fused_lr_supported(4096, 16384, 64)
-        assert not fused_lr_supported(4096, 1_000_000, 64)  # VMEM budget
-        assert not fused_lr_supported(100, 128, 64)  # B not divisible
-        assert not fused_lr_supported(64, 100, 16)   # D not mult of 128
-        assert not fused_lr_supported(64, 128, 8)    # tile not mult of 16
+def _float32_model(dim):
+    return BinaryLR(dim, compute_dtype="float32")
 
-    def test_supported_tracks_what_mosaic_compiled_on_the_chip(self):
-        """The budget counts the float32 copy of the X tile: these shapes
-        all passed the old (w + g + 2 bf16 tiles) estimate and Mosaic
-        refused every one on a v5e ("scoped allocation ... exceeded
-        scoped vmem limit", 16.02M to 25.33M against 16.00M); the last
-        three are the widest the budget admits per tile and compiled
-        there (chip_smoke.py's kernel leg compiles them on every run)."""
-        for shape in ((128, 32768, 64), (128, 49152, 64), (256, 24576, 128),
-                      (64, 196608, 16)):
-            assert not fused_lr_supported(*shape), shape
-        for shape in ((32, 116480, 16), (128, 31744, 64), (256, 16128, 128)):
-            assert fused_lr_supported(*shape), shape
 
-    @pytest.mark.parametrize("shape", [(512, 16384, 64), (4096, 16384, 64)])
-    def test_lowers_to_mosaic_for_tpu(self, shape):
-        """The chip smoke's two shapes lower for platforms=['tpu'] from
-        the CPU (lowering only: compiling needs the chip)."""
-        import functools
+def _limit_for(rows, dim, chunk_tiles, slots):
+    """The least VMEM limit under which ``slots`` chunk slots fit."""
+    lo, hi = 0, pallas_lr.VMEM_LIMIT_BYTES
+    while lo + 1 < hi:
+        mid = (lo + hi) // 2
+        plan = panel_plan(rows, dim, vmem_limit=mid, chunk_tiles=chunk_tiles)
+        if plan is not None and plan.slots >= slots:
+            hi = mid
+        else:
+            lo = mid
+    return hi
 
-        import jax
-        from jax import export
 
-        B, D, tile = shape
-        fn = functools.partial(fused_lr_grad, batch_tile=tile, interpret=False)
-        exported = export.export(jax.jit(fn), platforms=["tpu"])(
-            jax.ShapeDtypeStruct((D,), jnp.float32),
-            jax.ShapeDtypeStruct((B, D), jnp.bfloat16),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((B,), jnp.float32),
-        )
-        assert "tpu_custom_call" in exported.mlir_module()
+# rows, dim, chunk_tiles, slots of VMEM (None: the default limit, all held)
+SHAPES = [
+    pytest.param(16, 1000, 256, None, id="D1000-one-chunk"),
+    pytest.param(24, 16384 + 64, 8, None, id="D16448-17-chunks-held"),
+    pytest.param(24, 16384 + 64, 8, 10, id="D16448-8-of-17-held"),
+    pytest.param(16, 3000, 4, 3, id="D3000-1-of-6-held"),
+    pytest.param(8, 3000, 5, 4, id="D3000-one-panel-2-of-5-held"),
+]
 
-    def test_unsupported_raises(self):
-        with pytest.raises(ValueError, match="unsupported"):
-            fused_lr_grad(
-                jnp.zeros(100), jnp.zeros((64, 100)), jnp.zeros(64, jnp.int32),
-                jnp.ones(64), batch_tile=16,
-            )
+
+@pytest.mark.parametrize("rows,dim,chunk_tiles,slots", SHAPES)
+def test_equals_binary_lr_grad_in_float32(rows, dim, chunk_tiles, slots):
+    limit = (pallas_lr.VMEM_LIMIT_BYTES if slots is None
+             else _limit_for(rows, dim, chunk_tiles, slots))
+    plan = panel_plan(rows, dim, vmem_limit=limit, chunk_tiles=chunk_tiles)
+    assert plan.held == (plan.chunks if slots is None else slots - 2)
+    assert (plan.held_share == 1.0) == (slots is None)
+    assert plan.dim_padded % 128 == 0 and 0 <= plan.dim_padded - dim
+    w, X, y, mask = _problem(rows, dim, masked=3)
+    cfg = types.SimpleNamespace(l2_c=0.0, l2_scale_by_batch=False)
+    model = _float32_model(dim)
+    with jax.default_matmul_precision("highest"):
+        want = model.grad(w, (X, y, mask), cfg)
+    got = model.grad_panels(w, (pad_columns(X, plan), y, mask), cfg, plan,
+                            interpret=True)
+    assert got.shape == (dim,) and got.dtype == jnp.float32
+    assert _rel(got, want) < 1e-6
+
+
+@pytest.mark.parametrize("l2_c,by_batch", [(0.5, False), (0.5, True)])
+def test_l2_term_is_the_models(l2_c, by_batch):
+    rows, dim = 16, 1000
+    plan = panel_plan(rows, dim)
+    w, X, y, mask = _problem(rows, dim, seed=1, masked=5)
+    cfg = types.SimpleNamespace(l2_c=l2_c, l2_scale_by_batch=by_batch)
+    model = _float32_model(dim)
+    with jax.default_matmul_precision("highest"):
+        want = model.grad(w, (X, y, mask), cfg)
+        bare = model.grad(w, (X, y, mask),
+                          types.SimpleNamespace(l2_c=0.0,
+                                                l2_scale_by_batch=False))
+    got = model.grad_panels(w, (pad_columns(X, plan), y, mask), cfg, plan,
+                            interpret=True)
+    assert _rel(got, want) < 1e-6
+    assert _rel(want, bare) > 1e-2   # the term is there to be missed
+
+
+def test_masked_rows_contribute_nothing():
+    rows, dim = 16, 1000
+    plan = panel_plan(rows, dim)
+    w, X, y, mask = _problem(rows, dim, seed=2, masked=6)
+    garbage = X.at[-6:].set(1e6)
+    a = lr_grad_panels(w, pad_columns(X, plan), y, mask, plan, interpret=True)
+    b = lr_grad_panels(w, pad_columns(garbage, plan), y, mask, plan,
+                       interpret=True)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_pad_columns_never_reach_the_gradient():
+    """Whatever stands in the pad columns of X moves nothing in
+    ``g[:D]``: they meet zero weights forward and are cut backward."""
+    rows, dim = 16, 1000
+    plan = panel_plan(rows, dim)
+    w, X, y, mask = _problem(rows, dim, seed=3)
+    Xp = pad_columns(X, plan)
+    assert Xp.shape == (rows, plan.dim_padded)
+    assert not np.asarray(Xp[:, dim:]).any()
+    dirty = Xp.at[:, dim:].set(7.0)
+    a = lr_grad_panels(w, Xp, y, mask, plan, interpret=True)
+    b = lr_grad_panels(w, dirty, y, mask, plan, interpret=True)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_feature_scale_is_the_models():
+    rows, dim = 16, 1000
+    plan = panel_plan(rows, dim)
+    w, X, y, mask = _problem(rows, dim, seed=4)
+    cfg = types.SimpleNamespace(l2_c=0.1, l2_scale_by_batch=False)
+    model = BinaryLR(dim, compute_dtype="float32", feature_scale=0.25)
+    with jax.default_matmul_precision("highest"):
+        want = model.grad(w, (X, y, mask), cfg)
+    got = model.grad_panels(w, (pad_columns(X, plan), y, mask), cfg, plan,
+                            interpret=True)
+    assert _rel(got, want) < 1e-6
+
+
+def test_the_plan_follows_the_shape_and_the_limit():
+    cell = panel_plan(384, 1_000_000)
+    assert cell == PanelPlan(384, 1_000_000, 253, 31, 31,
+                             pallas_lr.VMEM_LIMIT_BYTES)
+    assert cell.held_share == 1.0 and cell.slots == 31
+    assert cell.dim_padded == 31 * 253 * 128 == 1_003_904
+    # half of VMEM: the partials, the weights and a part of a panel
+    half = panel_plan(384, 1_000_000, vmem_limit=64 << 20)
+    assert 0 < half.held_share < 1 and half.slots == half.held + 2
+    # no room beside the partials, or rows that are no sublane groups
+    assert panel_plan(384, 1_000_000, vmem_limit=40 << 20) is None
+    assert panel_plan(380, 1_000_000) is None
+    assert panel_plan(384, 4_000_000) is None   # 128 MB of partials
+
+
+def test_a_matrix_that_was_not_padded_is_refused():
+    plan = panel_plan(16, 1000)
+    w, X, y, mask = _problem(16, 1000)
+    with pytest.raises(ValueError, match="pad_columns"):
+        lr_grad_panels(w, X, y, mask, plan, interpret=True)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("limit", [None, 64 << 20],
+                         ids=["all-held", "part-held"])
+def test_compiles_for_a_v5e_at_the_cells_size(one_chip, limit):
+    """Mosaic takes the kernel at 384 x 1,000,000 float32 under the
+    default limit and under one that holds part of a panel, and XLA
+    hands it the resident shard as it lies: no copy, transpose or
+    reshape of the 1.5 GB operand in the step's program."""
+    rows, dim = 384, 1_000_000
+    plan = (panel_plan(rows, dim) if limit is None
+            else panel_plan(rows, dim, vmem_limit=limit))
+    cfg = types.SimpleNamespace(l2_c=1.0, l2_scale_by_batch=False)
+    model = BinaryLR(dim)
+
+    def ps_grad_step(w, Xp, y, mask):
+        return model.grad_panels(w, (Xp, y, mask), cfg, plan)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(ps_grad_step).lower(
+        spec((dim,), jnp.float32), spec((rows, plan.dim_padded), jnp.float32),
+        spec((rows,), jnp.int32), spec((rows,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    big = f"f32[{rows},{plan.dim_padded}]"
+    for line in text.splitlines():
+        if (big in line and re.match(r"\s*(ROOT )?%\S+ = ", line)
+                and "custom-call(" not in line):
+            assert " parameter(" in line, line
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

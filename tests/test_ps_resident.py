@@ -203,3 +203,131 @@ def test_whole_shard_is_nothing_where_a_batch_is_anything_else(kw):
 
     X = np.arange(12, dtype=np.float32).reshape(6, 2)
     assert DataIter(X, np.zeros(6), **{"batch_size": -1, **kw}).whole_shard() is None
+
+
+# -- the one-pass step of a resident shard (ops/pallas_lr.py) ---------------
+ROUNDS = "distlr_ps_grad_rounds_total"
+
+
+def _rounds(rank, path):
+    return get_registry().get(ROUNDS).labels(rank=str(rank), path=path).value
+
+
+@pytest.fixture
+def one_pass_on_the_cpu(monkeypatch):
+    """The selection as a TPU makes it, the kernel interpreted."""
+    from distlr_tpu.train import ps_trainer
+
+    monkeypatch.setattr(ps_trainer, "_ONE_PASS_PLATFORMS", ("tpu", "cpu"))
+
+
+def _one_pass_job(tmp_path, model="binary_lr", **kw):
+    # 96 rows a worker (whole sublane groups), a D that is no multiple of
+    # 128, float32 matmuls: XLA's program on the CPU rounds to bfloat16
+    # otherwise, and the comparison below is at 1e-6
+    return _job(tmp_path, model, 1, num_feature_dim=300,
+                compute_dtype="float32", l2_c=0.3,
+                **{"num_iteration": 4, **kw})
+
+
+def _write_rows(cfg, rows, **kw):
+    """``rows`` training rows (and a quarter as many to test on)."""
+    write_synthetic_shards(cfg.data_dir, rows * 5 // 4, cfg.num_feature_dim,
+                           num_parts=1, seed=6, sparsity=0.0, **kw)
+
+
+def test_a_resident_shard_takes_the_one_pass_step(tmp_path, monkeypatch,
+                                                  one_pass_on_the_cpu):
+    from distlr_tpu.train import ps_trainer
+
+    cfg = _one_pass_job(tmp_path)
+    _write_rows(cfg, 96)
+    before = family_total(H2D)
+    with _group(cfg) as group:
+        w = PSWorker(cfg, 0, group.hosts)
+        with monkeypatch.context() as m:
+            m.setattr(ps_trainer, "_ONE_PASS_PLATFORMS", ("tpu",))
+            parent = PSWorker(cfg, 0, group.hosts)
+            parent.load_data()
+        try:
+            w.load_data()
+            plan = w._panels
+            assert parent._panels is None and plan is not None
+            assert (plan.rows, plan.dim, plan.held_share) == (96, 300, 1.0)
+            X = w._resident[0]
+            assert X.shape == (96, plan.dim_padded) and X.dtype == np.float32
+            assert not np.asarray(X[:, 300:]).any()
+            assert np.array_equal(np.asarray(X[:, :300]),
+                                  np.asarray(parent._resident[0]))
+            # what crossed and what is counted as held: the shard's bytes
+            assert family_total(H2D) - before == 2 * _shard_bytes(w)
+            reg = get_registry()
+            assert (reg.get("distlr_ps_resident_bytes").labels(rank="0").value
+                    == _shard_bytes(w))
+            assert reg.get("distlr_ps_grad_panel_held").labels(
+                rank="0").value == 1.0
+            was = _rounds(0, "one_pass"), _rounds(0, "two_pass")
+            w.grad_step = _Recorder(w.grad_step)
+            w.run(save=False)
+            assert w.rounds == cfg.num_iteration
+            assert _rounds(0, "one_pass") - was[0] == cfg.num_iteration
+            for weights, pushed in w.grad_step.seen:
+                want = parent.grad_step(weights, parent._resident)
+                assert pushed.dtype == want.dtype == np.float32
+                assert pushed.shape == want.shape == (300,)
+                assert (np.linalg.norm(pushed - want)
+                        <= 1e-6 * np.linalg.norm(want))
+            assert _rounds(0, "two_pass") - was[1] == len(w.grad_step.seen)
+        finally:
+            w.close()
+            parent.close()
+
+
+@pytest.mark.parametrize("refusal", ["streamed", "softmax", "rows"])
+def test_the_selection_keeps_the_xla_step(tmp_path, one_pass_on_the_cpu,
+                                          refusal):
+    """A streamed batch, a softmax model and rows that are no whole
+    sublane groups each keep ``model.grad`` under XLA, counted so."""
+    kw = {"streamed": dict(batch_size=32),
+          "softmax": dict(model="softmax"),
+          "rows": {}}[refusal]
+    cfg = _one_pass_job(tmp_path, num_iteration=2, **kw)
+    _write_rows(cfg, 100 if refusal == "rows" else 96,
+                num_classes=cfg.num_classes)
+    was = _rounds(0, "one_pass"), _rounds(0, "two_pass")
+    with _group(cfg) as group:
+        w = PSWorker(cfg, 0, group.hosts)
+        try:
+            w.load_data()
+            assert w._panels is None
+            assert (w._resident is None) == (refusal == "streamed")
+            if w._resident is not None:
+                assert w._resident[0].shape == (w._train.num_samples, 300)
+            final = w.run(save=False)
+        finally:
+            w.close()
+    assert np.isfinite(final).all() and np.count_nonzero(final)
+    assert _rounds(0, "one_pass") == was[0]
+    assert _rounds(0, "two_pass") - was[1] == w.rounds > 0
+    assert get_registry().get("distlr_ps_grad_panel_held").labels(
+        rank="0").value == 0.0
+
+
+def test_the_selection_reads_the_model_the_device_and_the_shape():
+    import jax
+
+    from distlr_tpu.models.linear import BinaryLR, SoftmaxRegression
+    from distlr_tpu.train.ps_trainer import _one_pass_plan
+
+    tpu = type("Device", (), {"platform": "tpu"})()
+    plan = _one_pass_plan(BinaryLR(1_000_000), 384, 1_000_000, tpu)
+    assert plan.held_share == 1.0 and plan.dim_padded % 128 == 0
+    assert _one_pass_plan(BinaryLR(1_000_000, int8_dot=True),
+                          384, 1_000_000, tpu) is None
+    assert _one_pass_plan(SoftmaxRegression(1_000_000, 3),
+                          384, 1_000_000, tpu) is None
+    assert _one_pass_plan(BinaryLR(1_000_000), 380, 1_000_000, tpu) is None
+    assert _one_pass_plan(BinaryLR(4_000_000), 384, 4_000_000, tpu) is None
+    # the CPU never: the kernel runs there only interpreted, in tests
+    assert _one_pass_plan(BinaryLR(1_000_000), 384, 1_000_000,
+                          jax.devices()[0]) is None
