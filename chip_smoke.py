@@ -4,7 +4,8 @@
 Drives the three execution planes once, through the entry points a user
 reaches from ``python -m distlr_tpu.launch``, at D = 1,000,000 on the
 TPU this process finds: the SPMD trainer (dense and sparse), the
-parameter-server plane (native servers, Hogwild workers), the scoring
+parameter-server plane (native servers; Hogwild workers, then lock-step
+ones held to the benchmark's plain reference), the scoring
 server, and the Pallas kernel; on a host with four or more chips also
 the ``data`` x ``model`` mesh.  One process — it holds the chip — and
 the only children are the native KV servers.
@@ -46,6 +47,7 @@ class Sizes:
     sparse_epochs: int
     ps_batch: int
     ps_epochs: int
+    bsp_rows: int          # a BSP worker's whole resident shard
     kernel_rows: int       # of the row-panel kernel's shard, D = d wide
     kernel_interpret: bool
     timing_steps: int
@@ -58,6 +60,7 @@ FULL = Sizes(
     # 1e6 * 64 = 6.4e7 >= ps_trainer._PS_AUTO_CPU_THRESHOLD (2**25), so
     # ps_compute_backend=auto must put the step on the accelerator
     ps_batch=64, ps_epochs=2,
+    bsp_rows=128,          # x 1e6 is over the same threshold
     kernel_rows=384,       # a worker's shard in dense-ps-async-1chip
     kernel_interpret=False,
     timing_steps=20,
@@ -67,6 +70,7 @@ REHEARSAL = Sizes(
     dense_samples=640, dense_batch=64, dense_epochs=6,
     sparse_samples=2560, sparse_batch=1024, sparse_epochs=4,
     ps_batch=16, ps_epochs=2,
+    bsp_rows=16,
     kernel_rows=16,
     kernel_interpret=True,
     timing_steps=5,
@@ -336,6 +340,60 @@ class Smoke:
                 max(pinned, key=len).split("pinned: ")[1]),
         }
 
+    def ps_bsp(self) -> dict:
+        """The lock-step mode through the benchmark's own driver pieces:
+        two workers, two servers (``sync=1``), two whole-shard rounds;
+        every worker computes a round on the same weights, and the
+        weights after follow ``families/dense_ps_bsp.round``."""
+        import numpy as np
+
+        from chipbench import manifest
+        from chipbench.drivers import ps_bsp_epochs, ps_epochs
+        from chipbench.families import dense_ps_bsp
+
+        cell = manifest.Cell(manifest.load_benchmark(), "dense-ps-bsp-1chip")
+        conf = ps_bsp_epochs.effective_config(cell, not self.on_tpu)
+        conf = {**conf,
+                "generator": {**conf["generator"],
+                              "rows_per_worker": self.s.bsp_rows,
+                              "test_rows": 64},
+                "program": {**conf["program"], "num_workers": 2,
+                            "num_feature_dim": self.s.d}}
+        rounds, lr = 2, conf["program"]["learning_rate"]
+        job = ps_epochs.prepare(conf, 20260928, lambda t: print(f"  {t}"))
+        failed = True
+        try:
+            got = ps_bsp_epochs.record_rounds(job, rounds, rounds)
+            pinned, shards = job.pinned, job.shards
+            kept = {"shards": shards, "test": job.test}
+            ps_epochs.in_threads(job, lambda w: w.finish(save=False))
+            failed = False
+        finally:
+            job.close(failed)
+        if self.on_tpu:
+            for ln in pinned:
+                _check("train -> tpu:" in ln, ln)
+        compared = ps_bsp_epochs.compare(kept, got, conf["family"], lr,
+                                         conf["limits"])
+        bad = [r for r in compared if not r["ok"]]
+        _check(not bad, f"the rounds left the reference: {bad}")
+        want = got["w_before"]
+        for _ in range(rounds):
+            want = dense_ps_bsp.round(want, shards, lr)
+        moved = np.linalg.norm(got["w_after"] - got["w_before"])
+        off = np.linalg.norm(got["w_after"] - want) / moved
+        _check(off <= conf["limits"]["update_diff_rel"],
+               f"weights after {rounds} rounds are {off:.3g} of the move "
+               "off the reference")
+        by_name = {r["name"]: r["value"] for r in compared}
+        return {
+            "steps": sum(got["rounds"]),
+            "weights_disagree": int(by_name["weights_disagree"]),
+            "update_diff_rel": f"{by_name['update_diff_rel']:.2e}",
+            "after_rounds_off": f"{off:.2e}",
+            "round_miscount": int(by_name["round_miscount_recorded"]),
+        }
+
     def serve(self) -> dict:
         import numpy as np
 
@@ -549,6 +607,7 @@ def main(argv=None) -> int:
         legs = [("sync-dense", smoke.sync_dense),
                 ("sync-sparse", smoke.sync_sparse),
                 ("ps-async", smoke.ps_async),
+                ("ps-bsp", smoke.ps_bsp),
                 ("serve", smoke.serve),
                 ("kernel", smoke.kernel)]
         if dev["count"] >= 4:

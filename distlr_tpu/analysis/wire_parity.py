@@ -390,7 +390,7 @@ def check(root: str | None = None,
                     ((wrel, sline),)))
 
     findings += _check_store_format(root, hdr, hrel)
-    findings += _check_stats_fields(root, hdr, hrel)
+    findings += _check_stats_fields(root, hdr, hrel, hpath)
     findings += _check_codec_ids(root, hdr, hrel)
     findings += _check_raw_literals(root, hdr, hrel)
     return findings
@@ -466,7 +466,8 @@ def _check_store_format(root: str, hdr: dict, hrel: str) -> list[Finding]:
     return out
 
 
-def _check_stats_fields(root: str, hdr: dict, hrel: str) -> list[Finding]:
+def _check_stats_fields(root: str, hdr: dict, hrel: str,
+                        hpath: str) -> list[Finding]:
     """STATS_FIELDS in ps/client.py must track kStatsVals in length and
     reproduce the protocol's v1 counter order as its prefix."""
     cpath = os.path.join(root, "distlr_tpu", "ps", "client.py")
@@ -495,7 +496,33 @@ def _check_stats_fields(root: str, hdr: dict, hrel: str) -> list[Finding]:
             f"order {STATS_V1_ORDER[:v1_hdr]} (kStatsValsV1 = {v1_hdr}; "
             "old servers reply exactly these, in exactly this order)",
             ((crel, line), (hrel, v1line))))
+    if v1_hdr is not None:
+        out += _check_stats_tail(hpath, fields[v1_hdr:], (crel, line),
+                                 (hrel, hline))
     return out
+
+
+def _check_stats_tail(hpath: str, tail, csite, hsite) -> list[Finding]:
+    """The additive tail of STATS_FIELDS (everything after the v1 six):
+    the header's kStats comment is the one place the slots' order is
+    written down, so each name has to stand there, in the tuple's order."""
+    with open(hpath) as f:
+        text = f.read()
+    start = text.find("kStats response payload")
+    end = text.find("constexpr uint64_t kStatsValsV1")
+    block = text[start:end] if 0 <= start < end else ""
+    at = 0
+    for name in tail:
+        m = re.search(rf"\b{re.escape(name)}\b", block[at:])
+        if m is None:
+            return [Finding(
+                "wire", "stats-fields-tail-order",
+                f"STATS_FIELDS names {name!r} where the header's kStats "
+                "comment does not (or names it earlier): the tail's "
+                "order is the wire's, write it down in both",
+                (csite, hsite))]
+        at += m.end()
+    return []
 
 
 def _check_codec_ids(root: str, hdr: dict, hrel: str) -> list[Finding]:

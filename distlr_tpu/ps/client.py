@@ -159,9 +159,10 @@ def _observe_op(op: str, *, sent=0, received: int = 0,
 #: Order of the counters a server stats probe returns (kv_protocol.h).
 #: The ``cpu_*`` tail is the continuous-profiling extension: cumulative
 #: per-handler THREAD CPU seconds (CLOCK_THREAD_CPUTIME_ID around each
-#: dispatch) — fractional, so they stay floats in the stats dict while
-#: the v1 counters stay ints.  A pre-extension server replies only the
-#: first six; the probe reports what arrived.
+#: dispatch) — fractional, so every ``*_seconds`` counter stays a float
+#: in the stats dict while the others stay ints.  A pre-extension server
+#: replies only the first six, one from before the BSP tail eleven; the
+#: probe reports what arrived.
 STATS_FIELDS = (
     "dim",
     "initialized",
@@ -177,6 +178,14 @@ STATS_FIELDS = (
     # (kv_protocol.h kEpoch) — a probe of a migrating group reads the
     # flip rank by rank
     "epoch",
+    # the BSP barrier's additive tail (zeros from an async server):
+    # rounds released; seconds released pushes were held, arrival to own
+    # reply; seconds from a round's first arrival to its last; thread-CPU
+    # seconds of the release (also inside cpu_push_seconds)
+    "sync_rounds",
+    "sync_hold_seconds",
+    "sync_spread_seconds",
+    "cpu_release_seconds",
 )
 
 # The field list IS a wire mirror: its length must track kStatsVals and
@@ -1387,7 +1396,7 @@ class KVWorker:
             )
             self._check(n, "stats")
             return {
-                name: float(v) if name.startswith("cpu_") else int(v)
+                name: float(v) if name.endswith("_seconds") else int(v)
                 for name, v in zip(STATS_FIELDS, out[:n])
             }
 

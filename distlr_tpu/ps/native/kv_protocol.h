@@ -125,8 +125,16 @@ enum class Op : uint8_t {
 // server's current layout EPOCH — so one health probe shows a mixed-
 // epoch group mid-migration, and `distlr_ps_server_stat{stat="epoch"}`
 // scrapes the flip.
+// Slots 11-14 (the BSP barrier's tail, additive after `epoch`; zeros
+// from an async server), kept under the lock the sync branch already
+// holds: sync_rounds (rounds released), sync_hold_seconds (sum over
+// released pushes of the wall from a push's arrival to its own reply
+// written), sync_spread_seconds (sum over rounds of last arrival minus
+// first), cpu_release_seconds (thread CPU of the release: apply, clear,
+// W gathers and replies; it runs on the last voter's thread, so the
+// same cycles also stand in cpu_push_seconds).
 constexpr uint64_t kStatsValsV1 = 6;
-constexpr uint64_t kStatsVals = 11;
+constexpr uint64_t kStatsVals = 15;
 
 enum Flags : uint8_t {
   kNone = 0,

@@ -109,6 +109,7 @@
 
 #include <algorithm>
 #include <atomic>
+#include <chrono>
 #include <cmath>
 #include <condition_variable>
 #include <cstring>
@@ -137,6 +138,9 @@ struct PendingPush {
   // kPushPull: the deferred reply carries the post-round weights for
   // this push's keys (the fused pull half) instead of an empty frame.
   bool want_vals = false;
+  // BSP: when the push joined the round (MonoNowS), for the barrier's
+  // hold and spread counters (kStats sync_* tail).
+  double arrived_s = 0.0;
 };
 
 struct FtrlParams {
@@ -158,6 +162,20 @@ inline double WallNowS() {
   timeval tv{};
   gettimeofday(&tv, nullptr);
   return static_cast<double>(tv.tv_sec) + 1e-6 * tv.tv_usec;
+}
+
+// Monotonic seconds: intervals inside one process (the BSP barrier's
+// hold and spread), never compared across hosts.
+inline double MonoNowS() {
+  return std::chrono::duration<double>(
+             std::chrono::steady_clock::now().time_since_epoch())
+      .count();
+}
+
+inline double ThreadCpuNowS() {
+  timespec ts{};
+  clock_gettime(CLOCK_THREAD_CPUTIME_ID, &ts);
+  return static_cast<double>(ts.tv_sec) + 1e-9 * ts.tv_nsec;
 }
 
 // Per-handler thread-CPU accounting slots (the kStats extension and
@@ -1062,10 +1080,16 @@ class KVServer {
     // in merge_ with no pending entry — DropConnection's rollback could
     // never remove it, and the worker's retry would count twice.
     if (merge_.size() < weights_.size()) merge_.resize(weights_.size(), 0.0f);
-    pending_.push_back({fd, h, keys, vals, reply_weights});
+    pending_.push_back({fd, h, keys, vals, reply_weights, MonoNowS()});
     for (size_t i = 0; i < keys.size(); ++i) merge_[keys[i]] += vals[i];
 
     if (static_cast<int>(pending_.size()) == num_workers_) {
+      // The round's counters (kStats sync_* tail, kv_protocol.h): the
+      // release runs on this voter's thread, so its thread-CPU is also
+      // inside cpu_push_seconds.
+      const double release_cpu0 = ThreadCpuNowS();
+      // pending_ is in arrival order (pushed under mu_)
+      sync_spread_s_ += pending_.back().arrived_s - pending_.front().arrived_s;
       const float w = static_cast<float>(num_workers_);
       if (last_gradient_) {
         // Q1 compat: apply only ONE worker's gradient / W (the reference
@@ -1139,7 +1163,12 @@ class KVServer {
         } else {
           Respond(p.fd, p.header, nullptr, 0);
         }
+        // held from its arrival until its own reply was written: the
+        // later a push stands in the release, the longer
+        sync_hold_s_ += MonoNowS() - p.arrived_s;
       }
+      ++sync_rounds_;
+      cpu_release_s_ += ThreadCpuNowS() - release_cpu0;
     }
   }
 
@@ -1258,6 +1287,13 @@ class KVServer {
       // slot 10 (the membership round): this rank's layout epoch — a
       // health probe of a migrating group reads the flip rank by rank
       stats[kStatsValsV1 + kCpuSlots] = static_cast<double>(epoch_);
+      // slots 11-14 (the BSP barrier's tail, additive like the rest;
+      // zeros from an async server)
+      double* sync = stats + kStatsValsV1 + kCpuSlots + 1;
+      sync[0] = static_cast<double>(sync_rounds_);
+      sync[1] = sync_hold_s_;
+      sync[2] = sync_spread_s_;
+      sync[3] = cpu_release_s_;
     }
     // per-handler thread-CPU seconds (the continuous-profiling
     // extension; atomic — no mu_ needed)
@@ -2048,6 +2084,14 @@ class KVServer {
   std::vector<Val> z_;
   std::vector<Val> nacc_;
   std::vector<PendingPush> pending_;
+  //: BSP round counters (guarded by mu_; kStats sync_* tail): rounds
+  //: released, seconds released pushes were held (arrival to own
+  //: reply written), seconds between a round's first and last arrival,
+  //: thread-CPU seconds of the release (apply, clear, gathers, replies)
+  uint64_t sync_rounds_ = 0;
+  double sync_hold_s_ = 0.0;
+  double sync_spread_s_ = 0.0;
+  double cpu_release_s_ = 0.0;
   std::unordered_map<uint16_t, std::vector<PendingPush>> barrier_;
   std::set<uint16_t> released_barriers_;
 };

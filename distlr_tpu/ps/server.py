@@ -53,6 +53,31 @@ _SERVER_CPU = _reg.gauge(
     "health probe",
     labelnames=("rank", "handler"),
 )
+#: The BSP barrier's counters (the kStats ``sync_*`` tail), mirrored by
+#: every health() probe; an async group reads zeros.
+_SERVER_SYNC = {
+    "sync_rounds": _reg.gauge(
+        "distlr_ps_server_sync_rounds",
+        "BSP rounds this server rank has released (one update applied "
+        "and every deferred reply sent), from the latest health probe",
+        labelnames=("rank",)),
+    "sync_hold_seconds": _reg.gauge(
+        "distlr_ps_server_sync_hold_seconds",
+        "cumulative seconds released pushes were held at the BSP "
+        "barrier, each from its arrival to its own reply written",
+        labelnames=("rank",)),
+    "sync_spread_seconds": _reg.gauge(
+        "distlr_ps_server_sync_spread_seconds",
+        "cumulative seconds between a BSP round's first and last "
+        "arrival at this server rank",
+        labelnames=("rank",)),
+    "cpu_release_seconds": _reg.gauge(
+        "distlr_ps_server_sync_release_cpu_seconds",
+        "cumulative thread CPU seconds of the BSP release (apply, "
+        "clear, the W gathers and replies); also inside "
+        "distlr_kv_server_cpu_seconds{handler=\"push\"}",
+        labelnames=("rank",)),
+}
 _SUP_EVENTS = _reg.counter(
     "distlr_ps_supervisor_events_total",
     "supervisor audit-trail events (respawned/reseeded/seeded-zeros/"
@@ -727,7 +752,9 @@ class ServerGroup:
         for rank, s in enumerate(stats):
             for name, val in s.items():
                 _SERVER_STAT.labels(rank=rank, stat=name).set(val)
-                if name.startswith("cpu_") and name.endswith("_seconds"):
+                if name in _SERVER_SYNC:
+                    _SERVER_SYNC[name].labels(rank=rank).set(val)
+                elif name.startswith("cpu_") and name.endswith("_seconds"):
                     _SERVER_CPU.labels(
                         rank=rank,
                         handler=name[len("cpu_"):-len("_seconds")],

@@ -73,8 +73,9 @@ CAP_EPOCH = 1 << 9
 # --- kStats reply shape ------------------------------------------------
 #: the original six integer counters every vintage replies (kStatsValsV1)
 STATS_VALS_V1 = 6
-#: current stats count: v1 six + 4 per-handler CPU seconds + epoch
-STATS_VALS = 11
+#: current stats count: v1 six + 4 per-handler CPU seconds + epoch + the
+#: BSP barrier's four (rounds, hold, spread, release CPU)
+STATS_VALS = 15
 
 #: wire-corruption guard for vals_per_key (kMaxValsPerKey)
 MAX_VALS_PER_KEY = 4096

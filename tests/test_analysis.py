@@ -78,7 +78,7 @@ class TestWireParity:
         hdr = wire_parity.parse_header()
         assert hdr["kMagic"][0] == 0xD157C0DE
         assert hdr["kEpoch"][0] == 8
-        assert hdr["kStatsVals"][0] == 11
+        assert hdr["kStatsVals"][0] == 15
         assert hdr["kCapEpoch"][0] == 1 << 9       # 1ull << evaluation
         assert hdr["sizeof(MsgHeader)"][0] == 24   # static_assert twin
 
@@ -116,6 +116,20 @@ class TestWireParity:
             mutate_client=lambda s: s.replace('    "epoch",\n', ""))
         keys = {f.key for f in wire_parity.check(root=root)}
         assert "stats-fields-length" in keys
+
+    def test_stats_fields_tail_out_of_the_headers_order_fails(self, tmp_path):
+        """The additive tail's order is written down in the header's
+        kStats comment alone: two tail names swapped in the client (the
+        length still right) is the drift the length check cannot see."""
+        def swap(s):
+            a, b = '    "sync_hold_seconds",\n', '    "sync_spread_seconds",\n'
+            assert a + b in s
+            return s.replace(a + b, b + a)
+
+        root = _wire_fixture(tmp_path, mutate_client=swap)
+        keys = {f.key for f in wire_parity.check(root=root)}
+        assert "stats-fields-tail-order" in keys
+        assert "stats-fields-length" not in keys
 
     def test_protocol_model_is_a_framing_site(self, tmp_path):
         """ISSUE 14 satellite: a protocol literal re-inlined inside

@@ -116,6 +116,10 @@ def test_spans_of_four_threads_do_not_nest_into_each_other(data_dir):
 @pytest.mark.parametrize("mode,kw,has,lacks", [
     ("serialized", dict(ps_pipeline=False), {"pull", "push"}, {"wire"}),
     ("fused-bsp", dict(sync_mode=True), {"push"}, {"wire"}),
+    # the benchmark's BSP cell: a resident shard, the async round's spans
+    # on the loop's own thread and no comm thread
+    ("fused-bsp-resident", dict(sync_mode=True, sync_last_gradient=False),
+     {"push", "pull", "shard_put", "w_put", "grad_d2h"}, {"wire", "h2d"}),
     ("minibatch", dict(batch_size=32), {"h2d", "wire"}, {"shard_put"}),
     ("numpy", dict(ps_compute_backend="numpy"), {"compute", "wire"},
      {"shard_put", "w_put", "grad_d2h", "h2d"}),
@@ -134,6 +138,34 @@ def test_each_loop_variant_records_the_spans_it_has(data_dir, mode, kw, has,
     for rank in range(WORKERS):
         got = sorted(e["args"]["step"] for e in steps[rank])
         assert got == list(range(1, len(got) + 1)) and got
+    if mode != "fused-bsp-resident":
+        return
+    # every span of the round a step, on one thread a rank, and the one
+    # nesting there is: the placement inside the load
+    per_rank = _by(events, lambda e: (e["name"], e["args"]["rank"]))
+    ids = {e["args"]["id"]: e for e in events}
+    computed = _by([e for e in events if e["name"] == "compute"],
+                   lambda e: e["args"]["step"])
+    for rank in range(WORKERS):
+        for name in ROUND:
+            got = sorted(e["args"]["step"] for e in per_rank[name, rank]
+                         if e["args"]["step"])
+            assert got == list(range(1, ITERATIONS + 1)), (name, rank, got)
+        assert len({e["tid"] for name in ROUND
+                    for e in per_rank[name, rank]}) == 1
+        # the fused push-pull ends after the slowest worker's gradient of
+        # that round was ready: the barrier is inside the span
+        for e in per_rank["push", rank]:
+            if e["args"]["step"]:
+                ready = max(c["ts"] + c["dur"]
+                            for c in computed[e["args"]["step"]])
+                assert e["ts"] + e["dur"] >= ready - 1
+    nested = [e for e in events if "parent" in e["args"]]
+    assert sorted(e["name"] for e in nested) == ["shard_put"] * WORKERS
+    for e in nested:
+        parent = ids[e["args"]["parent"]]
+        assert (parent["name"], parent["args"]["rank"]) == (
+            "load_data", e["args"]["rank"])
 
 
 def test_a_profiler_trace_holds_the_workers_spans(data_dir, tmp_path):

@@ -1,0 +1,125 @@
+"""The BSP barrier's counters: the additive kStats tail (``sync_rounds``,
+``sync_hold_seconds``, ``sync_spread_seconds``, ``cpu_release_seconds``),
+its mirror in the registry, and a reply from before the tail."""
+
+import socket
+import struct
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from distlr_tpu.obs.registry import get_registry
+from distlr_tpu.ps import KVWorker, ServerGroup, wire
+from distlr_tpu.ps.client import STATS_FIELDS
+
+DIM, WORKERS, ROUNDS = 64, 3, 4
+TAIL = ("sync_rounds", "sync_hold_seconds", "sync_spread_seconds",
+        "cpu_release_seconds")
+
+
+def _rounds(group, sync, delays):
+    """``ROUNDS`` fused push-pulls a worker, worker ``r`` late by
+    ``delays[r]`` seconds a round; each server's stats before and after."""
+    with KVWorker(group.hosts, DIM, client_id=0xFC00) as probe:
+        probe.wait(probe.push_init(np.ones(DIM, np.float32)))
+        before = [probe.stats(r) for r in range(group.num_servers)]
+        workers = [KVWorker(group.hosts, DIM, client_id=r, sync_group=sync)
+                   for r in range(WORKERS)]
+
+        def loop(w, delay):
+            for _ in range(ROUNDS):
+                time.sleep(delay)
+                w.push_pull(np.full(DIM, 0.5, np.float32))
+
+        threads = [threading.Thread(target=loop, args=(w, d))
+                   for w, d in zip(workers, delays)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        for w in workers:
+            w.close()
+        return before, [probe.stats(r) for r in range(group.num_servers)]
+
+
+def test_the_tail_stands_after_epoch_in_the_wires_order():
+    assert STATS_FIELDS[-len(TAIL):] == TAIL
+    assert STATS_FIELDS[-len(TAIL) - 1] == "epoch"
+    assert len(STATS_FIELDS) == wire.STATS_VALS == 15
+
+
+@pytest.mark.parametrize("sync", [True, False], ids=["bsp", "async"])
+def test_a_server_counts_its_rounds_and_an_async_one_reports_zeros(sync):
+    late = 0.02
+    with ServerGroup(2, WORKERS, DIM, sync=sync) as g:
+        before, after = _rounds(g, sync, [0.0, late / 2, late])
+        health = g.health()
+    for b, a, h in zip(before, after, health):
+        assert a["total_pushes"] - b["total_pushes"] == WORKERS * ROUNDS
+        assert all(b[name] == 0 for name in TAIL)
+        assert isinstance(a["sync_rounds"], int)
+        assert all(isinstance(a[name], float) for name in TAIL[1:])
+        if not sync:
+            assert all(a[name] == 0 for name in TAIL)
+            continue
+        # one release a round a server
+        assert a["sync_rounds"] == ROUNDS == h["sync_rounds"]
+        assert a["pending_sync_pushes"] == 0
+        # the last worker is `late` behind the first every round, and
+        # every push waits at least for the rest of its round
+        assert a["sync_spread_seconds"] >= 0.8 * late * ROUNDS
+        assert a["sync_hold_seconds"] >= a["sync_spread_seconds"] >= 0
+        assert a["sync_hold_seconds"] <= WORKERS * (
+            a["sync_spread_seconds"] + 1.0)
+        # the release's cycles are the push handler's too
+        assert 0 < a["cpu_release_seconds"] <= a["cpu_push_seconds"]
+    reg = get_registry()
+    for rank, a in enumerate(after):
+        for stat, series in (
+                ("sync_rounds", "distlr_ps_server_sync_rounds"),
+                ("sync_hold_seconds", "distlr_ps_server_sync_hold_seconds"),
+                ("sync_spread_seconds",
+                 "distlr_ps_server_sync_spread_seconds"),
+                ("cpu_release_seconds",
+                 "distlr_ps_server_sync_release_cpu_seconds")):
+            mirrored = dict(reg.get(series).children())[(str(rank),)].value
+            assert mirrored == pytest.approx(a[stat])
+    # the release is not a handler of its own: its cycles are the push's
+    handlers = {labels[1] for labels, _c in reg.get(
+        "distlr_kv_server_cpu_seconds").children()}
+    assert "release" not in handlers
+
+
+def _serve_a_reply_of(listener, slots):
+    """One connection of a server from before the tail: whatever the
+    request's aux asks for, a kStats reply of ``slots`` counters."""
+    conn, _ = listener.accept()
+    with conn:
+        while True:
+            hdr = conn.recv(wire.HEADER_STRUCT.size, socket.MSG_WAITALL)
+            if len(hdr) < wire.HEADER_STRUCT.size:
+                return
+            magic, op, _flags, _aux, cid, ts, _n = wire.HEADER_STRUCT.unpack(hdr)
+            assert magic == wire.MAGIC
+            n = slots if op == wire.OP_STATS else 0
+            conn.sendall(wire.HEADER_STRUCT.pack(
+                wire.MAGIC, op, wire.FLAG_RESPONSE, 0, cid, ts, 2 * n)
+                + struct.pack(f"<{n}d", *range(1, n + 1)))
+
+
+@pytest.mark.parametrize("slots", [wire.STATS_VALS_V1, 11])
+def test_a_reply_from_before_the_tail_still_parses(slots):
+    with socket.socket() as listener:
+        listener.bind(("127.0.0.1", 0))
+        listener.listen(1)
+        port = listener.getsockname()[1]
+        server = threading.Thread(target=_serve_a_reply_of,
+                                  args=(listener, slots), daemon=True)
+        server.start()
+        with KVWorker(f"127.0.0.1:{port}", 8, client_id=3) as kv:
+            got = kv.stats(0)
+        server.join(timeout=5)
+    assert list(got) == list(STATS_FIELDS[:slots])
+    assert got["total_pushes"] == 5 and not set(TAIL) & set(got)
