@@ -161,8 +161,8 @@ def _observe_op(op: str, *, sent=0, received: int = 0,
 #: per-handler THREAD CPU seconds (CLOCK_THREAD_CPUTIME_ID around each
 #: dispatch) — fractional, so every ``*_seconds`` counter stays a float
 #: in the stats dict while the others stay ints.  A pre-extension server
-#: replies only the first six, one from before the BSP tail eleven; the
-#: probe reports what arrived.
+#: replies only the first six, one from before the BSP tail eleven, one
+#: from before ``run_frames`` fifteen; the probe reports what arrived.
 STATS_FIELDS = (
     "dim",
     "initialized",
@@ -186,6 +186,11 @@ STATS_FIELDS = (
     "sync_hold_seconds",
     "sync_spread_seconds",
     "cpu_release_seconds",
+    # of the operations total_pushes and total_pulls count, those whose
+    # keys were one ascending consecutive run, handled as the range of
+    # slots it is (a fused push-pull stands in both totals, so twice
+    # here): every default-key op of a dense worker, no scattered frame
+    "run_frames",
 )
 
 # The field list IS a wire mirror: its length must track kStatsVals and
@@ -1040,9 +1045,11 @@ class KVWorker:
         servers.  The key frame shrinks from ``dim`` u64s to
         ``dim/vpk`` — at D=1M over two servers vpk = 4,000: 1 KB of
         keys a server where the flat set is 4 MB, beside 2 MB of
-        values.  The server expands the rows at its parsing layer
-        (kv_protocol.h), so what it applies and replies is what the
-        flat keys would have got, bit for bit.  ``(all flat keys, 1)``
+        values.  The server walks the rows as they stand and, these
+        keys being one consecutive run, handles the frame as the range
+        of slots it is (kv_protocol.h; kStats ``run_frames``): what it
+        applies and replies is what the flat keys would have got, bit
+        for bit.  ``(all flat keys, 1)``
         when no divisor aligns (a prime D above the cap, three servers
         over a D that 3 does not divide).  Cached; a re-route drops the
         cache (:meth:`_apply_layout`)."""

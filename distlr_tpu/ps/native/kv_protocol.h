@@ -26,9 +26,13 @@
 // key space) address it as runs of the largest vals_per_key that
 // divides dim and every range boundary (ps/client.py
 // _dense_row_encoding; at D=1M over two servers 125 keys a server
-// where the flat frame has 500,000).  The server expands at the
-// parsing layer, so merge/barrier/rollback semantics are
-// byte-identical to a client that expanded the keys itself.
+// where the flat frame has 500,000).  The server never writes the flat
+// keys out: its handlers walk the rows (kv_server.cc Rows), every slot
+// getting the float32 operations, in the order, that a client's own
+// expanded keys would have given it, so apply/merge/barrier/rollback
+// semantics are byte-identical; a frame whose keys are one ascending
+// consecutive run is handled as the one range of slots it is (kStats
+// run_frames counts those).
 //
 // Semantics mirror the reference server handle (src/main.cc:41-96):
 //   * first PUSH initializes server weights (src/main.cc:50-56)
@@ -133,8 +137,16 @@ enum class Op : uint8_t {
 // first), cpu_release_seconds (thread CPU of the release: apply, clear,
 // W gathers and replies; it runs on the last voter's thread, so the
 // same cycles also stand in cpu_push_seconds).
+// Slot 15 (additive after the barrier's tail): run_frames, of the
+// operations total_pushes and total_pulls count, those whose keys were
+// one ascending consecutive run (keys[i] == keys[0] + i), handled as
+// the range [keys[0]*vpk, (keys[0] + num_keys)*vpk) it is: one loop to
+// apply or merge, one copy to reply.  A fused push-pull stands in both
+// totals and so counts twice here; run_frames over the rise of the two
+// totals is the share of a job's traffic on that path (1 for a dense
+// worker's default-key ops, 0 for scattered keys).
 constexpr uint64_t kStatsValsV1 = 6;
-constexpr uint64_t kStatsVals = 15;
+constexpr uint64_t kStatsVals = 16;
 
 enum Flags : uint8_t {
   kNone = 0,

@@ -102,6 +102,7 @@
 #include <sys/socket.h>
 #include <sys/stat.h>
 #include <sys/time.h>
+#include <sys/uio.h>
 #include <time.h>
 #include <unistd.h>
 
@@ -127,20 +128,54 @@
 
 namespace distlr {
 
+// A keyed frame as the rows it is (kv_protocol.h vals_per_key): row key
+// k owns the vpk consecutive flat slots [k*vpk, (k+1)*vpk), and the
+// frame's values stand row after row in frame order.  No handler
+// writes the flat keys out; the WAL record's writer alone does.
+struct Rows {
+  const Key* keys = nullptr;
+  uint64_t num_keys = 0;
+  uint64_t vpk = 1;
+  // keys[i] == keys[0] + i: the frame is the one range
+  // [keys[0]*vpk, (keys[0] + num_keys)*vpk) of flat slots
+  bool run = false;
+
+  uint64_t flat() const { return num_keys * vpk; }
+
+  // f(slot, at, n) for each stretch of n consecutive flat slots from
+  // `slot`, whose values stand at [at, at + n) of the frame: the whole
+  // frame at once where it is a run, else row by row in frame order
+  // (so a duplicate key meets its values in the order they were sent).
+  template <typename F>
+  void ForSpans(F f) const {
+    if (run) {
+      f(keys[0] * vpk, uint64_t{0}, flat());
+      return;
+    }
+    for (uint64_t i = 0; i < num_keys; ++i) f(keys[i] * vpk, i * vpk, vpk);
+  }
+};
+
 struct PendingPush {
   int fd;
   MsgHeader header;       // echoed back (with kResponse) on release
   // The pushed gradient is kept so a disconnecting worker's contribution
   // can be rolled back out of the merge buffer (worker-restart recovery;
-  // the reference has no such path — SURVEY.md §5.3).
-  std::vector<Key> keys;
-  std::vector<Val> vals;
+  // the reference has no such path — SURVEY.md §5.3): the row keys as
+  // sent (rows() views them) and the values, which are the frame's own
+  // buffer moved here, not a copy.
+  std::vector<Key> keys{};
+  uint64_t vpk = 1;
+  bool run = false;
+  std::vector<Val> vals{};
   // kPushPull: the deferred reply carries the post-round weights for
   // this push's keys (the fused pull half) instead of an empty frame.
   bool want_vals = false;
   // BSP: when the push joined the round (MonoNowS), for the barrier's
   // hold and spread counters (kStats sync_* tail).
   double arrived_s = 0.0;
+
+  Rows rows() const { return {keys.data(), keys.size(), vpk, run}; }
 };
 
 struct FtrlParams {
@@ -219,6 +254,7 @@ class KVServer {
         store_wal_fsync_s_(store_wal_fsync_s), epoch_(epoch),
         opt_segments_(std::move(opt_segments)) {
     weights_.resize(dim, 0.0f);
+    spare_vals_.reserve(static_cast<size_t>(std::max(num_workers, 0)));
     has_ftrl_ = opt_ == Opt::kFtrl;
     for (const auto& seg : opt_segments_) {
       if (seg.second == Opt::kFtrl) has_ftrl_ = true;
@@ -548,9 +584,11 @@ class KVServer {
 
   void ServeLoop(int fd) {
     std::vector<Key> keys;
-    std::vector<Key> expanded;
     std::vector<Val> vals;
     std::vector<uint8_t> coded;
+    // the values of a reply, copied under mu_ and written after it is
+    // released: this connection's own, so a frame allocates nothing
+    std::vector<Val> reply;
     while (true) {
       MsgHeader h{};
       if (!ReadFull(fd, &h, sizeof(h)) || h.magic != kMagic) break;
@@ -562,8 +600,8 @@ class KVServer {
       timespec cpu0{};
       clock_gettime(CLOCK_THREAD_CPUTIME_ID, &cpu0);
       // Trace trailer (kv_protocol.h kTraced): stripped HERE, at the
-      // parsing layer — like vpk expansion and codec decode, so every
-      // handler sees exactly the frame an untraced client sent.  A
+      // parsing layer — like codec decode, so every handler sees
+      // exactly the frame an untraced client sent.  A
       // kHello never carries the trailer (its kTraced flag only asks
       // for a clock in the reply).
       TraceFrame tf{};
@@ -573,10 +611,10 @@ class KVServer {
       const double tr_t0 = traced ? WallNowS() : 0.0;
       double tr_decoded = tr_t0;
       // vals_per_key (kv_protocol.h): each key addresses vpk consecutive
-      // flat slots starting at key*vpk.  Expansion happens HERE, at the
-      // parsing layer, so every handler below (merge, barrier release,
-      // disconnect rollback) sees exactly the per-lane keys a legacy
-      // client would have sent — the semantics cannot diverge.
+      // flat slots starting at key*vpk.  The handlers below (apply,
+      // merge, barrier release, disconnect rollback, replies) walk that
+      // form (Rows) and give every slot what the per-lane keys of a
+      // legacy client would have given it, in the same order.
       const bool keyed_op =
           op == Op::kPush || op == Op::kPull || op == Op::kPushPull;
       const uint64_t vpk = keyed_op && h.aux > 1 ? h.aux : 1;
@@ -588,8 +626,8 @@ class KVServer {
       // check alone cannot catch a frame whose header is intact but
       // whose counts are corrupt.  Guards: vals_per_key capped
       // (kMaxValsPerKey), num_keys * vals_per_key capped by max_dim_
-      // AND read chunk-by-chunk (see ReadChunked), every EXPANDED key
-      // id capped by max_dim_, and capacity grown to the frame's MAX
+      // AND read chunk-by-chunk (see ReadChunked), every key's LAST
+      // flat slot capped by max_dim_, and capacity grown to the frame's MAX
       // key — not its last, the wire does not promise sorted keys, and
       // an unsorted frame passing a back()-based bound would be an
       // out-of-bounds heap write.
@@ -604,14 +642,18 @@ class KVServer {
         break;
       }
       if (!ReadChunked(fd, keys, h.num_keys)) break;
-      // a key's WHOLE expanded range [k*vpk, (k+1)*vpk) must fit below
+      // a key's WHOLE range [k*vpk, (k+1)*vpk) must fit below
       // max_dim_: k < max_dim_ / vpk  =>  k*vpk + vpk - 1 < max_dim_
       const Key key_cap = max_dim_ / vpk;
       Key max_key = 0;
       bool keys_ok = true;
+      // one ascending consecutive run of keys is one range of flat
+      // slots (every default-key op of a dense worker: Rows::run)
+      bool run = h.num_keys > 0;
       for (uint64_t i = 0; i < h.num_keys; ++i) {
         if (keys[i] >= key_cap) { keys_ok = false; break; }
         if (keys[i] > max_key) max_key = keys[i];
+        if (keys[i] != keys[0] + i) run = false;
       }
       if (!keys_ok) {
         std::fprintf(stderr,
@@ -621,29 +663,16 @@ class KVServer {
                      (unsigned long long)vpk);
         break;
       }
-      const std::vector<Key>* use_keys = &keys;
-      uint64_t n_flat = h.num_keys;
-      if (vpk > 1) {
-        n_flat = h.num_keys * vpk;
-        expanded.resize(n_flat);
-        for (uint64_t i = 0; i < h.num_keys; ++i) {
-          const Key base = keys[i] * vpk;
-          for (uint64_t j = 0; j < vpk; ++j) expanded[i * vpk + j] = base + j;
-        }
-        max_key = max_key * vpk + vpk - 1;
-        use_keys = &expanded;
-      }
-      // Handlers reply with h.num_keys-independent sizes (vals counts),
-      // but the echoed header must describe the EXPANDED frame so
-      // deferred-release bookkeeping stays uniform.
-      MsgHeader hf = h;
-      hf.num_keys = n_flat;
+      const Rows rows{keys.data(), h.num_keys, vpk, run};
+      const uint64_t n_flat = rows.flat();
+      // the frame's highest flat slot, for EnsureCapacity
+      max_key = max_key * vpk + vpk - 1;
       if (op == Op::kPush || op == Op::kPushPull) {
         // Wire codec (kv_protocol.h): a coded push's value payload is
-        // decoded HERE, at the parsing layer — like vpk expansion, so
-        // every handler below (merge, rollback, optimizer, deferred
-        // release) sees exactly the dense f32 values a legacy client
-        // would have sent and the semantics cannot diverge.  A codec
+        // decoded HERE, at the parsing layer, so every handler below
+        // (merge, rollback, optimizer, deferred release) sees exactly
+        // the dense f32 values a legacy client would have sent and the
+        // semantics cannot diverge.  A codec
         // this server never advertised (negotiation is the only legal
         // path to these bits) is wire corruption: drop the connection.
         const uint8_t codec = CodecOf(h.flags);
@@ -680,9 +709,10 @@ class KVServer {
           continue;  // payload fully read above — the stream stays framed
         }
         if (opt_state) {
-          HandleOptStatePush(fd, hf, *use_keys, vals, max_key);
+          HandleOptStatePush(fd, h, rows, vals, max_key);
         } else {
-          HandlePush(fd, hf, *use_keys, vals, max_key, op == Op::kPushPull);
+          HandlePush(fd, h, rows, vals, reply, max_key,
+                     op == Op::kPushPull);
         }
         if (traced) {
           TraceLog(op == Op::kPushPull ? "kv.push_pull" : "kv.push", tf,
@@ -696,9 +726,9 @@ class KVServer {
           continue;
         }
         if (h.flags & kOptState) {
-          HandleOptStatePull(fd, hf, *use_keys, max_key);
+          HandleOptStatePull(fd, h, rows, reply, max_key);
         } else {
-          HandlePull(fd, hf, *use_keys, max_key);
+          HandlePull(fd, h, rows, reply, max_key);
         }
         if (traced) {
           TraceLog("kv.pull", tf, tr_t0, tr_decoded, WallNowS(), n_flat,
@@ -822,9 +852,25 @@ class KVServer {
     // so the echoed header describes the frame actually sent
     h.flags = static_cast<uint8_t>((h.flags | kResponse) & ~kTraced);
     h.num_keys = nvals;
-    // Responses carry vals only (keys are implied by the request).
-    WriteFull(fd, &h, sizeof(h));
-    if (nvals) WriteFull(fd, vals, nvals * sizeof(Val));
+    // Responses carry vals only (keys are implied by the request);
+    // header and payload leave in one writev.
+    iovec iov[2] = {{&h, sizeof(h)},
+                    {const_cast<Val*>(vals), nvals * sizeof(Val)}};
+    iovec* at = iov;
+    int left = nvals ? 2 : 1;
+    while (left > 0) {
+      ssize_t r = writev(fd, at, left);
+      if (r <= 0) return;
+      while (left > 0 && static_cast<size_t>(r) >= at->iov_len) {
+        r -= static_cast<ssize_t>(at->iov_len);
+        ++at;
+        --left;
+      }
+      if (left > 0) {
+        at->iov_base = static_cast<char*>(at->iov_base) + r;
+        at->iov_len -= static_cast<size_t>(r);
+      }
+    }
   }
 
   // Explicit protocol-level rejection (kError): the stream stays framed
@@ -982,106 +1028,168 @@ class KVServer {
     return opt_;
   }
 
-  // Apply one gradient value to one coordinate under the configured
-  // optimizer — THE pluggable update this server exists to serialize.
+  // Apply the gradient values g[0, n) to the consecutive coordinates
+  // [s, s + n) under the configured optimizer — THE pluggable update
+  // this server exists to serialize (caller holds mu_).  One loop a
+  // stretch that one optimizer governs (the whole span unless an
+  // --opt_segments boundary falls inside it), each coordinate's
+  // expression kept verbatim: the trajectories are oracle-pinned.
   // FTRL skips zero gradients (no information; and re-deriving w from
   // unchanged z would zero a freshly init-pushed weight, since init
   // seeds weights_ directly and leaves z/n at 0 until real traffic).
   // signSGD async is the one-voter majority: w -= lr * sign(g).
-  inline void ApplyGrad(Key k, float g) {
-    const Opt o = opt_segments_.empty() ? opt_ : OptFor(k);
-    if (o == Opt::kFtrl) {
-      if (g != 0.0f) FtrlStep(k, g);
-    } else if (o == Opt::kSign) {
-      if (g > 0.0f) weights_[k] -= lr_;
-      else if (g < 0.0f) weights_[k] += lr_;
-    } else {
-      weights_[k] -= lr_ * g;
+  void ApplySpan(Key s, const Val* g, uint64_t n) {
+    while (n > 0) {
+      Opt o = opt_;
+      uint64_t take = n;
+      for (const auto& seg : opt_segments_) {
+        if (s < seg.first) {
+          o = seg.second;
+          take = std::min<uint64_t>(n, seg.first - s);
+          break;
+        }
+      }
+      if (o == Opt::kFtrl) {
+        for (uint64_t j = 0; j < take; ++j)
+          if (g[j] != 0.0f) FtrlStep(s + j, g[j]);
+      } else if (o == Opt::kSign) {
+        Val* w = weights_.data() + s;
+        for (uint64_t j = 0; j < take; ++j) {
+          if (g[j] > 0.0f) w[j] -= lr_;
+          else if (g[j] < 0.0f) w[j] += lr_;
+        }
+      } else {
+        Val* w = weights_.data() + s;
+        for (uint64_t j = 0; j < take; ++j) w[j] -= lr_ * g[j];
+      }
+      s += take;
+      g += take;
+      n -= take;
     }
   }
 
-  // Gather the current weights for a key set (caller holds mu_) — the
-  // payload of a fused kPushPull reply.
-  std::vector<Val> WeightsFor(const std::vector<Key>& keys) {
-    std::vector<Val> out(keys.size());
-    for (size_t i = 0; i < keys.size(); ++i) out[i] = weights_[keys[i]];
-    return out;
+  // weights_ seeded from a frame's values (init, or a first push).
+  void SeedWeights(const Rows& rows, const std::vector<Val>& vals) {
+    rows.ForSpans([&](Key s, uint64_t at, uint64_t n) {
+      std::memcpy(weights_.data() + s, vals.data() + at, n * sizeof(Val));
+    });
+  }
+
+  // The current values of `from` at a frame's slots, in frame order,
+  // into out[0, rows.flat()) (caller holds mu_): one copy of the range
+  // for a run, a copy a row otherwise.
+  static void CopyRows(const std::vector<Val>& from, const Rows& rows,
+                       Val* out) {
+    rows.ForSpans([&](Key s, uint64_t at, uint64_t n) {
+      std::memcpy(out + at, from.data() + s, n * sizeof(Val));
+    });
+  }
+
+  // A reply's values sized for this frame; the buffer is the
+  // connection's own (ServeLoop), so same-size frames never allocate.
+  static Val* SizedFor(std::vector<Val>& reply, uint64_t n) {
+    if (reply.size() != n) reply.resize(n);
+    return reply.data();
   }
 
   // --- PUSH: the reference DataHandle push branch (src/main.cc:48-84).
   // reply_weights = fused kPushPull: the reply carries the post-update
-  // weights for the pushed keys (see kv_protocol.h). ---
-  void HandlePush(int fd, const MsgHeader& h, const std::vector<Key>& keys,
-                  const std::vector<Val>& vals, Key max_key,
-                  bool reply_weights = false) {
+  // weights for the pushed keys (see kv_protocol.h), copied into
+  // `reply` under mu_ and written after it is released.  A BSP push
+  // takes `vals` with it (moved into the round's pending list). ---
+  void HandlePush(int fd, const MsgHeader& h, const Rows& rows,
+                  std::vector<Val>& vals, std::vector<Val>& reply,
+                  Key max_key, bool reply_weights = false) {
     std::unique_lock<std::mutex> lock(mu_);
     ++n_push_;
     if (reply_weights) ++n_pull_;  // it serves the next pull too
-    // max_key computed by Serve over the WHOLE frame — keys.back()
+    // a fused frame stands in both counts, so it does here
+    if (rows.run) run_frames_ += reply_weights ? 2 : 1;
+    // max_key computed by Serve over the WHOLE frame — the last key
     // would assume sorted keys, and an unsorted frame would then write
     // out of bounds.
-    if (!keys.empty()) EnsureCapacity(max_key);
+    if (rows.num_keys) EnsureCapacity(max_key);
+    // every branch but the BSP merge answers at once, a fused frame
+    // with the weights as this push leaves them
+    const auto reply_now = [&] {
+      const uint64_t n = reply_weights ? rows.flat() : 0;
+      if (n) CopyRows(weights_, rows, SizedFor(reply, n));
+      lock.unlock();
+      Respond(fd, h, reply.data(), n);
+    };
 
     if (h.flags & kInitPush) {
       // Idempotent init (kv_protocol.h): seeds only an uninitialized
       // server, replies immediately either way, never joins the sync
       // merge — a restarted worker can re-send it safely.  kForceInit
       // (checkpoint resume against a surviving group) overwrites.
-      if ((!initialized_ || (h.flags & kForceInit)) && !keys.empty()) {
-        for (size_t i = 0; i < keys.size(); ++i) weights_[keys[i]] = vals[i];
+      if ((!initialized_ || (h.flags & kForceInit)) && rows.num_keys) {
+        SeedWeights(rows, vals);
         initialized_ = true;
         // WAL records describe the mutation that ACTUALLY happened (a
         // no-op'd idempotent re-init is not logged), so replay applies
         // every record unconditionally.
-        WalAppend(n_push_, kInitPush, Op::kPush, keys, vals);
+        WalAppend(n_push_, kInitPush, Op::kPush, rows, vals);
       }
-      const auto out = reply_weights ? WeightsFor(keys) : std::vector<Val>();
-      lock.unlock();
-      Respond(fd, h, out.data(), out.size());
+      reply_now();
       return;
     }
 
-    if (!initialized_ && !keys.empty()) {
+    if (!initialized_ && rows.num_keys) {
       // First non-empty push seeds the weights (src/main.cc:50-56).  An
       // EMPTY push (a sparse worker's "present" vote for a range it did
       // not touch) can never initialize — it falls through to the normal
       // sync/async handling so it still counts toward the BSP barrier.
-      for (size_t i = 0; i < keys.size(); ++i) weights_[keys[i]] = vals[i];
+      SeedWeights(rows, vals);
       initialized_ = true;
       // logged as an init record: the SEMANTIC was a seed (weights
       // set, not gradient-applied), and replay must reproduce exactly
       // that regardless of what the wire flags said
-      WalAppend(n_push_, kInitPush, Op::kPush, keys, vals);
-      const auto out = reply_weights ? WeightsFor(keys) : std::vector<Val>();
-      lock.unlock();
-      Respond(fd, h, out.data(), out.size());
+      WalAppend(n_push_, kInitPush, Op::kPush, rows, vals);
+      reply_now();
       return;
     }
 
     if (!sync_) {
       // Async/Hogwild: apply immediately (src/main.cc:79-84) under the
       // configured optimizer (SGD or per-coordinate FTRL-Proximal).
-      for (size_t i = 0; i < keys.size(); ++i)
-        ApplyGrad(keys[i], vals[i]);
+      rows.ForSpans([&](Key s, uint64_t at, uint64_t n) {
+        ApplySpan(s, vals.data() + at, n);
+      });
       // empty "present" votes are logged too: the WAL clock must track
       // n_push_ exactly or the RPO push-clock audit would drift
-      WalAppend(n_push_, 0, Op::kPush, keys, vals);
-      const auto out = reply_weights ? WeightsFor(keys) : std::vector<Val>();
-      lock.unlock();
-      Respond(fd, h, out.data(), out.size());
+      WalAppend(n_push_, 0, Op::kPush, rows, vals);
+      reply_now();
       return;
     }
 
     // Sync/BSP: merge and defer the response (src/main.cc:57-78).
     // Order matters for exception safety: ALL allocating operations
-    // (merge_ resize, the pending entry's key/val copies) happen BEFORE
-    // the merge_ mutation loop, which itself cannot throw.  The reverse
-    // order would let a bad_alloc in push_back leave an orphan gradient
-    // in merge_ with no pending entry — DropConnection's rollback could
-    // never remove it, and the worker's retry would count twice.
+    // (merge_ resize, the pending entry and its copy of the row keys)
+    // happen BEFORE the merge_ mutation loop, which itself cannot
+    // throw.  The reverse order would let a bad_alloc in push_back
+    // leave an orphan gradient in merge_ with no pending entry —
+    // DropConnection's rollback could never remove it, and the worker's
+    // retry would count twice.
     if (merge_.size() < weights_.size()) merge_.resize(weights_.size(), 0.0f);
-    pending_.push_back({fd, h, keys, vals, reply_weights, MonoNowS()});
-    for (size_t i = 0; i < keys.size(); ++i) merge_[keys[i]] += vals[i];
+    pending_.push_back({fd, h,
+                        std::vector<Key>(rows.keys, rows.keys + rows.num_keys),
+                        rows.vpk, rows.run, {}, reply_weights, MonoNowS()});
+    // The entry takes the frame's buffer, and the connection one a
+    // released push has handed back (same size in a steady job, so its
+    // next frame is read into it as it stands).
+    pending_.back().vals.swap(vals);
+    if (!spare_vals_.empty()) {
+      vals.swap(spare_vals_.back());
+      spare_vals_.pop_back();
+    }
+    {
+      const Val* g = pending_.back().vals.data();
+      rows.ForSpans([&](Key s, uint64_t at, uint64_t n) {
+        Val* m = merge_.data() + s;
+        for (uint64_t j = 0; j < n; ++j) m[j] += g[at + j];
+      });
+    }
 
     if (static_cast<int>(pending_.size()) == num_workers_) {
       // The round's counters (kStats sync_* tail, kv_protocol.h): the
@@ -1109,8 +1217,11 @@ class KVServer {
             pick = &p;
         }
         if (pick != nullptr) {
-          for (size_t i = 0; i < pick->keys.size(); ++i)
-            weights_[pick->keys[i]] -= lr_ * pick->vals[i] / w;
+          const Val* g = pick->vals.data();
+          pick->rows().ForSpans([&](Key s, uint64_t at, uint64_t n) {
+            Val* wt = weights_.data() + s;
+            for (uint64_t j = 0; j < n; ++j) wt[j] -= lr_ * g[at + j] / w;
+          });
         }
       } else if (!opt_segments_.empty()) {
         // Per-namespace optimizers (sgd|ftrl segments): dispatch the
@@ -1126,7 +1237,7 @@ class KVServer {
         }
       } else if (opt_ == Opt::kFtrl) {
         // FTRL BSP: ONE optimizer step on the round's mean gradient,
-        // untouched (zero-merge) coordinates skipped — see ApplyGrad.
+        // untouched (zero-merge) coordinates skipped — see ApplySpan.
         for (size_t i = 0; i < merge_.size(); ++i)
           if (merge_[i] != 0.0f) FtrlStep(i, merge_[i] / w);
       } else if (opt_ == Opt::kSign) {
@@ -1155,17 +1266,28 @@ class KVServer {
       // kShutdown holds mu_ while severing other connections, so it
       // cannot cut a release loop midway and strand a peer without its
       // reply.  Fused (kPushPull) pushes get the post-round weights for
-      // their keys — exactly what their next pull would have returned.
+      // their keys — exactly what their next pull would have returned —
+      // a run straight out of weights_, which mu_ holds still.
       for (auto& p : release) {
-        if (p.want_vals) {
-          const auto out = WeightsFor(p.keys);
-          Respond(p.fd, p.header, out.data(), out.size());
-        } else {
+        const Rows pr = p.rows();
+        if (!p.want_vals) {
           Respond(p.fd, p.header, nullptr, 0);
+        } else if (pr.run) {
+          Respond(p.fd, p.header, weights_.data() + pr.keys[0] * pr.vpk,
+                  pr.flat());
+        } else {
+          CopyRows(weights_, pr, SizedFor(reply, pr.flat()));
+          Respond(p.fd, p.header, reply.data(), pr.flat());
         }
         // held from its arrival until its own reply was written: the
         // later a push stands in the release, the longer
         sync_hold_s_ += MonoNowS() - p.arrived_s;
+        // its buffer goes to the next round's frames (HandlePush above)
+        if (spare_vals_.size() < static_cast<size_t>(num_workers_) &&
+            !p.vals.empty()) {
+          spare_vals_.emplace_back();
+          spare_vals_.back().swap(p.vals);
+        }
       }
       ++sync_rounds_;
       cpu_release_s_ += ThreadCpuNowS() - release_cpu0;
@@ -1181,8 +1303,11 @@ class KVServer {
     std::lock_guard<std::mutex> lock(mu_);
     for (auto it = pending_.begin(); it != pending_.end();) {
       if (it->fd == fd) {
-        for (size_t i = 0; i < it->keys.size(); ++i)
-          merge_[it->keys[i]] -= it->vals[i];  // roll back the merge
+        const Val* g = it->vals.data();
+        it->rows().ForSpans([&](Key s, uint64_t at, uint64_t n) {
+          Val* m = merge_.data() + s;
+          for (uint64_t j = 0; j < n; ++j) m[j] -= g[at + j];  // roll back
+        });
         it = pending_.erase(it);
       } else {
         ++it;
@@ -1202,29 +1327,27 @@ class KVServer {
   // to zero = per-coordinate learning rates and L1 duals forgotten);
   // these two ops let it capture and restore the full optimizer state.
   // Layout on the wire: [z for every key..., n for every key...] —
-  // 2x vals per expanded key, both directions. ---
-  void HandleOptStatePull(int fd, const MsgHeader& h,
-                          const std::vector<Key>& keys, Key max_key) {
+  // 2x vals per flat slot, both directions. ---
+  void HandleOptStatePull(int fd, const MsgHeader& h, const Rows& rows,
+                          std::vector<Val>& reply, Key max_key) {
     if (!has_ftrl_) {  // any FTRL segment allocates z/n (zeros elsewhere)
       RespondError(fd, h);
       return;
     }
-    const size_t n = keys.size();
-    std::vector<Val> out(2 * n);
+    const uint64_t n = rows.flat();
+    Val* out = SizedFor(reply, 2 * n);
     {
       std::lock_guard<std::mutex> lock(mu_);
       ++n_pull_;
-      if (!keys.empty()) EnsureCapacity(max_key);
-      for (size_t i = 0; i < n; ++i) {
-        out[i] = z_[keys[i]];
-        out[n + i] = nacc_[keys[i]];
-      }
+      run_frames_ += rows.run;
+      if (rows.num_keys) EnsureCapacity(max_key);
+      CopyRows(z_, rows, out);
+      CopyRows(nacc_, rows, out + n);
     }
-    Respond(fd, h, out.data(), out.size());
+    Respond(fd, h, out, 2 * n);
   }
 
-  void HandleOptStatePush(int fd, const MsgHeader& h,
-                          const std::vector<Key>& keys,
+  void HandleOptStatePush(int fd, const MsgHeader& h, const Rows& rows,
                           const std::vector<Val>& vals, Key max_key) {
     // ServeLoop enforced kInitPush: this is the idempotent seed form
     // only, replied immediately, never merged (mirrors weight init).
@@ -1234,31 +1357,34 @@ class KVServer {
     }
     std::lock_guard<std::mutex> lock(mu_);
     ++n_push_;
-    if (!keys.empty()) EnsureCapacity(max_key);
-    if ((!initialized_ || (h.flags & kForceInit)) && !keys.empty()) {
-      const size_t n = keys.size();
-      for (size_t i = 0; i < n; ++i) {
-        z_[keys[i]] = vals[i];
-        nacc_[keys[i]] = vals[n + i];
-      }
-      WalAppend(n_push_, kOptState | kInitPush, Op::kPush, keys, vals);
+    run_frames_ += rows.run;
+    if (rows.num_keys) EnsureCapacity(max_key);
+    if ((!initialized_ || (h.flags & kForceInit)) && rows.num_keys) {
+      const uint64_t flat = rows.flat();
+      rows.ForSpans([&](Key s, uint64_t at, uint64_t n) {
+        std::memcpy(z_.data() + s, vals.data() + at, n * sizeof(Val));
+        std::memcpy(nacc_.data() + s, vals.data() + flat + at,
+                    n * sizeof(Val));
+      });
+      WalAppend(n_push_, kOptState | kInitPush, Op::kPush, rows, vals);
     }
     Respond(fd, h, nullptr, 0);
   }
 
   // --- PULL: reply current weights (src/main.cc:85-95) ---
-  void HandlePull(int fd, const MsgHeader& h, const std::vector<Key>& keys,
-                  Key max_key) {
-    std::vector<Val> out(keys.size());
+  void HandlePull(int fd, const MsgHeader& h, const Rows& rows,
+                  std::vector<Val>& reply, Key max_key) {
+    Val* out = SizedFor(reply, rows.flat());
     {
       std::lock_guard<std::mutex> lock(mu_);
       ++n_pull_;
-      // frame-wide max from Serve, not keys.back() (unsorted frame =>
+      run_frames_ += rows.run;
+      // frame-wide max from Serve, not the last key (unsorted frame =>
       // out-of-bounds read)
-      if (!keys.empty()) EnsureCapacity(max_key);
-      for (size_t i = 0; i < keys.size(); ++i) out[i] = weights_[keys[i]];
+      if (rows.num_keys) EnsureCapacity(max_key);
+      CopyRows(weights_, rows, out);
     }
-    Respond(fd, h, out.data(), out.size());
+    Respond(fd, h, out, rows.flat());
   }
 
   // --- STATS: liveness/progress probe (no reference equivalent — the
@@ -1289,11 +1415,14 @@ class KVServer {
       stats[kStatsValsV1 + kCpuSlots] = static_cast<double>(epoch_);
       // slots 11-14 (the BSP barrier's tail, additive like the rest;
       // zeros from an async server)
-      double* sync = stats + kStatsValsV1 + kCpuSlots + 1;
-      sync[0] = static_cast<double>(sync_rounds_);
-      sync[1] = sync_hold_s_;
-      sync[2] = sync_spread_s_;
-      sync[3] = cpu_release_s_;
+      double* tail = stats + kStatsValsV1 + kCpuSlots + 1;
+      tail[0] = static_cast<double>(sync_rounds_);
+      tail[1] = sync_hold_s_;
+      tail[2] = sync_spread_s_;
+      tail[3] = cpu_release_s_;
+      // slot 15: of the operations slots 4 and 5 count, those whose
+      // frame was one run of row keys
+      tail[4] = static_cast<double>(run_frames_);
     }
     // per-handler thread-CPU seconds (the continuous-profiling
     // extension; atomic — no mu_ needed)
@@ -1405,7 +1534,7 @@ class KVServer {
         return;
       }
     }
-    waiters.push_back({fd, h, {}, {}});
+    waiters.push_back({fd, h});
     if (static_cast<int>(waiters.size()) < num_workers_) return;
     std::vector<PendingPush> release;
     release.swap(waiters);
@@ -1760,7 +1889,8 @@ class KVServer {
         for (uint32_t i = 0; i < nkeys; ++i) weights_[keys[i]] = vals[i];
         initialized_ = true;
       } else {
-        for (uint32_t i = 0; i < nkeys; ++i) ApplyGrad(keys[i], vals[i]);
+        // a record's keys are flat (WalAppend), a coordinate each
+        for (uint32_t i = 0; i < nkeys; ++i) ApplySpan(keys[i], &vals[i], 1);
       }
       n_push_ = seq;
       ++applied;
@@ -1818,12 +1948,17 @@ class KVServer {
   // a SIGKILL after the reply loses nothing; the batched fsync in
   // StoreLoop (group commit) is what bounds POWER-loss exposure to
   // --store_wal_fsync seconds.
-  void WalAppend(uint64_t seq, uint8_t flags, Op op,
-                 const std::vector<Key>& keys,
+  //
+  // A record's format is older than row frames and keeps its bytes: the
+  // keys are FLAT, one u64 a value, so this writer is the one place
+  // that writes a frame's rows out slot by slot — straight into the
+  // record, and only where a WAL is armed (ps/store.py, ReplaySegment
+  // and the RPO audit read what they always read).
+  void WalAppend(uint64_t seq, uint8_t flags, Op op, const Rows& rows,
                  const std::vector<Val>& vals) {
     if (wal_fd_ < 0) return;
-    const uint32_t nkeys = static_cast<uint32_t>(keys.size());
-    const size_t kb = keys.size() * sizeof(Key);
+    const uint32_t nkeys = static_cast<uint32_t>(rows.flat());
+    const size_t kb = rows.flat() * sizeof(Key);
     const size_t vb = vals.size() * sizeof(Val);
     wal_buf_.resize(kWalRecordHeaderSize + kb + vb);
     uint8_t* b = wal_buf_.data();
@@ -1832,7 +1967,13 @@ class KVServer {
     std::memcpy(b + 8, &nkeys, 4);
     b[12] = flags;
     b[13] = static_cast<uint8_t>(op);
-    if (kb) std::memcpy(b + kWalRecordHeaderSize, keys.data(), kb);
+    uint8_t* kout = b + kWalRecordHeaderSize;
+    rows.ForSpans([&](Key s, uint64_t at, uint64_t n) {
+      for (uint64_t j = 0; j < n; ++j) {
+        const Key k = s + j;
+        std::memcpy(kout + (at + j) * sizeof(Key), &k, sizeof(Key));
+      }
+    });
     if (vb) std::memcpy(b + kWalRecordHeaderSize + kb, vals.data(), vb);
     uint32_t crc = Crc32(0, b + kWalRecordHeaderSize, kb + vb);
     std::memcpy(b + 16, &crc, 4);
@@ -2092,6 +2233,13 @@ class KVServer {
   double sync_hold_s_ = 0.0;
   double sync_spread_s_ = 0.0;
   double cpu_release_s_ = 0.0;
+  //: of the operations n_push_ and n_pull_ count, those whose frame was
+  //: one run of row keys (guarded by mu_; kStats run_frames): a fused
+  //: push-pull stands in both counts and so twice here
+  uint64_t run_frames_ = 0;
+  //: value buffers of released BSP pushes, at most one a worker, for
+  //: the connections' next frames (guarded by mu_; HandlePush)
+  std::vector<std::vector<Val>> spare_vals_;
   std::unordered_map<uint16_t, std::vector<PendingPush>> barrier_;
   std::set<uint16_t> released_barriers_;
 };

@@ -53,9 +53,10 @@ _SERVER_CPU = _reg.gauge(
     "health probe",
     labelnames=("rank", "handler"),
 )
-#: The BSP barrier's counters (the kStats ``sync_*`` tail), mirrored by
-#: every health() probe; an async group reads zeros.
-_SERVER_SYNC = {
+#: The kStats tail's counters with a series of their own, mirrored by
+#: every health() probe: the BSP barrier's (an async group reads zeros)
+#: and ``run_frames``, how much of a rank's traffic its run path took.
+_SERVER_TAIL = {
     "sync_rounds": _reg.gauge(
         "distlr_ps_server_sync_rounds",
         "BSP rounds this server rank has released (one update applied "
@@ -76,6 +77,13 @@ _SERVER_SYNC = {
         "cumulative thread CPU seconds of the BSP release (apply, "
         "clear, the W gathers and replies); also inside "
         "distlr_kv_server_cpu_seconds{handler=\"push\"}",
+        labelnames=("rank",)),
+    "run_frames": _reg.gauge(
+        "distlr_ps_server_run_frames",
+        "pushes and pulls this server rank handled as one range of slots "
+        "(a frame whose row keys are one consecutive run; a fused push-pull "
+        "counts in both, as in the stats total_pushes and total_pulls), "
+        "from the latest health probe",
         labelnames=("rank",)),
 }
 _SUP_EVENTS = _reg.counter(
@@ -752,8 +760,8 @@ class ServerGroup:
         for rank, s in enumerate(stats):
             for name, val in s.items():
                 _SERVER_STAT.labels(rank=rank, stat=name).set(val)
-                if name in _SERVER_SYNC:
-                    _SERVER_SYNC[name].labels(rank=rank).set(val)
+                if name in _SERVER_TAIL:
+                    _SERVER_TAIL[name].labels(rank=rank).set(val)
                 elif name.startswith("cpu_") and name.endswith("_seconds"):
                     _SERVER_CPU.labels(
                         rank=rank,

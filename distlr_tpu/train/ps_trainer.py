@@ -1107,8 +1107,8 @@ class PSWorker:
                 # ~2.7x fewer keyed bytes at R=32 than R expanded keys);
                 # groups whose range boundaries don't align to R fall
                 # back to the expanded encoding, bit-identical
-                # semantics either way (the server expands at parse
-                # time onto the same code paths).
+                # semantics either way (the server walks rows and flat
+                # keys with the same loops, slot for slot).
                 vpk = (row_width
                        if row_width > 1 and self.kv.supports_vals_per_key(
                            row_width)

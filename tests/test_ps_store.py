@@ -410,6 +410,72 @@ class TestKillNineRecovery:
                 kv.shutdown_servers()
             g.wait()
 
+    def test_run_frames_keep_the_records_bytes_and_replay(self, tmp_path):
+        """The server handles a frame of row keys as rows, and a run of
+        them as one range; a WAL record is older than that and keeps its
+        bytes: FLAT keys, one u64 a value, whatever the frame was.  The
+        segment is rebuilt here byte for byte from the format
+        (``kv_protocol.h``), which is what the server wrote for the same
+        pushes before it stopped writing flat keys out per frame; after
+        a SIGKILL the replay lands on the weights that were acked."""
+        import zlib
+
+        from distlr_tpu.ps import wire
+
+        vpk, rows = 4096, 3
+        dim = vpk * rows            # the default frame: one run of 3 rows
+        rng = np.random.default_rng(31)
+        w0, g1, g2 = rng.normal(size=(3, dim)).astype(np.float32)
+        g_rows = rng.normal(size=2 * vpk).astype(np.float32)
+        g_flat = rng.normal(size=5).astype(np.float32)
+        row_keys = np.array([0, 2], np.uint64)
+        flat_keys = np.array([1, 2, 3, 9000, dim - 1], np.uint64)
+        with ServerGroup(1, 1, dim=dim, sync=False, store_dir=str(tmp_path),
+                         store_interval_s=60.0, store_wal=True,
+                         store_wal_fsync_s=0.01) as g:
+            with KVWorker(g.hosts, dim, sync_group=False,
+                          timeout_ms=2000) as kv:
+                kv.push_init(w0)                                   # a run
+                kv.push(g1)                                        # a run
+                kv.push_pull(g2)                                   # a run
+                kv.push(g_rows, keys=row_keys, vals_per_key=vpk)   # rows
+                kv.push(g_flat, keys=flat_keys)                    # flat
+                acked = kv.pull()
+                assert kv.stats(0)["run_frames"] == 5   # 3 + the fused + pull
+                g.procs[0].kill()
+                g.procs[0].wait()
+        rank_dir = os.path.join(str(tmp_path), "rank-0")
+        (clock, path), = ps_store.wal_segments(rank_dir)
+        assert clock == 0
+
+        def record(seq, flags, keys, vals):
+            payload = keys.astype("<u8").tobytes() + vals.tobytes()
+            return ps_store.WAL_RECORD_STRUCT.pack(
+                seq, keys.size, flags, wire.OP_PUSH, 0,
+                zlib.crc32(payload)) + payload
+
+        every = np.arange(dim, dtype=np.uint64)
+        of_rows = (row_keys[:, None] * vpk
+                   + np.arange(vpk, dtype=np.uint64)[None, :]).reshape(-1)
+        want = ps_store.WAL_SEGMENT_STRUCT.pack(
+            ps_store.WAL_MAGIC, ps_store.STORE_VERSION, 1)
+        want += record(1, wire.FLAG_INIT_PUSH, every, w0)
+        want += record(2, 0, every, g1)
+        want += record(3, 0, every, g2)
+        want += record(4, 0, of_rows, g_rows)
+        want += record(5, 0, flat_keys, g_flat)
+        with open(path, "rb") as f:
+            assert f.read() == want
+        assert [r.seq for r in ps_store.iter_wal(path)] == [1, 2, 3, 4, 5]
+        with ServerGroup(1, 1, dim=dim, sync=False, store_dir=str(tmp_path),
+                         store_wal=True) as g:
+            with KVWorker(g.hosts, dim, sync_group=False,
+                          timeout_ms=2000) as kv:
+                assert kv.pull().tobytes() == acked.tobytes()
+                assert kv.stats(0)["total_pushes"] == 5
+                kv.shutdown_servers()
+            g.wait()
+
     def test_snapshot_only_rpo_bounded_by_interval(self, tmp_path):
         """Snapshot-only loss is bounded by the acks issued inside the
         final snapshot interval (+ scheduling slack)."""

@@ -1,6 +1,8 @@
 """The BSP barrier's counters: the additive kStats tail (``sync_rounds``,
 ``sync_hold_seconds``, ``sync_spread_seconds``, ``cpu_release_seconds``),
-its mirror in the registry, and a reply from before the tail."""
+its mirror in the registry, and a reply from before the tail.  Behind
+them ``run_frames``: the pushes and pulls a server handled as one range
+of slots (every default-key op of a dense job, no scattered frame)."""
 
 import socket
 import struct
@@ -45,9 +47,9 @@ def _rounds(group, sync, delays):
 
 
 def test_the_tail_stands_after_epoch_in_the_wires_order():
-    assert STATS_FIELDS[-len(TAIL):] == TAIL
-    assert STATS_FIELDS[-len(TAIL) - 1] == "epoch"
-    assert len(STATS_FIELDS) == wire.STATS_VALS == 15
+    assert STATS_FIELDS[-len(TAIL) - 1:] == TAIL + ("run_frames",)
+    assert STATS_FIELDS[-len(TAIL) - 2] == "epoch"
+    assert len(STATS_FIELDS) == wire.STATS_VALS == 16
 
 
 @pytest.mark.parametrize("sync", [True, False], ids=["bsp", "async"])
@@ -58,6 +60,13 @@ def test_a_server_counts_its_rounds_and_an_async_one_reports_zeros(sync):
         health = g.health()
     for b, a, h in zip(before, after, health):
         assert a["total_pushes"] - b["total_pushes"] == WORKERS * ROUNDS
+        # a dense job's frames are all runs: every push and pull of the
+        # window (a fused push-pull is one of each) took the run path
+        assert isinstance(a["run_frames"], int)
+        assert a["run_frames"] - b["run_frames"] == (
+            a["total_pushes"] - b["total_pushes"]
+            + a["total_pulls"] - b["total_pulls"]) == 2 * WORKERS * ROUNDS
+        assert h["run_frames"] == a["run_frames"]
         assert all(b[name] == 0 for name in TAIL)
         assert isinstance(a["sync_rounds"], int)
         assert all(isinstance(a[name], float) for name in TAIL[1:])
@@ -77,6 +86,8 @@ def test_a_server_counts_its_rounds_and_an_async_one_reports_zeros(sync):
         assert 0 < a["cpu_release_seconds"] <= a["cpu_push_seconds"]
     reg = get_registry()
     for rank, a in enumerate(after):
+        mirrored = dict(reg.get("distlr_ps_server_run_frames").children())
+        assert mirrored[(str(rank),)].value == a["run_frames"]
         for stat, series in (
                 ("sync_rounds", "distlr_ps_server_sync_rounds"),
                 ("sync_hold_seconds", "distlr_ps_server_sync_hold_seconds"),
@@ -109,7 +120,58 @@ def _serve_a_reply_of(listener, slots):
                 + struct.pack(f"<{n}d", *range(1, n + 1)))
 
 
-@pytest.mark.parametrize("slots", [wire.STATS_VALS_V1, 11])
+@pytest.mark.parametrize("sync", [True, False], ids=["bsp", "async"])
+def test_a_keyed_job_of_scattered_frames_counts_no_run(sync):
+    """Row keys with gaps, flat keys with gaps, and a fused frame of
+    each: pushes and pulls counted, none of them a run.  One frame of
+    consecutive flat keys beside them is one."""
+    vpk = 4
+    rows = np.array([0, 2, 5, 9, 12], np.uint64)        # of DIM / vpk = 16
+    flat = np.array([1, 3, 4, 40, 63], np.uint64)
+    with ServerGroup(1, 1, DIM, sync=sync) as g, \
+            KVWorker(g.hosts, DIM, client_id=0, sync_group=sync) as kv:
+        kv.wait(kv.push_init(np.ones(DIM, np.float32)))
+        before = kv.stats(0)
+        for _ in range(ROUNDS):
+            kv.wait(kv.push(np.full(rows.size * vpk, 0.5, np.float32),
+                            keys=rows, vals_per_key=vpk))
+            kv.pull(keys=rows, vals_per_key=vpk)
+            kv.push_pull(np.full(flat.size, 0.25, np.float32), keys=flat)
+            kv.pull(keys=flat)
+        mid = kv.stats(0)
+        kv.pull(keys=np.arange(8, 24, dtype=np.uint64))
+        after = kv.stats(0)
+    assert mid["total_pushes"] - before["total_pushes"] == 2 * ROUNDS
+    assert mid["total_pulls"] - before["total_pulls"] == 3 * ROUNDS
+    assert mid["run_frames"] == before["run_frames"]
+    assert after["run_frames"] - mid["run_frames"] == 1
+
+
+def test_a_request_of_the_old_length_is_still_answered():
+    """A client from before ``run_frames`` asks for fifteen counters and
+    gets fifteen, the barrier's tail last; one that asks for more than
+    there are gets what there is."""
+    with ServerGroup(1, 1, DIM, sync=False) as g:
+        with KVWorker(g.hosts, DIM, client_id=0, sync_group=False) as kv:
+            kv.wait(kv.push_init(np.ones(DIM, np.float32)))
+            kv.pull()
+        with socket.create_connection(("127.0.0.1", g.ports[0])) as s:
+            for aux, slots in ((15, 15), (16, 16), (99, 16)):
+                s.sendall(wire.HEADER_STRUCT.pack(
+                    wire.MAGIC, wire.OP_STATS, 0, aux, 7, 1, 0))
+                hdr = s.recv(wire.HEADER_STRUCT.size, socket.MSG_WAITALL)
+                n = wire.HEADER_STRUCT.unpack(hdr)[-1]
+                assert n == 2 * slots
+                got = struct.unpack(f"<{slots}d",
+                                    s.recv(4 * n, socket.MSG_WAITALL))
+                named = dict(zip(STATS_FIELDS, got))
+                assert named["total_pushes"] == 1
+                assert named["total_pulls"] == 1
+                assert named.get("run_frames", 2) == 2
+                assert ("run_frames" in named) == (slots == 16)
+
+
+@pytest.mark.parametrize("slots", [wire.STATS_VALS_V1, 11, 15])
 def test_a_reply_from_before_the_tail_still_parses(slots):
     with socket.socket() as listener:
         listener.bind(("127.0.0.1", 0))
@@ -122,4 +184,5 @@ def test_a_reply_from_before_the_tail_still_parses(slots):
             got = kv.stats(0)
         server.join(timeout=5)
     assert list(got) == list(STATS_FIELDS[:slots])
-    assert got["total_pushes"] == 5 and not set(TAIL) & set(got)
+    assert got["total_pushes"] == 5 and "run_frames" not in got
+    assert set(TAIL) <= set(got) if slots == 15 else not set(TAIL) & set(got)

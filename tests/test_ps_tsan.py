@@ -11,7 +11,9 @@ Coverage (extended by the distlr-lint round beyond the original
 sync/async sweep): the fused push_pull, FTRL with ``--opt_segments``
 per-namespace updates plus concurrent opt-state snapshots, the kEpoch
 fence and a live resize under concurrent clients, and codec-negotiated
-(int8 / signSGD) pushes.  The CLIENT library's own TSan build is
+(int8 / signSGD) pushes, and rounds of run frames, gapped row keys and a
+push rolled back out of the merge (``test_ps_run_frames``' workload).
+The CLIENT library's own TSan build is
 ``tests/test_sanitizer_matrix.py`` (it needs the runtime preloaded).
 """
 
@@ -278,3 +280,27 @@ def test_codec_pushes_under_tsan(tsan_env, codec):
 
         _run_threads(workers, run, group)
         assert_no_reports(group)
+
+
+@needs_toolchain
+@pytest.mark.parametrize("sync", [True, False], ids=["sync", "async"])
+def test_run_frame_rounds_under_tsan(tsan_env, sync):
+    """Frames handled as the rows they are: fused default-key frames
+    (runs of three rows: apply or merge over the range, the reply out of
+    ``weights_``, the values' buffer moved into the round and handed
+    back), row keys with gaps, and a connection that dies with its push
+    in the merge — all of it on the TSan server build."""
+    from test_ps_run_frames import (
+        SAN_DIM,
+        SAN_WORKERS,
+        check_run_frame_rounds,
+        run_frame_rounds,
+    )
+
+    binary, assert_no_reports = tsan_env
+    group = ServerGroup(2, SAN_WORKERS, SAN_DIM, learning_rate=0.05,
+                        sync=sync, binary=binary)
+    with group:
+        last, stats = run_frame_rounds(group, sync)
+        assert_no_reports(group)
+    check_run_frame_rounds(last, stats, sync)

@@ -41,6 +41,12 @@ def _runs_here(path: str) -> bool:
 
 @pytest.fixture(scope="module")
 def oracle_bin():
+    return build_oracle()
+
+
+def build_oracle() -> str:
+    """The oracle's binary, built here; skips the caller where it cannot
+    be built or does not run."""
     path = os.path.join(ORACLE_DIR, "reference_oracle")
     make_args = ["make", "-C", ORACLE_DIR, "reference_oracle"]
     r = subprocess.run(make_args, capture_output=True, text=True)

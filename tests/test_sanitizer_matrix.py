@@ -181,6 +181,30 @@ _STORE_DRIVER = textwrap.dedent("""
 """)
 
 
+#: ISSUE 31: the server handles a frame as the rows it is.  Rounds of
+#: run frames (apply / merge over the range, the reply out of weights_,
+#: the values' buffer moved into the round and handed back), row keys
+#: with gaps, a push rolled back out of the merge — in both modes
+#: (``tests/test_ps_run_frames.py`` has the workload and its checks).
+_RUN_FRAMES_DRIVER = textwrap.dedent(f"""
+    import sys
+    sys.path.insert(0, {os.path.join(REPO, "tests")!r})
+    from distlr_tpu.ps import ServerGroup
+    from test_ps_run_frames import (
+        SAN_DIM, SAN_WORKERS, check_run_frame_rounds, run_frame_rounds)
+
+    for sync in (False, True):
+        with ServerGroup(2, SAN_WORKERS, SAN_DIM, learning_rate=0.05,
+                         sync=sync) as group:
+            last, stats = run_frame_rounds(group, sync)
+            group.wait()
+            assert [p.returncode for p in group.procs] == [0, 0], \\
+                [p.returncode for p in group.procs]
+        check_run_frame_rounds(last, stats, sync)
+    print("DRIVER_OK")
+""")
+
+
 def _run_variant(variant: str, tmp_path, *, preload: str | None = None,
                  timeout: int = 300, driver_src: str = _DRIVER) -> None:
     _build(variant)
@@ -220,6 +244,12 @@ def test_asan_server_e2e(tmp_path):
 @needs_toolchain
 def test_ubsan_server_e2e(tmp_path):
     _run_variant("ubsan", tmp_path)
+
+
+@needs_toolchain
+@pytest.mark.parametrize("variant", ["asan", "ubsan"])
+def test_run_frame_rounds_under_asan_and_ubsan(variant, tmp_path):
+    _run_variant(variant, tmp_path, driver_src=_RUN_FRAMES_DRIVER)
 
 
 @needs_toolchain
