@@ -6,9 +6,10 @@ reaches from ``python -m distlr_tpu.launch``, at D = 1,000,000 on the
 TPU this process finds: the SPMD trainer (dense and sparse), the
 parameter-server plane (native servers; Hogwild workers, then lock-step
 ones held to the benchmark's plain reference), the scoring
-server, and the Pallas kernel; on a host with four or more chips also
-the ``data`` x ``model`` mesh.  One process — it holds the chip — and
-the only children are the native KV servers.
+server, and the Pallas kernel; on a host with two or more chips also the
+lock-step job a worker to a chip (``ps-bsp-chips``; skipped, by name, on
+one), and with four or more the ``data`` x ``model`` mesh.  One process
+— it holds the chip — and the only children are the native KV servers.
 
     python chip_smoke.py                 # on the chip: the check
     python chip_smoke.py --rehearse-cpu  # anywhere: tiny sizes, says
@@ -340,27 +341,37 @@ class Smoke:
                 max(pinned, key=len).split("pinned: ")[1]),
         }
 
-    def ps_bsp(self) -> dict:
+    def ps_bsp(self, devices=None) -> dict:
         """The lock-step mode through the benchmark's own driver pieces:
         two workers, two servers (``sync=1``), two whole-shard rounds;
         every worker computes a round on the same weights, and the
-        weights after follow ``families/dense_ps_bsp.round``."""
+        weights after follow ``families/dense_ps_bsp.round``.  With
+        ``devices``, worker *r* is handed ``devices[r]`` (the four-chip
+        cell's ``prepare``) and has to compute there."""
         import numpy as np
 
         from chipbench import manifest
-        from chipbench.drivers import ps_bsp_epochs, ps_epochs
+        from chipbench.drivers import ps_bsp_epochs, ps_bsp_epochs_chips, ps_epochs
         from chipbench.families import dense_ps_bsp
 
         cell = manifest.Cell(manifest.load_benchmark(), "dense-ps-bsp-1chip")
         conf = ps_bsp_epochs.effective_config(cell, not self.on_tpu)
+        # a worker to a chip: 128 rows keep the step on a jax device at the
+        # rehearsal's width too (under 2**20 elements "auto" takes numpy)
+        rows = self.s.bsp_rows if devices is None else max(self.s.bsp_rows, 128)
         conf = {**conf,
                 "generator": {**conf["generator"],
-                              "rows_per_worker": self.s.bsp_rows,
+                              "rows_per_worker": rows,
                               "test_rows": 64},
                 "program": {**conf["program"], "num_workers": 2,
                             "num_feature_dim": self.s.d}}
         rounds, lr = 2, conf["program"]["learning_rate"]
-        job = ps_epochs.prepare(conf, 20260928, lambda t: print(f"  {t}"))
+
+        def say(text):
+            print(f"  {text}")
+
+        job = (ps_epochs.prepare(conf, 20260928, say) if devices is None
+               else ps_bsp_epochs_chips.prepare(conf, 20260928, say, devices))
         failed = True
         try:
             got = ps_bsp_epochs.record_rounds(job, rounds, rounds)
@@ -373,6 +384,13 @@ class Smoke:
         if self.on_tpu:
             for ln in pinned:
                 _check("train -> tpu:" in ln, ln)
+        on = {}
+        if devices is not None:
+            on = ps_bsp_epochs_chips.step_devices()
+            _check(on == {r: d.id for r, d in enumerate(devices)},
+                   f"worker r's step on device r: {on}")
+            for d, ln in zip(devices, sorted(pinned)):
+                _check(f"(id {d.id})" in ln, ln)
         compared = ps_bsp_epochs.compare(kept, got, conf["family"], lr,
                                          conf["limits"])
         bad = [r for r in compared if not r["ok"]]
@@ -392,7 +410,14 @@ class Smoke:
             "update_diff_rel": f"{by_name['update_diff_rel']:.2e}",
             "after_rounds_off": f"{off:.2e}",
             "round_miscount": int(by_name["round_miscount_recorded"]),
+            **({"step_devices": json.dumps(on, separators=(",", ":"))}
+               if on else {}),
         }
+
+    def ps_bsp_chips(self) -> dict:
+        """The lock-step job a worker to a chip: two workers on the first
+        two chips, each shard, step and readback on its own."""
+        return self.ps_bsp(self.devices[:2])
 
     def serve(self) -> dict:
         import numpy as np
@@ -610,6 +635,11 @@ def main(argv=None) -> int:
                 ("ps-bsp", smoke.ps_bsp),
                 ("serve", smoke.serve),
                 ("kernel", smoke.kernel)]
+        if dev["count"] >= 2:
+            legs.append(("ps-bsp-chips", smoke.ps_bsp_chips))
+        else:
+            print("SMOKE ps-bsp-chips skipped devices=1 (a worker to a chip "
+                  "needs two)", flush=True)
         if dev["count"] >= 4:
             legs.append(("mesh", smoke.mesh))
         for name, fn in legs:

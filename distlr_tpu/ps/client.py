@@ -162,7 +162,8 @@ def _observe_op(op: str, *, sent=0, received: int = 0,
 #: dispatch) — fractional, so every ``*_seconds`` counter stays a float
 #: in the stats dict while the others stay ints.  A pre-extension server
 #: replies only the first six, one from before the BSP tail eleven, one
-#: from before ``run_frames`` fifteen; the probe reports what arrived.
+#: from before ``run_frames`` fifteen, one from before
+#: ``lock_wait_seconds`` sixteen; the probe reports what arrived.
 STATS_FIELDS = (
     "dim",
     "initialized",
@@ -191,6 +192,10 @@ STATS_FIELDS = (
     # slots it is (a fused push-pull stands in both totals, so twice
     # here): every default-key op of a dense worker, no scattered frame
     "run_frames",
+    # wall seconds the push handlers stood waiting for the server's one
+    # lock, over total_pushes: near zero where pushes arrive apart, what
+    # a merge pays first where W workers push at the same instant
+    "lock_wait_seconds",
 )
 
 # The field list IS a wire mirror: its length must track kStatsVals and

@@ -145,8 +145,14 @@ enum class Op : uint8_t {
 // totals and so counts twice here; run_frames over the rise of the two
 // totals is the share of a job's traffic on that path (1 for a dense
 // worker's default-key ops, 0 for scattered keys).
+// Slot 16 (additive after run_frames): lock_wait_seconds, the wall
+// seconds the push handlers (kPush and kPushPull, every mode) stood
+// waiting for the server's one lock, summed over total_pushes: near
+// zero where pushes arrive apart, and the first thing a merge pays
+// where W workers on W chips push at the same instant (each behind the
+// others' merges, and all behind a release that is still answering).
 constexpr uint64_t kStatsValsV1 = 6;
-constexpr uint64_t kStatsVals = 16;
+constexpr uint64_t kStatsVals = 17;
 
 enum Flags : uint8_t {
   kNone = 0,

@@ -1100,7 +1100,11 @@ class KVServer {
   void HandlePush(int fd, const MsgHeader& h, const Rows& rows,
                   std::vector<Val>& vals, std::vector<Val>& reply,
                   Key max_key, bool reply_weights = false) {
+    // kStats lock_wait_seconds: what a push stood behind its peers'
+    // merges and the release before its own could begin
+    const double asked_s = MonoNowS();
     std::unique_lock<std::mutex> lock(mu_);
+    lock_wait_s_ += MonoNowS() - asked_s;
     ++n_push_;
     if (reply_weights) ++n_pull_;  // it serves the next pull too
     // a fused frame stands in both counts, so it does here
@@ -1423,6 +1427,8 @@ class KVServer {
       // slot 15: of the operations slots 4 and 5 count, those whose
       // frame was one run of row keys
       tail[4] = static_cast<double>(run_frames_);
+      // slot 16: seconds the push handlers waited for mu_
+      tail[5] = lock_wait_s_;
     }
     // per-handler thread-CPU seconds (the continuous-profiling
     // extension; atomic — no mu_ needed)
@@ -2237,6 +2243,9 @@ class KVServer {
   //: one run of row keys (guarded by mu_; kStats run_frames): a fused
   //: push-pull stands in both counts and so twice here
   uint64_t run_frames_ = 0;
+  //: wall seconds the push handlers stood waiting for mu_ (guarded by
+  //: mu_: added once it is held; kStats lock_wait_seconds)
+  double lock_wait_s_ = 0.0;
   //: value buffers of released BSP pushes, at most one a worker, for
   //: the connections' next frames (guarded by mu_; HandlePush)
   std::vector<std::vector<Val>> spare_vals_;

@@ -55,7 +55,8 @@ _SERVER_CPU = _reg.gauge(
 )
 #: The kStats tail's counters with a series of their own, mirrored by
 #: every health() probe: the BSP barrier's (an async group reads zeros)
-#: and ``run_frames``, how much of a rank's traffic its run path took.
+#: ``run_frames``, how much of a rank's traffic its run path took, and
+#: ``lock_wait_seconds``, what its pushes stood waiting for its lock.
 _SERVER_TAIL = {
     "sync_rounds": _reg.gauge(
         "distlr_ps_server_sync_rounds",
@@ -84,6 +85,12 @@ _SERVER_TAIL = {
         "(a frame whose row keys are one consecutive run; a fused push-pull "
         "counts in both, as in the stats total_pushes and total_pulls), "
         "from the latest health probe",
+        labelnames=("rank",)),
+    "lock_wait_seconds": _reg.gauge(
+        "distlr_ps_server_lock_wait_seconds",
+        "cumulative wall seconds this server rank's push handlers stood "
+        "waiting for its one lock (behind other pushes' merges and the "
+        "BSP release), from the latest health probe",
         labelnames=("rank",)),
 }
 _SUP_EVENTS = _reg.counter(

@@ -74,8 +74,9 @@ CAP_EPOCH = 1 << 9
 #: the original six integer counters every vintage replies (kStatsValsV1)
 STATS_VALS_V1 = 6
 #: current stats count: v1 six + 4 per-handler CPU seconds + epoch + the
-#: BSP barrier's four (rounds, hold, spread, release CPU) + run_frames
-STATS_VALS = 16
+#: BSP barrier's four (rounds, hold, spread, release CPU) + run_frames +
+#: lock_wait_seconds
+STATS_VALS = 17
 
 #: wire-corruption guard for vals_per_key (kMaxValsPerKey)
 MAX_VALS_PER_KEY = 4096

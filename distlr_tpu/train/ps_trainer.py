@@ -118,6 +118,18 @@ _RESIDENT_BYTES = get_registry().gauge(
 )
 
 
+#: Which device of its process a dense worker's step is pinned to (the
+#: ``id`` JAX gives it): worker *i* of a process takes device
+#: ``i % len(devices)`` of the job's (``worker_devices``); absent where
+#: the step computes in numpy on the host.
+_STEP_DEVICE = get_registry().gauge(
+    "distlr_ps_step_device",
+    "id of the jax device a PS worker's dense step, resident shard, "
+    "weights and gradient live on",
+    labelnames=("rank",),
+)
+
+
 #: Which program a dense worker's step on a jax device ran, a round:
 #: ``one_pass`` is the row-panel kernel over a resident row-major shard
 #: (``ops/pallas_lr.py``), ``two_pass`` is ``model.grad`` under XLA, whose
@@ -215,10 +227,12 @@ def server_optimizer(cfg: Config) -> str:
     return "signsgd" if cfg.ps_compress == "signsgd" else cfg.ps_optimizer
 
 
-def ps_compute_device(cfg: Config, rows: int | None = None):
+def ps_compute_device(cfg: Config, rows: int | None = None, device=None):
     """Where PS workers run their dense step: the string ``"numpy"``
     (host numpy/BLAS, no jax dispatch), a jax device, or None (default
-    backend).
+    backend).  ``device`` is the device of the default backend the job
+    gave this worker (:func:`worker_devices`); it stands wherever the
+    choice is the default backend, and decides nothing else.
 
     The reference's workers are host-CPU programs (``src/lr.cc:35-41``);
     our PS mode jits the same math, but for tiny models the accelerator
@@ -235,33 +249,48 @@ def ps_compute_device(cfg: Config, rows: int | None = None):
     """
     choice = cfg.ps_compute_backend
     if choice == "default":
-        return None
+        return device
     if choice == "numpy":
         return "numpy"
     if choice == "cpu":
         return jax.devices("cpu")[0]
     if jax.default_backend() == "cpu" and rows is None:
-        return None
+        return device
     if rows is None:
         rows = cfg.batch_size
     if rows <= 0:
-        return None
+        return device
     work = ps_param_dim(cfg) * rows
     if work < _PS_AUTO_NUMPY_THRESHOLD:
         return "numpy"
     if jax.default_backend() == "cpu" or work >= _PS_AUTO_CPU_THRESHOLD:
-        return None
+        return device
     # raises when JAX_PLATFORMS names no cpu backend: the operator
     # excluded the host, so "auto" says so rather than pick for them
     return jax.devices("cpu")[0]
+
+
+def _jax_device(choice):
+    """The jax device a :func:`ps_compute_device` choice that is not
+    ``"numpy"`` computes on: None is the default backend's first."""
+    return jax.devices()[0] if choice is None else choice
 
 
 def _describe_compute_device(device) -> str:
     """Log form of a :func:`ps_compute_device` choice."""
     if device == "numpy":
         return "numpy (host, no jax)"
-    d = jax.devices()[0] if device is None else device
+    d = _jax_device(device)
     return f"{d.platform}:{d.device_kind} (id {d.id})"
+
+
+def worker_devices(n: int) -> list:
+    """The step device of each of a process's ``n`` PS workers: worker
+    *i* takes local device ``i % len(devices)`` of the default backend,
+    so four workers on a four-chip host compute on a chip each, more
+    workers than devices wrap, and one device serves every worker."""
+    devices = jax.local_devices()
+    return [devices[i % len(devices)] for i in range(n)]
 
 
 def _np_dense_grad(w, X, y, mask, l2_c, l2_scale_by_batch, num_classes=None):
@@ -582,6 +611,15 @@ class PSWorker:
     ``"numpy"``).  Minibatch workers, and every keyed model, stream numpy
     batches from host RAM, one ``device_put`` a step.
 
+    Which device.  ``ps_compute_device`` decides host or accelerator
+    from the step's size; which accelerator device is the job's to say
+    (``device``: ``run_ps_workers`` hands worker *i* of a process local
+    device ``i % len(devices)``, ``worker_devices``), and the shard, the
+    round's weights, the step, the gradient's readback and the eval all
+    follow it.  ``distlr_ps_step_device{rank}`` is its id, and the
+    ``pinned`` log line names it.  Without one the worker takes the
+    default backend's first device.
+
     How a resident shard is held, and what reads it.  Where the model is
     a ``BinaryLR`` without ``int8_dot``, the device a TPU, the rows whole
     groups of eight and VMEM holds at least a part of a row panel
@@ -615,9 +653,13 @@ class PSWorker:
     ``barrier_wait``, ``eval``, ``checkpoint``.
     """
 
-    def __init__(self, cfg: Config, rank: int, hosts: str, *, train_iter=None, test_iter=None):
+    def __init__(self, cfg: Config, rank: int, hosts: str, *, train_iter=None,
+                 test_iter=None, device=None):
         self.cfg = cfg
         self.rank = rank
+        #: the device of the default backend the job gave this worker
+        #: (``worker_devices``); None is the backend's first
+        self._device = device
         self.model = get_model(cfg)
         if cfg.feature_dtype != "float32":
             # PS workers stream float32 numpy batches from host RAM per
@@ -861,9 +903,10 @@ class PSWorker:
         # size their choice independently (a tiny minibatch must not
         # drag a huge full-test-set eval onto the host CPU).
         train_rows = cfg.batch_size if cfg.batch_size > 0 else train.num_samples
-        step_dev = ps_compute_device(cfg, train_rows)
-        self._eval_dev = (ps_compute_device(cfg, test.num_samples)
-                          if test is not None else None)
+        step_dev = ps_compute_device(cfg, train_rows, self._device)
+        self._eval_dev = (
+            ps_compute_device(cfg, test.num_samples, self._device)
+            if test is not None else None)
         log.info(
             "rank %d dense steps pinned: train -> %s%s (ps_compute_backend=%s)",
             self.rank, _describe_compute_device(step_dev),
@@ -881,12 +924,13 @@ class PSWorker:
         else:
             self._resident = self._place_shard(train, step_dev)
             rank = str(self.rank)
+            _STEP_DEVICE.labels(rank=rank).set(_jax_device(step_dev).id)
             plan = self._panels
             _PANEL_HELD.labels(rank=rank).set(plan.held_share if plan else 0.0)
             # the kernel is interpreted off the TPU (tests)
             one_pass = {} if plan is None else dict(
                 panels=plan,
-                interpret=(step_dev or jax.devices()[0]).platform != "tpu")
+                interpret=_jax_device(step_dev).platform != "tpu")
 
             def grad_step(wf, batch):
                 # the plan goes with the resident batch alone: its X is
@@ -925,7 +969,7 @@ class PSWorker:
         if batch is None:
             train.reset()
             batch = train.next_batch()
-        device = step_dev or jax.devices()[0]
+        device = _jax_device(step_dev)
         mesh = make_mesh(devices=[device])
         self._panels = _one_pass_plan(self.model, *batch[0].shape, device)
         with self._span("shard_put"):
@@ -1491,8 +1535,11 @@ def run_ps_workers(cfg: Config, hosts: str, ranks, *, eval_fn=None, save=False,
     subset of ranks against remote servers (started via
     ``python -m distlr_tpu.launch ps-server`` or :class:`ServerGroup`).
 
-    Worker threads share one JAX backend/jit cache; each blocks
-    independently in the native client (the GIL is released during
+    Worker threads share one JAX backend/jit cache; worker *i* of the
+    call computes on local device ``i % len(devices)``
+    (:func:`worker_devices`: a chip each where the host has as many
+    chips as workers, all on the one device where it has one); each
+    blocks independently in the native client (the GIL is released during
     ctypes calls), so async staleness is real.  ``on_error`` runs once
     if any worker raises (local mode uses it to tear the servers down so
     peers blocked on the sync barrier fail fast instead of hanging).
@@ -1516,7 +1563,9 @@ def run_ps_workers(cfg: Config, hosts: str, ranks, *, eval_fn=None, save=False,
         bump_resume_attempt(cfg)
     results: dict[int, np.ndarray | None] = {r: None for r in ranks}
     errors: list[Exception] = []
-    workers = [PSWorker(cfg, r, hosts) for r in ranks]
+    devices = worker_devices(len(ranks))
+    workers = [PSWorker(cfg, r, hosts, device=d)
+               for r, d in zip(ranks, devices)]
 
     def run_one(i, r):
         attempts = 0
@@ -1548,7 +1597,8 @@ def run_ps_workers(cfg: Config, hosts: str, ranks, *, eval_fn=None, save=False,
                 deadline = time.monotonic() + 5.0
                 while True:
                     try:
-                        workers[i] = PSWorker(cfg, r, hosts)
+                        workers[i] = PSWorker(cfg, r, hosts,
+                                              device=devices[i])
                         break
                     except Exception as e2:
                         if time.monotonic() >= deadline:

@@ -160,7 +160,8 @@ def test_a_matrix_that_was_not_padded_is_refused():
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def chips():
+    """A described v5e 2x2's four chips, one sharding each."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -169,17 +170,19 @@ def one_chip():
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return [SingleDeviceSharding(d) for d in topo.devices]
 
 
-@pytest.mark.parametrize("limit", [None, 64 << 20],
-                         ids=["all-held", "part-held"])
-def test_compiles_for_a_v5e_at_the_cells_size(one_chip, limit):
+@pytest.mark.parametrize("limit,rows,chip", [
+    (None, 384, 0), (64 << 20, 384, 0), (None, 1152, 2)],
+    ids=["all-held", "part-held", "four-chip-cells-shard-on-chip-2"])
+def test_compiles_for_a_v5e_at_the_cells_size(chips, limit, rows, chip):
     """Mosaic takes the kernel at 384 x 1,000,000 float32 under the
-    default limit and under one that holds part of a panel, and XLA
-    hands it the resident shard as it lies: no copy, transpose or
-    reshape of the 1.5 GB operand in the step's program."""
-    rows, dim = 384, 1_000_000
+    default limit and under one that holds part of a panel, and at the
+    1,152 rows a worker of the four-chip cell keeps, for a chip that is
+    not the first; XLA hands it the resident shard as it lies: no copy,
+    transpose or reshape of the operand in the step's program."""
+    one_chip, dim = chips[chip], 1_000_000
     plan = (panel_plan(rows, dim) if limit is None
             else panel_plan(rows, dim, vmem_limit=limit))
     cfg = types.SimpleNamespace(l2_c=1.0, l2_scale_by_batch=False)

@@ -2,7 +2,9 @@
 ``sync_hold_seconds``, ``sync_spread_seconds``, ``cpu_release_seconds``),
 its mirror in the registry, and a reply from before the tail.  Behind
 them ``run_frames``: the pushes and pulls a server handled as one range
-of slots (every default-key op of a dense job, no scattered frame)."""
+of slots (every default-key op of a dense job, no scattered frame), and
+``lock_wait_seconds``: what the push handlers stood waiting for the
+server's one lock."""
 
 import socket
 import struct
@@ -47,9 +49,10 @@ def _rounds(group, sync, delays):
 
 
 def test_the_tail_stands_after_epoch_in_the_wires_order():
-    assert STATS_FIELDS[-len(TAIL) - 1:] == TAIL + ("run_frames",)
-    assert STATS_FIELDS[-len(TAIL) - 2] == "epoch"
-    assert len(STATS_FIELDS) == wire.STATS_VALS == 16
+    assert STATS_FIELDS[-len(TAIL) - 2:] == TAIL + (
+        "run_frames", "lock_wait_seconds")
+    assert STATS_FIELDS[-len(TAIL) - 3] == "epoch"
+    assert len(STATS_FIELDS) == wire.STATS_VALS == 17
 
 
 @pytest.mark.parametrize("sync", [True, False], ids=["bsp", "async"])
@@ -149,14 +152,15 @@ def test_a_keyed_job_of_scattered_frames_counts_no_run(sync):
 
 def test_a_request_of_the_old_length_is_still_answered():
     """A client from before ``run_frames`` asks for fifteen counters and
-    gets fifteen, the barrier's tail last; one that asks for more than
-    there are gets what there is."""
+    gets fifteen, the barrier's tail last, one from before
+    ``lock_wait_seconds`` sixteen; one that asks for more than there are
+    gets what there is."""
     with ServerGroup(1, 1, DIM, sync=False) as g:
         with KVWorker(g.hosts, DIM, client_id=0, sync_group=False) as kv:
             kv.wait(kv.push_init(np.ones(DIM, np.float32)))
             kv.pull()
         with socket.create_connection(("127.0.0.1", g.ports[0])) as s:
-            for aux, slots in ((15, 15), (16, 16), (99, 16)):
+            for aux, slots in ((15, 15), (16, 16), (17, 17), (99, 17)):
                 s.sendall(wire.HEADER_STRUCT.pack(
                     wire.MAGIC, wire.OP_STATS, 0, aux, 7, 1, 0))
                 hdr = s.recv(wire.HEADER_STRUCT.size, socket.MSG_WAITALL)
@@ -168,10 +172,12 @@ def test_a_request_of_the_old_length_is_still_answered():
                 assert named["total_pushes"] == 1
                 assert named["total_pulls"] == 1
                 assert named.get("run_frames", 2) == 2
-                assert ("run_frames" in named) == (slots == 16)
+                assert ("run_frames" in named) == (slots >= 16)
+                assert ("lock_wait_seconds" in named) == (slots == 17)
+                assert named.get("lock_wait_seconds", 0.0) >= 0.0
 
 
-@pytest.mark.parametrize("slots", [wire.STATS_VALS_V1, 11, 15])
+@pytest.mark.parametrize("slots", [wire.STATS_VALS_V1, 11, 15, 16])
 def test_a_reply_from_before_the_tail_still_parses(slots):
     with socket.socket() as listener:
         listener.bind(("127.0.0.1", 0))
@@ -184,5 +190,50 @@ def test_a_reply_from_before_the_tail_still_parses(slots):
             got = kv.stats(0)
         server.join(timeout=5)
     assert list(got) == list(STATS_FIELDS[:slots])
-    assert got["total_pushes"] == 5 and "run_frames" not in got
-    assert set(TAIL) <= set(got) if slots == 15 else not set(TAIL) & set(got)
+    assert got["total_pushes"] == 5 and "lock_wait_seconds" not in got
+    assert ("run_frames" in got) == (slots == 16)
+    assert set(TAIL) <= set(got) if slots >= 15 else not set(TAIL) & set(got)
+
+
+@pytest.mark.parametrize("sync", [True, False], ids=["bsp", "async"])
+def test_lock_wait_rises_where_four_pushes_arrive_at_once(sync):
+    """Four workers let go at the same instant, round after round, on a
+    vector wide enough that a merge or an apply holds the lock for a
+    while: a push stands behind its peers', and the counter says for how
+    long.  One worker alone waits for nobody."""
+    dim, workers, rounds = 1 << 20, 4, 12
+    grad = np.full(dim, 1e-3, np.float32)
+    with ServerGroup(1, workers, dim, sync=sync) as g, \
+            KVWorker(g.hosts, dim, client_id=0xFC00) as probe:
+        probe.wait(probe.push_init(np.zeros(dim, np.float32)))
+        before = probe.stats(0)
+        assert before["lock_wait_seconds"] < 0.05
+        kvs = [KVWorker(g.hosts, dim, client_id=r, sync_group=sync)
+               for r in range(workers)]
+        gate = threading.Barrier(workers)
+
+        def loop(kv):
+            for _ in range(rounds):
+                gate.wait()
+                kv.push_pull(grad)
+
+        threads = [threading.Thread(target=loop, args=(kv,)) for kv in kvs]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        for kv in kvs:
+            kv.close()
+        after = probe.stats(0)
+        health = g.health()
+    assert after["total_pushes"] - before["total_pushes"] == workers * rounds
+    waited = after["lock_wait_seconds"] - before["lock_wait_seconds"]
+    assert isinstance(after["lock_wait_seconds"], float) and waited > 0
+    # no push can have waited longer than the whole exchange lasted
+    if sync:
+        assert waited <= after["sync_hold_seconds"] + 1e-3
+    assert health[0]["lock_wait_seconds"] >= after["lock_wait_seconds"]
+    mirrored = dict(get_registry().get(
+        "distlr_ps_server_lock_wait_seconds").children())
+    assert mirrored[("0",)].value == pytest.approx(
+        health[0]["lock_wait_seconds"])
