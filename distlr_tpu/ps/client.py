@@ -163,7 +163,8 @@ def _observe_op(op: str, *, sent=0, received: int = 0,
 #: in the stats dict while the others stay ints.  A pre-extension server
 #: replies only the first six, one from before the BSP tail eleven, one
 #: from before ``run_frames`` fifteen, one from before
-#: ``lock_wait_seconds`` sixteen; the probe reports what arrived.
+#: ``lock_wait_seconds`` sixteen, one from before the release's fan-out
+#: seventeen; the probe reports what arrived.
 STATS_FIELDS = (
     "dim",
     "initialized",
@@ -182,7 +183,8 @@ STATS_FIELDS = (
     # the BSP barrier's additive tail (zeros from an async server):
     # rounds released; seconds released pushes were held, arrival to own
     # reply; seconds from a round's first arrival to its last; thread-CPU
-    # seconds of the release (also inside cpu_push_seconds)
+    # seconds of the release, its writers' included (also inside
+    # cpu_push_seconds)
     "sync_rounds",
     "sync_hold_seconds",
     "sync_spread_seconds",
@@ -196,6 +198,13 @@ STATS_FIELDS = (
     # lock, over total_pushes: near zero where pushes arrive apart, what
     # a merge pays first where W workers push at the same instant
     "lock_wait_seconds",
+    # the BSP release's fan-out (zeros from an async server): a round's
+    # value-carrying replies are written side by side, one thread each;
+    # those written by a thread other than the releasing one (W - 1 a
+    # round of W fused pushes, 0 for header-only rounds), and the wall
+    # seconds of the releases, last merge done to last reply written
+    "release_fanned_replies",
+    "release_wall_seconds",
 )
 
 # The field list IS a wire mirror: its length must track kStatsVals and

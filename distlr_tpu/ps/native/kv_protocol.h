@@ -135,8 +135,9 @@ enum class Op : uint8_t {
 // released pushes of the wall from a push's arrival to its own reply
 // written), sync_spread_seconds (sum over rounds of last arrival minus
 // first), cpu_release_seconds (thread CPU of the release: apply, clear,
-// W gathers and replies; it runs on the last voter's thread, so the
-// same cycles also stand in cpu_push_seconds).
+// W gathers and replies, on the last voter's thread and, for the
+// replies it hands them, the server's writers; the same cycles also
+// stand in cpu_push_seconds).
 // Slot 15 (additive after the barrier's tail): run_frames, of the
 // operations total_pushes and total_pulls count, those whose keys were
 // one ascending consecutive run (keys[i] == keys[0] + i), handled as
@@ -151,8 +152,20 @@ enum class Op : uint8_t {
 // zero where pushes arrive apart, and the first thing a merge pays
 // where W workers on W chips push at the same instant (each behind the
 // others' merges, and all behind a release that is still answering).
+// Slots 17 and 18 (additive after lock_wait_seconds; zeros from an
+// async server), the release's fan-out: a round's replies that carry
+// values (a fused push's post-round weights) are written side by side,
+// one thread a reply, where the round holds more than one, each on a
+// connection of its own; header-only replies stay on the releasing
+// thread, which also writes one of the fused ones and holds the
+// server's lock until the last is out.  release_fanned_replies counts
+// the replies written by a thread other than the releasing one (W - 1
+// a round of W fused pushes, 0 for header-only rounds): how often the
+// fan-out engages.  release_wall_seconds is the wall from the last
+// voter's merge done to the last reply written, summed over
+// sync_rounds: the release's length, which the lock is held for.
 constexpr uint64_t kStatsValsV1 = 6;
-constexpr uint64_t kStatsVals = 17;
+constexpr uint64_t kStatsVals = 19;
 
 enum Flags : uint8_t {
   kNone = 0,

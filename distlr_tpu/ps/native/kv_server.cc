@@ -171,9 +171,11 @@ struct PendingPush {
   // kPushPull: the deferred reply carries the post-round weights for
   // this push's keys (the fused pull half) instead of an empty frame.
   bool want_vals = false;
-  // BSP: when the push joined the round (MonoNowS), for the barrier's
-  // hold and spread counters (kStats sync_* tail).
+  // BSP: when the push joined the round and when its own reply had been
+  // written (MonoNowS), for the barrier's hold and spread counters
+  // (kStats sync_* tail).
   double arrived_s = 0.0;
+  double replied_s = 0.0;
 
   Rows rows() const { return {keys.data(), keys.size(), vpk, run}; }
 };
@@ -427,6 +429,8 @@ class KVServer {
       std::unique_lock<std::mutex> lock(mu_);
       serves_done_.wait(lock, [this] { return live_serves_ == 0; });
     }
+    // no connection is left to release a round: the writers stand idle
+    StopWriters();
     close(listen_fd_);
     // bounded wait for the detached profiler loop (it polls shutdown_
     // every 100ms) so the final window write below cannot race it
@@ -1092,6 +1096,106 @@ class KVServer {
     return reply.data();
   }
 
+  // One deferred reply of a BSP release (the releasing thread holds
+  // mu_): a header, or for a fused push the post-round weights of its
+  // keys — a run straight out of weights_, scattered rows gathered into
+  // `rows_buf`, the writing thread's own.
+  void WriteReply(PendingPush& p, std::vector<Val>& rows_buf) {
+    const Rows pr = p.rows();
+    if (!p.want_vals) {
+      Respond(p.fd, p.header, nullptr, 0);
+    } else if (pr.run) {
+      Respond(p.fd, p.header, weights_.data() + pr.keys[0] * pr.vpk,
+              pr.flat());
+    } else {
+      CopyRows(weights_, pr, SizedFor(rows_buf, pr.flat()));
+      Respond(p.fd, p.header, rows_buf.data(), pr.flat());
+    }
+    p.replied_s = MonoNowS();
+  }
+
+  // Each of a release's replies on a connection of its own?  Two on one
+  // socket (a client that pushed twice before it waited) must leave in
+  // the order they came, by one thread.
+  static bool DistinctFds(const std::vector<PendingPush>& release) {
+    std::vector<int> fds;
+    fds.reserve(release.size());
+    for (const auto& p : release) fds.push_back(p.fd);
+    std::sort(fds.begin(), fds.end());
+    return std::adjacent_find(fds.begin(), fds.end()) == fds.end();
+  }
+
+  // --- the release's writers: threads the server keeps (started at the
+  // first release that wants them, one a reply up to kMaxWriters, never
+  // a round) that write the replies a releasing thread hands them while
+  // it holds mu_, so they read weights_ and the round's pushes with no
+  // lock of their own; wr_mu_ orders the hand-over and the join, and is
+  // only ever taken inside mu_ or alone. ---
+  static constexpr size_t kMaxWriters = 15;
+
+  // Hand jobs[0, n) to the writers; returns n, or 0 where no writer
+  // could be started (the caller then writes them itself).
+  size_t HandToWriters(PendingPush** jobs, size_t n) {
+    std::lock_guard<std::mutex> lk(wr_mu_);
+    while (wr_threads_ < std::min(n, kMaxWriters)) {
+      ++wr_threads_;
+      if (!SpawnDetached(&KVServer::WriterTrampoline, this)) {
+        --wr_threads_;
+        break;
+      }
+    }
+    if (wr_threads_ == 0) return 0;
+    wr_jobs_ = jobs;
+    wr_todo_ = wr_left_ = n;
+    wr_work_.notify_all();
+    return n;
+  }
+
+  // Wait for the last handed reply; the writers' thread-CPU over them.
+  double JoinWriters() {
+    std::unique_lock<std::mutex> lk(wr_mu_);
+    wr_idle_.wait(lk, [this] { return wr_left_ == 0; });
+    const double cpu_s = wr_cpu_s_;
+    wr_cpu_s_ = 0.0;
+    return cpu_s;
+  }
+
+  void StopWriters() {
+    std::unique_lock<std::mutex> lk(wr_mu_);
+    wr_stop_ = true;
+    wr_work_.notify_all();
+    wr_idle_.wait(lk, [this] { return wr_threads_ == 0; });
+  }
+
+  void WriterLoop() {
+    std::vector<Val> rows_buf;  // a scattered reply's values, this writer's
+    std::unique_lock<std::mutex> lk(wr_mu_);
+    while (true) {
+      wr_work_.wait(lk, [this] { return wr_stop_ || wr_todo_ > 0; });
+      if (wr_todo_ == 0) break;  // stopped, nothing handed
+      PendingPush* p = wr_jobs_[--wr_todo_];
+      lk.unlock();
+      const double cpu0 = ThreadCpuNowS();
+      WriteReply(*p, rows_buf);
+      const double cpu_s = ThreadCpuNowS() - cpu0;
+      // the release is the push handler's work, whoever burns it
+      cpu_us_[kCpuPush].fetch_add(static_cast<uint64_t>(1e6 * cpu_s),
+                                  std::memory_order_relaxed);
+      lk.lock();
+      wr_cpu_s_ += cpu_s;
+      if (--wr_left_ == 0) wr_idle_.notify_all();
+    }
+    // notify UNDER the mutex, as Serve does: Run() may destroy the
+    // object once it sees no writer left, and cannot before lk is gone
+    --wr_threads_;
+    wr_idle_.notify_all();
+  }
+
+  static void* WriterTrampoline(void* p) {
+    static_cast<KVServer*>(p)->WriterLoop();
+    return nullptr;
+  }
+
   // --- PUSH: the reference DataHandle push branch (src/main.cc:48-84).
   // reply_weights = fused kPushPull: the reply carries the post-update
   // weights for the pushed keys (see kv_protocol.h), copied into
@@ -1197,8 +1301,9 @@ class KVServer {
 
     if (static_cast<int>(pending_.size()) == num_workers_) {
       // The round's counters (kStats sync_* tail, kv_protocol.h): the
-      // release runs on this voter's thread, so its thread-CPU is also
-      // inside cpu_push_seconds.
+      // release runs on this voter's thread and the server's writers,
+      // whose thread-CPU all stands in cpu_push_seconds too.
+      const double release_t0 = MonoNowS();
       const double release_cpu0 = ThreadCpuNowS();
       // pending_ is in arrival order (pushed under mu_)
       sync_spread_s_ += pending_.back().arrived_s - pending_.front().arrived_s;
@@ -1265,27 +1370,45 @@ class KVServer {
       std::fill(merge_.begin(), merge_.end(), 0.0f);
       std::vector<PendingPush> release;
       release.swap(pending_);
-      // Releasing every deferred reply at once IS the BSP barrier.
-      // Written under mu_ (weights are read for fused replies): a racing
-      // kShutdown holds mu_ while severing other connections, so it
-      // cannot cut a release loop midway and strand a peer without its
-      // reply.  Fused (kPushPull) pushes get the post-round weights for
-      // their keys — exactly what their next pull would have returned —
-      // a run straight out of weights_, which mu_ holds still.
+      // Releasing every deferred reply at once IS the BSP barrier, and
+      // the replies that carry values leave at once too: a fused
+      // (kPushPull) push gets the post-round weights for its keys —
+      // exactly what its next pull would have returned, a run straight
+      // out of weights_ — and where a round holds more than one such
+      // reply, each on a connection of its own, the server's writers
+      // write all but one side by side while this thread writes the
+      // last.  One after another on this thread they were a ring on W
+      // chips: the worker answered last began its next round last and
+      // was answered last again, so every reply's 2 MB writev stood in
+      // every round's period, and a peer slow to read its reply held
+      // up the replies queued behind it.  Header-only replies (a plain
+      // kPush, an empty vote) are 24 bytes each and stay on this thread.
+      // mu_ stays held until the last writer is done: weights_ stands
+      // still under them with no copy, merge_ is clear before any worker
+      // can push again, and a racing kShutdown, which takes mu_ to sever
+      // the other connections, cannot cut a release midway and strand a
+      // peer without its reply.  (A connection that closes meanwhile
+      // waits in DropConnection for mu_, so no reply goes to a recycled
+      // fd.)
+      std::vector<PendingPush*> fused;
+      for (auto& p : release)
+        if (p.want_vals && !p.keys.empty()) fused.push_back(&p);
+      const size_t fanned = fused.size() > 1 && DistinctFds(release)
+                                ? HandToWriters(fused.data(), fused.size() - 1)
+                                : 0;
+      size_t handed = 0;  // fused[0, fanned) are the writers'
       for (auto& p : release) {
-        const Rows pr = p.rows();
-        if (!p.want_vals) {
-          Respond(p.fd, p.header, nullptr, 0);
-        } else if (pr.run) {
-          Respond(p.fd, p.header, weights_.data() + pr.keys[0] * pr.vpk,
-                  pr.flat());
-        } else {
-          CopyRows(weights_, pr, SizedFor(reply, pr.flat()));
-          Respond(p.fd, p.header, reply.data(), pr.flat());
-        }
-        // held from its arrival until its own reply was written: the
-        // later a push stands in the release, the longer
-        sync_hold_s_ += MonoNowS() - p.arrived_s;
+        if (handed < fanned && &p == fused[handed]) ++handed;
+        else WriteReply(p, reply);
+      }
+      if (fanned) {
+        cpu_release_s_ += JoinWriters();
+        release_fanned_ += fanned;
+      }
+      release_wall_s_ += MonoNowS() - release_t0;
+      for (auto& p : release) {
+        // held from its arrival until its own reply was written
+        sync_hold_s_ += p.replied_s - p.arrived_s;
         // its buffer goes to the next round's frames (HandlePush above)
         if (spare_vals_.size() < static_cast<size_t>(num_workers_) &&
             !p.vals.empty()) {
@@ -1429,6 +1552,10 @@ class KVServer {
       tail[4] = static_cast<double>(run_frames_);
       // slot 16: seconds the push handlers waited for mu_
       tail[5] = lock_wait_s_;
+      // slots 17 and 18: the release's replies written by its writers,
+      // and the releases' wall seconds
+      tail[6] = static_cast<double>(release_fanned_);
+      tail[7] = release_wall_s_;
     }
     // per-handler thread-CPU seconds (the continuous-profiling
     // extension; atomic — no mu_ needed)
@@ -1546,10 +1673,11 @@ class KVServer {
     release.swap(waiters);
     barrier_.erase(id);
     released_barriers_.insert(id);
-    // Replies written under mu_ — see HandlePush's release loop: the
+    // Replies written under mu_ — see HandlePush's release: the
     // exit-barrier reply to rank 0 triggers its kShutdown, whose
     // connection-severing loop takes mu_ and must not interleave here
-    // (it would strand peers mid-release without their replies).
+    // (it would strand peers mid-release without their replies).  A
+    // header each, so on this thread: the writers are for values.
     for (auto& p : release) Respond(p.fd, p.header, nullptr, 0);
   }
 
@@ -2234,7 +2362,8 @@ class KVServer {
   //: BSP round counters (guarded by mu_; kStats sync_* tail): rounds
   //: released, seconds released pushes were held (arrival to own
   //: reply written), seconds between a round's first and last arrival,
-  //: thread-CPU seconds of the release (apply, clear, gathers, replies)
+  //: thread-CPU seconds of the release (apply, clear, gathers, replies;
+  //: the writers' share of the replies included)
   uint64_t sync_rounds_ = 0;
   double sync_hold_s_ = 0.0;
   double sync_spread_s_ = 0.0;
@@ -2246,6 +2375,23 @@ class KVServer {
   //: wall seconds the push handlers stood waiting for mu_ (guarded by
   //: mu_: added once it is held; kStats lock_wait_seconds)
   double lock_wait_s_ = 0.0;
+  //: the release's fan-out (guarded by mu_; kStats slots 17 and 18):
+  //: replies written by a writer and not by the releasing thread, and
+  //: wall seconds of the releases, last merge done to last reply written
+  uint64_t release_fanned_ = 0;
+  double release_wall_s_ = 0.0;
+  //: the release's writers (all guarded by wr_mu_): the replies handed
+  //: over (the first wr_todo_ not yet taken, wr_left_ not yet written),
+  //: the writers' thread-CPU since the last join, and the threads alive
+  std::mutex wr_mu_;
+  std::condition_variable wr_work_;
+  std::condition_variable wr_idle_;
+  PendingPush** wr_jobs_ = nullptr;
+  size_t wr_todo_ = 0;
+  size_t wr_left_ = 0;
+  double wr_cpu_s_ = 0.0;
+  size_t wr_threads_ = 0;
+  bool wr_stop_ = false;
   //: value buffers of released BSP pushes, at most one a worker, for
   //: the connections' next frames (guarded by mu_; HandlePush)
   std::vector<std::vector<Val>> spare_vals_;

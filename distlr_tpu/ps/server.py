@@ -55,8 +55,11 @@ _SERVER_CPU = _reg.gauge(
 )
 #: The kStats tail's counters with a series of their own, mirrored by
 #: every health() probe: the BSP barrier's (an async group reads zeros)
-#: ``run_frames``, how much of a rank's traffic its run path took, and
-#: ``lock_wait_seconds``, what its pushes stood waiting for its lock.
+#: ``run_frames``, how much of a rank's traffic its run path took,
+#: ``lock_wait_seconds``, what its pushes stood waiting for its lock, and
+#: the BSP release's ``release_fanned_replies`` and
+#: ``release_wall_seconds``: how often its replies left side by side,
+#: and how long a release (and so the lock it holds) lasted.
 _SERVER_TAIL = {
     "sync_rounds": _reg.gauge(
         "distlr_ps_server_sync_rounds",
@@ -76,7 +79,8 @@ _SERVER_TAIL = {
     "cpu_release_seconds": _reg.gauge(
         "distlr_ps_server_sync_release_cpu_seconds",
         "cumulative thread CPU seconds of the BSP release (apply, "
-        "clear, the W gathers and replies); also inside "
+        "clear, the W gathers and replies, the writers' share "
+        "included); also inside "
         "distlr_kv_server_cpu_seconds{handler=\"push\"}",
         labelnames=("rank",)),
     "run_frames": _reg.gauge(
@@ -91,6 +95,19 @@ _SERVER_TAIL = {
         "cumulative wall seconds this server rank's push handlers stood "
         "waiting for its one lock (behind other pushes' merges and the "
         "BSP release), from the latest health probe",
+        labelnames=("rank",)),
+    "release_fanned_replies": _reg.gauge(
+        "distlr_ps_server_release_fanned_replies",
+        "deferred BSP replies this server rank had written by a thread "
+        "other than the releasing one (a round's value-carrying replies "
+        "leave side by side: W - 1 a round of W fused pushes, 0 for "
+        "header-only rounds), from the latest health probe",
+        labelnames=("rank",)),
+    "release_wall_seconds": _reg.gauge(
+        "distlr_ps_server_release_wall_seconds",
+        "cumulative wall seconds of this server rank's BSP releases, "
+        "from the last voter's merge done to the last reply written "
+        "(its lock is held that long), from the latest health probe",
         labelnames=("rank",)),
 }
 _SUP_EVENTS = _reg.counter(

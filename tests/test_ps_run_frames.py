@@ -74,9 +74,15 @@ class Raw:
         magic, _op, flags, _aux, _cid, ts, n = wire.HEADER_STRUCT.unpack(hdr)
         assert magic == wire.MAGIC and flags & wire.FLAG_RESPONSE
         assert not flags & wire.FLAG_ERROR and ts == self.ts
-        body = self.s.recv(4 * n, socket.MSG_WAITALL) if n else b""
-        assert len(body) == 4 * n
-        return np.frombuffer(body, dtype=F32).copy()
+        # piece by piece: one MSG_WAITALL read of a body larger than the
+        # socket's buffers comes back short
+        body = bytearray(4 * n)
+        view, at = memoryview(body), 0
+        while at < len(body):
+            got = self.s.recv_into(view[at:])
+            assert got, "the server closed the connection inside a reply"
+            at += got
+        return np.frombuffer(body, dtype=F32)
 
     def call(self, *a, **kw) -> np.ndarray:
         self.send(*a, **kw)

@@ -4,7 +4,9 @@ its mirror in the registry, and a reply from before the tail.  Behind
 them ``run_frames``: the pushes and pulls a server handled as one range
 of slots (every default-key op of a dense job, no scattered frame), and
 ``lock_wait_seconds``: what the push handlers stood waiting for the
-server's one lock."""
+server's one lock; and last the release's fan-out,
+``release_fanned_replies`` and ``release_wall_seconds``
+(``test_ps_release_fanout.py`` has what they count)."""
 
 import socket
 import struct
@@ -49,10 +51,11 @@ def _rounds(group, sync, delays):
 
 
 def test_the_tail_stands_after_epoch_in_the_wires_order():
-    assert STATS_FIELDS[-len(TAIL) - 2:] == TAIL + (
-        "run_frames", "lock_wait_seconds")
-    assert STATS_FIELDS[-len(TAIL) - 3] == "epoch"
-    assert len(STATS_FIELDS) == wire.STATS_VALS == 17
+    assert STATS_FIELDS[-len(TAIL) - 4:] == TAIL + (
+        "run_frames", "lock_wait_seconds",
+        "release_fanned_replies", "release_wall_seconds")
+    assert STATS_FIELDS[-len(TAIL) - 5] == "epoch"
+    assert len(STATS_FIELDS) == wire.STATS_VALS == 19
 
 
 @pytest.mark.parametrize("sync", [True, False], ids=["bsp", "async"])
@@ -153,14 +156,15 @@ def test_a_keyed_job_of_scattered_frames_counts_no_run(sync):
 def test_a_request_of_the_old_length_is_still_answered():
     """A client from before ``run_frames`` asks for fifteen counters and
     gets fifteen, the barrier's tail last, one from before
-    ``lock_wait_seconds`` sixteen; one that asks for more than there are
-    gets what there is."""
+    ``lock_wait_seconds`` sixteen, one from before the release's fan-out
+    seventeen; one that asks for more than there are gets what there is."""
     with ServerGroup(1, 1, DIM, sync=False) as g:
         with KVWorker(g.hosts, DIM, client_id=0, sync_group=False) as kv:
             kv.wait(kv.push_init(np.ones(DIM, np.float32)))
             kv.pull()
         with socket.create_connection(("127.0.0.1", g.ports[0])) as s:
-            for aux, slots in ((15, 15), (16, 16), (17, 17), (99, 17)):
+            for aux, slots in ((15, 15), (16, 16), (17, 17), (18, 18),
+                               (19, 19), (99, 19)):
                 s.sendall(wire.HEADER_STRUCT.pack(
                     wire.MAGIC, wire.OP_STATS, 0, aux, 7, 1, 0))
                 hdr = s.recv(wire.HEADER_STRUCT.size, socket.MSG_WAITALL)
@@ -173,11 +177,15 @@ def test_a_request_of_the_old_length_is_still_answered():
                 assert named["total_pulls"] == 1
                 assert named.get("run_frames", 2) == 2
                 assert ("run_frames" in named) == (slots >= 16)
-                assert ("lock_wait_seconds" in named) == (slots == 17)
+                assert ("lock_wait_seconds" in named) == (slots >= 17)
                 assert named.get("lock_wait_seconds", 0.0) >= 0.0
+                assert ("release_wall_seconds" in named) == (slots == 19)
+                # an async server: the release's two read zero
+                assert named.get("release_fanned_replies", 0.0) == 0.0
+                assert named.get("release_wall_seconds", 0.0) == 0.0
 
 
-@pytest.mark.parametrize("slots", [wire.STATS_VALS_V1, 11, 15, 16])
+@pytest.mark.parametrize("slots", [wire.STATS_VALS_V1, 11, 15, 16, 17])
 def test_a_reply_from_before_the_tail_still_parses(slots):
     with socket.socket() as listener:
         listener.bind(("127.0.0.1", 0))
@@ -190,8 +198,9 @@ def test_a_reply_from_before_the_tail_still_parses(slots):
             got = kv.stats(0)
         server.join(timeout=5)
     assert list(got) == list(STATS_FIELDS[:slots])
-    assert got["total_pushes"] == 5 and "lock_wait_seconds" not in got
-    assert ("run_frames" in got) == (slots == 16)
+    assert got["total_pushes"] == 5 and "release_fanned_replies" not in got
+    assert ("run_frames" in got) == (slots >= 16)
+    assert ("lock_wait_seconds" in got) == (slots == 17)
     assert set(TAIL) <= set(got) if slots >= 15 else not set(TAIL) & set(got)
 
 

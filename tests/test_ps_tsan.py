@@ -12,7 +12,9 @@ sync/async sweep): the fused push_pull, FTRL with ``--opt_segments``
 per-namespace updates plus concurrent opt-state snapshots, the kEpoch
 fence and a live resize under concurrent clients, and codec-negotiated
 (int8 / signSGD) pushes, and rounds of run frames, gapped row keys and a
-push rolled back out of the merge (``test_ps_run_frames``' workload).
+push rolled back out of the merge (``test_ps_run_frames``' workload),
+and the BSP release's writers with a stats probe beside them and a
+shutdown racing a release.
 The CLIENT library's own TSan build is
 ``tests/test_sanitizer_matrix.py`` (it needs the runtime preloaded).
 """
@@ -23,6 +25,7 @@ import glob
 import os
 import shutil
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -304,3 +307,71 @@ def test_run_frame_rounds_under_tsan(tsan_env, sync):
         last, stats = run_frame_rounds(group, sync)
         assert_no_reports(group)
     check_run_frame_rounds(last, stats, sync)
+
+
+@needs_toolchain
+def test_release_fanout_under_tsan(tsan_env):
+    """The release's writers: four concurrent fused pushers through 24
+    rounds against two servers (a round's four replies written side by
+    side out of ``weights_``, the releasing thread holding the lock),
+    a stats probe running beside them all the while, and a ``kShutdown``
+    sent while the pushers are still going round, so that it races a
+    release — on the TSan server build.  The writers are gone before
+    the server is: exit code 0, no report."""
+    binary, assert_no_reports = tsan_env
+    dim, workers, rounds = 1 << 16, 4, 24
+    group = ServerGroup(2, workers, dim, learning_rate=0.05, sync=True,
+                        binary=binary)
+    with group:
+        with KVWorker(group.hosts, dim, client_id=0xFC00,
+                      timeout_ms=60_000, sync_group=False) as probe:
+            probe.wait(probe.push_init(np.zeros(dim, np.float32)))
+            done = threading.Event()
+            probed, probe_errors = [0], []
+
+            def prober():
+                try:
+                    while not done.is_set():
+                        probe.stats(0), probe.stats(1)
+                        probed[0] += 1
+                except Exception as e:  # noqa: BLE001 — asserted below
+                    probe_errors.append(e)
+
+            finished = [0] * workers
+
+            def run(rank: int):
+                grad = np.full(dim, 1e-3 * (rank + 1), np.float32)
+                with KVWorker(group.hosts, dim, client_id=rank,
+                              timeout_ms=60_000) as kv:
+                    for _ in range(rounds):
+                        kv.push_pull(grad)
+                        finished[rank] += 1
+                    try:    # on into the shutdown: any round may be cut
+                        while True:
+                            kv.push_pull(grad)
+                    except OSError:
+                        pass
+
+            watcher = threading.Thread(target=prober, daemon=True)
+            watcher.start()
+            pushers = [threading.Thread(target=run, args=(r,), daemon=True)
+                       for r in range(workers)]
+            for t in pushers:
+                t.start()
+            deadline = time.monotonic() + 180
+            while min(finished) < rounds:
+                assert time.monotonic() < deadline, finished
+                time.sleep(0.01)
+            done.set()
+            watcher.join(timeout=60)
+            stats = [probe.stats(r) for r in range(2)]
+            probe.shutdown_servers()
+            for t in pushers:
+                t.join(timeout=120)
+        assert not any(t.is_alive() for t in pushers), "pusher wedged"
+        assert not probe_errors, f"prober failed: {probe_errors[0]!r}"
+        assert probed[0] > 0
+        for s in stats:
+            assert s["sync_rounds"] >= rounds
+            assert s["release_fanned_replies"] == 3 * s["sync_rounds"]
+        assert_no_reports(group)
