@@ -68,13 +68,16 @@ class PhaseTracer:
         self._epoch = time.perf_counter()
         self._ids = itertools.count(1)
         # per thread: the open spans, innermost last, each
-        # [span id, seconds its finished children took]
+        # [span id, seconds its finished children took, step, rank]
         self._open = threading.local()
         self._hist = self._registry.histogram(
             "distlr_phase_seconds",
             "wall seconds spent per pipeline phase",
             labelnames=("phase",),
         )
+        # a phase's series, looked up once: a span is a few microseconds
+        # of the loop it times, on several threads at once
+        self._series: dict[str, object] = {}
 
     @contextlib.contextmanager
     def phase(self, name: str, step: int | None = None,
@@ -83,7 +86,7 @@ class PhaseTracer:
             stack = self._open.stack
         except AttributeError:
             stack = self._open.stack = []
-        frame = [next(self._ids), 0.0]
+        frame = [next(self._ids), 0.0, step, rank]
         parent = stack[-1][0] if stack else None
         stack.append(frame)
         t0 = time.perf_counter()
@@ -95,23 +98,50 @@ class PhaseTracer:
             stack.pop()
             if stack:
                 stack[-1][1] += dur
-            own = max(dur - frame[1], 0.0)
-            self._hist.labels(phase=name).observe(dur)
-            tid = threading.get_ident()
-            with self._lock:
-                tot = self._totals.get(name)
-                if tot is None:
-                    self._totals[name] = [dur, 1, own]
-                else:
-                    tot[0] += dur
-                    tot[1] += 1
-                    tot[2] += own
-                if len(self._events) < self._max_events:
-                    self._events.append(
-                        (name, tid, t0 - self._epoch, dur, frame[0], parent,
-                         step, rank))
-                else:
-                    self._dropped += 1
+            self._keep(name, t0, dur, max(dur - frame[1], 0.0), frame[0],
+                       parent, step, rank)
+
+    def completed(self, name: str, start: float, duration: float) -> None:
+        """Record a span that has already ended: ``start`` and
+        ``duration`` in seconds on ``time.perf_counter``'s clock, read by
+        whoever did the work (the native KV client notes the instants of
+        an exchange; ``CLOCK_MONOTONIC`` is that clock).  The span open
+        on the calling thread is its parent: it gives ``step`` and
+        ``rank`` and counts the duration among its children's, so its
+        ``self_seconds`` leaves it out.  With none open the span stands
+        alone.  It is in the breakdown, the histogram and the event
+        buffer as any span; it is no ``TraceAnnotation`` (one cannot be
+        entered after the fact), so a profiler trace's readers get it
+        from :meth:`chrome_trace`."""
+        stack = getattr(self._open, "stack", None)
+        parent = step = rank = None
+        if stack:
+            top = stack[-1]
+            top[1] += duration
+            parent, step, rank = top[0], top[2], top[3]
+        self._keep(name, start, duration, duration, next(self._ids), parent,
+                   step, rank)
+
+    def _keep(self, name, t0, dur, own, span_id, parent, step, rank) -> None:
+        series = self._series.get(name)
+        if series is None:
+            series = self._series[name] = self._hist.labels(phase=name)
+        series.observe(dur)
+        tid = threading.get_ident()
+        with self._lock:
+            tot = self._totals.get(name)
+            if tot is None:
+                self._totals[name] = [dur, 1, own]
+            else:
+                tot[0] += dur
+                tot[1] += 1
+                tot[2] += own
+            if len(self._events) < self._max_events:
+                self._events.append(
+                    (name, tid, t0 - self._epoch, dur, span_id, parent,
+                     step, rank))
+            else:
+                self._dropped += 1
 
     def breakdown(self) -> dict[str, dict]:
         """``{phase: {"seconds", "count", "self_seconds"}}`` accumulated

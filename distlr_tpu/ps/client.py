@@ -18,6 +18,7 @@ import numpy as np
 
 from distlr_tpu.obs import dtrace
 from distlr_tpu.obs.registry import family_total, get_registry
+from distlr_tpu.obs.tracing import get_tracer
 from distlr_tpu.ps import wire
 from distlr_tpu.ps.build import build_native, client_lib
 from distlr_tpu.utils.logging import get_logger
@@ -164,7 +165,8 @@ def _observe_op(op: str, *, sent=0, received: int = 0,
 #: replies only the first six, one from before the BSP tail eleven, one
 #: from before ``run_frames`` fifteen, one from before
 #: ``lock_wait_seconds`` sixteen, one from before the release's fan-out
-#: seventeen; the probe reports what arrived.
+#: seventeen, one from before a push's phases nineteen; the probe
+#: reports what arrived.
 STATS_FIELDS = (
     "dim",
     "initialized",
@@ -205,7 +207,133 @@ STATS_FIELDS = (
     # seconds of the releases, last merge done to last reply written
     "release_fanned_replies",
     "release_wall_seconds",
+    # a push's life on the server, phase by phase: wall seconds on the
+    # monotonic clock (this host's perf_counter too).  Over
+    # total_pushes: header read to keys and values read and decoded;
+    # the lock held to the push's own arithmetic done (the BSP merge;
+    # an async push's apply and its reply's copy).  Over the pushes of
+    # released rounds: merge done to the round's release begun, the wait
+    # for the later arrivals (0 for the last voter).  Over sync_rounds:
+    # the release's begin to the mean applied and the merge cleared.
+    # Over value-carrying replies: one reply's write begun to written,
+    # whichever thread wrote it.  An async server reads zeros in
+    # sync_wait_seconds and release_apply_seconds.
+    "recv_seconds",
+    "merge_seconds",
+    "sync_wait_seconds",
+    "release_apply_seconds",
+    "reply_write_seconds",
 )
+
+#: kStats counters of the native servers, refreshed by every kStats read
+#: (:func:`mirror_server_stats`: the native process cannot scrape itself
+#: — the Python side mirrors its protocol counters into the registry).
+#: Every name of STATS_FIELDS has a series here; the phases of a push
+#: (``recv_seconds`` ... ``reply_write_seconds``) have this one only.
+_SERVER_STAT = _reg.gauge(
+    "distlr_ps_server_stat",
+    "latest kStats read (a health probe, or any KVWorker.stats call of "
+    "this process) of each native server counter",
+    labelnames=("rank", "stat"),
+)
+#: Per-handler thread-CPU seconds of the native server ranks, mirrored
+#: from the kStats CPU extension by every kStats read — the series a
+#: fleet flamegraph's Python edge lines up against the C++ side with.
+_SERVER_CPU = _reg.gauge(
+    "distlr_kv_server_cpu_seconds",
+    "cumulative per-handler thread CPU seconds inside the native KV "
+    "server (CLOCK_THREAD_CPUTIME_ID around each dispatch: payload "
+    "read + decode + apply, never socket wait), from the latest "
+    "health probe",
+    labelnames=("rank", "handler"),
+)
+#: The kStats tail's counters with a series of their own beside
+#: ``distlr_ps_server_stat{rank, stat}``, which repeats every one of
+#: them (ROADMAP D5 lists these eight as duplicates to retire): the BSP
+#: barrier's (an async group reads zeros)
+#: ``run_frames``, how much of a rank's traffic its run path took,
+#: ``lock_wait_seconds``, what its pushes stood waiting for its lock, and
+#: the BSP release's ``release_fanned_replies`` and
+#: ``release_wall_seconds``: how often its replies left side by side,
+#: and how long a release (and so the lock it holds) lasted.
+_SERVER_TAIL = {
+    "sync_rounds": _reg.gauge(
+        "distlr_ps_server_sync_rounds",
+        "BSP rounds this server rank has released (one update applied "
+        "and every deferred reply sent), from the latest health probe",
+        labelnames=("rank",)),
+    "sync_hold_seconds": _reg.gauge(
+        "distlr_ps_server_sync_hold_seconds",
+        "cumulative seconds released pushes were held at the BSP "
+        "barrier, each from its arrival to its own reply written",
+        labelnames=("rank",)),
+    "sync_spread_seconds": _reg.gauge(
+        "distlr_ps_server_sync_spread_seconds",
+        "cumulative seconds between a BSP round's first and last "
+        "arrival at this server rank",
+        labelnames=("rank",)),
+    "cpu_release_seconds": _reg.gauge(
+        "distlr_ps_server_sync_release_cpu_seconds",
+        "cumulative thread CPU seconds of the BSP release (apply, "
+        "clear, the W gathers and replies, the writers' share "
+        "included); also inside "
+        "distlr_kv_server_cpu_seconds{handler=\"push\"}",
+        labelnames=("rank",)),
+    "run_frames": _reg.gauge(
+        "distlr_ps_server_run_frames",
+        "pushes and pulls this server rank handled as one range of slots "
+        "(a frame whose row keys are one consecutive run; a fused push-pull "
+        "counts in both, as in the stats total_pushes and total_pulls), "
+        "from the latest health probe",
+        labelnames=("rank",)),
+    "lock_wait_seconds": _reg.gauge(
+        "distlr_ps_server_lock_wait_seconds",
+        "cumulative wall seconds this server rank's push handlers stood "
+        "waiting for its one lock (behind other pushes' merges and the "
+        "BSP release), from the latest health probe",
+        labelnames=("rank",)),
+    "release_fanned_replies": _reg.gauge(
+        "distlr_ps_server_release_fanned_replies",
+        "deferred BSP replies this server rank had written by a thread "
+        "other than the releasing one (a round's value-carrying replies "
+        "leave side by side: W - 1 a round of W fused pushes, 0 for "
+        "header-only rounds), from the latest health probe",
+        labelnames=("rank",)),
+    "release_wall_seconds": _reg.gauge(
+        "distlr_ps_server_release_wall_seconds",
+        "cumulative wall seconds of this server rank's BSP releases, "
+        "from the last voter's merge done to the last reply written "
+        "(its lock is held that long), from the latest health probe",
+        labelnames=("rank",)),
+}
+#: one rank's gauge children, looked up once: a worker's staleness probe
+#: reads kStats tens of times a second
+_MIRRORED: dict[int, list] = {}
+
+
+def mirror_server_stats(rank: int, stats: dict) -> None:
+    """One kStats reply into the registry, where it was parsed: every
+    counter as ``distlr_ps_server_stat{rank, stat}``, the ``cpu_*`` ones
+    also as ``distlr_kv_server_cpu_seconds{rank, handler}``, eight of
+    the tail under series of their own.  The one function behind
+    :meth:`KVWorker.stats`, and so behind ``ServerGroup.health()``."""
+    children = _MIRRORED.get(rank)
+    if children is None:
+        children = _MIRRORED[rank] = []
+        for name in STATS_FIELDS:
+            mine = [_SERVER_STAT.labels(rank=rank, stat=name)]
+            if name in _SERVER_TAIL:
+                mine.append(_SERVER_TAIL[name].labels(rank=rank))
+            elif name.startswith("cpu_") and name.endswith("_seconds"):
+                mine.append(_SERVER_CPU.labels(
+                    rank=rank, handler=name[len("cpu_"):-len("_seconds")]))
+            children.append((name, mine))
+    for name, mine in children:
+        val = stats.get(name)
+        if val is not None:
+            for child in mine:
+                child.set(val)
+
 
 # The field list IS a wire mirror: its length must track kStatsVals and
 # its v1 prefix kStatsValsV1 (distlr_tpu.ps.wire, lint-checked against
@@ -437,6 +565,13 @@ def _load():
         lib.kv_clock_offset.argtypes = [ctypes.c_void_p, ctypes.c_uint32]
         lib.kv_last_wire_sent.restype = ctypes.c_uint64
         lib.kv_last_wire_sent.argtypes = [ctypes.c_void_p]
+        # a read of four doubles, called as a keyed op returns: through
+        # a handle that keeps the GIL (PyDLL), because releasing it for
+        # nanoseconds, where W lock-step workers return at once, hands
+        # it to a peer and stands in line for it again
+        lib.kv_last_exchange = ctypes.PyDLL(client_lib()).kv_last_exchange
+        lib.kv_last_exchange.restype = None
+        lib.kv_last_exchange.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
         lib.kv_negotiate_epoch.restype = ctypes.c_int
         lib.kv_negotiate_epoch.argtypes = [ctypes.c_void_p, ctypes.c_int]
         lib.kv_set_epoch.restype = ctypes.c_int
@@ -556,6 +691,8 @@ class KVWorker:
         self._sign_zero_checked = False
         # how default-key ops address the key space (lazy): (keys, vpk)
         self._dense_rows: tuple[np.ndarray, int] | None = None
+        # where kv_last_exchange writes an op's four instants
+        self._xchg = (ctypes.c_double * 4)()
         self._h = None
         if route is None:
             self._h = self._build_handle()
@@ -981,6 +1118,27 @@ class KVWorker:
             raise OSError("failed to set KV socket timeout")
         self._timeout_ms = int(timeout_ms)
 
+    def _record_exchange(self) -> None:
+        """The keyed op that has just returned, as three spans under the
+        span open on this thread (a loop's ``push`` or ``pull``, the comm
+        thread's ``wire``): ``xchg_send``, the call's start to the last
+        request byte handed to the kernel; ``xchg_await``, from there to
+        the first reply header read: the servers' read, merge, wait for
+        the round and release up to the first reply; ``xchg_recv``, from
+        there to the last value read.  The native client noted the
+        instants on ``time.perf_counter``'s clock (``kv_last_exchange``);
+        together the three cover the call but for its entry and exit.
+        Nothing where no reply was read (a pull of no keys)."""
+        t = self._xchg
+        self._lib.kv_last_exchange(self._h, t)
+        t0, t1, t2, t3 = t
+        if not 0.0 < t0 <= t1 <= t2 <= t3:  # an instant not reached is 0
+            return
+        tracer = get_tracer()
+        tracer.completed("xchg_send", t0, t1 - t0)
+        tracer.completed("xchg_await", t1, t2 - t1)
+        tracer.completed("xchg_recv", t2, t3 - t2)
+
     def _check(self, ts: int, what: str) -> int:
         if ts < 0:
             err = self._lib.kv_last_error(self._h).decode()
@@ -1138,6 +1296,7 @@ class KVWorker:
                     keys.shape[0], vpk,
                 )
                 self._check(ts, "push")
+                self._record_exchange()
                 _account_push_bytes(keys.nbytes + vals.nbytes,
                                     self._lib.kv_last_wire_sent(self._h))
                 return ts
@@ -1166,7 +1325,9 @@ class KVWorker:
                     keys.shape[0],
                     1 if force else 0, vpk,
                 )
-                return self._check(ts, "push_init")
+                self._check(ts, "push_init")
+                self._record_exchange()
+                return ts
 
         # idempotent by protocol design (kInitPush no-ops once seeded;
         # kForceInit re-sends the same vals) -> plain retry is safe
@@ -1200,6 +1361,7 @@ class KVWorker:
                     keys.shape[0], vpk,
                 )
                 self._check(ts, "push_pull")
+                self._record_exchange()
                 _account_push_bytes(keys.nbytes + vals.nbytes,
                                     self._lib.kv_last_wire_sent(self._h))
             return out
@@ -1234,6 +1396,7 @@ class KVWorker:
                     keys.shape[0], vpk,
                 )
                 self._check(ts, "pull")
+                self._record_exchange()
             return out
 
         with self._trace_op("pull"):
@@ -1403,11 +1566,15 @@ class KVWorker:
         # reconnect re-vote counts exactly once.
         self._with_retry("barrier", _issue)
 
-    def stats(self, server: int = 0) -> dict:
+    def stats(self, server: int = 0, *, rank: int | None = None) -> dict:
         """Health/progress counters of one server (never deferred, so it
         works mid-barrier — the supervisor's straggler detector).  Use a
         dedicated KVWorker for probing: ops on this connection must not
-        be in flight concurrently."""
+        be in flight concurrently.  Every read refreshes the registry's
+        mirror of that server (:func:`mirror_server_stats`) under
+        ``rank``: the group's rank of this handle's ``server``, which is
+        ``server`` itself unless the handle addresses a part of the
+        group (the supervisor's one-rank probes)."""
         out = np.zeros(len(STATS_FIELDS), dtype=np.float64)
 
         def _issue():
@@ -1421,7 +1588,9 @@ class KVWorker:
                 for name, v in zip(STATS_FIELDS, out[:n])
             }
 
-        return self._with_retry("stats", _issue)
+        got = self._with_retry("stats", _issue)
+        mirror_server_stats(server if rank is None else rank, got)
+        return got
 
     def global_pushes(self, *, per_worker_scale: bool = True) -> float:
         """The group's monotonic global push clock: the sum of every
@@ -1690,8 +1859,8 @@ class KVNamespace:
                                  force=force)
 
     # -- pass-through ------------------------------------------------------
-    def stats(self, server: int = 0) -> dict:
-        return self.kv.stats(server)
+    def stats(self, server: int = 0, *, rank: int | None = None) -> dict:
+        return self.kv.stats(server, rank=rank)
 
     def global_pushes(self, **kw) -> float:
         return self.kv.global_pushes(**kw)

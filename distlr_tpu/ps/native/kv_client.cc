@@ -32,6 +32,7 @@
 #include <cstring>
 #include <cstdio>
 #include <cstdlib>
+#include <ctime>
 #include <string>
 #include <vector>
 
@@ -110,8 +111,20 @@ struct Client {
   // the most recent op put on the wire — the honest numerator/
   // denominator for the push-byte compression-ratio accounting.
   uint64_t wire_sent = 0;
+  // Four instants of the most recent keyed op (CLOCK_MONOTONIC seconds,
+  // Python's time.perf_counter clock): the call's start, the last
+  // request byte handed to the kernel, the first reply header read
+  // (whichever server's), the last value read.  Zeros where the op
+  // failed before that instant.  kv_last_exchange reads them.
+  double exchange[4] = {0.0, 0.0, 0.0, 0.0};
   char err[256] = {0};
 };
+
+inline double MonoNowS() {
+  timespec ts{};
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return static_cast<double>(ts.tv_sec) + 1e-9 * ts.tv_nsec;
+}
 
 bool ReadFull(int fd, void* buf, size_t n) {
   auto* p = static_cast<char*>(buf);
@@ -218,6 +231,8 @@ int RoundTrip(Client* c, Op op, const Key* keys, const float* vals,
   c->epoch_mismatch = false;
   c->op_delivery_began = false;
   c->wire_sent = 0;
+  c->exchange[0] = MonoNowS();
+  c->exchange[1] = c->exchange[2] = c->exchange[3] = 0.0;
   if (c->poisoned) {
     snprintf(c->err, sizeof(c->err),
              "connection poisoned by an earlier receive failure; "
@@ -341,6 +356,7 @@ int RoundTrip(Client* c, Op op, const Key* keys, const float* vals,
   // Every request frame left intact; any failure from here on is on the
   // receive side, where delivery is a fact (only the REPLY is in doubt).
   c->op_delivery_began = true;
+  c->exchange[1] = MonoNowS();
 
   // Phase 2: collect every response (blocks through deferred replies —
   // in sync mode this wait IS the BSP barrier).
@@ -365,6 +381,7 @@ int RoundTrip(Client* c, Op op, const Key* keys, const float* vals,
       }
       return -1;
     }
+    if (c->exchange[2] == 0.0) c->exchange[2] = MonoNowS();
     if (rh.magic != kMagic || !(rh.flags & kResponse) || rh.timestamp != ts) {
       c->poisoned = true;
       snprintf(c->err, sizeof(c->err), "bad response from server %zu", s);
@@ -437,6 +454,7 @@ int RoundTrip(Client* c, Op op, const Key* keys, const float* vals,
       }
     }
   }
+  c->exchange[3] = MonoNowS();
   return static_cast<int>(ts);
 }
 
@@ -646,6 +664,17 @@ int kv_negotiate_codec(void* handle, int want) {
 // value payload over all servers) — the compression-ratio denominator.
 uint64_t kv_last_wire_sent(void* handle) {
   return static_cast<distlr::Client*>(handle)->wire_sent;
+}
+
+// The four instants of the handle's most recent keyed op (push, pull,
+// push-pull, barrier: whatever went through RoundTrip last) into
+// out[0, 4): its start, the last request byte handed to the kernel, the
+// first reply header read, the last value read; seconds on
+// CLOCK_MONOTONIC, which is Python's time.perf_counter.  A zero is an
+// instant the op did not reach.
+void kv_last_exchange(void* handle, double* out) {
+  const auto* c = static_cast<distlr::Client*>(handle);
+  for (int i = 0; i < 4; ++i) out[i] = c->exchange[i];
 }
 
 // --- distributed-trace negotiation (kv_protocol.h kCapTrace).  Sends a

@@ -164,8 +164,28 @@ enum class Op : uint8_t {
 // fan-out engages.  release_wall_seconds is the wall from the last
 // voter's merge done to the last reply written, summed over
 // sync_rounds: the release's length, which the lock is held for.
+// Slots 19-23 (additive after the release's two): a push's life on the
+// server, phase by phase, in wall seconds on the monotonic clock (the
+// clients' clock too, on one host), summed as named.  recv_seconds,
+// over total_pushes: from a push's header read to its keys and values
+// read and decoded (the bytes through the socket).  merge_seconds, over
+// total_pushes: from the server's lock held to the push's own
+// arithmetic done: the BSP merge; for an async or a seed push the apply
+// and the reply's copy; the release is not in it.  sync_wait_seconds,
+// over the pushes of released rounds (zero from an async server): from
+// a push's merge done to its round's release begun, the wait for the
+// later arrivals, 0 for the last voter; sync_hold_seconds less the
+// merge less this is a reply's place in the release.
+// release_apply_seconds, over sync_rounds (zero from an async server):
+// from the release's begin (the last voter's merge done) to the mean
+// applied and the merge buffer cleared; release_wall_seconds less this
+// is the replies' writes.  reply_write_seconds, over the replies that
+// carry values (a fused push's weights, BSP or async; header-only
+// replies are not timed): from one reply's write begun to written,
+// added by whichever thread wrote it, so a round's W replies written
+// side by side count W writes.
 constexpr uint64_t kStatsValsV1 = 6;
-constexpr uint64_t kStatsVals = 19;
+constexpr uint64_t kStatsVals = 24;
 
 enum Flags : uint8_t {
   kNone = 0,

@@ -176,6 +176,9 @@ struct PendingPush {
   // (kStats sync_* tail).
   double arrived_s = 0.0;
   double replied_s = 0.0;
+  // when its own merge was done: from there to the round's release is
+  // the wait for the later arrivals (kStats sync_wait_seconds)
+  double merged_s = 0.0;
 
   Rows rows() const { return {keys.data(), keys.size(), vpk, run}; }
 };
@@ -603,6 +606,10 @@ class KVServer {
       // the socket — the number a flamegraph's C++ edge should carry.
       timespec cpu0{};
       clock_gettime(CLOCK_THREAD_CPUTIME_ID, &cpu0);
+      // kStats recv_seconds: the wall from here, the header read, to a
+      // push's keys and values read and decoded
+      const double recv_t0 =
+          (op == Op::kPush || op == Op::kPushPull) ? MonoNowS() : 0.0;
       // Trace trailer (kv_protocol.h kTraced): stripped HERE, at the
       // parsing layer — like codec decode, so every handler sees
       // exactly the frame an untraced client sent.  A
@@ -708,6 +715,7 @@ class KVServer {
           break;
         }
         if (traced) tr_decoded = WallNowS();
+        const double recv_s = MonoNowS() - recv_t0;
         if (EpochFence(fd, h)) {
           AccumulateCpu(op, cpu0);
           continue;  // payload fully read above — the stream stays framed
@@ -716,7 +724,7 @@ class KVServer {
           HandleOptStatePush(fd, h, rows, vals, max_key);
         } else {
           HandlePush(fd, h, rows, vals, reply, max_key,
-                     op == Op::kPushPull);
+                     op == Op::kPushPull, recv_s);
         }
         if (traced) {
           TraceLog(op == Op::kPushPull ? "kv.push_pull" : "kv.push", tf,
@@ -1102,9 +1110,13 @@ class KVServer {
   // `rows_buf`, the writing thread's own.
   void WriteReply(PendingPush& p, std::vector<Val>& rows_buf) {
     const Rows pr = p.rows();
-    if (!p.want_vals) {
+    if (!p.want_vals || pr.flat() == 0) {
       Respond(p.fd, p.header, nullptr, 0);
-    } else if (pr.run) {
+      p.replied_s = MonoNowS();
+      return;
+    }
+    const double write_t0 = MonoNowS();
+    if (pr.run) {
       Respond(p.fd, p.header, weights_.data() + pr.keys[0] * pr.vpk,
               pr.flat());
     } else {
@@ -1112,6 +1124,17 @@ class KVServer {
       Respond(p.fd, p.header, rows_buf.data(), pr.flat());
     }
     p.replied_s = MonoNowS();
+    AddReplyWrite(p.replied_s - write_t0);
+  }
+
+  // kStats reply_write_seconds: the wall of one value-carrying reply's
+  // write, added by whichever thread wrote it (the releasing thread, a
+  // writer, an async push's own), so it is kept as the handlers' CPU is.
+  void AddReplyWrite(double seconds) {
+    if (seconds > 0) {
+      reply_write_ns_.fetch_add(static_cast<uint64_t>(1e9 * seconds),
+                                std::memory_order_relaxed);
+    }
   }
 
   // Each of a release's replies on a connection of its own?  Two on one
@@ -1203,12 +1226,17 @@ class KVServer {
   // takes `vals` with it (moved into the round's pending list). ---
   void HandlePush(int fd, const MsgHeader& h, const Rows& rows,
                   std::vector<Val>& vals, std::vector<Val>& reply,
-                  Key max_key, bool reply_weights = false) {
+                  Key max_key, bool reply_weights = false,
+                  double recv_s = 0.0) {
     // kStats lock_wait_seconds: what a push stood behind its peers'
     // merges and the release before its own could begin
     const double asked_s = MonoNowS();
     std::unique_lock<std::mutex> lock(mu_);
-    lock_wait_s_ += MonoNowS() - asked_s;
+    // kStats merge_seconds runs from here, mu_ held, to the push's own
+    // arithmetic done (the BSP merge; the apply and the reply's copy)
+    const double held_s = MonoNowS();
+    lock_wait_s_ += held_s - asked_s;
+    recv_s_ += recv_s;
     ++n_push_;
     if (reply_weights) ++n_pull_;  // it serves the next pull too
     // a fused frame stands in both counts, so it does here
@@ -1222,8 +1250,11 @@ class KVServer {
     const auto reply_now = [&] {
       const uint64_t n = reply_weights ? rows.flat() : 0;
       if (n) CopyRows(weights_, rows, SizedFor(reply, n));
+      const double done_s = MonoNowS();
+      merge_s_ += done_s - held_s;
       lock.unlock();
       Respond(fd, h, reply.data(), n);
+      if (n) AddReplyWrite(MonoNowS() - done_s);
     };
 
     if (h.flags & kInitPush) {
@@ -1298,15 +1329,21 @@ class KVServer {
         for (uint64_t j = 0; j < n; ++j) m[j] += g[at + j];
       });
     }
+    const double merged_s = MonoNowS();
+    pending_.back().merged_s = merged_s;
+    merge_s_ += merged_s - held_s;
 
     if (static_cast<int>(pending_.size()) == num_workers_) {
       // The round's counters (kStats sync_* tail, kv_protocol.h): the
       // release runs on this voter's thread and the server's writers,
-      // whose thread-CPU all stands in cpu_push_seconds too.
-      const double release_t0 = MonoNowS();
+      // whose thread-CPU all stands in cpu_push_seconds too.  It begins
+      // where the last voter's merge ended.
+      const double release_t0 = merged_s;
       const double release_cpu0 = ThreadCpuNowS();
       // pending_ is in arrival order (pushed under mu_)
       sync_spread_s_ += pending_.back().arrived_s - pending_.front().arrived_s;
+      // what each push waited for the later arrivals: 0 for the last
+      for (const auto& p : pending_) sync_wait_s_ += release_t0 - p.merged_s;
       const float w = static_cast<float>(num_workers_);
       if (last_gradient_) {
         // Q1 compat: apply only ONE worker's gradient / W (the reference
@@ -1368,6 +1405,7 @@ class KVServer {
           weights_[i] -= lr_ * merge_[i] / w;
       }
       std::fill(merge_.begin(), merge_.end(), 0.0f);
+      release_apply_s_ += MonoNowS() - release_t0;
       std::vector<PendingPush> release;
       release.swap(pending_);
       // Releasing every deferred reply at once IS the BSP barrier, and
@@ -1556,7 +1594,17 @@ class KVServer {
       // and the releases' wall seconds
       tail[6] = static_cast<double>(release_fanned_);
       tail[7] = release_wall_s_;
+      // slots 19-23: a push's phases (wall seconds): read, merge, the
+      // wait for the later arrivals, the release's apply, and (below,
+      // atomic) the value-carrying replies' writes
+      tail[8] = recv_s_;
+      tail[9] = merge_s_;
+      tail[10] = sync_wait_s_;
+      tail[11] = release_apply_s_;
     }
+    stats[kStatsVals - 1] =
+        1e-9 * static_cast<double>(
+                   reply_write_ns_.load(std::memory_order_relaxed));
     // per-handler thread-CPU seconds (the continuous-profiling
     // extension; atomic — no mu_ needed)
     for (int i = 0; i < kCpuSlots; ++i) {
@@ -2380,6 +2428,19 @@ class KVServer {
   //: wall seconds of the releases, last merge done to last reply written
   uint64_t release_fanned_ = 0;
   double release_wall_s_ = 0.0;
+  //: a push's phases in wall seconds (guarded by mu_; kStats slots
+  //: 19-22): the header read to the frame decoded; mu_ held to the
+  //: push's own arithmetic done; a BSP push's merge done to its round's
+  //: release begun; a release's begin to the mean applied and merge_
+  //: cleared
+  double recv_s_ = 0.0;
+  double merge_s_ = 0.0;
+  double sync_wait_s_ = 0.0;
+  double release_apply_s_ = 0.0;
+  //: kStats slot 23, reply_write_seconds: nanoseconds the
+  //: value-carrying replies' writes took, each added by the thread that
+  //: wrote it (atomic, as cpu_us_ is: an async reply leaves after mu_)
+  std::atomic<uint64_t> reply_write_ns_{0};
   //: the release's writers (all guarded by wr_mu_): the replies handed
   //: over (the first wr_todo_ not yet taken, wr_left_ not yet written),
   //: the writers' thread-CPU since the last join, and the threads alive

@@ -34,82 +34,6 @@ _UP = _reg.gauge(
     "1 while this server rank's process is managed and running",
     labelnames=("rank",),
 )
-#: kStats counters of the native servers, refreshed by every health()
-#: probe (the native process cannot scrape itself — the Python side
-#: mirrors its protocol counters into the registry).
-_SERVER_STAT = _reg.gauge(
-    "distlr_ps_server_stat",
-    "latest health-probe value of each native server kStats counter",
-    labelnames=("rank", "stat"),
-)
-#: Per-handler thread-CPU seconds of the native server ranks, mirrored
-#: from the kStats CPU extension by every health() probe — the series a
-#: fleet flamegraph's Python edge lines up against the C++ side with.
-_SERVER_CPU = _reg.gauge(
-    "distlr_kv_server_cpu_seconds",
-    "cumulative per-handler thread CPU seconds inside the native KV "
-    "server (CLOCK_THREAD_CPUTIME_ID around each dispatch: payload "
-    "read + decode + apply, never socket wait), from the latest "
-    "health probe",
-    labelnames=("rank", "handler"),
-)
-#: The kStats tail's counters with a series of their own, mirrored by
-#: every health() probe: the BSP barrier's (an async group reads zeros)
-#: ``run_frames``, how much of a rank's traffic its run path took,
-#: ``lock_wait_seconds``, what its pushes stood waiting for its lock, and
-#: the BSP release's ``release_fanned_replies`` and
-#: ``release_wall_seconds``: how often its replies left side by side,
-#: and how long a release (and so the lock it holds) lasted.
-_SERVER_TAIL = {
-    "sync_rounds": _reg.gauge(
-        "distlr_ps_server_sync_rounds",
-        "BSP rounds this server rank has released (one update applied "
-        "and every deferred reply sent), from the latest health probe",
-        labelnames=("rank",)),
-    "sync_hold_seconds": _reg.gauge(
-        "distlr_ps_server_sync_hold_seconds",
-        "cumulative seconds released pushes were held at the BSP "
-        "barrier, each from its arrival to its own reply written",
-        labelnames=("rank",)),
-    "sync_spread_seconds": _reg.gauge(
-        "distlr_ps_server_sync_spread_seconds",
-        "cumulative seconds between a BSP round's first and last "
-        "arrival at this server rank",
-        labelnames=("rank",)),
-    "cpu_release_seconds": _reg.gauge(
-        "distlr_ps_server_sync_release_cpu_seconds",
-        "cumulative thread CPU seconds of the BSP release (apply, "
-        "clear, the W gathers and replies, the writers' share "
-        "included); also inside "
-        "distlr_kv_server_cpu_seconds{handler=\"push\"}",
-        labelnames=("rank",)),
-    "run_frames": _reg.gauge(
-        "distlr_ps_server_run_frames",
-        "pushes and pulls this server rank handled as one range of slots "
-        "(a frame whose row keys are one consecutive run; a fused push-pull "
-        "counts in both, as in the stats total_pushes and total_pulls), "
-        "from the latest health probe",
-        labelnames=("rank",)),
-    "lock_wait_seconds": _reg.gauge(
-        "distlr_ps_server_lock_wait_seconds",
-        "cumulative wall seconds this server rank's push handlers stood "
-        "waiting for its one lock (behind other pushes' merges and the "
-        "BSP release), from the latest health probe",
-        labelnames=("rank",)),
-    "release_fanned_replies": _reg.gauge(
-        "distlr_ps_server_release_fanned_replies",
-        "deferred BSP replies this server rank had written by a thread "
-        "other than the releasing one (a round's value-carrying replies "
-        "leave side by side: W - 1 a round of W fused pushes, 0 for "
-        "header-only rounds), from the latest health probe",
-        labelnames=("rank",)),
-    "release_wall_seconds": _reg.gauge(
-        "distlr_ps_server_release_wall_seconds",
-        "cumulative wall seconds of this server rank's BSP releases, "
-        "from the last voter's merge done to the last reply written "
-        "(its lock is held that long), from the latest health probe",
-        labelnames=("rank",)),
-}
 _SUP_EVENTS = _reg.counter(
     "distlr_ps_supervisor_events_total",
     "supervisor audit-trail events (respawned/reseeded/seeded-zeros/"
@@ -777,21 +701,11 @@ class ServerGroup:
         # not time out inside it
         with KVWorker(self.direct_hosts, self.dim, client_id=0xFFFF,
                       timeout_ms=timeout_ms) as probe:
-            stats = [probe.stats(rank) for rank in range(self.num_servers)]
-        # Mirror the native counters into the registry: the server process
-        # itself has no scrape surface, so a health probe doubles as its
-        # exporter (total_pushes/total_pulls/pending_sync_pushes/...).
-        for rank, s in enumerate(stats):
-            for name, val in s.items():
-                _SERVER_STAT.labels(rank=rank, stat=name).set(val)
-                if name in _SERVER_TAIL:
-                    _SERVER_TAIL[name].labels(rank=rank).set(val)
-                elif name.startswith("cpu_") and name.endswith("_seconds"):
-                    _SERVER_CPU.labels(
-                        rank=rank,
-                        handler=name[len("cpu_"):-len("_seconds")],
-                    ).set(val)
-        return stats
+            # every kStats read mirrors the native counters into the
+            # registry (KVWorker.stats): the server process has no scrape
+            # surface of its own, so a health probe doubles as its
+            # exporter (total_pushes/total_pulls/pending_sync_pushes/...)
+            return [probe.stats(rank) for rank in range(self.num_servers)]
 
     def global_pushes(self, *, timeout_ms: int = 2000) -> float:
         """Server-side view of the group's monotonic push clock (see
@@ -1021,7 +935,7 @@ class ServerSupervisor:
                     # become "authoritative" and a crash within
                     # snapshot_interval would re-seed zeros over real
                     # (possibly checkpoint-restored) weights.
-                    s = kv.stats(0)
+                    s = kv.stats(0, rank=r)
                     if not s["initialized"]:
                         continue
                     if (self._snap_valid[r]

@@ -75,8 +75,9 @@ CAP_EPOCH = 1 << 9
 STATS_VALS_V1 = 6
 #: current stats count: v1 six + 4 per-handler CPU seconds + epoch + the
 #: BSP barrier's four (rounds, hold, spread, release CPU) + run_frames +
-#: lock_wait_seconds + the release's two (fanned replies, wall)
-STATS_VALS = 19
+#: lock_wait_seconds + the release's two (fanned replies, wall) + a
+#: push's five phases (recv, merge, sync wait, release apply, reply write)
+STATS_VALS = 24
 
 #: wire-corruption guard for vals_per_key (kMaxValsPerKey)
 MAX_VALS_PER_KEY = 4096

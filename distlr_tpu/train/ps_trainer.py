@@ -650,7 +650,15 @@ class PSWorker:
     device), ``grad_d2h`` (readback), ``push`` (the loop blocked on its
     exchange), ``pull``; ``wire`` on the comm thread (a pipelined fused
     push-pull, send to reply, with the step that submitted it);
-    ``barrier_wait``, ``eval``, ``checkpoint``.
+    ``barrier_wait``, ``eval``, ``checkpoint``.  Under whichever of them
+    is open when a keyed operation returns, ``KVWorker`` records that
+    exchange's three phases from the native client's own instants, one
+    site for every loop variant here: ``xchg_send`` (the call's start to
+    the last request byte handed to the kernel), ``xchg_await`` (to the
+    first reply header read: the servers' read, merge, wait for the
+    round and release), ``xchg_recv`` (to the last value read).  They are
+    ``PhaseTracer`` spans with their parent's ``step`` and ``rank`` and
+    no annotations: an annotation cannot be entered after the fact.
     """
 
     def __init__(self, cfg: Config, rank: int, hosts: str, *, train_iter=None,

@@ -141,6 +141,11 @@ class TestWorkerRestartRecovery:
             kv.reconnect()
             # a pull (never deferred) completes on the rebuilt handle
             np.testing.assert_allclose(kv.pull(), np.zeros(8), rtol=1e-6)
+            # the old connection's deferred push is rolled back on the
+            # server's reader thread: on a loaded host the next push can
+            # beat it and release the round with it (the driver's run of
+            # PR 32 saw this test fail that way)
+            assert _wait_pending_zero(sync_group_of_two) == 0
             # the receive timeout survived the rebuild: a second wedged
             # push still times out fast instead of blocking forever
             t0 = time.monotonic()
