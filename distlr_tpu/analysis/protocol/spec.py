@@ -34,6 +34,17 @@ Modeling choices (every abstraction is stated, none silent):
   client); what is CHECKED is its outcome under every interleaving of
   resizes/faults around it: capability intersection, mixed-vintage
   downgrade, announce-only-if-every-rank-speaks-kEpoch.
+* **the values' carrier is below the model** — where a client and a
+  server share a host, a frame's float32 values may cross in a mapping
+  the two attached at connect and not on the socket (``kv_protocol.h``
+  "values in a mapping", ``wire.CODEC_MAPPED``).  Values are not
+  modeled, so neither is where they stand: a frame still enqueues when
+  its header enters the kernel (the client has copied the values before
+  that), a server still processes one frame and a client consumes one
+  reply per step, per-connection order, the withheld reply and a closed
+  socket as the sign of a dead peer are the socket's either way.  The
+  attach is part of the atomic negotiation step; its outcome changes no
+  transition.
 
 The ``Spec`` flags name the historical fixes; reverting one
 (:mod:`~distlr_tpu.analysis.protocol.mutants`) must make the checker

@@ -58,6 +58,7 @@ HEADER_TO_WIRE = {
     "kCodecNone": "CODEC_NONE",
     "kCodecInt8": "CODEC_INT8",
     "kCodecSign": "CODEC_SIGN",
+    "kCodecMapped": "CODEC_MAPPED",
     # constexpr values
     "kQuantBlock": "QUANT_BLOCK",
     "kStatsValsV1": "STATS_VALS_V1",
@@ -67,6 +68,11 @@ HEADER_TO_WIRE = {
     "kCapCodecSign": "CAP_CODEC_SIGN",
     "kCapTrace": "CAP_TRACE",
     "kCapEpoch": "CAP_EPOCH",
+    "kCapMapped": "CAP_MAPPED",
+    "kMappedMinBytes": "MAPPED_MIN_BYTES",
+    "kMappedHeaderBytes": "MAPPED_HEADER_BYTES",
+    "kMappedAsk": "MAPPED_ASK",
+    "kMappedConfirm": "MAPPED_CONFIRM",
     # static_assert-ed frame sizes
     "sizeof(MsgHeader)": "HEADER_SIZE",
     "sizeof(TraceFrame)": "TRACE_FRAME_SIZE",

@@ -52,7 +52,6 @@ import socket
 import struct
 from distlr_tpu import sync
 from distlr_tpu.chaos.plan import FaultPlan, FaultSpec
-from distlr_tpu.compress import codecs
 from distlr_tpu.obs import dtrace
 from distlr_tpu.ps import wire
 from distlr_tpu.obs.registry import get_registry
@@ -99,20 +98,23 @@ _OP_PUSH, _OP_PUSHPULL = wire.OP_PUSH, wire.OP_PUSH_PULL
 _OPT_STATE, _TRACED = wire.FLAG_OPT_STATE, wire.FLAG_TRACED
 _TRACE_FRAME = wire.TRACE_FRAME_STRUCT
 _OP_HELLO = wire.OP_HELLO
-_CODEC_NAMES = {v: k for k, v in codecs.CODEC_IDS.items()}
 
 
 def _push_vals_bytes(flags: int, n_flat: int) -> int:
-    """Value-payload bytes of a push-class frame carrying ``n_flat``
-    expanded values — codec-aware via the shared
-    :func:`distlr_tpu.compress.codecs.payload_bytes` (one definition of
-    the byte layout next to the native CodecPayloadBytes): a proxy that
-    assumed dense f32 would misframe every compressed push and degrade
-    the whole stream to a raw relay, silently disabling op-offset
-    faults for exactly the runs the compression bench needs them on."""
-    codec = _CODEC_NAMES.get(wire.codec_of(flags), "none")
-    mult = 2 if codec == "none" and flags & _OPT_STATE else 1
-    return codecs.payload_bytes(codec, n_flat) * mult
+    """Value-payload bytes ON THE SOCKET of a push-class frame carrying
+    ``n_flat`` expanded values, from the frame's own codec field via
+    :func:`distlr_tpu.ps.wire.codec_payload_bytes` (the one Python
+    definition of the byte layout, next to the native
+    CodecPayloadBytes): a proxy that assumed dense f32 would misframe
+    every compressed push and degrade the whole stream to a raw relay,
+    silently disabling op-offset faults for exactly the runs the
+    compression bench needs them on.  A frame whose values cross in a
+    shared mapping (``wire.CODEC_MAPPED``) has none here; none is ever
+    seen here either (the attach refuses a client whose socket is not
+    the server's own peer, which a proxy's never is)."""
+    codec = wire.codec_of(flags)
+    mult = 2 if codec == wire.CODEC_NONE and flags & _OPT_STATE else 1
+    return wire.codec_payload_bytes(codec, n_flat) * mult
 #: pump socket timeout: bounds stop() latency without busy-waiting
 _TICK_S = 0.1
 #: event-log cap — a runaway plan must not grow memory unboundedly

@@ -52,6 +52,18 @@ _DENSE_FRAMES = _reg.counter(
     "vals_per_key divides dim and the handle's range boundaries)",
     labelnames=("op", "encoding"),
 )
+_PAYLOAD_FRAMES = _reg.counter(
+    "distlr_ps_payload_frames_total",
+    "value-carrying frames (one a server) of keyed ops that succeeded, "
+    "by how the float32 values crossed: mapped = in the connection's "
+    "shared mapping (a same-host server, connected directly, slices of "
+    "64 KiB or more), inline = on the socket (a proxy or another host "
+    "in between, an older server, coded, opt-state and small frames)",
+    labelnames=("op", "carrier"),
+)
+#: an op's two children of it (mapped, inline), looked up once: the
+#: count is taken as every keyed op returns
+_PAYLOAD_CHILDREN: dict[str, tuple] = {}
 _CHUNKED_PULLS = _reg.counter(
     "distlr_ps_client_chunked_pulls_total",
     "pull_chunked calls (serving-tier bounded reads)",
@@ -165,8 +177,8 @@ def _observe_op(op: str, *, sent=0, received: int = 0,
 #: replies only the first six, one from before the BSP tail eleven, one
 #: from before ``run_frames`` fifteen, one from before
 #: ``lock_wait_seconds`` sixteen, one from before the release's fan-out
-#: seventeen, one from before a push's phases nineteen; the probe
-#: reports what arrived.
+#: seventeen, one from before a push's phases nineteen, one from before
+#: ``mapped_frames`` twenty-four; the probe reports what arrived.
 STATS_FIELDS = (
     "dim",
     "initialized",
@@ -223,6 +235,12 @@ STATS_FIELDS = (
     "sync_wait_seconds",
     "release_apply_seconds",
     "reply_write_seconds",
+    # of the operations total_pushes and total_pulls count, those whose
+    # values crossed in their connection's shared mapping and not through
+    # the socket (kv_protocol.h kCodecMapped; a fused push-pull twice, as
+    # in run_frames): over the rise of the two totals, the share of a
+    # job's value-carrying traffic that stayed out of the kernel
+    "mapped_frames",
 )
 
 #: kStats counters of the native servers, refreshed by every kStats read
@@ -233,7 +251,10 @@ STATS_FIELDS = (
 _SERVER_STAT = _reg.gauge(
     "distlr_ps_server_stat",
     "latest kStats read (a health probe, or any KVWorker.stats call of "
-    "this process) of each native server counter",
+    "this process) of each native server counter, the stat label one of "
+    "STATS_FIELDS; its newest, mapped_frames: of the operations "
+    "total_pushes and total_pulls count, those whose values crossed in "
+    "their connection's shared mapping and not through the socket",
     labelnames=("rank", "stat"),
 )
 #: Per-handler thread-CPU seconds of the native server ranks, mirrored
@@ -572,8 +593,13 @@ def _load():
         lib.kv_last_exchange = ctypes.PyDLL(client_lib()).kv_last_exchange
         lib.kv_last_exchange.restype = None
         lib.kv_last_exchange.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
+        lib.kv_last_carried = ctypes.PyDLL(client_lib()).kv_last_carried
+        lib.kv_last_carried.restype = None
+        lib.kv_last_carried.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
         lib.kv_negotiate_epoch.restype = ctypes.c_int
         lib.kv_negotiate_epoch.argtypes = [ctypes.c_void_p, ctypes.c_int]
+        lib.kv_negotiate_mapping.restype = ctypes.c_int
+        lib.kv_negotiate_mapping.argtypes = [ctypes.c_void_p]
         lib.kv_set_epoch.restype = ctypes.c_int
         lib.kv_set_epoch.argtypes = [ctypes.c_void_p, ctypes.c_int]
         lib.kv_epoch_mismatch.restype = ctypes.c_int
@@ -691,8 +717,15 @@ class KVWorker:
         self._sign_zero_checked = False
         # how default-key ops address the key space (lazy): (keys, vpk)
         self._dense_rows: tuple[np.ndarray, int] | None = None
-        # where kv_last_exchange writes an op's four instants
+        # where kv_last_exchange writes an op's four instants, and
+        # kv_last_carried its value-carrying frames (mapped, inline)
         self._xchg = (ctypes.c_double * 4)()
+        self._carried = (ctypes.c_uint64 * 2)()
+        #: connections of the current handle whose values cross in a
+        #: shared mapping (kv_protocol.h "values in a mapping"):
+        #: re-derived at every (re)connect from what the servers
+        #: advertise and whether each socket reaches its server directly
+        self.mapped_connections = 0
         self._h = None
         if route is None:
             self._h = self._build_handle()
@@ -799,6 +832,15 @@ class KVWorker:
                 else:
                     self._epoch_armed = True
                     _CLIENT_EPOCH.set(self._epoch)
+            # the values' carrier: nothing asks for it and nothing can
+            # turn it off.  A same-host server reached directly shares a
+            # mapping with each connection whose slice is large enough to
+            # use one; every refusal is the socket, silently
+            got = lib.kv_negotiate_mapping(h)
+            if got < 0:
+                raise OSError("mapping negotiation failed: "
+                              + lib.kv_last_error(h).decode())
+            self.mapped_connections = got
         except Exception:
             lib.kv_close(h)
             raise
@@ -1118,17 +1160,31 @@ class KVWorker:
             raise OSError("failed to set KV socket timeout")
         self._timeout_ms = int(timeout_ms)
 
-    def _record_exchange(self) -> None:
+    def _record_exchange(self, op: str) -> None:
         """The keyed op that has just returned, as three spans under the
         span open on this thread (a loop's ``push`` or ``pull``, the comm
         thread's ``wire``): ``xchg_send``, the call's start to the last
-        request byte handed to the kernel; ``xchg_await``, from there to
-        the first reply header read: the servers' read, merge, wait for
-        the round and release up to the first reply; ``xchg_recv``, from
-        there to the last value read.  The native client noted the
+        request byte handed over (on the socket: to the kernel; in a
+        mapping: the values copied into the request area and the header
+        in the kernel); ``xchg_await``, from there to the first reply
+        header read: the servers' read, merge, wait for the round and
+        release up to the first reply (a mapped reply's values are in
+        the reply area by then); ``xchg_recv``, from there to the last
+        value in the caller's buffer.  The native client noted the
         instants on ``time.perf_counter``'s clock (``kv_last_exchange``);
         together the three cover the call but for its entry and exit.
-        Nothing where no reply was read (a pull of no keys)."""
+        Nothing where no reply was read (a pull of no keys).  Its
+        value-carrying frames go by carrier (``kv_last_carried``) under
+        ``distlr_ps_payload_frames_total{op, carrier}``."""
+        self._lib.kv_last_carried(self._h, self._carried)
+        children = _PAYLOAD_CHILDREN.get(op)
+        if children is None:
+            children = _PAYLOAD_CHILDREN[op] = tuple(
+                _PAYLOAD_FRAMES.labels(op=op, carrier=c)
+                for c in ("mapped", "inline"))
+        for child, frames in zip(children, self._carried):
+            if frames:
+                child.inc(frames)
         t = self._xchg
         self._lib.kv_last_exchange(self._h, t)
         t0, t1, t2, t3 = t
@@ -1296,7 +1352,7 @@ class KVWorker:
                     keys.shape[0], vpk,
                 )
                 self._check(ts, "push")
-                self._record_exchange()
+                self._record_exchange("push")
                 _account_push_bytes(keys.nbytes + vals.nbytes,
                                     self._lib.kv_last_wire_sent(self._h))
                 return ts
@@ -1326,7 +1382,7 @@ class KVWorker:
                     1 if force else 0, vpk,
                 )
                 self._check(ts, "push_init")
-                self._record_exchange()
+                self._record_exchange("push_init")
                 return ts
 
         # idempotent by protocol design (kInitPush no-ops once seeded;
@@ -1361,7 +1417,7 @@ class KVWorker:
                     keys.shape[0], vpk,
                 )
                 self._check(ts, "push_pull")
-                self._record_exchange()
+                self._record_exchange("push_pull")
                 _account_push_bytes(keys.nbytes + vals.nbytes,
                                     self._lib.kv_last_wire_sent(self._h))
             return out
@@ -1396,7 +1452,7 @@ class KVWorker:
                     keys.shape[0], vpk,
                 )
                 self._check(ts, "pull")
-                self._record_exchange()
+                self._record_exchange("pull")
             return out
 
         with self._trace_op("pull"):

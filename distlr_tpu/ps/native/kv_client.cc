@@ -23,6 +23,7 @@
 #include <netinet/tcp.h>
 #include <poll.h>
 #include <sys/socket.h>
+#include <sys/stat.h>
 #include <sys/time.h>
 #include <unistd.h>
 
@@ -45,6 +46,13 @@ struct ServerConn {
   int fd = -1;
   Key range_begin = 0;  // inclusive global key
   Key range_end = 0;    // exclusive global key
+  // this connection's shared mapping (kv_protocol.h "values in a
+  // mapping"): attached only by kv_negotiate_mapping, gone with the
+  // connection
+  MappedSegment map;
+  // the op in flight's frame to this server says kCodecMapped (its
+  // reply must too)
+  bool frame_mapped = false;
 };
 
 struct Client {
@@ -117,6 +125,10 @@ struct Client {
   // (whichever server's), the last value read.  Zeros where the op
   // failed before that instant.  kv_last_exchange reads them.
   double exchange[4] = {0.0, 0.0, 0.0, 0.0};
+  // Of the most recent keyed op's frames that carried values (a push's
+  // or a reply's; one a server), those whose values crossed in the
+  // mapping and those whose values crossed on the socket.
+  uint64_t carried[2] = {0, 0};
   char err[256] = {0};
 };
 
@@ -233,6 +245,7 @@ int RoundTrip(Client* c, Op op, const Key* keys, const float* vals,
   c->wire_sent = 0;
   c->exchange[0] = MonoNowS();
   c->exchange[1] = c->exchange[2] = c->exchange[3] = 0.0;
+  c->carried[0] = c->carried[1] = 0;
   if (c->poisoned) {
     snprintf(c->err, sizeof(c->err),
              "connection poisoned by an earlier receive failure; "
@@ -310,13 +323,26 @@ int RoundTrip(Client* c, Op op, const Key* keys, const float* vals,
   const uint8_t codec =
       (is_push && c->codec && !(flags & (kInitPush | kOptState)))
           ? c->codec : 0;
-  const uint8_t send_flags = static_cast<uint8_t>(
-      flags | (codec << kCodecShift) | (traced ? kTraced : 0));
+  const bool keyed = is_push || op == Op::kPull;
   std::vector<std::vector<Key>> local_keys(c->servers.size());
   std::vector<uint8_t> coded;
   for (size_t s = 0; s < c->servers.size(); ++s) {
     const auto [b, e] = slices[s];
     if (b == e && !visit_all && !(op == Op::kBarrier && s == 0)) continue;
+    const uint64_t n_vals = (e - b) * vpk * mult;
+    // The carrier of this frame's values (kv_protocol.h "values in a
+    // mapping"), from what this connection negotiated and what the
+    // frame is: an attached connection, float32 values (no gradient
+    // codec, no opt-state pair) of kMappedMinBytes or more that the
+    // area's real length holds.
+    const MappedSegment& map = c->servers[s].map;
+    const bool mapped = map.attached && keyed && codec == 0 && !opt_state &&
+                        n_vals * sizeof(Val) >= kMappedMinBytes &&
+                        n_vals <= map.area_vals;
+    c->servers[s].frame_mapped = mapped;
+    const uint8_t send_flags = static_cast<uint8_t>(
+        flags | ((mapped ? uint8_t{kCodecMapped} : codec) << kCodecShift) |
+        (traced ? kTraced : 0));
     MsgHeader h{kMagic, static_cast<uint8_t>(op), send_flags, aux,
                 c->client_id, ts, e - b};
     auto& lk = local_keys[s];
@@ -326,7 +352,6 @@ int RoundTrip(Client* c, Op op, const Key* keys, const float* vals,
     const Key rebase = c->servers[s].range_begin / vpk;
     for (uint64_t i = b; i < e; ++i) lk[i - b] = keys[i] - rebase;
     const int fd = c->servers[s].fd;
-    const uint64_t n_vals = (e - b) * vpk * mult;
     const void* payload = nullptr;
     uint64_t payload_bytes = 0;
     if (is_push && n_vals) {
@@ -338,12 +363,16 @@ int RoundTrip(Client* c, Op op, const Key* keys, const float* vals,
         EncodeGrad(codec, vals + b * vpk, n_vals, coded.data());
         payload = coded.data();
       }
+      // the values go first: the header after them is what tells the
+      // server they are there
+      if (mapped) std::memcpy(map.req(), payload, payload_bytes);
     }
+    if (keyed && n_vals) ++c->carried[mapped ? 0 : 1];
     if (!WriteFull(fd, &h, sizeof(h), &c->op_delivery_began) ||
         (traced && !WriteFull(fd, &tf, sizeof(tf), &c->op_delivery_began)) ||
         (h.num_keys && !WriteFull(fd, lk.data(), lk.size() * sizeof(Key),
                                   &c->op_delivery_began)) ||
-        (is_push && h.num_keys &&
+        (is_push && h.num_keys && !mapped &&
          !WriteFull(fd, payload, payload_bytes, &c->op_delivery_began))) {
       c->poisoned = true;  // peers already received slices of this ts
       snprintf(c->err, sizeof(c->err), "send to server %zu failed", s);
@@ -434,7 +463,22 @@ int RoundTrip(Client* c, Op op, const Key* keys, const float* vals,
                "response size mismatch from server %zu", s);
       return -1;
     }
-    if (expected) {
+    // a reply crosses as its request did: the server echoes the field
+    const bool in_map = c->servers[s].frame_mapped;
+    if ((CodecOf(rh.flags) == kCodecMapped) != in_map) {
+      c->poisoned = true;
+      snprintf(c->err, sizeof(c->err),
+               "reply from server %zu in the wrong carrier", s);
+      return -1;
+    }
+    if (expected && in_map) {
+      // `expected` values stand in the reply area (it holds them: the
+      // request's size was checked against the area's real length)
+      if (out_vals != nullptr) {
+        std::memcpy(out_vals + b * vpk * mult, c->servers[s].map.reply(),
+                    expected * sizeof(Val));
+      }
+    } else if (expected) {
       bool ok;
       if (out_vals != nullptr) {
         ok = ReadFull(c->servers[s].fd, out_vals + b * vpk * mult,
@@ -627,6 +671,46 @@ static int HelloProbe(distlr::Client* c, size_t s, uint8_t flags,
   return 0;
 }
 
+// One step of the mapping's attach toward server s (kv_protocol.h
+// "values in a mapping"): a kHello whose codec field says kCodecMapped,
+// aux the step, `nk` keys; the reply's u64s (two Val slots each) into
+// out[0, cap).  Returns how many arrived (0 = refused), or -1 on a
+// transport/framing failure (handle poisoned, err set).
+static int AttachStep(distlr::Client* c, size_t s, uint64_t step,
+                      const uint64_t* keys, uint64_t nk, uint64_t* out,
+                      uint64_t cap) {
+  const uint32_t ts = c->next_ts++;
+  distlr::MsgHeader h{distlr::kMagic,
+                      static_cast<uint8_t>(distlr::Op::kHello),
+                      static_cast<uint8_t>(distlr::kCodecMapped
+                                           << distlr::kCodecShift),
+                      static_cast<uint16_t>(step), c->client_id, ts, nk};
+  const int fd = c->servers[s].fd;
+  distlr::MsgHeader rh{};
+  errno = 0;
+  if (!distlr::WriteFull(fd, &h, sizeof(h)) ||
+      !distlr::WriteFull(fd, keys, nk * sizeof(uint64_t)) ||
+      !distlr::ReadFull(fd, &rh, sizeof(rh))) {
+    c->poisoned = true;
+    c->timed_out = errno == EAGAIN || errno == EWOULDBLOCK;
+    snprintf(c->err, sizeof(c->err),
+             "mapping attach with server %zu failed", s);
+    return -1;
+  }
+  uint64_t got[4] = {0, 0, 0, 0};
+  if (rh.magic != distlr::kMagic || !(rh.flags & distlr::kResponse) ||
+      rh.timestamp != ts || rh.num_keys % 2 != 0 || rh.num_keys > 8 ||
+      !distlr::ReadFull(fd, got, rh.num_keys * sizeof(distlr::Val))) {
+    c->poisoned = true;
+    snprintf(c->err, sizeof(c->err),
+             "bad mapping attach reply from server %zu", s);
+    return -1;
+  }
+  const uint64_t n = rh.num_keys / 2;
+  for (uint64_t i = 0; i < n && i < cap; ++i) out[i] = got[i];
+  return static_cast<int>(n);
+}
+
 // --- gradient-codec negotiation (kv_protocol.h capability handshake).
 // Sends kHello to EVERY server and intersects the capability masks: a
 // legacy server's empty reply reads as "no capabilities", so the
@@ -668,13 +752,95 @@ uint64_t kv_last_wire_sent(void* handle) {
 
 // The four instants of the handle's most recent keyed op (push, pull,
 // push-pull, barrier: whatever went through RoundTrip last) into
-// out[0, 4): its start, the last request byte handed to the kernel, the
-// first reply header read, the last value read; seconds on
+// out[0, 4): its start, the last request byte handed over (a mapped
+// frame's values copied and its header in the kernel), the first reply
+// header read, the last value in the caller's buffer; seconds on
 // CLOCK_MONOTONIC, which is Python's time.perf_counter.  A zero is an
 // instant the op did not reach.
 void kv_last_exchange(void* handle, double* out) {
   const auto* c = static_cast<distlr::Client*>(handle);
   for (int i = 0; i < 4; ++i) out[i] = c->exchange[i];
+}
+
+// Of that op's value-carrying frames (one a server), into out[0, 2):
+// those whose values crossed in the connection's mapping, and those
+// whose values crossed on the socket.
+void kv_last_carried(void* handle, uint64_t* out) {
+  const auto* c = static_cast<distlr::Client*>(handle);
+  out[0] = c->carried[0];
+  out[1] = c->carried[1];
+}
+
+// --- the shared mapping's attach (kv_protocol.h "values in a mapping").
+// For every server whose slice of this handle's key space is
+// kMappedMinBytes or more (no smaller frame would use it): the
+// capability pass, then ASK and CONFIRM.  Whatever refuses or fails on
+// the way (no capability, a proxy in between, another host, no memory
+// file) leaves that connection on the socket, silently.  Returns the
+// connections now attached, or -1 on a transport failure (the handle is
+// poisoned like after any receive failure).
+int kv_negotiate_mapping(void* handle) {
+  auto* c = static_cast<distlr::Client*>(handle);
+  c->timed_out = false;
+  if (c->poisoned) {
+    snprintf(c->err, sizeof(c->err),
+             "connection poisoned by an earlier receive failure; "
+             "reconnect (kv_connect) before issuing more ops");
+    return -1;
+  }
+  int attached = 0;
+  for (size_t s = 0; s < c->servers.size(); ++s) {
+    auto& sc = c->servers[s];
+    const uint64_t want = sc.range_end - sc.range_begin;
+    if (sc.map.attached || want * sizeof(distlr::Val) < distlr::kMappedMinBytes)
+      continue;
+    uint64_t mask = 0;
+    if (HelloProbe(c, s, distlr::kNone, &mask, nullptr) < 0) return -1;
+    if (!(mask & distlr::kCapMapped)) continue;
+    sockaddr_in me{};
+    socklen_t len = sizeof(me);
+    if (getsockname(sc.fd, reinterpret_cast<sockaddr*>(&me), &len) < 0 ||
+        me.sin_family != AF_INET)
+      continue;
+    const uint64_t ask[2] = {
+        (static_cast<uint64_t>(ntohl(me.sin_addr.s_addr)) << 16) |
+            ntohs(me.sin_port),
+        want};
+    uint64_t got[3] = {0, 0, 0};  // the server's pid, descriptor, values
+    const int n = AttachStep(c, s, distlr::kMappedAsk, ask, 2, got, 3);
+    if (n < 0) return -1;
+    if (n != 3) continue;  // refused: not direct, or no memory file there
+    // CONFIRM with the nonce read in the file the server named; 0 where
+    // it cannot be opened (another host), is not a memory file sealed at
+    // the promised length, or cannot be mapped
+    uint64_t nonce = 0;
+    char path[64];
+    snprintf(path, sizeof(path), "/proc/%llu/fd/%llu",
+             (unsigned long long)got[0], (unsigned long long)got[1]);
+    const int mfd = open(path, O_RDWR | O_CLOEXEC);
+    if (mfd >= 0) {
+      struct stat st{};
+      const int seals = fcntl(mfd, F_GET_SEALS);
+      if (got[2] >= 1 && got[2] <= (1ull << 40) && fstat(mfd, &st) == 0 &&
+          S_ISREG(st.st_mode) &&
+          static_cast<uint64_t>(st.st_size) == distlr::MappedBytes(got[2]) &&
+          seals >= 0 && (seals & F_SEAL_SHRINK) && sc.map.Map(mfd, got[2])) {
+        std::memcpy(&nonce, sc.map.base, sizeof(nonce));
+      }
+      close(mfd);
+    }
+    uint64_t armed = 0;
+    const int m =
+        AttachStep(c, s, distlr::kMappedConfirm, &nonce, 1, &armed, 1);
+    if (m < 0) return -1;
+    if (m == 1 && armed == 1 && sc.map.base != nullptr) {
+      sc.map.attached = true;
+      ++attached;
+    } else {
+      sc.map.Unmap();
+    }
+  }
+  return attached;
 }
 
 // --- distributed-trace negotiation (kv_protocol.h kCapTrace).  Sends a
@@ -1030,7 +1196,10 @@ const char* kv_last_error(void* handle) {
 
 void kv_close(void* handle) {
   auto* c = static_cast<distlr::Client*>(handle);
-  for (auto& sc : c->servers) close(sc.fd);
+  for (auto& sc : c->servers) {
+    close(sc.fd);
+    sc.map.Unmap();  // the mapping goes with its connection
+  }
   delete c;
 }
 

@@ -13,6 +13,9 @@
 //   then num_keys * u64 keys
 //   then (op == PUSH || (op == PULL && is_response))
 //        num_keys * vals_per_key * f32 vals
+//        (none on the socket where the header's codec field says
+//        kCodecMapped: they stand in the connection's shared mapping,
+//        see "values in a mapping" below)
 //
 // vals_per_key (the header's aux field for kPush/kPull/kPushPull;
 // 0 == 1 == legacy scalar keys): each key addresses vals_per_key
@@ -46,6 +49,8 @@
 
 #ifndef DISTLR_TPU_PS_KV_PROTOCOL_H_
 #define DISTLR_TPU_PS_KV_PROTOCOL_H_
+
+#include <sys/mman.h>
 
 #include <cmath>
 #include <cstdint>
@@ -183,9 +188,20 @@ enum class Op : uint8_t {
 // carry values (a fused push's weights, BSP or async; header-only
 // replies are not timed): from one reply's write begun to written,
 // added by whichever thread wrote it, so a round's W replies written
-// side by side count W writes.
+// side by side count W writes; where the values cross in the mapping
+// (kCodecMapped) a write is the copy into the reply area and the
+// 24-byte header after it.
+// Slot 24 (additive after a push's phases): mapped_frames, of the
+// operations total_pushes and total_pulls count, those whose values
+// crossed in the connection's shared mapping and not through the socket
+// (kCodecMapped: the request's values read in place, the reply's
+// written in place).  A fused push-pull stands in both totals and so
+// counts twice here, as in run_frames; mapped_frames over the rise of
+// the two totals is the share of a job's value-carrying traffic that
+// stayed out of the kernel (1 for same-host workers of slices of
+// kMappedMinBytes or more, 0 through a proxy or across hosts).
 constexpr uint64_t kStatsValsV1 = 6;
-constexpr uint64_t kStatsVals = 24;
+constexpr uint64_t kStatsVals = 25;
 
 enum Flags : uint8_t {
   kNone = 0,
@@ -213,6 +229,10 @@ enum Flags : uint8_t {
   // every server of the group decodes the codec — an un-negotiated
   // compressed frame against an old server would desynchronize the
   // stream (the old server reads num_keys*vpk f32s of payload).
+  // The field's last value, kCodecMapped, is no compression but a
+  // carrier: the frame's float32 values cross in the connection's
+  // shared mapping ("values in a mapping" below), on a pull and on a
+  // reply as on a push, and only after that connection's attach.
   kCodecShift = 4,
   kCodecMask = 0x30,
   // The op addresses the server optimizer's per-coordinate accumulator
@@ -277,11 +297,108 @@ static_assert(sizeof(TraceFrame) == 16, "TraceFrame must be 16 bytes");
 // Keys, headers, and every reply stay dense/uncompressed — pulls are
 // the serving tier's path and already have keyed/chunked/hot-row
 // reductions; the PUSH payload is what crosses the wire every batch.
+//
+// --- values in a mapping (kCodecMapped) --------------------------------
+// A client and a server on one host, connected directly, share one
+// mapping a connection: a request area and a reply area, each as large
+// as that server's slice of the handle's key space.  A keyed frame
+// (kPush, kPushPull, kPull; init pushes too) whose codec field says
+// kCodecMapped carries its header, trace trailer and keys on the socket
+// as ever and NO values there: a push's values stand at the start of
+// the request area, and the reply to such a frame (the field echoed)
+// is its 24-byte header, num_keys saying how many values stand at the
+// start of the reply area.  They are the float32 values the socket
+// would have carried, bit for bit; every handler reads and writes them
+// in place.  Order, the withheld reply that is the barrier, timeouts,
+// poisoning, the epoch fence and a closed socket as the sign of a dead
+// peer are the socket's and unchanged.
+//   Handed over: a request is handed over when its values are copied
+// into the request area AND its header and keys are in the kernel (the
+// client's exchange instant 1); the server reads the area only after it
+// has read that header.  Acknowledged: the server has done with the
+// request area (merged, applied, logged) before it writes the reply
+// header, except that a push withheld at the BSP barrier is read once
+// more in place if its connection closes first (the rollback); the
+// client writes the area again only after it has read that header (one
+// operation in flight a connection), so an acknowledged push was read
+// whole.  The reply's values are all in the reply area before the
+// header is written, and the server writes that area again only for
+// the connection's next operation.
+//   Which frames: the client picks frame by frame, from the connection's
+// attach, the frame's size (kMappedMinBytes of values or more: under
+// it the copy saved is smaller than the mapping's cache misses cost)
+// and its codec (a coded push and an opt-state operation stay on the
+// socket).  Scattered keyed frames over the size ride it like runs:
+// their values are as contiguous.  The server answers a frame in the
+// carrier it came in.  A mapped frame on a connection that never
+// attached, or one that claims more values than the area's real length
+// holds, is wire corruption: the connection is dropped, not the server.
+//   The attach (kHello, after the capability pass saw kCapMapped; at
+// connect and at every reconnect; only for a slice of kMappedMinBytes
+// or more, since no smaller frame would ever use it):
+//   1. ASK: kHello, codec field kCodecMapped, aux kMappedAsk, two keys:
+//      the client's own address of this socket (getsockname: IPv4 << 16
+//      | port) and the values an area must hold.  The server refuses
+//      (an empty reply) unless that address is its accepted socket's
+//      peer (getpeername): a proxy, a NAT or a relay in between reads
+//      as "not direct".  Else it makes the segment, an anonymous sealed
+//      memory file (memfd: sized by the server, sealed against shrink,
+//      grow and further seals, so no peer can SIGBUS the other; under
+//      no name in any file system, so nothing outlives a killed
+//      process), maps it, writes a random nonce at its start, and
+//      answers three u64 in six Val slots: its pid, the file's
+//      descriptor number, the values an area holds.
+//   2. CONFIRM: the client opens /proc/<pid>/fd/<n> (one host: the same
+//      file; another host: nothing, or a file that fails the checks),
+//      checks it is a sealed memory file of the promised length, maps it
+//      and sends kHello, aux kMappedConfirm, one key: the nonce it read
+//      there (0 where it could not).  The server compares, closes its
+//      descriptor either way (from here on the segment has no handle
+//      but the two mappings) and answers one u64 1 in two Val slots, or
+//      the empty reply and unmaps.
+// Layout: kMappedHeaderBytes (u64 nonce, u64 values an area holds),
+// then the request area, then the reply area, each rounded up to a
+// multiple of kMappedHeaderBytes (MappedStride).  Any refusal or
+// failure is a silent fall back to values on the socket, for that
+// connection; one segment a connection, never handed to another.
 enum Codec : uint8_t {
   kCodecNone = 0,
   kCodecInt8 = 1,
   kCodecSign = 2,
+  kCodecMapped = 3,
 };
+
+//: value payloads under this many bytes stay on the socket, attached or
+//: not.  From a reading on the chip's host (PR 35, call 1:
+//: benchmarks/exp_mapped_payload.py with this constant at 4096; a fused
+//: push-pull's median ms, socket / mapping): at 64 KiB 0.513 / 0.387 for
+//: one worker and 1.209 / 1.146 for four in lock step, at 2 MiB 2.722 /
+//: 1.757 and 4.423 / 3.243; from 4 to 32 KiB the mapping read 0.05-0.08
+//: ms under the socket for one worker and 0.04-0.06 for four, which is
+//: three system calls on that host and inside the legs' p90 spread
+//: (0.07-0.45 ms).  So the mapping is never the dearer carrier there;
+//: the constant stands where its gain is plain for one worker and for
+//: four, and where a connection is worth a segment and two round trips
+//: of attach at all.
+constexpr uint64_t kMappedMinBytes = 65536;
+//: the mapping's header, and the alignment of its two areas
+constexpr uint64_t kMappedHeaderBytes = 4096;
+//: a mapped kHello's aux: the attach's two steps
+constexpr uint64_t kMappedAsk = 1;
+constexpr uint64_t kMappedConfirm = 2;
+
+// Bytes from one area's start to the next: an area of `vals` values
+// rounded up to whole pages.
+inline uint64_t MappedStride(uint64_t vals) {
+  const uint64_t bytes = vals * sizeof(float);
+  return (bytes + kMappedHeaderBytes - 1) / kMappedHeaderBytes *
+         kMappedHeaderBytes;
+}
+
+// The segment's whole length for areas of `vals` values.
+inline uint64_t MappedBytes(uint64_t vals) {
+  return kMappedHeaderBytes + 2 * MappedStride(vals);
+}
 
 //: int8 block-quantization granularity (values per f32 scale)
 constexpr uint64_t kQuantBlock = 256;
@@ -294,6 +411,7 @@ inline uint8_t CodecOf(uint8_t flags) {
 // sides derive it from (codec, n), so a compressed frame needs no extra
 // length field and stays as corruption-guarded as the dense layout.
 inline uint64_t CodecPayloadBytes(uint8_t codec, uint64_t n) {
+  if (codec == kCodecMapped) return 0;  // the values are not on the socket
   if (codec == kCodecInt8)
     return ((n + kQuantBlock - 1) / kQuantBlock) * 4 + n;
   if (codec == kCodecSign) return (n + 7) / 8;
@@ -381,6 +499,11 @@ constexpr uint64_t kCapTrace = 1ull << 8;
 // announcing an epoch: a kEpoch frame against a pre-epoch binary would
 // never be answered (unknown ops are skipped, not nacked).
 constexpr uint64_t kCapEpoch = 1ull << 9;
+// The server shares a mapping with a same-host client that asks
+// (kCodecMapped, "values in a mapping" above).  A client asks only
+// after it saw this bit: an older server would answer an ASK with its
+// capability mask.
+constexpr uint64_t kCapMapped = 1ull << 10;
 
 #pragma pack(push, 1)
 struct MsgHeader {
@@ -466,6 +589,41 @@ constexpr uint32_t kWalRecordHeaderSize = 20;
 
 using Key = uint64_t;
 using Val = float;
+
+// One side's view of a connection's segment ("values in a mapping"
+// above).  area_vals is what the mapping's real length holds: every
+// frame's size is checked against it, never against a count a peer sent.
+struct MappedSegment {
+  uint8_t* base = nullptr;
+  uint64_t bytes = 0;
+  uint64_t area_vals = 0;
+  // both sides hold the mapping and the nonce was read back: frames may
+  // say kCodecMapped
+  bool attached = false;
+
+  Val* req() const {
+    return reinterpret_cast<Val*>(base + kMappedHeaderBytes);
+  }
+  Val* reply() const {
+    return reinterpret_cast<Val*>(base + kMappedHeaderBytes +
+                                  MappedStride(area_vals));
+  }
+  // Map the whole of memory file `fd` (MappedBytes(vals) long; the
+  // caller has checked or made that length).
+  bool Map(int fd, uint64_t vals) {
+    void* p = mmap(nullptr, MappedBytes(vals), PROT_READ | PROT_WRITE,
+                   MAP_SHARED, fd, 0);
+    if (p == MAP_FAILED) return false;
+    base = static_cast<uint8_t*>(p);
+    bytes = MappedBytes(vals);
+    area_vals = vals;
+    return true;
+  }
+  void Unmap() {
+    if (base != nullptr) munmap(base, bytes);
+    *this = MappedSegment{};
+  }
+};
 
 }  // namespace distlr
 

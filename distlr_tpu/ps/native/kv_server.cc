@@ -99,6 +99,7 @@
 #include <netinet/in.h>
 #include <netinet/tcp.h>
 #include <pthread.h>
+#include <sys/mman.h>
 #include <sys/socket.h>
 #include <sys/stat.h>
 #include <sys/time.h>
@@ -117,6 +118,7 @@
 #include <cstdio>
 #include <cstdlib>
 #include <mutex>
+#include <random>
 #include <string>
 #include <csignal>
 #include <set>
@@ -163,11 +165,18 @@ struct PendingPush {
   // can be rolled back out of the merge buffer (worker-restart recovery;
   // the reference has no such path — SURVEY.md §5.3): the row keys as
   // sent (rows() views them) and the values, which are the frame's own
-  // buffer moved here, not a copy.
+  // buffer moved here, not a copy.  A mapped push's values
+  // (kv_protocol.h kCodecMapped) stay where they are, in its
+  // connection's request area, which the client leaves alone until it
+  // is answered and the server keeps mapped until DropConnection has
+  // run: `vals` is then empty and `in_map` says where they stand.
   std::vector<Key> keys{};
   uint64_t vpk = 1;
   bool run = false;
   std::vector<Val> vals{};
+  const Val* in_map = nullptr;
+  // where a mapped push's reply values go: its connection's reply area
+  Val* reply_area = nullptr;
   // kPushPull: the deferred reply carries the post-round weights for
   // this push's keys (the fused pull half) instead of an empty frame.
   bool want_vals = false;
@@ -181,6 +190,22 @@ struct PendingPush {
   double merged_s = 0.0;
 
   Rows rows() const { return {keys.data(), keys.size(), vpk, run}; }
+  const Val* grad() const { return in_map != nullptr ? in_map : vals.data(); }
+};
+
+// A connection's shared mapping on the server's side (kv_protocol.h
+// "values in a mapping"): the segment, and until the client has
+// confirmed, the memory file's descriptor and the nonce written there.
+struct MappedConn {
+  MappedSegment seg;
+  int memfd = -1;
+  uint64_t nonce = 0;
+
+  void Release() {
+    if (memfd >= 0) close(memfd);
+    memfd = -1;
+    seg.Unmap();
+  }
 };
 
 struct FtrlParams {
@@ -562,8 +587,11 @@ class KVServer {
   }
 
   void Serve(int fd) {
+    // this connection's mapping: outlives FinishConnection, whose
+    // rollback may read a withheld push in its request area
+    MappedConn map;
     try {
-      ServeLoop(fd);
+      ServeLoop(fd, map);
     } catch (const std::bad_alloc&) {
       // Last line of the never-kill-the-rank invariant: a key just
       // UNDER max_dim_ passes every guard yet can demand a huge
@@ -577,6 +605,7 @@ class KVServer {
                    "for requested capacity failed\n");
     }
     FinishConnection(fd);
+    map.Release();
     {
       // notify UNDER the mutex: the shutdown waiter may destroy this
       // whole object the moment it observes live_serves_ == 0, and it
@@ -589,7 +618,7 @@ class KVServer {
     }
   }
 
-  void ServeLoop(int fd) {
+  void ServeLoop(int fd, MappedConn& map) {
     std::vector<Key> keys;
     std::vector<Val> vals;
     std::vector<uint8_t> coded;
@@ -660,8 +689,9 @@ class KVServer {
       bool keys_ok = true;
       // one ascending consecutive run of keys is one range of flat
       // slots (every default-key op of a dense worker: Rows::run)
-      bool run = h.num_keys > 0;
-      for (uint64_t i = 0; i < h.num_keys; ++i) {
+      // (a kHello's keys are the attach's words, not coordinates)
+      bool run = keyed_op && h.num_keys > 0;
+      for (uint64_t i = 0; keyed_op && i < h.num_keys; ++i) {
         if (keys[i] >= key_cap) { keys_ok = false; break; }
         if (keys[i] > max_key) max_key = keys[i];
         if (keys[i] != keys[0] + i) run = false;
@@ -678,6 +708,23 @@ class KVServer {
       const uint64_t n_flat = rows.flat();
       // the frame's highest flat slot, for EnsureCapacity
       max_key = max_key * vpk + vpk - 1;
+      // Values in the mapping (kv_protocol.h kCodecMapped): only on a
+      // connection that attached, never an opt-state pair, and no more
+      // than the area's real length holds.  Anything else is wire
+      // corruption: this connection goes, the server stays.
+      const bool mapped = keyed_op && CodecOf(h.flags) == kCodecMapped;
+      if (mapped && (!map.seg.attached || (h.flags & kOptState) ||
+                     n_flat > map.seg.area_vals)) {
+        std::fprintf(stderr,
+                     "[distlr_kv_server] dropping connection: mapped "
+                     "frame of %llu values on a connection whose area "
+                     "holds %llu (attached %d, flags 0x%x)\n",
+                     (unsigned long long)n_flat,
+                     (unsigned long long)map.seg.area_vals,
+                     map.seg.attached ? 1 : 0, h.flags);
+        break;
+      }
+      Val* const reply_area = mapped ? map.seg.reply() : nullptr;
       if (op == Op::kPush || op == Op::kPushPull) {
         // Wire codec (kv_protocol.h): a coded push's value payload is
         // decoded HERE, at the parsing layer, so every handler below
@@ -686,7 +733,7 @@ class KVServer {
         // semantics cannot diverge.  A codec
         // this server never advertised (negotiation is the only legal
         // path to these bits) is wire corruption: drop the connection.
-        const uint8_t codec = CodecOf(h.flags);
+        const uint8_t codec = mapped ? uint8_t{kCodecNone} : CodecOf(h.flags);
         const bool opt_state = (h.flags & kOptState) != 0;
         if (codec != kCodecNone &&
             (!compress_ || codec > kCodecSign || opt_state ||
@@ -706,14 +753,19 @@ class KVServer {
                        "kOptState push without kInitPush\n");
           break;
         }
+        // where the push's values stand: the request area, read in
+        // place, or this connection's buffer, read off the socket
+        const Val* pushed = mapped ? map.seg.req() : nullptr;
         if (codec != kCodecNone) {
           if (!ReadChunked(fd, coded, CodecPayloadBytes(codec, n_flat)))
             break;
           vals.resize(n_flat);
           DecodeGrad(codec, coded.data(), n_flat, vals.data());
-        } else if (!ReadChunked(fd, vals, opt_state ? 2 * n_flat : n_flat)) {
+        } else if (!mapped &&
+                   !ReadChunked(fd, vals, opt_state ? 2 * n_flat : n_flat)) {
           break;
         }
+        if (!mapped) pushed = vals.data();
         if (traced) tr_decoded = WallNowS();
         const double recv_s = MonoNowS() - recv_t0;
         if (EpochFence(fd, h)) {
@@ -723,8 +775,8 @@ class KVServer {
         if (opt_state) {
           HandleOptStatePush(fd, h, rows, vals, max_key);
         } else {
-          HandlePush(fd, h, rows, vals, reply, max_key,
-                     op == Op::kPushPull, recv_s);
+          HandlePush(fd, h, rows, pushed, mapped ? nullptr : &vals, reply,
+                     reply_area, max_key, op == Op::kPushPull, recv_s);
         }
         if (traced) {
           TraceLog(op == Op::kPushPull ? "kv.push_pull" : "kv.push", tf,
@@ -740,7 +792,7 @@ class KVServer {
         if (h.flags & kOptState) {
           HandleOptStatePull(fd, h, rows, reply, max_key);
         } else {
-          HandlePull(fd, h, rows, reply, max_key);
+          HandlePull(fd, h, rows, reply, reply_area, max_key);
         }
         if (traced) {
           TraceLog("kv.pull", tf, tr_t0, tr_decoded, WallNowS(), n_flat,
@@ -754,7 +806,7 @@ class KVServer {
       } else if (op == Op::kStats) {
         HandleStats(fd, h);
       } else if (op == Op::kHello) {
-        HandleHello(fd, h);
+        HandleHello(fd, h, keys, map);
       } else if (op == Op::kEpoch) {
         HandleEpoch(fd, h);
       } else if (op == Op::kShutdown) {
@@ -865,11 +917,13 @@ class KVServer {
     h.flags = static_cast<uint8_t>((h.flags | kResponse) & ~kTraced);
     h.num_keys = nvals;
     // Responses carry vals only (keys are implied by the request);
-    // header and payload leave in one writev.
+    // header and payload leave in one writev.  No `vals` for nvals > 0:
+    // they stand in the connection's reply area (kCodecMapped, echoed
+    // in the flags) and the header alone says so.
     iovec iov[2] = {{&h, sizeof(h)},
                     {const_cast<Val*>(vals), nvals * sizeof(Val)}};
     iovec* at = iov;
-    int left = nvals ? 2 : 1;
+    int left = nvals && vals != nullptr ? 2 : 1;
     while (left > 0) {
       ssize_t r = writev(fd, at, left);
       if (r <= 0) return;
@@ -897,12 +951,17 @@ class KVServer {
   // --- HELLO: capability handshake (kv_protocol.h).  With --compress=0
   // the reply is the legacy empty frame — byte-identical to a pre-codec
   // server, which is exactly what negotiating clients fall back on. ---
-  void HandleHello(int fd, const MsgHeader& h) {
+  void HandleHello(int fd, const MsgHeader& h, const std::vector<Key>& keys,
+                   MappedConn& map) {
     if (!compress_) {
       Respond(fd, h, nullptr, 0);
       return;
     }
-    uint64_t mask = kCapCodecInt8 | kCapTrace | kCapEpoch;
+    if (CodecOf(h.flags) == kCodecMapped) {
+      HandleAttach(fd, h, keys, map);
+      return;
+    }
+    uint64_t mask = kCapCodecInt8 | kCapTrace | kCapEpoch | kCapMapped;
     // sign votes only mean majority-vote through the signsgd kernel;
     // any other optimizer would apply sign-mean, so don't offer it
     if (opt_ == Opt::kSign) mask |= kCapCodecSign;
@@ -918,6 +977,71 @@ class KVServer {
     }
     Val out[2];
     std::memcpy(out, &d, sizeof(d));
+    Respond(fd, h, out, 2);
+  }
+
+  // --- the shared mapping's attach (kv_protocol.h "values in a
+  // mapping"): ASK makes the segment for a client that is this socket's
+  // own peer, CONFIRM arms it once the client has read the nonce back.
+  // Every refusal is the empty reply; the client then stays on the
+  // socket. ---
+  void HandleAttach(int fd, MsgHeader h, const std::vector<Key>& keys,
+                    MappedConn& map) {
+    // the replies are control frames: their codec field stays clear
+    h.flags = static_cast<uint8_t>(h.flags & ~kCodecMask);
+    if (h.aux == kMappedAsk) {
+      sockaddr_in peer{};
+      socklen_t len = sizeof(peer);
+      // one segment a connection; the asker must be the peer itself
+      if (h.num_keys != 2 || map.seg.base != nullptr || keys[1] == 0 ||
+          keys[1] > max_dim_ ||
+          getpeername(fd, reinterpret_cast<sockaddr*>(&peer), &len) < 0 ||
+          peer.sin_family != AF_INET ||
+          keys[0] != ((static_cast<uint64_t>(ntohl(peer.sin_addr.s_addr))
+                       << 16) | ntohs(peer.sin_port))) {
+        Respond(fd, h, nullptr, 0);
+        return;
+      }
+      const uint64_t vals = keys[1];
+      map.memfd = memfd_create("distlr-kv", MFD_CLOEXEC | MFD_ALLOW_SEALING);
+      if (map.memfd < 0 ||
+          ftruncate(map.memfd, static_cast<off_t>(MappedBytes(vals))) < 0 ||
+          fcntl(map.memfd, F_ADD_SEALS,
+                F_SEAL_SHRINK | F_SEAL_GROW | F_SEAL_SEAL) < 0 ||
+          !map.seg.Map(map.memfd, vals)) {
+        map.Release();
+        Respond(fd, h, nullptr, 0);
+        return;
+      }
+      std::random_device rd;
+      do {
+        map.nonce = (static_cast<uint64_t>(rd()) << 32) | rd();
+      } while (map.nonce == 0);
+      std::memcpy(map.seg.base, &map.nonce, sizeof(map.nonce));
+      std::memcpy(map.seg.base + sizeof(map.nonce), &vals, sizeof(vals));
+      const uint64_t said[3] = {static_cast<uint64_t>(getpid()),
+                                static_cast<uint64_t>(map.memfd), vals};
+      Val out[6];
+      std::memcpy(out, said, sizeof(said));
+      Respond(fd, h, out, 6);
+      return;
+    }
+    // CONFIRM (or anything else): the descriptor goes either way, so
+    // the segment has no handle left but the two mappings
+    const bool ok = h.aux == kMappedConfirm && h.num_keys == 1 &&
+                    map.seg.base != nullptr && !map.seg.attached &&
+                    map.memfd >= 0 && keys[0] == map.nonce;
+    if (!ok) {
+      if (!map.seg.attached) map.Release();
+      Respond(fd, h, nullptr, 0);
+      return;
+    }
+    close(map.memfd);
+    map.memfd = -1;
+    map.seg.attached = true;
+    const uint64_t armed = 1;
+    Val out[2];
+    std::memcpy(out, &armed, sizeof(armed));
     Respond(fd, h, out, 2);
   }
 
@@ -1081,9 +1205,9 @@ class KVServer {
   }
 
   // weights_ seeded from a frame's values (init, or a first push).
-  void SeedWeights(const Rows& rows, const std::vector<Val>& vals) {
+  void SeedWeights(const Rows& rows, const Val* vals) {
     rows.ForSpans([&](Key s, uint64_t at, uint64_t n) {
-      std::memcpy(weights_.data() + s, vals.data() + at, n * sizeof(Val));
+      std::memcpy(weights_.data() + s, vals + at, n * sizeof(Val));
     });
   }
 
@@ -1116,7 +1240,12 @@ class KVServer {
       return;
     }
     const double write_t0 = MonoNowS();
-    if (pr.run) {
+    if (p.reply_area != nullptr) {
+      // a mapped push: the weights into its connection's reply area,
+      // then the header that says they are there
+      CopyRows(weights_, pr, p.reply_area);
+      Respond(p.fd, p.header, nullptr, pr.flat());
+    } else if (pr.run) {
       Respond(p.fd, p.header, weights_.data() + pr.keys[0] * pr.vpk,
               pr.flat());
     } else {
@@ -1222,12 +1351,16 @@ class KVServer {
   // --- PUSH: the reference DataHandle push branch (src/main.cc:48-84).
   // reply_weights = fused kPushPull: the reply carries the post-update
   // weights for the pushed keys (see kv_protocol.h), copied into
-  // `reply` under mu_ and written after it is released.  A BSP push
-  // takes `vals` with it (moved into the round's pending list). ---
+  // `reply` under mu_ and written after it is released.  `vals` are
+  // the push's values where they stand: in `frame` (the connection's
+  // buffer, which a BSP push takes with it into the round's pending
+  // list), or, `frame` null, in the connection's request area, read in
+  // place (kv_protocol.h kCodecMapped); then `reply_area` is where the
+  // reply's values go, under mu_, in place of `reply` and the socket. ---
   void HandlePush(int fd, const MsgHeader& h, const Rows& rows,
-                  std::vector<Val>& vals, std::vector<Val>& reply,
-                  Key max_key, bool reply_weights = false,
-                  double recv_s = 0.0) {
+                  const Val* vals, std::vector<Val>* frame,
+                  std::vector<Val>& reply, Val* reply_area, Key max_key,
+                  bool reply_weights = false, double recv_s = 0.0) {
     // kStats lock_wait_seconds: what a push stood behind its peers'
     // merges and the release before its own could begin
     const double asked_s = MonoNowS();
@@ -1241,6 +1374,7 @@ class KVServer {
     if (reply_weights) ++n_pull_;  // it serves the next pull too
     // a fused frame stands in both counts, so it does here
     if (rows.run) run_frames_ += reply_weights ? 2 : 1;
+    if (frame == nullptr) mapped_frames_ += reply_weights ? 2 : 1;
     // max_key computed by Serve over the WHOLE frame — the last key
     // would assume sorted keys, and an unsorted frame would then write
     // out of bounds.
@@ -1249,11 +1383,14 @@ class KVServer {
     // with the weights as this push leaves them
     const auto reply_now = [&] {
       const uint64_t n = reply_weights ? rows.flat() : 0;
-      if (n) CopyRows(weights_, rows, SizedFor(reply, n));
+      Val* const out = n == 0 ? nullptr
+                       : reply_area != nullptr ? reply_area
+                                               : SizedFor(reply, n);
+      if (n) CopyRows(weights_, rows, out);
       const double done_s = MonoNowS();
       merge_s_ += done_s - held_s;
       lock.unlock();
-      Respond(fd, h, reply.data(), n);
+      Respond(fd, h, reply_area != nullptr ? nullptr : out, n);
       if (n) AddReplyWrite(MonoNowS() - done_s);
     };
 
@@ -1268,7 +1405,7 @@ class KVServer {
         // WAL records describe the mutation that ACTUALLY happened (a
         // no-op'd idempotent re-init is not logged), so replay applies
         // every record unconditionally.
-        WalAppend(n_push_, kInitPush, Op::kPush, rows, vals);
+        WalAppend(n_push_, kInitPush, Op::kPush, rows, vals, rows.flat());
       }
       reply_now();
       return;
@@ -1284,7 +1421,7 @@ class KVServer {
       // logged as an init record: the SEMANTIC was a seed (weights
       // set, not gradient-applied), and replay must reproduce exactly
       // that regardless of what the wire flags said
-      WalAppend(n_push_, kInitPush, Op::kPush, rows, vals);
+      WalAppend(n_push_, kInitPush, Op::kPush, rows, vals, rows.flat());
       reply_now();
       return;
     }
@@ -1293,11 +1430,11 @@ class KVServer {
       // Async/Hogwild: apply immediately (src/main.cc:79-84) under the
       // configured optimizer (SGD or per-coordinate FTRL-Proximal).
       rows.ForSpans([&](Key s, uint64_t at, uint64_t n) {
-        ApplySpan(s, vals.data() + at, n);
+        ApplySpan(s, vals + at, n);
       });
       // empty "present" votes are logged too: the WAL clock must track
       // n_push_ exactly or the RPO push-clock audit would drift
-      WalAppend(n_push_, 0, Op::kPush, rows, vals);
+      WalAppend(n_push_, 0, Op::kPush, rows, vals, rows.flat());
       reply_now();
       return;
     }
@@ -1313,17 +1450,21 @@ class KVServer {
     if (merge_.size() < weights_.size()) merge_.resize(weights_.size(), 0.0f);
     pending_.push_back({fd, h,
                         std::vector<Key>(rows.keys, rows.keys + rows.num_keys),
-                        rows.vpk, rows.run, {}, reply_weights, MonoNowS()});
+                        rows.vpk, rows.run, {}, frame == nullptr ? vals : nullptr,
+                        reply_area, reply_weights, MonoNowS()});
     // The entry takes the frame's buffer, and the connection one a
     // released push has handed back (same size in a steady job, so its
-    // next frame is read into it as it stands).
-    pending_.back().vals.swap(vals);
-    if (!spare_vals_.empty()) {
-      vals.swap(spare_vals_.back());
-      spare_vals_.pop_back();
+    // next frame is read into it as it stands).  A mapped push has no
+    // buffer to take: its values wait in the request area.
+    if (frame != nullptr) {
+      pending_.back().vals.swap(*frame);
+      if (!spare_vals_.empty()) {
+        frame->swap(spare_vals_.back());
+        spare_vals_.pop_back();
+      }
     }
     {
-      const Val* g = pending_.back().vals.data();
+      const Val* g = pending_.back().grad();
       rows.ForSpans([&](Key s, uint64_t at, uint64_t n) {
         Val* m = merge_.data() + s;
         for (uint64_t j = 0; j < n; ++j) m[j] += g[at + j];
@@ -1363,7 +1504,7 @@ class KVServer {
             pick = &p;
         }
         if (pick != nullptr) {
-          const Val* g = pick->vals.data();
+          const Val* g = pick->grad();
           pick->rows().ForSpans([&](Key s, uint64_t at, uint64_t n) {
             Val* wt = weights_.data() + s;
             for (uint64_t j = 0; j < n; ++j) wt[j] -= lr_ * g[at + j] / w;
@@ -1468,7 +1609,7 @@ class KVServer {
     std::lock_guard<std::mutex> lock(mu_);
     for (auto it = pending_.begin(); it != pending_.end();) {
       if (it->fd == fd) {
-        const Val* g = it->vals.data();
+        const Val* g = it->grad();
         it->rows().ForSpans([&](Key s, uint64_t at, uint64_t n) {
           Val* m = merge_.data() + s;
           for (uint64_t j = 0; j < n; ++j) m[j] -= g[at + j];  // roll back
@@ -1531,25 +1672,30 @@ class KVServer {
         std::memcpy(nacc_.data() + s, vals.data() + flat + at,
                     n * sizeof(Val));
       });
-      WalAppend(n_push_, kOptState | kInitPush, Op::kPush, rows, vals);
+      WalAppend(n_push_, kOptState | kInitPush, Op::kPush, rows, vals.data(),
+                vals.size());
     }
     Respond(fd, h, nullptr, 0);
   }
 
   // --- PULL: reply current weights (src/main.cc:85-95) ---
+  // (`reply_area`: the connection's, where the request said
+  // kCodecMapped; the weights then go straight there, under mu_)
   void HandlePull(int fd, const MsgHeader& h, const Rows& rows,
-                  std::vector<Val>& reply, Key max_key) {
-    Val* out = SizedFor(reply, rows.flat());
+                  std::vector<Val>& reply, Val* reply_area, Key max_key) {
+    Val* out = reply_area != nullptr ? reply_area
+                                     : SizedFor(reply, rows.flat());
     {
       std::lock_guard<std::mutex> lock(mu_);
       ++n_pull_;
       run_frames_ += rows.run;
+      mapped_frames_ += reply_area != nullptr;
       // frame-wide max from Serve, not the last key (unsorted frame =>
       // out-of-bounds read)
       if (rows.num_keys) EnsureCapacity(max_key);
       CopyRows(weights_, rows, out);
     }
-    Respond(fd, h, out, rows.flat());
+    Respond(fd, h, reply_area != nullptr ? nullptr : out, rows.flat());
   }
 
   // --- STATS: liveness/progress probe (no reference equivalent — the
@@ -1565,6 +1711,8 @@ class KVServer {
             ? std::min<uint64_t>(h.aux, kStatsVals)
             : kStatsValsV1;
     double stats[kStatsVals];
+    // slots 11 and up: the additive tail after `epoch`
+    double* const tail = stats + kStatsValsV1 + kCpuSlots + 1;
     {
       std::lock_guard<std::mutex> lock(mu_);
       stats[0] = static_cast<double>(weights_.size());
@@ -1580,7 +1728,6 @@ class KVServer {
       stats[kStatsValsV1 + kCpuSlots] = static_cast<double>(epoch_);
       // slots 11-14 (the BSP barrier's tail, additive like the rest;
       // zeros from an async server)
-      double* tail = stats + kStatsValsV1 + kCpuSlots + 1;
       tail[0] = static_cast<double>(sync_rounds_);
       tail[1] = sync_hold_s_;
       tail[2] = sync_spread_s_;
@@ -1601,8 +1748,12 @@ class KVServer {
       tail[9] = merge_s_;
       tail[10] = sync_wait_s_;
       tail[11] = release_apply_s_;
+      // slot 24: of the operations slots 4 and 5 count, those whose
+      // values crossed in a connection's mapping
+      tail[13] = static_cast<double>(mapped_frames_);
     }
-    stats[kStatsVals - 1] =
+    // slot 23
+    tail[12] =
         1e-9 * static_cast<double>(
                    reply_write_ns_.load(std::memory_order_relaxed));
     // per-handler thread-CPU seconds (the continuous-profiling
@@ -2137,11 +2288,11 @@ class KVServer {
   // record, and only where a WAL is armed (ps/store.py, ReplaySegment
   // and the RPO audit read what they always read).
   void WalAppend(uint64_t seq, uint8_t flags, Op op, const Rows& rows,
-                 const std::vector<Val>& vals) {
+                 const Val* vals, uint64_t nvals) {
     if (wal_fd_ < 0) return;
     const uint32_t nkeys = static_cast<uint32_t>(rows.flat());
     const size_t kb = rows.flat() * sizeof(Key);
-    const size_t vb = vals.size() * sizeof(Val);
+    const size_t vb = nvals * sizeof(Val);
     wal_buf_.resize(kWalRecordHeaderSize + kb + vb);
     uint8_t* b = wal_buf_.data();
     std::memset(b, 0, kWalRecordHeaderSize);
@@ -2156,7 +2307,7 @@ class KVServer {
         std::memcpy(kout + (at + j) * sizeof(Key), &k, sizeof(Key));
       }
     });
-    if (vb) std::memcpy(b + kWalRecordHeaderSize + kb, vals.data(), vb);
+    if (vb) std::memcpy(b + kWalRecordHeaderSize + kb, vals, vb);
     uint32_t crc = Crc32(0, b + kWalRecordHeaderSize, kb + vb);
     std::memcpy(b + 16, &crc, 4);
     if (!WriteFull(wal_fd_, b, wal_buf_.size())) {
@@ -2441,6 +2592,10 @@ class KVServer {
   //: value-carrying replies' writes took, each added by the thread that
   //: wrote it (atomic, as cpu_us_ is: an async reply leaves after mu_)
   std::atomic<uint64_t> reply_write_ns_{0};
+  //: of the operations n_push_ and n_pull_ count, those whose values
+  //: crossed in their connection's mapping (guarded by mu_; kStats
+  //: mapped_frames): a fused push-pull twice, as in run_frames_
+  uint64_t mapped_frames_ = 0;
   //: the release's writers (all guarded by wr_mu_): the replies handed
   //: over (the first wr_todo_ not yet taken, wr_left_ not yet written),
   //: the writers' thread-CPU since the last join, and the threads alive

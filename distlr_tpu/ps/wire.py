@@ -60,6 +60,18 @@ FLAG_TRACED = 128
 CODEC_NONE = 0
 CODEC_INT8 = 1
 CODEC_SIGN = 2
+#: not a compression but a carrier: the frame's float32 values cross in
+#: the connection's shared mapping, none on the socket (kv_protocol.h
+#: "values in a mapping": same-host, direct connections that attached)
+CODEC_MAPPED = 3
+
+#: value payloads under this many bytes stay on the socket (kMappedMinBytes)
+MAPPED_MIN_BYTES = 65536
+#: the mapping's header and the alignment of its two areas
+MAPPED_HEADER_BYTES = 4096
+#: a mapped kHello's aux: the attach's two steps (ask, confirm)
+MAPPED_ASK = 1
+MAPPED_CONFIRM = 2
 
 #: int8 block-quantization granularity, values per f32 scale (kQuantBlock)
 QUANT_BLOCK = 256
@@ -69,6 +81,8 @@ CAP_CODEC_INT8 = 1 << CODEC_INT8
 CAP_CODEC_SIGN = 1 << CODEC_SIGN
 CAP_TRACE = 1 << 8
 CAP_EPOCH = 1 << 9
+#: the server shares a mapping with a same-host client that asks
+CAP_MAPPED = 1 << 10
 
 # --- kStats reply shape ------------------------------------------------
 #: the original six integer counters every vintage replies (kStatsValsV1)
@@ -77,7 +91,8 @@ STATS_VALS_V1 = 6
 #: BSP barrier's four (rounds, hold, spread, release CPU) + run_frames +
 #: lock_wait_seconds + the release's two (fanned replies, wall) + a
 #: push's five phases (recv, merge, sync wait, release apply, reply write)
-STATS_VALS = 24
+#: + mapped_frames
+STATS_VALS = 25
 
 #: wire-corruption guard for vals_per_key (kMaxValsPerKey)
 MAX_VALS_PER_KEY = 4096
@@ -115,6 +130,8 @@ def codec_payload_bytes(codec: int, n: int) -> int:
     """Exact value-payload bytes of a coded frame carrying ``n`` values
     (native ``CodecPayloadBytes`` — both sides derive the size from
     ``(codec, n)``, so coded frames need no extra length field)."""
+    if codec == CODEC_MAPPED:
+        return 0  # the values are in the mapping, not on the socket
     if codec == CODEC_INT8:
         return ((n + QUANT_BLOCK - 1) // QUANT_BLOCK) * 4 + n
     if codec == CODEC_SIGN:

@@ -558,7 +558,8 @@ class TestNativeCpu:
         v1 reply; the extension replies at most kStatsVals (11 since the
         membership round appended the epoch slot, 15 since the BSP
         barrier's tail, 16 since run_frames, 17 since lock_wait_seconds,
-        19 since the release's fan-out, 24 since a push's phases)."""
+        19 since the release's fan-out, 24 since a push's phases, 25
+        since ``mapped_frames``)."""
         import socket
         import struct
 
@@ -571,7 +572,8 @@ class TestNativeCpu:
                 # client_id u32, ts u32, num_keys u64; op 6 = kStats
                 for aux, expect_slots in ((0, 12), (10, 20), (11, 22),
                                           (15, 30), (16, 32), (17, 34),
-                                          (19, 38), (24, 48), (64, 48)):
+                                          (19, 38), (24, 48), (25, 50),
+                                          (64, 50)):
                     s.sendall(struct.pack("<IBBHIIQ", 0xD157C0DE, 6, 0,
                                           aux, 1, 1, 0))
                     hdr = s.recv(24, socket.MSG_WAITALL)

@@ -51,14 +51,16 @@ def _rounds(group, sync, delays):
 
 
 def test_the_tail_stands_after_epoch_in_the_wires_order():
-    assert STATS_FIELDS[-len(TAIL) - 9:] == TAIL + (
+    assert STATS_FIELDS[-len(TAIL) - 10:] == TAIL + (
         "run_frames", "lock_wait_seconds",
         "release_fanned_replies", "release_wall_seconds",
         # a push's phases (test_ps_push_phases.py has what they count)
         "recv_seconds", "merge_seconds", "sync_wait_seconds",
-        "release_apply_seconds", "reply_write_seconds")
-    assert STATS_FIELDS[-len(TAIL) - 10] == "epoch"
-    assert len(STATS_FIELDS) == wire.STATS_VALS == 24
+        "release_apply_seconds", "reply_write_seconds",
+        # values that crossed in a mapping (test_ps_mapped_payload.py)
+        "mapped_frames")
+    assert STATS_FIELDS[-len(TAIL) - 11] == "epoch"
+    assert len(STATS_FIELDS) == wire.STATS_VALS == 25
 
 
 @pytest.mark.parametrize("sync", [True, False], ids=["bsp", "async"])
@@ -160,15 +162,16 @@ def test_a_request_of_the_old_length_is_still_answered():
     """A client from before ``run_frames`` asks for fifteen counters and
     gets fifteen, the barrier's tail last, one from before
     ``lock_wait_seconds`` sixteen, one from before the release's fan-out
-    seventeen, one from before a push's phases nineteen; one that asks
-    for more than there are gets what there is."""
+    seventeen, one from before a push's phases nineteen, one from before
+    ``mapped_frames`` twenty-four; one that asks for more than there are
+    gets what there is."""
     with ServerGroup(1, 1, DIM, sync=False) as g:
         with KVWorker(g.hosts, DIM, client_id=0, sync_group=False) as kv:
             kv.wait(kv.push_init(np.ones(DIM, np.float32)))
             kv.pull()
         with socket.create_connection(("127.0.0.1", g.ports[0])) as s:
             for aux, slots in ((15, 15), (16, 16), (17, 17), (18, 18),
-                               (19, 19), (24, 24), (99, 24)):
+                               (19, 19), (24, 24), (25, 25), (99, 25)):
                 s.sendall(wire.HEADER_STRUCT.pack(
                     wire.MAGIC, wire.OP_STATS, 0, aux, 7, 1, 0))
                 hdr = s.recv(wire.HEADER_STRUCT.size, socket.MSG_WAITALL)
@@ -184,7 +187,8 @@ def test_a_request_of_the_old_length_is_still_answered():
                 assert ("lock_wait_seconds" in named) == (slots >= 17)
                 assert named.get("lock_wait_seconds", 0.0) >= 0.0
                 assert ("release_wall_seconds" in named) == (slots >= 19)
-                assert ("reply_write_seconds" in named) == (slots == 24)
+                assert ("reply_write_seconds" in named) == (slots >= 24)
+                assert ("mapped_frames" in named) == (slots == 25)
                 # an async server: the release's two read zero
                 assert named.get("release_fanned_replies", 0.0) == 0.0
                 assert named.get("release_wall_seconds", 0.0) == 0.0
