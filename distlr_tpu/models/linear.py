@@ -269,6 +269,24 @@ class BinaryLR:
         ll = jax.nn.softplus(z) - y.astype(jnp.float32) * z
         return _masked_mean(ll, mask)
 
+    def logits_panels(self, w, Xp, plan):
+        """:meth:`logits` over features held as
+        ``ops.pallas_lr.pad_columns(X, plan)``: one read of ``Xp`` out of
+        HBM, float32 whatever ``compute_dtype`` says, as
+        :meth:`grad_panels`' forward sweep is."""
+        from distlr_tpu.ops.pallas_lr import lr_logits_rows  # noqa: PLC0415
+
+        z = lr_logits_rows(w, Xp, plan)
+        return z * self.feature_scale if self.feature_scale != 1.0 else z
+
+    def eval_from_logits(self, z, y, mask):
+        """``(accuracy, logloss)`` of the rows whose logits are ``z``:
+        what :meth:`accuracy` and :meth:`logloss` give, from one forward
+        pass (no L2 term, the mask honoured)."""
+        correct = ((z > 0).astype(jnp.int32) == y).astype(jnp.float32)
+        ll = jax.nn.softplus(z) - y.astype(jnp.float32) * z
+        return _masked_mean(correct, mask), _masked_mean(ll, mask)
+
 
 @dataclasses.dataclass(frozen=True)
 class SoftmaxRegression:
@@ -357,6 +375,14 @@ class SoftmaxRegression:
         z = self.logits(W, X)
         ll = -jax.nn.log_softmax(z)[jnp.arange(z.shape[0]), y]
         return _masked_mean(ll, mask)
+
+    def eval_from_logits(self, z, y, mask):
+        """``(accuracy, logloss)`` from one forward pass's ``(B, K)``
+        logits (see BinaryLR.eval_from_logits)."""
+        correct = (jnp.argmax(z, axis=-1).astype(jnp.int32) == y).astype(
+            jnp.float32)
+        ll = -jax.nn.log_softmax(z)[jnp.arange(z.shape[0]), y]
+        return _masked_mean(correct, mask), _masked_mean(ll, mask)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -137,6 +137,23 @@ def pad_columns(X, plan: PanelPlan):
                    ((0, 0), (0, plan.dim_padded - plan.dim)))
 
 
+def lr_logits_rows(w, Xp, plan: PanelPlan):
+    """``X w``, ``float32[rows]``, from ``Xp = pad_columns(X, plan)``: the
+    kernel's forward sweep alone, for whoever wants the logits and no
+    gradient (a PS worker's eval over its resident test rows).  Nothing
+    has to stay in VMEM between two sweeps here, so this is plain
+    ``jnp``: the elementwise product and the lane reduction are one XLA
+    fusion that streams ``Xp`` out of HBM once, float32 throughout (a
+    ``dot`` of float32 operands is the MXU's in bfloat16 passes, and an
+    N = 1 product wastes it)."""
+    if Xp.shape != (plan.rows, plan.dim_padded) or Xp.dtype != jnp.float32:
+        raise ValueError(
+            f"the forward reads float32{[plan.rows, plan.dim_padded]} "
+            f"(pad_columns), not {Xp.dtype}{list(Xp.shape)}")
+    wp = jnp.pad(w.astype(jnp.float32), (0, plan.dim_padded - plan.dim))
+    return jnp.sum(Xp * wp[None, :], axis=1)
+
+
 def _kernel(plan: PanelPlan, x_hbm, w_ref, y_ref, mask_ref, g_ref, buf, sems):
     """``x_hbm``: ``f32[rows, dim_padded]`` in HBM; ``w_ref``:
     ``f32[chunks, weight_rows, 128]``, chunk ``k``'s tiles one a row;

@@ -118,6 +118,34 @@ _RESIDENT_BYTES = get_registry().gauge(
 )
 
 
+#: Rank 0's test split where its eval keeps it on the eval's device
+#: (:meth:`PSWorker.evaluate`): placed at the first eval, read in place
+#: by every later one; 0 where each eval streams the split from the host
+#: (it does not fit beside what the device holds, or the eval is numpy's).
+_TEST_RESIDENT_BYTES = get_registry().gauge(
+    "distlr_ps_test_resident_bytes",
+    "bytes of the test split a PS worker's eval keeps on its device "
+    "(0 = every eval streams the split from the host)",
+    labelnames=("rank",),
+)
+_EVALS = get_registry().counter(
+    "distlr_ps_evals_total",
+    "evals of the whole test split a PS worker ran (rank 0's, every "
+    "test_interval epochs, and any direct evaluate() call)",
+    labelnames=("rank",),
+)
+_EVAL_ROWS = get_registry().counter(
+    "distlr_ps_eval_rows_total",
+    "test rows a PS worker's evals covered: the whole split an eval",
+    labelnames=("rank",),
+)
+#: A test split is kept on the eval's device where the device says it
+#: has this many times the split's bytes free: at the peak of placement
+#: the bytes as handed over, their restored form and the row-major
+#: relayout stand together (``feed.place``, ``_row_major_program``).
+_TEST_PLACE_HEADROOM = 3
+
+
 #: Which device of its process a dense worker's step is pinned to (the
 #: ``id`` JAX gives it): worker *i* of a process takes device
 #: ``i % len(devices)`` of the job's (``worker_devices``); absent where
@@ -284,6 +312,16 @@ def _describe_compute_device(device) -> str:
     return f"{d.platform}:{d.device_kind} (id {d.id})"
 
 
+def _device_free_bytes(device) -> int | None:
+    """Bytes ``device`` says it can still hand out, or None where the
+    backend keeps no such count (the CPU: host RAM, where the rows
+    already are)."""
+    stats = device.memory_stats() or {}
+    if "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
 def worker_devices(n: int) -> list:
     """The step device of each of a process's ``n`` PS workers: worker
     *i* takes local device ``i % len(devices)`` of the default backend,
@@ -409,11 +447,20 @@ def _compiled_acc(model):
     """Eval takes no cfg, so its cache is keyed on the model alone
     (an L2 sweep must not recompile the full-test-set eval program).
     Returns ``(accuracy, test_logloss)`` — logloss is the driver's
-    parity metric (BASELINE.json epochs-to-logloss)."""
-    return jax.jit(lambda w, X, y, mask: (
-        model.accuracy(w, (X, y, mask)),
-        model.logloss(w, (X, y, mask)),
-    ))
+    parity metric (BASELINE.json epochs-to-logloss) — from ONE forward
+    pass: the logits are formed once and both numbers read off them.
+    ``panels`` (static) is the plan of a resident row-major ``X``
+    (``PSWorker._place_rows``): the float32 forward over it, in this same
+    jitted function."""
+    # a name of its own and no ``step`` in it: a trace shows the program
+    # as ``jit_ps_eval``, and the benchmark finds the gradient step's
+    # runs by theirs
+    def ps_eval(w, X, y, mask, panels=None):
+        z = (model.logits(w, X) if panels is None
+             else model.logits_panels(w, X, panels))
+        return model.eval_from_logits(z, y, mask)
+
+    return jax.jit(ps_eval, static_argnames=("panels",))
 
 
 def _sparse_batch_grad(w_u, pos, vals, y, mask, l2_c, l2_scale_by_batch):
@@ -620,6 +667,18 @@ class PSWorker:
     ``pinned`` log line names it.  Without one the worker takes the
     default backend's first device.
 
+    The test split (rank 0).  An eval on a jax device keeps the split
+    there too: the first :meth:`evaluate` places it once, as the shard is
+    placed (``feed.place``, then row-major where a plan reads it), where
+    the device says it has room (``_test_on_device``: the split's bytes
+    against the device's free memory, no option), and every later eval
+    is the weights in, one forward pass over the resident rows
+    (``jit_ps_eval``: the logits once, accuracy and logloss off them) and
+    two scalars out.  Where it does not fit, or the eval is numpy's, each
+    eval reads the host's rows as before.
+    ``distlr_ps_test_resident_bytes{rank}`` says which,
+    ``distlr_ps_evals_total`` and ``distlr_ps_eval_rows_total`` count.
+
     How a resident shard is held, and what reads it.  Where the model is
     a ``BinaryLR`` without ``int8_dot``, the device a TPU, the rows whole
     groups of eight and VMEM holds at least a part of a row panel
@@ -650,7 +709,13 @@ class PSWorker:
     device), ``grad_d2h`` (readback), ``push`` (the loop blocked on its
     exchange), ``pull``; ``wire`` on the comm thread (a pipelined fused
     push-pull, send to reply, with the step that submitted it);
-    ``barrier_wait``, ``eval``, ``checkpoint``.  Under whichever of them
+    ``barrier_wait``, ``eval``, ``checkpoint``; under ``eval`` (rank 0,
+    a dense model): ``eval_pull`` (the weights after the round, pulled as
+    the reference's ``Test`` pulls them), ``test_put`` (the first eval
+    alone: the test split placed on the eval's device, to ready),
+    ``eval_w_put``, ``eval_compute`` (dispatch to accuracy and logloss
+    ready; a plain annotation, ``compute`` and the step marker stay the
+    gradient step's), ``eval_d2h``.  Under whichever of them
     is open when a keyed operation returns, ``KVWorker`` records that
     exchange's three phases from the native client's own instants, one
     site for every loop variant here: ``xchg_send`` (the call's start to
@@ -751,6 +816,10 @@ class PSWorker:
         self._eval_dev = None
         self._resident = None  # (X, y, mask) on the step's device
         self._resident_rows = 0
+        #: rank 0's test split on the eval's device, the plan its X is held
+        #: for (or None) and its count of rows: bound by the first eval
+        #: that keeps it
+        self._test_resident = None
         #: the one-pass step's plan where the resident X is held for it
         self._panels = None
         #: dense models: ``(flat weights, batch) -> flat float32
@@ -977,19 +1046,28 @@ class PSWorker:
         if batch is None:
             train.reset()
             batch = train.next_batch()
-        device = _jax_device(step_dev)
-        mesh = make_mesh(devices=[device])
-        self._panels = _one_pass_plan(self.model, *batch[0].shape, device)
-        with self._span("shard_put"):
-            X, *rest = (feed.place(a, mesh) for a in batch)
-            if self._panels is not None:
-                # once, on the device: the columns into the lanes
-                X = _row_major_program(self._panels)(X)
-            placed = jax.block_until_ready((X, *rest))
+        placed, self._panels = self._place_rows(
+            "shard_put", batch, _jax_device(step_dev))
         self._resident_rows = int(batch[-1].sum())
         _RESIDENT_BYTES.labels(rank=str(self.rank)).set(
             sum(a.nbytes for a in batch))
         return placed
+
+    def _place_rows(self, span: str, batch, device):
+        """``(X, y, mask)`` on ``device`` to stay, under a span called
+        ``span``, and the row-panel plan ``X`` is held for (or None):
+        each leaf through ``feed.place``; where a plan reads the rows
+        (``_one_pass_plan``) the features are then relaid on the device,
+        row-major and padded."""
+        mesh = make_mesh(devices=[device])
+        plan = _one_pass_plan(self.model, *batch[0].shape, device)
+        with self._span(span):
+            X, *rest = (feed.place(a, mesh) for a in batch)
+            if plan is not None:
+                # once, on the device: the columns into the lanes
+                X = _row_major_program(plan)(X)
+            placed = jax.block_until_ready((X, *rest))
+        return placed, plan
 
     def _batches(self, train):
         """An epoch's dense batches, each beside its count of real rows;
@@ -1381,23 +1459,94 @@ class PSWorker:
         eval the epoch loop runs on rank 0."""
         cfg, test = self.cfg, self._test
         if cfg.model == "sparse_softmax":
-            return self._sparse_softmax_eval(test)
-        if cfg.model == "sparse_lr":
-            return self._sparse_eval(test)
-        if cfg.model == "blocked_lr":
-            return self._blocked_eval(test)
+            got = self._sparse_softmax_eval(test)
+        elif cfg.model == "sparse_lr":
+            got = self._sparse_eval(test)
+        elif cfg.model == "blocked_lr":
+            got = self._blocked_eval(test)
+        else:
+            return self._dense_eval(w, test)
+        self._count_eval(test.num_samples)
+        return got
+
+    def _count_eval(self, rows: int) -> None:
+        rank = str(self.rank)
+        _EVALS.labels(rank=rank).inc()
+        _EVAL_ROWS.labels(rank=rank).inc(rows)
+
+    def _dense_eval(self, w, test) -> tuple[float, float]:
+        """One forward pass over the whole split at ``w`` (pulled here,
+        as the reference's ``Test`` pulls, where none is given).  On a
+        jax device the split is **resident** where it fits: the first
+        eval places it (:meth:`_test_on_device`) and every later one
+        reads it in place, so an eval moves the weights in and two
+        scalars out.  Spans: ``eval_pull``, ``test_put`` (once),
+        ``eval_w_put``, ``eval_compute`` (dispatch to both scalars
+        ready; no step marker: ``compute`` is the gradient step's),
+        ``eval_d2h``; a split that is streamed crosses under ``h2d``."""
+        cfg = self.cfg
         if w is None:
-            w = self.kv.pull()
-        K = cfg.num_classes if cfg.model == "softmax" else None
-        test.reset()
-        Xt, yt, mt = test.next_batch()
+            with self._span("eval_pull"):
+                w = self.kv.pull()
         if self._eval_dev == "numpy":
+            K = cfg.num_classes if cfg.model == "softmax" else None
+            Xt, yt, mt = self._test_batch(test)
+            self._count_eval(int(mt.sum()))
             return _np_dense_eval(
                 w.reshape(cfg.num_feature_dim, K) if K else w,
                 Xt, yt, mt.astype(np.float32), K)
-        a, ll = self._acc_fn(*self._place(
-            self._eval_dev, self._shape_params(w), Xt, yt, mt))
-        return float(a), float(ll)
+        batch, how, rows = self._test_on_device(test)
+        self._count_eval(rows)
+        with self._span("eval_w_put"):
+            wd = jax.block_until_ready(jax.device_put(
+                self._shape_params(w), _jax_device(self._eval_dev)))
+        with self._span("eval_compute"):
+            got = jax.block_until_ready(self._acc_fn(wd, *batch, **how))
+        with self._span("eval_d2h"):
+            # both scalars in one readback, not one after the other
+            a, ll = (float(v) for v in jax.device_get(got))
+        return a, ll
+
+    @staticmethod
+    def _test_batch(test):
+        """The whole split as one batch: the arrays the iterator holds
+        where the batch is just those (no gather of every row a call)."""
+        batch = test.whole_shard()
+        if batch is None:
+            test.reset()
+            batch = test.next_batch()
+        return batch
+
+    def _test_on_device(self, test):
+        """``((X, y, mask), how, rows)`` for the eval program on the
+        eval's device: the batch, the plan its ``X`` is held for as the
+        program's keyword, and the rows its mask counts.  The split is
+        placed **once**, by the first eval, where the device has room for
+        it beside what the worker already keeps there
+        (``_TEST_PLACE_HEADROOM`` times its bytes free, by the device's
+        own count; a backend that keeps none is the host's memory, where
+        the rows are): as the train shard is placed, under ``test_put``,
+        counted in ``distlr_h2d_bytes_total`` and in
+        ``distlr_ps_test_resident_bytes``.  Where it does not fit, every
+        eval streams it under ``h2d`` as before and the gauge reads 0."""
+        if self._test_resident is None:
+            batch = self._test_batch(test)
+            device = _jax_device(self._eval_dev)
+            nbytes, rows = sum(a.nbytes for a in batch), int(batch[-1].sum())
+            free = _device_free_bytes(device)
+            gauge = _TEST_RESIDENT_BYTES.labels(rank=str(self.rank))
+            if free is not None and free < _TEST_PLACE_HEADROOM * nbytes:
+                gauge.set(0)
+                with self._span("h2d"):
+                    return self._place(device, *batch), {}, rows
+            self._test_resident = (
+                *self._place_rows("test_put", batch, device), rows)
+            gauge.set(nbytes)
+            log.info("rank %d test split resident on %s: %d rows, %d bytes",
+                     self.rank, _describe_compute_device(self._eval_dev),
+                     test.num_samples, nbytes)
+        placed, plan, rows = self._test_resident
+        return placed, ({} if plan is None else {"panels": plan}), rows
 
     def finish(self, *, save=True) -> np.ndarray:
         """Pull the final weights, export them, meet the peers at the

@@ -205,3 +205,54 @@ def test_compiles_for_a_v5e_at_the_cells_size(chips, limit, rows, chip):
                 and "custom-call(" not in line):
             assert " parameter(" in line, line
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# -- the forward alone: a PS worker's eval over its resident test rows -------
+@pytest.mark.parametrize("rows,dim", [(16, 1000), (24, 16384 + 64), (8, 300)])
+def test_the_forward_over_padded_rows_is_the_models_logits(rows, dim):
+    w, X, _y, _mask = _problem(rows, dim, seed=3)
+    plan = panel_plan(rows, dim)
+    z = pallas_lr.lr_logits_rows(w, pad_columns(X, plan), plan)
+    want = np.asarray(X, np.float64) @ np.asarray(w, np.float64)
+    assert z.shape == (rows,) and z.dtype == jnp.float32
+    assert _rel(z, want) <= 1e-6
+    model = BinaryLR(dim, feature_scale=0.5)
+    assert _rel(model.logits_panels(w, pad_columns(X, plan), plan),
+                0.5 * want) <= 1e-6
+
+
+def test_the_forward_refuses_a_matrix_that_was_not_padded():
+    plan = panel_plan(16, 1000)
+    w, X, _y, _mask = _problem(16, 1000)
+    with pytest.raises(ValueError, match="pad_columns"):
+        pallas_lr.lr_logits_rows(w, X, plan)
+
+
+def test_the_eval_compiles_for_a_v5e_as_one_read_of_the_resident_rows(chips):
+    """The eval program at the cell's size (256 x 1,000,000 float32 test
+    rows held row-major and padded): the logits are one fusion over the
+    resident matrix as it lies (no copy, transpose or convert of it, no
+    second pass for the logloss), and both scalars come off them."""
+    rows, dim = 256, 1_000_000
+    plan, model = panel_plan(rows, dim), BinaryLR(dim)
+
+    def ps_eval(w, Xp, y, mask):
+        return model.eval_from_logits(model.logits_panels(w, Xp, plan), y,
+                                      mask)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chips[0])
+
+    compiled = jax.jit(ps_eval).lower(
+        spec((dim,), jnp.float32), spec((rows, plan.dim_padded), jnp.float32),
+        spec((rows,), jnp.int32), spec((rows,), jnp.bool_)).compile()
+    big = f"f32[{rows},{plan.dim_padded}]"
+    entry = compiled.as_text().split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    held = re.search(rf"%(\S+) = {re.escape(big)}\S* parameter\(", entry)
+    readers = [ln for ln in entry.splitlines()
+               if re.search(rf"%{re.escape(held.group(1))}\b", ln)
+               and " parameter(" not in ln]
+    assert len(readers) == 1 and " fusion(" in readers[0], readers
+    # and nothing of the entry computation produces a matrix of that size
+    assert len(re.findall(rf"= {re.escape(big)}", entry)) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20

@@ -198,3 +198,74 @@ def test_a_profiler_trace_holds_the_workers_spans(data_dir, tmp_path):
     # step_num on the step marker, step elsewhere
     assert {int(s["step_num"]) for s in found["compute"]} == {1, 2, 3}
     assert {int(s["step"]) for s in found["wire"]} == {1, 2, 3}
+
+
+# -- the eval's spans (rank 0, a dense model on a jax device) ----------------
+EVAL_PHASES = ("eval_pull", "eval_w_put", "eval_compute", "eval_d2h")
+
+
+def test_an_evals_phases_lie_in_its_eval_span_with_its_rank_and_round(data_dir):
+    """Lock step, an eval after every 2nd of 5 rounds: rank 0 alone opens
+    ``eval``, its four phases and (the first time) ``test_put`` are its
+    children, each with rank 0 and the round the eval follows; the pull's
+    exchange phases hang off ``eval_pull``; ``compute`` stays the gradient
+    step's, one a round."""
+    events = _events(_cfg(data_dir, sync_mode=True, num_iteration=5,
+                          test_interval=2))
+    ids = {e["args"]["id"]: e for e in events}
+    names = _by(events, lambda e: e["name"])
+    assert [(e["args"]["rank"], e["args"]["step"]) for e in names["eval"]] == [
+        (0, 2), (0, 4)]
+    for name in EVAL_PHASES:
+        got = names[name]
+        assert [(e["args"]["rank"], e["args"]["step"]) for e in got] == [
+            (0, 2), (0, 4)], name
+        for e, parent in zip(got, names["eval"]):
+            assert e["args"]["parent"] == parent["args"]["id"], name
+            assert e["tid"] == parent["tid"]
+    # in the order the reference's Test has them
+    for parent in names["eval"]:
+        inside = sorted((e["ts"], e["name"]) for e in events
+                        if e["args"].get("parent") == parent["args"]["id"])
+        assert [n for _ts, n in inside if n != "test_put"] == list(EVAL_PHASES)
+    (put,) = names["test_put"]
+    assert put["args"]["parent"] == names["eval"][0]["args"]["id"]
+    assert (put["args"]["rank"], put["args"]["step"]) == (0, 2)
+    pulls = {e["args"]["id"] for e in names["eval_pull"]}
+    for name in XCHG:
+        under = [e for e in names[name] if e["args"].get("parent") in pulls]
+        assert [(e["args"]["rank"], e["args"]["step"]) for e in under] == [
+            (0, 2), (0, 4)], name
+    # an eval is no step: the marker's spans are the rounds', one a round
+    computed = _by(names["compute"], lambda e: e["args"]["rank"])
+    for rank in range(WORKERS):
+        assert sorted(e["args"]["step"] for e in computed[rank]) == [
+            1, 2, 3, 4, 5]
+    assert all(ids[e["args"]["parent"]]["name"] != "eval_compute"
+               for e in events if "parent" in e["args"])
+
+
+def test_a_profiler_trace_holds_the_evals_phases_as_plain_annotations(
+        data_dir, tmp_path):
+    from jax.profiler import ProfileData
+
+    from chipbench import trace_reduce
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=options):
+        run_ps_local(_cfg(data_dir, sync_mode=True, num_iteration=4,
+                          test_interval=2), save=False)
+    found = collections.defaultdict(list)
+    path = trace_reduce.find_xplane(str(tmp_path))
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in ("eval", "test_put", *EVAL_PHASES):
+                    found[ev.name].append(dict(ev.stats))
+    for name in ("eval", *EVAL_PHASES):
+        assert [(int(s["rank"]), int(s["step"])) for s in found[name]] == [
+            (0, 2), (0, 4)], name
+        # a step marker would carry step_num: these group no device work
+        assert not any("step_num" in s for s in found[name])
+    assert len(found["test_put"]) == 1
