@@ -168,6 +168,13 @@ _GRAD_ROUNDS = get_registry().counter(
     "program reads the features out of HBM",
     labelnames=("rank", "path"),
 )
+_GRAD_DISPATCHES = get_registry().counter(
+    "distlr_ps_grad_dispatches_total",
+    "rounds of a PS worker's dense step on a jax device, by whether the "
+    "program was dispatched with the copy of its weights to the device "
+    "still in flight (the round's chain stood enqueued whole) or landed",
+    labelnames=("rank", "weights"),
+)
 _PANEL_HELD = get_registry().gauge(
     "distlr_ps_grad_panel_held",
     "share f of a row panel the one-pass step keeps in VMEM between its "
@@ -691,7 +698,11 @@ class PSWorker:
     round; ``softmax``; the CPU) keeps the device's default layout and
     ``model.grad`` under XLA.  ``distlr_ps_grad_rounds_total{rank, path}``
     counts the rounds of each, ``distlr_ps_grad_panel_held{rank}`` is the
-    share of a panel VMEM holds.
+    share of a panel VMEM holds.  A round's device chain (weights in,
+    the program, the gradient out) is enqueued whole and waited for once
+    (``_bind_dense_step``); ``distlr_ps_grad_dispatches_total{rank,
+    weights}`` counts the rounds whose program was dispatched with the
+    weights' copy still ``in_flight``, and those where it had ``landed``.
 
     ``run()`` is ``load_data()`` (iterators, the device choice, the
     placement; once), ``start()`` (seed push, start barrier), ``fit()``
@@ -704,9 +715,12 @@ class PSWorker:
     worker's round count (:attr:`rounds`) and ``rank``: ``load_data`` and
     ``shard_put`` once; a round: ``data_load`` (fetching the batch: the
     numpy slice, nothing for a resident shard), ``h2d`` (a streamed
-    batch's put, where the step's device is named), ``w_put`` (weights to
-    the device), ``compute`` (dispatch to the gradient ready on the
-    device), ``grad_d2h`` (readback), ``push`` (the loop blocked on its
+    batch's put, where the step's device is named), ``w_put`` (the
+    weights handed to the runtime for the device: staging and enqueue,
+    not the copy), ``compute`` (dispatch to the worker's own program
+    finished, the rest of the weights' copy before it included; the
+    readback is enqueued inside, behind the program), ``grad_d2h`` (the
+    rest of that readback), ``push`` (the loop blocked on its
     exchange), ``pull``; ``wire`` on the comm thread (a pipelined fused
     push-pull, send to reply, with the step that submitted it);
     ``barrier_wait``, ``eval``, ``checkpoint``; under ``eval`` (rank 0,
@@ -1010,6 +1024,18 @@ class PSWorker:
                 interpret=_jax_device(step_dev).platform != "tpu")
 
             def grad_step(wf, batch):
+                # The round's device chain is enqueued whole and waited
+                # for once: the weights' copy, the program behind it, the
+                # readback behind the program.  The runtime orders the
+                # three on the device; the host stands between no two.
+                # The fence on ``wf``: the readback cannot end before the
+                # program has run, nor the program start before the copy
+                # has left the host, so ``wf`` is free again when this
+                # returns and nobody may write it before.  The loops hand
+                # in what ``pull`` / ``push_pull`` returned, a fresh array
+                # every reply and never written in place; a client that
+                # keeps its reply buffer has to keep this fence.
+                #
                 # the plan goes with the resident batch alone: its X is
                 # held for it (``_place_shard``)
                 how = one_pass if batch is self._resident else {}
@@ -1017,16 +1043,24 @@ class PSWorker:
                     with self._span("h2d"):
                         batch = self._place(step_dev, *batch)
                 with self._span("w_put"):
-                    w = jax.block_until_ready(
-                        jax.device_put(self._shape_params(wf), step_dev))
+                    # the hand-over (staging, enqueue), not the copy
+                    w = jax.device_put(self._shape_params(wf), step_dev)
                 with self._span("compute", marks_step=True):
-                    g = jax.block_until_ready(
-                        self._grad_fn(w, *batch, **how))
+                    landed = w.is_ready()
+                    g = self._grad_fn(w, *batch, **how)
+                    g.copy_to_host_async()
+                    # the span ends with this worker's own program and
+                    # encloses no other's: the readers of ``compute`` and
+                    # of its step marker rest on that
+                    jax.block_until_ready(g)
                 _GRAD_ROUNDS.labels(
                     rank=rank, path="one_pass" if how else "two_pass").inc()
+                _GRAD_DISPATCHES.labels(
+                    rank=rank,
+                    weights="landed" if landed else "in_flight").inc()
                 with self._span("grad_d2h"):
-                    # one copy, device to host; the reshape is a view and
-                    # the client sends from this buffer
+                    # the rest of the copy already under way; the reshape
+                    # is a view and the client sends from this buffer
                     return np.asarray(g).reshape(-1)
         self.grad_step = grad_step
 

@@ -1,0 +1,233 @@
+"""A PS worker's round hands its device the chain whole (weights in, the
+gradient program, the gradient out) and waits once: the same gradient as
+the blocking form bit for bit, the same three spans in the same order, a
+counter of how the dispatch found the weights' copy, and the fence on the
+weights' host array that makes it safe."""
+
+import collections
+
+import jax
+import numpy as np
+import pytest
+
+from distlr_tpu.config import Config
+from distlr_tpu.data.synthetic import write_synthetic_shards
+from distlr_tpu.obs.registry import get_registry
+from distlr_tpu.obs.tracing import get_tracer
+from distlr_tpu.ps import ServerGroup
+from distlr_tpu.train.ps_trainer import PSWorker, ps_param_dim
+from test_ps_resident import _group, _in_threads  # a group for a job; a thread a worker
+
+DIM, CLASSES, WORKERS, ITERATIONS = 24, 3, 2, 3
+CHAIN = ("w_put", "compute", "grad_d2h")
+
+MODELS = ("binary_lr", "softmax")
+BATCHES = {"resident": -1, "streamed": 32}
+LOOPS = {"fused-bsp": dict(sync_mode=True),
+         "pipelined-async": dict(sync_mode=False)}
+CASES = [(m, b, l) for m in MODELS for b in BATCHES for l in LOOPS]
+ROUNDS, DISPATCHES = ("distlr_ps_grad_rounds_total",
+                      "distlr_ps_grad_dispatches_total")
+SERIES = {ROUNDS: ("path", ("one_pass", "two_pass")),
+          DISPATCHES: ("weights", ("in_flight", "landed"))}
+
+
+def _cfg(d, model, batch, loop, **kw):
+    classes = CLASSES if model == "softmax" else 2
+    write_synthetic_shards(d, 100 * WORKERS, DIM, num_parts=WORKERS, seed=11,
+                           sparsity=0.0, num_classes=classes)
+    base = dict(
+        data_dir=d, num_feature_dim=DIM, model=model, num_classes=classes,
+        num_workers=WORKERS, num_servers=2, batch_size=BATCHES[batch],
+        num_iteration=ITERATIONS, learning_rate=0.2, l2_c=0.0,
+        test_interval=0,
+        # the jitted step on the default backend: "auto" would take these
+        # tiny steps to numpy, which has no device and no chain
+        ps_compute_backend="default")
+    return Config(**{**base, **LOOPS[loop], **kw})
+
+
+class _Recorder:
+    """Round ``grad_step``: the very arrays the loop handed in (kept, so
+    that none is freed and its address used again), a copy of the weights'
+    bits and of the gradient that came back."""
+
+    def __init__(self, step):
+        self.step, self.seen = step, []
+
+    def __call__(self, wf, batch):
+        bits = np.array(wf)
+        g = self.step(wf, batch)
+        self.seen.append((wf, bits, batch, np.array(g)))
+        return g
+
+
+def _blocking(worker, wf, batch):
+    """The round's chain as it stood before: a wait after every link."""
+    if batch is not worker._resident:
+        batch = jax.block_until_ready(
+            tuple(jax.device_put(a) for a in batch))
+    w = jax.block_until_ready(jax.device_put(worker._shape_params(wf)))
+    g = jax.block_until_ready(worker._grad_fn(w, *batch))
+    return np.asarray(g).reshape(-1)
+
+
+def _counts():
+    """The two families' children, by family, rank and label value."""
+    out = collections.Counter()
+    for name, (label, values) in SERIES.items():
+        fam = get_registry().get(name)
+        for rank in range(WORKERS):
+            for value in values:
+                out[name, rank, value] = fam.labels(
+                    rank=str(rank), **{label: value}).value
+    return out
+
+
+def _run(tmp_path_factory, model, batch, loop, **kw):
+    d = str(tmp_path_factory.mktemp(f"chain-{model}-{batch}-{loop}"))
+    cfg = _cfg(d, model, batch, loop, **kw)
+    tracer = get_tracer()
+    with _group(cfg) as group:
+        workers = [PSWorker(cfg, r, group.hosts) for r in range(WORKERS)]
+        try:
+            for w in workers:
+                w.load_data()
+                assert (w._resident is not None) == (batch == "resident")
+                assert w._panels is None  # the CPU keeps the XLA step
+                w.grad_step = _Recorder(w.grad_step)
+            before = _counts()
+            tracer.reset()
+            _in_threads(workers, lambda w: w.run(save=False))
+            events = tracer.chrome_trace()["traceEvents"]
+            counted = _counts() - before
+            seen = {w.rank: w.grad_step.seen for w in workers}
+            want = {w.rank: [_blocking(w, bits, b)
+                             for _, bits, b, _ in w.grad_step.seen]
+                    for w in workers}
+            # the fence, from outside: the weights' array written over
+            # once the step has returned
+            w0 = workers[0]
+            wf, _, b, _ = seen[0][0]
+            wf = np.array(wf)
+            got = w0.grad_step.step(wf, b)
+            kept = np.array(got)
+            wf[:] = np.nan
+            return dict(
+                cfg=cfg, events=events, seen=seen, want=want,
+                rounds={w.rank: w.rounds for w in workers},
+                counted=counted,
+                overwritten=(got, kept, want[0][0]))
+        finally:
+            for w in workers:
+                w.close()
+
+
+@pytest.fixture(scope="module", params=CASES, ids="-".join)
+def run(request, tmp_path_factory):
+    return _run(tmp_path_factory, *request.param)
+
+
+def test_the_gradient_is_the_blocking_forms_bit_for_bit(run):
+    for rank, seen in run["seen"].items():
+        assert len(seen) == run["rounds"][rank] >= ITERATIONS
+        for (_, _, _, got), want in zip(seen, run["want"][rank]):
+            assert got.dtype == want.dtype == np.float32
+            assert got.shape == want.shape == (ps_param_dim(run["cfg"]),)
+            assert np.array_equal(got, want)
+            assert np.count_nonzero(got)
+
+
+def test_a_round_records_its_three_spans_once_each_in_that_order(run):
+    spans = collections.defaultdict(list)
+    for e in run["events"]:
+        if e["name"] in CHAIN:
+            spans[e["args"]["rank"], e["args"]["step"]].append(e)
+    for rank, rounds in run["rounds"].items():
+        for step in range(1, rounds + 1):
+            got = sorted(spans.pop((rank, step)), key=lambda e: e["ts"])
+            assert [e["name"] for e in got] == list(CHAIN), (rank, step)
+            assert len({e["tid"] for e in got}) == 1
+            for a, b in zip(got, got[1:]):
+                # one after the other on the loop's thread, none inside
+                # another (the tracer's clock is in whole microseconds)
+                assert a["ts"] + a["dur"] <= b["ts"] + 1
+    assert not spans  # and no span of the chain under any other step
+
+
+def test_every_round_counts_one_dispatch_beside_its_program(run):
+    for rank, rounds in run["rounds"].items():
+        programs, dispatches = (
+            sum(n for (name, r, _), n in run["counted"].items()
+                if name == family and r == rank)
+            for family in (ROUNDS, DISPATCHES))
+        # the fixture's one step more, on rank 0, is outside both reads
+        assert dispatches == programs == rounds
+
+
+def test_the_weights_array_written_over_afterwards_changes_nothing(run):
+    got, kept, want = run["overwritten"]
+    assert np.array_equal(got, kept)
+    assert np.array_equal(got, want)
+    assert np.isfinite(got).all()
+
+
+def test_no_reply_lands_in_an_array_a_round_was_handed(run):
+    """The fence the step's comment states: every reply of ``push_pull``
+    (and the first ``pull``) is a fresh array and no loop writes one in
+    place, so nothing writes the weights a chain in flight may still be
+    reading.  (The pipelined loop computes an epoch's first two rounds on
+    one array, with no exchange in flight before the first: the same
+    object, still never written.)"""
+    fused = run["cfg"].sync_mode
+    for seen in run["seen"].values():
+        for wf, bits, *_ in seen:
+            assert np.array_equal(wf, bits)  # as it was when handed in
+        arrays = [wf for wf, *_ in seen]
+        for a, b in zip(arrays, arrays[1:]):
+            if a is b:
+                assert not fused
+            else:
+                assert not np.shares_memory(a, b)
+        if run["cfg"].batch_size > 0 or not fused:
+            continue
+        # whole-shard lock-step rounds: a round an exchange, so the
+        # weights moved
+        for (_, a, *_), (_, b, *_) in zip(seen, seen[1:]):
+            assert not np.array_equal(a, b)
+
+
+@pytest.mark.parametrize("model", MODELS)
+def test_the_serialized_loops_pull_is_a_fresh_array_too(tmp_path_factory,
+                                                        model):
+    got = _run(tmp_path_factory, model, "resident", "pipelined-async",
+               ps_pipeline=False)
+    names = {e["name"] for e in got["events"]}
+    assert "pull" in names and "wire" not in names
+    for rank, seen in got["seen"].items():
+        assert len(seen) == ITERATIONS
+        arrays = [wf for wf, *_ in seen]
+        for a, b in zip(arrays, arrays[1:]):
+            assert a is not b and not np.shares_memory(a, b)
+        for (_, _, _, g), want in zip(seen, got["want"][rank]):
+            assert np.array_equal(g, want)
+
+
+@pytest.mark.parametrize("op", ["pull", "push_pull"])
+def test_the_client_returns_a_new_array_every_call(op):
+    from distlr_tpu.ps import KVWorker
+
+    with ServerGroup(2, 1, DIM, learning_rate=0.1, sync=False) as group:
+        kv = KVWorker(group.hosts, DIM)
+        try:
+            kv.wait(kv.push_init(np.ones(DIM, np.float32)))
+            g = np.full(DIM, 0.5, np.float32)
+            call = kv.pull if op == "pull" else (lambda: kv.push_pull(g))
+            first = call()
+            bits = np.array(first)
+            second = call()
+            assert first is not second
+            assert not np.shares_memory(first, second)
+            assert np.array_equal(first, bits)  # the later reply left it be
+        finally:
+            kv.close()
