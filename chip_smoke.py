@@ -268,7 +268,7 @@ class Smoke:
         import numpy as np
 
         from distlr_tpu import Config
-        from distlr_tpu.obs.registry import get_registry
+        from distlr_tpu.obs.registry import family_total, get_registry
         from distlr_tpu.obs.tracing import get_tracer
         from distlr_tpu.train import ps_trainer
         from distlr_tpu.utils.logging import log_eval_line
@@ -299,7 +299,11 @@ class Smoke:
             log_eval_line(epoch, acc)
 
         tracer = get_tracer()
-        wire_before = tracer.breakdown().get("wire", {"count": 0})["count"]
+        def count(span: str) -> int:
+            return tracer.breakdown().get(span, {"count": 0})["count"]
+
+        spans_before = {s: count(s) for s in ("wire", "shard_put", "h2d")}
+        windows_before = family_total("distlr_ps_window_rounds_total")
         before = acked_pushes()
         try:
             weights = ps_trainer.run_ps_local(cfg, eval_fn=on_eval, save=True)
@@ -324,11 +328,22 @@ class Smoke:
         # every acknowledged dense push ticks the group's push clock by one
         _check(pushes >= cfg.num_workers * steps_per_worker,
                f"push clock advanced by {pushes}")
-        # minibatch workers stream: a pipelined exchange a step on the
-        # comm thread, and no shard placed ahead
-        _check(spans["wire"]["count"] - wire_before
-               == cfg.num_workers * steps_per_worker, f"wire spans {spans['wire']}")
-        _check("shard_put" not in spans, "a minibatch worker placed its shard")
+        # a pipelined exchange a step on the comm thread; on the chip
+        # minibatch workers keep their shard on the device and read a
+        # window of it a round: one placement a worker, no batch streamed
+        # (the rehearsal's tiny steps are numpy's, which places nothing)
+        rounds = cfg.num_workers * steps_per_worker
+        _check(count("wire") - spans_before["wire"] == rounds,
+               f"wire spans {spans['wire']}")
+        resident = self.on_tpu
+        _check(count("shard_put") - spans_before["shard_put"]
+               == resident * cfg.num_workers,
+               "a minibatch worker places its shard once")
+        _check(count("h2d") == spans_before["h2d"],
+               "a minibatch worker streamed a batch")
+        _check(family_total("distlr_ps_window_rounds_total") - windows_before
+               == resident * rounds,
+               "every round read a window of the resident shard")
         w = np.asarray(weights[0])
         _check(w.shape == (self.s.d,) and np.isfinite(w).all(), "pulled weights finite")
         _check(np.count_nonzero(w) > 0, "pulled weights non-zero")

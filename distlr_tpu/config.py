@@ -66,7 +66,13 @@ class Config:
     data_dir: str = "./a9a-data"      # DATA_DIR (train/ test/ models/ subdirs)
     num_feature_dim: int = 123        # NUM_FEATURE_DIM (D)
     num_iteration: int = 100          # NUM_ITERATION (outer epochs)
-    batch_size: int = -1              # BATCH_SIZE (-1 = full shard)
+    # BATCH_SIZE (-1 = full shard).  A dense PS worker on a jax device
+    # keeps its shard resident either way: with B > 0 a round's batch is
+    # the window [k B, k B + B) of it in file order (a short last batch
+    # padded and masked); a shuffled or wrap_final_batch iterator, a
+    # shard the device has no room for and keyed models stream a batch a
+    # step from the host.
+    batch_size: int = -1
     test_interval: int = 10           # TEST_INTERVAL (eval every k epochs)
     random_seed: int = 10             # RANDOM_SEED (unused by ref — Q2)
     l2_c: float = 1.0                 # L2 coefficient C (hardcoded 1 in ref lr.h:10)

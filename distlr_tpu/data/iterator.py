@@ -19,7 +19,20 @@ one pass (epoch) over its shard; ``batch_size=-1`` means the whole shard
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import numpy as np
+
+
+class Window(NamedTuple):
+    """A batch as rows ``[first, first + batch_size)`` of the shard in
+    the order the iterator holds it, of which the leading ``rows`` are
+    real: fewer than ``batch_size`` in a shard's last, short batch, whose
+    window runs past the shard's end (whoever holds the rows keeps
+    masked pad rows there)."""
+
+    first: int
+    rows: int
 
 
 class DataIter:
@@ -97,10 +110,28 @@ class DataIter:
         """``(X, y, mask)`` of the one batch an epoch has, as the arrays
         this iterator holds and no copy of them; None where a batch is
         anything but the whole shard in the order it is held."""
+        return self.held_rows() if self.batch_size == self.num_samples else None
+
+    def held_rows(self):
+        """``(X, y, mask)`` of every row, as the arrays this iterator
+        holds and no copy of them, where each batch of an epoch is a
+        :class:`Window` of them (:meth:`next_window`): the rows served in
+        the order they are held: no shuffle, and no short last batch for
+        Q5 to wrap.  None otherwise."""
         n = self.num_samples
-        if self.batch_size != n or not np.array_equal(self._order, np.arange(n)):
+        if ((self.wrap_compat and n % self.batch_size)
+                or not np.array_equal(self._order, np.arange(n))):
             return None
         return self.X, self.y, np.ones(n, dtype=bool)
+
+    def next_window(self) -> Window:
+        """:meth:`next_batch` for whoever keeps :meth:`held_rows`: where
+        the batch lies, and no rows gathered."""
+        if not self.has_next():
+            raise StopIteration
+        first = self._offset
+        self._offset += self.batch_size
+        return Window(first, min(self.batch_size, self.num_samples - first))
 
     def __iter__(self):
         while self.has_next():

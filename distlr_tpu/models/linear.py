@@ -227,20 +227,30 @@ class BinaryLR:
             g = g * self.feature_scale
         return g + _l2_grad(w, cfg, n)
 
-    def grad_panels(self, w, batch, cfg: Config, plan, *, interpret=False):
+    def grad_panels(self, w, batch, cfg: Config, plan, *, first=None,
+                    interpret=False):
         """:meth:`grad` from one HBM read of the features, for a batch
         whose ``X`` is held as ``ops.pallas_lr.pad_columns(X, plan)``:
         the row-panel kernel gives ``X^T r`` in float32 whatever
         ``compute_dtype`` says (XLA's two fusions agree with float32 to
         1.5e-7 on the chip as well: PERF.md section 2); the mean, the L2
-        term and ``feature_scale`` are this method's, as in ``grad``."""
+        term and ``feature_scale`` are this method's, as in ``grad``.
+
+        ``first`` (an int32 scalar, traced) makes the batch the window
+        ``[first, first + plan.rows)`` of taller resident arrays: ``y``
+        and ``mask`` are sliced here, the kernel reads the features'
+        window where it lies."""
         from distlr_tpu.ops.pallas_lr import lr_grad_panels  # noqa: PLC0415
 
         Xp, y, mask = batch
+        if first is not None:
+            y, mask = (jax.lax.dynamic_slice(a, (first,), (plan.rows,))
+                       for a in (y, mask))
         n = jnp.maximum(jnp.sum(mask), 1).astype(jnp.float32)
         scaled = self.feature_scale != 1.0
         g = lr_grad_panels(w * self.feature_scale if scaled else w,
-                           Xp, y, mask, plan, interpret=interpret) / n
+                           Xp, y, mask, plan, first=first,
+                           interpret=interpret) / n
         if scaled:
             g = g * self.feature_scale
         return g + _l2_grad(w, cfg, n)

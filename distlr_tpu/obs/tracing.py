@@ -61,7 +61,8 @@ class PhaseTracer:
         self._registry = registry or get_registry()
         self._max_events = max_events
         self._lock = threading.Lock()
-        # (name, tid, start, duration, span id, parent id, step, rank)
+        # (name, tid, start, duration, span id, parent id, step, rank,
+        #  further stats or None)
         self._events: list[tuple] = []
         self._dropped = 0
         self._totals: dict[str, list] = {}  # phase -> [seconds, count, self]
@@ -81,7 +82,7 @@ class PhaseTracer:
 
     @contextlib.contextmanager
     def phase(self, name: str, step: int | None = None,
-              rank: int | None = None):
+              rank: int | None = None, **stats):
         try:
             stack = self._open.stack
         except AttributeError:
@@ -99,7 +100,7 @@ class PhaseTracer:
             if stack:
                 stack[-1][1] += dur
             self._keep(name, t0, dur, max(dur - frame[1], 0.0), frame[0],
-                       parent, step, rank)
+                       parent, step, rank, stats or None)
 
     def completed(self, name: str, start: float, duration: float) -> None:
         """Record a span that has already ended: ``start`` and
@@ -122,7 +123,8 @@ class PhaseTracer:
         self._keep(name, start, duration, duration, next(self._ids), parent,
                    step, rank)
 
-    def _keep(self, name, t0, dur, own, span_id, parent, step, rank) -> None:
+    def _keep(self, name, t0, dur, own, span_id, parent, step, rank,
+              stats=None) -> None:
         series = self._series.get(name)
         if series is None:
             series = self._series[name] = self._hist.labels(phase=name)
@@ -139,7 +141,7 @@ class PhaseTracer:
             if len(self._events) < self._max_events:
                 self._events.append(
                     (name, tid, t0 - self._epoch, dur, span_id, parent,
-                     step, rank))
+                     step, rank, stats))
             else:
                 self._dropped += 1
 
@@ -170,14 +172,16 @@ class PhaseTracer:
         """Trace-event JSON object (``ph: "X"`` complete events, us
         timestamps) — loadable in Perfetto / chrome://tracing.  Each
         event's ``args`` hold its span ``id`` and, where it has them, its
-        ``parent`` span's id, its ``step`` and its ``rank``."""
+        ``parent`` span's id, its ``step``, its ``rank`` and whatever
+        further stats it was opened with (a ``push`` span that is an
+        epoch's drain: ``drain``)."""
         pid = os.getpid()
         with self._lock:
             recorded = list(self._events)
             dropped = self._dropped
         events = []
-        for name, tid, t0, dur, span_id, parent, step, rank in recorded:
-            args = {"id": span_id}
+        for name, tid, t0, dur, span_id, parent, step, rank, stats in recorded:
+            args = {"id": span_id, **(stats or {})}
             if parent is not None:
                 args["parent"] = parent
             if step is not None:
@@ -221,31 +225,34 @@ def get_tracer() -> PhaseTracer:
 
 
 def trace_phase(name: str, step: int | None = None,
-                rank: int | None = None):
+                rank: int | None = None, **stats):
     """``with trace_phase("compute", step=n): ...`` on the default tracer."""
-    return _TRACER.phase(name, step, rank)
+    return _TRACER.phase(name, step, rank, **stats)
 
 
 @contextlib.contextmanager
 def loop_span(name: str, step: int | None = None, *,
-              rank: int | None = None, marks_step: bool = False):
+              rank: int | None = None, marks_step: bool = False, **more):
     """One span of a training loop, on both records: the process's
     ``PhaseTracer`` and, while a ``jax.profiler`` trace is being taken
     (``cfg.profile_dir``, or a caller's own ``jax.profiler.trace``), the
     host lines of the same ``.xplane.pb`` as the device operations, so
     that the two share a clock.  An annotation records nothing while no
     trace is open.  ``marks_step`` makes it the step marker the
-    profiler's tools group device work by.  JAX is imported here, by the
-    loops that have it already, and not with this module."""
+    profiler's tools group device work by.  ``more`` are further stats
+    of the span, on both records beside ``step`` and ``rank``
+    (``drain=1``: a ``push`` that is an epoch's last, with no round's
+    compute left to hide it).  JAX is imported here, by the loops that
+    have it already, and not with this module."""
     import jax  # noqa: PLC0415
 
     stats = {k: v for k, v in (("step", step), ("rank", rank))
-             if v is not None}
+             if v is not None} | more
     if marks_step:
         stats.pop("step", None)
         annotation = jax.profiler.StepTraceAnnotation(
             name, step_num=step, **stats)
     else:
         annotation = jax.profiler.TraceAnnotation(name, **stats)
-    with trace_phase(name, step, rank), annotation:
+    with trace_phase(name, step, rank, **more), annotation:
         yield

@@ -39,6 +39,15 @@ passes):
 The eight partials are summed, cut to ``D``, and turned into the mean
 gradient with its L2 term by plain ``jnp`` around the call
 (:meth:`BinaryLR.grad_panels`), in the caller's jitted function.
+
+A **window**.  The matrix may be taller than the plan: a minibatch
+worker keeps its whole shard resident and a round's batch is
+``plan.rows`` rows of it from a first row that changes every round
+(``PSWorker``).  That first row is a scalar operand in SMEM, added to a
+panel's row where its fetch is addressed, so one executable serves every
+window and the window is read where it lies: a ``dynamic_slice`` in
+front of the call would write the window out and read it again, twice
+the step's bytes.
 """
 
 from __future__ import annotations
@@ -129,12 +138,15 @@ def panel_plan(rows: int, dim: int, *, vmem_limit: int = VMEM_LIMIT_BYTES,
     return dataclasses.replace(plan, held=slots - _RING)
 
 
-def pad_columns(X, plan: PanelPlan):
+def pad_columns(X, plan: PanelPlan, rows: int | None = None):
     """``X`` as the kernel reads it: ``float32[rows, dim_padded]``, the
     pad columns zero.  On a TPU this is the relayout to row-major
-    (module docstring); call it once for a matrix that stays."""
+    (module docstring); call it once for a matrix that stays.  ``rows``
+    (no fewer than ``X`` has) adds zero rows below: a shard whose last
+    window would otherwise run past its end."""
+    below = 0 if rows is None else rows - X.shape[0]
     return jnp.pad(X.astype(jnp.float32),
-                   ((0, 0), (0, plan.dim_padded - plan.dim)))
+                   ((0, below), (0, plan.dim_padded - plan.dim)))
 
 
 def lr_logits_rows(w, Xp, plan: PanelPlan):
@@ -154,13 +166,18 @@ def lr_logits_rows(w, Xp, plan: PanelPlan):
     return jnp.sum(Xp * wp[None, :], axis=1)
 
 
-def _kernel(plan: PanelPlan, x_hbm, w_ref, y_ref, mask_ref, g_ref, buf, sems):
-    """``x_hbm``: ``f32[rows, dim_padded]`` in HBM; ``w_ref``:
-    ``f32[chunks, weight_rows, 128]``, chunk ``k``'s tiles one a row;
-    ``y_ref``, ``mask_ref``: ``f32[rows, 1]``; ``g_ref``: ``f32[8,
-    dim_padded]``, the sublane partials; ``buf``: ``f32[slots, 8,
-    chunk_cols]``; ``sems``: a DMA semaphore a slot."""
+def _kernel(plan: PanelPlan, first_ref, x_hbm, w_ref, y_ref, mask_ref, g_ref,
+            buf, sems):
+    """``first_ref``: ``i32[1]`` in SMEM, the window's first row of
+    ``x_hbm`` (a multiple of eight), or None where the matrix is read
+    from row 0; ``x_hbm``: ``f32[R, dim_padded]`` in HBM, ``R`` no fewer
+    than ``rows``; ``w_ref``: ``f32[chunks, weight_rows, 128]``, chunk
+    ``k``'s tiles one a row; ``y_ref``, ``mask_ref``: ``f32[rows, 1]``,
+    the window's; ``g_ref``: ``f32[8, dim_padded]``, the sublane
+    partials; ``buf``: ``f32[slots, 8, chunk_cols]``; ``sems``: a DMA
+    semaphore a slot."""
     panels = plan.rows // _SUBLANES
+    first = 0 if first_ref is None else first_ref[0]
     tiles, cols = plan.chunk_tiles, plan.chunk_cols
     held, streamed = plan.held, plan.chunks - plan.held
     ring_fetches = 2 * streamed * panels
@@ -175,8 +192,8 @@ def _kernel(plan: PanelPlan, x_hbm, w_ref, y_ref, mask_ref, g_ref, buf, sems):
 
     def copy(panel, chunk, slot):
         return pltpu.make_async_copy(
-            x_hbm.at[pl.ds(pl.multiple_of(panel * _SUBLANES, _SUBLANES),
-                           _SUBLANES),
+            x_hbm.at[pl.ds(pl.multiple_of(first + panel * _SUBLANES,
+                                          _SUBLANES), _SUBLANES),
                      pl.ds(pl.multiple_of(chunk * cols, _LANES), cols)],
             buf.at[slot], sems.at[slot])
 
@@ -274,13 +291,25 @@ def _kernel(plan: PanelPlan, x_hbm, w_ref, y_ref, mask_ref, g_ref, buf, sems):
     loop(panels, panel)
 
 
-def lr_grad_panels(w, Xp, y, mask, plan: PanelPlan, *, interpret: bool = False):
+def lr_grad_panels(w, Xp, y, mask, plan: PanelPlan, *, first=None,
+                   interpret: bool = False):
     """``X^T ((sigmoid(X w) - y) * mask)``, ``float32[dim]``, from
-    ``Xp = pad_columns(X, plan)``: the unnormalised logistic gradient."""
-    if Xp.shape != (plan.rows, plan.dim_padded) or Xp.dtype != jnp.float32:
+    ``Xp = pad_columns(X, plan)``: the unnormalised logistic gradient.
+
+    With ``first`` (an int32 scalar, traced or not; a multiple of eight)
+    the rows are the window ``[first, first + plan.rows)`` of a taller
+    ``Xp``, read where it lies; ``y`` and ``mask`` are the window's own
+    ``plan.rows`` values.  A window that would run past the last row
+    starts where it still fits, as ``lax.dynamic_slice`` has it."""
+    whole = (plan.rows, plan.dim_padded)
+    fits = (Xp.shape == whole if first is None
+            else Xp.shape[1] == plan.dim_padded and Xp.shape[0] >= plan.rows
+            and Xp.shape[0] % _SUBLANES == 0)
+    if not fits or Xp.dtype != jnp.float32:
         raise ValueError(
-            f"the kernel reads float32{[plan.rows, plan.dim_padded]} "
-            f"(pad_columns), not {Xp.dtype}{list(Xp.shape)}")
+            f"the kernel reads float32{list(whole)} (pad_columns), or a "
+            "window of whole groups of such rows from a first row, not "
+            f"{Xp.dtype}{list(Xp.shape)}")
     # chunk k's tiles one a row, each chunk's rows padded to whole groups:
     # a group of eight tiles' weights is one aligned (8, 128) load
     w3 = jnp.pad(
@@ -289,9 +318,18 @@ def lr_grad_panels(w, Xp, y, mask, plan: PanelPlan, *, interpret: bool = False):
         ((0, 0), (0, plan.weight_rows - plan.chunk_tiles), (0, 0)))
     column = lambda v: v.astype(jnp.float32).reshape(plan.rows, 1)  # noqa: E731
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    operands = [Xp, w3, column(y), column(mask)]
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY), vmem, vmem, vmem]
+    if first is None:
+        kernel = functools.partial(_kernel, plan, None)
+    else:
+        kernel = functools.partial(_kernel, plan)
+        operands.insert(0, jnp.clip(jnp.asarray(first, jnp.int32),
+                                    0, Xp.shape[0] - plan.rows).reshape(1))
+        in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
     partials = pl.pallas_call(
-        functools.partial(_kernel, plan),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY), vmem, vmem, vmem],
+        kernel,
+        in_specs=in_specs,
         out_specs=vmem,
         out_shape=jax.ShapeDtypeStruct((_SUBLANES, plan.dim_padded),
                                        jnp.float32),
@@ -303,5 +341,5 @@ def lr_grad_panels(w, Xp, y, mask, plan: PanelPlan, *, interpret: bool = False):
             vmem_limit_bytes=plan.vmem_limit),
         name="lr_grad_panels",
         interpret=interpret,
-    )(Xp, w3, column(y), column(mask))
+    )(*operands)
     return jnp.sum(partials, axis=0)[:plan.dim]

@@ -38,7 +38,7 @@ import numpy as np
 from distlr_tpu.compress import GradientAccumulator
 from distlr_tpu.config import Config
 from distlr_tpu.data import DataIter
-from distlr_tpu.data.iterator import SparseDataIter
+from distlr_tpu.data.iterator import SparseDataIter, Window
 from distlr_tpu.data.sharding import part_name
 from distlr_tpu.models import get_model
 from distlr_tpu.models.linear import BinaryLR
@@ -108,12 +108,26 @@ _ACCUM_K = get_registry().gauge(
 )
 
 
-#: What a whole-shard dense worker keeps on its step's device (features,
-#: labels, mask), placed once by :meth:`PSWorker.load_data`; a streaming
-#: worker's series stays absent.
+#: What a dense worker keeps on its step's device (its shard's features,
+#: labels, mask: the batch of a whole-shard worker, the rows a minibatch
+#: worker's windows lie in), placed once by :meth:`PSWorker.load_data`; a
+#: streaming worker's series stays absent.
 _RESIDENT_BYTES = get_registry().gauge(
     "distlr_ps_resident_bytes",
-    "bytes of a PS worker's whole-shard batch held on its step's device",
+    "bytes of a PS worker's shard held on its step's device",
+    labelnames=("rank",),
+)
+#: Rounds whose batch was a window of a resident shard, and the real rows
+#: those rounds read (a shard's last, short window counts its real rows).
+_WINDOW_ROUNDS = get_registry().counter(
+    "distlr_ps_window_rounds_total",
+    "rounds of a PS worker's dense step whose batch was a window of its "
+    "resident shard",
+    labelnames=("rank",),
+)
+_WINDOW_ROWS = get_registry().counter(
+    "distlr_ps_window_rows_total",
+    "real rows the windowed rounds of a PS worker's dense step read",
     labelnames=("rank",),
 )
 
@@ -139,11 +153,12 @@ _EVAL_ROWS = get_registry().counter(
     "test rows a PS worker's evals covered: the whole split an eval",
     labelnames=("rank",),
 )
-#: A test split is kept on the eval's device where the device says it
-#: has this many times the split's bytes free: at the peak of placement
-#: the bytes as handed over, their restored form and the row-major
-#: relayout stand together (``feed.place``, ``_row_major_program``).
-_TEST_PLACE_HEADROOM = 3
+#: A test split, or a shard read in windows, is kept on the device where
+#: the device says it has this many times its bytes free: at the peak of
+#: placement the bytes as handed over, their restored form and the
+#: row-major relayout stand together (``feed.place``,
+#: ``_row_major_program``).
+_PLACE_HEADROOM = 3
 
 
 #: Which device of its process a dense worker's step is pinned to (the
@@ -407,13 +422,23 @@ def _compiled_fns(model, l2_c: float, l2_scale_by_batch: bool):
     # which no other jitted function of the process shares.  ``panels``
     # (static) is the plan of a resident row-major ``X``: the one-pass
     # program of ``_one_pass_plan``, in this same jitted function.
-    def ps_grad_step(w, X, y, mask, panels=None, interpret=False):
-        if panels is None:
-            return model.grad(w, (X, y, mask), gcfg)
-        return model.grad_panels(w, (X, y, mask), gcfg, panels,
-                                 interpret=interpret)
+    # ``first`` (traced: one executable for every value) makes the batch
+    # a window of the resident arrays from that row on: ``panels.rows``
+    # rows read in place by the kernel or, with no plan, ``window``
+    # (static) rows sliced out for ``model.grad``.
+    def ps_grad_step(w, X, y, mask, first=None, panels=None, interpret=False,
+                     window=None):
+        if panels is not None:
+            return model.grad_panels(w, (X, y, mask), gcfg, panels,
+                                     first=first, interpret=interpret)
+        if first is not None:
+            X, y, mask = (
+                jax.lax.dynamic_slice_in_dim(a, first, window)
+                for a in (X, y, mask))
+        return model.grad(w, (X, y, mask), gcfg)
 
-    return jax.jit(ps_grad_step, static_argnames=("panels", "interpret"))
+    return jax.jit(ps_grad_step,
+                   static_argnames=("panels", "interpret", "window"))
 
 
 #: platforms on which a resident shard's step is the one-pass program
@@ -437,14 +462,18 @@ def _one_pass_plan(model, rows: int, dim: int, device):
 
 
 @functools.lru_cache(maxsize=None)
-def _row_major_program(plan):
+def _row_major_program(plan, rows=None):
     """The jitted relayout of a placed shard's features to what the
-    one-pass step reads (``ops.pallas_lr.pad_columns``).  Its name
-    carries no ``step``: the benchmark finds the step's runs by that."""
+    one-pass step reads (``ops.pallas_lr.pad_columns``), with zero rows
+    below up to ``rows`` where the shard's last window is short; with no
+    plan, those rows alone.  Its name carries no ``step``: the benchmark
+    finds the step's runs by that."""
     from distlr_tpu.ops.pallas_lr import pad_columns  # noqa: PLC0415
 
     def ps_shard_row_major(X):
-        return pad_columns(X, plan)
+        if plan is None:
+            return jax.numpy.pad(X, ((0, rows - X.shape[0]), (0, 0)))
+        return pad_columns(X, plan, rows)
 
     return jax.jit(ps_shard_row_major)
 
@@ -654,16 +683,29 @@ class PSWorker:
     each batch pulls and pushes only its unique touched columns, so a
     D=1M-bucket CTR model ships KBs per step instead of 12 MB.
 
-    Where the data lives.  A dense worker whose batch is its whole shard
-    (the reference's ``BATCH_SIZE=-1``: the same rows every iteration)
-    and whose step runs on a jax device keeps that batch **resident**:
-    :meth:`load_data` places ``X``, ``y`` and ``mask`` on the step's
-    device once (``shard_put``, through ``parallel.feed.place``) and
-    every round computes on those arrays; a round then moves only the
-    weights in and the gradient out.  It is chosen from what the worker
-    sees (one batch an epoch that is the shard; a step device other than
-    ``"numpy"``).  Minibatch workers, and every keyed model, stream numpy
-    batches from host RAM, one ``device_put`` a step.
+    Where the data lives.  A dense worker whose step runs on a jax device
+    keeps its shard **resident** wherever its iterator serves the shard's
+    rows in the order it holds them (``DataIter.held_rows``: no shuffle,
+    no Q5 wrap), whatever ``batch_size`` is: :meth:`load_data` places
+    ``X``, ``y`` and ``mask`` on the step's device once (``shard_put``,
+    through ``parallel.feed.place``) and every round computes on those
+    arrays; a round then moves only the weights in and the gradient out.
+    With the reference's ``BATCH_SIZE=-1`` the batch is all of them, the
+    same rows every iteration.  With ``batch_size`` B a round's batch is
+    the **window** ``[k B, k B + B)`` of them (``iterator.Window``), its
+    first row an operand of the one compiled step; a shard that B does
+    not divide gets masked zero rows below it on the device, so that the
+    last, short batch is a window like the others (padded and masked, as
+    ``DataIter`` has it; not upstream's Q5 wrap).  It is chosen from what
+    the worker sees (the iterator; a step device other than ``"numpy"``;
+    for a minibatch worker, that the device says it has room:
+    ``_PLACE_HEADROOM`` times the shard's bytes free, no option).
+    What still streams numpy batches from host RAM, one ``device_put`` a
+    step: a shuffled or ``wrap_compat`` iterator, a shard the device has
+    no room for, and every keyed model.
+    ``distlr_ps_resident_bytes{rank}`` is the shard as held;
+    ``distlr_ps_window_rounds_total`` and ``distlr_ps_window_rows_total``
+    count the windowed rounds and the real rows they read.
 
     Which device.  ``ps_compute_device`` decides host or accelerator
     from the step's size; which accelerator device is the job's to say
@@ -687,16 +729,22 @@ class PSWorker:
     ``distlr_ps_evals_total`` and ``distlr_ps_eval_rows_total`` count.
 
     How a resident shard is held, and what reads it.  Where the model is
-    a ``BinaryLR`` without ``int8_dot``, the device a TPU, the rows whole
-    groups of eight and VMEM holds at least a part of a row panel
-    (``_one_pass_plan``: no option), ``X`` is relaid once, inside
-    ``shard_put``, to ``float32[rows, Dp]`` with the columns in the lanes
-    and zero pad columns, and ``jit_ps_grad_step`` is the row-panel
-    kernel of ``ops/pallas_lr.py``: the gradient from ONE read of ``X``
-    out of HBM (XLA's forward and backward fusions each stream it).
-    Everything else (streamed batches, which would pay the relayout every
-    round; ``softmax``; the CPU) keeps the device's default layout and
-    ``model.grad`` under XLA.  ``distlr_ps_grad_rounds_total{rank, path}``
+    a ``BinaryLR`` without ``int8_dot``, the device a TPU, the rows a
+    step reads (the shard's, or a window's B) whole groups of eight and
+    VMEM holds at least a part of a row panel (``_one_pass_plan``: no
+    option), ``X`` is relaid once, inside ``shard_put``, to
+    ``float32[rows, Dp]`` with the columns in the lanes and zero pad
+    columns, and ``jit_ps_grad_step`` is the row-panel kernel of
+    ``ops/pallas_lr.py``: the gradient from ONE read of ``X`` out of HBM
+    (XLA's forward and backward fusions each stream it).  A window is
+    read where it lies: its first row goes to the kernel as a scalar,
+    which adds it to a panel's row (no ``dynamic_slice`` of ``X``, which
+    would write the window out and read it again).  Everything else
+    (streamed batches, which would pay the relayout every round;
+    ``softmax``; a B that is no multiple of eight; the CPU) keeps the
+    device's default layout and ``model.grad`` under XLA, over a
+    ``dynamic_slice`` of the resident rows where the batch is a window.
+    ``distlr_ps_grad_rounds_total{rank, path}``
     counts the rounds of each, ``distlr_ps_grad_panel_held{rank}`` is the
     share of a panel VMEM holds.  A round's device chain (weights in,
     the program, the gradient out) is enqueued whole and waited for once
@@ -714,14 +762,17 @@ class PSWorker:
     profiler trace is taken, a ``TraceAnnotation``) carry ``step`` = the
     worker's round count (:attr:`rounds`) and ``rank``: ``load_data`` and
     ``shard_put`` once; a round: ``data_load`` (fetching the batch: the
-    numpy slice, nothing for a resident shard), ``h2d`` (a streamed
-    batch's put, where the step's device is named), ``w_put`` (the
+    numpy slice, nothing for a resident shard or a window of one),
+    ``h2d`` (a streamed batch's put, where the step's device is named:
+    none opens in a resident or windowed round), ``w_put`` (the
     weights handed to the runtime for the device: staging and enqueue,
     not the copy), ``compute`` (dispatch to the worker's own program
     finished, the rest of the weights' copy before it included; the
     readback is enqueued inside, behind the program), ``grad_d2h`` (the
     rest of that readback), ``push`` (the loop blocked on its
-    exchange), ``pull``; ``wire`` on the comm thread (a pipelined fused
+    exchange; in the pipelined async loop the one that ends an epoch,
+    with no round's compute left to hide it, carries ``drain=1`` beside
+    ``step`` and ``rank``), ``pull``; ``wire`` on the comm thread (a pipelined fused
     push-pull, send to reply, with the step that submitted it);
     ``barrier_wait``, ``eval``, ``checkpoint``; under ``eval`` (rank 0,
     a dense model): ``eval_pull`` (the weights after the round, pulled as
@@ -750,8 +801,8 @@ class PSWorker:
         self.model = get_model(cfg)
         if cfg.feature_dtype != "float32":
             # PS workers stream float32 numpy batches from host RAM per
-            # step, and a whole-shard worker's resident batch is that
-            # same float32 array placed once: no quantized path exists
+            # step, and a resident shard is that same float32 array
+            # placed once: no quantized path exists
             # on this plane. Reject rather than silently ignore the
             # documented +11%/2x expectation.
             raise ValueError(
@@ -830,6 +881,8 @@ class PSWorker:
         self._eval_dev = None
         self._resident = None  # (X, y, mask) on the step's device
         self._resident_rows = 0
+        #: a round's batch is a window of the resident rows, not all of them
+        self._windowed = False
         #: rank 0's test split on the eval's device, the plan its X is held
         #: for (or None) and its count of rows: bound by the first eval
         #: that keeps it
@@ -961,15 +1014,15 @@ class PSWorker:
         return DataIter.from_file(path, self.cfg.num_feature_dim, -1,
                                   multiclass=self.cfg.model == "softmax")
 
-    def _span(self, name: str, *, marks_step: bool = False):
+    def _span(self, name: str, *, marks_step: bool = False, **more):
         """A span of this worker's loop: its round count and its rank."""
         return loop_span(name, self.rounds, rank=self.rank,
-                         marks_step=marks_step)
+                         marks_step=marks_step, **more)
 
     def load_data(self) -> None:
         """Once a worker: bind the iterators (parsing the shards where
         none were handed in), pick the device of the dense step and of
-        the eval, and place a whole-shard batch there (class docstring).
+        the eval, and place the shard there (class docstring).
         A second call does nothing."""
         if self._train is not None:
             return
@@ -1022,6 +1075,11 @@ class PSWorker:
             one_pass = {} if plan is None else dict(
                 panels=plan,
                 interpret=_jax_device(step_dev).platform != "tpu")
+            # a window of the resident rows: the plan's rows where the
+            # kernel reads it in place, else sliced out for ``model.grad``
+            windowed = one_pass or dict(window=train.batch_size)
+            window_rounds = _WINDOW_ROUNDS.labels(rank=rank)
+            window_rows = _WINDOW_ROWS.labels(rank=rank)
 
             def grad_step(wf, batch):
                 # The round's device chain is enqueued whole and waited
@@ -1036,10 +1094,19 @@ class PSWorker:
                 # every reply and never written in place; a client that
                 # keeps its reply buffer has to keep this fence.
                 #
-                # the plan goes with the resident batch alone: its X is
+                # the plan goes with the resident rows alone: their X is
                 # held for it (``_place_shard``)
-                how = one_pass if batch is self._resident else {}
-                if batch is not self._resident:
+                if isinstance(batch, Window):
+                    # the first row is an operand, not a shape: every
+                    # window runs the one executable
+                    how = dict(windowed, first=np.int32(batch.first))
+                    window_rounds.inc()
+                    window_rows.inc(batch.rows)
+                    batch = self._resident
+                elif batch is self._resident:
+                    how = one_pass
+                else:
+                    how = {}
                     with self._span("h2d"):
                         batch = self._place(step_dev, *batch)
                 with self._span("w_put"):
@@ -1054,7 +1121,8 @@ class PSWorker:
                     # of its step marker rest on that
                     jax.block_until_ready(g)
                 _GRAD_ROUNDS.labels(
-                    rank=rank, path="one_pass" if how else "two_pass").inc()
+                    rank=rank,
+                    path="one_pass" if "panels" in how else "two_pass").inc()
                 _GRAD_DISPATCHES.labels(
                     rank=rank,
                     weights="landed" if landed else "in_flight").inc()
@@ -1065,55 +1133,90 @@ class PSWorker:
         self.grad_step = grad_step
 
     def _place_shard(self, train, step_dev):
-        """``(X, y, mask)`` of a whole-shard batch on the step's device,
-        or None where the worker streams: every epoch of such an iterator
-        yields these same rows, so they cross to the device once.  Each
-        leaf goes through ``feed.place``, which picks the layout it is
-        handed over in and counts it in ``distlr_h2d_bytes_total``; where
-        the one-pass step will read them (``_one_pass_plan``), the
-        features are then relaid on the device, row-major and padded."""
-        if train.num_batches != 1 or train.batch_size != train.num_samples:
-            return None
-        # the arrays the iterator holds where the batch is just those (no
-        # 1.5 GB gather of every row in turn, as ``next_batch`` makes)
-        batch = train.whole_shard()
-        if batch is None:
-            train.reset()
-            batch = train.next_batch()
+        """``(X, y, mask)`` of the shard on the step's device, or None
+        where the worker streams.  A whole-shard batch: every epoch
+        yields these same rows, so they cross to the device once.  A
+        minibatch iterator that serves the rows in the order it holds
+        them (``DataIter.held_rows``): every batch is a window of them,
+        so they cross once too, where the device says it has room
+        (``_PLACE_HEADROOM`` times their bytes free; no option), with
+        masked zero rows below where the last batch is short, so that
+        every window has ``batch_size`` rows to read.  Each leaf goes
+        through ``feed.place``, which picks the layout it is handed over
+        in and counts it in ``distlr_h2d_bytes_total``; where the
+        one-pass step will read them (``_one_pass_plan``), the features
+        are then relaid on the device, row-major and padded."""
+        device = _jax_device(step_dev)
+        if train.num_batches == 1 and train.batch_size == train.num_samples:
+            # the arrays the iterator holds where the batch is just those
+            # (no 1.5 GB gather of every row in turn, as ``next_batch``
+            # makes)
+            batch = train.whole_shard()
+            if batch is None:
+                train.reset()
+                batch = train.next_batch()
+            window = None
+        else:
+            batch = train.held_rows()
+            if batch is None:
+                return None
+            free = _device_free_bytes(device)
+            nbytes = sum(a.nbytes for a in batch)
+            if free is not None and free < _PLACE_HEADROOM * nbytes:
+                log.info("rank %d streams its shard: %d bytes, %d free on %s",
+                         self.rank, nbytes, free,
+                         _describe_compute_device(step_dev))
+                return None
+            window = train.batch_size
         placed, self._panels = self._place_rows(
-            "shard_put", batch, _jax_device(step_dev))
+            "shard_put", batch, device, window=window,
+            below=0 if window is None else max(
+                0, train.num_batches * window - train.num_samples))
+        self._windowed = window is not None
         self._resident_rows = int(batch[-1].sum())
         _RESIDENT_BYTES.labels(rank=str(self.rank)).set(
             sum(a.nbytes for a in batch))
         return placed
 
-    def _place_rows(self, span: str, batch, device):
+    def _place_rows(self, span: str, batch, device, *, window=None, below=0):
         """``(X, y, mask)`` on ``device`` to stay, under a span called
         ``span``, and the row-panel plan ``X`` is held for (or None):
         each leaf through ``feed.place``; where a plan reads the rows
         (``_one_pass_plan``) the features are then relaid on the device,
-        row-major and padded."""
+        row-major and padded.  ``window``: the rows a step reads of them
+        (all, where None), which the plan is for; ``below``: masked zero
+        rows to stand under them, the features' made on the device."""
+        X, y, mask = batch
+        if below:
+            y = np.concatenate([y, np.zeros(below, y.dtype)])
+            mask = np.concatenate([mask, np.zeros(below, mask.dtype)])
         mesh = make_mesh(devices=[device])
-        plan = _one_pass_plan(self.model, *batch[0].shape, device)
+        plan = _one_pass_plan(self.model, window or X.shape[0], X.shape[1],
+                              device)
         with self._span(span):
-            X, *rest = (feed.place(a, mesh) for a in batch)
-            if plan is not None:
+            X, y, mask = (feed.place(a, mesh) for a in (X, y, mask))
+            if plan is not None or below:
                 # once, on the device: the columns into the lanes
-                X = _row_major_program(plan)(X)
-            placed = jax.block_until_ready((X, *rest))
+                X = _row_major_program(plan, len(y) if below else None)(X)
+            placed = jax.block_until_ready((X, y, mask))
         return placed, plan
 
     def _batches(self, train):
         """An epoch's dense batches, each beside its count of real rows;
         ``data_load`` is what fetching one cost the loop: the numpy slice
-        of a streamed batch, nothing for a resident shard."""
-        resident = self._resident
+        of a streamed batch, nothing for a resident shard, of which a
+        minibatch is a :class:`~distlr_tpu.data.iterator.Window`."""
+        resident, windowed = self._resident, self._windowed
         for _ in range(train.num_batches):
             self.rounds += 1
             with self._span("data_load"):
-                batch = resident if resident is not None else train.next_batch()
-            yield batch, (self._resident_rows if resident is not None
-                          else int(batch[-1].sum()))
+                if resident is None:
+                    batch = train.next_batch()
+                else:
+                    batch = train.next_window() if windowed else resident
+            yield batch, (int(batch[-1].sum()) if resident is None
+                          else batch.rows if windowed
+                          else self._resident_rows)
 
     def start(self, *, resume=False, rejoin=False) -> None:
         """Seed the group (rank 0) and meet the peers at the start
@@ -1448,7 +1551,10 @@ class PSWorker:
                         self.rounds)
                     self.timer.stop(n_real)
                 if fut is not None:
-                    with self._span("push"):
+                    # the epoch's drain: no round's compute is left to
+                    # hide this push, and none is in flight across an
+                    # epoch's end
+                    with self._span("push", drain=1):
                         self._w_cache = fut.result()
                     self._w_time = time.perf_counter()
                     self._w_pushes = self._sample_push_clock()
@@ -1557,7 +1663,7 @@ class PSWorker:
         program's keyword, and the rows its mask counts.  The split is
         placed **once**, by the first eval, where the device has room for
         it beside what the worker already keeps there
-        (``_TEST_PLACE_HEADROOM`` times its bytes free, by the device's
+        (``_PLACE_HEADROOM`` times its bytes free, by the device's
         own count; a backend that keeps none is the host's memory, where
         the rows are): as the train shard is placed, under ``test_put``,
         counted in ``distlr_h2d_bytes_total`` and in
@@ -1569,7 +1675,7 @@ class PSWorker:
             nbytes, rows = sum(a.nbytes for a in batch), int(batch[-1].sum())
             free = _device_free_bytes(device)
             gauge = _TEST_RESIDENT_BYTES.labels(rank=str(self.rank))
-            if free is not None and free < _TEST_PLACE_HEADROOM * nbytes:
+            if free is not None and free < _PLACE_HEADROOM * nbytes:
                 gauge.set(0)
                 with self._span("h2d"):
                     return self._place(device, *batch), {}, rows

@@ -27,6 +27,15 @@ entries for ``dense-ps-bsp-4chip``).  Its other clauses are held, without
 the place, by ``tests/chipbench/test_dense_ps_bsp_chips.py::
 test_the_entries_that_were_there_are_as_they_were``, which says nothing of
 where in the list its own entries stand.
+
+A third: ``tests/chipbench/test_dense_ps_bsp_eval.py::
+test_the_new_entries_stand_at_the_end_of_their_lists`` (PR 36) holds its
+seven entries, its cell and its configuration to the end of their lists
+and the benchmark to five cells, and fails from PR 40 on (four entries, a
+cell and a configuration for ``dense-ps-async-minibatch-1chip``).  Its
+other clauses are held, without the place, by
+``tests/chipbench/test_dense_ps_minibatch.py::
+test_the_entries_that_were_there_are_as_they_were``.
 """
 
 import os
@@ -54,6 +63,8 @@ HELD_TO_THE_END = {
     "that_were_there": "PR 26",
     "test_dense_ps_bsp.py::test_the_entries_that_were_there_keep_their_"
     "order_and_the_new_follow": "PR 30",
+    "test_dense_ps_bsp_eval.py::test_the_new_entries_stand_at_the_end_of_"
+    "their_lists": "PR 36",
 }
 
 

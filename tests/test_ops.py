@@ -2,6 +2,7 @@
 equal to ``BinaryLR.grad`` in float32 whatever share of a panel VMEM
 holds, and compiled for a described v5e at the cell's size."""
 
+import dataclasses
 import re
 import types
 
@@ -152,6 +153,72 @@ def test_the_plan_follows_the_shape_and_the_limit():
     assert panel_plan(384, 4_000_000) is None   # 128 MB of partials
 
 
+# -- a window of a taller resident matrix, from a first row -----------------
+# R, B, D, chunk_tiles, slots of VMEM: all of a panel held, and a part
+WINDOWS = [
+    pytest.param(48, 16, 1000, 256, None, id="16-of-48-D1000"),
+    pytest.param(72, 24, 16384 + 64, 8, 10, id="24-of-72-D16448-8-of-17-held"),
+]
+
+
+@pytest.mark.parametrize("where", ["0", "8", "R-B"])
+@pytest.mark.parametrize("R,B,dim,chunk_tiles,slots", WINDOWS)
+def test_a_window_from_a_first_row_is_the_models_grad_on_the_sliced_rows(
+        R, B, dim, chunk_tiles, slots, where):
+    first = {"0": 0, "8": 8, "R-B": R - B}[where]
+    limit = (pallas_lr.VMEM_LIMIT_BYTES if slots is None
+             else _limit_for(B, dim, chunk_tiles, slots))
+    plan = panel_plan(B, dim, vmem_limit=limit, chunk_tiles=chunk_tiles)
+    assert plan.rows == B and (plan.held_share == 1.0) == (slots is None)
+    w, X, y, mask = _problem(R, dim, seed=5, masked=3)
+    cfg = types.SimpleNamespace(l2_c=0.2, l2_scale_by_batch=False)
+    model = _float32_model(dim)
+    rows = slice(first, first + B)
+    with jax.default_matmul_precision("highest"):
+        want = model.grad(w, (X[rows], y[rows], mask[rows]), cfg)
+    got = model.grad_panels(w, (pad_columns(X, plan), y, mask), cfg, plan,
+                            first=jnp.int32(first), interpret=True)
+    assert got.shape == (dim,) and got.dtype == jnp.float32
+    assert _rel(got, want) < 1e-6
+
+
+def test_every_window_runs_the_one_executable():
+    """The first row is an operand: a jitted step traced once serves
+    every window, and one that would run past the last row starts where
+    it still fits, as ``dynamic_slice`` has it."""
+    R, B, dim = 48, 16, 1000
+    plan = panel_plan(B, dim)
+    w, X, y, mask = _problem(R, dim, seed=6)
+    Xp = pad_columns(X, plan)
+    step = jax.jit(lambda first: lr_grad_panels(
+        w, Xp, jax.lax.dynamic_slice(y, (first,), (B,)),
+        jax.lax.dynamic_slice(mask, (first,), (B,)), plan, first=first,
+        interpret=True))
+    got = {first: np.asarray(step(np.int32(first))) for first in (0, 16, 32, 40)}
+    assert step._cache_size() == 1
+    np.testing.assert_array_equal(got[32], got[40])
+    assert _rel(got[0], got[16]) > 1e-2 and _rel(got[16], got[32]) > 1e-2
+    whole = lr_grad_panels(w, Xp[16:32], y[16:32], mask[16:32], plan,
+                           interpret=True)
+    np.testing.assert_array_equal(got[16], np.asarray(whole))
+
+
+def test_pad_columns_adds_the_rows_a_short_last_window_needs():
+    plan = panel_plan(16, 1000)
+    _w, X, _y, _mask = _problem(40, 1000)
+    Xp = pad_columns(X, plan, rows=48)
+    assert Xp.shape == (48, plan.dim_padded) and not np.asarray(Xp[40:]).any()
+    np.testing.assert_array_equal(np.asarray(Xp[:40, :1000]), np.asarray(X))
+
+
+def test_a_window_of_a_matrix_that_does_not_hold_it_is_refused():
+    plan = panel_plan(16, 1000)
+    w, X, y, mask = _problem(8, 1000)
+    with pytest.raises(ValueError, match="window"):
+        lr_grad_panels(w, pad_columns(X, plan), y, mask, plan,
+                       first=jnp.int32(0), interpret=True)
+
+
 def test_a_matrix_that_was_not_padded_is_refused():
     plan = panel_plan(16, 1000)
     w, X, y, mask = _problem(16, 1000)
@@ -204,6 +271,41 @@ def test_compiles_for_a_v5e_at_the_cells_size(chips, limit, rows, chip):
         if (big in line and re.match(r"\s*(ROOT )?%\S+ = ", line)
                 and "custom-call(" not in line):
             assert " parameter(" in line, line
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_a_window_compiles_for_a_v5e_as_one_read_of_the_rows_where_they_lie(
+        chips):
+    """The minibatch cell's step: 128 of a resident shard's 384 rows from
+    a traced first row.  Mosaic takes it; XLA hands the kernel the shard
+    as it lies (no ``dynamic-slice``, copy or reshape of the matrix, so
+    no second crossing of the window's 514 MB), and the plan is the
+    whole-shard one but for its rows."""
+    R, B, dim = 384, 128, 1_000_000
+    plan = panel_plan(B, dim)
+    assert dataclasses.replace(plan, rows=R) == panel_plan(R, dim)
+    assert (plan.held_share, plan.chunks) == (1.0, 31)
+    cfg = types.SimpleNamespace(l2_c=0.0, l2_scale_by_batch=False)
+    model = BinaryLR(dim)
+
+    def ps_grad_step(w, Xp, y, mask, first):
+        return model.grad_panels(w, (Xp, y, mask), cfg, plan, first=first)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chips[0])
+
+    compiled = jax.jit(ps_grad_step).lower(
+        spec((dim,), jnp.float32), spec((R, plan.dim_padded), jnp.float32),
+        spec((R,), jnp.int32), spec((R,), jnp.bool_),
+        spec((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    big = f"f32[{R},{plan.dim_padded}]"
+    for line in text.splitlines():
+        if (big in line and re.match(r"\s*(ROOT )?%\S+ = ", line)
+                and "custom-call(" not in line):
+            assert " parameter(" in line, line
+    assert f"f32[{B},{plan.dim_padded}]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 

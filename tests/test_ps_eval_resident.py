@@ -171,7 +171,7 @@ def test_a_split_that_does_not_fit_is_streamed_at_every_eval(lone_worker,
 
     def free(device):
         seen.append(device)
-        return ps_trainer._TEST_PLACE_HEADROOM * split_bytes - 1
+        return ps_trainer._PLACE_HEADROOM * split_bytes - 1
 
     monkeypatch.setattr(ps_trainer, "_device_free_bytes", free)
     tracer = get_tracer()
@@ -184,7 +184,7 @@ def test_a_split_that_does_not_fit_is_streamed_at_every_eval(lone_worker,
     # room enough by one byte: placed, and the same two numbers
     monkeypatch.setattr(
         ps_trainer, "_device_free_bytes",
-        lambda device: ps_trainer._TEST_PLACE_HEADROOM * split_bytes)
+        lambda device: ps_trainer._PLACE_HEADROOM * split_bytes)
     resident = w.evaluate(weights)
     assert w._test_resident is not None
     assert _gauge("distlr_ps_test_resident_bytes") == split_bytes

@@ -1,7 +1,9 @@
-"""A dense PS worker whose batch is its whole shard keeps it on the
-step's device: placed once, the same gradients as a streamed batch bit
-for bit, and a second ``fit`` that loads, places and compiles nothing.
-Minibatch workers stream as before."""
+"""A dense PS worker keeps its shard on the step's device: placed once,
+the same gradients as a streamed batch bit for bit, and a second ``fit``
+that loads, places and compiles nothing.  A whole-shard worker's batch is
+the resident rows, a minibatch worker's a window of them; what still
+streams is a shuffled or Q5-wrapping iterator, a keyed model, and a shard
+the device has no room for."""
 
 import threading
 
@@ -16,6 +18,7 @@ from distlr_tpu.train.ps_trainer import PSWorker, ps_param_dim
 
 DIM, CLASSES = 24, 3
 H2D = "distlr_h2d_bytes_total"
+ROUNDS = "distlr_ps_grad_rounds_total"
 
 
 def _job(tmp_path, model, num_workers, **kw):
@@ -119,11 +122,29 @@ def test_whole_shard_is_placed_once_and_gives_the_streamed_gradients(
                 w.close()
 
 
-def test_a_minibatch_worker_still_streams(tmp_path):
-    cfg = _job(tmp_path, "binary_lr", 1, batch_size=16, num_iteration=2)
+@pytest.mark.parametrize("why", ["shuffled", "wrap_compat", "no_room"])
+def test_a_minibatch_worker_still_streams(tmp_path, monkeypatch, why):
+    """What a window cannot serve: rows in another order than they are
+    held, a last batch that Q5 wraps, a shard the device has no room
+    for."""
+    from distlr_tpu.data.iterator import DataIter
+    from distlr_tpu.train import ps_trainer
+
+    # 80 training rows: the last batch of 24 is short
+    cfg = _job(tmp_path, "binary_lr", 1, batch_size=24, num_iteration=2,
+               wrap_final_batch=why == "wrap_compat")
+    train = None
+    if why == "shuffled":
+        from distlr_tpu.data.sharding import part_name
+
+        train = DataIter.from_file(f"{cfg.data_dir}/train/{part_name(0)}",
+                                   DIM, 24, shuffle=True, seed=3)
+    if why == "no_room":
+        monkeypatch.setattr(ps_trainer, "_device_free_bytes",
+                            lambda device: 100 * DIM * 4)
     before = family_total(H2D)
     with _group(cfg) as group:
-        w = PSWorker(cfg, 0, group.hosts)
+        w = PSWorker(cfg, 0, group.hosts, train_iter=train)
         try:
             w.load_data()
             assert w._resident is None
@@ -133,6 +154,136 @@ def test_a_minibatch_worker_still_streams(tmp_path):
     assert family_total(H2D) == before
     assert w.rounds == 2 * w._train.num_batches > 2
     assert np.isfinite(final).all() and np.count_nonzero(final)
+
+
+def test_a_keyed_model_places_nothing(tmp_path):
+    cfg = _job(tmp_path, "sparse_lr", 1, batch_size=16, num_iteration=1)
+    before = family_total(H2D)
+    with _group(cfg) as group:
+        w = PSWorker(cfg, 0, group.hosts)
+        try:
+            w.load_data()
+            assert w._resident is None and w.grad_step is None
+            w.run(save=False)
+        finally:
+            w.close()
+    assert family_total(H2D) == before and w.rounds == w._train.num_batches
+
+
+# -- a minibatch worker's batch is a window of its resident shard -----------
+WINDOW_ROUNDS = "distlr_ps_window_rounds_total"
+WINDOW_ROWS = "distlr_ps_window_rows_total"
+
+
+def _count(name, rank=0, **labels):
+    return get_registry().get(name).labels(rank=str(rank), **labels).value
+
+
+def _h2d_spans():
+    from distlr_tpu.obs.tracing import get_tracer
+
+    return get_tracer().breakdown().get("h2d", {"count": 0})["count"]
+
+
+@pytest.fixture
+def one_pass_on_the_cpu(monkeypatch):
+    """The selection as a TPU makes it, the kernel interpreted."""
+    from distlr_tpu.train import ps_trainer
+
+    monkeypatch.setattr(ps_trainer, "_ONE_PASS_PLATFORMS", ("tpu", "cpu"))
+
+
+@pytest.mark.parametrize("step", ["xla", "one_pass"])
+@pytest.mark.parametrize("sync", [False, True], ids=["async", "bsp"])
+@pytest.mark.parametrize("batch", [32, 40], ids=["B-divides-R", "B-leaves-16"])
+def test_a_windowed_workers_gradients_are_a_streamed_workers(
+        tmp_path, monkeypatch, request, batch, sync, step):
+    """Round by round over two epochs of 96 rows: the windows
+    ``[k B, k B + B)`` of the resident shard give what the iterator's
+    gathered batches give, the short last batch's pad rows masked out."""
+    from distlr_tpu.train import ps_trainer
+
+    if step == "one_pass":
+        request.getfixturevalue("one_pass_on_the_cpu")
+    cfg = _job(tmp_path, "binary_lr", 1, num_feature_dim=300,
+               compute_dtype="float32", l2_c=0.3, batch_size=batch,
+               sync_mode=sync, num_iteration=2)
+    write_synthetic_shards(cfg.data_dir, 120, 300, num_parts=1, seed=6,
+                           sparsity=0.0)
+    rounds = 2 * -(-96 // batch)
+    was = (_count(WINDOW_ROUNDS), _count(WINDOW_ROWS),
+           _count(ROUNDS, path=step if step == "one_pass" else "two_pass"))
+    with _group(cfg) as group:
+        w = PSWorker(cfg, 0, group.hosts)
+        with monkeypatch.context() as m:
+            m.setattr(PSWorker, "_place_shard", lambda self, train, dev: None)
+            m.setattr(ps_trainer, "_ONE_PASS_PLATFORMS", ("tpu",))
+            twin = PSWorker(cfg, 0, group.hosts)
+            twin.load_data()
+        try:
+            w.load_data()
+            X, y, mask = w._resident
+            assert twin._resident is None and w._windowed
+            assert (w._panels is not None) == (step == "one_pass")
+            assert len(y) == len(mask) == X.shape[0] == rounds // 2 * batch
+            assert int(mask.sum()) == 96 and not np.asarray(X[96:]).any()
+            w.grad_step = _Recorder(w.grad_step, keep=rounds)
+            placed, spans = family_total(H2D), _h2d_spans()
+            w.run(save=False)
+            assert w.rounds == rounds == len(w.grad_step.seen)
+            assert family_total(H2D) == placed and _h2d_spans() == spans
+            assert _count(WINDOW_ROUNDS) - was[0] == rounds
+            assert _count(WINDOW_ROWS) - was[1] == 2 * 96
+            assert _count(ROUNDS, path=step if step == "one_pass"
+                          else "two_pass") - was[2] == rounds
+            twin._train.reset()
+            for k, (weights, pushed) in enumerate(w.grad_step.seen):
+                if k == rounds // 2:
+                    twin._train.reset()
+                streamed = twin.grad_step(weights, twin._train.next_batch())
+                assert pushed.dtype == streamed.dtype == np.float32
+                # not bit for bit: XLA sums a slice fused into the product
+                # in another order than a batch handed in whole
+                assert (np.linalg.norm(pushed - streamed)
+                        <= 1e-6 * np.linalg.norm(streamed)), k
+                assert np.count_nonzero(pushed)
+        finally:
+            w.close()
+            twin.close()
+
+
+@pytest.mark.parametrize("step", ["xla", "one_pass"])
+def test_windows_place_once_and_run_one_executable(tmp_path, request, step):
+    """Two ``fit``s of a windowed worker: one placement, no ``h2d`` span,
+    and the jit cache of the step as the first round left it."""
+    if step == "one_pass":
+        request.getfixturevalue("one_pass_on_the_cpu")
+    cfg = _job(tmp_path, "binary_lr", 1, num_feature_dim=300,
+               compute_dtype="float32", batch_size=24, num_iteration=9)
+    write_synthetic_shards(cfg.data_dir, 120, 300, num_parts=1, seed=6,
+                           sparsity=0.0)
+    before, spans = family_total(H2D), _h2d_spans()
+    with _group(cfg) as group:
+        w = PSWorker(cfg, 0, group.hosts)
+        try:
+            w.load_data()
+            placed = family_total(H2D)
+            shard = sum(a.nbytes for a in w._train.held_rows())
+            assert shard > 96 * 300 * 4
+            assert placed - before == shard
+            assert _count("distlr_ps_resident_bytes") == shard
+            w.start()
+            w.grad_step = _Recorder(w.grad_step, keep=1)
+            w.fit(epochs=1)
+            compiled = w._grad_fn._cache_size()
+            w.fit(epochs=2)
+            w.fit(epochs=1)
+            assert (w.epochs_done, w.rounds) == (4, 16)
+            assert w._grad_fn._cache_size() == compiled
+            assert family_total(H2D) == placed and _h2d_spans() == spans
+            w.finish(save=False)
+        finally:
+            w.close()
 
 
 def test_numpy_steps_place_nothing(tmp_path):
@@ -206,19 +357,9 @@ def test_whole_shard_is_nothing_where_a_batch_is_anything_else(kw):
 
 
 # -- the one-pass step of a resident shard (ops/pallas_lr.py) ---------------
-ROUNDS = "distlr_ps_grad_rounds_total"
-
 
 def _rounds(rank, path):
-    return get_registry().get(ROUNDS).labels(rank=str(rank), path=path).value
-
-
-@pytest.fixture
-def one_pass_on_the_cpu(monkeypatch):
-    """The selection as a TPU makes it, the kernel interpreted."""
-    from distlr_tpu.train import ps_trainer
-
-    monkeypatch.setattr(ps_trainer, "_ONE_PASS_PLATFORMS", ("tpu", "cpu"))
+    return _count(ROUNDS, rank, path=path)
 
 
 def _one_pass_job(tmp_path, model="binary_lr", **kw):
@@ -283,14 +424,16 @@ def test_a_resident_shard_takes_the_one_pass_step(tmp_path, monkeypatch,
             parent.close()
 
 
-@pytest.mark.parametrize("refusal", ["streamed", "softmax", "rows"])
+@pytest.mark.parametrize("refusal", ["streamed", "softmax", "rows", "window"])
 def test_the_selection_keeps_the_xla_step(tmp_path, one_pass_on_the_cpu,
                                           refusal):
-    """A streamed batch, a softmax model and rows that are no whole
-    sublane groups each keep ``model.grad`` under XLA, counted so."""
-    kw = {"streamed": dict(batch_size=32),
+    """A streamed batch (the rows of a Q5-wrapping iterator), a softmax
+    model, rows that are no whole sublane groups and windows that are
+    none each keep ``model.grad`` under XLA, counted so."""
+    kw = {"streamed": dict(batch_size=36, wrap_final_batch=True),
           "softmax": dict(model="softmax"),
-          "rows": {}}[refusal]
+          "rows": {},
+          "window": dict(batch_size=36)}[refusal]
     cfg = _one_pass_job(tmp_path, num_iteration=2, **kw)
     _write_rows(cfg, 100 if refusal == "rows" else 96,
                 num_classes=cfg.num_classes)
@@ -301,8 +444,12 @@ def test_the_selection_keeps_the_xla_step(tmp_path, one_pass_on_the_cpu,
             w.load_data()
             assert w._panels is None
             assert (w._resident is None) == (refusal == "streamed")
+            assert w._windowed == (refusal == "window")
             if w._resident is not None:
-                assert w._resident[0].shape == (w._train.num_samples, 300)
+                # the default layout, three windows of 36 where 96 rows
+                # are read in windows
+                assert w._resident[0].shape == (
+                    108 if refusal == "window" else w._train.num_samples, 300)
             final = w.run(save=False)
         finally:
             w.close()

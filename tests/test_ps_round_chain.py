@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from distlr_tpu.config import Config
+from distlr_tpu.data.iterator import Window
 from distlr_tpu.data.synthetic import write_synthetic_shards
 from distlr_tpu.obs.registry import get_registry
 from distlr_tpu.obs.tracing import get_tracer
@@ -22,7 +23,11 @@ DIM, CLASSES, WORKERS, ITERATIONS = 24, 3, 2, 3
 CHAIN = ("w_put", "compute", "grad_d2h")
 
 MODELS = ("binary_lr", "softmax")
-BATCHES = {"resident": -1, "streamed": 32}
+# 80 rows a worker: whole; in windows of 32 of the resident shard; and
+# streamed, as Q5's wrapped last batch still is
+BATCHES = {"resident": dict(batch_size=-1),
+           "windowed": dict(batch_size=32),
+           "streamed": dict(batch_size=32, wrap_final_batch=True)}
 LOOPS = {"fused-bsp": dict(sync_mode=True),
          "pipelined-async": dict(sync_mode=False)}
 CASES = [(m, b, l) for m in MODELS for b in BATCHES for l in LOOPS]
@@ -38,7 +43,7 @@ def _cfg(d, model, batch, loop, **kw):
                            sparsity=0.0, num_classes=classes)
     base = dict(
         data_dir=d, num_feature_dim=DIM, model=model, num_classes=classes,
-        num_workers=WORKERS, num_servers=2, batch_size=BATCHES[batch],
+        num_workers=WORKERS, num_servers=2, **BATCHES[batch],
         num_iteration=ITERATIONS, learning_rate=0.2, l2_c=0.0,
         test_interval=0,
         # the jitted step on the default backend: "auto" would take these
@@ -64,11 +69,15 @@ class _Recorder:
 
 def _blocking(worker, wf, batch):
     """The round's chain as it stood before: a wait after every link."""
-    if batch is not worker._resident:
+    how = {}
+    if isinstance(batch, Window):
+        how = dict(first=np.int32(batch.first), window=32)
+        batch = worker._resident
+    elif batch is not worker._resident:
         batch = jax.block_until_ready(
             tuple(jax.device_put(a) for a in batch))
     w = jax.block_until_ready(jax.device_put(worker._shape_params(wf)))
-    g = jax.block_until_ready(worker._grad_fn(w, *batch))
+    g = jax.block_until_ready(worker._grad_fn(w, *batch, **how))
     return np.asarray(g).reshape(-1)
 
 
@@ -93,7 +102,8 @@ def _run(tmp_path_factory, model, batch, loop, **kw):
         try:
             for w in workers:
                 w.load_data()
-                assert (w._resident is not None) == (batch == "resident")
+                assert (w._resident is not None) == (batch != "streamed")
+                assert w._windowed == (batch == "windowed")
                 assert w._panels is None  # the CPU keeps the XLA step
                 w.grad_step = _Recorder(w.grad_step)
             before = _counts()

@@ -124,11 +124,15 @@ def test_spans_of_four_threads_do_not_nest_into_each_other(data_dir):
     # on the loop's own thread and no comm thread
     ("fused-bsp-resident", dict(sync_mode=True, sync_last_gradient=False),
      {"push", "pull", "shard_put", "w_put", "grad_d2h"}, {"wire", "h2d"}),
-    ("minibatch", dict(batch_size=32), {"h2d", "wire"}, {"shard_put"}),
+    # 80 rows a worker in windows of 32 of the resident shard; what a
+    # window cannot serve (Q5's wrapped last batch) streams as before
+    ("minibatch", dict(batch_size=32), {"shard_put", "wire"}, {"h2d"}),
+    ("minibatch-wrapped", dict(batch_size=32, wrap_final_batch=True),
+     {"h2d", "wire"}, {"shard_put"}),
     ("numpy", dict(ps_compute_backend="numpy"), {"compute", "wire"},
      {"shard_put", "w_put", "grad_d2h", "h2d"}),
-    ("accumulated", dict(ps_accum_max=2, batch_size=32), {"pull", "push"},
-     {"wire", "shard_put"}),
+    ("accumulated", dict(ps_accum_max=2, batch_size=32),
+     {"pull", "push", "shard_put"}, {"wire", "h2d"}),
 ])
 def test_each_loop_variant_records_the_spans_it_has(data_dir, mode, kw, has,
                                                     lacks):
@@ -269,3 +273,27 @@ def test_a_profiler_trace_holds_the_evals_phases_as_plain_annotations(
         # a step marker would carry step_num: these group no device work
         assert not any("step_num" in s for s in found[name])
     assert len(found["test_put"]) == 1
+
+
+def test_an_epochs_drain_is_told_apart_under_the_same_name(data_dir):
+    """The pipelined async loop waits at every epoch's end for the push in
+    flight: that ``push`` span carries ``drain`` beside ``step`` and
+    ``rank``, the ones inside an epoch do not, and a whole-shard worker's
+    every push is one."""
+    events = _events(_cfg(data_dir, batch_size=32))
+    per_rank = _by([e for e in events if e["name"] == "push"
+                    and e["args"]["step"]], lambda e: e["args"]["rank"])
+    for rank in range(WORKERS):
+        drains = [e["args"]["step"] for e in per_rank[rank]
+                  if e["args"].get("drain")]
+        waits = [e["args"]["step"] for e in per_rank[rank]
+                 if not e["args"].get("drain")]
+        # three rounds an epoch: a wait at its second and third round,
+        # the drain after the third
+        assert drains == [3, 6, 9] and waits == [2, 3, 5, 6, 8, 9]
+    assert not any(e["args"].get("drain") for e in events
+                   if e["name"] != "push")
+    whole = _events(_cfg(data_dir))
+    pushes = [e for e in whole if e["name"] == "push" and e["args"]["step"]]
+    assert len(pushes) == WORKERS * ITERATIONS
+    assert all(e["args"].get("drain") == 1 for e in pushes)

@@ -556,7 +556,8 @@ class TestSupervisorStoreEvents:
                   and g.procs[0].poll() is None, what="respawn")
             _wait(lambda: "store-stale" in _names(sup),
                   what="store-stale audit event")
-            assert "reseeded" in _names(sup)
+            # recorded after the re-seed's push, which follows the audit
+            _wait(lambda: "reseeded" in _names(sup), what="reseeded event")
             sup.stop()
 
     def test_store_corrupt_fallback_is_audited(self, tmp_path):
