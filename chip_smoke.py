@@ -566,6 +566,7 @@ class Smoke:
             "shape": f"{rows}x{d}", "padded_to": plan.dim_padded,
             "vmem_limit_bytes": plan.vmem_limit,
             "f": f"{plan.held_share:.3f}", "chunks": plan.chunks,
+            "ahead": plan.ahead,
             "interpret": self.s.kernel_interpret, "rel_err": f"{err:.2e}",
             "one_pass_ms": f"{one_ms:.3f}", "two_pass_ms": f"{two_ms:.3f}",
         }

@@ -28,17 +28,36 @@ passes):
   as the chunks land (one lane reduction at the end gives ``z`` for the
   eight rows), then ``r = (sigmoid(z) - y) * mask``;
 * sweep 2 accumulates ``x * r`` into eight sublane partials of the
-  gradient from the same VMEM bytes, and as it leaves a chunk's slot it
-  starts the fetch of the next panel's chunk into it, so HBM is never
-  waiting on a whole panel of arithmetic;
+  gradient from the same VMEM bytes (the call's first panel writes them,
+  so nothing is zeroed), and as it leaves a chunk's slot it starts the
+  fetch that slot is next for;
+* **look-ahead slots**: with exactly a panel's ``chunks`` slots, the next
+  panel's chunk ``k`` cannot start before sweep 2 has left this panel's
+  chunk ``k``, so from the landing of a panel's last chunk through its
+  forward, the lane reduction, the sigmoid and the first backward no copy
+  is in flight: 44.1 us a panel at D = 1M where the stream needs 39.2
+  (PERF.md section 6, PR 41).  A plan therefore has ``ahead`` slots more,
+  out of the VMEM that is left: a whole second bank where it fits
+  (``ahead == chunks``), as many as fit otherwise.  The held chunks turn
+  through ``held + ahead`` slots in the order they are read, and a
+  slot's next fetch starts when its last reader is done, so ``ahead``
+  fetches are queued whenever the arithmetic between the sweeps runs;
 * where VMEM holds only ``held`` of a panel's ``chunks`` (a share
   ``f = held / chunks``), the others go through a ring of two slots in
-  both sweeps: the matrix is read ``2 - f`` times, not twice.
-  :func:`panel_plan` works ``f`` out from the shape and the VMEM limit.
+  both sweeps: the matrix is read ``2 - f`` times, not twice, and
+  nothing is left to fetch ahead into (``ahead`` = 0).
+  :func:`panel_plan` works ``f`` and ``ahead`` out from the shape and
+  the VMEM limit;
+* after the last panel **the eight partials are summed where they lie**,
+  in VMEM, and the kernel's result is the gradient's ``Dp`` columns once
+  (a tile a row, 4 MB): 32 MB of partials do not cross HBM twice for
+  XLA to add eight numbers a column.
 
-The eight partials are summed, cut to ``D``, and turned into the mean
-gradient with its L2 term by plain ``jnp`` around the call
-(:meth:`BinaryLR.grad_panels`), in the caller's jitted function.
+The gradient is cut to ``D`` and turned into the mean gradient with its
+L2 term by plain ``jnp`` around the call (:meth:`BinaryLR.grad_panels`),
+in the caller's jitted function; the weights' pad and that trim stay
+XLA's (``D`` = 1,000,000 is no whole number of tiles: the last chunk of
+``w`` would be ragged).
 
 A **window**.  The matrix may be taller than the plan: a minibatch
 worker keeps its whole shard resident and a round's batch is
@@ -64,9 +83,11 @@ from jax.experimental.pallas import tpu as pltpu
 _LANES = 128
 _SUBLANES = 8   # rows of a panel: one float32 sublane group
 #: column tiles of a chunk, about: 256 tiles x 8 rows x 512 B = 1 MB, large
-#: enough that a DMA runs at HBM's rate and small enough that the two
-#: chunks of arithmetic between a panel's last fetch landing and the next
-#: panel's first fetch starting are a few percent of a panel's 40 us
+#: enough that a DMA runs at HBM's rate.  A chunk is also the grain of the
+#: hand-over between fetch and arithmetic; what HBM loses while a panel's
+#: arithmetic runs is the look-ahead slots' to cover, not the chunk
+#: size's: with none, the ledger read 44.1 us a panel for a stream of 39.2
+#: (4-12% by the size of the call, not "a few percent")
 _CHUNK_TILES = 256
 #: slots the chunks that do not stay go through, in turn
 _RING = 2
@@ -81,6 +102,11 @@ _VMEM_SLACK = 4 << 20
 VMEM_LIMIT_BYTES = 120 << 20
 
 
+def _whole_groups(n: int) -> int:
+    """``n`` rows as VMEM holds them: whole sublane groups."""
+    return pl.cdiv(n, _SUBLANES) * _SUBLANES
+
+
 @dataclasses.dataclass(frozen=True)
 class PanelPlan:
     """How a ``float32[rows, dim]`` matrix is walked."""
@@ -90,6 +116,7 @@ class PanelPlan:
     chunk_tiles: int    # 128-column tiles of a chunk
     chunks: int         # chunks of a panel
     held: int           # of them, those that stay in VMEM between the sweeps
+    ahead: int          # slots beyond them, for the next panel's fetches
     vmem_limit: int
 
     @property
@@ -103,7 +130,7 @@ class PanelPlan:
     @property
     def weight_rows(self) -> int:
         """Rows a chunk's weights take, one tile a row, whole groups."""
-        return pl.cdiv(self.chunk_tiles, _SUBLANES) * _SUBLANES
+        return _whole_groups(self.chunk_tiles)
 
     @property
     def held_share(self) -> float:
@@ -111,8 +138,32 @@ class PanelPlan:
         return self.held / self.chunks
 
     @property
+    def ahead_share(self) -> float:
+        """``ahead / chunks``: how much of the next panel is on its way
+        while the arithmetic between a panel's two sweeps runs."""
+        return self.ahead / self.chunks
+
+    @property
     def slots(self) -> int:
-        return self.held + (_RING if self.held < self.chunks else 0)
+        return (self.held + self.ahead
+                + (_RING if self.held < self.chunks else 0))
+
+    @property
+    def slot_bytes(self) -> int:
+        return _SUBLANES * self.chunk_cols * 4
+
+    @property
+    def fixed_bytes(self) -> int:
+        """VMEM beside the slots: the eight partials of g, g itself a
+        tile a row, w, and the slack."""
+        return (_SUBLANES * self.dim_padded * 4
+                + _whole_groups(self.chunks * self.chunk_tiles) * _LANES * 4
+                + self.chunks * self.weight_rows * _LANES * 4 + _VMEM_SLACK)
+
+    @property
+    def vmem_bytes(self) -> int:
+        """What the plan counts against ``vmem_limit``."""
+        return self.fixed_bytes + self.slots * self.slot_bytes
 
 
 def panel_plan(rows: int, dim: int, *, vmem_limit: int = VMEM_LIMIT_BYTES,
@@ -120,19 +171,18 @@ def panel_plan(rows: int, dim: int, *, vmem_limit: int = VMEM_LIMIT_BYTES,
     """The plan for a ``float32[rows, dim]`` matrix under ``vmem_limit``
     bytes of VMEM, or None where the kernel cannot run: rows that are not
     whole sublane groups, or a limit that leaves no chunk of a panel in
-    place once the gradient's partials and the weights are counted."""
+    place once the gradient, its partials and the weights are counted.
+    Where a whole panel stays, what VMEM is left is the look-ahead's
+    (``ahead``), up to a second bank; nothing a caller sets."""
     if rows <= 0 or dim <= 0 or rows % _SUBLANES:
         return None
     tiles = pl.cdiv(dim, _LANES)
     chunks = pl.cdiv(tiles, chunk_tiles)
-    plan = PanelPlan(rows, dim, pl.cdiv(tiles, chunks), chunks, chunks,
+    plan = PanelPlan(rows, dim, pl.cdiv(tiles, chunks), chunks, chunks, 0,
                      vmem_limit)
-    # beside the slots: the eight partials of g, and w
-    fixed = (_SUBLANES * plan.dim_padded * 4
-             + chunks * plan.weight_rows * _LANES * 4 + _VMEM_SLACK)
-    slots = (vmem_limit - fixed) // (_SUBLANES * plan.chunk_cols * 4)
+    slots = (vmem_limit - plan.fixed_bytes) // plan.slot_bytes
     if slots >= chunks:
-        return plan
+        return dataclasses.replace(plan, ahead=min(slots - chunks, chunks))
     if slots - _RING < 1:
         return None
     return dataclasses.replace(plan, held=slots - _RING)
@@ -167,28 +217,31 @@ def lr_logits_rows(w, Xp, plan: PanelPlan):
 
 
 def _kernel(plan: PanelPlan, first_ref, x_hbm, w_ref, y_ref, mask_ref, g_ref,
-            buf, sems):
+            part, buf, sems):
     """``first_ref``: ``i32[1]`` in SMEM, the window's first row of
     ``x_hbm`` (a multiple of eight), or None where the matrix is read
     from row 0; ``x_hbm``: ``f32[R, dim_padded]`` in HBM, ``R`` no fewer
     than ``rows``; ``w_ref``: ``f32[chunks, weight_rows, 128]``, chunk
     ``k``'s tiles one a row; ``y_ref``, ``mask_ref``: ``f32[rows, 1]``,
-    the window's; ``g_ref``: ``f32[8, dim_padded]``, the sublane
+    the window's; ``g_ref``: ``f32[dim_padded / 128, 128]``, the
+    gradient a tile a row; ``part``: ``f32[8, dim_padded]``, its sublane
     partials; ``buf``: ``f32[slots, 8, chunk_cols]``; ``sems``: a DMA
     semaphore a slot."""
     panels = plan.rows // _SUBLANES
     first = 0 if first_ref is None else first_ref[0]
     tiles, cols = plan.chunk_tiles, plan.chunk_cols
     held, streamed = plan.held, plan.chunks - plan.held
+    bank = held + plan.ahead    # slots the held chunks turn through
+    kept = held * panels        # held chunks of the call, in the order read
     ring_fetches = 2 * streamed * panels
 
-    def loop(n, body):
-        """``body(k)`` for each ``k < n``, for what it does."""
+    def loop(n, body, start=0):
+        """``body(k)`` for each ``start <= k < n``, for what it does."""
         def step(k, carry):
             body(k)
             return carry
 
-        lax.fori_loop(0, n, step, 0)
+        lax.fori_loop(start, n, step, 0)
 
     def copy(panel, chunk, slot):
         return pltpu.make_async_copy(
@@ -197,11 +250,16 @@ def _kernel(plan: PanelPlan, first_ref, x_hbm, w_ref, y_ref, mask_ref, g_ref,
                      pl.ds(pl.multiple_of(chunk * cols, _LANES), cols)],
             buf.at[slot], sems.at[slot])
 
+    def held_copy(q):
+        """The ``q``-th held chunk's fetch: chunk ``q % held`` of panel
+        ``q // held``, into the slot the ``q - bank``-th has left."""
+        return copy(q // held, q % held, q % bank)
+
     def ring_copy(q):
         """The ``q``-th fetch through the ring: the streamed chunks of
         panel 0 for sweep 1, again for sweep 2, then panel 1's."""
         return copy(q // (2 * streamed), held + q % streamed,
-                    held + q % _RING)
+                    bank + q % _RING)
 
     def start_ring(q):
         pl.when(q < ring_fetches)(lambda: ring_copy(q).start())
@@ -226,13 +284,15 @@ def _kernel(plan: PanelPlan, first_ref, x_hbm, w_ref, y_ref, mask_ref, g_ref,
             acc = group(groups, acc, tiles % _SUBLANES)
         return acc
 
-    def backward(slot, chunk, r):
-        """Sweep 2 over the chunk in ``slot``: ``g[:, cols] += x * r``."""
+    def backward(slot, chunk, r, opening):
+        """Sweep 2 over the chunk in ``slot``: ``part[:, cols] += x * r``;
+        the call's ``opening`` panel writes, so nothing is zeroed."""
         base = chunk * cols
 
         def add(col, width):
             at = pl.ds(pl.multiple_of(base + col, _LANES), width)
-            g_ref[:, at] += buf[slot, :, pl.ds(col, width)] * r[:, :width]
+            xr = buf[slot, :, pl.ds(col, width)] * r[:, :width]
+            part[:, at] = xr if opening else part[:, at] + xr
 
         wide = _SUBLANES * _LANES
         if tiles >= _SUBLANES:
@@ -242,18 +302,18 @@ def _kernel(plan: PanelPlan, first_ref, x_hbm, w_ref, y_ref, mask_ref, g_ref,
         if cols > full:
             add(full, cols - full)
 
-    g_ref[...] = jnp.zeros_like(g_ref)
-    loop(held, lambda k: copy(0, k, k).start())
+    loop(min(bank, kept), lambda q: held_copy(q).start())
     if streamed:
         for q in range(_RING):
             start_ring(q)
 
-    def panel(p):
+    def panel(p, opening=False):
         acc = (jnp.zeros((_SUBLANES, _LANES), jnp.float32),) * _SUBLANES
 
         def held_forward(k, acc):
-            copy(p, k, k).wait()
-            return forward(k, k, acc)
+            q = p * held + k
+            held_copy(q).wait()
+            return forward(q % bank, k, acc)
 
         acc = lax.fori_loop(0, held, held_forward, acc)
 
@@ -263,7 +323,7 @@ def _kernel(plan: PanelPlan, first_ref, x_hbm, w_ref, y_ref, mask_ref, g_ref,
             def step(j, carry):
                 q = first + j
                 ring_copy(q).wait()
-                carry = body(held + q % _RING, held + j, carry)
+                carry = body(bank + q % _RING, held + j, carry)
                 start_ring(q + _RING)
                 return carry
 
@@ -277,18 +337,39 @@ def _kernel(plan: PanelPlan, first_ref, x_hbm, w_ref, y_ref, mask_ref, g_ref,
         r = jnp.broadcast_to(r, (_SUBLANES, _SUBLANES * _LANES))
 
         def held_backward(k):
-            backward(k, k, r)
-            pl.when(p + 1 < panels)(lambda: copy(p + 1, k, k).start())
+            """A slot's last reader is done: the fetch ``bank`` chunks on
+            starts, so ``ahead`` of them are queued whenever the
+            arithmetic between the two sweeps runs."""
+            q = p * held + k
+            backward(q % bank, k, r, opening)
+            pl.when(q + bank < kept)(lambda: held_copy(q + bank).start())
 
         def ring_backward(slot, chunk, carry):
-            backward(slot, chunk, r)
+            backward(slot, chunk, r, opening)
             return carry
 
         loop(held, held_backward)
         if streamed:
             ring_pass(2 * streamed * p + streamed, ring_backward, 0)
 
-    loop(panels, panel)
+    panel(0, opening=True)
+    loop(panels, panel, start=1)
+
+    # the eight partials summed where they lie, a tile a row of ``g_ref``:
+    # no fetch is left for this to delay
+    def total(tile):
+        at = pl.ds(pl.multiple_of(tile * _LANES, _LANES), _LANES)
+        return jnp.sum(part[:, at], axis=0, keepdims=True)
+
+    def sum_group(i, count=_SUBLANES):
+        first = pl.multiple_of(i * _SUBLANES, _SUBLANES)
+        for u in range(count):
+            g_ref[pl.ds(first + u, 1), :] = total(first + u)
+
+    groups, rest = divmod(plan.chunks * tiles, _SUBLANES)
+    loop(groups, sum_group)
+    if rest:
+        sum_group(groups, rest)
 
 
 def lr_grad_panels(w, Xp, y, mask, plan: PanelPlan, *, first=None,
@@ -327,13 +408,14 @@ def lr_grad_panels(w, Xp, y, mask, plan: PanelPlan, *, first=None,
         operands.insert(0, jnp.clip(jnp.asarray(first, jnp.int32),
                                     0, Xp.shape[0] - plan.rows).reshape(1))
         in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
-    partials = pl.pallas_call(
+    g = pl.pallas_call(
         kernel,
         in_specs=in_specs,
         out_specs=vmem,
-        out_shape=jax.ShapeDtypeStruct((_SUBLANES, plan.dim_padded),
+        out_shape=jax.ShapeDtypeStruct((plan.dim_padded // _LANES, _LANES),
                                        jnp.float32),
         scratch_shapes=[
+            pltpu.VMEM((_SUBLANES, plan.dim_padded), jnp.float32),
             pltpu.VMEM((plan.slots, _SUBLANES, plan.chunk_cols), jnp.float32),
             pltpu.SemaphoreType.DMA((plan.slots,)),
         ],
@@ -342,4 +424,4 @@ def lr_grad_panels(w, Xp, y, mask, plan: PanelPlan, *, first=None,
         name="lr_grad_panels",
         interpret=interpret,
     )(*operands)
-    return jnp.sum(partials, axis=0)[:plan.dim]
+    return g.reshape(plan.dim_padded)[:plan.dim]

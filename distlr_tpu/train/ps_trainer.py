@@ -197,6 +197,14 @@ _PANEL_HELD = get_registry().gauge(
     "(0 = the two-pass program)",
     labelnames=("rank",),
 )
+_PANEL_AHEAD = get_registry().gauge(
+    "distlr_ps_grad_panel_ahead",
+    "share ahead / chunks of the next row panel whose fetches the one-pass "
+    "step has queued while the arithmetic between a panel's two sweeps "
+    "runs: slots of VMEM beyond a held panel's (0 = a part-held panel, or "
+    "the two-pass program)",
+    labelnames=("rank",),
+)
 
 
 class _StepTrace:
@@ -746,8 +754,10 @@ class PSWorker:
     ``dynamic_slice`` of the resident rows where the batch is a window.
     ``distlr_ps_grad_rounds_total{rank, path}``
     counts the rounds of each, ``distlr_ps_grad_panel_held{rank}`` is the
-    share of a panel VMEM holds.  A round's device chain (weights in,
-    the program, the gradient out) is enqueued whole and waited for once
+    share of a panel VMEM holds, ``distlr_ps_grad_panel_ahead{rank}`` the
+    share of the next one it has slots to fetch ahead into.  A round's
+    device chain (weights in, the program, the gradient out) is enqueued
+    whole and waited for once
     (``_bind_dense_step``); ``distlr_ps_grad_dispatches_total{rank,
     weights}`` counts the rounds whose program was dispatched with the
     weights' copy still ``in_flight``, and those where it had ``landed``.
@@ -1071,6 +1081,8 @@ class PSWorker:
             _STEP_DEVICE.labels(rank=rank).set(_jax_device(step_dev).id)
             plan = self._panels
             _PANEL_HELD.labels(rank=rank).set(plan.held_share if plan else 0.0)
+            _PANEL_AHEAD.labels(rank=rank).set(
+                plan.ahead_share if plan else 0.0)
             # the kernel is interpreted off the TPU (tests)
             one_pass = {} if plan is None else dict(
                 panels=plan,

@@ -1,6 +1,7 @@
 """The row-panel kernel (``ops/pallas_lr.py``), interpreted on the CPU:
 equal to ``BinaryLR.grad`` in float32 whatever share of a panel VMEM
-holds, and compiled for a described v5e at the cell's size."""
+holds and however many slots it has to fetch ahead into, and compiled
+for a described v5e at the cell's size."""
 
 import dataclasses
 import re
@@ -52,23 +53,53 @@ def _limit_for(rows, dim, chunk_tiles, slots):
     return hi
 
 
-# rows, dim, chunk_tiles, slots of VMEM (None: the default limit, all held)
+def _plan(rows, dim, chunk_tiles=256, slots=None):
+    """The plan with ``slots`` chunk slots of VMEM and no more (None: the
+    default limit, which at these sizes is a whole second bank): fewer
+    than a panel's chunks hold a part of it and keep the ring of two,
+    what is over them is the look-ahead's."""
+    limit = (pallas_lr.VMEM_LIMIT_BYTES if slots is None
+             else _limit_for(rows, dim, chunk_tiles, slots))
+    plan = panel_plan(rows, dim, vmem_limit=limit, chunk_tiles=chunk_tiles)
+    if slots is None:
+        slots = 2 * plan.chunks
+    assert plan.slots == slots and plan.vmem_bytes <= limit
+    if slots < plan.chunks:
+        assert (plan.held, plan.ahead) == (slots - 2, 0)
+    else:
+        assert (plan.held, plan.ahead) == (plan.chunks, slots - plan.chunks)
+    return plan
+
+
+# rows, dim, chunk_tiles, slots of VMEM (None: the default limit)
 SHAPES = [
-    pytest.param(16, 1000, 256, None, id="D1000-one-chunk"),
-    pytest.param(24, 16384 + 64, 8, None, id="D16448-17-chunks-held"),
+    pytest.param(16, 1000, 256, None, id="D1000-one-chunk-and-a-bank-ahead"),
+    pytest.param(16, 1000, 256, 1, id="D1000-one-chunk-none-ahead"),
+    pytest.param(24, 16384 + 64, 8, None, id="D16448-17-chunks-a-bank-ahead"),
+    pytest.param(24, 16384 + 64, 8, 22, id="D16448-17-chunks-5-ahead"),
+    pytest.param(24, 16384 + 64, 8, 17, id="D16448-17-chunks-none-ahead"),
     pytest.param(24, 16384 + 64, 8, 10, id="D16448-8-of-17-held"),
+    pytest.param(40, 3000, 4, 10, id="D3000-five-panels-4-of-6-ahead"),
+    pytest.param(40, 3000, 4, 7, id="D3000-five-panels-1-of-6-ahead"),
     pytest.param(16, 3000, 4, 3, id="D3000-1-of-6-held"),
+    pytest.param(8, 3000, 5, None, id="D3000-one-panel-a-bank-ahead"),
     pytest.param(8, 3000, 5, 4, id="D3000-one-panel-2-of-5-held"),
 ]
+
+# a panel of four chunks: none ahead, two, a whole second bank
+AHEAD = [pytest.param(4, id="none-ahead"), pytest.param(6, id="2-of-4-ahead"),
+         pytest.param(None, id="a-bank-ahead")]
+
+
+def _four_chunks(rows, slots):
+    plan = _plan(rows, 1000, 2, slots)
+    assert (plan.chunks, plan.held_share) == (4, 1.0)
+    return plan
 
 
 @pytest.mark.parametrize("rows,dim,chunk_tiles,slots", SHAPES)
 def test_equals_binary_lr_grad_in_float32(rows, dim, chunk_tiles, slots):
-    limit = (pallas_lr.VMEM_LIMIT_BYTES if slots is None
-             else _limit_for(rows, dim, chunk_tiles, slots))
-    plan = panel_plan(rows, dim, vmem_limit=limit, chunk_tiles=chunk_tiles)
-    assert plan.held == (plan.chunks if slots is None else slots - 2)
-    assert (plan.held_share == 1.0) == (slots is None)
+    plan = _plan(rows, dim, chunk_tiles, slots)
     assert plan.dim_padded % 128 == 0 and 0 <= plan.dim_padded - dim
     w, X, y, mask = _problem(rows, dim, masked=3)
     cfg = types.SimpleNamespace(l2_c=0.0, l2_scale_by_batch=False)
@@ -81,10 +112,11 @@ def test_equals_binary_lr_grad_in_float32(rows, dim, chunk_tiles, slots):
     assert _rel(got, want) < 1e-6
 
 
+@pytest.mark.parametrize("slots", AHEAD)
 @pytest.mark.parametrize("l2_c,by_batch", [(0.5, False), (0.5, True)])
-def test_l2_term_is_the_models(l2_c, by_batch):
+def test_l2_term_is_the_models(l2_c, by_batch, slots):
     rows, dim = 16, 1000
-    plan = panel_plan(rows, dim)
+    plan = _four_chunks(rows, slots)
     w, X, y, mask = _problem(rows, dim, seed=1, masked=5)
     cfg = types.SimpleNamespace(l2_c=l2_c, l2_scale_by_batch=by_batch)
     model = _float32_model(dim)
@@ -99,9 +131,10 @@ def test_l2_term_is_the_models(l2_c, by_batch):
     assert _rel(want, bare) > 1e-2   # the term is there to be missed
 
 
-def test_masked_rows_contribute_nothing():
+@pytest.mark.parametrize("slots", AHEAD)
+def test_masked_rows_contribute_nothing(slots):
     rows, dim = 16, 1000
-    plan = panel_plan(rows, dim)
+    plan = _four_chunks(rows, slots)
     w, X, y, mask = _problem(rows, dim, seed=2, masked=6)
     garbage = X.at[-6:].set(1e6)
     a = lr_grad_panels(w, pad_columns(X, plan), y, mask, plan, interpret=True)
@@ -110,11 +143,12 @@ def test_masked_rows_contribute_nothing():
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_pad_columns_never_reach_the_gradient():
+@pytest.mark.parametrize("slots", AHEAD)
+def test_pad_columns_never_reach_the_gradient(slots):
     """Whatever stands in the pad columns of X moves nothing in
     ``g[:D]``: they meet zero weights forward and are cut backward."""
     rows, dim = 16, 1000
-    plan = panel_plan(rows, dim)
+    plan = _four_chunks(rows, slots)
     w, X, y, mask = _problem(rows, dim, seed=3)
     Xp = pad_columns(X, plan)
     assert Xp.shape == (rows, plan.dim_padded)
@@ -125,9 +159,10 @@ def test_pad_columns_never_reach_the_gradient():
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_feature_scale_is_the_models():
+@pytest.mark.parametrize("slots", AHEAD)
+def test_feature_scale_is_the_models(slots):
     rows, dim = 16, 1000
-    plan = panel_plan(rows, dim)
+    plan = _four_chunks(rows, slots)
     w, X, y, mask = _problem(rows, dim, seed=4)
     cfg = types.SimpleNamespace(l2_c=0.1, l2_scale_by_batch=False)
     model = BinaryLR(dim, compute_dtype="float32", feature_scale=0.25)
@@ -140,13 +175,31 @@ def test_feature_scale_is_the_models():
 
 def test_the_plan_follows_the_shape_and_the_limit():
     cell = panel_plan(384, 1_000_000)
-    assert cell == PanelPlan(384, 1_000_000, 253, 31, 31,
+    assert cell == PanelPlan(384, 1_000_000, 253, 31, 31, 31,
                              pallas_lr.VMEM_LIMIT_BYTES)
-    assert cell.held_share == 1.0 and cell.slots == 31
+    assert cell.held_share == cell.ahead_share == 1.0 and cell.slots == 62
     assert cell.dim_padded == 31 * 253 * 128 == 1_003_904
-    # half of VMEM: the partials, the weights and a part of a panel
+    # two banks of 31 slots of 8 x 32,384 floats; beside them the eight
+    # partials, the gradient a tile a row, the weights, the slack
+    assert cell.slot_bytes == 8 * 253 * 128 * 4
+    assert cell.fixed_bytes == (8 * 1_003_904 * 4 + 7848 * 128 * 4
+                                + 31 * 256 * 128 * 4 + (4 << 20))
+    assert cell.vmem_bytes == cell.fixed_bytes + 62 * cell.slot_bytes
+    # 103.6 MiB of the 120
+    assert cell.vmem_bytes == 108_650_496 < pallas_lr.VMEM_LIMIT_BYTES
+    # the rows do not move the plan: a window's, the four-chip cell's
+    for rows in (128, 1152):
+        assert panel_plan(rows, 1_000_000) == dataclasses.replace(
+            cell, rows=rows)
+    # a limit between one bank and two: the slots that fit, all ahead
+    some = panel_plan(384, 1_000_000, vmem_limit=96 << 20)
+    assert some.held == 31 and 0 < some.ahead < 31
+    assert some.vmem_bytes <= 96 << 20 < some.vmem_bytes + some.slot_bytes
+    # half of VMEM: the partials, the weights and a part of a panel,
+    # nothing ahead, the ring of two as before
     half = panel_plan(384, 1_000_000, vmem_limit=64 << 20)
     assert 0 < half.held_share < 1 and half.slots == half.held + 2
+    assert half.ahead == 0 and half.ahead_share == 0.0
     # no room beside the partials, or rows that are no sublane groups
     assert panel_plan(384, 1_000_000, vmem_limit=40 << 20) is None
     assert panel_plan(380, 1_000_000) is None
@@ -154,9 +207,14 @@ def test_the_plan_follows_the_shape_and_the_limit():
 
 
 # -- a window of a taller resident matrix, from a first row -----------------
-# R, B, D, chunk_tiles, slots of VMEM: all of a panel held, and a part
+# R, B, D, chunk_tiles, slots of VMEM: a panel held and a bank, a part of a
+# bank or nothing ahead of it; a part of a panel held
 WINDOWS = [
     pytest.param(48, 16, 1000, 256, None, id="16-of-48-D1000"),
+    pytest.param(72, 24, 16384 + 64, 8, None,
+                 id="24-of-72-D16448-a-bank-ahead"),
+    pytest.param(72, 24, 16384 + 64, 8, 22, id="24-of-72-D16448-5-ahead"),
+    pytest.param(72, 24, 16384 + 64, 8, 17, id="24-of-72-D16448-none-ahead"),
     pytest.param(72, 24, 16384 + 64, 8, 10, id="24-of-72-D16448-8-of-17-held"),
 ]
 
@@ -166,10 +224,8 @@ WINDOWS = [
 def test_a_window_from_a_first_row_is_the_models_grad_on_the_sliced_rows(
         R, B, dim, chunk_tiles, slots, where):
     first = {"0": 0, "8": 8, "R-B": R - B}[where]
-    limit = (pallas_lr.VMEM_LIMIT_BYTES if slots is None
-             else _limit_for(B, dim, chunk_tiles, slots))
-    plan = panel_plan(B, dim, vmem_limit=limit, chunk_tiles=chunk_tiles)
-    assert plan.rows == B and (plan.held_share == 1.0) == (slots is None)
+    plan = _plan(B, dim, chunk_tiles, slots)
+    assert plan.rows == B
     w, X, y, mask = _problem(R, dim, seed=5, masked=3)
     cfg = types.SimpleNamespace(l2_c=0.2, l2_scale_by_batch=False)
     model = _float32_model(dim)
@@ -240,6 +296,19 @@ def chips():
     return [SingleDeviceSharding(d) for d in topo.devices]
 
 
+def _no_partials_leave_the_kernel(text, plan):
+    """The eight sublane partials are summed in VMEM: the step's program
+    holds no ``f32[8, Dp]`` result, so no reduction over one either, and
+    the kernel's result is the gradient once, a tile a row."""
+    assert f"f32[8,{plan.dim_padded}]" not in text
+    call, = (ln for ln in text.splitlines() if "tpu_custom_call" in ln)
+    assert f" = f32[{plan.dim_padded // 128},128]" in call, call
+    for line in text.splitlines():
+        if re.match(r"\s*(ROOT )?%\S+ = \S+ reduce\(", line):
+            # what is reduced is a scalar's worth: the mask's count
+            assert re.search(r"= [fs]32\[\]\S* reduce\(", line), line
+
+
 @pytest.mark.parametrize("limit,rows,chip", [
     (None, 384, 0), (64 << 20, 384, 0), (None, 1152, 2)],
     ids=["all-held", "part-held", "four-chip-cells-shard-on-chip-2"])
@@ -248,7 +317,8 @@ def test_compiles_for_a_v5e_at_the_cells_size(chips, limit, rows, chip):
     default limit and under one that holds part of a panel, and at the
     1,152 rows a worker of the four-chip cell keeps, for a chip that is
     not the first; XLA hands it the resident shard as it lies: no copy,
-    transpose or reshape of the operand in the step's program."""
+    transpose or reshape of the operand in the step's program, and gets
+    the gradient back once."""
     one_chip, dim = chips[chip], 1_000_000
     plan = (panel_plan(rows, dim) if limit is None
             else panel_plan(rows, dim, vmem_limit=limit))
@@ -271,7 +341,8 @@ def test_compiles_for_a_v5e_at_the_cells_size(chips, limit, rows, chip):
         if (big in line and re.match(r"\s*(ROOT )?%\S+ = ", line)
                 and "custom-call(" not in line):
             assert " parameter(" in line, line
-    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    _no_partials_leave_the_kernel(text, plan)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
 def test_a_window_compiles_for_a_v5e_as_one_read_of_the_rows_where_they_lie(
@@ -306,7 +377,8 @@ def test_a_window_compiles_for_a_v5e_as_one_read_of_the_rows_where_they_lie(
                 and "custom-call(" not in line):
             assert " parameter(" in line, line
     assert f"f32[{B},{plan.dim_padded}]" not in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    _no_partials_leave_the_kernel(text, plan)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
 # -- the forward alone: a PS worker's eval over its resident test rows -------

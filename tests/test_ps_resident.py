@@ -395,6 +395,8 @@ def test_a_resident_shard_takes_the_one_pass_step(tmp_path, monkeypatch,
             plan = w._panels
             assert parent._panels is None and plan is not None
             assert (plan.rows, plan.dim, plan.held_share) == (96, 300, 1.0)
+            # a whole second bank of slots: the next panel fetched ahead
+            assert plan.ahead == plan.chunks and plan.slots == 2 * plan.chunks
             X = w._resident[0]
             assert X.shape == (96, plan.dim_padded) and X.dtype == np.float32
             assert not np.asarray(X[:, 300:]).any()
@@ -407,6 +409,8 @@ def test_a_resident_shard_takes_the_one_pass_step(tmp_path, monkeypatch,
                     == _shard_bytes(w))
             assert reg.get("distlr_ps_grad_panel_held").labels(
                 rank="0").value == 1.0
+            assert reg.get("distlr_ps_grad_panel_ahead").labels(
+                rank="0").value == plan.ahead_share == 1.0
             was = _rounds(0, "one_pass"), _rounds(0, "two_pass")
             w.grad_step = _Recorder(w.grad_step)
             w.run(save=False)
@@ -456,8 +460,9 @@ def test_the_selection_keeps_the_xla_step(tmp_path, one_pass_on_the_cpu,
     assert np.isfinite(final).all() and np.count_nonzero(final)
     assert _rounds(0, "one_pass") == was[0]
     assert _rounds(0, "two_pass") - was[1] == w.rounds > 0
-    assert get_registry().get("distlr_ps_grad_panel_held").labels(
-        rank="0").value == 0.0
+    for share in ("held", "ahead"):
+        assert get_registry().get(f"distlr_ps_grad_panel_{share}").labels(
+            rank="0").value == 0.0
 
 
 def test_the_selection_reads_the_model_the_device_and_the_shape():
