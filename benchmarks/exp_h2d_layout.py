@@ -22,8 +22,14 @@ the transposes, the DMA and what runs on the device:
                 program on the device: the step sees what it sees today.
 
 Each way's array is checked equal to ``default``'s, and the product's
-own train step is timed on it.  Exits non-zero without a TPU (``--smoke``
-runs tiny shapes anywhere and says nothing about a rate).
+own train step is timed on it.  Then the stream: ``--stream`` batches
+back to back through one ``feed.Pacer``, as the sync trainer's producer
+puts them, with ``feed.AHEAD`` at 1, 2 and 3: the
+link's rate from the first put to the last batch restored says which
+``AHEAD`` is the smallest at which the link never waits for the host
+(``--piece-mb`` moves ``feed._PIECE_BYTES`` for the whole run).  Exits
+non-zero without a TPU (``--smoke`` runs tiny shapes anywhere and says
+nothing about a rate).
 
 Run on the chip: python benchmarks/exp_h2d_layout.py [--rows 768]
 """
@@ -36,6 +42,7 @@ import json
 import os
 import sys
 import tempfile
+import threading
 import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -138,6 +145,26 @@ def read_trace(trace_dir: str) -> dict:
     return out
 
 
+def stream(mesh, x, batches: int, ahead: int) -> dict:
+    """``batches`` copies of ``x`` placed back to back by one thread,
+    paced ``ahead`` pieces ahead of the link, each let go when the next
+    is placed."""
+    shipped, feed.AHEAD = feed.AHEAD, ahead  # the constant under test
+    try:
+        pacer = feed.Pacer(threading.Event())
+        t0, last = time.perf_counter(), None
+        for _ in range(batches):
+            last = feed.place(x, mesh, pacer)
+        t1 = time.perf_counter()
+        jax.block_until_ready(last)
+        t2 = time.perf_counter()
+    finally:
+        feed.AHEAD = shipped
+    return {"ahead": ahead, "batches": batches,
+            "put_ms_a_batch": (t1 - t0) * 1e3 / batches,
+            "gb_per_s": batches * x.nbytes / 1e9 / (t2 - t0)}
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=768)
@@ -147,7 +174,13 @@ def main():
                     help="tiny shapes on any backend; no rate means anything")
     ap.add_argument("--keep-trace", default=None,
                     help="directory to keep the .xplane.pb in")
+    ap.add_argument("--stream", type=int, default=8,
+                    help="batches a reading of the paced stream")
+    ap.add_argument("--piece-mb", type=int, default=None,
+                    help="feed._PIECE_BYTES for this run, in MiB")
     args = ap.parse_args()
+    if args.piece_mb:
+        feed._PIECE_BYTES = args.piece_mb << 20
     dev = start_benchmark("exp_h2d_layout.py", full_size=not args.smoke)
     rows, dim = (16, 4096) if args.smoke else (args.rows, args.dim)
 
@@ -243,6 +276,12 @@ def main():
             "gb_per_s_put_to_ready": gb / (min(ready) * 1e-3),
             "trace": {k: v for k, v in traced.items()
                       if k.split(".")[0] == name}}))
+    # the stream: one producer, batch after batch, at each AHEAD
+    plan = feed._plan(x, mesh)
+    for ahead in (1, 2, 3):
+        print(json.dumps({"stream": stream(mesh, x, args.stream, ahead),
+                          "pieces": plan.pieces if plan else None,
+                          "shipped_ahead": feed.AHEAD}))
     # the other dense feature dtypes, at the test batch's rows: the
     # product's way against the plain put, bit for bit
     for dtype in (np.int8, np.float32):

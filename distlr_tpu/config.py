@@ -256,11 +256,14 @@ class Config:
 
     # ---- input pipeline ----
     # Host->device streaming depth in Trainer.fit: with prefetch=N, up
-    # to N-1 batches are host-sliced and device_put ahead of the running
-    # step from a background thread (double buffering at 2 — the
-    # trajectory is identical, only the host work overlaps the device
-    # step).  1 = strictly serial (the reference's DataIter shape,
-    # include/data_iter.h:40-55).
+    # to N-1 batches are host-sliced and handed to device_put ahead of
+    # the running step by one background thread a fit, which goes on
+    # over every epoch's end (double buffering at 2 — the trajectory is
+    # identical, only the host work overlaps the device step).  It says
+    # how many batches the device holds, not when their bytes cross: a
+    # large dense batch is put piece by piece, each when the link has
+    # room for it (parallel/feed.py).  1 = strictly serial (the
+    # reference's DataIter shape, include/data_iter.h:40-55).
     prefetch: int = 2
 
     # ---- checkpoint / obs ----

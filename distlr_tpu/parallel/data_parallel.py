@@ -111,7 +111,7 @@ def make_eval_step(model, mesh: Mesh):
     return jax.jit(evaluate)
 
 
-def shard_batch(batch, mesh: Mesh):
+def shard_batch(batch, mesh: Mesh, pacer=None):
     """Place a host batch pytree onto the mesh, sharded over ``data``.
 
     Host->HBM streaming: the successor of the reference's per-step
@@ -128,5 +128,6 @@ def shard_batch(batch, mesh: Mesh):
     host holds and restores shape, dtype and layout on the device; every
     other leaf is put as it always was.  Either way a leaf comes back
     with the values, shape, dtype and ``P(data)`` sharding of a plain
-    ``device_put``."""
-    return jax.tree.map(lambda x: feed.place(x, mesh), batch)
+    ``device_put``.  ``pacer`` is a streaming caller's ``feed.Pacer``:
+    the large leaf's pieces are then put in their turn at the link."""
+    return jax.tree.map(lambda x: feed.place(x, mesh, pacer), batch)

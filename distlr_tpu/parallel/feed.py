@@ -15,12 +15,13 @@ transpose of 1.5 GB at 5-7 GB/s, in series with the 108 ms DMA.
 holds: the array's buffer viewed as ``(n, 128)`` 32-bit words, whose
 device layout is the host's own order, so the runtime's relayout is a
 straight copy (39 ms for 1.5 GB where the transpose took 190), in
-pieces, so that a piece's DMA runs while the next is copied and the
-link is never idle (the trace still names the copy's chunks
-``Transpose::ExecuteChunk``: the runtime's one relayout routine).  ``(rows, D)``, the dtype and the layout the step's
-kernels were compiled for are restored on the device, at HBM speed, by
-a small program of the feed's own (``jit_feed_restore``), dispatched by
-the thread that did the put.  What the caller gets back is equal in
+pieces, so that a piece's DMA runs while the next is copied (the trace
+still names the copy's chunks ``Transpose::ExecuteChunk``: the
+runtime's one relayout routine).  ``(rows, D)``, the dtype and the
+layout the step's kernels were compiled for are restored on the device,
+at HBM speed, by a small program of the feed's own
+(``jit_feed_restore``), dispatched by the thread that did the put.
+What the caller gets back is equal in
 values, shape, dtype and sharding to a plain ``device_put``, and so in
 the device's default layout (for ``[rows, 1000000]`` the rows in the
 lanes): a caller that wants the columns there, as a PS worker's resident
@@ -35,13 +36,30 @@ is), with rows and row bytes that the word view divides.  Everything
 else (labels, masks, sparse leaves, device arrays, the CPU) takes the
 plain ``device_put``.  ``distlr_h2d_bytes_total{layout}`` says which
 way each byte went.
+
+*When* a piece is handed over is the caller's: the runtime starts DMAs
+in the order they were put, one at a time, and a program's launch goes
+through that same queue, twice (its argument table is a small copy of
+its own, behind every copy queued when the program is dispatched with
+its inputs ready; the execution is then sequenced behind the copy in
+flight: PERF.md section 5).  ``place(x, mesh)`` puts a leaf's pieces one
+after the other without a pause, which is right for a leaf placed once
+(a PS worker's shard, a test split).  A caller that streams batch after
+batch, the sync trainer's producer thread, passes the :class:`Pacer` it
+owns: a piece is then put only when the piece ``AHEAD`` before it, of
+this leaf or of the batch before, has landed, so the queue a launch
+waits behind is ``AHEAD`` pieces long and never a batch, and never
+empty either.  The pacer engages where the plan does and does nothing
+for a plain ``device_put``, whose copies are megabytes.
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +68,7 @@ from jax.experimental.layout import Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distlr_tpu.obs.registry import get_registry
+from distlr_tpu.obs.tracing import loop_span
 from distlr_tpu.parallel.mesh import DATA_AXIS
 
 _H2D_BYTES = get_registry().counter(
@@ -60,6 +79,15 @@ _H2D_BYTES = get_registry().counter(
     "host threads when the device's layout differs",
     labelnames=("layout",),
 )
+_PACED_PIECES = get_registry().counter(
+    "distlr_h2d_paced_pieces_total",
+    "pieces of sync batches put through the producer's pacer "
+    "(parallel/feed.py), by whether the producer had to wait for an "
+    "earlier piece to land first: a share of no near 1 says the host is "
+    "behind the link (AHEAD too small, or the relayout too slow), near 0 "
+    "that the link sets the pace",
+    labelnames=("waited",),
+)
 
 #: A leaf under this size keeps the plain ``device_put``.  The restore
 #: program costs a dispatch, a launch (1.2 ms on the v5e) and 8 us a
@@ -68,12 +96,31 @@ _H2D_BYTES = get_registry().counter(
 #: launch is most of what there is to win, and a device-bound step (the
 #: sparse families: 10 MB leaves, the chip 91% busy) would pay for it.
 AS_HELD_MIN_BYTES = 32 << 20
-#: Size of the pieces a leaf is handed over in.  A piece's DMA is issued
-#: when the whole piece is relaid, so the first DMA starts after one
-#: piece's copy (about 10 ms at this size) and not after the whole
-#: leaf's (39 ms for 1.5 GB); the restore kernel is unrolled over the
-#: pieces, so more of them buy little and compile slower.
+#: Size of the pieces a leaf is handed over in (the most; the plan takes
+#: the count that divides the shard's tiles: six of 256 MB for
+#: ``bf16[768, 1000000]``).  A piece's DMA is issued when the whole piece
+#: is relaid, so the first DMA starts after one piece's copy (7 ms at
+#: this size) and not after the whole leaf's (39 ms for 1.5 GB); under a
+#: :class:`Pacer` a launch waits behind ``AHEAD + 1`` pieces, 18 ms each;
+#: the restore kernel is unrolled over the pieces, so more of them
+#: compile slower.  Twelve of 128 MB (the v5e, PERF.md section 6) carry
+#: the same bytes a second, halve a launch's wait (a step's ``compute``
+#: span 27 ms for 46) and double the producer's wake-ups; the link is the
+#: wall either way, so the size stayed.
 _PIECE_BYTES = 192 << 20
+#: Pieces a :class:`Pacer` lets stand between the producer and the link:
+#: piece *k* is put when piece *k - AHEAD* has landed.  The smallest
+#: value at which the link never waits for the host: a piece's relayout
+#: (7 ms) has to run under the DMA of the piece before it (18 ms), so
+#: one piece must still be queued while the next is relaid.  Every piece
+#: more is 36 ms that a step's launch waits and a sixth of a batch more
+#: on the device.  Read on the v5e, 768 x 1M bfloat16 a batch, six
+#: pieces (PERF.md section 6; samples/s, a step's ``compute`` span,
+#: ``memory_peak_bytes``): 1: 4,833, 19 ms, 5.15 GB (the link idle for
+#: every relayout: 25.4 ms a piece); **2: 7,050, 46 ms, 5.91 GB** (the
+#: link's own 7,080); 3: 7,082, 82 ms, 6.94 GB.  The bare stream, no
+#: consumer (``benchmarks/exp_h2d_layout.py``): 9.99, 13.80, 13.80 GB/s.
+AHEAD = 2
 _LANES = 128
 #: the dense feature dtypes (``Config.feature_dtype``)
 _DTYPES = (np.dtype(np.float32), np.dtype(jnp.bfloat16), np.dtype(np.int8))
@@ -252,9 +299,53 @@ def _restore_program(plan: _Plan):
         feed_restore, out_shardings=NamedSharding(plan.mesh, P(DATA_AXIS)))
 
 
-def place(x, mesh: Mesh):
+class Stopped(Exception):
+    """Out of a paced :func:`place`: the pacer's owner has given the fit
+    up, and the rest of the batch is not put."""
+
+
+class Pacer:
+    """A streaming producer's hold on the host link, for as long as it
+    streams (the sync trainer makes one a ``fit``, on its producer
+    thread; see the module's docstring for why).
+
+    It keeps the last ``AHEAD`` pieces it has put, oldest first, across
+    leaves and batches; before the next is put the oldest of them has
+    landed (``block_until_ready`` on the caller's thread, under an
+    ``h2d_pace`` span that carries ``step``) and is let go: the restore
+    program holds the pieces it needs.  ``stop`` set by then ends the
+    batch with :class:`Stopped`.  ``device_put`` is the runtime's; a
+    test hands in pieces whose landing it controls."""
+
+    def __init__(self, stop: threading.Event, device_put=jax.device_put):
+        self._stop = stop
+        self._device_put = device_put
+        self._sent: collections.deque = collections.deque()
+        #: the batch the next pieces belong to, set by the producer
+        self.step: int | None = None
+
+    def put(self, piece, sharding):
+        """``device_put(piece, sharding)``, in its turn."""
+        waited = "no"
+        if len(self._sent) >= AHEAD:
+            oldest = self._sent.popleft()
+            if not oldest.is_ready():
+                waited = "yes"
+                with loop_span("h2d_pace", self.step):
+                    oldest.block_until_ready()
+            del oldest  # let go before the next piece is allocated
+        if self._stop.is_set():
+            raise Stopped
+        _PACED_PIECES.labels(waited=waited).inc()
+        out = self._device_put(piece, sharding)
+        self._sent.append(out)
+        return out
+
+
+def place(x, mesh: Mesh, pacer: Pacer | None = None):
     """Put one leaf of a host batch on ``mesh``, sharded over ``data``
-    along its leading axis."""
+    along its leading axis.  With a ``pacer`` the pieces of a large
+    dense leaf are put in its turn; without one, at once."""
     sharding = NamedSharding(mesh, P(DATA_AXIS))
     plan = _plan(x, mesh)
     if plan is None:
@@ -265,7 +356,7 @@ def place(x, mesh: Mesh):
     words = x.reshape(-1).view(np.uint32).reshape(
         mesh.shape[DATA_AXIS], plan.pieces, -1, _LANES)
     by_device = NamedSharding(mesh, P(DATA_AXIS, None, None))
-    pieces = [jax.device_put(words[:, k], by_device)
-              for k in range(plan.pieces)]
+    put = jax.device_put if pacer is None else pacer.put
+    pieces = [put(words[:, k], by_device) for k in range(plan.pieces)]
     _H2D_BYTES.labels(layout="as_held").inc(x.nbytes)
     return _restore_program(plan)(*pieces)

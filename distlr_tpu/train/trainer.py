@@ -36,6 +36,7 @@ from distlr_tpu.models import get_model
 from distlr_tpu.obs import jaxrt
 from distlr_tpu.obs.tracing import loop_span as _loop_span, trace_phase
 from distlr_tpu.parallel import (
+    feed,
     make_eval_step,
     make_mesh,
     make_sync_train_step,
@@ -278,10 +279,10 @@ class GlobalShardedData:
 
 
 def _prefetch_to_device(shard_fn, host_batches, depth: int):
-    """Double-buffered host->device streaming: yield ``shard_fn(item)``
-    for each item of ``host_batches``, with up to ``depth`` of them
-    sliced + ``device_put`` ahead of the consumer, from a background
-    thread.
+    """Host->device streaming from ONE background thread for as long as
+    ``host_batches`` lasts (a whole ``fit``): yield ``shard_fn(item,
+    pacer)`` for each item, with up to ``depth`` of them sliced and
+    handed to ``device_put`` ahead of the consumer.
 
     The reference's ``DataIter`` role streams shards to the compute each
     epoch on the worker's own thread (``include/data_iter.h:16-35``);
@@ -296,23 +297,35 @@ def _prefetch_to_device(shard_fn, host_batches, depth: int):
     issues the DMA only when the whole array is relaid; DMAs run one at
     a time, 14 GB/s, in the order they were issued, and a program's
     launch waits behind every DMA queued when its inputs became ready.
-    So two batches put ahead are relaid at the same time, on the same
-    threads, and land one after the other.  ``shard_fn`` is the
-    trainer's ``_shard_batch``: for a large dense matrix it hands the
-    runtime the host's own bytes in pieces, so that the relayout is a
-    straight copy hidden under the previous piece's DMA, and dispatches
-    the small program that restores the matrix on the device
-    (``parallel/feed.py``), from this thread.
+    ``shard_fn`` is the trainer's ``put``: for a large dense matrix it
+    hands the runtime the host's own bytes in pieces, so that the
+    relayout is a straight copy hidden under the previous piece's DMA,
+    and dispatches the small program that restores the matrix on the
+    device (``parallel/feed.py``), from this thread.
 
-    The producer never waits for a transfer to land: how many copies are
-    in flight is set by ``depth`` alone, and the consumer does the
-    waiting (``h2d_wait`` in :meth:`Trainer.fit`).
+    ``depth`` says how many batches are alive ahead of the step (queued
+    here; one more in the producer's hand), so what the device holds at
+    most.  *When* their bytes are put is the pacer's, which this
+    producer owns from its first batch to its last (``feed.Pacer``): a
+    piece is put when the piece ``feed.AHEAD`` before it has landed,
+    across batches and epochs, so the link's queue is a piece or two
+    long and never empty.  Were a whole batch or two put at once (as
+    they were: 5,700 samples/s where the link carries 7,080), every
+    launch (the restore's, the step's) would wait for all of it, and all
+    the device's work would run in series after the last byte with the
+    link idle.  The consumer still waits for the batch it takes
+    (``h2d_wait`` in :meth:`Trainer.fit`); a batch is handed over when
+    its last piece is put, ``feed.AHEAD`` DMAs before it lands.
+
+    Closing the generator ends the thread before it returns: a consumer
+    that raises leaves no producer behind.
 
     Safe because :meth:`GlobalShardedData.batches` yields independent
     arrays (fancy-indexed / reshaped slices, never a reused buffer).
     """
     q: queue.Queue = queue.Queue(maxsize=depth)
     stop = threading.Event()
+    pacer = feed.Pacer(stop)
     end = object()
     errs: list[BaseException] = []
 
@@ -321,7 +334,9 @@ def _prefetch_to_device(shard_fn, host_batches, depth: int):
             for item in host_batches:
                 if stop.is_set():
                     return
-                q.put(shard_fn(item))
+                q.put(shard_fn(item, pacer))
+        except feed.Stopped:
+            return
         except BaseException as e:  # propagate to the consumer
             errs.append(e)
         q.put(end)
@@ -338,11 +353,14 @@ def _prefetch_to_device(shard_fn, host_batches, depth: int):
                 return
             yield item
     finally:
-        # Consumer may exit early (exception mid-epoch): unblock a
-        # producer stuck in q.put so the thread can observe `stop`.
+        # Consumer may exit early (exception mid-epoch): a producer
+        # stuck in q.put (of a batch, or of `end` behind one) is let go
+        # until it has observed `stop` and ended.
         stop.set()
-        with contextlib.suppress(queue.Empty):
-            q.get_nowait()
+        while t.is_alive():
+            with contextlib.suppress(queue.Empty):
+                q.get_nowait()
+            t.join(0.01)
 
 
 class Trainer:
@@ -399,12 +417,14 @@ class Trainer:
 
             self.train_step = make_feature_sharded_train_step(self.model, cfg, self.mesh)
             self.eval_step = make_feature_sharded_eval_step(self.model, self.mesh)
-            self._shard_batch = lambda b: shard_batch_2d(b, self.mesh)
+            # X is sharded over both axes: plain puts, nothing to pace
+            self._shard_batch = lambda b, pacer=None: shard_batch_2d(b, self.mesh)
             self._shard_weights = lambda w: shard_weights(w, self.mesh)
         else:
             self.train_step = make_sync_train_step(self.model, cfg, self.mesh)
             self.eval_step = make_eval_step(self.model, self.mesh)
-            self._shard_batch = lambda b: shard_batch(b, self.mesh)
+            self._shard_batch = lambda b, pacer=None: shard_batch(
+                b, self.mesh, pacer)
             self._shard_weights = lambda w: jax.device_put(
                 w, jax.sharding.NamedSharding(self.mesh, jax.sharding.PartitionSpec())
             )
@@ -591,77 +611,85 @@ class Trainer:
             if ckpt is not None:
                 stack.callback(ckpt.close)
 
-            def sliced(host_iter, ids):
-                """``(step id, host batch)``: ``batch_slice`` is the numpy
-                slice, pad and reshape of the next batch (a view on one
-                chip, a copy on a mesh), on whichever thread pulls."""
-                for n in ids:
-                    with _loop_span("batch_slice", n):
-                        hb = next(host_iter)
-                    yield n, hb
-
-            def put(item):
-                n, hb = item
-                # h2d is the host's synchronous part of device_put: the
-                # call returns with the copy still in flight, and the
-                # rest of it is waited for in the consumer's h2d_wait
-                with _loop_span("h2d", n):
-                    return hb, self._shard_batch(hb)
-
             # an epoch's batches are counted ahead, so that no thread
             # pulls once more to find the epoch over: every span of the
             # loop belongs to a batch, and no step id is used twice
             steps_per_epoch = self._train_data.num_batches(cfg.batch_size)
+
+            def host_batches(n):
+                """``(step id, host batch)`` of every epoch this call
+                runs, ids from ``n`` on: ``batch_slice`` is the numpy
+                slice, pad and reshape of the next batch (a view on one
+                chip, a copy on a mesh), on whichever thread pulls."""
+                for _ in range(start_epoch, epochs):
+                    epoch_batches = self._train_data.batches(
+                        cfg.batch_size, wrap=bool(cfg.wrap_final_batch))
+                    for _ in range(steps_per_epoch):
+                        with _loop_span("batch_slice", n):
+                            hb = next(epoch_batches)
+                        yield n, hb
+                        n += 1
+
+            def put(item, pacer=None):
+                n, hb = item
+                # h2d is the host's part of the puts, on the producer's
+                # thread its waits for its turn at the link included
+                # (h2d_pace); the calls return with the copies still in
+                # flight, and the rest is waited for in the consumer's
+                # h2d_wait
+                with _loop_span("h2d", n):
+                    if pacer is not None:
+                        pacer.step = n
+                    return hb, self._shard_batch(hb, pacer)
+
+            if cfg.prefetch > 1:
+                # ONE producer for the whole call: it goes from an
+                # epoch's last batch to the next epoch's first as from
+                # any batch to the next, under an eval or a checkpoint
+                # too.  batch_slice and h2d land on its timeline under
+                # the step id of the batch they make — the trace shows
+                # the overlap the prefetch buys
+                pairs = _prefetch_to_device(
+                    put, host_batches(self.batches_taken), cfg.prefetch - 1)
+            else:  # prefetch=1: the strictly-serial reference shape
+                pairs = (put(item)
+                         for item in host_batches(self.batches_taken))
+            # closing() runs the generator's finally DETERMINISTICALLY
+            # when a step raises — relying on GC leaves the producer
+            # thread blocked on the queue for as long as the caller
+            # retains the exception traceback (which run_ps_workers
+            # does), and a retried fit() would stack a second
+            # producer on top.
+            stack.enter_context(contextlib.closing(pairs))
             for epoch in range(start_epoch, epochs):
-                ids = range(self.batches_taken,
-                            self.batches_taken + steps_per_epoch)
-                host_batches = sliced(
-                    self._train_data.batches(
-                        cfg.batch_size, wrap=bool(cfg.wrap_final_batch)),
-                    ids,
-                )
-                if cfg.prefetch > 1:
-                    # batch_slice and h2d land on the producer thread's
-                    # timeline under the step id of the batch they make —
-                    # the trace shows the overlap the prefetch buys
-                    pairs = _prefetch_to_device(
-                        put, host_batches, cfg.prefetch - 1
-                    )
-                else:  # prefetch=1: the strictly-serial reference shape
-                    pairs = (put(item) for item in host_batches)
-                # closing() runs the generator's finally DETERMINISTICALLY
-                # when a step raises — relying on GC leaves the producer
-                # thread blocked on the queue for as long as the caller
-                # retains the exception traceback (which run_ps_workers
-                # does), and a retried fit() would stack a second
-                # producer on top.
-                with contextlib.closing(pairs):
-                    it = iter(pairs)
-                    for n in ids:
-                        # data_load = time this consumer spent WAITING for
-                        # the next batch to be ON THE DEVICE: queue_wait
-                        # until the producer hands it over (with
-                        # prefetch=1, the slice and the dispatch
-                        # themselves), h2d_wait until its copy has landed.
-                        with _loop_span("data_load", n):
-                            with _loop_span("queue_wait", n):
-                                host_batch, batch = next(it)
-                            with _loop_span("h2d_wait", n):
-                                jax.block_until_ready(batch)
-                        self.batches_taken = n + 1
-                        # compute = dispatch of the step to its weights
-                        # being ready, with the step's own batch resident.
-                        # That is the step PLUS whatever the launch waits
-                        # for on the device's side: on the TPU runtime a
-                        # launch queues behind the copies put before it
-                        # (the batches the producer is ahead by), so with
-                        # copies in flight part of the wait for input is
-                        # still in here (PERF.md section 5)
-                        self.timer.start()
-                        with _loop_span("compute", n, marks_step=True):
-                            self.weights, step_metrics = self.train_step(self.weights, batch)
-                            jax.block_until_ready(self.weights)
-                        self.timer.stop(int(host_batch[-1].sum()))
+                for n in range(self.batches_taken,
+                               self.batches_taken + steps_per_epoch):
+                    # data_load = time this consumer spent WAITING for
+                    # the next batch to be ON THE DEVICE: queue_wait
+                    # until the producer hands it over (with
+                    # prefetch=1, the slice and the dispatch
+                    # themselves), h2d_wait until its copy has landed.
+                    with _loop_span("data_load", n):
+                        with _loop_span("queue_wait", n):
+                            host_batch, batch = next(pairs)
+                        with _loop_span("h2d_wait", n):
+                            jax.block_until_ready(batch)
+                    self.batches_taken = n + 1
+                    # compute = dispatch of the step to its weights
+                    # being ready, with the step's own batch resident.
+                    # That is the step PLUS whatever the launch waits
+                    # for on the device's side: on the TPU runtime a
+                    # launch queues behind the copies put before it
+                    # (the pieces the producer is ahead by and the one
+                    # in flight, with the link busy under them: 42 of
+                    # this span's 46 ms in the dense cell), so with
+                    # copies in flight part of the wait for input is
+                    # still in here (PERF.md section 5)
+                    self.timer.start()
+                    with _loop_span("compute", n, marks_step=True):
+                        self.weights, step_metrics = self.train_step(self.weights, batch)
+                        jax.block_until_ready(self.weights)
+                    self.timer.stop(int(host_batch[-1].sum()))
                 if test_batch is not None and cfg.test_interval > 0 and (epoch + 1) % cfg.test_interval == 0:
                     with _loop_span("eval"):
                         em = self.eval_step(self.weights, test_batch)

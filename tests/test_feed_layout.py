@@ -11,6 +11,10 @@ Whatever way a leaf went, what ``_shard_batch`` returns is a plain
 ``distlr_h2d_bytes_total`` says which way it went.
 """
 
+import gc
+import threading
+import weakref
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,6 +25,7 @@ from distlr_tpu import Config
 from distlr_tpu.data.hashing import write_ctr_shards, write_raw_ctr_shards
 from distlr_tpu.obs import jaxrt
 from distlr_tpu.obs.registry import get_registry
+from distlr_tpu.obs.tracing import get_tracer
 from distlr_tpu.parallel import feed
 from distlr_tpu.train import Trainer
 from distlr_tpu.train.trainer import GlobalShardedData
@@ -211,3 +216,201 @@ def test_the_restore_program_is_not_taken_for_a_step(engaged):
         (1, x.nbytes // 4 // 128 // plan.pieces, 128), jnp.uint32)
     text = feed._restore_program(plan).lower(*[piece] * plan.pieces).as_text()
     assert "module @jit_feed_restore" in text
+
+
+# -- the producer's pacer (ISSUE 42) ---------------------------------------
+
+def _paced() -> dict:
+    fam = get_registry().snapshot().get("distlr_h2d_paced_pieces_total", {})
+    got = {s["labels"]["waited"]: s["value"] for s in fam.get("series", [])}
+    return {"yes": got.get("yes", 0.0), "no": got.get("no", 0.0)}
+
+
+def _paced_since(before: dict) -> dict:
+    return {k: v - before[k] for k, v in _paced().items()}
+
+
+class _Piece:
+    """What a ``device_put`` returns, landing when the test says."""
+
+    def __init__(self, landed, asked):
+        self._landed, self._asked = landed, asked
+
+    def is_ready(self):
+        return self._landed.is_set()
+
+    def block_until_ready(self):
+        self._asked.set()
+        assert self._landed.wait(10), "the test never let the piece land"
+        return self
+
+
+class _Link:
+    """Stands in ``jax.device_put`` for a :class:`feed.Pacer`: keeps, a
+    piece put, the event that lands it, the event that says the pacer
+    waits for it, and a weak reference to the piece itself."""
+
+    def __init__(self):
+        self.landed, self.asked, self.refs = [], [], []
+
+    def __call__(self, piece, sharding):
+        self.landed.append(threading.Event())
+        self.asked.append(threading.Event())
+        out = _Piece(self.landed[-1], self.asked[-1])
+        self.refs.append(weakref.ref(out))
+        return out
+
+
+def _produce(pacer, batches, pieces, done):
+    """A producer's puts: ``batches`` leaves of ``pieces`` pieces each."""
+    try:
+        for n in range(batches):
+            pacer.step = 100 + n
+            for k in range(pieces):
+                pacer.put((n, k), None)
+    except feed.Stopped:
+        done.append("stopped")
+    else:
+        done.append("all put")
+
+
+@pytest.mark.parametrize("batches,pieces", [(2, 4), (3, 1), (1, 6), (2, 3)])
+def test_the_pacer_keeps_ahead_pieces_between_the_producer_and_the_link(
+        batches, pieces):
+    """Piece *k* is put when piece *k - AHEAD* has landed, whichever
+    batch that one belongs to; a landed piece is let go at once."""
+    link, done, total = _Link(), [], batches * pieces
+    pacer = feed.Pacer(threading.Event(), device_put=link)
+    before = _paced()
+    get_tracer().reset()
+    t = threading.Thread(target=_produce, args=(pacer, batches, pieces, done))
+    t.start()
+    for i in range(max(total - feed.AHEAD, 0)):
+        assert link.asked[i].wait(10), f"no wait for piece {i}"
+        # the producer stands at piece i: AHEAD are put beyond the landed
+        # ones, this batch's or the next's, and not one more
+        assert len(link.landed) == i + feed.AHEAD
+        gc.collect()
+        assert [r() is None for r in link.refs[:i]] == [True] * i
+        link.landed[i].set()
+    t.join(10)
+    assert not t.is_alive() and done == ["all put"]
+    assert len(link.landed) == total
+    waits = max(total - feed.AHEAD, 0)
+    assert _paced_since(before) == {"yes": waits, "no": total - waits}
+    # every wait is a span, under the id of the batch whose piece waits
+    spans = [e for e in get_tracer().chrome_trace()["traceEvents"]
+             if e["name"] == "h2d_pace"]
+    assert [e["args"]["step"] for e in spans] == [
+        100 + (i + feed.AHEAD) // pieces for i in range(waits)]
+    # what is still held is the last AHEAD pieces, until the pacer goes
+    gc.collect()
+    held = min(feed.AHEAD, total)
+    assert [r() is None for r in link.refs] == (
+        [True] * (total - held) + [False] * held)
+    del pacer
+    gc.collect()
+    assert all(r() is None for r in link.refs)
+
+
+def test_a_piece_that_has_landed_is_not_waited_for():
+    link, done = _Link(), []
+
+    def landing(piece, sharding):
+        out = link(piece, sharding)
+        link.landed[-1].set()
+        return out
+
+    pacer = feed.Pacer(threading.Event(), device_put=landing)
+    before = _paced()
+    get_tracer().reset()
+    _produce(pacer, 2, 5, done)
+    assert done == ["all put"]
+    assert _paced_since(before) == {"yes": 0, "no": 10}
+    assert not any(a.is_set() for a in link.asked)
+    assert "h2d_pace" not in get_tracer().breakdown()
+
+
+@pytest.mark.parametrize("when", ["while it waits", "before a put"])
+def test_the_pacer_gives_the_batch_up_when_its_owner_stops(when):
+    link, done, stop = _Link(), [], threading.Event()
+    pacer = feed.Pacer(stop, device_put=link)
+    if when == "before a put":
+        stop.set()
+        _produce(pacer, 1, 4, done)
+        assert done == ["stopped"] and link.landed == []
+        return
+    t = threading.Thread(target=_produce, args=(pacer, 2, 4, done))
+    t.start()
+    assert link.asked[0].wait(10)
+    stop.set()
+    link.landed[0].set()
+    t.join(10)
+    assert not t.is_alive() and done == ["stopped"]
+    assert len(link.landed) == feed.AHEAD  # nothing was put after the stop
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("n_dev", [1, 4, 8])
+def test_a_paced_place_is_a_plain_device_put(engaged, n_dev, dtype):
+    """Through the trainer's ``_shard_batch`` with a pacer, as the
+    producer calls it: two batches and the test split through ONE pacer
+    come back as plain puts, and every piece went through it."""
+    tr = _dense_trainer(n_dev, dtype, ROWS + 40)
+    batches = [*tr._train_data.batches(ROWS), tr._test_data.full_batch()]
+    pacer = feed.Pacer(threading.Event())
+    for hb in batches:
+        pieces = feed._plan(hb[0], tr.mesh).pieces
+        assert pieces >= 2  # int8: two tiles a shard, so two pieces
+        before, handed = _paced(), _handed()
+        placed = tr._shard_batch(hb, pacer)
+        _same_as_plain_put(tr, placed, hb)
+        assert sum(_paced_since(before).values()) == pieces
+        assert _since(handed) == {
+            "as_held": hb[0].nbytes,
+            "default": sum(leaf.nbytes for leaf in hb[1:])}
+
+
+def test_a_pacer_does_nothing_for_a_plain_put(tpu_layouts):
+    """Where the plan does not engage (here: a leaf under the size that
+    pays for a program) the pacer is not asked."""
+    mesh = jax.make_mesh((1,), ("data",))
+    x = np.ones((ROWS, DIM), np.float32)
+
+    def refuse(piece, sharding):
+        raise AssertionError("a plain put went through the pacer")
+
+    before = _paced()
+    got = feed.place(x, mesh, feed.Pacer(threading.Event(), refuse))
+    assert bool(jnp.array_equal(got, x))
+    assert _paced_since(before) == {"yes": 0, "no": 0}
+
+
+def test_place_without_a_pacer_puts_its_pieces_at_once(engaged, monkeypatch):
+    """The PS workers' shards and test splits: the calls ``place`` made
+    before there was a pacer, in their order, and nothing of the pacer's."""
+    mesh = jax.make_mesh((1,), ("data",))
+    x = np.random.default_rng(2).normal(size=(ROWS, DIM)).astype(np.float32)
+    plan = feed._plan(x, mesh)
+    calls, real = [], jax.device_put
+
+    def recording(piece, sharding):
+        calls.append((piece, sharding))
+        return real(piece, sharding)
+
+    monkeypatch.setattr(jax, "device_put", recording)
+    before = _paced()
+    get_tracer().reset()
+    got = feed.place(x, mesh)
+    monkeypatch.undo()
+    assert bool(jnp.array_equal(got, x))
+    assert len(calls) == plan.pieces > 1
+    words = x.reshape(-1).view(np.uint32)
+    per = words.size // plan.pieces
+    for k, (piece, sharding) in enumerate(calls):
+        # the k-th run of the host's own buffer: a view, not a copy
+        assert np.shares_memory(piece, x)
+        assert np.array_equal(piece.reshape(-1), words[k * per:(k + 1) * per])
+        assert sharding.spec == P("data", None, None)
+    assert _paced_since(before) == {"yes": 0, "no": 0}
+    assert "h2d_pace" not in get_tracer().breakdown()

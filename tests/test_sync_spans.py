@@ -72,7 +72,7 @@ class _CopyChannel:
     def __init__(self, place):
         self.place, self.free_at, self.lock = place, 0.0, threading.Lock()
 
-    def __call__(self, host_batch):
+    def __call__(self, host_batch, pacer=None):
         with self.lock:
             self.free_at = max(self.free_at, time.perf_counter()) + COPY_S
             ready_at = self.free_at
@@ -280,3 +280,112 @@ def test_fit_reports_the_rate_of_the_run_beside_the_rate_inside_steps(
         tr.timer.samples_per_sec)
     assert tr.fit_samples_per_sec == pytest.approx(2 * 640 / wall, rel=0.2)
     assert np.isfinite(tr.fit_samples_per_sec)
+
+
+# -- the producer's waits for its turn at the link (ISSUE 42) --------------
+
+PACED_DIM, PACED_ROWS = 576, 128
+
+
+class _LatePiece:
+    """A piece that is put at once and lands ``COPY_S / 10`` after the
+    piece before it: one link, one DMA at a time."""
+
+    def __init__(self, value, ready_at):
+        self.value, self.ready_at = value, ready_at
+
+    def is_ready(self):
+        return time.perf_counter() >= self.ready_at
+
+    def block_until_ready(self):
+        time.sleep(max(0.0, self.ready_at - time.perf_counter()))
+        return self
+
+
+@pytest.fixture
+def paced_feed(monkeypatch):
+    """The feed engaged on the CPU as ``tests/test_feed_layout.py`` does
+    it, with the pacer's pieces landing late: the producer has to wait
+    for its turn at the link, as on the chip."""
+    from distlr_tpu.parallel import feed
+
+    monkeypatch.setattr(feed, "_default_is_row_major", lambda *a: False)
+    monkeypatch.setattr(feed, "AS_HELD_MIN_BYTES", 1)
+    monkeypatch.setattr(feed, "_PIECE_BYTES", 1 << 15)
+    free_at = [0.0]
+
+    def late_put(piece, sharding):
+        free_at[0] = max(free_at[0], time.perf_counter()) + COPY_S / 10
+        return _LatePiece(jax.device_put(piece, sharding), free_at[0])
+
+    pacer, restore = feed.Pacer, feed._restore_program
+    monkeypatch.setattr(
+        feed, "Pacer", lambda stop: pacer(stop, device_put=late_put))
+    monkeypatch.setattr(
+        feed, "_restore_program",
+        lambda plan: lambda *pieces: restore(plan)(
+            *[getattr(p, "value", p) for p in pieces]))
+    return feed
+
+
+def _paced_counts():
+    from distlr_tpu.obs.registry import get_registry
+
+    fam = get_registry().snapshot().get("distlr_h2d_paced_pieces_total", {})
+    got = {s["labels"]["waited"]: s["value"] for s in fam.get("series", [])}
+    return got.get("yes", 0.0), got.get("no", 0.0)
+
+
+@pytest.mark.parametrize("prefetch", [2, 3])
+def test_the_producers_waits_are_spans_under_h2d(paced_feed, prefetch):
+    from distlr_tpu.train.trainer import GlobalShardedData
+
+    rng = np.random.default_rng(0)
+    X = rng.normal(size=(3 * PACED_ROWS, PACED_DIM)).astype(np.float32)
+    data = GlobalShardedData([(X, (X[:, 0] > 0).astype(np.int32))])
+    cfg = Config(num_feature_dim=PACED_DIM, mesh_shape={"data": 1},
+                 batch_size=PACED_ROWS, l2_c=0.0, test_interval=0,
+                 prefetch=prefetch)
+    tr = Trainer(cfg).load_data(train=data, test=data)
+    tr._test_data = None  # no eval_put: every piece is a train batch's
+    pieces = paced_feed._plan(X[:PACED_ROWS], tr.mesh).pieces
+    assert pieces == 8
+    tracer = get_tracer()
+    tracer.reset()
+    yes0, no0 = _paced_counts()
+    tr.fit(epochs=2)
+    tr.fit(epochs=1)
+    yes, no = (a - b for a, b in zip(_paced_counts(), (yes0, no0)))
+    events = _events()
+    by_id = {e["args"]["id"]: e for e in events}
+    h2d = [e for e in events if e["name"] == "h2d"]
+    paces = [e for e in events if e["name"] == "h2d_pace"]
+    batches = 9
+    # h2d stays one span a batch, its id used once, over epochs and fits
+    assert sorted(e["args"]["step"] for e in h2d) == list(range(batches))
+    # six pieces a batch through the pacer (eight here), by whether the
+    # producer waited; every wait is a span
+    assert yes + no == batches * pieces
+    assert len(paces) == yes > no
+    for e in paces:
+        parent = by_id[e["args"]["parent"]]
+        assert parent["name"] == "h2d"
+        assert parent["args"]["step"] == e["args"]["step"]
+        assert parent["tid"] == e["tid"]
+        assert parent["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= parent["ts"] + parent["dur"] + 1
+    # ... also a batch's first pieces, which wait for the batch before:
+    # the pacer is carried across batches and epochs (not across fits)
+    firsts = {e["args"]["step"] for e in paces}
+    assert {1, 2, 3, 4, 5, 7, 8} <= firsts
+    spans = tracer.breakdown()
+    assert spans["h2d_pace"]["seconds"] <= spans["h2d"]["seconds"]
+    assert spans["h2d"]["self_seconds"] <= (
+        spans["h2d"]["seconds"] - spans["h2d_pace"]["seconds"] + 1e-4)
+    # the other spans' ids are still used once a name
+    seen = set()
+    for e in events:
+        if "step" in e["args"] and e["name"] != "h2d_pace":
+            assert (e["name"], e["args"]["step"]) not in seen
+            seen.add((e["name"], e["args"]["step"]))
+    assert tr.batches_taken == batches

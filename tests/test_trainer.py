@@ -264,6 +264,193 @@ class TestPrefetch:
             Config(prefetch=0)
 
 
+@pytest.fixture
+def producers(monkeypatch):
+    """Every ``distlr-prefetch`` thread started while the test runs."""
+    import threading
+
+    started, start = [], threading.Thread.start
+
+    def counting(thread):
+        if thread.name == "distlr-prefetch":
+            started.append(thread)
+        start(thread)
+
+    monkeypatch.setattr(threading.Thread, "start", counting)
+    return started
+
+
+def _span_counts():
+    from distlr_tpu.obs.tracing import get_tracer
+
+    return {k: v["count"] for k, v in get_tracer().breakdown().items()}
+
+
+class TestOneProducerAFit:
+    """ISSUE 42: the producer thread lives as long as its ``fit``, over
+    every epoch of the call, and never past it."""
+
+    STEPS = 4  # 1,280 train rows over 8 shards, 40 rows a shard a batch
+
+    def _trainer(self, data_dir, **kw):
+        kw = {"prefetch": 2, "test_interval": 1, **kw}
+        cfg = Config(data_dir=data_dir, num_feature_dim=24, batch_size=40,
+                     learning_rate=0.3, l2_c=0.0, **kw)
+        return Trainer(cfg, mesh=make_mesh({"data": 8})).load_data()
+
+    @pytest.mark.parametrize("prefetch", [2, 3, 5])
+    def test_one_thread_a_fit_of_several_epochs(self, data_dir, producers,
+                                                prefetch):
+        from distlr_tpu.obs.tracing import get_tracer
+
+        tr = self._trainer(data_dir, prefetch=prefetch)
+        get_tracer().reset()
+        tr.fit(epochs=4, eval_fn=lambda *a: None)
+        assert len(producers) == 1 and not producers[0].is_alive()
+        # it made the call's batches and not one more
+        counts = _span_counts()
+        assert counts["batch_slice"] == counts["h2d"] == 4 * self.STEPS
+        assert counts["compute"] == tr.batches_taken == 4 * self.STEPS
+        events = get_tracer().chrome_trace()["traceEvents"]
+        assert len({e["tid"] for e in events if e["name"] == "h2d"}) == 1
+        # a second fit starts its own, and ends it
+        tr.fit(epochs=2, eval_fn=lambda *a: None)
+        assert len(producers) == 2 and not producers[1].is_alive()
+        assert tr.batches_taken == 6 * self.STEPS
+
+    def test_the_serial_shape_starts_no_thread(self, data_dir, producers):
+        tr = self._trainer(data_dir, prefetch=1)
+        tr.fit(epochs=3, eval_fn=lambda *a: None)
+        assert producers == []
+        assert tr.batches_taken == 3 * self.STEPS
+
+    @pytest.mark.parametrize("fails_at", [0, 3, 7])
+    def test_a_step_that_raises_leaves_no_producer_behind(
+            self, data_dir, producers, fails_at):
+        """``fails_at`` 7 is in the second epoch: the producer has by
+        then gone over an epoch's end."""
+        tr = self._trainer(data_dir, prefetch=3)
+        step, seen = tr.train_step, []
+
+        def failing(w, batch):
+            if len(seen) == fails_at:
+                raise RuntimeError("step failed")
+            seen.append(1)
+            return step(w, batch)
+
+        tr.train_step = failing
+        with pytest.raises(RuntimeError, match="step failed"):
+            tr.fit(epochs=3, eval_fn=lambda *a: None)
+        # closed before fit returned: no polling, nothing to wait for
+        assert len(producers) == 1 and not producers[0].is_alive()
+        # a retried fit stacks none on top
+        tr.train_step = step
+        tr.fit(epochs=1, eval_fn=lambda *a: None)
+        assert len(producers) == 2
+        assert not any(t.is_alive() for t in producers)
+
+    def test_a_resumed_fit_produces_the_epochs_that_are_left(
+            self, data_dir, producers, tmp_path):
+        from distlr_tpu.obs.tracing import get_tracer
+
+        kw = dict(checkpoint_dir=str(tmp_path), checkpoint_interval=1)
+        first = self._trainer(data_dir, **kw)
+        first.fit(epochs=2, eval_fn=lambda *a: None)
+        whole = self._trainer(data_dir)
+        whole.fit(epochs=5, eval_fn=lambda *a: None)
+        resumed = self._trainer(data_dir, **kw)
+        get_tracer().reset()
+        resumed.fit(epochs=5, resume=True, eval_fn=lambda *a: None)
+        assert _span_counts()["batch_slice"] == 3 * self.STEPS
+        assert resumed.batches_taken == 3 * self.STEPS
+        assert len(producers) == 3
+        assert not any(t.is_alive() for t in producers)
+        np.testing.assert_array_equal(np.asarray(resumed.weights),
+                                      np.asarray(whole.weights))
+        # nothing left to run: a thread that ends at once, no batch made
+        get_tracer().reset()
+        resumed.fit(epochs=5, resume=True, eval_fn=lambda *a: None)
+        assert "batch_slice" not in _span_counts()
+        assert not any(t.is_alive() for t in producers)
+
+
+def _write_family(family, d):
+    from distlr_tpu.data.hashing import write_ctr_shards, write_raw_ctr_shards
+
+    if family in ("binary_lr", "feature_sharded"):
+        write_synthetic_shards(d, 600, 24, num_parts=2, seed=1, sparsity=0.0)
+        return dict(num_feature_dim=24)
+    if family == "softmax":
+        write_synthetic_shards(d, 600, 32, num_parts=2, seed=1, num_classes=4)
+        return dict(model="softmax", num_feature_dim=32, num_classes=4)
+    if family == "blocked_lr":
+        write_raw_ctr_shards(d, 600, 6, 40, 2, seed=9)
+        return dict(model="blocked_lr", num_feature_dim=4096, block_size=4)
+    write_ctr_shards(d, 600, 5, 50, 64, 2, seed=3)
+    kw = dict(model=family, num_feature_dim=64)
+    return {**kw, "num_classes": 2} if family == "sparse_softmax" else kw
+
+
+FAMILIES = ("binary_lr", "softmax", "sparse_lr", "sparse_softmax",
+            "blocked_lr", "feature_sharded")
+
+
+class TestProducerKeepsTheTrajectory:
+    """ISSUE 42: whatever the producer is ahead by, over epochs' ends,
+    evals and checkpoints, the rows' order and so every weight are the
+    serial shape's, bit for bit, in every family the sync trainer runs."""
+
+    @pytest.fixture(scope="class")
+    def runs(self, tmp_path_factory):
+        """``run(family, wrap, prefetch)`` -> weights after 3 epochs, the
+        accuracies of the three evals, the checkpoint after epoch 2;
+        each computed once."""
+        dirs, done = {}, {}
+
+        def run(family, wrap, prefetch):
+            key = (family, wrap, prefetch)
+            if key in done:
+                return done[key]
+            if family not in dirs:
+                d = str(tmp_path_factory.mktemp(family))
+                dirs[family] = (d, _write_family(family, d))
+            d, kw = dirs[family]
+            ck = str(tmp_path_factory.mktemp("ck"))
+            shape = ({"data": 2, "model": 2} if family == "feature_sharded"
+                     else {"data": 2})
+            # 240 train rows a shard in batches of 64: a short fourth batch
+            cfg = Config(data_dir=d, mesh_shape=shape, batch_size=64,
+                         learning_rate=0.3, l2_c=0.0, test_interval=1,
+                         checkpoint_dir=ck, checkpoint_interval=2,
+                         wrap_final_batch=int(wrap), prefetch=prefetch, **kw)
+            tr = Trainer(cfg).load_data()
+            accs = []
+            w = np.asarray(tr.fit(epochs=3,
+                                  eval_fn=lambda _e, acc: accs.append(acc)))
+            assert tr.timer.steps == 12
+            from distlr_tpu.train.checkpoint import Checkpointer
+
+            with Checkpointer(ck) as ckpt:
+                saved = np.asarray(ckpt.restore(2)["weights"])
+                assert ckpt.latest_step() == 3
+            done[key] = (w, accs, saved)
+            return done[key]
+
+        return run
+
+    @pytest.mark.parametrize("prefetch", [2, 3])
+    @pytest.mark.parametrize("wrap", [False, True], ids=["padded", "wrapped"])
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_weights_are_the_serial_shapes_bit_for_bit(self, runs, family,
+                                                       wrap, prefetch):
+        w1, accs1, saved1 = runs(family, wrap, 1)
+        w, accs, saved = runs(family, wrap, prefetch)
+        assert np.any(w1 != np.asarray(saved1).reshape(w1.shape))
+        np.testing.assert_array_equal(w, w1)
+        assert accs == accs1 and len(accs) == 3
+        np.testing.assert_array_equal(saved, saved1)
+
+
 class TestFeatureShardedTrainer:
     def test_2d_mesh_end_to_end(self, data_dir):
         cfg = Config(
