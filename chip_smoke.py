@@ -59,7 +59,7 @@ FULL = Sizes(
     dense_samples=1280, dense_batch=256, dense_epochs=10,
     sparse_samples=163_840, sparse_batch=65_536, sparse_epochs=8,
     # 1e6 * 64 = 6.4e7 >= ps_trainer._PS_AUTO_CPU_THRESHOLD (2**25), so
-    # ps_compute_backend=auto must put the step on the accelerator
+    # ps_compute_device must put the step on the accelerator
     ps_batch=64, ps_epochs=2,
     bsp_rows=128,          # x 1e6 is over the same threshold
     kernel_rows=384,       # a worker's shard in dense-ps-async-1chip
@@ -281,7 +281,6 @@ class Smoke:
             sync_mode=False, num_workers=2, num_servers=2,
             batch_size=self.s.ps_batch, num_iteration=self.s.ps_epochs,
             test_interval=1, learning_rate=DENSE_LR, l2_c=0.0)
-        _check(cfg.ps_compute_backend == "auto", "the default selection")
 
         ops = get_registry().get("distlr_ps_client_ops_total")
 

@@ -68,14 +68,6 @@ class GradientAccumulator:
         self._buf += np.asarray(g, np.float32).reshape(-1)
         self._batches += 1
 
-    def add_at(self, idx: np.ndarray, g: np.ndarray) -> None:
-        """Accumulate a keyed gradient: ``g[i]`` lands on flat
-        coordinate ``idx[i]`` (indices must be unique, as a batch's
-        unique-key gradients are)."""
-        self._buf[np.asarray(idx, np.int64)] += np.asarray(
-            g, np.float32).reshape(-1)
-        self._batches += 1
-
     def add_rows(self, rows: np.ndarray, g: np.ndarray, vpk: int) -> None:
         """Accumulate a row-keyed gradient: row ``rows[i]`` owns flat
         slots ``[rows[i]*vpk, (rows[i]+1)*vpk)`` (the vals_per_key

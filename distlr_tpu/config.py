@@ -142,14 +142,6 @@ class Config:
     # ---- PS / async mode ----
     ps_host: str = "127.0.0.1"        # DMLC_PS_ROOT_URI
     ps_port: int = 8001               # DMLC_PS_ROOT_PORT
-    # Where PS workers run their gradient/eval steps. "auto" picks plain
-    # host numpy/BLAS when the per-batch workload (param_dim x batch
-    # elements) is tiny (jax dispatch itself dominates: measured 213 us
-    # dispatch vs 44 us math at D=123 B=256, and dispatch is GIL-bound so
-    # threaded workers serialize on it), the jitted host CPU backend for
-    # small workloads (accelerator round trips dominate), and the
-    # default backend otherwise. "numpy" / "cpu" / "default" force.
-    ps_compute_backend: str = "auto"  # auto | numpy | cpu | default
     # Dense PS protocol optimization: replace the reference's two round
     # trips per batch (pull -> grad -> push, src/lr.cc:116-132) with ONE
     # fused push_pull (the reply carries the post-update weights), and in
@@ -635,11 +627,6 @@ class Config:
             raise ValueError(
                 "chaos_seed must be None (use the plan's seed) or in "
                 f"[0, 2^64), got {self.chaos_seed}")
-        if self.ps_compute_backend not in ("auto", "numpy", "cpu", "default"):
-            raise ValueError(
-                "ps_compute_backend must be auto|numpy|cpu|default, "
-                f"got {self.ps_compute_backend!r}"
-            )
         if self.obs_metrics_port is not None and not (
             0 <= self.obs_metrics_port < 1 << 16
         ):

@@ -43,10 +43,13 @@ import os
 import numpy as np
 
 from distlr_tpu import sync
-
+from distlr_tpu.compress import GradientAccumulator
 from distlr_tpu.config import Config
+from distlr_tpu.models import host_math
 from distlr_tpu.obs import dtrace
 from distlr_tpu.obs.registry import get_registry
+from distlr_tpu.ps import KVWorker, RetryPolicy
+from distlr_tpu.train.ps_trainer import RowKeys, keyed_model, ps_param_dim
 from distlr_tpu.utils.logging import get_logger
 
 log = get_logger(__name__)
@@ -116,13 +119,6 @@ class OnlineTrainer:
                 f"online training supports {_SUPPORTED}, got {cfg.model!r}")
         if worker_id < 0:
             raise ValueError(f"worker_id must be >= 0, got {worker_id}")
-        # imported here, not at module top: these helpers live with the
-        # batch PS trainer (the asked-for reuse), which imports jax —
-        # acceptable for a trainer process, deferred for everyone else
-        from distlr_tpu.compress import GradientAccumulator  # noqa: PLC0415
-        from distlr_tpu.ps import KVWorker, RetryPolicy  # noqa: PLC0415
-        from distlr_tpu.train.ps_trainer import ps_param_dim  # noqa: PLC0415
-
         self.cfg = cfg
         self.shard_dir = shard_dir
         self.dim = ps_param_dim(cfg)
@@ -171,13 +167,14 @@ class OnlineTrainer:
         self._num_classes = (cfg.num_classes
                              if cfg.model in ("softmax", "sparse_softmax")
                              else None)
-        # sparse_softmax keyed rows: one feature key owns K class lanes;
-        # vals_per_key rides the wire when the group's range boundaries
-        # align, else keys expand per lane (the keyed trainers' rule)
-        self._row_vpk = 1
-        if cfg.model == "sparse_softmax" and self.kv.supports_vals_per_key(
-                cfg.num_classes):
-            self._row_vpk = cfg.num_classes
+        # keyed models: the gradient over a batch's unique rows, and how
+        # the connection addresses them (sparse_softmax: one feature key
+        # owns K class lanes; the keyed trainers' one rule, ``RowKeys``)
+        self._rows = self._keyed_grad = None
+        keyed = keyed_model(cfg)
+        if keyed is not None:
+            self._rows = RowKeys(self.kv, keyed[0])
+            self._keyed_grad = keyed[1]
 
     @property
     def accum_k(self) -> int:
@@ -186,8 +183,6 @@ class OnlineTrainer:
 
     # -- gradient plumbing -------------------------------------------------
     def _dense_batch(self, X, y) -> None:
-        from distlr_tpu.train.ps_trainer import _np_dense_grad  # noqa: PLC0415
-
         cfg = self.cfg
         if self._accum.batches == 0:
             # pull once per accumulation span: batches within a span ride
@@ -197,87 +192,39 @@ class OnlineTrainer:
         K = self._num_classes
         w = (self._w_cache.reshape(cfg.num_feature_dim, K) if K
              else self._w_cache)
-        mask = np.ones(len(y), np.float32)
-        g = _np_dense_grad(w, X, y, mask, cfg.l2_c,
-                           bool(cfg.l2_scale_by_batch), K)
+        g = host_math.dense_grad(w, X, y, np.ones(len(y), np.float32),
+                                 cfg.l2_c, bool(cfg.l2_scale_by_batch), K)
         self._accum.add(g)
-        self.examples += len(y)
-        _EXAMPLES.inc(len(y))
 
-    def _sparse_batch(self, pc, pv, y) -> None:
-        from distlr_tpu.train.ps_trainer import _sparse_batch_grad  # noqa: PLC0415
-
-        cfg = self.cfg
+    def _keyed_batch(self, pc, pv, y) -> None:
+        """Pull the batch's unique rows, accumulate their gradient at the
+        connection's own key granularity (a sparse_softmax feature key
+        owns its K class lanes of the row-major (D, K) table)."""
+        cfg, rows = self.cfg, self._rows
         ub, pos = np.unique(pc, return_inverse=True)
-        keys = ub.astype(np.uint64)
-        w_u = self.kv.pull(keys=keys)
-        mask = np.ones(len(y), np.float32)
-        g_u = _sparse_batch_grad(w_u, pos.reshape(pc.shape), pv, y, mask,
-                                 cfg.l2_c, bool(cfg.l2_scale_by_batch))
-        self._accum.add_at(ub, g_u)
-        self.examples += len(y)
-        _EXAMPLES.inc(len(y))
-
-    def _sparse_softmax_batch(self, pc, pv, y) -> None:
-        """Keyed rows per class (the ISSUE-6 follow-on): each unique
-        feature key owns its K class lanes of the row-major (D, K)
-        table — pulled/pushed vals_per_key=K when aligned, expanded
-        per-lane keys otherwise."""
-        from distlr_tpu.train.ps_trainer import (  # noqa: PLC0415
-            _expand_block_keys,
-            _sparse_softmax_batch_grad,
-        )
-
-        cfg = self.cfg
-        K = cfg.num_classes
-        ub, pos = np.unique(pc, return_inverse=True)
-        rows = ub.astype(np.uint64)
-        if self._row_vpk > 1:
-            w_u = self.kv.pull(keys=rows, vals_per_key=K)
-        else:
-            w_u = self.kv.pull(keys=_expand_block_keys(rows, K))
-        mask = np.ones(len(y), np.float32)
-        g_u = _sparse_softmax_batch_grad(
-            w_u.reshape(-1, K), pos.reshape(pc.shape), pv, y, mask,
+        keys = rows.keys(ub)
+        w_u = self.kv.pull(keys=keys, vals_per_key=rows.vpk)
+        if rows.width > 1:
+            w_u = w_u.reshape(-1, rows.width)
+        g_u = self._keyed_grad(
+            w_u, pos.reshape(pc.shape), pv, y, np.ones(len(y), np.float32),
             cfg.l2_c, bool(cfg.l2_scale_by_batch))
-        self._accum.add_rows(ub, g_u.reshape(-1), K)
-        self.examples += len(y)
-        _EXAMPLES.inc(len(y))
+        self._accum.add_rows(keys, g_u, rows.vpk)
 
     def _flush_push(self) -> None:
         """Push the accumulated MEAN gradient (one Hogwild update of
         batch size span*B); the accumulator advances its own AdaBatch
         schedule per flush."""
-        cfg = self.cfg
-        if cfg.model == "sparse_lr":
-            res = self._accum.flush_keyed()
-            if res is None:
-                return
-            keys, vals = res
-            if keys.size:  # async Hogwild: a cancelled span pushes nothing
-                self.kv.wait(self.kv.push(vals, keys=keys))
-        elif cfg.model == "sparse_softmax":
-            res = self._accum.flush_keyed(vpk=cfg.num_classes)
-            if res is None:
-                return
-            rows, vals = res
-            if rows.size:
-                if self._row_vpk > 1:
-                    self.kv.wait(self.kv.push(
-                        vals, keys=rows, vals_per_key=cfg.num_classes))
-                else:
-                    from distlr_tpu.train.ps_trainer import (  # noqa: PLC0415
-                        _expand_block_keys,
-                    )
-
-                    self.kv.wait(self.kv.push(
-                        vals, keys=_expand_block_keys(rows,
-                                                      cfg.num_classes)))
+        vpk = 1 if self._rows is None else self._rows.vpk
+        if self._rows is None:
+            keys, g = None, self._accum.flush_dense()
         else:
-            g = self._accum.flush_dense()
-            if g is None:
-                return
-            self.kv.wait(self.kv.push(g))
+            keys, g = self._accum.flush_keyed(vpk) or (None, None)
+        if g is None:
+            return
+        # async Hogwild: a keyed span that cancelled to zeros pushes nothing
+        if keys is None or keys.size:
+            self.kv.wait(self.kv.push(g, keys=keys, vals_per_key=vpk))
         self._w_cache = None
         self.pushes += 1
         _PUSHES.inc()
@@ -392,29 +339,26 @@ class OnlineTrainer:
                 "online.consume",
                 tags={"shard": shard, "records": len(lines),
                       "worker": self.worker_id}):
-            if cfg.model in ("sparse_lr", "sparse_softmax"):
+            if self._rows is not None:
                 (row_ptr, cols, vals), y = parse_libsvm_lines(
                     lines, cfg.num_feature_dim, dense=False,
                     multiclass=cfg.model == "sparse_softmax")
-                pc, pv = csr_to_padded_coo(row_ptr, cols, vals,
-                                           nnz_max=cfg.nnz_max)
-                batch_fn = (self._sparse_softmax_batch
-                            if cfg.model == "sparse_softmax"
-                            else self._sparse_batch)
-                for lo in range(0, len(y), B):
-                    batch_fn(pc[lo:lo + B], pv[lo:lo + B], y[lo:lo + B])
-                    if self._accum.ready:
-                        self._flush_push()
-                    n += len(y[lo:lo + B])
+                feats = csr_to_padded_coo(row_ptr, cols, vals,
+                                          nnz_max=cfg.nnz_max)
+                batch_fn = self._keyed_batch
             else:
                 X, y = parse_libsvm_lines(
                     lines, cfg.num_feature_dim, dense=True,
                     multiclass=self._num_classes is not None)
-                for lo in range(0, len(y), B):
-                    self._dense_batch(X[lo:lo + B], y[lo:lo + B])
-                    if self._accum.ready:
-                        self._flush_push()
-                    n += len(y[lo:lo + B])
+                feats, batch_fn = (X,), self._dense_batch
+            for lo in range(0, len(y), B):
+                yb = y[lo:lo + B]
+                batch_fn(*(a[lo:lo + B] for a in feats), yb)
+                self.examples += len(yb)
+                _EXAMPLES.inc(len(yb))
+                if self._accum.ready:
+                    self._flush_push()
+                n += len(yb)
         dur = sync.monotonic() - t0
         for ctx in traces[1:]:
             # the other traces coalesced into this shard each get the

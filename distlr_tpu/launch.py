@@ -371,14 +371,6 @@ def _add_config_flags(p: argparse.ArgumentParser) -> None:
         "records are already in the page cache)",
     )
     p.add_argument(
-        "--ps-compute-backend", dest="ps_compute_backend",
-        choices=["auto", "numpy", "cpu", "default"],
-        help="where PS workers run their dense steps: auto (plain numpy "
-        "for tiny per-batch workloads where jax dispatch dominates, "
-        "jitted host CPU for small ones, accelerator otherwise), or "
-        "force numpy/cpu/default",
-    )
-    p.add_argument(
         "--cpu-devices", dest="cpu_devices", type=int,
         help="run on an N-device virtual CPU mesh instead of the default "
         "backend (same as JAX_PLATFORMS=cpu with XLA_FLAGS="
@@ -397,7 +389,7 @@ def _config_from_args(args: argparse.Namespace) -> Config:
             "data_dir", "num_feature_dim", "num_iteration", "batch_size",
             "learning_rate", "l2_c", "test_interval", "model", "num_classes",
             "nnz_max", "compat_mode", "checkpoint_dir", "checkpoint_interval",
-            "profile_dir", "num_workers", "num_servers", "ps_compute_backend",
+            "profile_dir", "num_workers", "num_servers",
             "feature_dtype", "block_size", "block_groups", "ctr_fields",
             "hash_seed", "ps_pipeline", "obs_metrics_port",
             "random_seed", "prefetch", "ps_timeout_ms",

@@ -369,8 +369,8 @@ enum Codec : uint8_t {
 };
 
 //: value payloads under this many bytes stay on the socket, attached or
-//: not.  From a reading on the chip's host (PR 35, call 1:
-//: benchmarks/exp_mapped_payload.py with this constant at 4096; a fused
+//: not.  From a reading on the chip's host (PR 35, call 1: a sweep of
+//: sizes with this constant at 4096, PERF.md section 6; a fused
 //: push-pull's median ms, socket / mapping): at 64 KiB 0.513 / 0.387 for
 //: one worker and 1.209 / 1.146 for four in lock step, at 2 MiB 2.722 /
 //: 1.757 and 4.423 / 3.243; from 4 to 32 KiB the mapping read 0.05-0.08

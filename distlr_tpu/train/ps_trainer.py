@@ -40,7 +40,7 @@ from distlr_tpu.config import Config
 from distlr_tpu.data import DataIter
 from distlr_tpu.data.iterator import SparseDataIter, Window
 from distlr_tpu.data.sharding import part_name
-from distlr_tpu.models import get_model
+from distlr_tpu.models import get_model, host_math
 from distlr_tpu.models.linear import BinaryLR
 from distlr_tpu.obs import dtrace, jaxrt
 from distlr_tpu.obs.registry import COUNT_BUCKETS, get_registry
@@ -209,7 +209,7 @@ _PANEL_AHEAD = get_registry().gauge(
 
 class _StepTrace:
     """StepTimer proxy that puts each ``start()``/``stop()`` bracket —
-    one training batch, in every loop variant — under its own
+    one training batch, whatever the exchange — under its own
     distributed-trace root (:mod:`distlr_tpu.obs.dtrace`).  Sampled
     steps get a ``train.step`` span whose KV pulls/pushes carry the
     trace trailer, so the server-side apply is causally linked to the
@@ -254,7 +254,7 @@ _PS_AUTO_CPU_THRESHOLD = 1 << 25
 # Below this, even the jitted host-CPU step is dominated by jax dispatch
 # overhead (measured 213 us dispatch vs 44 us of numpy math at D=123,
 # B=256 — and dispatch is GIL-bound, so threaded workers serialize on
-# it): "auto" drops to plain numpy/BLAS.  f32 numpy is also CLOSER to
+# it): the step drops to plain numpy/BLAS.  f32 numpy is also CLOSER to
 # the f32 reference trajectory than the bf16-matmul jax step.
 _PS_AUTO_NUMPY_THRESHOLD = 1 << 20
 
@@ -294,8 +294,8 @@ def ps_compute_device(cfg: Config, rows: int | None = None, device=None):
 
     The reference's workers are host-CPU programs (``src/lr.cc:35-41``);
     our PS mode jits the same math, but for tiny models the accelerator
-    round trip per minibatch dwarfs the math, so "auto" keeps small
-    steps on the host — below ``_PS_AUTO_NUMPY_THRESHOLD`` as plain
+    round trip per minibatch dwarfs the math, so small steps stay on the
+    host — below ``_PS_AUTO_NUMPY_THRESHOLD`` as plain
     numpy (jit dispatch itself dominates there), below
     ``_PS_AUTO_CPU_THRESHOLD`` on the jitted CPU backend — and sends big
     ones to the accelerator.
@@ -305,13 +305,6 @@ def ps_compute_device(cfg: Config, rows: int | None = None, device=None):
     own).  When it is unknown (``None`` with ``batch_size=-1``), the step
     is assumed big enough to amortize accelerator dispatch.
     """
-    choice = cfg.ps_compute_backend
-    if choice == "default":
-        return device
-    if choice == "numpy":
-        return "numpy"
-    if choice == "cpu":
-        return jax.devices("cpu")[0]
     if jax.default_backend() == "cpu" and rows is None:
         return device
     if rows is None:
@@ -324,7 +317,7 @@ def ps_compute_device(cfg: Config, rows: int | None = None, device=None):
     if jax.default_backend() == "cpu" or work >= _PS_AUTO_CPU_THRESHOLD:
         return device
     # raises when JAX_PLATFORMS names no cpu backend: the operator
-    # excluded the host, so "auto" says so rather than pick for them
+    # excluded the host, and this says so rather than pick for them
     return jax.devices("cpu")[0]
 
 
@@ -359,58 +352,6 @@ def worker_devices(n: int) -> list:
     workers than devices wrap, and one device serves every worker."""
     devices = jax.local_devices()
     return [devices[i % len(devices)] for i in range(n)]
-
-
-def _np_dense_grad(w, X, y, mask, l2_c, l2_scale_by_batch, num_classes=None):
-    """f32 numpy mirror of BinaryLR.grad / SoftmaxRegression.grad
-    (models/linear.py) for the tiny-step regime where jax dispatch
-    dominates; quirk gates (Q4 L2/B) identical."""
-    y = np.asarray(y)
-    mask = np.asarray(mask, np.float32)
-    n = np.float32(max(mask.sum(), 1.0))
-    if num_classes is None:
-        z = X @ w
-        sig = (0.5 * (1.0 + np.tanh(0.5 * z))).astype(np.float32)
-        resid = (sig - y.astype(np.float32)) * mask
-        g = resid @ X / n
-    else:
-        z = X @ w  # (B, K)
-        z -= z.max(axis=1, keepdims=True)
-        p = np.exp(z)
-        p /= p.sum(axis=1, keepdims=True)
-        p[np.arange(len(y)), y] -= 1.0
-        g = X.T @ (p * mask[:, None]) / n
-    if l2_c:
-        term = np.float32(l2_c) * w
-        g = g + (term / n if l2_scale_by_batch else term)
-    return np.asarray(g, dtype=np.float32)
-
-
-def _binary_eval_from_logits(z, y, mask) -> tuple[float, float]:
-    """(accuracy, logloss) of binary logits — THE masked-mean definition,
-    shared by the numpy dense eval and the keyed (sparse/blocked) evals
-    so the metrics cannot silently diverge."""
-    z = np.asarray(z, np.float64)
-    m = np.asarray(mask, np.float64)
-    n = max(m.sum(), 1.0)
-    acc = float((((z > 0).astype(np.int64) == y) * m).sum() / n)
-    ll = float(((np.logaddexp(0.0, z) - y * z) * m).sum() / n)
-    return acc, ll
-
-
-def _np_dense_eval(w, X, y, mask, num_classes=None):
-    """f32 numpy ``(accuracy, logloss)`` for the dense models — one
-    forward pass, no jax dispatch."""
-    z = np.asarray(X @ w, np.float64)
-    if num_classes is None:
-        return _binary_eval_from_logits(z, y, mask)
-    m = np.asarray(mask, np.float64)
-    n = max(m.sum(), 1.0)
-    pred = z.argmax(axis=1)
-    zs = z - z.max(axis=1, keepdims=True)
-    ll = np.log(np.exp(zs).sum(axis=1)) - zs[np.arange(len(y)), y]
-    acc = float(((pred == y) * m).sum() / n)
-    return acc, float((ll * m).sum() / n)
 
 
 @functools.lru_cache(maxsize=None)
@@ -507,108 +448,6 @@ def _compiled_acc(model):
     return jax.jit(ps_eval, static_argnames=("panels",))
 
 
-def _sparse_batch_grad(w_u, pos, vals, y, mask, l2_c, l2_scale_by_batch):
-    """Gradient of the sparse one-hot LR loss wrt the batch's UNIQUE
-    touched weights (numpy, host-side).
-
-    Mirrors ``SparseBinaryLR.grad`` (models/linear.py) restricted to the
-    touched key set: ``w_u`` are the pulled weights for the batch's unique
-    columns, ``pos`` maps each (row, slot) to its index in ``w_u``.  The
-    scatter is ``np.bincount`` (vectorized C) — PS-sparse batches are
-    exactly the tiny host-side steps where jit dispatch would dominate,
-    and a per-batch-varying unique-key count would recompile every step.
-
-    L2 is applied *lazily* (only the touched coordinates, like every
-    sparse parameter server): with ``l2_c > 0`` the effective decay per
-    weight scales with how often it is touched, unlike the dense path's
-    every-step decay — callers comparing against the dense trainer should
-    set ``l2_c = 0`` or account for touch frequency.
-    """
-    z = (w_u[pos] * vals).sum(axis=-1)
-    sig = 0.5 * (1.0 + np.tanh(0.5 * z))  # overflow-stable sigmoid
-    n = np.float32(max(mask.sum(), 1))
-    resid = ((sig - y) * mask).astype(np.float32)
-    contrib = (resid[:, None] * vals).ravel() / n
-    g = np.bincount(pos.ravel(), weights=contrib, minlength=len(w_u)).astype(np.float32)
-    if l2_c:
-        # Decay only genuinely-active keys: COO padding (col 0, val 0)
-        # puts key 0 in EVERY batch's unique set, which would give bucket
-        # 0 dense-style every-step decay while real features decay per
-        # touch.
-        active = np.bincount(pos.ravel(), weights=(vals != 0).ravel().astype(np.float32),
-                             minlength=len(w_u)) > 0
-        term = np.float32(l2_c) * w_u * active
-        g += term / n if l2_scale_by_batch else term
-    return g
-
-
-def _sparse_softmax_batch_grad(W_u, pos, vals, y, mask, l2_c,
-                               l2_scale_by_batch):
-    """Gradient of the sparse softmax loss wrt the batch's UNIQUE touched
-    (D, K) table rows (numpy, host-side).
-
-    Mirrors ``SparseSoftmaxRegression.grad`` (models/linear.py)
-    restricted to the touched row set: ``W_u`` is the ``(n_u, K)``
-    pulled slice, ``pos`` maps each (sample, slot) to its row.  Lazy L2
-    at ROW granularity with the same active-key discount as the binary
-    sparse path (COO padding aliases row 0 in every batch)."""
-    z = (W_u[pos] * vals[..., None]).sum(axis=1)      # (B, K)
-    z -= z.max(axis=1, keepdims=True)
-    p = np.exp(z, dtype=np.float32)
-    p /= p.sum(axis=1, keepdims=True)
-    p[np.arange(len(y)), y] -= 1.0
-    n = np.float32(max(mask.sum(), 1))
-    resid = p * np.asarray(mask, np.float32)[:, None]  # (B, K)
-    contrib = (vals[..., None] * resid[:, None, :]).reshape(
-        -1, W_u.shape[1]) / n                          # (B*F, K)
-    g = np.zeros_like(W_u, dtype=np.float32)
-    np.add.at(g, pos.ravel(), contrib)
-    if l2_c:
-        active = np.bincount(
-            pos.ravel(), weights=(vals != 0).ravel().astype(np.float32),
-            minlength=len(W_u)) > 0
-        term = np.float32(l2_c) * W_u * active[:, None]
-        g += term / n if l2_scale_by_batch else term
-    return g
-
-
-def _expand_block_keys(blocks: np.ndarray, block_size: int) -> np.ndarray:
-    """Unique block-row ids -> their flat KV keys (row b owns the
-    contiguous range ``[b*R, (b+1)*R)`` of the ``ps_param_dim`` key
-    space — the row-major layout of the (num_blocks, R) table)."""
-    r = np.arange(block_size, dtype=np.uint64)
-    return (blocks.astype(np.uint64)[:, None] * np.uint64(block_size) + r).reshape(-1)
-
-
-def _blocked_batch_grad(t_u, pos, lane_vals, y, mask, l2_c, l2_scale_by_batch):
-    """Gradient of the blocked LR loss wrt the batch's UNIQUE touched
-    table rows (numpy, host-side).
-
-    Mirrors ``BlockedSparseLR.grad`` (models/linear.py) restricted to the
-    touched row set: ``t_u`` is the ``(n_u, R)`` pulled slice, ``pos``
-    maps each (sample, group) to its row in ``t_u``.  Like the sparse
-    path, L2 is applied lazily — and at ROW granularity: a gathered row
-    decays as a unit (all R lanes), because the row is the parameter unit
-    of this model (one conjunction's weights).
-    """
-    z = (t_u[pos] * lane_vals).sum(axis=(-1, -2))
-    sig = 0.5 * (1.0 + np.tanh(0.5 * z))  # overflow-stable sigmoid
-    n = np.float32(max(mask.sum(), 1))
-    resid = ((sig - y) * mask).astype(np.float32)
-    contrib = (resid[:, None, None] * lane_vals).reshape(-1, t_u.shape[1]) / n
-    g = np.zeros_like(t_u, dtype=np.float32)
-    np.add.at(g, pos.reshape(-1), contrib)
-    if l2_c:
-        # Padded groups (all-zero lanes) alias row pos of block id 0's
-        # slot; only rows gathered with a real (nonzero) lane decay.
-        touched = (lane_vals != 0).any(axis=-1).reshape(-1)
-        active = np.zeros(len(t_u), bool)
-        np.logical_or.at(active, pos.reshape(-1), touched)
-        term = np.float32(l2_c) * t_u * active[:, None]
-        g += term / n if l2_scale_by_batch else term
-    return g
-
-
 def _ps_resume_state(cfg: Config, rank: int):
     """``(start_epoch, weights | None, attempt | None)`` from
     ``cfg.checkpoint_dir`` (``attempt`` is None when no sidecar exists).
@@ -681,6 +520,228 @@ def bump_resume_attempt(cfg: Config) -> None:
     os.replace(tmp, sidecar)
 
 
+class RowKeys:
+    """How a connection addresses table rows ``width`` values wide: THE
+    one place that decides it.  Blocked tables gather R-lane rows, sparse
+    softmax K-class rows; where the group's range boundaries align to the
+    width a row crosses the wire as ONE key (``vals_per_key=width``,
+    ps-lite lens-style: ~2.7x fewer keyed bytes at R=32 than R expanded
+    keys), elsewhere as ``width`` expanded per-lane keys, with
+    bit-identical semantics either way (the server walks rows and flat
+    keys with the same loops, slot for slot).  ``vpk`` goes with every
+    keyed op of the connection and with the accumulator's ``add_rows`` /
+    ``flush_keyed``; :meth:`keys` names unique row ids on the wire."""
+
+    def __init__(self, kv, width: int):
+        self.width = width
+        self.vpk = width if kv.supports_vals_per_key(width) else 1
+
+    def keys(self, rows: np.ndarray) -> np.ndarray:
+        if self.vpk == self.width:
+            return rows.astype(np.uint64)
+        return host_math.expand_block_keys(rows, self.width)
+
+
+def keyed_model(cfg: Config):
+    """``(row width, gradient)`` of a keyed model, None for a dense one:
+    how many values a row of its table holds (one key each, ``RowKeys``)
+    and its gradient wrt a batch's unique rows, host-side."""
+    return {
+        "sparse_lr": (1, host_math.sparse_batch_grad),
+        "sparse_softmax": (cfg.num_classes,
+                           host_math.sparse_softmax_batch_grad),
+        "blocked_lr": (cfg.block_size, host_math.blocked_batch_grad),
+    }.get(cfg.model)
+
+
+class _Exchange:
+    """An exchange is how a round's weights reach :meth:`PSWorker.fit`'s
+    one loop and its gradient the servers: ``weights(keys)``,
+    ``send(g, keys)`` and, at an epoch's end, ``drain()``.  What outlives
+    a ``fit`` stays on the worker: what the benchmark reads (``_w_cache``,
+    ``_comm``) and the staleness stamp (``_w_time``, ``_w_pushes``); its
+    ``kv.pull`` / ``kv.push_pull`` / ``_comm_pool()`` are looked up at
+    every call: taps replace them on the instance.  Each variant stamps
+    where its weights arrive (async only counts: in BSP the weight age is
+    the round); all feed ``_STALENESS`` and the pushes-behind histogram."""
+
+    def __init__(self, worker, accum: GradientAccumulator | None = None):
+        self.w, self.accum = worker, accum
+        self.vpk = worker._rows.vpk if worker._rows is not None else 1
+        self.sync = worker.cfg.sync_mode
+        self._age = None if self.sync else _STALENESS.labels(rank=worker.rank)
+
+    def stamp(self, since: float | None = None) -> None:
+        """The weights under the coming gradients are in: pulled at
+        ``since`` (now: on arrival), the group's push clock as it is."""
+        w = self.w
+        w._w_time = time.perf_counter() if since is None else since
+        w._w_pushes = None if self.sync else w._sample_push_clock()
+
+    def aged(self) -> None:
+        """A gradient is about to leave: the age of the weights under it."""
+        if not self.sync:
+            w = self.w
+            self._age.set(time.perf_counter() - w._w_time)
+            w._record_pushes_behind(w._w_pushes)
+
+    def drain(self):
+        pass
+
+
+class _Serialized(_Exchange):
+    """The reference's protocol (``src/lr.cc:116-132``): pull,
+    then push and wait, two blocking round trips a round.  Dense with
+    ``ps_pipeline=False``, and every keyed model in BOTH modes.  Sync: a
+    pull issued before the round's push would read pre-round weights and
+    change the BSP trajectory.  Async: a comm-thread pipeline (pull k+1
+    overlapping grad k) was measured ~10% SLOWER at CTR scale (4 workers,
+    D=200k, B=512: 560-570k serialized vs ~490-520k pipelined): the
+    per-op executor handoff under GIL contention costs more than the
+    ~50us localhost round trip it hides, and no fused op exists to REMOVE
+    a round trip (pull and push key sets differ per batch)."""
+
+    def weights(self, keys):
+        since = time.perf_counter()  # the stamp: from before the pull
+        got = self.pull(keys)
+        self.stamp(since)
+        return got
+
+    def send(self, g, keys):
+        self.aged()
+        self.push(g, keys)
+
+    def pull(self, keys):
+        w = self.w
+        with w._span("pull"):
+            return w.kv.pull(keys=keys, vals_per_key=self.vpk)
+
+    def push(self, g, keys):
+        w = self.w
+        with w._span("push"):
+            w.kv.wait(w.kv.push(g, keys=keys, vals_per_key=self.vpk))
+
+
+class _DenseSpan(_Serialized):
+    """AdaBatch local accumulation (``--accum-start/--accum-max``) round
+    the serialized exchange: push a span's MEAN every k rounds, k growing
+    on the schedule, which divides push traffic by k on top of the wire
+    codec's ratio; in sync mode the BSP round IS the span, workers in
+    lockstep on the shared schedule.  A dense span pulls once, at its
+    start, and its age runs from that reply's arrival to the flush.
+    Spans flush at an epoch's end too (``drain``: partial), so epochs
+    stay self-contained for eval.  The fused and pipelined protocols are
+    bypassed: the span already removes k-1 of every k round trips,
+    without overlapping state."""
+
+    def weights(self, keys):
+        w = self.w
+        if self.accum.batches == 0:
+            w._w_cache = self.pull(None)
+            self.stamp()
+        return w._w_cache
+
+    def send(self, g, keys):
+        self.accum.add(g)
+        if self.accum.ready:
+            self.drain()
+
+    def drain(self):
+        g = self.accum.flush_dense()
+        if g is not None:
+            super().send(g, None)
+
+
+class _KeyedSpan(_Serialized):
+    """The accumulated exchange of a keyed model: a round pulls its own
+    rows, as serialized, and the flush unions the span's touched rows
+    into ONE keyed frame (deduped keys: fewer keyed bytes on top of the
+    k-fold frequency cut)."""
+
+    def send(self, g, keys):
+        self.aged()
+        self.accum.add_rows(keys, g, self.vpk)
+        if self.accum.ready:
+            self.drain()
+
+    def drain(self):
+        # None: an empty span (no batches), symmetric across workers.  A
+        # span whose gradients cancelled to exact zeros still pushes an
+        # EMPTY keyed frame in sync mode: the BSP "present" vote peers'
+        # deferred replies are waiting on.
+        flushed = self.accum.flush_keyed(self.vpk)
+        if flushed is not None and (flushed[0].size or self.sync):
+            self.push(flushed[1], flushed[0])
+
+
+class _Fused(_Exchange):
+    """BSP: ONE deferred round trip a round, a ``push_pull`` blocking on
+    the loop's own thread, whose reply the worker holds (``_w_cache``) as
+    the next round's weights: the post-round state, what the next pull
+    would return (rounds totally ordered, so the trajectory is the
+    serialized one bit for bit: the oracle parity tests pin it).  A
+    worker that holds no weights yet pulls once, before its first round."""
+
+    def __init__(self, worker):
+        super().__init__(worker)
+        if worker._w_cache is None:
+            with worker._span("pull"):
+                reply = worker.kv.pull()
+            self.arrived(reply)
+
+    def weights(self, keys):
+        return self.w._w_cache
+
+    def arrived(self, reply) -> None:
+        self.w._w_cache = reply
+
+    def send(self, g, keys):
+        w = self.w
+        with w._span("push"):
+            w._w_cache = w.kv.push_pull(g)
+
+
+class _Pipelined(_Fused):
+    """Async (Hogwild): the fused round trip double-buffered against
+    compute on the comm thread: batch k+1's gradient is computed while
+    batch k's ``push_pull`` is in flight, so the weights used are stale
+    by exactly the one in-flight push.  KV ops stay serialized on the
+    comm thread (one connection, never two ops concurrently), and none
+    is in flight across an epoch's end (``drain``)."""
+
+    fut = None
+
+    def arrived(self, reply) -> None:
+        self.w._w_cache = reply
+        self.stamp()
+
+    def send(self, g, keys):
+        w = self.w
+        # g rides weights that arrived at _w_time; its round trip starts
+        # now, so the age at landing is ~this (+ one in-flight RTT, bounded
+        # by the next wait).  The pushes-behind twin: the clock now minus
+        # the clock when _w_cache arrived = peer updates plus our own (<=1)
+        # in-flight fused push.
+        self.aged()
+        self._wait()
+        # the step's dtrace context and its round count ride along
+        # explicitly: the comm thread is a different thread, and the fused
+        # op belongs to the step that SUBMITTED it
+        self.fut = w._comm_pool().submit(
+            w._traced_push_pull, g, dtrace.current(), w.rounds)
+
+    def drain(self):
+        # the epoch's last push: no round's compute is left to hide it
+        self._wait(drain=1)
+
+    def _wait(self, **more):
+        fut, self.fut = self.fut, None
+        if fut is not None:
+            with self.w._span("push", **more):
+                reply = fut.result()
+            self.arrived(reply)
+
+
 class PSWorker:
     """One worker's training loop against a KV server group.
 
@@ -716,7 +777,7 @@ class PSWorker:
     count the windowed rounds and the real rows they read.
 
     Which device.  ``ps_compute_device`` decides host or accelerator
-    from the step's size; which accelerator device is the job's to say
+    from the step's size (no option); which accelerator device is the job's to say
     (``device``: ``run_ps_workers`` hands worker *i* of a process local
     device ``i % len(devices)``, ``worker_devices``), and the shard, the
     round's weights, the step, the gradient's readback and the eval all
@@ -763,16 +824,26 @@ class PSWorker:
     weights' copy still ``in_flight``, and those where it had ``landed``.
 
     ``run()`` is ``load_data()`` (iterators, the device choice, the
-    placement; once), ``start()`` (seed push, start barrier), ``fit()``
-    (the epochs) and ``finish()`` (final pull, export, exit barrier,
-    retiring the group).  ``fit(epochs=E)`` can be called again on the
-    same worker: it loads, places and compiles nothing.
+    placement, the gradient step; once), ``start()`` (seed push, start
+    barrier), ``fit()`` (the epochs) and ``finish()`` (final pull, export,
+    exit barrier, retiring the group).  ``fit(epochs=E)`` can be called
+    again on the same worker: it loads, places and compiles nothing.
+
+    The loop.  An epoch of ``fit()`` is ONE loop, whatever the model and
+    the protocol: a round is its batch (``_rounds``; a keyed one then
+    names its unique rows, ``_keyed_round``), ``exchange.weights``,
+    :attr:`grad_step` (every model's, bound once by ``load_data()``),
+    ``exchange.send``; ``exchange.drain()`` ends the epoch.  The exchange
+    is chosen once a ``fit`` from the config and the model (``_exchange``;
+    ``_Exchange`` and its variants say what each does); how a keyed
+    model's rows cross the wire is ``RowKeys``' to say.
 
     Spans (``obs.tracing.loop_span``: ``PhaseTracer`` and, while a
     profiler trace is taken, a ``TraceAnnotation``) carry ``step`` = the
     worker's round count (:attr:`rounds`) and ``rank``: ``load_data`` and
     ``shard_put`` once; a round: ``data_load`` (fetching the batch: the
-    numpy slice, nothing for a resident shard or a window of one),
+    numpy slice, nothing for a resident shard or a window of one; a
+    keyed batch's unique rows, inside the step),
     ``h2d`` (a streamed batch's put, where the step's device is named:
     none opens in a resident or windowed round), ``w_put`` (the
     weights handed to the runtime for the device: staging and enqueue,
@@ -780,10 +851,10 @@ class PSWorker:
     finished, the rest of the weights' copy before it included; the
     readback is enqueued inside, behind the program), ``grad_d2h`` (the
     rest of that readback), ``push`` (the loop blocked on its
-    exchange; in the pipelined async loop the one that ends an epoch,
+    exchange; in the pipelined exchange the one that ends an epoch,
     with no round's compute left to hide it, carries ``drain=1`` beside
-    ``step`` and ``rank``), ``pull``; ``wire`` on the comm thread (a pipelined fused
-    push-pull, send to reply, with the step that submitted it);
+    ``step`` and ``rank``), ``pull``; ``wire`` on the comm thread (a
+    pipelined push-pull, send to reply, with the step that submitted it);
     ``barrier_wait``, ``eval``, ``checkpoint``; under ``eval`` (rank 0,
     a dense model): ``eval_pull`` (the weights after the round, pulled as
     the reference's ``Test`` pulls them), ``test_put`` (the first eval
@@ -793,7 +864,7 @@ class PSWorker:
     gradient step's), ``eval_d2h``.  Under whichever of them
     is open when a keyed operation returns, ``KVWorker`` records that
     exchange's three phases from the native client's own instants, one
-    site for every loop variant here: ``xchg_send`` (the call's start to
+    site for every exchange here: ``xchg_send`` (the call's start to
     the last request byte handed to the kernel), ``xchg_await`` (to the
     first reply header read: the servers' read, merge, wait for the
     round and release), ``xchg_recv`` (to the last value read).  They are
@@ -851,10 +922,11 @@ class PSWorker:
         self._test_iter = test_iter
         # Keyed models never use the jitted dense-batch fns (their
         # per-batch unique-key count varies, so they run numpy host math
-        # instead — _sparse_batch_grad / _blocked_batch_grad); building
-        # them would plant a lambda whose (X, y, mask) signature crashes
-        # on padded-COO / blocked batches.
-        if cfg.model in ("sparse_lr", "blocked_lr"):
+        # instead, models/host_math.py); building them would plant a
+        # lambda whose (X, y, mask) signature crashes on padded-COO /
+        # blocked batches.
+        self._keyed = keyed_model(cfg)
+        if self._keyed is not None:
             self._grad_fn = self._acc_fn = None
         else:
             self._grad_fn = _compiled_fns(self.model, cfg.l2_c, bool(cfg.l2_scale_by_batch))
@@ -899,14 +971,20 @@ class PSWorker:
         self._test_resident = None
         #: the one-pass step's plan where the resident X is held for it
         self._panels = None
-        #: dense models: ``(flat weights, batch) -> flat float32
-        #: gradient`` on the device load_data() picked
+        #: ``(flat weights, batch) -> flat float32 gradient``, bound by
+        #: load_data(): a dense model's on the device it picked, a keyed
+        #: model's in numpy over the batch's unique rows
         self.grad_step = None
-        # pipelined dense path state: last fused-reply weights, and a
-        # single comm thread (KV ops must never overlap on one connection)
+        #: keyed models: how the connection addresses their rows
+        self._rows: RowKeys | None = None
+        # what the loop's exchange keeps here (``_Exchange``): the flat
+        # weights the loop holds now (a span's pull or a fused reply), the
+        # staleness stamp of the weights under the next gradient (when
+        # they were pulled, the group's push clock then), and a single
+        # comm thread (KV ops must never overlap on one connection)
         self._w_cache: np.ndarray | None = None
-        self._w_time = 0.0  # when _w_cache was pulled (staleness gauge)
-        self._w_pushes: float | None = None  # push clock at _w_cache arrival
+        self._w_time = 0.0
+        self._w_pushes: float | None = None
         self._comm = None
         if cfg.model in ("sparse_lr", "blocked_lr") and cfg.l2_c > 0:
             # Keyed PS applies L2 lazily (only a batch's touched keys/rows
@@ -1042,12 +1120,36 @@ class PSWorker:
             test = self._test_iter if self._test_iter is not None else (
                 self._load_test_iter() if self.rank == 0 else None)
             if self._grad_fn is None:
-                log.info("rank %d %s steps and eval run in numpy on the "
-                         "host (keyed models never use the accelerator)",
-                         self.rank, self.cfg.model)
+                self._bind_keyed_step()
             else:
                 self._bind_dense_step(train, test)
         self._train, self._test = train, test
+
+    def _bind_keyed_step(self) -> None:
+        """Keyed Push/Pull: only a batch's unique touched columns (sparse)
+        or table rows (blocked, sparse softmax) travel: ps-lite's
+        sliced-key capability, SURVEY.md §2.2 E1.d/g, which the reference
+        app itself never exercises.  The step is numpy's, on the host."""
+        cfg = self.cfg
+        width, grad = self._keyed
+        self._rows = rows = RowKeys(self.kv, width)
+        log.info("rank %d %s steps and eval run in numpy on the host "
+                 "(keyed models never use the accelerator)",
+                 self.rank, cfg.model)
+        if width > 1:
+            # visible (and test-assertable) record of which wire encoding
+            # the keyed rounds use
+            log.info("rank %d keyed wire encoding: %s", self.rank,
+                     f"vals_per_key={rows.vpk}" if rows.vpk > 1
+                     else "expanded per-lane keys")
+        l2 = (cfg.l2_c, bool(cfg.l2_scale_by_batch))
+
+        def grad_step(w_u, batch):
+            with self._span("compute", marks_step=True):
+                if width > 1:
+                    w_u = w_u.reshape(-1, width)
+                return grad(w_u, *batch, *l2).reshape(-1)
+        self.grad_step = grad_step
 
     def _bind_dense_step(self, train, test) -> None:
         cfg = self.cfg
@@ -1062,17 +1164,16 @@ class PSWorker:
             ps_compute_device(cfg, test.num_samples, self._device)
             if test is not None else None)
         log.info(
-            "rank %d dense steps pinned: train -> %s%s (ps_compute_backend=%s)",
+            "rank %d dense steps pinned: train -> %s%s",
             self.rank, _describe_compute_device(step_dev),
             "" if test is None
-            else f", eval -> {_describe_compute_device(self._eval_dev)}",
-            cfg.ps_compute_backend)
+            else f", eval -> {_describe_compute_device(self._eval_dev)}")
         K = cfg.num_classes if cfg.model == "softmax" else None
         if step_dev == "numpy":
             def grad_step(wf, batch):
                 W = wf.reshape(cfg.num_feature_dim, K) if K else wf
                 with self._span("compute", marks_step=True):
-                    return _np_dense_grad(
+                    return host_math.dense_grad(
                         W, *batch, cfg.l2_c, bool(cfg.l2_scale_by_batch), K
                     ).reshape(-1)
         else:
@@ -1101,10 +1202,10 @@ class PSWorker:
                 # The fence on ``wf``: the readback cannot end before the
                 # program has run, nor the program start before the copy
                 # has left the host, so ``wf`` is free again when this
-                # returns and nobody may write it before.  The loops hand
-                # in what ``pull`` / ``push_pull`` returned, a fresh array
-                # every reply and never written in place; a client that
-                # keeps its reply buffer has to keep this fence.
+                # returns and nobody may write it before.  The exchanges
+                # hand in what ``pull`` / ``push_pull`` returned, a fresh
+                # array every reply and never written in place; a client
+                # that keeps its reply buffer has to keep this fence.
                 #
                 # the plan goes with the resident rows alone: their X is
                 # held for it (``_place_shard``)
@@ -1213,22 +1314,35 @@ class PSWorker:
             placed = jax.block_until_ready((X, y, mask))
         return placed, plan
 
-    def _batches(self, train):
-        """An epoch's dense batches, each beside its count of real rows;
-        ``data_load`` is what fetching one cost the loop: the numpy slice
-        of a streamed batch, nothing for a resident shard, of which a
-        minibatch is a :class:`~distlr_tpu.data.iterator.Window`."""
-        resident, windowed = self._resident, self._windowed
+    def _rounds(self, train):
+        """An epoch's rounds: each batch beside its count of real rows.
+        ``data_load`` is what fetching a dense one cost the loop: the
+        numpy slice of a streamed batch; nothing for a resident shard, of
+        which a minibatch is a :class:`~distlr_tpu.data.iterator.Window`.
+        A keyed batch comes as the iterator has it: its ``data_load`` is
+        :meth:`_keyed_round`'s, inside the step."""
+        keyed, resident, windowed = (
+            self._rows is not None, self._resident, self._windowed)
         for _ in range(train.num_batches):
             self.rounds += 1
-            with self._span("data_load"):
-                if resident is None:
-                    batch = train.next_batch()
-                else:
-                    batch = train.next_window() if windowed else resident
+            if keyed:
+                batch = train.next_batch()
+            else:
+                with self._span("data_load"):
+                    batch = (train.next_batch() if resident is None
+                             else train.next_window() if windowed
+                             else resident)
             yield batch, (int(batch[-1].sum()) if resident is None
                           else batch.rows if windowed
                           else self._resident_rows)
+
+    def _keyed_round(self, batch):
+        """A keyed round's ``data_load``: the batch's unique rows as wire
+        keys, and each entry's place among them in the ids' stead."""
+        with self._span("data_load"):
+            ids = batch[0]
+            ub, pos = np.unique(ids, return_inverse=True)
+            return (pos.reshape(ids.shape), *batch[1:]), self._rows.keys(ub)
 
     def start(self, *, resume=False, rejoin=False) -> None:
         """Seed the group (rank 0) and meet the peers at the start
@@ -1313,263 +1427,49 @@ class PSWorker:
             json.dump({"epoch": epoch, "attempt": self._sidecar_attempt}, f)
         os.replace(tmp, sidecar)
 
-    def _flush_keyed_accum(self, accum: GradientAccumulator,
-                           vpk: int) -> None:
-        """Push one keyed accumulation span (mean gradient over the
-        span's touched rows).  A span whose gradients cancelled to exact
-        zeros still pushes an EMPTY keyed frame in sync mode — the BSP
-        "present" vote peers' deferred replies are waiting on."""
-        res = accum.flush_keyed(vpk)
-        if res is None:
-            return  # empty span (no batches) — symmetric across workers
-        rows, vals = res
-        if rows.size == 0 and not self.cfg.sync_mode:
-            return
-        with self._span("push"):
-            self.kv.wait(self.kv.push(vals, keys=rows, vals_per_key=vpk))
-
-    def _flush_dense_accum(self, accum: GradientAccumulator) -> None:
-        """Push one dense accumulation span (mean gradient)."""
-        g = accum.flush_dense()
-        if g is None:
-            return
-        if not self.cfg.sync_mode:
-            _STALENESS.labels(rank=self.rank).set(
-                time.perf_counter() - self._w_time)
-            self._record_pushes_behind(self._w_pushes)
-        with self._span("push"):
-            self.kv.wait(self.kv.push(g))
+    def _exchange(self) -> _Exchange:
+        """The exchange of this worker's rounds, chosen once a
+        :meth:`fit` from the config and the model."""
+        cfg = self.cfg
+        keyed = self._rows is not None
+        if cfg.ps_accum_max > 1:
+            return (_KeyedSpan if keyed else _DenseSpan)(
+                self, GradientAccumulator(
+                    self._param_dim(), start=cfg.ps_accum_start,
+                    growth=cfg.ps_accum_growth,
+                    growth_every=cfg.ps_accum_growth_every,
+                    max_k=cfg.ps_accum_max,
+                    gauge=_ACCUM_K.labels(rank=str(self.rank))))
+        if keyed or not cfg.ps_pipeline:
+            return _Serialized(self)
+        return _Fused(self) if cfg.sync_mode else _Pipelined(self)
 
     def fit(self, epochs: int | None = None, *, eval_fn=None,
             ckpt=None) -> None:
         """Run ``epochs`` more epochs (default: what is left of
         ``cfg.num_iteration``) from :attr:`epochs_done`, against a group
-        :meth:`start` has seeded.  Leaves no exchange in flight."""
+        :meth:`start` has seeded (class docstring: the loop).  Leaves no
+        exchange in flight."""
         cfg = self.cfg
         self.load_data()
         train, test = self._train, self._test
 
-        # AdaBatch local accumulation (--accum-start/--accum-max): push
-        # the span's MEAN every k batches, k growing on the schedule —
-        # divides push traffic by k on top of the wire codec's ratio.
-        # Spans flush at epoch end too (partial), so epochs stay
-        # self-contained for eval and BSP workers stay in lockstep.
-        accum = None
-        if cfg.ps_accum_max > 1:
-            accum = GradientAccumulator(
-                self._param_dim(), start=cfg.ps_accum_start,
-                growth=cfg.ps_accum_growth,
-                growth_every=cfg.ps_accum_growth_every,
-                max_k=cfg.ps_accum_max,
-                gauge=_ACCUM_K.labels(rank=str(self.rank)))
-
-        sparse = cfg.model in ("sparse_lr", "sparse_softmax")
-        blocked = cfg.model == "blocked_lr"
-        # keyed rows wider than one value: blocked tables gather R-lane
-        # rows, sparse softmax gathers K-class rows — both ride the
-        # vals_per_key wire encoding where the group's ranges align
-        row_width = (cfg.block_size if blocked
-                     else cfg.num_classes if cfg.model == "sparse_softmax"
-                     else 1)
-        compute_g = self.grad_step
+        exchange = self._exchange()
+        grad_step = self.grad_step
+        keyed, keys = self._rows is not None, None
         first = self.epochs_done
         last = cfg.num_iteration if epochs is None else first + epochs
         for epoch in range(first, last):
             train.reset()
-            if sparse or blocked:
-                # Keyed Push/Pull: only the batch's unique touched columns
-                # (sparse) / R-wide block-row key ranges (blocked) travel —
-                # ps-lite's sliced-key capability, SURVEY.md §2.2 E1.d/g,
-                # which the reference app itself never exercises.
-                # Blocked rows prefer the vals_per_key wire encoding
-                # (one u64 row id per R-lane row, ps-lite lens-style —
-                # ~2.7x fewer keyed bytes at R=32 than R expanded keys);
-                # groups whose range boundaries don't align to R fall
-                # back to the expanded encoding, bit-identical
-                # semantics either way (the server walks rows and flat
-                # keys with the same loops, slot for slot).
-                vpk = (row_width
-                       if row_width > 1 and self.kv.supports_vals_per_key(
-                           row_width)
-                       else 1)
-                if row_width > 1 and epoch == first:
-                    # visible (and test-assertable) record of which wire
-                    # encoding the keyed rounds actually used
-                    log.info(
-                        "rank %d keyed wire encoding: %s", self.rank,
-                        f"vals_per_key={vpk}" if vpk > 1
-                        else "expanded per-lane keys")
-
-                def prep(b):
-                    ids = b[0]
-                    ub, pos = np.unique(ids, return_inverse=True)
-                    if row_width > 1 and vpk == 1:
-                        keys = _expand_block_keys(ub, row_width)
-                    else:
-                        keys = ub.astype(np.uint64)
-                    return keys, (pos.reshape(ids.shape), *b[1:])
-
-                def kgrad(w_u, rest):
-                    if blocked:
-                        pos, lane_vals, y, mask = rest
-                        return _blocked_batch_grad(
-                            w_u.reshape(-1, cfg.block_size), pos, lane_vals,
-                            y, mask, cfg.l2_c, bool(cfg.l2_scale_by_batch),
-                        ).reshape(-1)
-                    pos, vals, y, mask = rest
-                    if cfg.model == "sparse_softmax":
-                        return _sparse_softmax_batch_grad(
-                            w_u.reshape(-1, cfg.num_classes), pos, vals,
-                            y, mask, cfg.l2_c, bool(cfg.l2_scale_by_batch),
-                        ).reshape(-1)
-                    return _sparse_batch_grad(
-                        w_u, pos, vals, y, mask,
-                        cfg.l2_c, bool(cfg.l2_scale_by_batch),
-                    )
-
-                # Keyed rounds stay serialized in BOTH modes.  Sync: a
-                # pull issued before the round's push would read pre-round
-                # weights and change the BSP trajectory.  Async: a
-                # comm-thread pipeline (pull k+1 overlapping grad k) was
-                # measured ~10% SLOWER at CTR scale (4 workers, D=200k,
-                # B=512: 560-570k serialized vs ~490-520k pipelined) — the
-                # per-op executor handoff under GIL contention costs more
-                # than the ~50us localhost round trip it hides; unlike the
-                # dense path, there is no fused op here to REMOVE a round
-                # trip (pull and push key sets differ per batch).
-                for b in train:
-                    self.rounds += 1
-                    self.timer.start()
-                    with self._span("data_load"):
-                        keys, rest = prep(b)
-                    t_pull = time.perf_counter()
-                    with self._span("pull"):
-                        w_u = self.kv.pull(keys=keys, vals_per_key=vpk)
-                    p0 = None if cfg.sync_mode else self._sample_push_clock()
-                    with self._span("compute", marks_step=True):
-                        g = kgrad(w_u, rest)
-                    if not cfg.sync_mode:
-                        _STALENESS.labels(rank=self.rank).set(
-                            time.perf_counter() - t_pull)
-                        self._record_pushes_behind(p0)
-                    if accum is not None:
-                        # accumulate at the batch's own key granularity;
-                        # the flush unions the span's touched rows into
-                        # ONE keyed frame (deduped keys = fewer keyed
-                        # bytes on top of the k-fold frequency cut)
-                        if vpk > 1:
-                            accum.add_rows(keys, g, vpk)
-                        else:
-                            accum.add_at(keys, g)
-                        if accum.ready:
-                            self._flush_keyed_accum(accum, vpk)
-                    else:
-                        with self._span("push"):
-                            self.kv.wait(self.kv.push(g, keys=keys,
-                                                      vals_per_key=vpk))
-                    self.timer.stop(int(b[-1].sum()))
-                if accum is not None:
-                    self._flush_keyed_accum(accum, vpk)
-            elif accum is not None:
-                # Dense + AdaBatch accumulation: pull once per span,
-                # compute k batches against the span's weights, push the
-                # mean (one PS round per span — in sync mode the BSP
-                # round IS per span, workers in lockstep on the shared
-                # schedule).  The fused/pipelined dense protocols are
-                # bypassed: the span already removes k-1 of every k
-                # round trips, which is the same wall-clock win
-                # pipelining buys, without overlapping state.
-                for batch, n_real in self._batches(train):
-                    self.timer.start()
-                    if accum.batches == 0:
-                        with self._span("pull"):
-                            self._w_cache = self.kv.pull()
-                        self._w_time = time.perf_counter()
-                        self._w_pushes = (None if cfg.sync_mode
-                                          else self._sample_push_clock())
-                    accum.add(compute_g(self._w_cache, batch))
-                    if accum.ready:
-                        self._flush_dense_accum(accum)
-                    self.timer.stop(n_real)
-                self._flush_dense_accum(accum)
-            elif not cfg.ps_pipeline:
-                # Reference-faithful serialized protocol: two blocking
-                # round trips per batch (src/lr.cc:116-132).
-                for batch, n_real in self._batches(train):
-                    self.timer.start()
-                    t_pull = time.perf_counter()
-                    with self._span("pull"):
-                        w = self.kv.pull()
-                    p0 = None if cfg.sync_mode else self._sample_push_clock()
-                    g = compute_g(w, batch)
-                    if not cfg.sync_mode:
-                        _STALENESS.labels(rank=self.rank).set(
-                            time.perf_counter() - t_pull)
-                        self._record_pushes_behind(p0)
-                    with self._span("push"):
-                        self.kv.wait(self.kv.push(g))
-                    self.timer.stop(n_real)
-            elif cfg.sync_mode:
-                # Fused BSP: ONE deferred round trip per batch; the reply
-                # is the post-round weights = what the next pull would
-                # return (rounds totally ordered -> bit-identical
-                # trajectory, pinned by the oracle parity tests).
-                if self._w_cache is None:
-                    with self._span("pull"):
-                        self._w_cache = self.kv.pull()
-                for batch, n_real in self._batches(train):
-                    self.timer.start()
-                    g = compute_g(self._w_cache, batch)
-                    with self._span("push"):
-                        self._w_cache = self.kv.push_pull(g)
-                    self.timer.stop(n_real)
-            else:
-                # Pipelined async (Hogwild): fused round trips double-
-                # buffered against compute — batch k+1's gradient is
-                # computed while batch k's push_pull is in flight.  The
-                # weights used are stale by exactly the one in-flight
-                # push; KV ops stay serialized on the comm thread (one
-                # connection, never two ops concurrently).
-                if self._w_cache is None:
-                    with self._span("pull"):
-                        self._w_cache = self.kv.pull()
-                    self._w_time = time.perf_counter()
-                    self._w_pushes = self._sample_push_clock()
-                fut = None
-                for batch, n_real in self._batches(train):
-                    self.timer.start()
-                    g = compute_g(self._w_cache, batch)
-                    # g rides weights pulled at _w_time; its round trip
-                    # starts now — the age at landing is ~this (+ one
-                    # in-flight RTT, bounded by the next result() wait)
-                    _STALENESS.labels(rank=self.rank).set(
-                        time.perf_counter() - self._w_time)
-                    # pushes-behind twin: clock now minus the clock when
-                    # _w_cache arrived — peer updates plus our own (<=1)
-                    # in-flight fused push, i.e. exactly how many updates
-                    # behind the weights under this gradient are
-                    self._record_pushes_behind(self._w_pushes)
-                    if fut is not None:
-                        with self._span("push"):
-                            self._w_cache = fut.result()
-                        self._w_time = time.perf_counter()
-                        self._w_pushes = self._sample_push_clock()
-                    # the step's dtrace context and its round count ride
-                    # along explicitly: the comm thread is a different
-                    # thread, and the fused op belongs to the step that
-                    # SUBMITTED it
-                    fut = self._comm_pool().submit(
-                        self._traced_push_pull, g, dtrace.current(),
-                        self.rounds)
-                    self.timer.stop(n_real)
-                if fut is not None:
-                    # the epoch's drain: no round's compute is left to
-                    # hide this push, and none is in flight across an
-                    # epoch's end
-                    with self._span("push", drain=1):
-                        self._w_cache = fut.result()
-                    self._w_time = time.perf_counter()
-                    self._w_pushes = self._sample_push_clock()
+            for batch, n_real in self._rounds(train):
+                self.timer.start()
+                if keyed:
+                    batch, keys = self._keyed_round(batch)
+                w = exchange.weights(keys)
+                g = grad_step(w, batch)
+                exchange.send(g, keys)
+                self.timer.stop(n_real)
+            exchange.drain()
             # runtime introspection (obs.jaxrt): fold this epoch's jit
             # cache growth into distlr_jax_compiles_total and refresh
             # the live device-buffer gauges (walk throttled process-wide)
@@ -1609,15 +1509,10 @@ class PSWorker:
         """``(accuracy, logloss)`` on the test split: of what the servers
         hold now or, for a dense model, of the flat weights ``w``.  The
         eval the epoch loop runs on rank 0."""
-        cfg, test = self.cfg, self._test
-        if cfg.model == "sparse_softmax":
-            got = self._sparse_softmax_eval(test)
-        elif cfg.model == "sparse_lr":
-            got = self._sparse_eval(test)
-        elif cfg.model == "blocked_lr":
-            got = self._blocked_eval(test)
-        else:
+        test = self._test
+        if self._rows is None:
             return self._dense_eval(w, test)
+        got = self._keyed_eval(test)
         self._count_eval(test.num_samples)
         return got
 
@@ -1644,7 +1539,7 @@ class PSWorker:
             K = cfg.num_classes if cfg.model == "softmax" else None
             Xt, yt, mt = self._test_batch(test)
             self._count_eval(int(mt.sum()))
-            return _np_dense_eval(
+            return host_math.dense_eval(
                 w.reshape(cfg.num_feature_dim, K) if K else w,
                 Xt, yt, mt.astype(np.float32), K)
         batch, how, rows = self._test_on_device(test)
@@ -1722,63 +1617,26 @@ class PSWorker:
             self.kv.shutdown_servers()
         return self.final_weights
 
-    @staticmethod
-    def _eval_from_logits(z, y, mask) -> tuple[float, float]:
-        """(accuracy, logloss) from ONE forward pass's logits — numpy,
-        host-side (the keyed eval paths are exactly the small-step regime
-        where a second full-test-set forward would double the eval cost)."""
-        return _binary_eval_from_logits(z, y, mask)
-
-    def _blocked_eval(self, test) -> tuple[float, float]:
-        """Full-test-set ``(accuracy, logloss)``: keyed pull of the test
-        set's unique block rows, scattered into a full (num_blocks, R)
-        table."""
+    def _keyed_eval(self, test) -> tuple[float, float]:
+        """Full-test-set ``(accuracy, logloss)`` of a keyed model: a
+        keyed pull of the rows the split touches, scattered into a full
+        table, then ONE forward pass for both numbers (numpy, host-side:
+        the keyed evals are exactly the small-step regime where a second
+        full-test-set forward would double the eval cost)."""
+        rows, model = self._rows, self.cfg.model
         test.reset()
-        blocks, lane_vals, y, mask = test.next_batch()
-        R = self.cfg.block_size
-        ub = np.unique(blocks)
-        t = np.zeros((self.cfg.num_feature_dim // R, R), np.float32)
-        if self.kv.supports_vals_per_key(R):
-            pulled = self.kv.pull(keys=ub.astype(np.uint64), vals_per_key=R)
-        else:
-            pulled = self.kv.pull(keys=_expand_block_keys(ub, R))
-        t[ub] = pulled.reshape(len(ub), R)
-        z = (t[blocks] * lane_vals).sum(axis=(-1, -2))
-        return self._eval_from_logits(z, y, mask)
-
-    def _sparse_eval(self, test) -> tuple[float, float]:
-        """Full-test-set ``(accuracy, logloss)``: keyed pull of the test
-        set's unique columns scattered into a full-width vector, then one
-        forward pass for both metrics."""
-        test.reset()
-        cols, vals, y, mask = test.next_batch()
-        keys = np.unique(cols).astype(np.uint64)
-        w = np.zeros(self.cfg.num_feature_dim, np.float32)
-        w[keys] = self.kv.pull(keys=keys)
-        z = (w[cols] * vals).sum(axis=-1)
-        return self._eval_from_logits(z, y, mask)
-
-    def _sparse_softmax_eval(self, test) -> tuple[float, float]:
-        """Full-test-set ``(accuracy, cross-entropy)``: keyed pull of the
-        test set's unique (D, K) rows (vals_per_key=K where the group's
-        ranges align), scattered into a full table."""
-        test.reset()
-        cols, vals, y, mask = test.next_batch()
-        K = self.cfg.num_classes
-        ub = np.unique(cols).astype(np.uint64)
-        W = np.zeros((self.cfg.num_feature_dim, K), np.float32)
-        if self.kv.supports_vals_per_key(K):
-            pulled = self.kv.pull(keys=ub, vals_per_key=K)
-        else:
-            pulled = self.kv.pull(keys=_expand_block_keys(ub, K))
-        W[ub] = pulled.reshape(len(ub), K)
-        z = np.asarray((W[cols] * vals[..., None]).sum(axis=1), np.float64)
-        m = np.asarray(mask, np.float64)
-        n = max(m.sum(), 1.0)
-        acc = float(((z.argmax(axis=1) == y) * m).sum() / n)
-        zs = z - z.max(axis=1, keepdims=True)
-        ll = np.log(np.exp(zs).sum(axis=1)) - zs[np.arange(len(y)), y]
-        return acc, float((ll * m).sum() / n)
+        ids, vals, y, mask = test.next_batch()
+        ub = np.unique(ids)
+        table = np.zeros((self._param_dim() // rows.width, rows.width),
+                         np.float32)
+        table[ub] = self.kv.pull(keys=rows.keys(ub), vals_per_key=rows.vpk
+                                 ).reshape(len(ub), rows.width)
+        if model == "sparse_softmax":
+            return host_math.softmax_eval_from_logits(
+                (table[ids] * vals[..., None]).sum(axis=1), y, mask)
+        z = ((table[ids] * vals).sum(axis=(-1, -2)) if model == "blocked_lr"
+             else (table[ids, 0] * vals).sum(axis=-1))
+        return host_math.binary_eval_from_logits(z, y, mask)
 
     @staticmethod
     def _place(device, *arrays):

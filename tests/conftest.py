@@ -38,6 +38,7 @@ other clauses are held, without the place, by
 test_the_entries_that_were_there_are_as_they_were``.
 """
 
+import contextlib
 import os
 import sys
 
@@ -76,3 +77,42 @@ def pytest_collection_modifyitems(items):
                     raises=AssertionError, strict=True,
                     reason=f"holds {pr}'s entries to the end of per_layer, "
                            "where later PRs must append (ROADMAP S3)"))
+
+
+@pytest.fixture(scope="session")
+def ps_steps_on():
+    """``with ps_steps_on(where):`` a PS worker's dense step and eval go
+    where a real job's go at ITS size, at a test's size:
+    ``ps_compute_device`` chooses from ``param_dim x rows`` and its two
+    thresholds and has no override, so a test moves the thresholds.
+    ``"device"``: the worker's device of the default backend (the step
+    is over both thresholds); ``"cpu"``: the jitted host CPU (between
+    them; the default backend has to be an accelerator for that to be
+    another device); ``"numpy"``: plain numpy (under both); ``"size"``:
+    the real thresholds, inside a ``with`` that moved them."""
+    from distlr_tpu.train import ps_trainer
+
+    real = (ps_trainer._PS_AUTO_NUMPY_THRESHOLD,
+            ps_trainer._PS_AUTO_CPU_THRESHOLD)
+    picks = {"device": (0, 0), "cpu": (0, 1 << 62),
+             "numpy": (1 << 62, 1 << 62), "size": real}
+
+    @contextlib.contextmanager
+    def pick(where):
+        numpy_below, cpu_below = picks[where]
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(ps_trainer, "_PS_AUTO_NUMPY_THRESHOLD", numpy_below)
+            mp.setattr(ps_trainer, "_PS_AUTO_CPU_THRESHOLD", cpu_below)
+            yield
+
+    return pick
+
+
+@pytest.fixture
+def ps_steps_on_device(ps_steps_on):
+    """The jitted step on the worker's device, for a module that asks
+    (``pytestmark = pytest.mark.usefixtures("ps_steps_on_device")``): by
+    their size a test's tiny steps would go to numpy, where nothing is
+    placed and no chain runs."""
+    with ps_steps_on("device"):
+        yield

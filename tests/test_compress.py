@@ -535,8 +535,8 @@ class TestAccumulator:
 
     def test_flush_keyed_unions_touched_rows(self):
         a = GradientAccumulator(8, start=2, max_k=2)
-        a.add_at(np.array([1, 3]), np.array([1.0, 1.0], np.float32))
-        a.add_at(np.array([3, 5]), np.array([1.0, 3.0], np.float32))
+        a.add_rows(np.array([1, 3]), np.array([1.0, 1.0], np.float32), vpk=1)
+        a.add_rows(np.array([3, 5]), np.array([1.0, 3.0], np.float32), vpk=1)
         keys, vals = a.flush_keyed()
         np.testing.assert_array_equal(keys, [1, 3, 5])
         np.testing.assert_array_equal(vals, [0.5, 1.0, 1.5])

@@ -236,15 +236,21 @@ class TestPSComputeDevice:
     """PS workers pick the step device by workload size (dispatch-latency
     avoidance for tiny reference-scale models)."""
 
-    def test_forced_choices(self):
+    def test_a_size_between_the_thresholds_picks_the_host_cpu(
+            self, monkeypatch, ps_steps_on):
+        import jax
+
         from distlr_tpu.train.ps_trainer import ps_compute_device
 
-        cfg = Config(num_feature_dim=16)
-        assert ps_compute_device(cfg.replace(ps_compute_backend="default")) is None
-        dev = ps_compute_device(cfg.replace(ps_compute_backend="cpu"))
+        cfg = Config(num_feature_dim=16, batch_size=64)
+        with ps_steps_on("device"):
+            assert ps_compute_device(cfg, rows=64) is None
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with ps_steps_on("cpu"):
+            dev = ps_compute_device(cfg, rows=64)
         assert dev is not None and dev.platform == "cpu"
 
-    def test_auto_thresholds(self, monkeypatch):
+    def test_auto_thresholds(self, monkeypatch, ps_steps_on):
         import jax
 
         from distlr_tpu.train import ps_trainer
@@ -267,9 +273,9 @@ class TestPSComputeDevice:
         assert ps_trainer.ps_compute_device(small.replace(batch_size=-1), rows=2000) == "numpy"
         assert ps_trainer.ps_compute_device(mid.replace(batch_size=-1), rows=1000).platform == "cpu"
         assert ps_trainer.ps_compute_device(small, rows=5_000_000) is None
-        # forced numpy
-        assert ps_trainer.ps_compute_device(
-            big.replace(ps_compute_backend="numpy")) == "numpy"
+        # under a threshold that is over it, the big step is numpy's too
+        with ps_steps_on("numpy"):
+            assert ps_trainer.ps_compute_device(big) == "numpy"
 
     def test_auto_on_cpu_platform_is_default(self):
         # Under the test conftest the default backend IS cpu: auto must
@@ -278,9 +284,9 @@ class TestPSComputeDevice:
 
         assert ps_compute_device(Config(num_feature_dim=123, batch_size=256)) is None
 
-    def test_invalid_choice_rejected(self):
-        with pytest.raises(ValueError, match="ps_compute_backend"):
-            Config(ps_compute_backend="gpu")
+    def test_no_option_overrides_the_choice(self):
+        with pytest.raises(TypeError, match="ps_compute_backend"):
+            Config(ps_compute_backend="numpy")
 
 
 class TestKeyedOps:

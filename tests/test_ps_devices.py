@@ -32,6 +32,9 @@ from distlr_tpu.train.ps_trainer import (
 DIM = 24
 
 
+pytestmark = pytest.mark.usefixtures("ps_steps_on_device")
+
+
 def _cfg(tmp_path, num_workers, **kw):
     d = str(tmp_path / f"job-{num_workers}")
     write_synthetic_shards(d, 64 * num_workers, DIM, num_parts=num_workers,
@@ -39,7 +42,7 @@ def _cfg(tmp_path, num_workers, **kw):
     base = dict(data_dir=d, num_feature_dim=DIM, model="binary_lr",
                 num_workers=num_workers, num_servers=2, sync_mode=True,
                 batch_size=-1, num_iteration=3, learning_rate=0.2, l2_c=0.0,
-                test_interval=0, ps_compute_backend="default")
+                test_interval=0)
     return Config(**{**base, **kw})
 
 
@@ -103,21 +106,25 @@ def test_worker_i_of_a_process_takes_device_i_and_more_workers_wrap(
     assert worker_devices(4) == [local[0]] * 4
 
 
-def test_the_device_stands_where_the_choice_was_the_default_backend_only():
+def test_the_device_stands_where_the_choice_was_the_default_backend_only(
+        monkeypatch, ps_steps_on):
     dev = jax.local_devices()[3]
     big = Config(num_feature_dim=1 << 20, batch_size=64)
     assert ps_compute_device(big) is None
     assert ps_compute_device(big, device=dev) is dev
-    assert ps_compute_device(big.replace(ps_compute_backend="default"),
-                             device=dev) is dev
     assert ps_compute_device(big.replace(batch_size=-1), device=dev) is dev
-    # the thresholds keep deciding host or accelerator
     small = Config(num_feature_dim=123, batch_size=64)
-    assert ps_compute_device(small, 64, device=dev) == "numpy"
-    assert ps_compute_device(small.replace(ps_compute_backend="numpy"),
-                             64, device=dev) == "numpy"
-    cpu = ps_compute_device(small.replace(ps_compute_backend="cpu"),
-                            64, device=dev)
+    # a step over both thresholds, whatever its size
+    assert ps_compute_device(small, 64, device=dev) is dev
+    # the thresholds keep deciding host or accelerator: under both ...
+    with ps_steps_on("size"):
+        assert ps_compute_device(small, 64, device=dev) == "numpy"
+    with ps_steps_on("numpy"):
+        assert ps_compute_device(big, 64, device=dev) == "numpy"
+    # ... and between them, where the default backend is an accelerator
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with ps_steps_on("cpu"):
+        cpu = ps_compute_device(small, 64, device=dev)
     assert cpu.platform == "cpu" and cpu is jax.devices("cpu")[0]
 
 
@@ -243,7 +250,7 @@ def test_a_four_device_bsp_fit_follows_the_reference_round(tmp_path):
     cfg = Config(data_dir=str(d), num_feature_dim=dim, model="binary_lr",
                  num_workers=workers, num_servers=2, sync_mode=True,
                  batch_size=-1, num_iteration=rounds, learning_rate=lr,
-                 l2_c=0.0, test_interval=0, ps_compute_backend="default")
+                 l2_c=0.0, test_interval=0)
     w0 = (np.random.default_rng(3).standard_normal(dim) * 0.01).astype(
         np.float32)
     seen = {r: [] for r in range(workers)}

@@ -14,19 +14,22 @@ from chipbench.families import dense_ps_bsp_eval
 from distlr_tpu.config import Config
 from distlr_tpu.data.iterator import DataIter
 from distlr_tpu.data.synthetic import write_synthetic_shards
+from distlr_tpu.models import host_math
 from distlr_tpu.obs.registry import family_total, get_registry
 from distlr_tpu.obs.tracing import get_tracer
 from distlr_tpu.ps import KVWorker, ServerGroup
 from distlr_tpu.train import ps_trainer
 from distlr_tpu.train.ps_trainer import (
     PSWorker,
-    _np_dense_eval,
     ps_param_dim,
     run_ps_local,
 )
 
 DIM, ROWS, TEST_ROWS = 300, 96, 24
 H2D = "distlr_h2d_bytes_total"
+
+
+pytestmark = pytest.mark.usefixtures("ps_steps_on_device")
 
 
 def _cfg(tmp_path, workers=1, **kw):
@@ -37,8 +40,7 @@ def _cfg(tmp_path, workers=1, **kw):
     base = dict(data_dir=d, num_feature_dim=DIM, model="binary_lr",
                 num_workers=workers, num_servers=2, sync_mode=True,
                 batch_size=-1, num_iteration=30, learning_rate=0.2, l2_c=0.0,
-                test_interval=10, compute_dtype="float32",
-                ps_compute_backend="default")
+                test_interval=10, compute_dtype="float32")
     return Config(**{**base, **kw})
 
 
@@ -93,7 +95,7 @@ def test_the_resident_eval_is_the_references_and_numpys(tmp_path, monkeypatch,
                 ref_acc, ref_ll, z = dense_ps_bsp_eval.evaluate(
                     weights, *_as_coo(X), y)
                 assert abs(ll - ref_ll) <= 2e-6 * ref_ll
-                np_acc, np_ll = _np_dense_eval(weights, X, y,
+                np_acc, np_ll = host_math.dense_eval(weights, X, y,
                                                mask.astype(np.float32))
                 assert abs(ll - np_ll) <= 2e-6 * np_ll
                 # every row's class, but one whose logit is a rounding from 0
@@ -208,10 +210,10 @@ def test_a_backend_that_keeps_no_count_of_its_memory_is_the_hosts(monkeypatch):
 
 @pytest.mark.parametrize("eval_dev", ["numpy", "jax"])
 def test_an_eval_gathers_no_row_where_the_batch_is_the_iterators_arrays(
-        tmp_path, monkeypatch, eval_dev):
-    cfg = _cfg(tmp_path, ps_compute_backend=(
-        "numpy" if eval_dev == "numpy" else "default"))
-    with _group(cfg) as group:
+        tmp_path, monkeypatch, eval_dev, ps_steps_on):
+    cfg = _cfg(tmp_path)
+    with ps_steps_on("numpy" if eval_dev == "numpy" else "device"), \
+            _group(cfg) as group:
         w = PSWorker(cfg, 0, group.hosts)
         try:
             w.load_data()

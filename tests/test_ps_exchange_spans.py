@@ -120,12 +120,12 @@ def data_dir(tmp_path_factory):
     ("fused-bsp-resident", dict(sync_mode=True, sync_last_gradient=False),
      {"push", "pull"}, {"push"}),
     ("minibatch", dict(batch_size=32), {"wire", "pull"}, {"wire"}),
-    ("numpy", dict(ps_compute_backend="numpy"), {"wire", "pull"}, {"wire"}),
+    ("numpy", dict(), {"wire", "pull"}, {"wire"}),
     ("accumulated", dict(ps_accum_max=2, batch_size=32), {"pull", "push"},
      {"pull", "push"}),
 ])
-def test_every_loop_variant_records_them_under_its_exchange(data_dir, mode,
-                                                            kw, under, whole):
+def test_every_loop_variant_records_them_under_its_exchange(
+        data_dir, mode, kw, under, whole, ps_steps_on):
     """``under``: the spans of the variant that hold an exchange;
     ``whole``: those of them a round repeats, whose every exchange the
     three cover but for the call's entry and exit.  One worker, so that
@@ -134,10 +134,11 @@ def test_every_loop_variant_records_them_under_its_exchange(data_dir, mode,
     base = dict(data_dir=data_dir, num_feature_dim=DIM, model="binary_lr",
                 num_workers=1, num_servers=2, sync_mode=False,
                 batch_size=-1, num_iteration=ITERATIONS, learning_rate=0.2,
-                l2_c=0.0, test_interval=0, ps_compute_backend="default")
+                l2_c=0.0, test_interval=0)
     tracer = get_tracer()
     tracer.reset()
-    run_ps_local(Config(**{**base, **kw}), save=False)
+    with ps_steps_on("numpy" if mode == "numpy" else "device"):
+        run_ps_local(Config(**{**base, **kw}), save=False)
     events = tracer.chrome_trace()["traceEvents"]
     ids = {e["args"]["id"]: e for e in events}
     kids = collections.defaultdict(list)

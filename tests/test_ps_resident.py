@@ -21,6 +21,9 @@ H2D = "distlr_h2d_bytes_total"
 ROUNDS = "distlr_ps_grad_rounds_total"
 
 
+pytestmark = pytest.mark.usefixtures("ps_steps_on_device")
+
+
 def _job(tmp_path, model, num_workers, **kw):
     d = str(tmp_path / f"{model}-{num_workers}")
     write_synthetic_shards(d, 100 * num_workers, DIM, num_parts=num_workers,
@@ -31,10 +34,7 @@ def _job(tmp_path, model, num_workers, **kw):
         num_classes=CLASSES if model == "softmax" else 2,
         num_workers=num_workers, num_servers=2, sync_mode=False,
         batch_size=-1, num_iteration=5, learning_rate=0.2, l2_c=0.0,
-        test_interval=0,
-        # the jitted step on the default backend: "auto" would take these
-        # tiny steps to numpy, where nothing is placed
-        ps_compute_backend="default")
+        test_interval=0)
     return Config(**{**base, **kw})
 
 
@@ -163,7 +163,9 @@ def test_a_keyed_model_places_nothing(tmp_path):
         w = PSWorker(cfg, 0, group.hosts)
         try:
             w.load_data()
-            assert w._resident is None and w.grad_step is None
+            # its step is numpy's over the batch's unique rows: no program
+            assert w._resident is None and w._grad_fn is None
+            assert w.grad_step is not None
             w.run(save=False)
         finally:
             w.close()
@@ -286,11 +288,11 @@ def test_windows_place_once_and_run_one_executable(tmp_path, request, step):
             w.close()
 
 
-def test_numpy_steps_place_nothing(tmp_path):
-    """``auto`` takes a step this small to numpy: no device, no shard."""
-    cfg = _job(tmp_path, "binary_lr", 1, ps_compute_backend="auto")
+def test_numpy_steps_place_nothing(tmp_path, ps_steps_on):
+    """Its size takes a step this small to numpy: no device, no shard."""
+    cfg = _job(tmp_path, "binary_lr", 1)
     before = family_total(H2D)
-    with _group(cfg) as group:
+    with ps_steps_on("size"), _group(cfg) as group:
         w = PSWorker(cfg, 0, group.hosts)
         try:
             w.load_data()
@@ -469,7 +471,9 @@ def test_the_selection_reads_the_model_the_device_and_the_shape():
     import jax
 
     from distlr_tpu.models.linear import BinaryLR, SoftmaxRegression
-    from distlr_tpu.train.ps_trainer import _one_pass_plan
+    from distlr_tpu.train import ps_trainer
+
+    _one_pass_plan = ps_trainer._one_pass_plan
 
     tpu = type("Device", (), {"platform": "tpu"})()
     plan = _one_pass_plan(BinaryLR(1_000_000), 384, 1_000_000, tpu)

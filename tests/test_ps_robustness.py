@@ -249,7 +249,7 @@ class TestMidTrainingRestart:
         rank 0's exit vote (generation 1) can never pair with it."""
         from distlr_tpu.config import Config
         from distlr_tpu.data.synthetic import write_synthetic_shards
-        from distlr_tpu.train import ps_trainer
+        from distlr_tpu.models import host_math
         from distlr_tpu.train.ps_trainer import run_ps_local
 
         d = str(tmp_path / "data")
@@ -257,7 +257,7 @@ class TestMidTrainingRestart:
 
         # inject at the dense hot path (the numpy fast-path grad — tiny
         # D=16 steps route there, not through _place)
-        real_grad = ps_trainer._np_dense_grad
+        real_grad = host_math.dense_grad
         state = {"calls": 0, "crashed": False}
 
         def flaky_grad(*args, **kw):
@@ -268,7 +268,7 @@ class TestMidTrainingRestart:
                 raise RuntimeError("injected mid-training crash")
             return real_grad(*args, **kw)
 
-        monkeypatch.setattr(ps_trainer, "_np_dense_grad", flaky_grad)
+        monkeypatch.setattr(host_math, "dense_grad", flaky_grad)
         cfg = Config(
             data_dir=d, num_feature_dim=16, num_workers=2, num_servers=2,
             num_iteration=8, learning_rate=0.2, l2_c=0.0, batch_size=100,
@@ -474,9 +474,9 @@ class TestSurvivingGroupResume:
             checkpoint_interval=0, ps_timeout_ms=4000,
         )
 
-        from distlr_tpu.train import ps_trainer
+        from distlr_tpu.models import host_math
 
-        real_grad = ps_trainer._np_dense_grad
+        real_grad = host_math.dense_grad
         state = {"calls": 0, "crashed": False}
 
         def flaky_grad(*args, **kw):
@@ -486,7 +486,7 @@ class TestSurvivingGroupResume:
                 raise RuntimeError("injected crash before first checkpoint")
             return real_grad(*args, **kw)
 
-        monkeypatch.setattr(ps_trainer, "_np_dense_grad", flaky_grad)
+        monkeypatch.setattr(host_math, "dense_grad", flaky_grad)
         group = ServerGroup(2, 2, ps_param_dim(cfg), learning_rate=0.5, sync=True)
         with group:
             with pytest.raises(Exception):
@@ -495,7 +495,7 @@ class TestSurvivingGroupResume:
             sidecar = os.path.join(ck, "ps_latest.json")
             assert not os.path.exists(sidecar)  # crash predates any ckpt
 
-            monkeypatch.setattr(ps_trainer, "_np_dense_grad", real_grad)
+            monkeypatch.setattr(host_math, "dense_grad", real_grad)
             resumed = run_ps_workers(
                 cfg, group.hosts, range(2), save=False, resume=True,
             )

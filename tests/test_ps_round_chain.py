@@ -37,6 +37,9 @@ SERIES = {ROUNDS: ("path", ("one_pass", "two_pass")),
           DISPATCHES: ("weights", ("in_flight", "landed"))}
 
 
+pytestmark = pytest.mark.usefixtures("ps_steps_on_device")
+
+
 def _cfg(d, model, batch, loop, **kw):
     classes = CLASSES if model == "softmax" else 2
     write_synthetic_shards(d, 100 * WORKERS, DIM, num_parts=WORKERS, seed=11,
@@ -45,10 +48,7 @@ def _cfg(d, model, batch, loop, **kw):
         data_dir=d, num_feature_dim=DIM, model=model, num_classes=classes,
         num_workers=WORKERS, num_servers=2, **BATCHES[batch],
         num_iteration=ITERATIONS, learning_rate=0.2, l2_c=0.0,
-        test_interval=0,
-        # the jitted step on the default backend: "auto" would take these
-        # tiny steps to numpy, which has no device and no chain
-        ps_compute_backend="default")
+        test_interval=0)
     return Config(**{**base, **LOOPS[loop], **kw})
 
 
@@ -134,8 +134,11 @@ def _run(tmp_path_factory, model, batch, loop, **kw):
 
 
 @pytest.fixture(scope="module", params=CASES, ids="-".join)
-def run(request, tmp_path_factory):
-    return _run(tmp_path_factory, *request.param)
+def run(request, tmp_path_factory, ps_steps_on):
+    # the jitted step on the default backend: by their size these tiny
+    # steps would go to numpy, which has no device and no chain
+    with ps_steps_on("device"):
+        return _run(tmp_path_factory, *request.param)
 
 
 def test_the_gradient_is_the_blocking_forms_bit_for_bit(run):

@@ -19,6 +19,9 @@ ROUND = ("data_load", "w_put", "compute", "grad_d2h", "push")
 XCHG = ("xchg_send", "xchg_await", "xchg_recv")
 
 
+pytestmark = pytest.mark.usefixtures("ps_steps_on_device")
+
+
 @pytest.fixture(scope="module")
 def data_dir(tmp_path_factory):
     d = str(tmp_path_factory.mktemp("ps-spans"))
@@ -31,7 +34,7 @@ def _cfg(data_dir, **kw):
     base = dict(data_dir=data_dir, num_feature_dim=DIM, model="binary_lr",
                 num_workers=WORKERS, num_servers=2, sync_mode=False,
                 batch_size=-1, num_iteration=ITERATIONS, learning_rate=0.2,
-                l2_c=0.0, test_interval=0, ps_compute_backend="default")
+                l2_c=0.0, test_interval=0)
     return Config(**{**base, **kw})
 
 
@@ -129,14 +132,15 @@ def test_spans_of_four_threads_do_not_nest_into_each_other(data_dir):
     ("minibatch", dict(batch_size=32), {"shard_put", "wire"}, {"h2d"}),
     ("minibatch-wrapped", dict(batch_size=32, wrap_final_batch=True),
      {"h2d", "wire"}, {"shard_put"}),
-    ("numpy", dict(ps_compute_backend="numpy"), {"compute", "wire"},
+    ("numpy", dict(), {"compute", "wire"},
      {"shard_put", "w_put", "grad_d2h", "h2d"}),
     ("accumulated", dict(ps_accum_max=2, batch_size=32),
      {"pull", "push", "shard_put"}, {"wire", "h2d"}),
 ])
 def test_each_loop_variant_records_the_spans_it_has(data_dir, mode, kw, has,
-                                                    lacks):
-    events = _events(_cfg(data_dir, **kw))
+                                                    lacks, ps_steps_on):
+    with ps_steps_on("numpy" if mode == "numpy" else "device"):
+        events = _events(_cfg(data_dir, **kw))
     names = {e["name"] for e in events}
     assert {"data_load", "compute", "load_data", "barrier_wait"} | has <= names
     assert not (lacks & names), mode

@@ -833,7 +833,7 @@ class TestOnlineSparseSoftmax:
             tr = OnlineTrainer(cfg, sg.hosts, str(shard_dir),
                                poll_interval_s=0.05)
             # keyed rows per class: one feature key owns its K lanes
-            assert tr._row_vpk == K
+            assert tr._rows.vpk == K
             stats = tr.run(max_shards=1)
             with KVWorker(sg.hosts, D * K) as kv:
                 W = kv.pull().reshape(D, K)
