@@ -313,6 +313,20 @@ class SoftmaxRegression:
     def param_shape(self) -> tuple[int, ...]:
         return (self.num_features, self.num_classes)
 
+    @property
+    def _precision(self):
+        """What both contractions state.  A product with K output columns
+        goes to the MXU, and the TPU runs a float32 ``dot`` there as ONE
+        bfloat16 pass unless told otherwise: ``compute_dtype="float32"``
+        therefore states ``HIGHEST`` (six passes, float32 to the last
+        bit or two: PERF.md section 6, PR 44, has the chip's readings that
+        chose it over the three-pass ``HIGH``), so that float32 means float32
+        on every backend.  ``bfloat16`` states nothing and stays the one
+        pass it is.  ``BinaryLR`` needs none: its one-column product is
+        a float32 multiply-reduce on the VPU."""
+        return (jax.lax.Precision.HIGHEST
+                if jnp.dtype(self.compute_dtype) == jnp.float32 else None)
+
     def init(self, cfg: Config) -> jnp.ndarray:
         shape = (self.num_features, self.num_classes)
         if cfg.reference_rng_init:
@@ -330,6 +344,7 @@ class SoftmaxRegression:
         z = jnp.dot(
             X.astype(cdt),
             W.astype(cdt),
+            precision=self._precision,
             preferred_element_type=jnp.float32,
         )
         return z * self.feature_scale if self.feature_scale != 1.0 else z
@@ -359,6 +374,7 @@ class SoftmaxRegression:
             jnp.dot(
                 X.astype(cdt).T,
                 resid.astype(cdt),
+                precision=self._precision,
                 preferred_element_type=jnp.float32,
             )
             / n

@@ -205,6 +205,25 @@ _PANEL_AHEAD = get_registry().gauge(
     "the two-pass program)",
     labelnames=("rank",),
 )
+#: The class axis of a worker's step: the columns of the weights it pulls,
+#: of the logits it forms and of the gradient it pushes a feature.
+_STEP_CLASSES = get_registry().gauge(
+    "distlr_ps_step_classes",
+    "class axis of a PS worker's step: values a feature's row of the "
+    "weights and of the pushed gradient holds (1 = a binary model)",
+    labelnames=("rank",),
+)
+#: How a resident shard's features are held on the step's device: 1 on the
+#: series of the layout they are in, 0 on the other (``_place_rows``).
+_RESIDENT_LAYOUTS = ("default", "row_major")
+_RESIDENT_LAYOUT = get_registry().gauge(
+    "distlr_ps_resident_layout",
+    "how a PS worker's resident shard is held: row_major = relaid once on "
+    "the device, columns in the lanes and zero-padded, for the one-pass "
+    "step; default = as the device lays the shape out by itself, which "
+    "XLA's two products read with no copy",
+    labelnames=("rank", "layout"),
+)
 
 
 class _StepTrace:
@@ -400,7 +419,17 @@ def _one_pass_plan(model, rows: int, dim: int, device):
     whose step runs on ``device`` (``ops.pallas_lr.panel_plan``), or None
     where the step stays ``model.grad`` under XLA: any model but a
     ``BinaryLR`` without ``int8_dot``, a device that is no TPU, rows that
-    are not whole sublane groups, a panel of which VMEM holds nothing."""
+    are not whole sublane groups, a panel of which VMEM holds nothing.
+
+    A model without a plan (``softmax``: a class axis the kernel has no
+    sweep for) keeps its resident shard in the device's default layout
+    and its step is XLA's two products, counted ``path="two_pass"``: read
+    on the v5e at ``float32[3968, 62061]`` x ``[62061, 20]`` (the rows in
+    the lanes: 62,061 is no multiple of 128), each product is one fusion
+    that streams the shard at 750 GB/s with no transposing copy before
+    it, 2.71 ms a step against 2.71 over the same rows relaid row-major
+    and padded to 62,080 columns (``benchmarks/exp_softmax_step.py``;
+    PERF.md section 6, PR 44).  So nothing is relaid for it."""
     if not isinstance(model, BinaryLR) or model.int8_dot:
         return None
     if device.platform not in _ONE_PASS_PLATFORMS:
@@ -414,9 +443,12 @@ def _one_pass_plan(model, rows: int, dim: int, device):
 def _row_major_program(plan, rows=None):
     """The jitted relayout of a placed shard's features to what the
     one-pass step reads (``ops.pallas_lr.pad_columns``), with zero rows
-    below up to ``rows`` where the shard's last window is short; with no
-    plan, those rows alone.  Its name carries no ``step``: the benchmark
-    finds the step's runs by that."""
+    below up to ``rows`` where the shard's last window is short.  With no
+    plan (a device that is no TPU, a model the kernel does not serve) the
+    features keep the device's default layout, which is what
+    ``model.grad``'s products read without a copy (``_one_pass_plan``),
+    and the program adds those rows alone.  Its name carries no ``step``:
+    the benchmark finds the step's runs by that."""
     from distlr_tpu.ops.pallas_lr import pad_columns  # noqa: PLC0415
 
     def ps_shard_row_major(X):
@@ -813,6 +845,16 @@ class PSWorker:
     ``softmax``; a B that is no multiple of eight; the CPU) keeps the
     device's default layout and ``model.grad`` under XLA, over a
     ``dynamic_slice`` of the resident rows where the batch is a window.
+    A model without a plan loses nothing by that: ``softmax``'s two
+    products (``X W``, ``X^T R``; float32 at the precision its
+    ``compute_dtype`` states) each stream the default layout at HBM speed
+    with no copy before them, as they do a row-major one
+    (``_one_pass_plan``), so its resident shard is not relaid and its
+    rounds count ``path="two_pass"``.
+    ``distlr_ps_resident_layout{rank, layout}`` says which way a resident
+    shard is held (``row_major`` / ``default``),
+    ``distlr_ps_step_classes{rank}`` the class axis of the step (20
+    columns a feature pulled, computed and pushed; 1 for a binary model).
     ``distlr_ps_grad_rounds_total{rank, path}``
     counts the rounds of each, ``distlr_ps_grad_panel_held{rank}`` is the
     share of a panel VMEM holds, ``distlr_ps_grad_panel_ahead{rank}`` the
@@ -1169,6 +1211,7 @@ class PSWorker:
             "" if test is None
             else f", eval -> {_describe_compute_device(self._eval_dev)}")
         K = cfg.num_classes if cfg.model == "softmax" else None
+        _STEP_CLASSES.labels(rank=str(self.rank)).set(K or 1)
         if step_dev == "numpy":
             def grad_step(wf, batch):
                 W = wf.reshape(cfg.num_feature_dim, K) if K else wf
@@ -1181,6 +1224,11 @@ class PSWorker:
             rank = str(self.rank)
             _STEP_DEVICE.labels(rank=rank).set(_jax_device(step_dev).id)
             plan = self._panels
+            if self._resident is not None:
+                held = "default" if plan is None else "row_major"
+                for layout in _RESIDENT_LAYOUTS:
+                    _RESIDENT_LAYOUT.labels(rank=rank, layout=layout).set(
+                        layout == held)
             _PANEL_HELD.labels(rank=rank).set(plan.held_share if plan else 0.0)
             _PANEL_AHEAD.labels(rank=rank).set(
                 plan.ahead_share if plan else 0.0)
