@@ -1,6 +1,6 @@
 """Experiment: a PS worker's softmax step at news20's width, by the
-layout its resident shard is held in and the precision its two products
-state.
+layout its resident shard is held in, the precision its two products
+state, and the form its weights and gradient cross the host link in.
 
 ``SoftmaxRegression.grad`` over ``float32[3968, 62061]`` rows and
 ``float32[62061, 20]`` weights is XLA's two products with a row softmax
@@ -17,14 +17,41 @@ between them.  Two things about it had never been read on a chip:
   ``highest`` six, ``bfloat16`` the operands cast (``compute_dtype``'s
   product default).  Each gradient is held against numpy's float64 one.
 
+* **the form on the link** (PR 45).  The wire and the servers carry the
+  weights and the gradient as one flat ``float32[D*K]`` vector; the
+  device lays ``float32[62061, 20]`` out class-major in (8,128) tiles.
+  ``link`` times a ``device_put`` to ready and a readback of the two
+  shapes, from one thread and from four at once (the cell's four
+  workers share one chip and one runtime).  ``step ... operands=``
+  times the product's own step (``SoftmaxRegression.grad`` with the
+  cell's L2 term, whose backward fusion reads the weights too), its
+  forms interleaved over ``--rounds`` readings each: ``shaped`` is
+  ``model.grad`` jitted with ``[D, K]`` operands (the program before
+  PR 45), ``flat relayout=product`` is ``ps_trainer._compiled_fns``
+  itself, the flat operand and result with the model's shape restored
+  by two reshapes (XLA's own relayout: what ships, on every
+  platform), and three forms that were tried against it and not
+  built: the relayout written out through a ``[K, D]`` intermediate
+  behind an optimization barrier on both sides (``transpose``), on
+  the weights' side alone (``transpose_in``) and on the gradient's
+  alone (``transpose_out``).  ``chain`` times a worker's round of the
+  device alone, as ``PSWorker.grad_step`` enqueues it (put, program,
+  readback, one wait), one worker and four at once.
+
 Prints a line a variant (``ms`` a step over ``--steps`` runs, the
 relative error of the gradient's norm and of the gradient) and, for the
-``highest`` pair, the device operations of a traced run, which is where
-a transposing copy would show.  Read on the v5e (PERF.md section 6,
-PR 44): 2.66-2.71 ms in all eight variants (two fusions of 1.3 ms, each
-one read of the shard; no copy in either layout; the passes hide under
+``highest`` variants, the device operations of a traced run, which is
+where a transposing copy or a relayout shows.  Read on the v5e (PERF.md
+section 6, PR 44): 2.66-2.71 ms in all eight variants (two fusions of
+1.3 ms, each one read of the shard; no copy in either layout; the passes hide under
 the stream), ``default`` and ``bfloat16`` 2.1e-3 off the float64
-gradient, ``high`` 1.1e-5, ``highest`` 3.2e-7.  Exits non-zero without a
+gradient, ``high`` 1.1e-5, ``highest`` 3.2e-7.  PR 45's readings are in
+PERF.md section 6 under that PR: the two reshapes cost the program
+0.15 ms; ``transpose`` alone of the written-out forms reads under
+``product`` (an emitter XLA picks for the backward fusion when both its
+class-axis operand and result are typed ``[K, D]``, not a cheaper
+relayout), by less than the cell's spread end to end, so the product
+keeps the plain reshapes.  Exits non-zero without a
 TPU (``--smoke`` runs a tiny shape anywhere and says nothing about a
 time).
 
@@ -38,7 +65,9 @@ import os
 import shutil
 import sys
 import tempfile
+import threading
 import time
+import types
 
 import numpy as np
 
@@ -48,6 +77,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from chipbench import trace_reduce  # noqa: E402
+from distlr_tpu.models.linear import SoftmaxRegression  # noqa: E402
+from distlr_tpu.train import ps_trainer  # noqa: E402
 
 PRECISIONS = {"default": None, "high": jax.lax.Precision.HIGH,
               "highest": jax.lax.Precision.HIGHEST}
@@ -88,6 +119,152 @@ def _grad(W, X, y, mask, compute_dtype, precision):
                    preferred_element_type=jnp.float32) / n
 
 
+def _rel(got, want):
+    return np.linalg.norm(got.astype(np.float64) - want) / np.linalg.norm(want)
+
+
+def step_forms(model, l2_c=0.0, l2_scale_by_batch=False):
+    """The product's step by the form of its operand 0 and its result:
+    ``{name: (jitted step, whether it takes the weights flat)}``.
+    ``product`` is ``ps_trainer._compiled_fns`` as the worker calls it;
+    the others call the same ``model.grad`` with the same L2 term."""
+    gcfg = types.SimpleNamespace(l2_c=l2_c, l2_scale_by_batch=l2_scale_by_batch)
+    barrier = jax.lax.optimization_barrier
+
+    def wrap(shape_in, flat_out):
+        return jax.jit(lambda w, X, y, mask: flat_out(
+            model.grad(shape_in(w), (X, y, mask), gcfg)))
+
+    def in_plain(w):
+        return w.reshape(model.param_shape)
+
+    def in_written(w):
+        return barrier(w.reshape(model.param_shape).T).T
+
+    def out_plain(g):
+        return g.reshape(-1)
+
+    def out_written(g):
+        return barrier(g.T).T.reshape(-1)
+
+    return {
+        "shaped": (wrap(lambda w: w, lambda g: g), False),
+        "product": (ps_trainer._compiled_fns(
+            model, l2_c, l2_scale_by_batch), True),
+        "transpose": (wrap(in_written, out_written), True),
+        "transpose_in": (wrap(in_written, out_plain), True),
+        "transpose_out": (wrap(in_plain, out_written), True),
+    }
+
+
+def interleaved_ms(calls, steps, rounds):
+    """``{name: [ms a step, a reading a round]}``: every round times each
+    of ``calls`` (``name -> thunk that dispatches one step``) over
+    ``steps`` dispatches waited for once, in an order that turns round
+    with the round, so that a drift of the chip's falls on all alike."""
+    for call in calls.values():
+        jax.block_until_ready(call())
+    got = {name: [] for name in calls}
+    for r in range(rounds):
+        for name in (list(calls) if r % 2 == 0 else list(calls)[::-1]):
+            t = time.perf_counter()
+            for _ in range(steps):
+                g = calls[name]()
+            jax.block_until_ready(g)
+            got[name].append(1e3 * (time.perf_counter() - t) / steps)
+    return got
+
+
+def _timed(fn, steps):
+    """Mean and least ms of ``fn()`` over ``steps`` calls, each waited
+    for."""
+    took = []
+    for _ in range(steps):
+        t = time.perf_counter()
+        fn()
+        took.append(1e3 * (time.perf_counter() - t))
+    return float(np.mean(took)), float(np.min(took))
+
+
+def _step_ms(fn, operands, steps):
+    """``(ms a step, the last result)`` over ``steps`` dispatches waited
+    for once: the device's own pace, no host between two steps."""
+    g = jax.block_until_ready(fn(*operands))
+    t = time.perf_counter()
+    for _ in range(steps):
+        g = fn(*operands)
+    jax.block_until_ready(g)
+    return 1e3 * (time.perf_counter() - t) / steps, g
+
+
+def _at_once(fn, threads):
+    """``fn()`` on ``threads`` threads at once, all joined."""
+    workers = [threading.Thread(target=fn) for _ in range(threads)]
+    for t in workers:
+        t.start()
+    for t in workers:
+        t.join()
+
+
+def link_times(host, dev, steps, threads=1):
+    """``device_put`` to ready, and a readback, of ``host``'s shape: ms,
+    mean and least, over ``threads`` threads at once (four workers share
+    the cell's one chip and one runtime).  Every readback is of a fresh
+    device array (an Array keeps the host copy it once made)."""
+    bump = jax.jit(lambda a: a + 1.0)
+    puts, backs = [], []
+
+    def one():
+        d = jax.device_put(host, dev)
+        for _ in range(steps):
+            t = time.perf_counter()
+            jax.block_until_ready(jax.device_put(host, dev))
+            puts.append(1e3 * (time.perf_counter() - t))
+        for _ in range(steps):
+            d = jax.block_until_ready(bump(d))
+            t = time.perf_counter()
+            np.asarray(d)
+            backs.append(1e3 * (time.perf_counter() - t))
+
+    _at_once(one, threads)
+    return ((float(np.mean(puts)), float(np.min(puts))),
+            (float(np.mean(backs)), float(np.min(backs))))
+
+
+def chain_ms(fn, w_host, rest, dev, steps, threads=1):
+    """A worker's device chain as ``PSWorker.grad_step`` enqueues it: the
+    weights put, the program behind them, the readback behind the
+    program, one wait; mean and least ms a round, over ``threads``
+    workers at once on the one chip (with four, the cell's rounds less
+    their exchange)."""
+    def once():
+        w = jax.device_put(w_host, dev)
+        g = fn(w, *rest)
+        g.copy_to_host_async()
+        jax.block_until_ready(g)
+        return np.asarray(g)
+    once()
+    took = []
+    _at_once(lambda: took.append(_timed(once, steps)), threads)
+    return (float(np.mean([m for m, _ in took])),
+            float(np.min([least for _, least in took])))
+
+
+def traced_ops(fn, operands, layout, tag, runs=5):
+    trace_dir = tempfile.mkdtemp(prefix="exp-softmax-")
+    try:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(runs):
+                g = fn(*operands)
+            jax.block_until_ready(g)
+        xt = trace_reduce.load_xplane(trace_reduce.find_xplane(trace_dir))
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    for name, s in trace_reduce.top_ops(xt, n=8):
+        print(f"EXP   op layout={layout} {tag} ms_a_step={1e3 * s / runs:.4f} "
+              f"{name[:150]}", flush=True)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--rows", type=int, default=3968)
@@ -95,11 +272,14 @@ def main(argv=None) -> int:
     ap.add_argument("--classes", type=int, default=20)
     ap.add_argument("--nnz", type=int, default=80)
     ap.add_argument("--steps", type=int, default=20)
+    ap.add_argument("--rounds", type=int, default=6,
+                    help="interleaved readings of each form of the step, "
+                         "3 x --steps dispatches a reading")
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args(argv)
     if args.smoke:
-        args.rows, args.dim, args.steps = 128, 1000, 2
+        args.rows, args.dim, args.steps, args.rounds = 128, 1000, 2, 2
     dev = jax.devices()[0]
     if dev.platform != "tpu" and not args.smoke:
         raise SystemExit(f"the experiment reads a TPU; JAX found {dev.platform}")
@@ -129,31 +309,48 @@ def main(argv=None) -> int:
                 return _grad(w, X, y, mask, cdt, stated)[:dim]
 
             fn = jax.jit(step)
-            g = jax.block_until_ready(fn(wd, Xd, yd, md))
-            t = time.perf_counter()
-            for _ in range(args.steps):
-                g = fn(wd, Xd, yd, md)
-            jax.block_until_ready(g)
-            ms = 1e3 * (time.perf_counter() - t) / args.steps
+            ms, g = _step_ms(fn, (wd, Xd, yd, md), args.steps)
             got = np.asarray(g, np.float64)
             print(f"EXP step layout={layout} precision={tag} ms={ms:.3f} "
                   f"norm_rel_gap={abs(np.linalg.norm(got) - n_want) / n_want:.3g} "
                   f"diff_rel={np.linalg.norm(got - want) / n_want:.3g}",
                   flush=True)
             if tag == "highest" and not args.smoke:
-                trace_dir = tempfile.mkdtemp(prefix="exp-softmax-")
-                try:
-                    with jax.profiler.trace(trace_dir):
-                        for _ in range(5):
-                            g = fn(wd, Xd, yd, md)
-                        jax.block_until_ready(g)
-                    xt = trace_reduce.load_xplane(
-                        trace_reduce.find_xplane(trace_dir))
-                finally:
-                    shutil.rmtree(trace_dir, ignore_errors=True)
-                for name, s in trace_reduce.top_ops(xt, n=6):
-                    print(f"EXP   op layout={layout} ms_a_step={1e3 * s / 5:.3f} "
-                          f"{name[:150]}", flush=True)
+                traced_ops(fn, (wd, Xd, yd, md), layout, "operands=shaped")
+    # -- the form on the host link (PR 45) --------------------------------
+    flat = np.ascontiguousarray(W.reshape(-1))
+    for threads in (1, 4):
+        for host in (W, flat):
+            put, back = link_times(host, dev, args.steps, threads)
+            print(f"EXP link shape=f32{list(host.shape)} threads={threads} "
+                  f"put_ms={put[0]:.3f} put_min_ms={put[1]:.3f} "
+                  f"readback_ms={back[0]:.3f} readback_min_ms={back[1]:.3f}",
+                  flush=True)
+    rest = (held["default"], yd, md)
+    forms = step_forms(SoftmaxRegression(dim, K, compute_dtype="float32"))
+    on_dev = {name: jax.device_put(flat if takes_flat else W, dev)
+              for name, (_, takes_flat) in forms.items()}
+    took = interleaved_ms(
+        {name: (lambda fn=fn, w=on_dev[name]: fn(w, *rest))
+         for name, (fn, _) in forms.items()}, 3 * args.steps, args.rounds)
+    base = np.asarray(forms["shaped"][0](on_dev["shaped"], *rest)).reshape(-1)
+    for name, (fn, takes_flat) in forms.items():
+        operands = (f"flat relayout={name}" if takes_flat else "shaped")
+        got = np.asarray(fn(on_dev[name], *rest))
+        print(f"EXP step layout=default precision=highest "
+              f"operands={operands} ms={np.median(took[name]):.4f} "
+              f"readings={[round(v, 4) for v in took[name]]} "
+              f"shape={list(got.shape)} "
+              f"bits_as_shaped={bool(np.array_equal(got.reshape(-1), base))} "
+              f"diff_rel={_rel(got.reshape(dim, K), want):.3g}", flush=True)
+        for threads in (1, 4):
+            ms, least = chain_ms(fn, flat if takes_flat else W, rest, dev,
+                                 args.steps, threads)
+            print(f"EXP chain operands={operands} threads={threads} "
+                  f"ms={ms:.3f} min_ms={least:.3f}", flush=True)
+        if not args.smoke:
+            traced_ops(fn, (on_dev[name], *rest), "default",
+                       f"operands={operands.replace(' ', '_')}")
     return 0
 
 

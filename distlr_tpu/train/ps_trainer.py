@@ -224,6 +224,20 @@ _RESIDENT_LAYOUT = get_registry().gauge(
     "XLA's two products read with no copy",
     labelnames=("rank", "layout"),
 )
+#: Where a dense step's parameters take the model's shape.  The wire, the
+#: servers and ``grad_step`` carry them as one flat vector; a model whose
+#: ``param_shape`` has a rank above 1 restores it inside the jitted
+#: program (``device``: what crosses the host link, in and out, is the
+#: rank-1 array, a straight copy) or, for the numpy step, as a view on the
+#: host (``host``); rank-1 parameters are their own shape (``none``).
+_PARAMS_SHAPED_AT = ("none", "host", "device")
+_PARAMS_SHAPED = get_registry().gauge(
+    "distlr_ps_step_params_shaped",
+    "where a PS worker's dense step gives the flat parameter vector the "
+    "model's shape: none = rank-1 parameters, host = a numpy view, device "
+    "= inside the jitted program (1 on the series in force)",
+    labelnames=("rank", "where"),
+)
 
 
 class _StepTrace:
@@ -394,16 +408,24 @@ def _compiled_fns(model, l2_c: float, l2_scale_by_batch: bool):
     # a window of the resident arrays from that row on: ``panels.rows``
     # rows read in place by the kernel or, with no plan, ``window``
     # (static) rows sliced out for ``model.grad``.
+    #
+    # ``w`` and the result are the flat vector the wire carries: the
+    # model's shape exists in here alone (the two reshapes; for rank-1
+    # parameters they trace to nothing), so the host link moves a rank-1
+    # array each way and the relayout is the device's (``_PARAMS_SHAPED``).
     def ps_grad_step(w, X, y, mask, first=None, panels=None, interpret=False,
                      window=None):
+        w = w.reshape(model.param_shape)
         if panels is not None:
-            return model.grad_panels(w, (X, y, mask), gcfg, panels,
-                                     first=first, interpret=interpret)
-        if first is not None:
-            X, y, mask = (
-                jax.lax.dynamic_slice_in_dim(a, first, window)
-                for a in (X, y, mask))
-        return model.grad(w, (X, y, mask), gcfg)
+            g = model.grad_panels(w, (X, y, mask), gcfg, panels,
+                                  first=first, interpret=interpret)
+        else:
+            if first is not None:
+                X, y, mask = (
+                    jax.lax.dynamic_slice_in_dim(a, first, window)
+                    for a in (X, y, mask))
+            g = model.grad(w, (X, y, mask), gcfg)
+        return g.reshape(-1)
 
     return jax.jit(ps_grad_step,
                    static_argnames=("panels", "interpret", "window"))
@@ -473,6 +495,7 @@ def _compiled_acc(model):
     # as ``jit_ps_eval``, and the benchmark finds the gradient step's
     # runs by theirs
     def ps_eval(w, X, y, mask, panels=None):
+        w = w.reshape(model.param_shape)  # flat in, as ``ps_grad_step``'s
         z = (model.logits(w, X) if panels is None
              else model.logits_panels(w, X, panels))
         return model.eval_from_logits(z, y, mask)
@@ -888,14 +911,16 @@ class PSWorker:
     keyed batch's unique rows, inside the step),
     ``h2d`` (a streamed batch's put, where the step's device is named:
     none opens in a resident or windowed round), ``w_put`` (the
-    weights handed to the runtime for the device: staging and enqueue,
-    not the copy), ``compute`` (dispatch to the worker's own program
+    weights handed to the runtime for the device, the flat vector the
+    wire carries whatever the model's shape: staging and enqueue, not
+    the copy), ``compute`` (dispatch to the worker's own program
     finished, the rest of the weights' copy before it included; the
     readback is enqueued inside, behind the program), ``grad_d2h`` (the
-    rest of that readback), ``push`` (the loop blocked on its
-    exchange; in the pipelined exchange the one that ends an epoch,
-    with no round's compute left to hide it, carries ``drain=1`` beside
-    ``step`` and ``rank``), ``pull``; ``wire`` on the comm thread (a
+    rest of that readback, of a flat gradient: a class axis is restored
+    and flattened inside the program, ``distlr_ps_step_params_shaped``),
+    ``push`` (the loop blocked on its exchange; in the pipelined
+    exchange the one that ends an epoch, with no round's compute left to
+    hide it, carries ``drain=1`` beside ``step`` and ``rank``), ``pull``; ``wire`` on the comm thread (a
     pipelined push-pull, send to reply, with the step that submitted it);
     ``barrier_wait``, ``eval``, ``checkpoint``; under ``eval`` (rank 0,
     a dense model): ``eval_pull`` (the weights after the round, pulled as
@@ -1212,6 +1237,11 @@ class PSWorker:
             else f", eval -> {_describe_compute_device(self._eval_dev)}")
         K = cfg.num_classes if cfg.model == "softmax" else None
         _STEP_CLASSES.labels(rank=str(self.rank)).set(K or 1)
+        shaped_at = ("none" if len(self.model.param_shape) == 1
+                     else "host" if step_dev == "numpy" else "device")
+        for where in _PARAMS_SHAPED_AT:
+            _PARAMS_SHAPED.labels(rank=str(self.rank), where=where).set(
+                where == shaped_at)
         if step_dev == "numpy":
             def grad_step(wf, batch):
                 W = wf.reshape(cfg.num_feature_dim, K) if K else wf
@@ -1271,8 +1301,9 @@ class PSWorker:
                     with self._span("h2d"):
                         batch = self._place(step_dev, *batch)
                 with self._span("w_put"):
-                    # the hand-over (staging, enqueue), not the copy
-                    w = jax.device_put(self._shape_params(wf), step_dev)
+                    # the hand-over (staging, enqueue), not the copy; the
+                    # flat vector as it is: the program shapes it
+                    w = jax.device_put(wf, step_dev)
                 with self._span("compute", marks_step=True):
                     landed = w.is_ready()
                     g = self._grad_fn(w, *batch, **how)
@@ -1288,9 +1319,9 @@ class PSWorker:
                     rank=rank,
                     weights="landed" if landed else "in_flight").inc()
                 with self._span("grad_d2h"):
-                    # the rest of the copy already under way; the reshape
-                    # is a view and the client sends from this buffer
-                    return np.asarray(g).reshape(-1)
+                    # the rest of the copy already under way; the program's
+                    # result is flat and the client sends from this buffer
+                    return np.asarray(g)
         self.grad_step = grad_step
 
     def _place_shard(self, train, step_dev):
@@ -1594,7 +1625,7 @@ class PSWorker:
         self._count_eval(rows)
         with self._span("eval_w_put"):
             wd = jax.block_until_ready(jax.device_put(
-                self._shape_params(w), _jax_device(self._eval_dev)))
+                w, _jax_device(self._eval_dev)))
         with self._span("eval_compute"):
             got = jax.block_until_ready(self._acc_fn(wd, *batch, **how))
         with self._span("eval_d2h"):
@@ -1691,11 +1722,6 @@ class PSWorker:
         if device is None:
             return arrays
         return tuple(jax.device_put(a, device) for a in arrays)
-
-    def _shape_params(self, flat: np.ndarray):
-        if self.cfg.model in ("softmax", "sparse_softmax"):
-            return flat.reshape(self.cfg.num_feature_dim, self.cfg.num_classes)
-        return flat
 
     def _comm_pool(self):
         if self._comm is None:

@@ -1,7 +1,9 @@
 """The row-panel kernel (``ops/pallas_lr.py``), interpreted on the CPU:
 equal to ``BinaryLR.grad`` in float32 whatever share of a panel VMEM
 holds and however many slots it has to fetch ahead into, and compiled
-for a described v5e at the cell's size."""
+for a described v5e at the cell's size; beside it, in the one file that
+describes a v5e, the multiclass PS step as the compiler leaves it there
+(flat operand and result, the shard read as it lies)."""
 
 import dataclasses
 import re
@@ -12,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distlr_tpu.models.linear import BinaryLR
+from distlr_tpu.models.linear import BinaryLR, SoftmaxRegression
 from distlr_tpu.ops import PanelPlan, lr_grad_panels, pad_columns, panel_plan
 from distlr_tpu.ops import pallas_lr
 
@@ -402,6 +404,15 @@ def test_the_forward_refuses_a_matrix_that_was_not_padded():
         pallas_lr.lr_logits_rows(w, X, plan)
 
 
+def _readers_of_the_parameter(entry, shape):
+    """The lines of an entry computation that read its parameter of
+    ``shape`` (the resident rows)."""
+    held = re.search(rf"%(\S+) = {re.escape(shape)}\S* parameter\(", entry)
+    return [ln for ln in entry.splitlines()
+            if re.search(rf"%{re.escape(held.group(1))}\b", ln)
+            and " parameter(" not in ln]
+
+
 def test_the_eval_compiles_for_a_v5e_as_one_read_of_the_resident_rows(chips):
     """The eval program at the cell's size (256 x 1,000,000 float32 test
     rows held row-major and padded): the logits are one fusion over the
@@ -422,11 +433,36 @@ def test_the_eval_compiles_for_a_v5e_as_one_read_of_the_resident_rows(chips):
         spec((rows,), jnp.int32), spec((rows,), jnp.bool_)).compile()
     big = f"f32[{rows},{plan.dim_padded}]"
     entry = compiled.as_text().split("\nENTRY ", 1)[1].split("\n}", 1)[0]
-    held = re.search(rf"%(\S+) = {re.escape(big)}\S* parameter\(", entry)
-    readers = [ln for ln in entry.splitlines()
-               if re.search(rf"%{re.escape(held.group(1))}\b", ln)
-               and " parameter(" not in ln]
+    readers = _readers_of_the_parameter(entry, big)
     assert len(readers) == 1 and " fusion(" in readers[0], readers
     # and nothing of the entry computation produces a matrix of that size
     assert len(re.findall(rf"= {re.escape(big)}", entry)) == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+def test_the_softmax_step_compiles_for_a_v5e_flat_in_and_flat_out(chips):
+    """``jit_ps_grad_step`` at the multiclass cell's size (3,968 x 62,061
+    float32 rows, 20 classes), as ``ps_trainer`` builds it: operand 0 and
+    the result are the rank-1 ``f32[1241220]`` the wire carries, a
+    straight copy over the host link, and the model's shape is the
+    compiler's to restore; the resident shard is read by the two products
+    as it lies, with no copy of it."""
+    from distlr_tpu.train import ps_trainer
+
+    rows, dim, classes = 3968, 62061, 20
+    model = SoftmaxRegression(dim, classes, compute_dtype="float32")
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chips[0])
+
+    compiled = ps_trainer._compiled_fns(model, 0.0, False).lower(
+        spec((dim * classes,), jnp.float32), spec((rows, dim), jnp.float32),
+        spec((rows,), jnp.int32), spec((rows,), jnp.float32)).compile()
+    entry = compiled.as_text().split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    flat = f"f32[{dim * classes}]"
+    assert re.search(rf"%\S+ = {re.escape(flat)}\S* parameter\(0\)", entry)
+    assert re.search(rf"ROOT %\S+ = {re.escape(flat)}\S* reshape\(", entry)
+    big = f"f32[{rows},{dim}]"
+    readers = _readers_of_the_parameter(entry, big)
+    assert len(readers) == 2 and all(" fusion(" in ln for ln in readers)
+    assert len(re.findall(rf"= {re.escape(big)}", entry)) == 1

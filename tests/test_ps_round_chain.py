@@ -2,7 +2,9 @@
 gradient program, the gradient out) and waits once: the same gradient as
 the blocking form bit for bit, the same three spans in the same order, a
 counter of how the dispatch found the weights' copy, and the fence on the
-weights' host array that makes it safe."""
+weights' host array that makes it safe.  What goes in and comes out is the
+flat vector the wire carries, whatever the model's shape: a gauge says
+where that shape is restored."""
 
 import collections
 
@@ -33,6 +35,7 @@ LOOPS = {"fused-bsp": dict(sync_mode=True),
 CASES = [(m, b, l) for m in MODELS for b in BATCHES for l in LOOPS]
 ROUNDS, DISPATCHES = ("distlr_ps_grad_rounds_total",
                       "distlr_ps_grad_dispatches_total")
+SHAPED = "distlr_ps_step_params_shaped"
 SERIES = {ROUNDS: ("path", ("one_pass", "two_pass")),
           DISPATCHES: ("weights", ("in_flight", "landed"))}
 
@@ -76,9 +79,9 @@ def _blocking(worker, wf, batch):
     elif batch is not worker._resident:
         batch = jax.block_until_ready(
             tuple(jax.device_put(a) for a in batch))
-    w = jax.block_until_ready(jax.device_put(worker._shape_params(wf)))
+    w = jax.block_until_ready(jax.device_put(wf))
     g = jax.block_until_ready(worker._grad_fn(w, *batch, **how))
-    return np.asarray(g).reshape(-1)
+    return np.asarray(g)
 
 
 def _counts():
@@ -91,6 +94,15 @@ def _counts():
                 out[name, rank, value] = fam.labels(
                     rank=str(rank), **{label: value}).value
     return out
+
+
+def _shaped_at(rank):
+    """The series of ``distlr_ps_step_params_shaped`` that read 1 for
+    ``rank``, and the sum over all of its series."""
+    fam = get_registry().get(SHAPED)
+    got = {where: fam.labels(rank=str(rank), where=where).value
+           for where in ("none", "host", "device")}
+    return [where for where, v in got.items() if v == 1], sum(got.values())
 
 
 def _run(tmp_path_factory, model, batch, loop, **kw):
@@ -106,6 +118,7 @@ def _run(tmp_path_factory, model, batch, loop, **kw):
                 assert w._windowed == (batch == "windowed")
                 assert w._panels is None  # the CPU keeps the XLA step
                 w.grad_step = _Recorder(w.grad_step)
+            shaped = {w.rank: _shaped_at(w.rank) for w in workers}
             before = _counts()
             tracer.reset()
             _in_threads(workers, lambda w: w.run(save=False))
@@ -126,7 +139,7 @@ def _run(tmp_path_factory, model, batch, loop, **kw):
             return dict(
                 cfg=cfg, events=events, seen=seen, want=want,
                 rounds={w.rank: w.rounds for w in workers},
-                counted=counted,
+                counted=counted, shaped=shaped,
                 overwritten=(got, kept, want[0][0]))
         finally:
             for w in workers:
@@ -176,6 +189,34 @@ def test_every_round_counts_one_dispatch_beside_its_program(run):
             for family in (ROUNDS, DISPATCHES))
         # the fixture's one step more, on rank 0, is outside both reads
         assert dispatches == programs == rounds
+
+
+def test_the_gauge_says_where_the_parameters_take_their_shape(run):
+    """By the rank of the model's ``param_shape`` alone: a class axis is
+    restored inside the jitted program, a rank-1 vector nowhere."""
+    want = "device" if run["cfg"].model == "softmax" else "none"
+    for rank in run["rounds"]:
+        assert run["shaped"][rank] == ([want], 1)
+
+
+@pytest.mark.parametrize("model", MODELS)
+def test_a_numpy_step_shapes_its_parameters_on_the_host(
+        tmp_path_factory, ps_steps_on, model):
+    d = str(tmp_path_factory.mktemp(f"chain-numpy-{model}"))
+    cfg = _cfg(d, model, "resident", "pipelined-async")
+    with _group(cfg) as group, ps_steps_on("numpy"):
+        worker = PSWorker(cfg, 0, group.hosts)
+        try:
+            worker.load_data()
+            assert worker._resident is None  # numpy places nothing
+            want = "host" if model == "softmax" else "none"
+            assert _shaped_at(0) == ([want], 1)
+            g = worker.grad_step(
+                np.full(ps_param_dim(cfg), 0.01, np.float32),
+                worker._train.next_batch())
+            assert g.shape == (ps_param_dim(cfg),) and np.count_nonzero(g)
+        finally:
+            worker.close()
 
 
 def test_the_weights_array_written_over_afterwards_changes_nothing(run):

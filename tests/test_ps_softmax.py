@@ -1,12 +1,15 @@
 """The multiclass PS job: a softmax ``PSWorker`` over loopback servers with
-its shard resident, held against the benchmark's plain reference; and the
+its shard resident, held against the benchmark's plain reference; the
 precision its float32 products state, read off the lowered programs (the
 guard a CPU can give for a fault only a TPU shows: there a float32 ``dot``
-that states nothing is one bfloat16 pass)."""
+that states nothing is one bfloat16 pass); and the form its parameters
+cross the host link in: the flat vector the wire carries, the model's
+shape restored inside the jitted programs alone."""
 
 import os
 import re
 import threading
+import types
 
 import jax
 import numpy as np
@@ -146,14 +149,19 @@ def test_a_fit_moves_the_whole_class_axis_over_the_wire(job):
 
 
 # -- the precision the lowered programs state ------------------------------
-def _lowered(model, rows=16):
-    shape = model.param_shape
-    w = jax.ShapeDtypeStruct(shape, np.float32)
+def _shapes(model, rows=16):
+    """The programs' operands: the parameters flat, as the wire has them."""
+    w = jax.ShapeDtypeStruct((int(np.prod(model.param_shape)),), np.float32)
     X = jax.ShapeDtypeStruct((rows, model.num_features), np.float32)
     y = jax.ShapeDtypeStruct((rows,), np.int32)
     mask = jax.ShapeDtypeStruct((rows,), np.bool_)
-    step = ps_trainer._compiled_fns(model, 0.0, False).lower(w, X, y, mask)
-    ev = ps_trainer._compiled_acc(model).lower(w, X, y, mask)
+    return w, X, y, mask
+
+
+def _lowered(model, rows=16):
+    shapes = _shapes(model, rows)
+    step = ps_trainer._compiled_fns(model, 0.0, False).lower(*shapes)
+    ev = ps_trainer._compiled_acc(model).lower(*shapes)
     return step.as_text(), ev.as_text()
 
 
@@ -190,3 +198,94 @@ def test_a_binary_models_programs_state_no_precision(compute_dtype):
     for text in (step, ev):
         assert _dots(text)
         assert "HIGHEST" not in text and "precision = [HIGH" not in text
+
+
+# -- the parameters flat, the model's shape inside the program -------------
+GCFG = types.SimpleNamespace(l2_c=0.01, l2_scale_by_batch=True)
+FLAT_ROWS, FIRST, WINDOW = 48, 16, 24
+
+
+def _flat_case(compute_dtype):
+    model = SoftmaxRegression(96, K, compute_dtype=compute_dtype)
+    rng = np.random.default_rng(3)
+    w = (rng.standard_normal(96 * K) * 0.3).astype(np.float32)
+    X = rng.standard_normal((FLAT_ROWS, 96)).astype(np.float32)
+    y = rng.integers(0, K, FLAT_ROWS).astype(np.int32)
+    mask = (rng.random(FLAT_ROWS) < 0.9).astype(np.float32)
+    return model, w, (X, y, mask)
+
+
+@pytest.mark.parametrize("rows", ["whole", "window"])
+@pytest.mark.parametrize("compute_dtype", ["float32", "bfloat16"])
+def test_the_flat_step_is_the_models_gradient_bit_for_bit(compute_dtype, rows):
+    """``SoftmaxRegression.grad`` is called as it is: what the program
+    adds is two reshapes, and on the CPU they move no bit."""
+    model, w, batch = _flat_case(compute_dtype)
+    step = ps_trainer._compiled_fns(model, GCFG.l2_c, GCFG.l2_scale_by_batch)
+    if rows == "window":
+        got = step(w, *batch, first=np.int32(FIRST), window=WINDOW)
+        batch = tuple(a[FIRST:FIRST + WINDOW] for a in batch)
+    else:
+        got = step(w, *batch)
+    want = jax.jit(lambda W, b: model.grad(W, b, GCFG))(
+        w.reshape(model.param_shape), batch)
+    got = np.asarray(got)
+    assert got.shape == (96 * K,) and got.dtype == np.float32
+    assert np.array_equal(got, np.asarray(want).reshape(-1))
+    assert np.count_nonzero(got)
+
+
+@pytest.mark.parametrize("compute_dtype", ["float32", "bfloat16"])
+def test_the_flat_eval_is_the_models_forward_bit_for_bit(compute_dtype):
+    model, w, batch = _flat_case(compute_dtype)
+    got = ps_trainer._compiled_acc(model)(w, *batch)
+    want = jax.jit(lambda W, X, y, mask: model.eval_from_logits(
+        model.logits(W, X), y, mask))(w.reshape(model.param_shape), *batch)
+    assert [float(v) for v in got] == [float(v) for v in want]
+
+
+def _signature(text):
+    """Operand and result types of a lowered module's ``main``."""
+    main = re.search(r"func\.func public @main\((.*?)\) -> \((.*?)\) \{",
+                     text, re.S)
+    operands = re.findall(r"%arg\d+: (tensor<[^>]*>)", main.group(1))
+    results = re.findall(r"tensor<[^>]*>", main.group(2))
+    return operands, results
+
+
+@pytest.mark.parametrize("program", ["jit_ps_grad_step", "jit_ps_eval"])
+def test_a_softmax_program_takes_its_weights_flat(program):
+    """Operand 0 is ``f32[D*K]``, rank 1: what ``device_put`` is handed and
+    the device lays out by itself is the wire's vector, a straight copy;
+    the step's result is rank 1 too.  The products still state
+    ``HIGHEST`` (the case above reads the same text)."""
+    step, ev = _lowered(SoftmaxRegression(96, K, compute_dtype="float32"))
+    operands, results = _signature(step if program == "jit_ps_grad_step"
+                                   else ev)
+    assert operands[0] == f"tensor<{96 * K}xf32>"
+    if program == "jit_ps_grad_step":
+        assert results == [f"tensor<{96 * K}xf32>"]
+    else:
+        assert results == ["tensor<f32>", "tensor<f32>"]
+
+
+@pytest.mark.parametrize("compute_dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("program", ["jit_ps_grad_step", "jit_ps_eval"])
+def test_a_binary_models_programs_are_the_models_own(program, compute_dtype):
+    """Rank-1 parameters are their own shape: both reshapes trace to
+    nothing, and the lowered text is that of ``model.grad`` (``logits``
+    and ``eval_from_logits``) jitted directly under the same name."""
+    model = BinaryLR(96, compute_dtype=compute_dtype)
+    step, ev = _lowered(model)
+
+    def ps_grad_step(w, X, y, mask):
+        return model.grad(w, (X, y, mask), types.SimpleNamespace(
+            l2_c=0.0, l2_scale_by_batch=False))
+
+    def ps_eval(w, X, y, mask):
+        return model.eval_from_logits(model.logits(w, X), y, mask)
+
+    if program == "jit_ps_grad_step":
+        assert step == jax.jit(ps_grad_step).lower(*_shapes(model)).as_text()
+    else:
+        assert ev == jax.jit(ps_eval).lower(*_shapes(model)).as_text()
