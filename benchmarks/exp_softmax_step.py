@@ -38,6 +38,21 @@ between them.  Two things about it had never been read on a chip:
   device alone, as ``PSWorker.grad_step`` enqueues it (put, program,
   readback, one wait), one worker and four at once.
 
+* **the one-read kernel** (PR 46, ``ops/pallas_softmax.py``).
+  ``kernel form=resident`` is its arithmetic alone: every panel's two
+  products (six bfloat16 partial products each, X the stationary
+  operand), the softmax and the parts, over ONE 128-row panel fetched
+  once and held in VMEM, x 31, no HBM traffic; ``kernel form=fused`` is
+  the kernel as it ships, the shard crossing HBM once; each by the
+  column tiles of a block (``--block-tiles``), with its two float64
+  errors (the resident form's against the float64 gradient of panel 0's
+  rows under every panel's labels).  ``step operands=fused`` is
+  ``ps_trainer._compiled_fns`` with the kernel's plan over the shard
+  relaid row-major (what a worker runs since PR 46), interleaved with
+  ``flat relayout=product`` over the default layout (what it ran
+  before, and runs without a plan) over ``--rounds``.  ``--only-kernel``
+  prints these alone.
+
 Prints a line a variant (``ms`` a step over ``--steps`` runs, the
 relative error of the gradient's norm and of the gradient) and, for the
 ``highest`` variants, the device operations of a traced run, which is
@@ -51,9 +66,17 @@ PERF.md section 6 under that PR: the two reshapes cost the program
 ``product`` (an emitter XLA picks for the backward fusion when both its
 class-axis operand and result are typed ``[K, D]``, not a cheaper
 relayout), by less than the cell's spread end to end, so the product
-keeps the plain reshapes.  Exits non-zero without a
-TPU (``--smoke`` runs a tiny shape anywhere and says nothing about a
-time).
+keeps the plain reshapes.  PR 46's (PERF.md section 6 under that PR):
+``resident`` 1.544 ms and ``fused`` 1.546 ms at the plan's blocks of 27
+tiles (the kernel is bound by its arithmetic, the one read hides under
+it; blocks of 4, 8, 16 and 32 tiles read 2.25, 1.69, 1.55 and 1.59 ms
+fused), 5.7e-9 and 3.1e-7 off the float64 gradient where XLA's
+``highest`` step reads 1.4e-8 and 3.3e-7; the step that ships 1.708 ms
+beside 2.890.  The first reading had the weights' parts split by a
+conversion to bfloat16 and back under XLA, which XLA removes: 2.1e-4
+off (``split3_xla`` is the cure).  Exits non-zero without a
+TPU (``--smoke`` runs a tiny shape anywhere, the kernel interpreted, and
+says nothing about a time).
 
 Run on the chip: python benchmarks/exp_softmax_step.py
 """
@@ -61,6 +84,7 @@ Run on the chip: python benchmarks/exp_softmax_step.py
 from __future__ import annotations
 
 import argparse
+import functools
 import os
 import shutil
 import sys
@@ -78,6 +102,8 @@ import jax.numpy as jnp  # noqa: E402
 
 from chipbench import trace_reduce  # noqa: E402
 from distlr_tpu.models.linear import SoftmaxRegression  # noqa: E402
+from distlr_tpu.ops import pallas_softmax  # noqa: E402
+from distlr_tpu.ops.pallas_lr import pad_columns  # noqa: E402
 from distlr_tpu.train import ps_trainer  # noqa: E402
 
 PRECISIONS = {"default": None, "high": jax.lax.Precision.HIGH,
@@ -250,6 +276,71 @@ def chain_ms(fn, w_host, rest, dev, steps, threads=1):
             float(np.min([least for _, least in took])))
 
 
+def kernel_forms(X, y, W, want, dev, args, interpret):
+    """The one-read kernel (``ops/pallas_softmax.py``, PR 46) at the
+    shape: ``resident`` is its arithmetic alone (every panel's two
+    products, softmax and parts over panel 0's bytes, fetched once: no
+    HBM traffic), ``fused`` the kernel as it ships, the shard crossing
+    HBM once; each by the columns of a block.  Then the step that ships
+    (``_compiled_fns`` with the plan, flat in and out, the relayouts and
+    the parts of the weights XLA's) beside ``flat relayout=product``
+    over the default layout, interleaved."""
+    n, dim = X.shape
+    K = W.shape[1]
+    n_want = np.linalg.norm(want)
+    ones = np.ones(n, np.float32)
+    yd, md, wd = (jax.device_put(a, dev) for a in (y, ones, W))
+    # the resident form's own float64 gradient: panel 0's rows under
+    # every panel's labels
+    rows0 = pallas_softmax.PANEL_ROWS
+    want0 = float64_gradient(np.tile(X[:rows0], (n // rows0, 1)), y, W)
+    Xd = jax.device_put(X, dev)
+    for tiles in args.block_tiles:
+        plan = pallas_softmax.softmax_panel_plan(n, dim, K, block_tiles=tiles)
+        if plan is None:
+            print(f"EXP kernel block_tiles={tiles} no plan", flush=True)
+            continue
+        Xp = jax.block_until_ready(jax.jit(
+            lambda a, plan=plan: pad_columns(a, plan))(Xd))
+        for form, ref in (("resident", want0), ("fused", want)):
+            fn = jax.jit(functools.partial(
+                pallas_softmax.softmax_grad_panels, plan=plan,
+                interpret=interpret, resident=form == "resident"))
+            ms, g = _step_ms(fn, (wd, Xp, yd, md), args.steps)
+            got = np.asarray(g, np.float64) / n
+            n_ref = np.linalg.norm(ref)
+            print(f"EXP kernel form={form} block_cols={plan.block_cols} "
+                  f"blocks={plan.blocks} vmem_mib={plan.vmem_bytes / 2**20:.1f} "
+                  f"ms={ms:.4f} "
+                  f"norm_rel_gap={abs(np.linalg.norm(got) - n_ref) / n_ref:.3g} "
+                  f"diff_rel={np.linalg.norm(got - ref) / n_ref:.3g}",
+                  flush=True)
+            if not args.smoke and tiles == args.block_tiles[0]:
+                traced_ops(fn, (wd, Xp, yd, md), "row_major", f"kernel={form}")
+        del Xp
+    # the step that ships, beside the one it replaces
+    model = SoftmaxRegression(dim, K, compute_dtype="float32")
+    step = ps_trainer._compiled_fns(model, 0.0, False)
+    plan = pallas_softmax.softmax_panel_plan(n, dim, K)
+    flat = jax.device_put(np.ascontiguousarray(W.reshape(-1)), dev)
+    Xp = jax.block_until_ready(jax.jit(
+        lambda a: pad_columns(a, plan))(Xd))
+    calls = {
+        "flat relayout=product": lambda: step(flat, Xd, yd, md),
+        "fused": lambda: step(flat, Xp, yd, md, panels=plan,
+                              interpret=interpret),
+    }
+    took = interleaved_ms(calls, 3 * args.steps, args.rounds)
+    for name, call in calls.items():
+        got = np.asarray(call(), np.float64).reshape(dim, K)
+        print(f"EXP step operands={name} ms={np.median(took[name]):.4f} "
+              f"readings={[round(v, 4) for v in took[name]]} "
+              f"norm_rel_gap={abs(np.linalg.norm(got) - n_want) / n_want:.3g} "
+              f"diff_rel={np.linalg.norm(got - want) / n_want:.3g}", flush=True)
+    if not args.smoke:
+        traced_ops(calls["fused"], (), "row_major", "operands=fused")
+
+
 def traced_ops(fn, operands, layout, tag, runs=5):
     trace_dir = tempfile.mkdtemp(prefix="exp-softmax-")
     try:
@@ -276,17 +367,35 @@ def main(argv=None) -> int:
                     help="interleaved readings of each form of the step, "
                          "3 x --steps dispatches a reading")
     ap.add_argument("--seed", type=int, default=7)
+    ap.add_argument("--block-tiles", default=[None],
+                    type=lambda s: [int(v) or None for v in s.split(",")],
+                    help="128-column tiles of the kernel's block, a reading "
+                         "of both forms each (0: the plan's own choice)")
+    ap.add_argument("--only-kernel", action="store_true",
+                    help="the one-read kernel's readings alone")
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args(argv)
     if args.smoke:
-        args.rows, args.dim, args.steps, args.rounds = 128, 1000, 2, 2
+        args.rows, args.dim, args.steps, args.rounds = 256, 1000, 2, 2
     dev = jax.devices()[0]
     if dev.platform != "tpu" and not args.smoke:
         raise SystemExit(f"the experiment reads a TPU; JAX found {dev.platform}")
     n, dim, K = args.rows, args.dim, args.classes
-    padded = -(-dim // 128) * 128
     X, y, W = rows(args.seed, n, dim, K, args.nnz)
     want = float64_gradient(X, y, W)
+    n_want = np.linalg.norm(want)
+    if not args.only_kernel:
+        xla_forms(X, y, W, want, dev, args)
+    kernel_forms(X, y, W, want, dev, args, interpret=dev.platform != "tpu")
+    return 0
+
+
+def xla_forms(X, y, W, want, dev, args):
+    """XLA's step by layout and precision, the link, and the product's
+    step by the form of its operands (PRs 44 and 45)."""
+    n, dim = X.shape
+    K = W.shape[1]
+    padded = -(-dim // 128) * 128
     n_want = np.linalg.norm(want)
     held = {"default": jax.device_put(X, dev)}
     held["row_major"] = jax.block_until_ready(jax.jit(
@@ -351,7 +460,6 @@ def main(argv=None) -> int:
         if not args.smoke:
             traced_ops(fn, (on_dev[name], *rest), "default",
                        f"operands={operands.replace(' ', '_')}")
-    return 0
 
 
 if __name__ == "__main__":

@@ -144,6 +144,25 @@ def _l2_grad(w, cfg: Config, batch_n):
     return term / batch_n if cfg.l2_scale_by_batch else term
 
 
+def _grad_from_panels(kernel, w, batch, cfg: Config, plan, feature_scale,
+                      first, interpret):
+    """A dense model's ``grad`` round a row-panel kernel's ``X^T r``
+    (``ops.pallas_lr.lr_grad_panels``, ``ops.pallas_softmax.
+    softmax_grad_panels``): the window's ``y`` and ``mask`` sliced here,
+    the mean, ``feature_scale`` and the L2 term as ``grad`` has them."""
+    Xp, y, mask = batch
+    if first is not None:
+        y, mask = (jax.lax.dynamic_slice(a, (first,), (plan.rows,))
+                   for a in (y, mask))
+    n = jnp.maximum(jnp.sum(mask), 1).astype(jnp.float32)
+    scaled = feature_scale != 1.0
+    g = kernel(w * feature_scale if scaled else w, Xp, y, mask, plan,
+               first=first, interpret=interpret) / n
+    if scaled:
+        g = g * feature_scale
+    return g + _l2_grad(w, cfg, n)
+
+
 @dataclasses.dataclass(frozen=True)
 class BinaryLR:
     """Dense binary logistic regression: params = w of shape (D,)."""
@@ -242,18 +261,8 @@ class BinaryLR:
         window where it lies."""
         from distlr_tpu.ops.pallas_lr import lr_grad_panels  # noqa: PLC0415
 
-        Xp, y, mask = batch
-        if first is not None:
-            y, mask = (jax.lax.dynamic_slice(a, (first,), (plan.rows,))
-                       for a in (y, mask))
-        n = jnp.maximum(jnp.sum(mask), 1).astype(jnp.float32)
-        scaled = self.feature_scale != 1.0
-        g = lr_grad_panels(w * self.feature_scale if scaled else w,
-                           Xp, y, mask, plan, first=first,
-                           interpret=interpret) / n
-        if scaled:
-            g = g * self.feature_scale
-        return g + _l2_grad(w, cfg, n)
+        return _grad_from_panels(lr_grad_panels, w, batch, cfg, plan,
+                                 self.feature_scale, first, interpret)
 
     def predict(self, w, X):
         # Reference decision rule: z > 0 (src/lr.cc:100-106).
@@ -382,6 +391,23 @@ class SoftmaxRegression:
         if self.feature_scale != 1.0:
             g = g * self.feature_scale
         return g + _l2_grad(W, cfg, n)
+
+    def grad_panels(self, W, batch, cfg: Config, plan, *, first=None,
+                    interpret=False):
+        """:meth:`grad` from one HBM read of the features, for a batch
+        whose ``X`` is held as ``ops.pallas_lr.pad_columns(X, plan)``:
+        the row-panel kernel of ``ops/pallas_softmax.py`` gives
+        ``X^T R`` with both products float32 by the six bfloat16 partial
+        products ``Precision.HIGHEST`` is (``compute_dtype="float32"``'s
+        arithmetic: a plan is made for no other, ``panel_plan``); the
+        mean, the L2 term and ``feature_scale`` are this method's, as in
+        ``grad``.  ``first``: see :meth:`BinaryLR.grad_panels`."""
+        from distlr_tpu.ops.pallas_softmax import (  # noqa: PLC0415
+            softmax_grad_panels,
+        )
+
+        return _grad_from_panels(softmax_grad_panels, W, batch, cfg, plan,
+                                 self.feature_scale, first, interpret)
 
     def predict(self, W, X):
         return jnp.argmax(self.logits(W, X), axis=-1).astype(jnp.int32)

@@ -26,8 +26,10 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import importlib
 import json
 import os
+import sys
 import threading
 import time
 import types
@@ -41,7 +43,7 @@ from distlr_tpu.data import DataIter
 from distlr_tpu.data.iterator import SparseDataIter, Window
 from distlr_tpu.data.sharding import part_name
 from distlr_tpu.models import get_model, host_math
-from distlr_tpu.models.linear import BinaryLR
+from distlr_tpu.models.linear import BinaryLR, SoftmaxRegression
 from distlr_tpu.obs import dtrace, jaxrt
 from distlr_tpu.obs.registry import COUNT_BUCKETS, get_registry
 from distlr_tpu.obs.tracing import loop_span
@@ -174,13 +176,16 @@ _STEP_DEVICE = get_registry().gauge(
 
 
 #: Which program a dense worker's step on a jax device ran, a round:
-#: ``one_pass`` is the row-panel kernel over a resident row-major shard
-#: (``ops/pallas_lr.py``), ``two_pass`` is ``model.grad`` under XLA, whose
+#: ``one_pass`` is a row-panel kernel over a resident row-major shard
+#: (``ops/pallas_lr.py`` for a binary model, ``ops/pallas_softmax.py``
+#: for a float32 softmax), ``two_pass`` is ``model.grad`` under XLA, whose
 #: forward and backward each stream the features.
 _GRAD_ROUNDS = get_registry().counter(
     "distlr_ps_grad_rounds_total",
     "rounds of a PS worker's dense step on a jax device, by how often the "
-    "program reads the features out of HBM",
+    "program reads the features out of HBM (one_pass = a row-panel kernel, "
+    "the binary model's or the float32 softmax's; two_pass = XLA's forward "
+    "and backward products)",
     labelnames=("rank", "path"),
 )
 _GRAD_DISPATCHES = get_registry().counter(
@@ -194,7 +199,8 @@ _PANEL_HELD = get_registry().gauge(
     "distlr_ps_grad_panel_held",
     "share f of a row panel the one-pass step keeps in VMEM between its "
     "forward and backward sweeps: X crosses HBM 2 - f times a round "
-    "(0 = the two-pass program)",
+    "(0 = the two-pass program; the softmax kernel holds a panel whole or "
+    "has no plan)",
     labelnames=("rank",),
 )
 _PANEL_AHEAD = get_registry().gauge(
@@ -202,7 +208,7 @@ _PANEL_AHEAD = get_registry().gauge(
     "share ahead / chunks of the next row panel whose fetches the one-pass "
     "step has queued while the arithmetic between a panel's two sweeps "
     "runs: slots of VMEM beyond a held panel's (0 = a part-held panel, or "
-    "the two-pass program)",
+    "the two-pass program; the softmax kernel's second bank is 1)",
     labelnames=("rank",),
 )
 #: The class axis of a worker's step: the columns of the weights it pulls,
@@ -220,8 +226,9 @@ _RESIDENT_LAYOUT = get_registry().gauge(
     "distlr_ps_resident_layout",
     "how a PS worker's resident shard is held: row_major = relaid once on "
     "the device, columns in the lanes and zero-padded, for the one-pass "
-    "step; default = as the device lays the shape out by itself, which "
-    "XLA's two products read with no copy",
+    "step (a binary model's or a float32 softmax's row-panel kernel); "
+    "default = as the device lays the shape out by itself, which XLA's two "
+    "products read with no copy",
     labelnames=("rank", "layout"),
 )
 #: Where a dense step's parameters take the model's shape.  The wire, the
@@ -436,29 +443,58 @@ def _compiled_fns(model, l2_c: float, l2_scale_by_batch: bool):
 _ONE_PASS_PLATFORMS = ("tpu",)
 
 
-def _one_pass_plan(model, rows: int, dim: int, device):
+def _one_pass_plan(model, rows: int, dim: int, device, *, forward=False):
     """The row-panel plan for a resident ``float32[rows, dim]`` shard
-    whose step runs on ``device`` (``ops.pallas_lr.panel_plan``), or None
-    where the step stays ``model.grad`` under XLA: any model but a
-    ``BinaryLR`` without ``int8_dot``, a device that is no TPU, rows that
-    are not whole sublane groups, a panel of which VMEM holds nothing.
+    whose step runs on ``device``, or None where the step stays
+    ``model.grad`` under XLA: a device that is no TPU, a model with
+    ``int8_dot``, rows that are not whole sublane groups (a
+    ``BinaryLR``: ``ops.pallas_lr.panel_plan``) or whole 128-row panels
+    (a ``SoftmaxRegression``: ``ops.pallas_softmax.softmax_panel_plan``),
+    a panel VMEM does not hold (the binary kernel: none of it; the
+    softmax kernel: two whole ones beside the weights' parts and the
+    gradient).
 
-    A model without a plan (``softmax``: a class axis the kernel has no
-    sweep for) keeps its resident shard in the device's default layout
-    and its step is XLA's two products, counted ``path="two_pass"``: read
-    on the v5e at ``float32[3968, 62061]`` x ``[62061, 20]`` (the rows in
-    the lanes: 62,061 is no multiple of 128), each product is one fusion
-    that streams the shard at 750 GB/s with no transposing copy before
-    it, 2.71 ms a step against 2.71 over the same rows relaid row-major
-    and padded to 62,080 columns (``benchmarks/exp_softmax_step.py``;
-    PERF.md section 6, PR 44).  So nothing is relaid for it."""
-    if not isinstance(model, BinaryLR) or model.int8_dot:
+    The softmax model gets a plan where its arithmetic is the kernel's:
+    ``compute_dtype`` float32 (both products the six bfloat16 partial
+    products ``Precision.HIGHEST`` is; ``bfloat16`` keeps XLA's one-pass
+    products), a class axis whose three parts stand side by side in a
+    tile (``3 K <= 128``), and a gradient step: ``forward`` (the rows are
+    an eval's) answers None for it, since its forward alone is one XLA
+    fusion and one read already.  Without a plan its resident shard
+    keeps the device's default layout (``float32[3968, 62061]``: the
+    rows in the lanes), which XLA's two products each stream at
+    700-740 GB/s with no copy before them (2.89 ms a step on the v5e;
+    with the plan the shard crosses HBM once:
+    ``benchmarks/exp_softmax_step.py``; PERF.md section 6, PRs 44-46)."""
+    if (not isinstance(model, (BinaryLR, SoftmaxRegression)) or model.int8_dot
+            or device.platform not in _ONE_PASS_PLATFORMS):
         return None
-    if device.platform not in _ONE_PASS_PLATFORMS:
-        return None
-    from distlr_tpu.ops.pallas_lr import panel_plan  # noqa: PLC0415
+    if isinstance(model, BinaryLR):
+        from distlr_tpu.ops.pallas_lr import panel_plan  # noqa: PLC0415
 
-    return panel_plan(rows, dim)
+        return panel_plan(rows, dim)
+    if forward or jax.numpy.dtype(model.compute_dtype) != jax.numpy.float32:
+        return None
+    from distlr_tpu.ops.pallas_softmax import (  # noqa: PLC0415
+        softmax_panel_plan,
+    )
+
+    return softmax_panel_plan(rows, dim, model.num_classes)
+
+
+def _import_kernels_beside_the_load() -> None:
+    """Start importing the row-panel kernels on a thread of their own.
+    Pallas costs a process 1.5 s to import (read on the v5e's host,
+    PERF.md section 6, PR 46), and ``_one_pass_plan`` wants it only once
+    a worker's shard is parsed and densified: on a platform with a
+    one-pass program the import runs beside that instead of behind it.
+    Whoever needs the modules first waits on the import lock as for any
+    import; a failure surfaces there."""
+    name = "distlr_tpu.ops.pallas_softmax"    # imports ``pallas_lr`` too
+    if (name not in sys.modules
+            and jax.default_backend() in _ONE_PASS_PLATFORMS):
+        threading.Thread(target=importlib.import_module, args=(name,),
+                         name="distlr-import-kernels", daemon=True).start()
 
 
 @functools.lru_cache(maxsize=None)
@@ -863,17 +899,21 @@ class PSWorker:
     (XLA's forward and backward fusions each stream it).  A window is
     read where it lies: its first row goes to the kernel as a scalar,
     which adds it to a panel's row (no ``dynamic_slice`` of ``X``, which
-    would write the window out and read it again).  Everything else
-    (streamed batches, which would pay the relayout every round;
-    ``softmax``; a B that is no multiple of eight; the CPU) keeps the
-    device's default layout and ``model.grad`` under XLA, over a
-    ``dynamic_slice`` of the resident rows where the batch is a window.
-    A model without a plan loses nothing by that: ``softmax``'s two
-    products (``X W``, ``X^T R``; float32 at the precision its
-    ``compute_dtype`` states) each stream the default layout at HBM speed
-    with no copy before them, as they do a row-major one
-    (``_one_pass_plan``), so its resident shard is not relaid and its
-    rounds count ``path="two_pass"``.
+    would write the window out and read it again).  A ``softmax`` model
+    whose ``compute_dtype`` is float32 gets the same where its rows are
+    whole 128-row panels, three parts of its class axis fit a tile
+    (``3 K <= 128``) and VMEM holds two panels beside the weights' parts
+    and the gradient: the kernel of ``ops/pallas_softmax.py``, both
+    products float32 by the six bfloat16 partial products ``HIGHEST``
+    is.  Everything else (streamed batches, which would pay the relayout
+    every round; a bfloat16 or ``int8_dot`` softmax, whose products stay
+    XLA's one pass; a B that is no multiple of eight, or of 128 for the
+    softmax; the CPU) keeps the device's default layout and
+    ``model.grad`` under XLA, over a ``dynamic_slice`` of the resident
+    rows where the batch is a window, and its rounds count
+    ``path="two_pass"``.  Rank 0's test split keeps the default layout
+    under a softmax model whatever the step does: its forward alone is
+    one XLA fusion and one read.
     ``distlr_ps_resident_layout{rank, layout}`` says which way a resident
     shard is held (``row_major`` / ``default``),
     ``distlr_ps_step_classes{rank}`` the class axis of the step (20
@@ -1182,6 +1222,8 @@ class PSWorker:
         if self._train is not None:
             return
         with self._span("load_data"):
+            if self._grad_fn is not None:
+                _import_kernels_beside_the_load()
             train = (self._train_iter if self._train_iter is not None
                      else self._load_train_iter())
             test = self._test_iter if self._test_iter is not None else (
@@ -1370,21 +1412,23 @@ class PSWorker:
             sum(a.nbytes for a in batch))
         return placed
 
-    def _place_rows(self, span: str, batch, device, *, window=None, below=0):
+    def _place_rows(self, span: str, batch, device, *, window=None, below=0,
+                    forward=False):
         """``(X, y, mask)`` on ``device`` to stay, under a span called
         ``span``, and the row-panel plan ``X`` is held for (or None):
         each leaf through ``feed.place``; where a plan reads the rows
         (``_one_pass_plan``) the features are then relaid on the device,
         row-major and padded.  ``window``: the rows a step reads of them
         (all, where None), which the plan is for; ``below``: masked zero
-        rows to stand under them, the features' made on the device."""
+        rows to stand under them, the features' made on the device;
+        ``forward``: the rows are an eval's, read by no gradient step."""
         X, y, mask = batch
         if below:
             y = np.concatenate([y, np.zeros(below, y.dtype)])
             mask = np.concatenate([mask, np.zeros(below, mask.dtype)])
         mesh = make_mesh(devices=[device])
         plan = _one_pass_plan(self.model, window or X.shape[0], X.shape[1],
-                              device)
+                              device, forward=forward)
         with self._span(span):
             X, y, mask = (feed.place(a, mesh) for a in (X, y, mask))
             if plan is not None or below:
@@ -1666,7 +1710,8 @@ class PSWorker:
                 with self._span("h2d"):
                     return self._place(device, *batch), {}, rows
             self._test_resident = (
-                *self._place_rows("test_put", batch, device), rows)
+                *self._place_rows("test_put", batch, device, forward=True),
+                rows)
             gauge.set(nbytes)
             log.info("rank %d test split resident on %s: %d rows, %d bytes",
                      self.rank, _describe_compute_device(self._eval_dev),

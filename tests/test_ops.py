@@ -3,7 +3,9 @@ equal to ``BinaryLR.grad`` in float32 whatever share of a panel VMEM
 holds and however many slots it has to fetch ahead into, and compiled
 for a described v5e at the cell's size; beside it, in the one file that
 describes a v5e, the multiclass PS step as the compiler leaves it there
-(flat operand and result, the shard read as it lies)."""
+(flat operand and result, the shard read as it lies), without a plan
+and with the softmax kernel's (``ops/pallas_softmax.py``, whose
+interpreted tests are ``tests/test_ops_softmax.py``)."""
 
 import dataclasses
 import re
@@ -466,3 +468,43 @@ def test_the_softmax_step_compiles_for_a_v5e_flat_in_and_flat_out(chips):
     readers = _readers_of_the_parameter(entry, big)
     assert len(readers) == 2 and all(" fusion(" in ln for ln in readers)
     assert len(re.findall(rf"= {re.escape(big)}", entry)) == 1
+
+
+def test_the_one_read_softmax_step_compiles_for_a_v5e_at_the_cells_size(chips):
+    """``jit_ps_grad_step`` with the softmax kernel's plan, as a worker
+    of the multiclass cell runs it: Mosaic takes the kernel at
+    3,968 x 62,208 float32 under the plan's own count of VMEM; XLA hands
+    it the resident row-major shard as it lies (no copy, transpose or
+    reshape of the operand); operand 0 and the result stay the wire's
+    flat ``f32[1241220]``; and the parts of the weights are rounded by
+    ``reduce-precision``, which XLA's simplifier leaves in (a conversion
+    to bfloat16 and back it removes, and with it two of the three
+    parts)."""
+    from distlr_tpu.ops.pallas_softmax import softmax_panel_plan
+    from distlr_tpu.train import ps_trainer
+
+    rows, dim, classes = 3968, 62061, 20
+    model = SoftmaxRegression(dim, classes, compute_dtype="float32")
+    plan = softmax_panel_plan(rows, dim, classes)
+    assert plan.vmem_bytes <= plan.vmem_limit
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chips[0])
+
+    compiled = ps_trainer._compiled_fns(model, 0.0, False).lower(
+        spec((dim * classes,), jnp.float32),
+        spec((rows, plan.dim_padded), jnp.float32),
+        spec((rows,), jnp.int32), spec((rows,), jnp.float32),
+        panels=plan).compile()
+    text = compiled.as_text()
+    entry = text.split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    flat = f"f32[{dim * classes}]"
+    assert re.search(rf"%\S+ = {re.escape(flat)}\S* parameter\(0\)", entry)
+    assert re.search(rf"ROOT %\S+ = {re.escape(flat)}\S* reshape\(", entry)
+    big = f"f32[{rows},{plan.dim_padded}]"
+    readers = _readers_of_the_parameter(entry, big)
+    assert len(readers) == 1 and "tpu_custom_call" in readers[0], readers
+    assert len(re.findall(rf"= {re.escape(big)}", entry)) == 1
+    assert len(re.findall(r" reduce-precision\(", text)) >= 2
+    assert "dot(" not in text and "convolution(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
