@@ -147,12 +147,27 @@ class Config:
     # fused push_pull (the reply carries the post-update weights), and in
     # async mode additionally double-buffer — compute batch k+1's
     # gradient while batch k's round trip is in flight (self-staleness
-    # bounded by 1 in-flight push; Hogwild-legal).  Sync trajectories are
+    # bounded by 1 in-flight push; Hogwild-legal).  In sync mode the
+    # fused op blocks on the loop's own thread and trajectories are
     # bit-identical (BSP rounds are totally ordered, so the fused reply
-    # equals the next pull); set False for the reference-faithful op
-    # sequence.  Keyed models (sparse/blocked) ignore this (their pull
-    # and push key sets differ per batch).
+    # equals the next pull), unless ps_max_delay says the next round may
+    # run under it; set False for the reference-faithful op sequence.
+    # Keyed models (sparse/blocked) ignore this (their pull and push key
+    # sets differ per batch).
     ps_pipeline: bool = True
+    # Bounded-delay consistency for the sync (BSP) dense job (Li et al.,
+    # OSDI 2014: a worker may start round k+1 before its push of round k
+    # is acknowledged, but not before round k-tau is).  0 = lock step
+    # (every trajectory as pinned).  1 = a worker computes round k on the
+    # weights after round k-2 while its push of round k-1 stands at the
+    # servers' barrier: the servers still merge W pushes and apply one
+    # mean update a round, and every worker's round k still runs on the
+    # same weights, bit for bit, so the run has a trajectory (another one
+    # than lock step's).  Nothing is in flight at an eval, a checkpoint or
+    # fit's return.  tau >= 2 would need a server that holds two open
+    # rounds.
+    # 0 | 1
+    ps_max_delay: int = 0
     # Per-op receive timeout. A dead peer otherwise deadlocks the sync
     # BSP barrier forever (the reference's named straggler failure,
     # SURVEY.md §5.3), so detection is ON by default — but with a 10 min
@@ -593,6 +608,38 @@ class Config:
                 "(the group runs --optimizer=signsgd); it is incompatible "
                 f"with ps_optimizer={self.ps_optimizer!r}"
             )
+        if self.ps_max_delay not in (0, 1):
+            raise ValueError(
+                f"ps_max_delay must be 0 or 1, got {self.ps_max_delay!r}: "
+                "one connection carries one operation at a time and a "
+                "server holds one open round, so a worker can be one "
+                "round ahead of its acknowledgements and no more")
+        if self.ps_max_delay:
+            why = next((why for refused, why in (
+                (not self.sync_mode,
+                 "needs sync_mode: it bounds the delay of BSP rounds; an "
+                 "asynchronous push carried across an epoch's end is "
+                 "another lineage rule and no trajectory"),
+                (self.model in ("sparse_lr", "sparse_softmax", "blocked_lr"),
+                 f"is for dense models: a {self.model} round pulls and "
+                 "pushes its own batch's rows, so no fused reply holds the "
+                 "next round's weights"),
+                (not self.ps_pipeline,
+                 "needs ps_pipeline: the serialized pull-then-push has no "
+                 "fused reply for the next round to run under"),
+                (self.ps_accum_max > 1,
+                 "is incompatible with ps_accum_max > 1: a span already "
+                 "runs its rounds on the span's one pull, and a delayed "
+                 "span would be two kinds of staleness in one trajectory"),
+                (bool(self.sync_last_gradient),
+                 "is incompatible with sync_last_gradient: Q1's arrival-"
+                 "order lottery has no trajectory for the delay to keep"),
+                (self.ps_compress != "none",
+                 f"is incompatible with ps_compress={self.ps_compress!r}: "
+                 "no test holds a coded wire to the delayed reference"),
+            ) if refused), None)
+            if why:
+                raise ValueError(f"ps_max_delay=1 {why}")
         if self.ps_accum_start < 1 or self.ps_accum_max < self.ps_accum_start:
             raise ValueError(
                 "need 1 <= ps_accum_start <= ps_accum_max, got "

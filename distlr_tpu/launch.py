@@ -391,7 +391,7 @@ def _config_from_args(args: argparse.Namespace) -> Config:
             "nnz_max", "compat_mode", "checkpoint_dir", "checkpoint_interval",
             "profile_dir", "num_workers", "num_servers",
             "feature_dtype", "block_size", "block_groups", "ctr_fields",
-            "hash_seed", "ps_pipeline", "obs_metrics_port",
+            "hash_seed", "ps_pipeline", "ps_max_delay", "obs_metrics_port",
             "random_seed", "prefetch", "ps_timeout_ms",
             "obs_metrics_host", "obs_trace_path", "obs_run_dir",
             "ps_retry_attempts", "ps_retry_backoff_ms",
@@ -1986,6 +1986,14 @@ def main(argv=None) -> int:
                    help="disable the fused/pipelined dense PS protocol "
                    "(fall back to the reference's serialized two-round-"
                    "trips-per-batch sequence)")
+    p.add_argument("--ps-max-delay", dest="ps_max_delay", type=int,
+                   choices=[0, 1],
+                   help="bounded-delay consistency of the sync (BSP) dense "
+                   "job: 0 (default) = lock step; 1 = a worker computes "
+                   "round k on the weights after round k-2 while its push "
+                   "of round k-1 stands at the servers' barrier (the "
+                   "servers still apply one mean update a round; nothing "
+                   "is in flight at an eval, a checkpoint or the end)")
     p.set_defaults(fn=cmd_ps)
 
     r = sub.add_parser(

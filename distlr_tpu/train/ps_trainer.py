@@ -195,6 +195,16 @@ _GRAD_DISPATCHES = get_registry().counter(
     "still in flight (the round's chain stood enqueued whole) or landed",
     labelnames=("rank", "weights"),
 )
+_DELAYED_ROUNDS = get_registry().counter(
+    "distlr_ps_delayed_rounds_total",
+    "rounds of a BSP worker under bounded delay (ps_max_delay=1), by how "
+    "many rounds' updates the weights under the round's gradient lacked: "
+    "round 0 of a fit runs on what the worker holds with nothing in flight "
+    "and counts under behind=\"0\", every other round, round 1 included, "
+    "runs on the weights from before the push still at the servers and "
+    "counts under \"1\", so a fit of E rounds adds 1 and E - 1",
+    labelnames=("rank", "behind"),
+)
 _PANEL_HELD = get_registry().gauge(
     "distlr_ps_grad_panel_held",
     "share f of a row panel the one-pass step keeps in VMEM between its "
@@ -647,11 +657,20 @@ def keyed_model(cfg: Config):
 
 class _Exchange:
     """An exchange is how a round's weights reach :meth:`PSWorker.fit`'s
-    one loop and its gradient the servers: ``weights(keys)``,
-    ``send(g, keys)`` and, at an epoch's end, ``drain()``.  What outlives
-    a ``fit`` stays on the worker: what the benchmark reads (``_w_cache``,
-    ``_comm``) and the staleness stamp (``_w_time``, ``_w_pushes``); its
-    ``kv.pull`` / ``kv.push_pull`` / ``_comm_pool()`` are looked up at
+    one loop and its gradient the servers: ``weights(keys)`` and
+    ``send(g, keys)`` a round.  When nothing of the worker's is in
+    flight is the exchange's to say, stated once, here.  ``drain()``:
+    nothing is when it returns; ``fit`` calls it before whatever reads
+    the servers' weights (rank 0's eval, a checkpoint), and it costs
+    nothing where nothing is out.  ``finish()``: ``fit`` is about to
+    return; a drain, after which the worker holds the last reply.
+    ``epoch_end()``: what an epoch's end means to the protocol; a drain,
+    but under bounded delay (:class:`_Delayed`), where an epoch is no
+    boundary and the loop's epochs decide no round's staleness.  What
+    outlives a ``fit`` stays on the worker: what the benchmark reads
+    (``_w_cache``, ``_comm``, ``_in_flight``) and the staleness stamp
+    (``_w_time``, ``_w_pushes``); its ``kv.pull`` / ``kv.push_pull`` /
+    ``_comm_pool()`` are looked up at
     every call: taps replace them on the instance.  Each variant stamps
     where its weights arrive (async only counts: in BSP the weight age is
     the round); all feed ``_STALENESS`` and the pushes-behind histogram."""
@@ -678,6 +697,12 @@ class _Exchange:
 
     def drain(self):
         pass
+
+    def epoch_end(self):
+        self.drain()
+
+    def finish(self):
+        self.drain()
 
 
 class _Serialized(_Exchange):
@@ -793,14 +818,15 @@ class _Fused(_Exchange):
 
 
 class _Pipelined(_Fused):
-    """Async (Hogwild): the fused round trip double-buffered against
-    compute on the comm thread: batch k+1's gradient is computed while
-    batch k's ``push_pull`` is in flight, so the weights used are stale
-    by exactly the one in-flight push.  KV ops stay serialized on the
-    comm thread (one connection, never two ops concurrently), and none
-    is in flight across an epoch's end (``drain``)."""
-
-    fut = None
+    """The fused round trip double-buffered against compute on the comm
+    thread: batch k+1's gradient is computed while batch k's
+    ``push_pull`` is in flight (``PSWorker._in_flight``), so the weights
+    used are stale by exactly the one in-flight push.  KV ops stay
+    serialized on the comm thread (one connection, never two ops
+    concurrently).  As it stands it is the async (Hogwild) protocol: none
+    is in flight across an epoch's end (``epoch_end`` drains), so a
+    whole-shard epoch hides nothing.  :class:`_Delayed` is the same
+    pipeline against BSP servers."""
 
     def arrived(self, reply) -> None:
         self.w._w_cache = reply
@@ -818,19 +844,92 @@ class _Pipelined(_Fused):
         # the step's dtrace context and its round count ride along
         # explicitly: the comm thread is a different thread, and the fused
         # op belongs to the step that SUBMITTED it
-        self.fut = w._comm_pool().submit(
+        w._in_flight = w._comm_pool().submit(
             w._traced_push_pull, g, dtrace.current(), w.rounds)
 
     def drain(self):
-        # the epoch's last push: no round's compute is left to hide it
+        # no round's compute is left to hide this push
         self._wait(drain=1)
 
     def _wait(self, **more):
-        fut, self.fut = self.fut, None
-        if fut is not None:
-            with self.w._span("push", **more):
-                reply = fut.result()
+        reply = self._reply(**more)
+        if reply is not None:
             self.arrived(reply)
+
+    def _reply(self, **more):
+        """The reply to the push in flight, waited for under a ``push``
+        span; None where none is out."""
+        w = self.w
+        fut, w._in_flight = w._in_flight, None
+        if fut is None:
+            return None
+        with w._span("push", **more):
+            return fut.result()
+
+
+class _Delayed(_Pipelined):
+    """BSP under bounded delay, tau = 1 (``ps_max_delay``; Li et al.,
+    OSDI 2014): the pipeline against ``sync=1`` servers.  Round *k*'s
+    gradient is computed while the push of round *k* - 1 stands withheld
+    at the servers' barrier, on the weights after round *k* - 2.  With
+    rounds numbered from 0 inside one ``fit``, ``w_0`` what the worker
+    holds when it begins (the pull, or the last reply of the ``fit``
+    before) and ``v_k`` the weights under round *k*'s gradient:
+
+        v_0 = v_1 = w_0;   v_k = w_{k-1}  (k >= 2: the reply to the
+                                           worker's own push of round k-2)
+        w_{k+1} = w_k - lr * (sum over the W workers of g_r(v_k)) / W
+
+    The servers do what they do in lock step (merge W pushes, one update,
+    then every reply), and a worker sends push *k* only after the reply
+    to push *k* - 1, so no server holds two open rounds.  Every worker
+    runs round *k* on the same ``v_k``, bit for bit: the run has a
+    trajectory, and it is fixed by the rule and not by timing.  A reply
+    is taken where the rule has it (the ``send`` after the next
+    gradient) and never because it happened to be in; a ``drain`` that
+    waits for one early (rank 0's eval, a checkpoint) sets it aside, and
+    the next round still runs one round behind, so where the observers
+    fall changes nothing any worker computes.  An epoch is no boundary
+    (``epoch_end`` does nothing).  ``finish`` leaves the worker holding
+    ``w_E`` with nothing out.  Counted in
+    ``distlr_ps_delayed_rounds_total{rank, behind}``."""
+
+    def __init__(self, worker):
+        super().__init__(worker)
+        #: a reply a drain waited for, ahead of the round it is for
+        self.early = None
+        #: rounds this fit has begun: its first lacks nothing, every
+        #: later one lacks one round's update
+        self.begun = 0
+        rank = str(worker.rank)
+        self._behind = tuple(_DELAYED_ROUNDS.labels(rank=rank, behind=b)
+                             for b in ("0", "1"))
+
+    def weights(self, keys):
+        self._behind[self.begun > 0].inc()
+        self.begun += 1
+        return self.w._w_cache
+
+    def send(self, g, keys):
+        self._settle()
+        super().send(g, keys)
+
+    def epoch_end(self):
+        pass
+
+    def drain(self):
+        reply = self._reply(drain=1)
+        if reply is not None:
+            self.early = reply
+
+    def finish(self):
+        self.drain()
+        self._settle()
+
+    def _settle(self):
+        early, self.early = self.early, None
+        if early is not None:
+            self.arrived(early)
 
 
 class PSWorker:
@@ -938,9 +1037,17 @@ class PSWorker:
     the protocol: a round is its batch (``_rounds``; a keyed one then
     names its unique rows, ``_keyed_round``), ``exchange.weights``,
     :attr:`grad_step` (every model's, bound once by ``load_data()``),
-    ``exchange.send``; ``exchange.drain()`` ends the epoch.  The exchange
+    ``exchange.send``; ``exchange.epoch_end()`` ends the epoch, which to
+    every protocol but the bounded delay's means nothing stays in flight
+    across it.  The loop asks for nothing in flight (``exchange.drain()``)
+    before rank 0's eval and before a checkpoint, and ``exchange.finish()``
+    before it returns; when else a push may be out is the exchange's to
+    say (:class:`_Exchange`).  The exchange
     is chosen once a ``fit`` from the config and the model (``_exchange``;
-    ``_Exchange`` and its variants say what each does); how a keyed
+    ``_Exchange`` and its variants say what each does: serialized, a
+    span's mean, fused in lock step, pipelined in the asynchronous job,
+    and with ``ps_max_delay=1`` the pipeline against BSP servers,
+    :class:`_Delayed`); how a keyed
     model's rows cross the wire is ``RowKeys``' to say.
 
     Spans (``obs.tracing.loop_span``: ``PhaseTracer`` and, while a
@@ -955,12 +1062,16 @@ class PSWorker:
     wire carries whatever the model's shape: staging and enqueue, not
     the copy), ``compute`` (dispatch to the worker's own program
     finished, the rest of the weights' copy before it included; the
-    readback is enqueued inside, behind the program), ``grad_d2h`` (the
+    readback is enqueued inside, behind the program; under bounded delay
+    it carries ``in_flight=0|1``: a push of this worker's stood at the
+    servers while it ran), ``grad_d2h`` (the
     rest of that readback, of a flat gradient: a class axis is restored
     and flattened inside the program, ``distlr_ps_step_params_shaped``),
-    ``push`` (the loop blocked on its exchange; in the pipelined
-    exchange the one that ends an epoch, with no round's compute left to
-    hide it, carries ``drain=1`` beside ``step`` and ``rank``), ``pull``; ``wire`` on the comm thread (a
+    ``push`` (the loop blocked on its exchange; in a pipelined
+    exchange the waits no round's compute is left to hide carry
+    ``drain=1`` beside ``step`` and ``rank``: an epoch's last in the
+    asynchronous job; under bounded delay a ``fit``'s last, and rank 0's
+    before an eval or a checkpoint), ``pull``; ``wire`` on the comm thread (a
     pipelined push-pull, send to reply, with the step that submitted it);
     ``barrier_wait``, ``eval``, ``checkpoint``; under ``eval`` (rank 0,
     a dense model): ``eval_pull`` (the weights after the round, pulled as
@@ -1093,6 +1204,9 @@ class PSWorker:
         self._w_time = 0.0
         self._w_pushes: float | None = None
         self._comm = None
+        #: the comm thread's future of a fused push-pull now at the
+        #: servers (a pipelined exchange's; :attr:`in_flight`)
+        self._in_flight = None
         if cfg.model in ("sparse_lr", "blocked_lr") and cfg.l2_c > 0:
             # Keyed PS applies L2 lazily (only a batch's touched keys/rows
             # decay, scaled by touch frequency) while the sync trainer
@@ -1214,6 +1328,22 @@ class PSWorker:
         return loop_span(name, self.rounds, rank=self.rank,
                          marks_step=marks_step, **more)
 
+    @property
+    def in_flight(self) -> int:
+        """1 while a fused push-pull of this worker's is at the servers
+        (submitted by a pipelined exchange, its reply not yet taken)."""
+        return int(self._in_flight is not None)
+
+    def _compute_span(self):
+        """A dense round's ``compute`` span and step marker.  Under
+        bounded delay (``ps_max_delay``) it also says what stood in
+        flight while it ran: ``in_flight=1`` where a push of this
+        worker's was at the servers, 0 where none was."""
+        if self.cfg.ps_max_delay:
+            return self._span("compute", marks_step=True,
+                              in_flight=self.in_flight)
+        return self._span("compute", marks_step=True)
+
     def load_data(self) -> None:
         """Once a worker: bind the iterators (parsing the shards where
         none were handed in), pick the device of the dense step and of
@@ -1287,7 +1417,7 @@ class PSWorker:
         if step_dev == "numpy":
             def grad_step(wf, batch):
                 W = wf.reshape(cfg.num_feature_dim, K) if K else wf
-                with self._span("compute", marks_step=True):
+                with self._compute_span():
                     return host_math.dense_grad(
                         W, *batch, cfg.l2_c, bool(cfg.l2_scale_by_batch), K
                     ).reshape(-1)
@@ -1346,7 +1476,7 @@ class PSWorker:
                     # the hand-over (staging, enqueue), not the copy; the
                     # flat vector as it is: the program shapes it
                     w = jax.device_put(wf, step_dev)
-                with self._span("compute", marks_step=True):
+                with self._compute_span():
                     landed = w.is_ready()
                     g = self._grad_fn(w, *batch, **how)
                     g.copy_to_host_async()
@@ -1565,14 +1695,18 @@ class PSWorker:
                     gauge=_ACCUM_K.labels(rank=str(self.rank))))
         if keyed or not cfg.ps_pipeline:
             return _Serialized(self)
-        return _Fused(self) if cfg.sync_mode else _Pipelined(self)
+        if not cfg.sync_mode:
+            return _Pipelined(self)
+        return _Delayed(self) if cfg.ps_max_delay else _Fused(self)
 
     def fit(self, epochs: int | None = None, *, eval_fn=None,
             ckpt=None) -> None:
         """Run ``epochs`` more epochs (default: what is left of
         ``cfg.num_iteration``) from :attr:`epochs_done`, against a group
-        :meth:`start` has seeded (class docstring: the loop).  Leaves no
-        exchange in flight."""
+        :meth:`start` has seeded (class docstring: the loop).  Nothing
+        is in flight where it returns (``exchange.finish()``), nor while
+        rank 0 evaluates or a checkpoint is taken (``exchange.drain()``);
+        a ``fit`` that raises leaves what it had out to :meth:`close`."""
         cfg = self.cfg
         self.load_data()
         train, test = self._train, self._test
@@ -1592,7 +1726,7 @@ class PSWorker:
                 g = grad_step(w, batch)
                 exchange.send(g, keys)
                 self.timer.stop(n_real)
-            exchange.drain()
+            exchange.epoch_end()
             # runtime introspection (obs.jaxrt): fold this epoch's jit
             # cache growth into distlr_jax_compiles_total and refresh
             # the live device-buffer gauges (walk throttled process-wide)
@@ -1605,6 +1739,7 @@ class PSWorker:
                 and cfg.test_interval > 0
                 and (epoch + 1) % cfg.test_interval == 0
             ):
+                exchange.drain()
                 with self._span("eval"):
                     acc, test_ll = self.evaluate()
                 self.metrics.log(epoch=epoch + 1, accuracy=acc,
@@ -1619,11 +1754,13 @@ class PSWorker:
                 and cfg.checkpoint_interval > 0
                 and (epoch + 1) % cfg.checkpoint_interval == 0
             ):
+                exchange.drain()
                 with self._span("checkpoint"):
                     self._checkpoint(ckpt, epoch + 1)
 
             self.epochs_done = epoch + 1
 
+        exchange.finish()
         if ckpt is not None and last > first and ckpt.latest_step() != last:
             with self._span("checkpoint"):
                 self._checkpoint(ckpt, last)
