@@ -126,6 +126,7 @@
 #include <unordered_map>
 #include <vector>
 
+#include "kv_loops.h"
 #include "kv_protocol.h"
 
 namespace distlr {
@@ -1152,24 +1153,20 @@ class KVServer {
                   ((fp_.beta + std::sqrt(n_new)) / fp_.alpha + fp_.l2);
   }
 
-  // The optimizer governing one coordinate: the --opt_segments map when
-  // present (per-namespace optimizers: keys < end_i use opt_i, in
-  // ascending-end order), else the global --optimizer.  Segment lists
-  // are tiny (one entry per hosted namespace), so a linear scan beats
-  // anything clever.
-  inline Opt OptFor(Key k) const {
-    for (const auto& seg : opt_segments_) {
-      if (k < seg.first) return seg.second;
-    }
-    return opt_;
-  }
-
   // Apply the gradient values g[0, n) to the consecutive coordinates
   // [s, s + n) under the configured optimizer — THE pluggable update
   // this server exists to serialize (caller holds mu_).  One loop a
-  // stretch that one optimizer governs (the whole span unless an
-  // --opt_segments boundary falls inside it), each coordinate's
+  // stretch that one optimizer governs (the --opt_segments map when
+  // present: keys < end_i use opt_i, in ascending-end order, a tiny list
+  // scanned linearly; else the global --optimizer: so the whole span
+  // unless a boundary falls inside it), each coordinate's
   // expression kept verbatim: the trajectories are oracle-pinned.
+  // "Verbatim" is the same IEEE operations in the same order a
+  // coordinate, at whatever width: the SGD stretch is loops::SgdStep
+  // (kv_loops.h), which the release build packs four lanes at a time
+  // and the sanitizer builds run scalar, bit for bit the same; what
+  // forbids fusing its multiply into its subtract is -ffp-contract=off
+  // on the Makefile's CXXFLAGS line.
   // FTRL skips zero gradients (no information; and re-deriving w from
   // unchanged z would zero a freshly init-pushed weight, since init
   // seeds weights_ directly and leaves z/n at 0 until real traffic).
@@ -1195,8 +1192,7 @@ class KVServer {
           else if (g[j] < 0.0f) w[j] += lr_;
         }
       } else {
-        Val* w = weights_.data() + s;
-        for (uint64_t j = 0; j < take; ++j) w[j] -= lr_ * g[j];
+        loops::SgdStep(weights_.data() + s, g, take, lr_);
       }
       s += take;
       g += take;
@@ -1466,8 +1462,7 @@ class KVServer {
     {
       const Val* g = pending_.back().grad();
       rows.ForSpans([&](Key s, uint64_t at, uint64_t n) {
-        Val* m = merge_.data() + s;
-        for (uint64_t j = 0; j < n; ++j) m[j] += g[at + j];
+        loops::MergeAdd(merge_.data() + s, g + at, n);
       });
     }
     const double merged_s = MonoNowS();
@@ -1506,22 +1501,29 @@ class KVServer {
         if (pick != nullptr) {
           const Val* g = pick->grad();
           pick->rows().ForSpans([&](Key s, uint64_t at, uint64_t n) {
-            Val* wt = weights_.data() + s;
-            for (uint64_t j = 0; j < n; ++j) wt[j] -= lr_ * g[at + j] / w;
+            loops::MeanStep(weights_.data() + s, g + at, n, lr_, w);
           });
         }
       } else if (!opt_segments_.empty()) {
-        // Per-namespace optimizers (sgd|ftrl segments): dispatch the
-        // round's mean gradient per coordinate.  Uniform groups keep
-        // the verbatim loops below — those trajectories are
-        // oracle-pinned and must not change by a single operation.
-        for (size_t i = 0; i < merge_.size(); ++i) {
-          if (OptFor(i) == Opt::kFtrl) {
-            if (merge_[i] != 0.0f) FtrlStep(i, merge_[i] / w);
+        // Per-namespace optimizers (sgd|ftrl segments): the round's
+        // mean gradient, a stretch that one optimizer governs at a time
+        // (ApplySpan's rule: keys < end_i use opt_i, the rest opt_), each
+        // stretch the loop a uniform group of that optimizer runs below.
+        size_t at = 0;
+        auto stretch = [&](size_t end, Opt o) {
+          end = std::min(end, merge_.size());
+          if (end <= at) return;
+          if (o == Opt::kFtrl) {
+            for (size_t i = at; i < end; ++i)
+              if (merge_[i] != 0.0f) FtrlStep(i, merge_[i] / w);
           } else {
-            weights_[i] -= lr_ * merge_[i] / w;
+            loops::MeanStep(weights_.data() + at, merge_.data() + at,
+                            end - at, lr_, w);
           }
-        }
+          at = end;
+        };
+        for (const auto& seg : opt_segments_) stretch(seg.first, seg.second);
+        stretch(merge_.size(), opt_);
       } else if (opt_ == Opt::kFtrl) {
         // FTRL BSP: ONE optimizer step on the round's mean gradient,
         // untouched (zero-merge) coordinates skipped — see ApplySpan.
@@ -1541,10 +1543,20 @@ class KVServer {
       } else {
         // Correct BSP: mean of the merged gradients.  Expression kept
         // verbatim (lr*g/W, not lr*(g/W)) — the trajectory is pinned
-        // bit-identical by the reference-oracle parity tests.
-        for (size_t i = 0; i < merge_.size(); ++i)
-          weights_[i] -= lr_ * merge_[i] / w;
+        // bit-identical by the reference-oracle parity tests.  Verbatim
+        // a coordinate, at whatever width: loops::MeanStep (kv_loops.h)
+        // is a multiply, a divide and a subtract a weight, each rounded
+        // as the scalar loop rounds it, four lanes at a time in the
+        // release build (-ffp-contract=off on the Makefile's CXXFLAGS
+        // line is what keeps the multiply out of the subtract).
+        loops::MeanStep(weights_.data(), merge_.data(), merge_.size(), lr_,
+                        w);
       }
+      // A pass of its own: cleared inside MeanStep's loop (tried, PR 48)
+      // the release is slower, not faster, where it runs: weights_ was
+      // last read by the other cores' reply copies, and stores to merge_
+      // queued behind stores that wait for those lines cost more than a
+      // second pass over 2 MB this core has just read.
       std::fill(merge_.begin(), merge_.end(), 0.0f);
       release_apply_s_ += MonoNowS() - release_t0;
       std::vector<PendingPush> release;
