@@ -37,6 +37,7 @@ also written into a ``jax.profiler`` trace while one is taken.
 
 from __future__ import annotations
 
+import array
 import contextlib
 import itertools
 import json
@@ -47,10 +48,91 @@ import time
 from distlr_tpu.obs.registry import MetricsRegistry, get_registry
 
 #: Bounded event buffer: a long training run must not grow without limit.
-#: At ~100 B/event this caps trace memory near 20 MB; the per-phase
-#: breakdown keeps aggregating past the cap (only the *timeline* truncates,
-#: and the dump records how many events were dropped).
-MAX_TRACE_EVENTS = 200_000
+#: An event is one entry in each of eight typed columns
+#: (:class:`_Events`), 54 B and with the arrays' headroom under 58: the
+#: cap holds a buffer under 47 MB (as a tuple of nine boxed values an
+#: event took 200-260 B, so 200,000 of them 40-52 MB), and a 40 s window
+#: of the benchmark's busiest PS cell, some 420,000 events, fits whole.
+#: The per-phase breakdown keeps aggregating past the cap (only the
+#: *timeline* truncates, and the dump records how many events were
+#: dropped).
+MAX_TRACE_EVENTS = 800_000
+
+#: what a column holds where an event has no parent, step or rank
+_NO_ID, _NO_STEP, _NO_RANK = 0, -(1 << 63), -(1 << 31)
+
+
+class _Events:
+    """The event buffer as columns: one typed ``array`` a field, a
+    phase's name interned to its index, and the further stats of the rare
+    span that has any in a dictionary by event index.  Appended to and
+    copied under the tracer's lock; read as rows outside it."""
+
+    CODES = "HQddqqqi"  # name, tid, start, dur, span id, parent, step, rank
+
+    def __init__(self, columns=None, names=(), stats=()):
+        self.columns = columns or [array.array(c) for c in self.CODES]
+        self.names: dict[str, int] = dict(names)
+        self.stats: dict[int, dict] = dict(stats)
+        #: the columns' ``append``, bound once: a span is a few
+        #: microseconds of the loop it times
+        self.appends = tuple(c.append for c in self.columns)
+
+    def __len__(self) -> int:
+        return len(self.columns[0])
+
+    def copy(self) -> "_Events":
+        return _Events([c[:] for c in self.columns], self.names, self.stats)
+
+    def rows(self) -> list[tuple]:
+        """``(name, tid, start, duration, span id, parent id, step, rank,
+        further stats or None)`` an event, None where it has none."""
+        names = list(self.names)
+        stats = self.stats.get
+        return [
+            (names[n], tid, t0, dur, span_id,
+             None if parent == _NO_ID else parent,
+             None if step == _NO_STEP else step,
+             None if rank == _NO_RANK else rank, stats(i))
+            for i, (n, tid, t0, dur, span_id, parent, step, rank)
+            in enumerate(zip(*self.columns))]
+
+
+class _Span:
+    """One ``with`` block's span (:meth:`PhaseTracer.phase`).  A class
+    and not a generator's context manager: entering and leaving is most
+    of what a span costs the loop it times."""
+
+    __slots__ = ("tracer", "name", "step", "rank", "stats", "stack",
+                 "frame", "parent")
+
+    def __init__(self, tracer, name, step, rank, stats):
+        self.tracer, self.name, self.step, self.rank, self.stats = (
+            tracer, name, step, rank, stats)
+
+    def __enter__(self) -> None:
+        tracer = self.tracer
+        try:
+            stack = tracer._open.stack
+        except AttributeError:
+            stack = tracer._open.stack = []
+        self.stack = stack
+        self.parent = stack[-1][0] if stack else None
+        self.frame = frame = [next(tracer._ids), 0.0, self.step, self.rank,
+                              0.0]
+        stack.append(frame)
+        frame[4] = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        frame, stack = self.frame, self.stack
+        dur = t1 - frame[4]
+        stack.pop()
+        if stack:
+            stack[-1][1] += dur
+        self.tracer._keep(self.name, frame[4], dur,
+                          max(dur - frame[1], 0.0), frame[0], self.parent,
+                          self.step, self.rank, self.stats)
 
 
 class PhaseTracer:
@@ -61,55 +143,46 @@ class PhaseTracer:
         self._registry = registry or get_registry()
         self._max_events = max_events
         self._lock = threading.Lock()
-        # (name, tid, start, duration, span id, parent id, step, rank,
-        #  further stats or None)
-        self._events: list[tuple] = []
+        self._events = _Events()
         self._dropped = 0
         self._totals: dict[str, list] = {}  # phase -> [seconds, count, self]
         self._epoch = time.perf_counter()
         self._ids = itertools.count(1)
         # per thread: the open spans, innermost last, each
-        # [span id, seconds its finished children took, step, rank]
+        # [span id, seconds its finished children took, step, rank, start]
         self._open = threading.local()
         self._hist = self._registry.histogram(
             "distlr_phase_seconds",
-            "wall seconds spent per pipeline phase",
+            "wall seconds spent per pipeline phase (a PS worker's: "
+            "data_load, round and inside it w_put, compute, grad_d2h, push, "
+            "pull; epoch_end and inside it eval, checkpoint; "
+            "staleness_probe; wire on the comm thread, wire_handoff and "
+            "reply_wake to and from it; a keyed op's xchg_enter, xchg_send, "
+            "xchg_await, xchg_recv, xchg_wake, xchg_account)",
             labelnames=("phase",),
         )
         # a phase's series, looked up once: a span is a few microseconds
         # of the loop it times, on several threads at once
         self._series: dict[str, object] = {}
 
-    @contextlib.contextmanager
     def phase(self, name: str, step: int | None = None,
-              rank: int | None = None, **stats):
-        try:
-            stack = self._open.stack
-        except AttributeError:
-            stack = self._open.stack = []
-        frame = [next(self._ids), 0.0, step, rank]
-        parent = stack[-1][0] if stack else None
-        stack.append(frame)
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter()
-            dur = t1 - t0
-            stack.pop()
-            if stack:
-                stack[-1][1] += dur
-            self._keep(name, t0, dur, max(dur - frame[1], 0.0), frame[0],
-                       parent, step, rank, stats or None)
+              rank: int | None = None, **stats) -> "_Span":
+        """``with tracer.phase("pull", step=n): ...``: a span round the
+        block, a child of the span open on this thread."""
+        return _Span(self, name, step, rank, stats or None)
 
-    def completed(self, name: str, start: float, duration: float) -> None:
+    def completed(self, name: str, start: float, duration: float, *,
+                  inside: bool = True) -> None:
         """Record a span that has already ended: ``start`` and
         ``duration`` in seconds on ``time.perf_counter``'s clock, read by
         whoever did the work (the native KV client notes the instants of
         an exchange; ``CLOCK_MONOTONIC`` is that clock).  The span open
         on the calling thread is its parent: it gives ``step`` and
         ``rank`` and counts the duration among its children's, so its
-        ``self_seconds`` leaves it out.  With none open the span stands
+        ``self_seconds`` leaves it out.  ``inside=False``: a span that
+        led to its parent and ended where that began (the hand-over to
+        the thread that opened it) takes nothing from the parent's own
+        seconds.  With none open the span stands
         alone.  It is in the breakdown, the histogram and the event
         buffer as any span; it is no ``TraceAnnotation`` (one cannot be
         entered after the fact), so a profiler trace's readers get it
@@ -118,10 +191,17 @@ class PhaseTracer:
         parent = step = rank = None
         if stack:
             top = stack[-1]
-            top[1] += duration
+            if inside:
+                top[1] += duration
             parent, step, rank = top[0], top[2], top[3]
         self._keep(name, start, duration, duration, next(self._ids), parent,
                    step, rank)
+
+    def opened_at(self) -> float | None:
+        """When the innermost span open on the calling thread began, on
+        ``time.perf_counter``'s clock; None with none open."""
+        stack = getattr(self._open, "stack", None)
+        return stack[-1][4] if stack else None
 
     def _keep(self, name, t0, dur, own, span_id, parent, step, rank,
               stats=None) -> None:
@@ -138,10 +218,24 @@ class PhaseTracer:
                 tot[0] += dur
                 tot[1] += 1
                 tot[2] += own
-            if len(self._events) < self._max_events:
-                self._events.append(
-                    (name, tid, t0 - self._epoch, dur, span_id, parent,
-                     step, rank, stats))
+            events = self._events
+            kept = len(events)
+            if kept < self._max_events:
+                index = events.names.get(name)
+                if index is None:
+                    index = events.names[name] = len(events.names)
+                if stats:
+                    events.stats[kept] = stats
+                a_name, a_tid, a_t0, a_dur, a_id, a_parent, a_step, a_rank = (
+                    events.appends)
+                a_name(index)
+                a_tid(tid)
+                a_t0(t0 - self._epoch)
+                a_dur(dur)
+                a_id(span_id)
+                a_parent(_NO_ID if parent is None else parent)
+                a_step(_NO_STEP if step is None else step)
+                a_rank(_NO_RANK if rank is None else rank)
             else:
                 self._dropped += 1
 
@@ -162,7 +256,7 @@ class PhaseTracer:
 
     def reset(self) -> None:
         with self._lock:
-            self._events.clear()
+            self._events = _Events()
             self._totals.clear()
             self._dropped = 0
             self._epoch = time.perf_counter()
@@ -177,10 +271,11 @@ class PhaseTracer:
         epoch's drain: ``drain``)."""
         pid = os.getpid()
         with self._lock:
-            recorded = list(self._events)
+            recorded = self._events.copy()
             dropped = self._dropped
         events = []
-        for name, tid, t0, dur, span_id, parent, step, rank, stats in recorded:
+        for (name, tid, t0, dur, span_id, parent, step, rank,
+             stats) in recorded.rows():
             args = {"id": span_id, **(stats or {})}
             if parent is not None:
                 args["parent"] = parent

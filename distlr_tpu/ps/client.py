@@ -246,8 +246,8 @@ STATS_FIELDS = (
 #: kStats counters of the native servers, refreshed by every kStats read
 #: (:func:`mirror_server_stats`: the native process cannot scrape itself
 #: — the Python side mirrors its protocol counters into the registry).
-#: Every name of STATS_FIELDS has a series here; the phases of a push
-#: (``recv_seconds`` ... ``reply_write_seconds``) have this one only.
+#: Every name of STATS_FIELDS has a series here, and but for the
+#: handlers' ``cpu_*`` this one only.
 _SERVER_STAT = _reg.gauge(
     "distlr_ps_server_stat",
     "latest kStats read (a health probe, or any KVWorker.stats call of "
@@ -268,65 +268,6 @@ _SERVER_CPU = _reg.gauge(
     "health probe",
     labelnames=("rank", "handler"),
 )
-#: The kStats tail's counters with a series of their own beside
-#: ``distlr_ps_server_stat{rank, stat}``, which repeats every one of
-#: them (ROADMAP D5 lists these eight as duplicates to retire): the BSP
-#: barrier's (an async group reads zeros)
-#: ``run_frames``, how much of a rank's traffic its run path took,
-#: ``lock_wait_seconds``, what its pushes stood waiting for its lock, and
-#: the BSP release's ``release_fanned_replies`` and
-#: ``release_wall_seconds``: how often its replies left side by side,
-#: and how long a release (and so the lock it holds) lasted.
-_SERVER_TAIL = {
-    "sync_rounds": _reg.gauge(
-        "distlr_ps_server_sync_rounds",
-        "BSP rounds this server rank has released (one update applied "
-        "and every deferred reply sent), from the latest health probe",
-        labelnames=("rank",)),
-    "sync_hold_seconds": _reg.gauge(
-        "distlr_ps_server_sync_hold_seconds",
-        "cumulative seconds released pushes were held at the BSP "
-        "barrier, each from its arrival to its own reply written",
-        labelnames=("rank",)),
-    "sync_spread_seconds": _reg.gauge(
-        "distlr_ps_server_sync_spread_seconds",
-        "cumulative seconds between a BSP round's first and last "
-        "arrival at this server rank",
-        labelnames=("rank",)),
-    "cpu_release_seconds": _reg.gauge(
-        "distlr_ps_server_sync_release_cpu_seconds",
-        "cumulative thread CPU seconds of the BSP release (apply, "
-        "clear, the W gathers and replies, the writers' share "
-        "included); also inside "
-        "distlr_kv_server_cpu_seconds{handler=\"push\"}",
-        labelnames=("rank",)),
-    "run_frames": _reg.gauge(
-        "distlr_ps_server_run_frames",
-        "pushes and pulls this server rank handled as one range of slots "
-        "(a frame whose row keys are one consecutive run; a fused push-pull "
-        "counts in both, as in the stats total_pushes and total_pulls), "
-        "from the latest health probe",
-        labelnames=("rank",)),
-    "lock_wait_seconds": _reg.gauge(
-        "distlr_ps_server_lock_wait_seconds",
-        "cumulative wall seconds this server rank's push handlers stood "
-        "waiting for its one lock (behind other pushes' merges and the "
-        "BSP release), from the latest health probe",
-        labelnames=("rank",)),
-    "release_fanned_replies": _reg.gauge(
-        "distlr_ps_server_release_fanned_replies",
-        "deferred BSP replies this server rank had written by a thread "
-        "other than the releasing one (a round's value-carrying replies "
-        "leave side by side: W - 1 a round of W fused pushes, 0 for "
-        "header-only rounds), from the latest health probe",
-        labelnames=("rank",)),
-    "release_wall_seconds": _reg.gauge(
-        "distlr_ps_server_release_wall_seconds",
-        "cumulative wall seconds of this server rank's BSP releases, "
-        "from the last voter's merge done to the last reply written "
-        "(its lock is held that long), from the latest health probe",
-        labelnames=("rank",)),
-}
 #: one rank's gauge children, looked up once: a worker's staleness probe
 #: reads kStats tens of times a second
 _MIRRORED: dict[int, list] = {}
@@ -334,18 +275,18 @@ _MIRRORED: dict[int, list] = {}
 
 def mirror_server_stats(rank: int, stats: dict) -> None:
     """One kStats reply into the registry, where it was parsed: every
-    counter as ``distlr_ps_server_stat{rank, stat}``, the ``cpu_*`` ones
-    also as ``distlr_kv_server_cpu_seconds{rank, handler}``, eight of
-    the tail under series of their own.  The one function behind
-    :meth:`KVWorker.stats`, and so behind ``ServerGroup.health()``."""
+    counter as ``distlr_ps_server_stat{rank, stat}``, a handler's
+    ``cpu_*`` also as ``distlr_kv_server_cpu_seconds{rank, handler}``
+    (``cpu_release_seconds`` is no handler: the release's cycles are
+    inside the push's).  The one function behind :meth:`KVWorker.stats`,
+    and so behind ``ServerGroup.health()``."""
     children = _MIRRORED.get(rank)
     if children is None:
         children = _MIRRORED[rank] = []
         for name in STATS_FIELDS:
             mine = [_SERVER_STAT.labels(rank=rank, stat=name)]
-            if name in _SERVER_TAIL:
-                mine.append(_SERVER_TAIL[name].labels(rank=rank))
-            elif name.startswith("cpu_") and name.endswith("_seconds"):
+            if (name.startswith("cpu_") and name.endswith("_seconds")
+                    and name != "cpu_release_seconds"):
                 mine.append(_SERVER_CPU.labels(
                     rank=rank, handler=name[len("cpu_"):-len("_seconds")]))
             children.append((name, mine))
@@ -721,6 +662,9 @@ class KVWorker:
         # kv_last_carried its value-carrying frames (mapped, inline)
         self._xchg = (ctypes.c_double * 4)()
         self._carried = (ctypes.c_uint64 * 2)()
+        # the instants of the attempt that answered the op now returning,
+        # and when its native call was back in Python (_record_op)
+        self._answered: tuple | None = None
         #: connections of the current handle whose values cross in a
         #: shared mapping (kv_protocol.h "values in a mapping"):
         #: re-derived at every (re)connect from what the servers
@@ -1160,22 +1104,16 @@ class KVWorker:
             raise OSError("failed to set KV socket timeout")
         self._timeout_ms = int(timeout_ms)
 
-    def _record_exchange(self, op: str) -> None:
-        """The keyed op that has just returned, as three spans under the
-        span open on this thread (a loop's ``push`` or ``pull``, the comm
-        thread's ``wire``): ``xchg_send``, the call's start to the last
-        request byte handed over (on the socket: to the kernel; in a
-        mapping: the values copied into the request area and the header
-        in the kernel); ``xchg_await``, from there to the first reply
-        header read: the servers' read, merge, wait for the round and
-        release up to the first reply (a mapped reply's values are in
-        the reply area by then); ``xchg_recv``, from there to the last
-        value in the caller's buffer.  The native client noted the
-        instants on ``time.perf_counter``'s clock (``kv_last_exchange``);
-        together the three cover the call but for its entry and exit.
-        Nothing where no reply was read (a pull of no keys).  Its
-        value-carrying frames go by carrier (``kv_last_carried``) under
-        ``distlr_ps_payload_frames_total{op, carrier}``."""
+    def _record_exchange(self, op: str, back: float) -> None:
+        """The keyed op whose native call has just been answered; the
+        call returned at ``back``, the first instant Python read after
+        it.  Its value-carrying frames go by carrier
+        (``kv_last_carried``) under
+        ``distlr_ps_payload_frames_total{op, carrier}``, and its instants
+        (``kv_last_exchange``, on ``time.perf_counter``'s clock) are
+        kept with ``back`` for :meth:`_record_op`, which the op calls as
+        it returns.  Nothing is kept where no reply was read (a pull of
+        no keys: an instant not reached is 0)."""
         self._lib.kv_last_carried(self._h, self._carried)
         children = _PAYLOAD_CHILDREN.get(op)
         if children is None:
@@ -1188,12 +1126,47 @@ class KVWorker:
         t = self._xchg
         self._lib.kv_last_exchange(self._h, t)
         t0, t1, t2, t3 = t
-        if not 0.0 < t0 <= t1 <= t2 <= t3:  # an instant not reached is 0
+        self._answered = ((t0, t1, t2, t3, back)
+                          if 0.0 < t0 <= t1 <= t2 <= t3 <= back else None)
+
+    def _record_op(self, entered: float) -> None:
+        """The keyed op that is about to return, entered at ``entered``
+        (its first instruction in Python), as six spans one after
+        another under the span open on this thread (a loop's ``push`` or
+        ``pull``, the comm thread's ``wire``), which they cover but for
+        its own entry and exit:
+
+        * ``xchg_enter``: to the native call's start: the frame's keys,
+          the reply's buffer, the retry, trace and counter scopes;
+        * ``xchg_send``: to the last request byte handed over (on the
+          socket: to the kernel; in a mapping: the values copied into
+          the request area and the header in the kernel);
+        * ``xchg_await``: to the first reply header read: the servers'
+          read, merge, wait for the round and release up to the first
+          reply (a mapped reply's values are in the reply area by then);
+        * ``xchg_recv``: to the last value in the caller's buffer;
+        * ``xchg_wake``: to the first instant Python read after the
+          native call: the call's exit and the wait for the interpreter,
+          which another thread may hold;
+        * ``xchg_account``: to here: the reply checked, the op's
+          counters and byte accounts, the scopes' exits, these spans.
+
+        The native client noted the middle four instants.  On a retried
+        op they are those of the attempt that was answered, and
+        ``xchg_enter`` holds what went before it.  Nothing where no
+        attempt was (an op that raised, a push of unknown outcome, a
+        pull of no keys)."""
+        answered, self._answered = self._answered, None
+        if answered is None:
             return
-        tracer = get_tracer()
-        tracer.completed("xchg_send", t0, t1 - t0)
-        tracer.completed("xchg_await", t1, t2 - t1)
-        tracer.completed("xchg_recv", t2, t3 - t2)
+        t0, t1, t2, t3, back = answered
+        completed = get_tracer().completed
+        completed("xchg_enter", entered, t0 - entered)
+        completed("xchg_send", t0, t1 - t0)
+        completed("xchg_await", t1, t2 - t1)
+        completed("xchg_recv", t2, t3 - t2)
+        completed("xchg_wake", t3, back - t3)
+        completed("xchg_account", back, time.perf_counter() - back)
 
     def _check(self, ts: int, what: str) -> int:
         if ts < 0:
@@ -1337,6 +1310,7 @@ class KVWorker:
         crosses the wire coded; delivered pushes tick the
         ``distlr_ps_push_bytes_{raw,wire}_total`` counters exactly once
         each (a retried attempt counts only on its successful issue)."""
+        entered = time.perf_counter()
         vals = np.ascontiguousarray(vals, dtype=np.float32).reshape(-1)
         frame = self._push_frame(keys, int(vals_per_key), vals)
 
@@ -1351,14 +1325,17 @@ class KVWorker:
                     vals.ctypes.data_as(ctypes.c_void_p),
                     keys.shape[0], vpk,
                 )
+                back = time.perf_counter()
                 self._check(ts, "push")
-                self._record_exchange("push")
+                self._record_exchange("push", back)
                 _account_push_bytes(keys.nbytes + vals.nbytes,
                                     self._lib.kv_last_wire_sent(self._h))
                 return ts
 
         with self._trace_op("push"):
-            return self._push_with_retry("push", _issue)
+            ts = self._push_with_retry("push", _issue)
+        self._record_op(entered)
+        return ts
 
     def push_init(self, vals: np.ndarray, keys: np.ndarray | None = None,
                   *, force: bool = False) -> int:
@@ -1367,6 +1344,7 @@ class KVWorker:
         worker to re-send, unlike a plain first push.  ``force=True``
         overwrites live weights (kForceInit): checkpoint resume against a
         surviving group; restarted workers must NOT use it."""
+        entered = time.perf_counter()
         vals = np.ascontiguousarray(vals, dtype=np.float32)
         frame = self._resolve_keys(keys, 1, vals)
 
@@ -1381,13 +1359,16 @@ class KVWorker:
                     keys.shape[0],
                     1 if force else 0, vpk,
                 )
+                back = time.perf_counter()
                 self._check(ts, "push_init")
-                self._record_exchange("push_init")
+                self._record_exchange("push_init", back)
                 return ts
 
         # idempotent by protocol design (kInitPush no-ops once seeded;
         # kForceInit re-sends the same vals) -> plain retry is safe
-        return self._with_retry("push_init", _issue)
+        ts = self._with_retry("push_init", _issue)
+        self._record_op(entered)
+        return ts
 
     def push_pull(self, vals: np.ndarray,
                   keys: np.ndarray | None = None,
@@ -1399,6 +1380,7 @@ class KVWorker:
         returned weights are the post-round state — bit-identical to the
         pull that would have followed.  ``vals_per_key``: see
         :meth:`push`."""
+        entered = time.perf_counter()
         vals = np.ascontiguousarray(vals, dtype=np.float32).reshape(-1)
         frame = self._push_frame(keys, int(vals_per_key), vals)
         out = np.empty_like(vals)
@@ -1416,8 +1398,9 @@ class KVWorker:
                     out.ctypes.data_as(ctypes.c_void_p),
                     keys.shape[0], vpk,
                 )
+                back = time.perf_counter()
                 self._check(ts, "push_pull")
-                self._record_exchange("push_pull")
+                self._record_exchange("push_pull", back)
                 _account_push_bytes(keys.nbytes + vals.nbytes,
                                     self._lib.kv_last_wire_sent(self._h))
             return out
@@ -1431,13 +1414,16 @@ class KVWorker:
         # (counted), and the PULL half is re-issued idempotently so the
         # caller still gets current weights for the same keys.
         with self._trace_op("push_pull"):
-            return self._push_with_retry("push_pull", _issue,
-                                         on_unknown=_repull)
+            out = self._push_with_retry("push_pull", _issue,
+                                        on_unknown=_repull)
+        self._record_op(entered)
+        return out
 
     def pull(self, keys: np.ndarray | None = None,
              *, vals_per_key: int = 1) -> np.ndarray:
         """Blocking pull.  ``vals_per_key=R``: keys are row ids and the
         result holds ``len(keys)*R`` floats row-major (see :meth:`push`)."""
+        entered = time.perf_counter()
         frame = self._resolve_keys(keys, int(vals_per_key))
         out = np.empty(frame[0].shape[0] * frame[1], dtype=np.float32)
 
@@ -1451,12 +1437,15 @@ class KVWorker:
                     out.ctypes.data_as(ctypes.c_void_p),
                     keys.shape[0], vpk,
                 )
+                back = time.perf_counter()
                 self._check(ts, "pull")
-                self._record_exchange("pull")
+                self._record_exchange("pull", back)
             return out
 
         with self._trace_op("pull"):
-            return self._with_retry("pull", _issue)
+            out = self._with_retry("pull", _issue)
+        self._record_op(entered)
+        return out
 
     def pull_chunked(self, keys: np.ndarray | None = None, *,
                      vals_per_key: int = 1,

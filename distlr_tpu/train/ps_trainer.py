@@ -46,7 +46,7 @@ from distlr_tpu.models import get_model, host_math
 from distlr_tpu.models.linear import BinaryLR, SoftmaxRegression
 from distlr_tpu.obs import dtrace, jaxrt
 from distlr_tpu.obs.registry import COUNT_BUCKETS, get_registry
-from distlr_tpu.obs.tracing import loop_span
+from distlr_tpu.obs.tracing import get_tracer, loop_span, trace_phase
 from distlr_tpu.parallel import feed
 from distlr_tpu.parallel.mesh import make_mesh
 from distlr_tpu.ps import KVWorker, RetryPolicy, ServerGroup
@@ -843,9 +843,11 @@ class _Pipelined(_Fused):
         self._wait()
         # the step's dtrace context and its round count ride along
         # explicitly: the comm thread is a different thread, and the fused
-        # op belongs to the step that SUBMITTED it
+        # op belongs to the step that SUBMITTED it; so does the instant of
+        # the hand-over, which ``wire_handoff`` runs from
         w._in_flight = w._comm_pool().submit(
-            w._traced_push_pull, g, dtrace.current(), w.rounds)
+            w._traced_push_pull, g, dtrace.current(), w.rounds,
+            time.perf_counter())
 
     def drain(self):
         # no round's compute is left to hide this push
@@ -858,13 +860,22 @@ class _Pipelined(_Fused):
 
     def _reply(self, **more):
         """The reply to the push in flight, waited for under a ``push``
-        span; None where none is out."""
+        span; None where none is out.  ``reply_wake``, inside it: how
+        long a reply that was there waited for this loop to run, from
+        the later of the span's start and the ``wire`` span's end to
+        ``result()`` returned; ``push`` less it is what the loop waited
+        on the wire."""
         w = self.w
         fut, w._in_flight = w._in_flight, None
         if fut is None:
             return None
         with w._span("push", **more):
-            return fut.result()
+            reply = fut.result()
+            tracer = get_tracer()
+            there = max(tracer.opened_at(), w._wire_done)
+            tracer.completed("reply_wake", there,
+                             time.perf_counter() - there)
+            return reply
 
 
 class _Delayed(_Pipelined):
@@ -1055,7 +1066,10 @@ class PSWorker:
     worker's round count (:attr:`rounds`) and ``rank``: ``load_data`` and
     ``shard_put`` once; a round: ``data_load`` (fetching the batch: the
     numpy slice, nothing for a resident shard or a window of one; a
-    keyed batch's unique rows, inside the step),
+    keyed batch's unique rows, inside the step), then ``round`` (the
+    round's body, ``timer.start()`` to ``timer.stop()``: the parent of
+    the spans below, on the tracer alone; its self seconds are the
+    loop's own Python between them),
     ``h2d`` (a streamed batch's put, where the step's device is named:
     none opens in a resident or windowed round), ``w_put`` (the
     weights handed to the runtime for the device, the flat vector the
@@ -1071,9 +1085,17 @@ class PSWorker:
     exchange the waits no round's compute is left to hide carry
     ``drain=1`` beside ``step`` and ``rank``: an epoch's last in the
     asynchronous job; under bounded delay a ``fit``'s last, and rank 0's
-    before an eval or a checkpoint), ``pull``; ``wire`` on the comm thread (a
-    pipelined push-pull, send to reply, with the step that submitted it);
-    ``barrier_wait``, ``eval``, ``checkpoint``; under ``eval`` (rank 0,
+    before an eval or a checkpoint; ``reply_wake`` inside it: a reply
+    that was there, waiting for the loop to run), ``pull``; ``wire`` on
+    the comm thread (a pipelined push-pull, send to reply, with the step
+    that submitted it; ``wire_handoff`` under it: the loop's ``submit``
+    to the span's start); ``staleness_probe`` (an asynchronous worker's
+    kStats round trips on its probe connection, where one is made);
+    after an epoch's last round ``epoch_end`` (to the next epoch's first
+    ``data_load``, on the tracer alone: the exchange's end of the epoch,
+    so the asynchronous drain's ``push``, the runtime's probes, ``eval``
+    and ``checkpoint`` inside it; its self seconds are an epoch's
+    bookkeeping); ``barrier_wait``; under ``eval`` (rank 0,
     a dense model): ``eval_pull`` (the weights after the round, pulled as
     the reference's ``Test`` pulls them), ``test_put`` (the first eval
     alone: the test split placed on the eval's device, to ready),
@@ -1081,11 +1103,15 @@ class PSWorker:
     ready; a plain annotation, ``compute`` and the step marker stay the
     gradient step's), ``eval_d2h``.  Under whichever of them
     is open when a keyed operation returns, ``KVWorker`` records that
-    exchange's three phases from the native client's own instants, one
-    site for every exchange here: ``xchg_send`` (the call's start to
-    the last request byte handed to the kernel), ``xchg_await`` (to the
-    first reply header read: the servers' read, merge, wait for the
-    round and release), ``xchg_recv`` (to the last value read).  They are
+    operation's six phases, one site for every exchange here
+    (``KVWorker._record_op``): ``xchg_enter`` (the op's first
+    instruction in Python to the native call's start), then from the
+    native client's own instants ``xchg_send`` (to the last request byte
+    handed to the kernel), ``xchg_await`` (to the first reply header
+    read: the servers' read, merge, wait for the round and release) and
+    ``xchg_recv`` (to the last value read), ``xchg_wake`` (to Python
+    running again: the wait for the interpreter) and ``xchg_account``
+    (to the op's return: its counters and these spans).  They are
     ``PhaseTracer`` spans with their parent's ``step`` and ``rank`` and
     no annotations: an annotation cannot be entered after the fact.
     """
@@ -1205,8 +1231,10 @@ class PSWorker:
         self._w_pushes: float | None = None
         self._comm = None
         #: the comm thread's future of a fused push-pull now at the
-        #: servers (a pipelined exchange's; :attr:`in_flight`)
+        #: servers (a pipelined exchange's; :attr:`in_flight`), and when
+        #: the comm thread's last ``wire`` span had ended
         self._in_flight = None
+        self._wire_done = 0.0
         if cfg.model in ("sparse_lr", "blocked_lr") and cfg.l2_c > 0:
             # Keyed PS applies L2 lazily (only a batch's touched keys/rows
             # decay, scaled by touch frequency) while the sync trainer
@@ -1246,12 +1274,9 @@ class PSWorker:
                 self._probe_retry_at = (time.monotonic()
                                         + _PROBE_RETRY_COOLDOWN_S)
                 return None
-        try:
-            clock = self._push_probe.global_pushes()
-        except Exception:
-            self._drop_push_probe()
-            return None
-        self._last_pushes_sample = now
+        clock = self._probe_push_clock()
+        if clock is not None:
+            self._last_pushes_sample = now
         return clock
 
     def _record_pushes_behind(self, pulled_clock: float | None) -> None:
@@ -1260,12 +1285,21 @@ class PSWorker:
         peer updates the weights aged by while this worker computed."""
         if pulled_clock is None or self._push_probe is None:
             return
-        try:
-            clock = self._push_probe.global_pushes()
-        except Exception:
-            self._drop_push_probe()
-            return
-        self._staleness_pushes.observe(max(0.0, clock - pulled_clock))
+        clock = self._probe_push_clock()
+        if clock is not None:
+            self._staleness_pushes.observe(max(0.0, clock - pulled_clock))
+
+    def _probe_push_clock(self) -> float | None:
+        """The probe's round trips, one kStats read a server, under a
+        ``staleness_probe`` span: a probe that is made, never a
+        throttled return.  None, and the probe dropped, where it
+        failed."""
+        with self._loop_span("staleness_probe"):
+            try:
+                return self._push_probe.global_pushes()
+            except Exception:
+                self._drop_push_probe()
+                return None
 
     def _drop_push_probe(self) -> None:
         # A failed probe may mean the group is dying — or, under a
@@ -1327,6 +1361,13 @@ class PSWorker:
         """A span of this worker's loop: its round count and its rank."""
         return loop_span(name, self.rounds, rank=self.rank,
                          marks_step=marks_step, **more)
+
+    def _loop_span(self, name: str):
+        """A span on the tracer alone (no annotation: ``compute`` stays
+        the step marker and a profiler trace holds what it held):
+        ``round`` and ``epoch_end``, whose self seconds are the loop's
+        Python between their children, and the ``staleness_probe``."""
+        return trace_phase(name, self.rounds, self.rank)
 
     @property
     def in_flight(self) -> int:
@@ -1709,7 +1750,7 @@ class PSWorker:
         a ``fit`` that raises leaves what it had out to :meth:`close`."""
         cfg = self.cfg
         self.load_data()
-        train, test = self._train, self._test
+        train = self._train
 
         exchange = self._exchange()
         grad_step = self.grad_step
@@ -1719,51 +1760,61 @@ class PSWorker:
         for epoch in range(first, last):
             train.reset()
             for batch, n_real in self._rounds(train):
-                self.timer.start()
-                if keyed:
-                    batch, keys = self._keyed_round(batch)
-                w = exchange.weights(keys)
-                g = grad_step(w, batch)
-                exchange.send(g, keys)
-                self.timer.stop(n_real)
-            exchange.epoch_end()
-            # runtime introspection (obs.jaxrt): fold this epoch's jit
-            # cache growth into distlr_jax_compiles_total and refresh
-            # the live device-buffer gauges (walk throttled process-wide)
-            for probe in self._jit_probes:
-                probe.tick()
-            jaxrt.maybe_sample_device_bytes()
-            if (
-                self.rank == 0
-                and test is not None
-                and cfg.test_interval > 0
-                and (epoch + 1) % cfg.test_interval == 0
-            ):
-                exchange.drain()
-                with self._span("eval"):
-                    acc, test_ll = self.evaluate()
-                self.metrics.log(epoch=epoch + 1, accuracy=acc,
-                                 test_logloss=test_ll,
-                                 samples_per_sec=self.timer.samples_per_sec)
-                if eval_fn is not None:
-                    eval_fn(epoch + 1, acc)
-                else:
-                    log_eval_line(epoch + 1, acc)
-            if (
-                ckpt is not None
-                and cfg.checkpoint_interval > 0
-                and (epoch + 1) % cfg.checkpoint_interval == 0
-            ):
-                exchange.drain()
-                with self._span("checkpoint"):
-                    self._checkpoint(ckpt, epoch + 1)
-
-            self.epochs_done = epoch + 1
+                with self._loop_span("round"):
+                    self.timer.start()
+                    if keyed:
+                        batch, keys = self._keyed_round(batch)
+                    w = exchange.weights(keys)
+                    g = grad_step(w, batch)
+                    exchange.send(g, keys)
+                    self.timer.stop(n_real)
+            with self._loop_span("epoch_end"):
+                self._epoch_end(exchange, epoch + 1, eval_fn, ckpt)
 
         exchange.finish()
         if ckpt is not None and last > first and ckpt.latest_step() != last:
             with self._span("checkpoint"):
                 self._checkpoint(ckpt, last)
+
+    def _epoch_end(self, exchange: _Exchange, done: int, eval_fn,
+                   ckpt) -> None:
+        """What the loop does between an epoch's last round and the
+        next one's first, ``done`` epochs finished: the exchange's own
+        end of the epoch, the runtime's probes, rank 0's eval and the
+        checkpoint where one is due."""
+        cfg, test = self.cfg, self._test
+        exchange.epoch_end()
+        # runtime introspection (obs.jaxrt): fold this epoch's jit
+        # cache growth into distlr_jax_compiles_total and refresh
+        # the live device-buffer gauges (walk throttled process-wide)
+        for probe in self._jit_probes:
+            probe.tick()
+        jaxrt.maybe_sample_device_bytes()
+        if (
+            self.rank == 0
+            and test is not None
+            and cfg.test_interval > 0
+            and done % cfg.test_interval == 0
+        ):
+            exchange.drain()
+            with self._span("eval"):
+                acc, test_ll = self.evaluate()
+            self.metrics.log(epoch=done, accuracy=acc,
+                             test_logloss=test_ll,
+                             samples_per_sec=self.timer.samples_per_sec)
+            if eval_fn is not None:
+                eval_fn(done, acc)
+            else:
+                log_eval_line(done, acc)
+        if (
+            ckpt is not None
+            and cfg.checkpoint_interval > 0
+            and done % cfg.checkpoint_interval == 0
+        ):
+            exchange.drain()
+            with self._span("checkpoint"):
+                self._checkpoint(ckpt, done)
+        self.epochs_done = done
 
     def evaluate(self, w: np.ndarray | None = None) -> tuple[float, float]:
         """``(accuracy, logloss)`` on the test split: of what the servers
@@ -1914,14 +1965,21 @@ class PSWorker:
             )
         return self._comm
 
-    def _traced_push_pull(self, g, ctx, step):
+    def _traced_push_pull(self, g, ctx, step, submitted):
         """Comm-thread half of the pipelined fused op: re-install the
         submitting step's distributed-trace context (thread-local, so it
         doesn't cross the executor by itself) before issuing.  ``wire``
         is the exchange itself, send to reply, under the submitter's
-        step."""
+        step; ``wire_handoff``, recorded under it, is what led to it:
+        the loop's ``submit`` (at ``submitted``) to the span's start:
+        the executor's queue and this thread's wake-up."""
         with dtrace.use(ctx), loop_span("wire", step, rank=self.rank):
-            return self.kv.push_pull(g)
+            tracer = get_tracer()
+            tracer.completed("wire_handoff", submitted,
+                             tracer.opened_at() - submitted, inside=False)
+            reply = self.kv.push_pull(g)
+        self._wire_done = time.perf_counter()
+        return reply
 
     def close(self, *, wait: bool = True):
         self._drop_push_probe()

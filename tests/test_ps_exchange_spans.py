@@ -1,13 +1,17 @@
-"""An exchange's three phases on the client: ``xchg_send``,
-``xchg_await``, ``xchg_recv``, recorded by ``KVWorker`` after each keyed
-operation returns, from four instants the native client notes on
-``time.perf_counter``'s clock.  One site serves every loop variant of
-``PSWorker.fit``: the three lie under whichever of ``push``, ``pull`` and
-the comm thread's ``wire`` is open, and cover it but for the call's entry
-and exit."""
+"""A keyed operation's six phases on the client: ``xchg_enter`` (the
+op's first instruction in Python to the native call's start),
+``xchg_send``, ``xchg_await``, ``xchg_recv`` (from four instants the
+native client notes on ``time.perf_counter``'s clock), ``xchg_wake`` (the
+last value read to Python running again) and ``xchg_account`` (to the
+op's return), recorded by ``KVWorker`` as the op returns.  One site
+serves every loop variant of ``PSWorker.fit``: the six lie under
+whichever of ``push``, ``pull`` and the comm thread's ``wire`` is open,
+one after another, and cover it but for that span's own entry and exit."""
 
 import collections
 import ctypes
+import sys
+import threading
 import time
 
 import numpy as np
@@ -20,7 +24,8 @@ from distlr_tpu.ps import KVWorker, ServerGroup
 from distlr_tpu.train.ps_trainer import run_ps_local
 
 DIM, ITERATIONS = 24, 12
-XCHG = ("xchg_send", "xchg_await", "xchg_recv")
+NATIVE = ("xchg_send", "xchg_await", "xchg_recv")
+XCHG = ("xchg_enter", *NATIVE, "xchg_wake", "xchg_account")
 PARENTS = {"push", "pull", "wire", "eval", "checkpoint"}
 
 
@@ -66,7 +71,21 @@ def test_a_pull_of_no_keys_reads_no_reply_and_records_nothing():
         assert not set(XCHG) & tracer.phase_names()
 
 
-def test_the_three_partition_the_span_they_lie_in():
+def _one_after_another(k, parent):
+    """The six of one op, in order and abutting within 10 us, inside
+    ``parent``; what they leave of it, in microseconds."""
+    assert sorted(k) == sorted(XCHG)
+    assert parent["ts"] - 1 <= k["xchg_enter"]["ts"]
+    for a, b in zip(XCHG, XCHG[1:]):
+        assert k[a]["dur"] >= 0 and k[b]["dur"] >= 0, (a, b)
+        assert k[a]["ts"] + k[a]["dur"] == pytest.approx(k[b]["ts"], abs=10), (
+            a, b)
+    assert (k["xchg_account"]["ts"] + k["xchg_account"]["dur"]
+            <= parent["ts"] + parent["dur"] + 1)
+    return parent["dur"] - sum(e["dur"] for e in k.values())
+
+
+def test_the_six_partition_the_span_they_lie_in():
     dim = 1 << 20
     with ServerGroup(2, 1, dim, sync=True) as g, \
             KVWorker(g.hosts, dim, client_id=0) as kv:
@@ -99,7 +118,9 @@ def test_the_three_partition_the_span_they_lie_in():
                 <= p["ts"] + p["dur"] + 0.01)
         # 2 MB a server each way is no microsecond
         assert k["xchg_send"]["dur"] > 50 and k["xchg_recv"]["dur"] > 50
-    # the parent's own seconds are what the three leave
+        # the six leave the span its own entry and exit
+        assert _one_after_another(k, p) <= max(0.03 * p["dur"], 30.0)
+    # the parent's own seconds are what the six leave
     assert spans["push"]["self_seconds"] == pytest.approx(
         spans["push"]["seconds"] - sum(spans[n]["seconds"] for n in XCHG),
         abs=1e-5)
@@ -128,9 +149,9 @@ def test_every_loop_variant_records_them_under_its_exchange(
         data_dir, mode, kw, under, whole, ps_steps_on):
     """``under``: the spans of the variant that hold an exchange;
     ``whole``: those of them a round repeats, whose every exchange the
-    three cover but for the call's entry and exit.  One worker, so that
-    no other thread holds the interpreter between a call's return and
-    its span's end."""
+    six cover but for the span's own entry and exit.  One worker, so
+    that no other worker's loop holds the interpreter between a call's
+    return and its span's end."""
     base = dict(data_dir=data_dir, num_feature_dim=DIM, model="binary_lr",
                 num_workers=1, num_servers=2, sync_mode=False,
                 batch_size=-1, num_iteration=ITERATIONS, learning_rate=0.2,
@@ -152,28 +173,69 @@ def test_every_loop_variant_records_them_under_its_exchange(
             kids[parent["args"]["id"]].append(e)
     assert under <= {ids[p]["name"] for p in kids}, mode
     gaps = collections.defaultdict(list)
-    for pid, three in kids.items():
+    for pid, six in kids.items():
         parent = ids[pid]
-        assert sorted(e["name"] for e in three) == sorted(XCHG)
         # one after another inside the span they lie in (microseconds):
         # the native client's clock is the tracer's
-        k = {e["name"]: e for e in three}
-        assert parent["ts"] - 1 <= k["xchg_send"]["ts"]
-        assert (k["xchg_send"]["ts"] + k["xchg_send"]["dur"]
-                <= k["xchg_await"]["ts"] + 1)
-        assert (k["xchg_await"]["ts"] + k["xchg_await"]["dur"]
-                <= k["xchg_recv"]["ts"] + 1)
-        assert (k["xchg_recv"]["ts"] + k["xchg_recv"]["dur"]
-                <= parent["ts"] + parent["dur"] + 1), (mode, parent["name"])
-        covered = sum(e["dur"] for e in three)
-        gaps[parent["name"]].append((parent["dur"] - covered, parent["dur"]))
-    # ... and leave it the call's entry and exit: within 2% of the span
-    # or, at this test's 24 weights, where a whole exchange is 100 us,
-    # what the Python round the call costs (the loop's annotation, the
-    # retry and trace scopes, the op's counters: 50 to 75 us on an idle
-    # host, ISSUE 34's 50 in the client alone; held to 500 because the
-    # suite's other workers take the cores), on the round disturbed least
+        gap = _one_after_another({e["name"]: e for e in six}, parent)
+        gaps[parent["name"]].append((gap, parent["dur"]))
+    # ... and leave it its own entry and exit (the loop's annotation, a
+    # serialized push's ``wait``, the comm thread's ``wire_handoff``):
+    # within 3% of the span or, at this test's 24 weights, where a whole
+    # exchange is 100-300 us, what those cost: 15 to 30 us on an idle
+    # host, held to 100 because the suite's other workers take the cores
+    # (500 while the Python round the native call was in it: the 50 to
+    # 75 us of ISSUE 34 are ``xchg_enter`` and ``xchg_account`` now), on
+    # the round disturbed least
     for name in whole:
         assert len(gaps[name]) >= ITERATIONS, (mode, name)
         gap, span = min(gaps[name])
-        assert gap <= max(0.02 * span, 500.0), (mode, name, gap, span)
+        assert gap <= max(0.03 * span, 100.0), (mode, name, gap, span)
+
+
+def test_a_thread_that_holds_the_interpreter_shows_in_the_wake_alone():
+    """A second thread in a pure-Python loop gives the interpreter up a
+    switch interval after it is asked to, and no sooner.  Where it took
+    the interpreter while a native call ran, the op runs again that much
+    after its reply was there: the interval shows whole in ``xchg_wake``,
+    and in none of the native three, which need no interpreter."""
+    dim, ops, interval = 1 << 18, 40, 0.02
+    longest = {}
+    was = sys.getswitchinterval()
+    with ServerGroup(1, 1, dim, sync=False) as g, \
+            KVWorker(g.hosts, dim, client_id=0) as kv:
+        kv.wait(kv.push_init(np.zeros(dim, np.float32)))
+        grad = np.full(dim, 1e-3, np.float32)
+        tracer = get_tracer()
+        for held in (False, True):
+            stop = threading.Event()
+
+            def spin():
+                n = 0
+                while not stop.is_set():
+                    n += 1
+
+            spinner = threading.Thread(target=spin, daemon=True)
+            if held:
+                sys.setswitchinterval(interval)
+                spinner.start()
+            try:
+                tracer.reset()
+                for step in range(1, ops + 1):
+                    with trace_phase("push", step=step, rank=0):
+                        kv.push_pull(grad)
+                events = tracer.chrome_trace()["traceEvents"]
+            finally:
+                stop.set()
+                sys.setswitchinterval(was)
+                if held:
+                    spinner.join()
+            longest[held] = {n: max(e["dur"] for e in events
+                                    if e["name"] == n) * 1e-6 for n in XCHG}
+            assert sum(e["name"] == "xchg_wake" for e in events) == ops
+    alone, shared = longest[False], longest[True]
+    assert alone["xchg_wake"] < 0.25 * interval
+    assert shared["xchg_wake"] >= 0.75 * interval
+    for name in NATIVE:
+        assert shared[name] < alone[name] + 0.75 * interval, (
+            name, alone, shared)

@@ -138,9 +138,10 @@ def test_every_read_refreshes_the_mirror_and_health_is_one_of_them():
             assert mirrored(stat)["0"] == pytest.approx(got[0][stat])
             assert mirrored(stat)["1"] == pytest.approx(got[1][stat])
         assert mirrored("total_pushes")["0"] == 2
-        # the series of their own and the handlers' CPU follow too
-        own = dict(reg.get("distlr_ps_server_sync_rounds").children())
-        assert own[("0",)].value == own[("1",)].value == 1
+        # the barrier's tail and the handlers' CPU follow too: the tail
+        # under the one series, with no gauge of its own beside it
+        assert mirrored("sync_rounds") == {"0": 1, "1": 1}
+        assert reg.get("distlr_ps_server_sync_rounds") is None
         cpu = {labels: s.value for labels, s in reg.get(
             "distlr_kv_server_cpu_seconds").children()}
         assert cpu[("1", "push")] == pytest.approx(got[1]["cpu_push_seconds"])

@@ -1,6 +1,8 @@
 """The PS worker loop's spans: each with the worker's round count, its
-rank and the span it lies in; ``wire``, on the comm thread, under the
-step that submitted it; four workers' spans kept apart."""
+rank and the span it lies in (``round`` and ``epoch_end`` round the
+named ones, ``data_load`` between them); ``wire``, on the comm thread,
+under the step that submitted it, the hand-overs to and from that thread
+under ``wire`` and ``push``; four workers' spans kept apart."""
 
 import collections
 
@@ -14,9 +16,42 @@ from distlr_tpu.train.ps_trainer import run_ps_local
 
 DIM, WORKERS, ITERATIONS = 24, 4, 3
 ROUND = ("data_load", "w_put", "compute", "grad_d2h", "push")
-#: an exchange's three phases, recorded under whichever span is open when
-#: a keyed op returns (tests/test_ps_exchange_spans.py holds them)
-XCHG = ("xchg_send", "xchg_await", "xchg_recv")
+#: a keyed op's six phases, recorded under whichever span is open when
+#: it returns (tests/test_ps_exchange_spans.py holds them)
+XCHG = ("xchg_enter", "xchg_send", "xchg_await", "xchg_recv", "xchg_wake",
+        "xchg_account")
+#: the tree the loop opens: a span's name -> the names its parent may
+#: have (None: it may stand at the top of its thread).  ``push`` at the
+#: top is rank 0's seed push and a bounded-delay ``fit``'s last drain,
+#: ``staleness_probe`` there the stamp of the weights a ``fit`` opens with
+TREE = {
+    "load_data": {None}, "shard_put": {"load_data"}, "barrier_wait": {None},
+    "data_load": {None}, "round": {None}, "epoch_end": {None},
+    "w_put": {"round"}, "compute": {"round"}, "grad_d2h": {"round"},
+    "h2d": {"round"}, "pull": {None, "round"},
+    "push": {None, "round", "epoch_end"},
+    "staleness_probe": {None, "round", "epoch_end"},
+    "reply_wake": {"push"}, "wire": {None}, "wire_handoff": {"wire"},
+    "eval": {"epoch_end"}, "checkpoint": {"epoch_end"},
+    **{name: {"push", "pull", "wire", "eval_pull", "checkpoint"}
+       for name in XCHG},
+}
+
+
+def _holds_the_tree(events):
+    """Every event's parent is one :data:`TREE` allows it, of its own
+    rank; which names stood under which."""
+    ids = {e["args"]["id"]: e for e in events}
+    seen = collections.defaultdict(set)
+    for e in events:
+        parent = ids.get(e["args"].get("parent"))
+        under = None if parent is None else parent["name"]
+        assert under in TREE[e["name"]], (e["name"], under)
+        if parent is not None:
+            assert parent["args"]["rank"] == e["args"]["rank"]
+            assert parent["tid"] == e["tid"]
+        seen[under].add(e["name"])
+    return seen
 
 
 pytestmark = pytest.mark.usefixtures("ps_steps_on_device")
@@ -72,15 +107,105 @@ def test_every_span_has_its_step_its_rank_and_its_parent(data_dir):
             assert got == list(range(1, ITERATIONS + 1)), (name, rank, got)
     assert [e["args"]["rank"] for e in names["push"]
             if e["args"]["step"] == 0] == [0]
-    # the one nesting the loop opens: the placement inside the load
+    # the tree the loop opens: the placement inside the load; a round's
+    # device chain inside ``round``; an epoch's drain (a whole-shard
+    # epoch's only wait) inside ``epoch_end``, ``data_load`` between the
+    # two; the hand-overs where they were waited for
+    seen = _holds_the_tree(events)
+    assert seen["load_data"] == {"shard_put"}
+    assert {"w_put", "compute", "grad_d2h"} <= seen["round"] <= {
+        "w_put", "compute", "grad_d2h", "staleness_probe"}
+    assert {"push"} <= seen["epoch_end"] <= {"push", "staleness_probe"}
+    assert seen["wire"] == {"wire_handoff", *XCHG}
+    assert seen["push"] == {"reply_wake", *XCHG}      # XCHG: the seed push
+    assert {"round", "epoch_end", "data_load", "wire", "pull"} <= seen[None]
+    # ``round`` and ``epoch_end``: one a round (an epoch is a round
+    # here), with the round's count and the worker's rank
+    for rank in range(WORKERS):
+        for name in ("round", "epoch_end", "wire_handoff", "reply_wake"):
+            got = sorted(e["args"]["step"] for e in per_rank[name, rank])
+            assert got == list(range(1, ITERATIONS + 1)), (name, rank, got)
+
+
+def test_a_round_and_an_epochs_end_cover_the_loop_between_them(data_dir):
+    """On a worker's loop thread ``data_load``, ``round`` and
+    ``epoch_end`` follow one another, a minibatch epoch being three
+    rounds: between the first round's start and the last epoch's end
+    they leave the loop's own ``for`` and the spans' entries and exits."""
+    events = _events(_cfg(data_dir, num_workers=1, batch_size=32))
+    tops = sorted((e for e in events
+                   if e["name"] in ("data_load", "round", "epoch_end")),
+                  key=lambda e: e["ts"])
+    assert [e["name"] for e in tops] == (
+        ["data_load", "round"] * 3 + ["epoch_end"]) * ITERATIONS
+    assert [e["args"]["step"] for e in tops if e["name"] == "epoch_end"] == [
+        3, 6, 9]
+    assert len({e["tid"] for e in tops}) == 1
+    for a, b in zip(tops, tops[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1
+    covered = sum(e["dur"] for e in tops)
+    wall = tops[-1]["ts"] + tops[-1]["dur"] - tops[0]["ts"]
+    # 21 spans in a run of some tens of milliseconds: what they leave is
+    # their own entries and exits (held loosely: the suite's other
+    # workers take the cores)
+    assert wall - covered <= max(0.05 * wall, 21 * 60.0), (wall, covered)
+
+
+def test_the_hand_overs_lie_where_they_were_waited_for(data_dir):
+    """``wire_handoff`` on the comm thread under ``wire``, ending where
+    it starts (it led to it); ``reply_wake`` on the loop's thread at the
+    end of its ``push``, not before the ``wire`` it waited for ended."""
+    events = _events(_cfg(data_dir, batch_size=32))
     ids = {e["args"]["id"]: e for e in events}
-    nested = [e for e in events
-              if "parent" in e["args"] and e["name"] not in XCHG]
-    assert sorted(e["name"] for e in nested) == ["shard_put"] * WORKERS
-    for e in nested:
-        parent = ids[e["args"]["parent"]]
-        assert parent["name"] == "load_data"
-        assert parent["args"]["rank"] == e["args"]["rank"]
+    per_rank = _by(events, lambda e: (e["name"], e["args"]["rank"]))
+    for rank in range(WORKERS):
+        (loop_tid,) = {e["tid"] for e in per_rank["round", rank]}
+        wires = {e["args"]["step"]: e for e in per_rank["wire", rank]}
+        assert len(per_rank["wire_handoff", rank]) == len(wires) == 9
+        for e in per_rank["wire_handoff", rank]:
+            wire = ids[e["args"]["parent"]]
+            assert wire is wires[e["args"]["step"]]
+            assert e["tid"] == wire["tid"] != loop_tid
+            assert e["ts"] + e["dur"] == pytest.approx(wire["ts"], abs=0.01)
+            # submitted inside the round that computed the gradient
+            (rnd,) = [r for r in per_rank["round", rank]
+                      if r["args"]["step"] == e["args"]["step"]]
+            assert rnd["ts"] <= e["ts"] <= rnd["ts"] + rnd["dur"]
+        assert len(per_rank["reply_wake", rank]) == len(
+            per_rank["push", rank]) - (rank == 0)         # the seed push
+        for e in per_rank["reply_wake", rank]:
+            push = ids[e["args"]["parent"]]
+            assert push["name"] == "push" and e["tid"] == push["tid"] == loop_tid
+            wire = wires[push["args"]["step"] - ("drain" not in push["args"])]
+            assert e["ts"] >= max(push["ts"], wire["ts"] + wire["dur"]) - 0.01
+            assert e["ts"] + e["dur"] <= push["ts"] + push["dur"] + 0.01
+
+
+@pytest.mark.parametrize("kw,probed", [
+    (dict(), True),
+    (dict(batch_size=32), True),
+    (dict(sync_mode=True), False),
+    (dict(sync_mode=True, ps_max_delay=1, sync_last_gradient=False), False),
+], ids=["async", "async-minibatch", "bsp", "bsp-delay1"])
+def test_the_staleness_probe_is_a_span_of_asynchronous_runs_alone(
+        data_dir, kw, probed, monkeypatch):
+    from distlr_tpu.train import ps_trainer
+
+    # every stamp a probe: the 50 ms throttle would leave a short run one
+    monkeypatch.setattr(ps_trainer, "_PUSHES_SAMPLE_INTERVAL_S", 0.0)
+    events = _events(_cfg(data_dir, **kw))
+    probes = [e for e in events if e["name"] == "staleness_probe"]
+    assert bool(probes) == probed
+    ids = {e["args"]["id"]: e for e in events}
+    loops = {e["args"]["rank"]: e["tid"] for e in events
+             if e["name"] == "round"}
+    for e in probes:
+        if e["args"]["step"]:     # 0: the weights a fit opens with
+            assert ids[e["args"]["parent"]]["name"] in ("round", "epoch_end")
+        assert e["tid"] == loops[e["args"]["rank"]]
+        assert 0 <= e["args"]["step"] <= ITERATIONS * 3
+    if probed:
+        assert {e["args"]["rank"] for e in probes} == set(range(WORKERS))
 
 
 def test_wire_runs_on_the_comm_thread_under_its_submitters_step(data_dir):
@@ -112,8 +237,11 @@ def test_spans_of_four_threads_do_not_nest_into_each_other(data_dir):
         parent = ids.get(e["args"].get("parent"))
         if parent is not None:
             assert parent["tid"] == e["tid"]
-            assert parent["ts"] <= e["ts"]
-            assert parent["ts"] + parent["dur"] >= e["ts"] + e["dur"]
+            if e["name"] == "wire_handoff":   # it led to its parent
+                assert e["ts"] + e["dur"] <= parent["ts"] + 0.01
+                continue
+            assert parent["ts"] <= e["ts"] + 0.01
+            assert parent["ts"] + parent["dur"] >= e["ts"] + e["dur"] - 0.01
     ranks_of = _by(events, lambda e: e["tid"])
     assert len(ranks_of) == 2 * WORKERS
     for tid, evs in ranks_of.items():
@@ -152,10 +280,10 @@ def test_each_loop_variant_records_the_spans_it_has(data_dir, mode, kw, has,
         assert got == list(range(1, len(got) + 1)) and got
     if mode != "fused-bsp-resident":
         return
-    # every span of the round a step, on one thread a rank, and the one
-    # nesting the loop opens: the placement inside the load
+    # every span of the round a step, on one thread a rank, and the
+    # tree the loop opens: the placement inside the load, the round's
+    # chain and its push inside ``round``, an epoch's end empty
     per_rank = _by(events, lambda e: (e["name"], e["args"]["rank"]))
-    ids = {e["args"]["id"]: e for e in events}
     computed = _by([e for e in events if e["name"] == "compute"],
                    lambda e: e["args"]["step"])
     for rank in range(WORKERS):
@@ -172,13 +300,14 @@ def test_each_loop_variant_records_the_spans_it_has(data_dir, mode, kw, has,
                 ready = max(c["ts"] + c["dur"]
                             for c in computed[e["args"]["step"]])
                 assert e["ts"] + e["dur"] >= ready - 1
-    nested = [e for e in events
-              if "parent" in e["args"] and e["name"] not in XCHG]
-    assert sorted(e["name"] for e in nested) == ["shard_put"] * WORKERS
-    for e in nested:
-        parent = ids[e["args"]["parent"]]
-        assert (parent["name"], parent["args"]["rank"]) == (
-            "load_data", e["args"]["rank"])
+    seen = _holds_the_tree(events)
+    assert seen["load_data"] == {"shard_put"}
+    assert seen["round"] == {"w_put", "compute", "grad_d2h", "push"}
+    assert "epoch_end" in seen[None] and "epoch_end" not in seen
+    for rank in range(WORKERS):
+        for name in ("round", "epoch_end"):
+            got = sorted(e["args"]["step"] for e in per_rank[name, rank])
+            assert got == list(range(1, ITERATIONS + 1)), (name, rank, got)
 
 
 def test_a_profiler_trace_holds_the_workers_spans(data_dir, tmp_path):
@@ -224,6 +353,11 @@ def test_an_evals_phases_lie_in_its_eval_span_with_its_rank_and_round(data_dir):
     names = _by(events, lambda e: e["name"])
     assert [(e["args"]["rank"], e["args"]["step"]) for e in names["eval"]] == [
         (0, 2), (0, 4)]
+    # an eval is an epoch's end's: its span lies in rank 0's ``epoch_end``
+    for e in names["eval"]:
+        end = ids[e["args"]["parent"]]
+        assert (end["name"], end["args"]["rank"], end["args"]["step"]) == (
+            "epoch_end", 0, e["args"]["step"])
     for name in EVAL_PHASES:
         got = names[name]
         assert [(e["args"]["rank"], e["args"]["step"]) for e in got] == [

@@ -96,18 +96,13 @@ def test_a_server_counts_its_rounds_and_an_async_one_reports_zeros(sync):
         # the release's cycles are the push handler's too
         assert 0 < a["cpu_release_seconds"] <= a["cpu_push_seconds"]
     reg = get_registry()
+    mirrored = {labels: series.value for labels, series
+                in reg.get("distlr_ps_server_stat").children()}
     for rank, a in enumerate(after):
-        mirrored = dict(reg.get("distlr_ps_server_run_frames").children())
-        assert mirrored[(str(rank),)].value == a["run_frames"]
-        for stat, series in (
-                ("sync_rounds", "distlr_ps_server_sync_rounds"),
-                ("sync_hold_seconds", "distlr_ps_server_sync_hold_seconds"),
-                ("sync_spread_seconds",
-                 "distlr_ps_server_sync_spread_seconds"),
-                ("cpu_release_seconds",
-                 "distlr_ps_server_sync_release_cpu_seconds")):
-            mirrored = dict(reg.get(series).children())[(str(rank),)].value
-            assert mirrored == pytest.approx(a[stat])
+        assert mirrored[(str(rank), "run_frames")] == a["run_frames"]
+        for stat in ("sync_rounds", "sync_hold_seconds",
+                     "sync_spread_seconds", "cpu_release_seconds"):
+            assert mirrored[(str(rank), stat)] == pytest.approx(a[stat])
     # the release is not a handler of its own: its cycles are the push's
     handlers = {labels[1] for labels, _c in reg.get(
         "distlr_kv_server_cpu_seconds").children()}
@@ -252,6 +247,6 @@ def test_lock_wait_rises_where_four_pushes_arrive_at_once(sync):
         assert waited <= after["sync_hold_seconds"] + 1e-3
     assert health[0]["lock_wait_seconds"] >= after["lock_wait_seconds"]
     mirrored = dict(get_registry().get(
-        "distlr_ps_server_lock_wait_seconds").children())
-    assert mirrored[("0",)].value == pytest.approx(
+        "distlr_ps_server_stat").children())
+    assert mirrored[("0", "lock_wait_seconds")].value == pytest.approx(
         health[0]["lock_wait_seconds"])

@@ -130,3 +130,143 @@ def test_it_is_in_the_histogram_and_outlives_the_event_cap():
     tracer.reset()
     assert tracer.breakdown() == {} and "dropped_events" not in (
         tracer.chrome_trace()["otherData"])
+
+
+# -- a span that led to its parent, and where the open span began ------------
+def test_a_span_that_led_to_its_parent_takes_nothing_from_its_own_seconds(
+        tracer):
+    submitted = time.perf_counter()
+    time.sleep(0.003)
+    with tracer.phase("wire", step=5, rank=1):
+        began = tracer.opened_at()
+        tracer.completed("wire_handoff", submitted, began - submitted,
+                         inside=False)
+        time.sleep(0.002)
+    ev = _events(tracer)
+    assert ev["wire_handoff"]["args"] == {
+        "id": ev["wire_handoff"]["args"]["id"], "step": 5, "rank": 1,
+        "parent": ev["wire"]["args"]["id"]}
+    # it ends where its parent starts, to the microsecond's thousandth
+    assert (ev["wire_handoff"]["ts"] + ev["wire_handoff"]["dur"]
+            == pytest.approx(ev["wire"]["ts"], abs=0.002))
+    assert ev["wire_handoff"]["dur"] >= 3000
+    b = tracer.breakdown()
+    assert b["wire"]["self_seconds"] == b["wire"]["seconds"]
+
+
+def test_opened_at_is_the_innermost_open_spans_start(tracer):
+    assert tracer.opened_at() is None
+    before = time.perf_counter()
+    with tracer.phase("push"):
+        outer = tracer.opened_at()
+        with tracer.phase("inner"):
+            inner = tracer.opened_at()
+        assert tracer.opened_at() == outer
+    assert before <= outer <= inner <= time.perf_counter()
+    assert tracer.opened_at() is None
+    ev = _events(tracer)
+    assert (inner - outer) * 1e6 == pytest.approx(
+        ev["inner"]["ts"] - ev["push"]["ts"], abs=0.002)
+
+
+# -- the event buffer: columns, not a tuple of boxed values an event ---------
+def test_the_buffer_keeps_a_whole_window_in_under_64_mb():
+    """800,000 events, each with parent, step and rank (the most an
+    event holds but for the rare span's further stats), kept in at most
+    64 MB; the next one is counted as dropped and changes nothing."""
+    import tracemalloc
+
+    from distlr_tpu.obs.tracing import MAX_TRACE_EVENTS
+
+    assert MAX_TRACE_EVENTS >= 800_000
+    tracemalloc.start()
+    try:
+        tracer = PhaseTracer(registry=MetricsRegistry())
+        before = tracemalloc.get_traced_memory()[0]
+        t0 = time.perf_counter()
+        with tracer.phase("push", step=12345678, rank=3):
+            for i in range(MAX_TRACE_EVENTS):
+                tracer.completed("xchg_send", t0 + i * 1e-5, 1e-5 + i * 1e-9)
+            held = tracemalloc.get_traced_memory()[0] - before
+            tracer.completed("xchg_recv", t0, 1e-5)      # one too many
+        after = tracemalloc.get_traced_memory()[0] - before
+    finally:
+        tracemalloc.stop()
+    assert held <= 64e6, held
+    assert after <= held + 4096
+    doc = tracer.chrome_trace()
+    assert len(doc["traceEvents"]) == MAX_TRACE_EVENTS
+    # the one over and the parent, which ended after it
+    assert doc["otherData"]["dropped_events"] == 2
+    last = doc["traceEvents"][-1]
+    assert last["name"] == "xchg_send" and last["args"]["step"] == 12345678
+    b = tracer.breakdown()
+    assert (b["xchg_send"]["count"], b["xchg_recv"]["count"],
+            b["push"]["count"]) == (MAX_TRACE_EVENTS, 1, 1)
+    tracer.reset()
+    assert tracer.chrome_trace()["traceEvents"] == []
+
+
+def test_the_dump_is_what_the_tuple_form_gave(monkeypatch):
+    """A recorded fixture: twelve spans (parents, steps and ranks there
+    and missing, further stats, the largest thread id) and the
+    ``chrome_trace()`` and ``breakdown()`` the tracer gave for them while
+    its buffer was a list of tuples (PR 48's ``obs/tracing.py``)."""
+    import json
+    import os
+
+    from distlr_tpu.obs import tracing
+
+    path = os.path.join(os.path.dirname(__file__), "fixtures",
+                        "phase_trace_tuple_form.json")
+    with open(path) as f:
+        fixture = json.load(f)
+    tracer = PhaseTracer(registry=MetricsRegistry())
+    tracer._epoch = 0.0
+    monkeypatch.setattr(os, "getpid", lambda: fixture["pid"])
+    for name, tid, t0, dur, own, sid, parent, step, rank, stats in (
+            fixture["spans"]):
+        monkeypatch.setattr(tracing.threading, "get_ident", lambda tid=tid: tid)
+        tracer._keep(name, t0, dur, own, sid, parent, step, rank, stats)
+    monkeypatch.undo()
+    monkeypatch.setattr(os, "getpid", lambda: fixture["pid"])
+    assert tracer.chrome_trace() == fixture["chrome_trace"]
+    assert tracer.breakdown() == fixture["breakdown"]
+    # and through JSON, as a dump is read
+    assert json.loads(json.dumps(tracer.chrome_trace())) == (
+        fixture["chrome_trace"])
+
+
+def test_four_threads_appending_at_once_lose_none(tracer):
+    per, start = 20_000, threading.Barrier(4)
+
+    def work(rank):
+        start.wait(5)
+        with tracer.phase("round", step=rank, rank=rank, worker=rank):
+            for i in range(per):
+                tracer.completed(f"span{rank}", float(i), 1e-6)
+
+    threads = [threading.Thread(target=work, args=(r,)) for r in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    events = tracer.chrome_trace()["traceEvents"]
+    assert len(events) == 4 * (per + 1)
+    assert "dropped_events" not in tracer.chrome_trace()["otherData"]
+    rounds = {e["args"]["rank"]: e for e in events if e["name"] == "round"}
+    assert {r: e["args"]["worker"] for r, e in rounds.items()} == {
+        0: 0, 1: 1, 2: 2, 3: 3}
+    by_rank = {r: [e for e in events if e["name"] == f"span{r}"]
+               for r in range(4)}
+    for rank, mine in by_rank.items():
+        # every column of an event is its own event's: none torn
+        assert len(mine) == per
+        assert {e["tid"] for e in mine} == {rounds[rank]["tid"]}
+        assert {e["args"]["parent"] for e in mine} == {
+            rounds[rank]["args"]["id"]}
+        assert all(e["args"]["step"] == e["args"]["rank"] == rank
+                   for e in mine)
+        starts = [e["ts"] for e in mine]
+        assert starts == sorted(starts)
+    assert len({e["args"]["id"] for e in events}) == len(events)
