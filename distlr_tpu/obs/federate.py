@@ -358,20 +358,19 @@ def merge_snapshots(snaps: dict[tuple[str, int], dict], *,
             elif kind == "histogram":
                 out = reg.histogram(name, help_, labelnames, buckets=bounds)
                 for s in series:
-                    b, counts, total = _hist_parts(s)
+                    b, counts, _total = _hist_parts(s)
                     if b != bounds:
                         _conflict(rank_key, name,
                                   f"bucket boundaries {b} vs {bounds}")
                         continue
                     child = out.labels(**s["labels"])
-                    # merge bucket-wise into the child's internal counts
-                    # (same package; a public "add counts" API would only
-                    # exist for this one caller)
-                    with child._lock:
-                        for i, c in enumerate(counts):
-                            child._counts[i] += c
-                        child._sum += float(s["sum"])
-                        child._count += total
+                    # merge bucket-wise: the rank's counts as a share of
+                    # the child with no writer, the child's own at its
+                    # next read
+                    cell = child.cell()
+                    cell.counts[:] = counts
+                    cell.sum = float(s["sum"])
+                    cell.retired = True
             else:  # gauge (and any future untyped): per-rank identity
                 renamed = tuple(
                     f"exported_{n}" if n in ("role", "rank") else n

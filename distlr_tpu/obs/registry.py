@@ -8,7 +8,12 @@ shared sink every layer writes to: the PS server supervisor, the native
 client wrapper, both trainer loops, the microbatcher, and the serving
 front-end all run threads that record concurrently, so every update is
 lock-protected (exact counts under contention are a test contract,
-``tests/test_obs.py``).
+``tests/test_obs.py``), or goes to a *cell*: one writer's own share of a
+child (``child.cell()``), updated with no lock by the one thread that
+owns it and counted in by every read of the child, so that W threads
+recording the same series at the same instant hand nothing to each other
+(the keyed ops of ``ps/client.py``, a thread's spans in
+``obs/tracing.py``).
 
 Model mirrors the Prometheus client library:
 
@@ -100,12 +105,65 @@ def _label_str(names, values) -> str:
     return "{" + inner + "}"
 
 
-class _CounterChild:
-    __slots__ = ("_lock", "_value")
+class _CounterCell:
+    """One writer's share of a counter child (:meth:`_CounterChild.cell`).
+    ``inc`` takes no lock: the cell has ONE writer at a time (a
+    ``KVWorker``'s thread), and a reader gets the value before an update
+    or after it.  Amounts are the writer's to keep non-negative.
+    ``retired = True``, set by whoever knows the writer is gone, lets the
+    child take the count for its own (:class:`_CellOwner`)."""
+
+    __slots__ = ("value", "retired")
+
+    def __init__(self):
+        self.value = 0.0
+        self.retired = False
+
+    def inc(self, amount: float = 1.0) -> None:
+        self.value += amount
+
+
+class _CellOwner:
+    """What a child with cells shares: ``cell()`` hands one writer a
+    share of the series and every read counts the cells in.  A cell whose
+    writer is gone is marked ``retired``, with no lock: a finalizer marks
+    it, and one may run wherever the collector does, inside this child's
+    own lock too.  The next ``cell()`` or read moves a retired cell's
+    count into the child's own and lets it go, so the list does not grow
+    with the writers there have been.  ``_cells`` and ``_lock`` are the
+    child's slots."""
+
+    __slots__ = ()
+
+    def cell(self):
+        cell = self._new_cell()
+        with self._lock:
+            self._live_locked().append(cell)
+        return cell
+
+    def _live_locked(self) -> list:
+        """The cells still written to, the others' counts taken: under
+        the child's lock.  One pass, so that a cell marked while it runs
+        is either taken or kept."""
+        live = []
+        for cell in self._cells:
+            if cell.retired:
+                self._take_locked(cell)
+            else:
+                live.append(cell)
+        self._cells = live
+        return live
+
+
+class _CounterChild(_CellOwner):
+    __slots__ = ("_lock", "_value", "_cells")
+
+    _new_cell = _CounterCell
 
     def __init__(self):
         self._lock = threading.Lock()
         self._value = 0.0
+        self._cells: list[_CounterCell] = []
 
     def inc(self, amount: float = 1.0) -> None:
         if amount < 0:
@@ -113,22 +171,33 @@ class _CounterChild:
         with self._lock:
             self._value += amount
 
+    def _take_locked(self, cell: _CounterCell) -> None:
+        self._value += cell.value
+
     @property
     def value(self) -> float:
         with self._lock:
-            return self._value
+            cells = self._live_locked()
+            return self._value + sum([c.value for c in cells])
 
 
 class _GaugeChild:
-    __slots__ = ("_lock", "_value")
+    __slots__ = ("_lock", "_value", "_function")
 
     def __init__(self):
         self._lock = threading.Lock()
         self._value = 0.0
+        self._function = None
 
     def set(self, value: float) -> None:
         with self._lock:
             self._value = float(value)
+
+    def set_function(self, read) -> None:
+        """Derive the gauge when it is read: ``read()`` is its value at
+        every scrape, snapshot and ``value`` from now on (a ratio of two
+        counters costs their writers nothing)."""
+        self._function = read
 
     def inc(self, amount: float = 1.0) -> None:
         with self._lock:
@@ -139,42 +208,80 @@ class _GaugeChild:
 
     @property
     def value(self) -> float:
+        read = self._function
+        if read is not None:
+            return float(read())
         with self._lock:
             return self._value
 
 
-class _HistogramChild:
-    __slots__ = ("_lock", "_buckets", "_counts", "_sum", "_count")
+class _HistogramCell:
+    """One writer's share of a histogram child
+    (:meth:`_HistogramChild.cell`): as :class:`_CounterCell`.  An
+    observation is two updates, so a read that falls between them has its
+    bucket and not yet its seconds; the count is the buckets' sum and so
+    never apart from them."""
+
+    __slots__ = ("_buckets", "counts", "sum", "retired")
+
+    def __init__(self, buckets: tuple[float, ...]):
+        self._buckets = buckets
+        self.counts = [0] * (len(buckets) + 1)
+        self.sum = 0.0
+        self.retired = False
+
+    def observe(self, value: float) -> None:
+        self.counts[bisect.bisect_left(self._buckets, value)] += 1
+        self.sum += value
+
+
+class _HistogramChild(_CellOwner):
+    __slots__ = ("_lock", "_buckets", "_counts", "_sum", "_cells")
 
     def __init__(self, buckets: tuple[float, ...]):
         self._lock = threading.Lock()
         self._buckets = buckets
         self._counts = [0] * (len(buckets) + 1)  # last slot = +Inf
         self._sum = 0.0
-        self._count = 0
+        self._cells: list[_HistogramCell] = []
+
+    def _new_cell(self) -> _HistogramCell:
+        return _HistogramCell(self._buckets)
 
     def observe(self, value: float) -> None:
         i = bisect.bisect_left(self._buckets, value)  # bucket is "le" bound
         with self._lock:
             self._counts[i] += 1
             self._sum += value
-            self._count += 1
+
+    def _take_locked(self, cell: _HistogramCell) -> None:
+        for i, c in enumerate(cell.counts):
+            self._counts[i] += c
+        self._sum += cell.sum
+
+    def _read(self) -> tuple[list[int], float]:
+        """Per-bucket counts and seconds, the live cells counted in."""
+        with self._lock:
+            cells = self._live_locked()
+            counts, s = list(self._counts), self._sum
+            for cell in cells:
+                for i, c in enumerate(list(cell.counts)):
+                    counts[i] += c
+                s += cell.sum
+        return counts, s
 
     @property
     def sum(self) -> float:
-        with self._lock:
-            return self._sum
+        return self._read()[1]
 
     @property
     def count(self) -> int:
-        with self._lock:
-            return self._count
+        return sum(self._read()[0])
 
     def snapshot(self) -> dict:
         """Cumulative Prometheus-style view: ``{le: count}`` + sum/count."""
-        with self._lock:
-            counts = list(self._counts)
-            total, s = self._count, self._sum
+        counts, s = self._read()
+        total = sum(counts)
         cum, out = 0, {}
         for b, c in zip(self._buckets, counts):
             cum += c
@@ -184,9 +291,7 @@ class _HistogramChild:
     def percentile(self, q: float) -> float:
         """Estimate the q-quantile via :func:`percentile_from_counts`
         over this child's live bucket counts."""
-        with self._lock:
-            counts = list(self._counts)
-        return percentile_from_counts(self._buckets, counts, q)
+        return percentile_from_counts(self._buckets, self._read()[0], q)
 
 
 class _Family:
@@ -275,6 +380,9 @@ class Gauge(_Family):
 
     def dec(self, amount: float = 1.0) -> None:
         self._default().dec(amount)
+
+    def set_function(self, read) -> None:
+        self._default().set_function(read)
 
     @property
     def value(self) -> float:

@@ -98,6 +98,18 @@ class _Events:
             in enumerate(zip(*self.columns))]
 
 
+class _ThreadCells(dict):
+    """One thread's shares of the phase histogram, by phase name
+    (``registry`` cells: a span's seconds are observed with no lock, by
+    the one thread that times it).  It lives in the tracer's
+    ``threading.local`` and goes with its thread, leaving each share to
+    the series it is of."""
+
+    def __del__(self):
+        for cell in self.values():
+            cell.retired = True
+
+
 class _Span:
     """One ``with`` block's span (:meth:`PhaseTracer.phase`).  A class
     and not a generator's context manager: entering and leaving is most
@@ -150,6 +162,7 @@ class PhaseTracer:
         self._ids = itertools.count(1)
         # per thread: the open spans, innermost last, each
         # [span id, seconds its finished children took, step, rank, start]
+        # (``stack``), and the thread's shares of the histogram (``cells``)
         self._open = threading.local()
         self._hist = self._registry.histogram(
             "distlr_phase_seconds",
@@ -161,9 +174,6 @@ class PhaseTracer:
             "xchg_await, xchg_recv, xchg_wake, xchg_account)",
             labelnames=("phase",),
         )
-        # a phase's series, looked up once: a span is a few microseconds
-        # of the loop it times, on several threads at once
-        self._series: dict[str, object] = {}
 
     def phase(self, name: str, step: int | None = None,
               rank: int | None = None, **stats) -> "_Span":
@@ -197,47 +207,96 @@ class PhaseTracer:
         self._keep(name, start, duration, duration, next(self._ids), parent,
                    step, rank)
 
+    def completed_run(self, names, starts, end: float | None = None) -> None:
+        """Record consecutive spans that have already ended, as
+        :meth:`completed` once each in order would (the same parent, ids
+        one after another, each duration counted among the parent's
+        children's), taking the tracer's lock once for all of them:
+        ``names[i]`` from ``starts[i]`` to ``starts[i + 1]``, the last to
+        ``end``.  ``end=None`` is now, read when the others are recorded,
+        so a last span that holds its recorder's own bookkeeping
+        (``xchg_account``) holds the wait for the lock and the others'
+        recording too, and leaves out only its own."""
+        stack = getattr(self._open, "stack", None)
+        top = stack[-1] if stack else None
+        parent, step, rank = (top[0], top[2], top[3]) if top else (
+            None, None, None)
+        cells = [self._cell(name) for name in names]
+        ids = [next(self._ids) for _ in names]
+        tid = threading.get_ident()
+        record, last = self._record_locked, len(names) - 1
+        with self._lock:
+            for i, (name, cell, span_id) in enumerate(zip(names, cells, ids)):
+                t0 = starts[i]
+                if i < last:
+                    t1 = starts[i + 1]
+                else:
+                    t1 = time.perf_counter() if end is None else end
+                dur = t1 - t0
+                cell.observe(dur)
+                if top:
+                    top[1] += dur
+                record(name, tid, t0, dur, dur, span_id, parent, step, rank,
+                       None)
+
     def opened_at(self) -> float | None:
         """When the innermost span open on the calling thread began, on
         ``time.perf_counter``'s clock; None with none open."""
         stack = getattr(self._open, "stack", None)
         return stack[-1][4] if stack else None
 
+    def _cell(self, name):
+        """The calling thread's share of ``distlr_phase_seconds{phase}``
+        for ``name``, bound at the thread's first span of that name."""
+        local = self._open
+        try:
+            cells = local.cells
+        except AttributeError:
+            cells = local.cells = _ThreadCells()
+        cell = cells.get(name)
+        if cell is None:
+            cell = cells[name] = self._hist.labels(phase=name).cell()
+        return cell
+
     def _keep(self, name, t0, dur, own, span_id, parent, step, rank,
               stats=None) -> None:
-        series = self._series.get(name)
-        if series is None:
-            series = self._series[name] = self._hist.labels(phase=name)
-        series.observe(dur)
+        self._cell(name).observe(dur)
         tid = threading.get_ident()
         with self._lock:
-            tot = self._totals.get(name)
-            if tot is None:
-                self._totals[name] = [dur, 1, own]
-            else:
-                tot[0] += dur
-                tot[1] += 1
-                tot[2] += own
-            events = self._events
-            kept = len(events)
-            if kept < self._max_events:
-                index = events.names.get(name)
-                if index is None:
-                    index = events.names[name] = len(events.names)
-                if stats:
-                    events.stats[kept] = stats
-                a_name, a_tid, a_t0, a_dur, a_id, a_parent, a_step, a_rank = (
-                    events.appends)
-                a_name(index)
-                a_tid(tid)
-                a_t0(t0 - self._epoch)
-                a_dur(dur)
-                a_id(span_id)
-                a_parent(_NO_ID if parent is None else parent)
-                a_step(_NO_STEP if step is None else step)
-                a_rank(_NO_RANK if rank is None else rank)
-            else:
-                self._dropped += 1
+            self._record_locked(name, tid, t0, dur, own, span_id, parent,
+                                step, rank, stats)
+
+    def _record_locked(self, name, tid, t0, dur, own, span_id, parent, step,
+                       rank, stats) -> None:
+        """One span into the breakdown and the event buffer, under the
+        tracer's lock."""
+        tot = self._totals.get(name)
+        if tot is None:
+            self._totals[name] = [dur, 1, own]
+        else:
+            tot[0] += dur
+            tot[1] += 1
+            tot[2] += own
+        events = self._events
+        kept = len(events)
+        if kept < self._max_events:
+            index = events.names.get(name)
+            if index is None:
+                index = events.names[name] = len(events.names)
+            if stats:
+                events.stats[kept] = stats
+            a_name, a_tid, a_t0, a_dur, a_id, a_parent, a_step, a_rank = (
+                events.appends)
+            a_name(index)
+            a_tid(tid)
+            a_t0(t0 - self._epoch)
+            a_dur(dur)
+            a_id(span_id)
+            a_parent(_NO_ID if parent is None else parent)
+            a_step(_NO_STEP if step is None else step)
+            a_rank(_NO_RANK if rank is None else rank)
+        else:
+            self._dropped += 1
 
     def breakdown(self) -> dict[str, dict]:
         """``{phase: {"seconds", "count", "self_seconds"}}`` accumulated

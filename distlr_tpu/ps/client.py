@@ -13,6 +13,7 @@ import ctypes
 import dataclasses
 import random
 import time
+import weakref
 
 import numpy as np
 
@@ -61,9 +62,6 @@ _PAYLOAD_FRAMES = _reg.counter(
     "in between, an older server, coded, opt-state and small frames)",
     labelnames=("op", "carrier"),
 )
-#: an op's two children of it (mapped, inline), looked up once: the
-#: count is taken as every keyed op returns
-_PAYLOAD_CHILDREN: dict[str, tuple] = {}
 _CHUNKED_PULLS = _reg.counter(
     "distlr_ps_client_chunked_pulls_total",
     "pull_chunked calls (serving-tier bounded reads)",
@@ -129,45 +127,100 @@ _COMPRESS_RATIO = _reg.gauge(
     "cumulative push-byte compression ratio raw/wire (1.0-ish = dense "
     "f32; the codec x accumulation win reads directly off this gauge)",
 )
-def _account_push_bytes(raw: int, wire: int) -> None:
-    _PUSH_RAW.inc(raw)
-    _PUSH_WIRE.inc(wire)
-    # ratio derived from the counters themselves — no shadow totals to
-    # drift if the registry is ever reset or the counters relabeled
+
+
+def _compress_ratio() -> float:
+    # derived from the counters themselves, when the gauge is read — no
+    # shadow totals to drift if the registry is ever reset or the counters
+    # relabeled, and nothing for a push to refresh
     wire_total = family_total("distlr_ps_push_bytes_wire_total")
     if wire_total > 0:
-        _COMPRESS_RATIO.set(
-            family_total("distlr_ps_push_bytes_raw_total") / wire_total)
+        return family_total("distlr_ps_push_bytes_raw_total") / wire_total
+    return 0.0
+
+
+_COMPRESS_RATIO.set_function(_compress_ratio)
+
+
+def _op_failed(op: str, exc: BaseException) -> None:
+    """An op that raised, by outcome.  Timeouts are distinguished from
+    hard failures (a wedged barrier vs a dead peer read very differently
+    on a dashboard)."""
+    status = "timeout" if isinstance(exc, PSTimeoutError) else "error"
+    _OPS_TOTAL.labels(op=op, status=status).inc()
 
 
 @contextlib.contextmanager
-def _observe_op(op: str, *, sent=0, received: int = 0,
-                dense: str | None = None):
-    """Record one op's latency, outcome, and payload bytes.  Timeouts are
-    distinguished from hard failures (a wedged barrier vs a dead peer
-    read very differently on a dashboard).  ``sent`` may be a callable
-    evaluated on success — for ops whose wire size is only known after
-    the native call (compressed pushes).  ``dense`` is the encoding a
-    default-key op resolved to (``"rows"``/``"flat"``; None for an op
-    that passed its own keys)."""
+def _observe_op(op: str, *, sent: int = 0, received: int = 0):
+    """Record one op's latency, outcome, and payload bytes: the ops that
+    are no keyed exchange (a barrier vote, the opt-state pair).  A keyed
+    op's are :meth:`KVWorker._keyed`'s."""
     t0 = time.perf_counter()
     try:
         yield
-    except PSTimeoutError:
-        _OPS_TOTAL.labels(op=op, status="timeout").inc()
-        raise
-    except Exception:
-        _OPS_TOTAL.labels(op=op, status="error").inc()
+    except Exception as e:
+        _op_failed(op, e)
         raise
     _OP_SECONDS.labels(op=op).observe(time.perf_counter() - t0)
     _OPS_TOTAL.labels(op=op, status="ok").inc()
-    if dense is not None:
-        _DENSE_FRAMES.labels(op=op, encoding=dense).inc()
-    sent = sent() if callable(sent) else sent
     if sent:
         _BYTES_TOTAL.labels(op=op, direction="sent").inc(sent)
     if received:
         _BYTES_TOTAL.labels(op=op, direction="received").inc(received)
+
+
+class _OpAccount:
+    """One handle's shares of one keyed op's series: registry cells
+    (``obs/registry.py``), bound at the handle's first use of each and
+    updated with no lock as the op returns.  A ``KVWorker`` is used by
+    one thread at a time, so a share has one writer; W lock-step workers
+    whose pushes are answered at the same instant each count into their
+    own, and a read of the series (a scrape, ``family_total``, ``value``)
+    counts every live share in.  A failed op counts nothing here
+    (:func:`_op_failed`)."""
+
+    #: share -> (family, its labels beside ``op``; None: no labels)
+    SHARES = {
+        "seconds": (_OP_SECONDS, {}),
+        "ok": (_OPS_TOTAL, {"status": "ok"}),
+        "rows": (_DENSE_FRAMES, {"encoding": "rows"}),
+        "flat": (_DENSE_FRAMES, {"encoding": "flat"}),
+        "sent": (_BYTES_TOTAL, {"direction": "sent"}),
+        "received": (_BYTES_TOTAL, {"direction": "received"}),
+        "mapped": (_PAYLOAD_FRAMES, {"carrier": "mapped"}),
+        "inline": (_PAYLOAD_FRAMES, {"carrier": "inline"}),
+        "raw": (_PUSH_RAW, None),
+        "wire": (_PUSH_WIRE, None),
+    }
+
+    def __init__(self, op: str):
+        self.op = op
+        self.bound: list = []
+
+    def __getattr__(self, share: str):
+        # a share's first use (later ones find the attribute)
+        try:
+            family, labels = self.SHARES[share]
+        except KeyError:
+            raise AttributeError(share) from None
+        series = (family._default() if labels is None
+                  else family.labels(op=self.op, **labels))
+        cell = series.cell()
+        self.bound.append(cell)
+        setattr(self, share, cell)
+        return cell
+
+
+def _retire_accounts(accounts: dict) -> None:
+    """A handle that is gone: its shares are the series' own counts."""
+    for account in accounts.values():
+        for cell in account.bound:
+            cell.retired = True
+
+
+#: a keyed op's six phases (:meth:`KVWorker._record_op`)
+_XCHG = ("xchg_enter", "xchg_send", "xchg_await", "xchg_recv", "xchg_wake",
+         "xchg_account")
 
 #: Order of the counters a server stats probe returns (kv_protocol.h).
 #: The ``cpu_*`` tail is the continuous-profiling extension: cumulative
@@ -525,16 +578,18 @@ def _load():
         ]
         lib.kv_clock_offset.restype = ctypes.c_double
         lib.kv_clock_offset.argtypes = [ctypes.c_void_p, ctypes.c_uint32]
-        lib.kv_last_wire_sent.restype = ctypes.c_uint64
-        lib.kv_last_wire_sent.argtypes = [ctypes.c_void_p]
-        # a read of four doubles, called as a keyed op returns: through
+        # what a keyed op reads as it returns: a few words each, through
         # a handle that keeps the GIL (PyDLL), because releasing it for
         # nanoseconds, where W lock-step workers return at once, hands
         # it to a peer and stands in line for it again
-        lib.kv_last_exchange = ctypes.PyDLL(client_lib()).kv_last_exchange
+        keeps_gil = ctypes.PyDLL(client_lib())
+        lib.kv_last_wire_sent = keeps_gil.kv_last_wire_sent
+        lib.kv_last_wire_sent.restype = ctypes.c_uint64
+        lib.kv_last_wire_sent.argtypes = [ctypes.c_void_p]
+        lib.kv_last_exchange = keeps_gil.kv_last_exchange
         lib.kv_last_exchange.restype = None
         lib.kv_last_exchange.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
-        lib.kv_last_carried = ctypes.PyDLL(client_lib()).kv_last_carried
+        lib.kv_last_carried = keeps_gil.kv_last_carried
         lib.kv_last_carried.restype = None
         lib.kv_last_carried.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
         lib.kv_negotiate_epoch.restype = ctypes.c_int
@@ -665,6 +720,9 @@ class KVWorker:
         # the instants of the attempt that answered the op now returning,
         # and when its native call was back in Python (_record_op)
         self._answered: tuple | None = None
+        # this handle's shares of each keyed op's series, by op
+        self._accounts: dict[str, _OpAccount] = {}
+        weakref.finalize(self, _retire_accounts, self._accounts)
         #: connections of the current handle whose values cross in a
         #: shared mapping (kv_protocol.h "values in a mapping"):
         #: re-derived at every (re)connect from what the servers
@@ -1104,30 +1162,66 @@ class KVWorker:
             raise OSError("failed to set KV socket timeout")
         self._timeout_ms = int(timeout_ms)
 
-    def _record_exchange(self, op: str, back: float) -> None:
-        """The keyed op whose native call has just been answered; the
-        call returned at ``back``, the first instant Python read after
-        it.  Its value-carrying frames go by carrier
-        (``kv_last_carried``) under
-        ``distlr_ps_payload_frames_total{op, carrier}``, and its instants
-        (``kv_last_exchange``, on ``time.perf_counter``'s clock) are
-        kept with ``back`` for :meth:`_record_op`, which the op calls as
-        it returns.  Nothing is kept where no reply was read (a pull of
-        no keys: an instant not reached is 0)."""
-        self._lib.kv_last_carried(self._h, self._carried)
-        children = _PAYLOAD_CHILDREN.get(op)
-        if children is None:
-            children = _PAYLOAD_CHILDREN[op] = tuple(
-                _PAYLOAD_FRAMES.labels(op=op, carrier=c)
-                for c in ("mapped", "inline"))
-        for child, frames in zip(children, self._carried):
-            if frames:
-                child.inc(frames)
-        t = self._xchg
-        self._lib.kv_last_exchange(self._h, t)
-        t0, t1, t2, t3 = t
+    def _keyed(self, op: str, native, args: tuple, dense: str | None, *,
+               sent: int = 0, received: int = 0,
+               raw: int | None = None) -> int:
+        """One attempt of a keyed op: ``native(handle, *args)``, and, where
+        it is answered, the op's accounts in ONE pass that hands nothing
+        over: no call that releases the interpreter (the three getters
+        keep it: :func:`_load`), no lock (the counts go to this handle's
+        own shares, :class:`_OpAccount`), no walk of a family.  W
+        lock-step workers are answered at the same instant, and whatever
+        one of them gives away here the others' returns stand behind.
+
+        Latency and outcome (``distlr_ps_client_op_seconds``,
+        ``_ops_total``; a failed attempt: :func:`_op_failed`, and nothing
+        else); ``dense``, the encoding a default-key op resolved to
+        (``"rows"``/``"flat"``; None for an op that passed its own keys);
+        payload bytes ``sent`` and ``received``
+        (``distlr_ps_client_bytes_total``); the value-carrying frames by
+        carrier (``kv_last_carried``,
+        ``distlr_ps_payload_frames_total{op, carrier}``).  ``raw``: the op
+        is a gradient push of that many dense-f32-equivalent bytes; what
+        it sent is what left the kernel (``kv_last_wire_sent``, only
+        known after the call: a coded push), and both go to the push-byte
+        accounts.  The attempt's instants (``kv_last_exchange``, on
+        ``time.perf_counter``'s clock) are kept, with the first instant
+        Python read after the call, for :meth:`_record_op`, which the op
+        calls as it returns; nothing is kept where no reply was read (a
+        pull of no keys: an instant not reached is 0)."""
+        account = self._accounts.get(op)
+        if account is None:
+            account = self._accounts[op] = _OpAccount(op)
+        began = time.perf_counter()
+        try:
+            ts = native(self._h, *args)
+            back = time.perf_counter()
+            self._check(ts, op)
+        except Exception as e:
+            _op_failed(op, e)
+            raise
+        lib, h = self._lib, self._h
+        lib.kv_last_carried(h, self._carried)
+        lib.kv_last_exchange(h, self._xchg)
+        mapped, inline = self._carried
+        account.mapped.inc(mapped)
+        account.inline.inc(inline)
+        t0, t1, t2, t3 = self._xchg
         self._answered = ((t0, t1, t2, t3, back)
                           if 0.0 < t0 <= t1 <= t2 <= t3 <= back else None)
+        if raw is not None:
+            sent = lib.kv_last_wire_sent(h)
+            account.raw.inc(raw)
+            account.wire.inc(sent)
+        if dense is not None:
+            (account.rows if dense == "rows" else account.flat).inc()
+        if sent:
+            account.sent.inc(sent)
+        if received:
+            account.received.inc(received)
+        account.ok.inc()
+        account.seconds.observe(time.perf_counter() - began)
+        return ts
 
     def _record_op(self, entered: float) -> None:
         """The keyed op that is about to return, entered at ``entered``
@@ -1149,7 +1243,8 @@ class KVWorker:
           native call: the call's exit and the wait for the interpreter,
           which another thread may hold;
         * ``xchg_account``: to here: the reply checked, the op's
-          counters and byte accounts, the scopes' exits, these spans.
+          counters and byte accounts (:meth:`_keyed`), the scopes'
+          exits, the tracer's lock, taken once for the six.
 
         The native client noted the middle four instants.  On a retried
         op they are those of the attempt that was answered, and
@@ -1159,14 +1254,7 @@ class KVWorker:
         answered, self._answered = self._answered, None
         if answered is None:
             return
-        t0, t1, t2, t3, back = answered
-        completed = get_tracer().completed
-        completed("xchg_enter", entered, t0 - entered)
-        completed("xchg_send", t0, t1 - t0)
-        completed("xchg_await", t1, t2 - t1)
-        completed("xchg_recv", t2, t3 - t2)
-        completed("xchg_wake", t3, back - t3)
-        completed("xchg_account", back, time.perf_counter() - back)
+        get_tracer().completed_run(_XCHG, (entered, *answered))
 
     def _check(self, ts: int, what: str) -> int:
         if ts < 0:
@@ -1316,21 +1404,11 @@ class KVWorker:
 
         def _issue():
             keys, vpk, dense = self._frame_now(frame)
-            with _observe_op(
-                    "push", sent=lambda: self._lib.kv_last_wire_sent(self._h),
-                    dense=dense):
-                ts = self._lib.kv_push_vpk(
-                    self._h,
-                    keys.ctypes.data_as(ctypes.c_void_p),
-                    vals.ctypes.data_as(ctypes.c_void_p),
-                    keys.shape[0], vpk,
-                )
-                back = time.perf_counter()
-                self._check(ts, "push")
-                self._record_exchange("push", back)
-                _account_push_bytes(keys.nbytes + vals.nbytes,
-                                    self._lib.kv_last_wire_sent(self._h))
-                return ts
+            return self._keyed(
+                "push", self._lib.kv_push_vpk,
+                (keys.ctypes.data_as(ctypes.c_void_p),
+                 vals.ctypes.data_as(ctypes.c_void_p), keys.shape[0], vpk),
+                dense, raw=keys.nbytes + vals.nbytes)
 
         with self._trace_op("push"):
             ts = self._push_with_retry("push", _issue)
@@ -1350,19 +1428,12 @@ class KVWorker:
 
         def _issue():
             keys, vpk, dense = self._frame_now(frame)
-            with _observe_op("push_init", sent=keys.nbytes + vals.nbytes,
-                             dense=dense):
-                ts = self._lib.kv_push_init_vpk(
-                    self._h,
-                    keys.ctypes.data_as(ctypes.c_void_p),
-                    vals.ctypes.data_as(ctypes.c_void_p),
-                    keys.shape[0],
-                    1 if force else 0, vpk,
-                )
-                back = time.perf_counter()
-                self._check(ts, "push_init")
-                self._record_exchange("push_init", back)
-                return ts
+            return self._keyed(
+                "push_init", self._lib.kv_push_init_vpk,
+                (keys.ctypes.data_as(ctypes.c_void_p),
+                 vals.ctypes.data_as(ctypes.c_void_p), keys.shape[0],
+                 1 if force else 0, vpk),
+                dense, sent=keys.nbytes + vals.nbytes)
 
         # idempotent by protocol design (kInitPush no-ops once seeded;
         # kForceInit re-sends the same vals) -> plain retry is safe
@@ -1387,22 +1458,12 @@ class KVWorker:
 
         def _issue():
             keys, vpk, dense = self._frame_now(frame)
-            with _observe_op(
-                    "push_pull",
-                    sent=lambda: self._lib.kv_last_wire_sent(self._h),
-                    received=out.nbytes, dense=dense):
-                ts = self._lib.kv_push_pull_vpk(
-                    self._h,
-                    keys.ctypes.data_as(ctypes.c_void_p),
-                    vals.ctypes.data_as(ctypes.c_void_p),
-                    out.ctypes.data_as(ctypes.c_void_p),
-                    keys.shape[0], vpk,
-                )
-                back = time.perf_counter()
-                self._check(ts, "push_pull")
-                self._record_exchange("push_pull", back)
-                _account_push_bytes(keys.nbytes + vals.nbytes,
-                                    self._lib.kv_last_wire_sent(self._h))
+            self._keyed(
+                "push_pull", self._lib.kv_push_pull_vpk,
+                (keys.ctypes.data_as(ctypes.c_void_p),
+                 vals.ctypes.data_as(ctypes.c_void_p),
+                 out.ctypes.data_as(ctypes.c_void_p), keys.shape[0], vpk),
+                dense, received=out.nbytes, raw=keys.nbytes + vals.nbytes)
             return out
 
         def _repull():
@@ -1429,17 +1490,11 @@ class KVWorker:
 
         def _issue():
             keys, vpk, dense = self._frame_now(frame)
-            with _observe_op("pull", sent=keys.nbytes, received=out.nbytes,
-                             dense=dense):
-                ts = self._lib.kv_pull_vpk(
-                    self._h,
-                    keys.ctypes.data_as(ctypes.c_void_p),
-                    out.ctypes.data_as(ctypes.c_void_p),
-                    keys.shape[0], vpk,
-                )
-                back = time.perf_counter()
-                self._check(ts, "pull")
-                self._record_exchange("pull", back)
+            self._keyed(
+                "pull", self._lib.kv_pull_vpk,
+                (keys.ctypes.data_as(ctypes.c_void_p),
+                 out.ctypes.data_as(ctypes.c_void_p), keys.shape[0], vpk),
+                dense, sent=keys.nbytes, received=out.nbytes)
             return out
 
         with self._trace_op("pull"):

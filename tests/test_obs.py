@@ -69,6 +69,49 @@ class TestRegistryConcurrency:
         assert snap["buckets"][0.5] == n_threads * n_obs / 2
         assert snap["inf"] == n_threads * n_obs
 
+    def test_cells_are_exact_under_hammering_and_fold_in(self):
+        """A cell a thread: no lock on the way in, every count there at
+        any read, and in the series' own once the cell is folded."""
+        reg = MetricsRegistry()
+        c = reg.counter("t_cells_total", "h", labelnames=("op",))
+        h = reg.histogram("t_cells_seconds", "h")
+        n, threads = 20_000, 8
+        series, hist = c.labels(op="push"), h._default()
+        cells = [(series.cell(), hist.cell()) for _ in range(threads)]
+
+        def work(mine):
+            count, seconds = mine
+            for i in range(n):
+                count.inc(2)
+                seconds.observe(0.001 * (i % 3))
+
+        ts = [threading.Thread(target=work, args=(m,)) for m in cells]
+        for t in ts:
+            t.start()
+        series.inc(5)  # the locked way beside them
+        for t in ts:
+            t.join()
+        want = 2 * n * threads + 5
+        assert series.value == want and h.count == n * threads
+        for count, seconds in cells:  # their writers are gone
+            count.retired = seconds.retired = True
+        assert series.value == want and h.count == n * threads
+        assert series._cells == [] and hist._cells == []
+        assert series.value == want and h.count == n * threads
+        assert h.sum == pytest.approx(threads * sum(
+            0.001 * (i % 3) for i in range(n)))
+        assert f"t_cells_total{{op=\"push\"}} {want}" in reg.prometheus_text()
+
+    def test_a_gauge_can_be_derived_when_it_is_read(self):
+        reg = MetricsRegistry()
+        c = reg.counter("t_num_total", "h")
+        g = reg.gauge("t_ratio", "h")
+        g.set_function(lambda: c.value / 4)
+        c.inc(6)
+        assert g.value == 1.5
+        assert "t_ratio 1.5" in reg.prometheus_text()
+        assert reg.snapshot()["t_ratio"]["series"][0]["value"] == 1.5
+
     def test_counter_rejects_negative(self):
         c = MetricsRegistry().counter("c_total")
         with pytest.raises(ValueError):

@@ -270,3 +270,92 @@ def test_four_threads_appending_at_once_lose_none(tracer):
         starts = [e["ts"] for e in mine]
         assert starts == sorted(starts)
     assert len({e["args"]["id"] for e in events}) == len(events)
+
+
+RUN = ("xchg_enter", "xchg_send", "xchg_await", "xchg_recv", "xchg_wake",
+       "xchg_account")
+
+
+def _recorded(tracer):
+    """What a tracer kept, less the ids, on its own time axis."""
+    events = tracer.chrome_trace()["traceEvents"]
+    ids = {e["args"]["id"]: e["name"] for e in events}
+    rows = [(e["name"], e["dur"], ids.get(e["args"].get("parent")),
+             e["args"].get("step"), e["args"].get("rank")) for e in events]
+    order = [e["name"] for e in sorted(events, key=lambda e: e["args"]["id"])]
+    return rows, order, tracer.breakdown()
+
+
+@pytest.mark.parametrize("under", ["a span", "nothing"])
+def test_a_run_is_what_completed_once_each_in_order_would_record(under):
+    """``completed_run``: the same names, durations, parent, step and
+    rank, ids in the same order, the same seconds off the parent's own."""
+    starts = [100.0, 100.001, 100.003, 100.0045, 100.007, 100.0071]
+    end = 100.0074
+    one, run = (PhaseTracer(registry=MetricsRegistry()) for _ in range(2))
+
+    def each(tracer):
+        for name, t0, t1 in zip(RUN, starts, [*starts[1:], end]):
+            tracer.completed(name, t0, t1 - t0)
+
+    for tracer, record in ((one, each), (run, lambda t: t.completed_run(
+            RUN, starts, end))):
+        tracer._epoch = 99.0
+        if under == "a span":
+            with tracer.phase("push", step=3, rank=1):
+                record(tracer)
+        else:
+            record(tracer)
+    rows_one, order_one, spans_one = _recorded(one)
+    rows_run, order_run, spans_run = _recorded(run)
+    xchg = [r for r in rows_run if r[0] in RUN]
+    assert xchg == [r for r in rows_one if r[0] in RUN] and len(xchg) == 6
+    assert order_run == order_one
+    for name in RUN:
+        assert spans_run[name] == spans_one[name]
+    if under == "a span":
+        assert all(r[2:] == ("push", 3, 1) for r in xchg)
+        for spans in (spans_one, spans_run):
+            assert spans["push"]["self_seconds"] == pytest.approx(
+                max(spans["push"]["seconds"] - 0.0074, 0.0), abs=1e-6)
+    # the histogram has them too, from the thread's own shares
+    hist = run._registry.get("distlr_phase_seconds")
+    assert [hist.labels(phase=n).count for n in RUN] == [1] * 6
+    assert hist.labels(phase="xchg_await").sum == pytest.approx(0.0015)
+
+
+def test_a_run_with_no_end_given_ends_now(tracer):
+    t0 = time.perf_counter()
+    with tracer.phase("push"):
+        tracer.completed_run(("xchg_wake", "xchg_account"),
+                             (t0 - 0.002, t0 - 0.001))
+        after = time.perf_counter()
+    spans = tracer.breakdown()
+    assert spans["xchg_wake"]["seconds"] == pytest.approx(0.001, abs=1e-6)
+    assert 0.001 <= spans["xchg_account"]["seconds"] <= after - t0 + 0.001
+
+
+def test_a_threads_spans_stay_in_the_histogram_when_it_is_gone(tracer):
+    """A thread observes into its own shares of
+    ``distlr_phase_seconds{phase}``; they are counted while it lives and
+    the series' own once it has ended."""
+    hist = tracer._registry.get("distlr_phase_seconds")
+    ready, go = threading.Event(), threading.Event()
+
+    def work():
+        for _ in range(50):
+            with tracer.phase("round"):
+                pass
+        ready.set()
+        go.wait(10)
+
+    t = threading.Thread(target=work)
+    t.start()
+    assert ready.wait(10)
+    series = hist.labels(phase="round")
+    assert series.count == 50 and len(series._cells) == 1
+    go.set()
+    t.join(10)
+    assert series.count == 50 and series._cells == []
+    assert series.count == 50
+    assert tracer.breakdown()["round"]["count"] == 50
