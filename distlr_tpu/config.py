@@ -69,9 +69,10 @@ class Config:
     # BATCH_SIZE (-1 = full shard).  A dense PS worker on a jax device
     # keeps its shard resident either way: with B > 0 a round's batch is
     # the window [k B, k B + B) of it in file order (a short last batch
-    # padded and masked); a shuffled or wrap_final_batch iterator, a
-    # shard the device has no room for and keyed models stream a batch a
-    # step from the host.
+    # padded and masked), and so does a keyed sparse_lr worker whose step
+    # is worth the trip (its shard localised at load); a shuffled or
+    # wrap_final_batch iterator, a shard the device has no room for and
+    # the other keyed models stream a batch a step from the host.
     batch_size: int = -1
     test_interval: int = 10           # TEST_INTERVAL (eval every k epochs)
     random_seed: int = 10             # RANDOM_SEED (unused by ref — Q2)

@@ -189,6 +189,22 @@ class SparseDataIter(DataIter):
         idx, mask = self._next_idx()
         return self.X[idx], self.vals[idx], self.y[idx], mask
 
+    def held_rows(self):
+        """``(cols, vals, y, mask)`` of every row as this iterator holds
+        them, under :meth:`DataIter.held_rows`'s condition (each batch of
+        an epoch is a :class:`Window` of them); None otherwise."""
+        held = super().held_rows()
+        if held is None:
+            return None
+        cols, y, mask = held
+        return cols, self.vals, y, mask
+
+    def drop_rows(self) -> None:
+        """Let go of the row arrays: whoever took :meth:`held_rows` keeps
+        the rows now (a PS worker's device), and this iterator serves
+        :meth:`next_window` alone from here on."""
+        self.X = self.vals = self.y = None
+
 
 class BlockedDataIter(DataIter):
     """Row-blocked variant: yields ``(blocks, lane_vals, y, mask)`` —

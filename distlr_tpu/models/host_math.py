@@ -79,9 +79,13 @@ def sparse_batch_grad(w_u, pos, vals, y, mask, l2_c, l2_scale_by_batch):
     Mirrors ``SparseBinaryLR.grad`` (models/linear.py) restricted to the
     touched key set: ``w_u`` are the pulled weights for the batch's unique
     columns, ``pos`` maps each (row, slot) to its index in ``w_u``.  The
-    scatter is ``np.bincount`` (vectorized C) — PS-sparse batches are
-    exactly the tiny host-side steps where jit dispatch would dominate,
-    and a per-batch-varying unique-key count would recompile every step.
+    scatter is ``np.bincount`` (vectorized C): the step of every keyed
+    batch that is streamed from the host, and of the small ones, where
+    jit dispatch would dominate.  A worker that keeps its shard on its
+    step's device runs the same arithmetic there as one compiled
+    program, the pulled vector padded to one key count for the whole
+    shard so that no window compiles (``ps_trainer``'s
+    ``jit_ps_keyed_grad_step``).
 
     L2 is applied *lazily* (only the touched coordinates, like every
     sparse parameter server): with ``l2_c > 0`` the effective decay per
@@ -105,6 +109,22 @@ def sparse_batch_grad(w_u, pos, vals, y, mask, l2_c, l2_scale_by_batch):
         term = np.float32(l2_c) * w_u * active
         g += term / n if l2_scale_by_batch else term
     return g
+
+
+def localise(cols, dim: int):
+    """``np.unique(cols, return_inverse=True)`` for column ids below
+    ``dim``: the sorted unique columns (int64) and, in the ids' stead,
+    each entry's place among them (int32, ``cols``' shape).  By a table
+    of ``dim`` slots and no sort: 7 ms where the sort takes 94 for a
+    window of 16,384 x 39 entries over a million columns (PERF.md
+    section 6, PR 51); a key space this plane serves is one its servers
+    hold whole, so the table is never the larger."""
+    seen = np.zeros(dim, bool)
+    seen[cols.reshape(-1)] = True
+    keys = np.flatnonzero(seen)
+    place = np.empty(dim, np.int32)
+    place[keys] = np.arange(len(keys), dtype=np.int32)
+    return keys, place[cols]
 
 
 def sparse_softmax_batch_grad(W_u, pos, vals, y, mask, l2_c,

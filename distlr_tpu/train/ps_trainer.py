@@ -110,13 +110,16 @@ _ACCUM_K = get_registry().gauge(
 )
 
 
-#: What a dense worker keeps on its step's device (its shard's features,
-#: labels, mask: the batch of a whole-shard worker, the rows a minibatch
-#: worker's windows lie in), placed once by :meth:`PSWorker.load_data`; a
-#: streaming worker's series stays absent.
+#: What a worker keeps on its step's device, placed once by
+#: :meth:`PSWorker.load_data`: a dense worker's shard (features, labels,
+#: mask: the batch of a whole-shard worker, the rows a minibatch worker's
+#: windows lie in), a keyed ``sparse_lr`` worker's localised shard
+#: (places, values, labels, mask as placed, lane-dense); a streaming
+#: worker's series stays absent.
 _RESIDENT_BYTES = get_registry().gauge(
     "distlr_ps_resident_bytes",
-    "bytes of a PS worker's shard held on its step's device",
+    "bytes of a PS worker's shard held on its step's device (a dense "
+    "shard's rows; a keyed sparse shard's places, values, labels and mask)",
     labelnames=("rank",),
 )
 #: Rounds whose batch was a window of a resident shard, and the real rows
@@ -175,18 +178,37 @@ _STEP_DEVICE = get_registry().gauge(
 )
 
 
-#: Which program a dense worker's step on a jax device ran, a round:
-#: ``one_pass`` is a row-panel kernel over a resident row-major shard
-#: (``ops/pallas_lr.py`` for a binary model, ``ops/pallas_softmax.py``
-#: for a float32 softmax), ``two_pass`` is ``model.grad`` under XLA, whose
-#: forward and backward each stream the features.
+#: Which program a worker's step ran, a round.  A dense step on a jax
+#: device: ``one_pass`` is a row-panel kernel over a resident row-major
+#: shard (``ops/pallas_lr.py`` for a binary model,
+#: ``ops/pallas_softmax.py`` for a float32 softmax), ``two_pass`` is
+#: ``model.grad`` under XLA, whose forward and backward each stream the
+#: features.  A keyed step: ``keyed_device`` is the compiled program over
+#: a window of the resident localised shard (``_compiled_keyed_fns``),
+#: ``keyed_host`` numpy's over a batch from the host (``host_math``).
 _GRAD_ROUNDS = get_registry().counter(
     "distlr_ps_grad_rounds_total",
-    "rounds of a PS worker's dense step on a jax device, by how often the "
-    "program reads the features out of HBM (one_pass = a row-panel kernel, "
-    "the binary model's or the float32 softmax's; two_pass = XLA's forward "
-    "and backward products)",
+    "rounds of a PS worker's step, by the program that ran it: a dense "
+    "step on a jax device by how often it reads the features out of HBM "
+    "(one_pass = a row-panel kernel, the binary model's or the float32 "
+    "softmax's; two_pass = XLA's forward and backward products), a keyed "
+    "step by where it ran (keyed_device = the compiled gather and segment "
+    "sum over a window of the resident localised shard; keyed_host = "
+    "numpy's over a batch from the host)",
     labelnames=("rank", "path"),
+)
+#: A keyed round's size: the unique table rows it pulled and pushed (its
+#: keys on the wire where a row is one key, ``RowKeys``) and its real rows.
+_KEYED_KEYS = get_registry().counter(
+    "distlr_ps_keyed_keys_total",
+    "unique table rows (the keys of a binary sparse model) the keyed "
+    "rounds of a PS worker pulled and pushed",
+    labelnames=("rank",),
+)
+_KEYED_ROWS = get_registry().counter(
+    "distlr_ps_keyed_rows_total",
+    "real rows the keyed rounds of a PS worker computed on",
+    labelnames=("rank",),
 )
 _GRAD_DISPATCHES = get_registry().counter(
     "distlr_ps_grad_dispatches_total",
@@ -307,6 +329,26 @@ _PS_AUTO_CPU_THRESHOLD = 1 << 25
 # it): the step drops to plain numpy/BLAS.  f32 numpy is also CLOSER to
 # the f32 reference trajectory than the bf16-matmul jax step.
 _PS_AUTO_NUMPY_THRESHOLD = 1 << 20
+# A keyed step's work is its entries, rows x non-zeros, each a gather, a
+# product and a scattered add through an index: numpy's step costs 7 ns
+# an entry (4.5 ms at 16,384 x 39; PERF.md section 6, PR 51) where the
+# dense thresholds above count elements BLAS streams at 0.15-0.3 ns, so
+# an entry stands for this many of them.  16,384 x 39 entries are then
+# over the accelerator's threshold, 256 x 39 under numpy's.
+_PS_KEYED_ENTRY_WORK = 64
+#: The compiled keyed step takes the pulled vector padded to one key
+#: count for the whole shard: the largest window's, up to a whole
+#: multiple of this, so that no window compiles and workers whose
+#: largest windows differ by a few hundred keys share one executable.
+_KEYED_KEY_QUANTUM = 8192
+#: A keyed shard stays on the device where the device says it has this
+#: many times its bytes free: each leaf is put in the form it stays in
+#: (no relayout, no second copy), and the step's own temporaries are a
+#: few windows' worth.
+_KEYED_PLACE_HEADROOM = 1.25
+#: threads of one worker's localisation (numpy's indexing releases the
+#: interpreter)
+_LOCALISE_THREADS = 8
 
 
 def ps_retry_policy(cfg: Config) -> RetryPolicy | None:
@@ -335,8 +377,9 @@ def server_optimizer(cfg: Config) -> str:
     return "signsgd" if cfg.ps_compress == "signsgd" else cfg.ps_optimizer
 
 
-def ps_compute_device(cfg: Config, rows: int | None = None, device=None):
-    """Where PS workers run their dense step: the string ``"numpy"``
+def ps_compute_device(cfg: Config, rows: int | None = None, device=None,
+                      *, nnz: int | None = None):
+    """Where PS workers run their step: the string ``"numpy"``
     (host numpy/BLAS, no jax dispatch), a jax device, or None (default
     backend).  ``device`` is the device of the default backend the job
     gave this worker (:func:`worker_devices`); it stands wherever the
@@ -354,6 +397,10 @@ def ps_compute_device(cfg: Config, rows: int | None = None, device=None):
     shard, or full test set — the train and eval steps each pass their
     own).  When it is unknown (``None`` with ``batch_size=-1``), the step
     is assumed big enough to amortize accelerator dispatch.
+
+    ``nnz``: the step is a keyed one over ``rows`` rows of ``nnz`` slots,
+    and its work is its entries (``_PS_KEYED_ENTRY_WORK`` elements each),
+    not ``param_dim x rows``; the same thresholds then decide.
     """
     if jax.default_backend() == "cpu" and rows is None:
         return device
@@ -361,7 +408,8 @@ def ps_compute_device(cfg: Config, rows: int | None = None, device=None):
         rows = cfg.batch_size
     if rows <= 0:
         return device
-    work = ps_param_dim(cfg) * rows
+    work = (ps_param_dim(cfg) * rows if nnz is None
+            else rows * nnz * _PS_KEYED_ENTRY_WORK)
     if work < _PS_AUTO_NUMPY_THRESHOLD:
         return "numpy"
     if jax.default_backend() == "cpu" or work >= _PS_AUTO_CPU_THRESHOLD:
@@ -446,6 +494,53 @@ def _compiled_fns(model, l2_c: float, l2_scale_by_batch: bool):
 
     return jax.jit(ps_grad_step,
                    static_argnames=("panels", "interpret", "window"))
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_keyed_fns(l2_c: float, l2_scale_by_batch: bool):
+    """The jitted keyed step of ``sparse_lr``, shared across workers and
+    runs as :func:`_compiled_fns` is: ``host_math.sparse_batch_grad`` on
+    the worker's device, over window ``j`` of the resident localised
+    shard (:meth:`PSWorker._place_keyed_shard`).
+
+    ``w_u`` is the pulled vector padded to the shard's one key count;
+    ``places`` and ``vals`` are the shard's entries, lane-dense
+    (``[windows * lines, 128]``: a window's ``rows x slots`` entries in
+    ``lines`` whole lines, zero entries behind them where they end
+    inside one), ``y`` and ``mask`` its float32 labels and real-row
+    flags, ``windows * rows`` long.  ``j`` is traced, so every window
+    runs the one executable and reads its entries where they lie; what
+    crosses the host link a round is ``w_u`` in and the gradient out.
+    float32 throughout; the sums' order is the device's, not numpy's."""
+    jnp = jax.numpy
+
+    # a name of its own: a trace shows the program as
+    # ``jit_ps_keyed_grad_step``
+    def ps_keyed_grad_step(w_u, places, vals, y, mask, j, rows, slots):
+        lines = -(-rows * slots // places.shape[1])
+        p, v = (jax.lax.dynamic_slice_in_dim(a, j * lines, lines)
+                .reshape(-1)[:rows * slots].reshape(rows, slots)
+                for a in (places, vals))
+        yw, m = (jax.lax.dynamic_slice_in_dim(a, j * rows, rows)
+                 for a in (y, mask))
+        z = jnp.sum(w_u.at[p].get(mode="promise_in_bounds") * v, axis=-1)
+        n = jnp.maximum(jnp.sum(m), 1.0)
+        resid = (jax.nn.sigmoid(z) - yw) * m
+        g = jax.ops.segment_sum(
+            (resid[:, None] * v).reshape(-1), p.reshape(-1),
+            num_segments=w_u.shape[0], mode="promise_in_bounds") / n
+        if l2_c:
+            # lazily, on the keys some real entry touches
+            # (``sparse_batch_grad``): a pad entry's key decays with the
+            # entries that name it, not every round
+            active = jax.ops.segment_sum(
+                (v != 0).astype(jnp.float32).reshape(-1), p.reshape(-1),
+                num_segments=w_u.shape[0], mode="promise_in_bounds") > 0
+            term = jnp.float32(l2_c) * w_u * active
+            g = g + (term / n if l2_scale_by_batch else term)
+        return g
+
+    return jax.jit(ps_keyed_grad_step, static_argnames=("rows", "slots"))
 
 
 #: platforms on which a resident shard's step is the one-pass program
@@ -655,6 +750,11 @@ def keyed_model(cfg: Config):
     }.get(cfg.model)
 
 
+def _key_count(keys) -> dict:
+    """A keyed operation's span says how many keys it moved."""
+    return {} if keys is None else {"keys": len(keys)}
+
+
 class _Exchange:
     """An exchange is how a round's weights reach :meth:`PSWorker.fit`'s
     one loop and its gradient the servers: ``weights(keys)`` and
@@ -708,7 +808,9 @@ class _Exchange:
 class _Serialized(_Exchange):
     """The reference's protocol (``src/lr.cc:116-132``): pull,
     then push and wait, two blocking round trips a round.  Dense with
-    ``ps_pipeline=False``, and every keyed model in BOTH modes.  Sync: a
+    ``ps_pipeline=False``, and every keyed model in BOTH modes, its step
+    on the host or on the device (a keyed exchange under the next
+    window's step is not written).  Sync: a
     pull issued before the round's push would read pre-round weights and
     change the BSP trajectory.  Async: a comm-thread pipeline (pull k+1
     overlapping grad k) was measured ~10% SLOWER at CTR scale (4 workers,
@@ -729,12 +831,12 @@ class _Serialized(_Exchange):
 
     def pull(self, keys):
         w = self.w
-        with w._span("pull"):
+        with w._span("pull", **_key_count(keys)):
             return w.kv.pull(keys=keys, vals_per_key=self.vpk)
 
     def push(self, g, keys):
         w = self.w
-        with w._span("push"):
+        with w._span("push", **_key_count(keys)):
             w.kv.wait(w.kv.push(g, keys=keys, vals_per_key=self.vpk))
 
 
@@ -951,7 +1053,16 @@ class PSWorker:
     *keyed* Push/Pull (the ps-lite capability the reference app never
     exercises — its key set is always dense 0..D-1, ``src/lr.cc:117-121``):
     each batch pulls and pushes only its unique touched columns, so a
-    D=1M-bucket CTR model ships KBs per step instead of 12 MB.
+    D=1M-bucket CTR model ships KBs per step instead of 12 MB.  Where its
+    step is worth the trip its shard is **resident and localised** on the
+    step's device and the step is one compiled program there
+    (:meth:`_bind_keyed_step`, :meth:`_place_keyed_shard`: each window's
+    sorted unique keys and each entry's place among them worked out once,
+    at load; ``distlr_ps_grad_rounds_total{path="keyed_device"}``,
+    ``distlr_ps_keyed_keys_total``, ``distlr_ps_keyed_rows_total``); the
+    other keyed models (``blocked_lr``, ``sparse_softmax``) and every
+    small or streamed keyed batch keep numpy's step on the host
+    (``path="keyed_host"``).
 
     Where the data lives.  A dense worker whose step runs on a jax device
     keeps its shard **resident** wherever its iterator serves the shard's
@@ -971,8 +1082,9 @@ class PSWorker:
     for a minibatch worker, that the device says it has room:
     ``_PLACE_HEADROOM`` times the shard's bytes free, no option).
     What still streams numpy batches from host RAM, one ``device_put`` a
-    step: a shuffled or ``wrap_compat`` iterator, a shard the device has
-    no room for, and every keyed model.
+    step: a shuffled or ``wrap_compat`` iterator and a shard the device
+    has no room for; a keyed model under either, and ``blocked_lr`` and
+    ``sparse_softmax`` always, compute in numpy on the host.
     ``distlr_ps_resident_bytes{rank}`` is the shard as held;
     ``distlr_ps_window_rounds_total`` and ``distlr_ps_window_rows_total``
     count the windowed rounds and the real rows they read.
@@ -1064,9 +1176,13 @@ class PSWorker:
     Spans (``obs.tracing.loop_span``: ``PhaseTracer`` and, while a
     profiler trace is taken, a ``TraceAnnotation``) carry ``step`` = the
     worker's round count (:attr:`rounds`) and ``rank``: ``load_data`` and
-    ``shard_put`` once; a round: ``data_load`` (fetching the batch: the
+    ``shard_put`` once (a resident keyed shard: ``localise`` before it,
+    under ``load_data``: every window's unique keys and places); a
+    round: ``data_load`` (fetching the batch: the
     numpy slice, nothing for a resident shard or a window of one; a
-    keyed batch's unique rows, inside the step), then ``round`` (the
+    keyed batch's unique rows, inside the step: an ``np.unique`` of a
+    streamed batch's ids, a lookup for a window localised at load),
+    then ``round`` (the
     round's body, ``timer.start()`` to ``timer.stop()``: the parent of
     the spans below, on the tracer alone; its self seconds are the
     loop's own Python between them),
@@ -1081,7 +1197,9 @@ class PSWorker:
     servers while it ran), ``grad_d2h`` (the
     rest of that readback, of a flat gradient: a class axis is restored
     and flattened inside the program, ``distlr_ps_step_params_shaped``),
-    ``push`` (the loop blocked on its exchange; in a pipelined
+    ``push`` (the loop blocked on its exchange; a keyed round's
+    ``pull``, ``push`` and device chain carry ``keys``, the round's key
+    count; in a pipelined
     exchange the waits no round's compute is left to hide carry
     ``drain=1`` beside ``step`` and ``rank``: an epoch's last in the
     asynchronous job; under bounded delay a ``fit``'s last, and rank 0's
@@ -1164,11 +1282,11 @@ class PSWorker:
         self._staleness_pushes = _STALENESS_PUSHES.labels(rank=str(rank))
         self._train_iter = train_iter
         self._test_iter = test_iter
-        # Keyed models never use the jitted dense-batch fns (their
-        # per-batch unique-key count varies, so they run numpy host math
-        # instead, models/host_math.py); building them would plant a
-        # lambda whose (X, y, mask) signature crashes on padded-COO /
-        # blocked batches.
+        # Keyed models never use the jitted dense-batch fns (building
+        # them would plant a lambda whose (X, y, mask) signature crashes
+        # on padded-COO / blocked batches): their step is numpy host math
+        # (models/host_math.py) or, for a resident ``sparse_lr`` shard,
+        # the keyed program ``_bind_keyed_step`` binds.
         self._keyed = keyed_model(cfg)
         if self._keyed is not None:
             self._grad_fn = self._acc_fn = None
@@ -1176,8 +1294,9 @@ class PSWorker:
             self._grad_fn = _compiled_fns(self.model, cfg.l2_c, bool(cfg.l2_scale_by_batch))
             self._acc_fn = _compiled_acc(self.model)
         # runtime introspection (obs.jaxrt): compile-cache probes for the
-        # jitted dense step/eval fns (sparse/blocked paths run numpy host
-        # math — nothing to probe); ticked at each epoch end
+        # jitted dense step/eval fns (the keyed device step adds its own
+        # when it is bound; numpy host math has nothing to probe); ticked
+        # at each epoch end
         self._jit_probes = [
             jaxrt.JitCacheProbe(fn, site)
             for fn, site in ((self._grad_fn, "train.ps.grad"),
@@ -1217,10 +1336,18 @@ class PSWorker:
         self._panels = None
         #: ``(flat weights, batch) -> flat float32 gradient``, bound by
         #: load_data(): a dense model's on the device it picked, a keyed
-        #: model's in numpy over the batch's unique rows
+        #: model's over the batch's unique rows, in numpy or, a window of
+        #: a resident ``sparse_lr`` shard, on the device
         self.grad_step = None
         #: keyed models: how the connection addresses their rows
         self._rows: RowKeys | None = None
+        #: a resident keyed shard: window j's sorted unique keys as the
+        #: wire names them, worked out once by load_data()
+        self._window_keys: list[np.ndarray] | None = None
+        #: and with it: the step's device, the slots a row has, and the
+        #: one key count the compiled step takes the pulled vector padded to
+        self._keyed_dev = None
+        self._keyed_slots = self._keyed_key_count = 0
         # what the loop's exchange keeps here (``_Exchange``): the flat
         # weights the loop holds now (a span's pull or a fused reply), the
         # staleness stamp of the weights under the next gradient (when
@@ -1400,22 +1527,48 @@ class PSWorker:
             test = self._test_iter if self._test_iter is not None else (
                 self._load_test_iter() if self.rank == 0 else None)
             if self._grad_fn is None:
-                self._bind_keyed_step()
+                self._bind_keyed_step(train)
             else:
                 self._bind_dense_step(train, test)
         self._train, self._test = train, test
 
-    def _bind_keyed_step(self) -> None:
+    def _bind_keyed_step(self, train) -> None:
         """Keyed Push/Pull: only a batch's unique touched columns (sparse)
         or table rows (blocked, sparse softmax) travel: ps-lite's
         sliced-key capability, SURVEY.md §2.2 E1.d/g, which the reference
-        app itself never exercises.  The step is numpy's, on the host."""
+        app itself never exercises.
+
+        Where the step runs.  A ``sparse_lr`` worker whose iterator
+        serves its rows in the order it holds them
+        (``SparseDataIter.held_rows``), whose step is worth the trip
+        (``ps_compute_device``: rows x non-zeros, no option) and whose
+        device has room keeps its shard **resident and localised**
+        (:meth:`_place_keyed_shard`) and runs ``jit_ps_keyed_grad_step``
+        there, a window a round.  Everything else keeps numpy's step over
+        a batch from the host (``host_math``): ``blocked_lr`` and
+        ``sparse_softmax`` (no device step is written for them), a
+        shuffled or ``wrap_final_batch`` iterator, a small step, a shard
+        the device has no room for.  The log line says which, and why.
+        The eval is numpy's either way."""
         cfg = self.cfg
         width, grad = self._keyed
         self._rows = rows = RowKeys(self.kv, width)
-        log.info("rank %d %s steps and eval run in numpy on the host "
-                 "(keyed models never use the accelerator)",
-                 self.rank, cfg.model)
+        rank = str(self.rank)
+        self._keyed_keys = _KEYED_KEYS.labels(rank=rank)
+        self._keyed_rows = _KEYED_ROWS.labels(rank=rank)
+        why = self._place_keyed_shard(train)
+        if why is None:
+            log.info("rank %d %s steps pinned: train -> %s, a window of the "
+                     "resident localised shard a round (%d windows of %d "
+                     "rows, %d keys a step); eval in numpy on the host",
+                     self.rank, cfg.model,
+                     _describe_compute_device(self._keyed_dev),
+                     len(self._window_keys), train.batch_size,
+                     self._keyed_key_count)
+            self.grad_step = self._keyed_device_step(train)
+            return
+        log.info("rank %d %s steps and eval run in numpy on the host (%s)",
+                 self.rank, cfg.model, why)
         if width > 1:
             # visible (and test-assertable) record of which wire encoding
             # the keyed rounds use
@@ -1423,13 +1576,145 @@ class PSWorker:
                      f"vals_per_key={rows.vpk}" if rows.vpk > 1
                      else "expanded per-lane keys")
         l2 = (cfg.l2_c, bool(cfg.l2_scale_by_batch))
+        host_rounds = _GRAD_ROUNDS.labels(rank=rank, path="keyed_host")
 
         def grad_step(w_u, batch):
             with self._span("compute", marks_step=True):
                 if width > 1:
                     w_u = w_u.reshape(-1, width)
-                return grad(w_u, *batch, *l2).reshape(-1)
+                g = grad(w_u, *batch, *l2).reshape(-1)
+            host_rounds.inc()
+            return g
         self.grad_step = grad_step
+
+    def _place_keyed_shard(self, train) -> str | None:
+        """Localise a ``sparse_lr`` worker's shard and place it on its
+        step's device, once; None where that was done, else why the
+        worker keeps the host path (the log line's words).
+
+        Localisation: window *j* is rows ``[j B, j B + B)`` of the shard
+        in held order, every epoch from row 0, the last one short where
+        ``B`` does not divide the shard.  For each, once, under a
+        ``localise`` span: its sorted unique columns (``_window_keys``,
+        as ``RowKeys.keys`` names them on the wire) and, in the ids'
+        stead, each entry's place among them (``host_math.localise``, on
+        a few threads).  What a round's ``data_load`` then does is look
+        ``_window_keys[j]`` up.
+
+        Placement, under ``shard_put``: places (int32) and values
+        (float32) as ``[windows * lines, 128]``, a window's ``B x slots``
+        entries in ``lines`` whole lines of 128 (the TPU would pad a
+        ``[rows, 39]`` array's lanes to 128, 3.3 times the bytes), with
+        zero entries (place 0, value 0: they add nothing and name no key
+        of their own) where a window's entries end inside a line and
+        where the last window is short; labels and real-row flags as
+        float32, ``windows * B`` long.  Each leaf is a plain put in the
+        form it stays in.  The host's row arrays are let go afterwards
+        (``SparseDataIter.drop_rows``).  ``distlr_ps_resident_bytes`` is
+        the four leaves as placed."""
+        cfg = self.cfg
+        if cfg.model != "sparse_lr":
+            return f"no device step is written for {cfg.model}"
+        held = train.held_rows() if isinstance(train, SparseDataIter) else None
+        if held is None:
+            return ("the iterator does not serve the rows in the order it "
+                    "holds them: shuffled, or a wrapped short last batch")
+        cols, vals, y, mask = held
+        B, slots = train.batch_size, cols.shape[1]
+        step_dev = ps_compute_device(cfg, B, self._device, nnz=slots)
+        if step_dev == "numpy":
+            return (f"{B} rows x {slots} slots a step are under the size "
+                    "worth a jax dispatch")
+        device = _jax_device(step_dev)
+        windows = train.num_batches
+        lanes = 128
+        lines = -(-B * slots // lanes)
+        nbytes = windows * (lines * lanes * 8 + B * 8)
+        free = _device_free_bytes(device)
+        if free is not None and free < _KEYED_PLACE_HEADROOM * nbytes:
+            return (f"{nbytes} bytes localised, {free} free on "
+                    f"{_describe_compute_device(step_dev)}")
+        n = train.num_samples
+        exact = windows * B == n and lines * lanes == B * slots
+        places = np.zeros((windows, lines * lanes), np.int32)
+        # whole windows of whole lines are the host's own bytes, seen anew
+        values = (np.ascontiguousarray(vals, np.float32).reshape(windows, -1)
+                  if exact else np.zeros((windows, lines * lanes), np.float32))
+        dim = self._param_dim()
+
+        def one(j):
+            at = slice(j * B, min(j * B + B, n))
+            keys, place = host_math.localise(cols[at], dim)
+            places[j, :place.size] = place.reshape(-1)
+            if not exact:
+                values[j, :place.size] = vals[at].reshape(-1)
+            return self._rows.keys(keys)
+
+        with self._span("localise"):
+            from concurrent.futures import ThreadPoolExecutor  # noqa: PLC0415
+
+            with ThreadPoolExecutor(_LOCALISE_THREADS) as pool:
+                self._window_keys = list(pool.map(one, range(windows)))
+        most = max(len(k) for k in self._window_keys)
+        self._keyed_key_count = (-(-most // _KEYED_KEY_QUANTUM)
+                                 * _KEYED_KEY_QUANTUM)
+        below = windows * B - n
+        leaves = (places.reshape(-1, lanes), values.reshape(-1, lanes),
+                  np.pad(np.asarray(y, np.float32), (0, below)),
+                  np.pad(np.asarray(mask, np.float32), (0, below)))
+        mesh = make_mesh(devices=[device])
+        with self._span("shard_put"):
+            self._resident = jax.block_until_ready(
+                tuple(feed.place(a, mesh) for a in leaves))
+        self._keyed_dev, self._keyed_slots = step_dev, slots
+        self._windowed = True
+        _RESIDENT_BYTES.labels(rank=str(self.rank)).set(
+            sum(a.nbytes for a in leaves))
+        _STEP_DEVICE.labels(rank=str(self.rank)).set(device.id)
+        train.drop_rows()
+        return None
+
+    def _keyed_device_step(self, train):
+        """The keyed round's device chain, enqueued whole and waited for
+        once as the dense step's is (``_bind_dense_step``): the pulled
+        vector padded to the shard's key count and handed over
+        (``w_put``), ``jit_ps_keyed_grad_step`` over the round's window
+        (``compute``), the readback of the gradient (``grad_d2h``), of
+        which the window's own keys' part goes to the push.  The three
+        spans carry the round's key count (``keys``)."""
+        cfg = self.cfg
+        fn = _compiled_keyed_fns(cfg.l2_c, bool(cfg.l2_scale_by_batch))
+        self._jit_probes.append(jaxrt.JitCacheProbe(fn, "train.ps.keyed_grad"))
+        step_dev, padded = self._keyed_dev, self._keyed_key_count
+        shape = dict(rows=train.batch_size, slots=self._keyed_slots)
+        rank = str(self.rank)
+        device_rounds = _GRAD_ROUNDS.labels(rank=rank, path="keyed_device")
+        dispatches = {landed: _GRAD_DISPATCHES.labels(
+            rank=rank, weights="landed" if landed else "in_flight")
+            for landed in (False, True)}
+
+        def grad_step(w_u, window):
+            keys = len(w_u)
+            with self._span("w_put", keys=keys):
+                # the hand-over (staging, enqueue), not the copy
+                held = np.zeros(padded, np.float32)
+                held[:keys] = w_u
+                w = jax.device_put(held, step_dev)
+            with self._span("compute", marks_step=True, keys=keys):
+                landed = w.is_ready()
+                g = fn(w, *self._resident,
+                       np.int32(window.first // shape["rows"]), **shape)
+                g.copy_to_host_async()
+                # the span ends with this worker's own program and
+                # encloses no other's, as the dense step's
+                jax.block_until_ready(g)
+            device_rounds.inc()
+            dispatches[landed].inc()
+            with self._span("grad_d2h", keys=keys):
+                # the rest of the copy already under way; the client
+                # sends the window's own keys' part of this buffer
+                return np.asarray(g)[:keys]
+        return grad_step
 
     def _bind_dense_step(self, train, test) -> None:
         cfg = self.cfg
@@ -1613,14 +1898,15 @@ class PSWorker:
         ``data_load`` is what fetching a dense one cost the loop: the
         numpy slice of a streamed batch; nothing for a resident shard, of
         which a minibatch is a :class:`~distlr_tpu.data.iterator.Window`.
-        A keyed batch comes as the iterator has it: its ``data_load`` is
+        A keyed batch comes as the iterator has it (a resident keyed
+        shard's as a ``Window``): its ``data_load`` is
         :meth:`_keyed_round`'s, inside the step."""
         keyed, resident, windowed = (
             self._rows is not None, self._resident, self._windowed)
         for _ in range(train.num_batches):
             self.rounds += 1
             if keyed:
-                batch = train.next_batch()
+                batch = train.next_window() if windowed else train.next_batch()
             else:
                 with self._span("data_load"):
                     batch = (train.next_batch() if resident is None
@@ -1630,13 +1916,23 @@ class PSWorker:
                           else batch.rows if windowed
                           else self._resident_rows)
 
-    def _keyed_round(self, batch):
+    def _keyed_round(self, batch, n_real: int):
         """A keyed round's ``data_load``: the batch's unique rows as wire
-        keys, and each entry's place among them in the ids' stead."""
+        keys, and each entry's place among them in the ids' stead.  A
+        window of a resident shard was localised at load: a lookup."""
         with self._span("data_load"):
-            ids = batch[0]
-            ub, pos = np.unique(ids, return_inverse=True)
-            return (pos.reshape(ids.shape), *batch[1:]), self._rows.keys(ub)
+            if isinstance(batch, Window):
+                keys = self._window_keys[batch.first // self._train.batch_size]
+                unique = len(keys)
+            else:
+                ids = batch[0]
+                ub, pos = np.unique(ids, return_inverse=True)
+                batch, keys = ((pos.reshape(ids.shape), *batch[1:]),
+                               self._rows.keys(ub))
+                unique = len(ub)
+        self._keyed_keys.inc(unique)
+        self._keyed_rows.inc(n_real)
+        return batch, keys
 
     def start(self, *, resume=False, rejoin=False) -> None:
         """Seed the group (rank 0) and meet the peers at the start
@@ -1763,7 +2059,7 @@ class PSWorker:
                 with self._loop_span("round"):
                     self.timer.start()
                     if keyed:
-                        batch, keys = self._keyed_round(batch)
+                        batch, keys = self._keyed_round(batch, n_real)
                     w = exchange.weights(keys)
                     g = grad_step(w, batch)
                     exchange.send(g, keys)

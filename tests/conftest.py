@@ -36,6 +36,15 @@ cell and a configuration for ``dense-ps-async-minibatch-1chip``).  Its
 other clauses are held, without the place, by
 ``tests/chipbench/test_dense_ps_minibatch.py::
 test_the_entries_that_were_there_are_as_they_were``.
+
+A fourth: ``tests/chipbench/test_ps_host_readers.py::
+test_the_seven_entries_stand_at_the_end_with_a_file_each`` (PR 49) holds
+its seven entries to the LAST seven places and ``per_layer`` to 86 names,
+and fails from PR 51 on (ten entries for ``sparse-ps-async-keyed-1chip``).
+Its other clauses (each entry as written, its layer one the benchmark
+had, its file and reader) are held, without the place, by
+``tests/chipbench/test_sparse_ps_keyed.py::
+test_the_host_readers_entries_are_as_they_were``.
 """
 
 import contextlib
@@ -66,6 +75,8 @@ HELD_TO_THE_END = {
     "order_and_the_new_follow": "PR 30",
     "test_dense_ps_bsp_eval.py::test_the_new_entries_stand_at_the_end_of_"
     "their_lists": "PR 36",
+    "test_ps_host_readers.py::test_the_seven_entries_stand_at_the_end_with_"
+    "a_file_each": "PR 49",
 }
 
 

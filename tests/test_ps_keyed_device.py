@@ -1,0 +1,466 @@
+"""A keyed ``sparse_lr`` PS worker keeps its shard on its step's device,
+localised at load, and runs its step there as one compiled program: the
+localisation, the program against numpy's step and against the plain
+reference, four workers and two native servers end to end, and what
+stays as it was (the other keyed families, a shuffled or wrapped shard, a
+small step, every dense model's program)."""
+
+import hashlib
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import datagen
+from chipbench.families import sparse_ps_keyed
+from distlr_tpu.config import Config
+from distlr_tpu.data.hashing import make_uniform_blocked_batch
+from distlr_tpu.data.iterator import (
+    BlockedDataIter,
+    SparseDataIter,
+    Window,
+)
+from distlr_tpu.models import get_model, host_math
+from distlr_tpu.obs.registry import family_total, get_registry
+from distlr_tpu.obs.tracing import get_tracer
+from distlr_tpu.ps import KVWorker, ServerGroup
+from distlr_tpu.train import ps_trainer
+
+DIM, BATCH, SLOTS, LR = 4096, 256, 39, 0.2
+ROWS = 2 * BATCH + 200          # the last window is short
+WINDOWS = 3
+LINES = BATCH * SLOTS // 128    # 9,984 entries are 78 whole lines
+ROUNDS = "distlr_ps_grad_rounds_total"
+
+
+@pytest.fixture(scope="module")
+def rows():
+    return datagen.make_rows(51, "train", 4 * ROWS, fields="criteo-kaggle",
+                             num_buckets=DIM, label_scale=0.5,
+                             label_bias=-1.0)
+
+
+class Table:
+    """A connection whose servers are one array under plain SGD."""
+
+    def __init__(self, hosts, dim, **kw):
+        self.dim, self.pulled, self.pushed = dim, [], []
+        self.table = (np.random.default_rng(3).standard_normal(dim)
+                      .astype(np.float32) * 0.1)
+
+    def supports_vals_per_key(self, vpk):
+        return True
+
+    def _slots(self, keys, vpk):
+        return (np.asarray(keys, np.int64)[:, None] * vpk
+                + np.arange(vpk)).reshape(-1)
+
+    def pull(self, keys=None, *, vals_per_key=1):
+        self.pulled.append(np.array(keys))
+        return self.table[self._slots(keys, vals_per_key)].copy()
+
+    def push(self, vals, keys=None, *, vals_per_key=1):
+        self.pushed.append((np.array(keys), np.array(vals)))
+        self.table[self._slots(keys, vals_per_key)] -= LR * np.asarray(vals)
+        return 0
+
+    def wait(self, ts):
+        pass
+
+    def global_pushes(self):
+        return 0.0
+
+    def close(self):
+        pass
+
+
+def _cfg(model="sparse_lr", **kw):
+    base = dict(model=model, num_feature_dim=DIM, batch_size=BATCH,
+                learning_rate=LR, l2_c=0.0, test_interval=0, num_workers=2,
+                sync_mode=False)
+    return Config(**{**base, **kw})
+
+
+def _worker(monkeypatch, train, cfg=None):
+    monkeypatch.setattr(ps_trainer, "KVWorker", Table)
+    # rank 1: no test split to load, no eval; the loop is every rank's
+    worker = ps_trainer.PSWorker(cfg or _cfg(), 1, "nowhere:0",
+                                 train_iter=train)
+    get_tracer().reset()
+    worker.load_data()
+    return worker
+
+
+def _shard(rows, rank=0):
+    return tuple(a[rank * ROWS:(rank + 1) * ROWS] for a in rows)
+
+
+def _count(name, rank=1, **labels):
+    return get_registry().get(name).labels(rank=str(rank), **labels).value
+
+
+# -- the localisation ---------------------------------------------------------
+@pytest.mark.parametrize("dtype", [np.int32, np.int64])
+@pytest.mark.parametrize("shape", [(256, 39), (1, 5), (700, 3)])
+def test_localise_is_numpys_unique_without_the_sort(dtype, shape):
+    cols = np.random.default_rng(shape[0]).integers(0, 5000, shape).astype(
+        dtype)
+    keys, places = host_math.localise(cols, 5000)
+    want, inverse = np.unique(cols, return_inverse=True)
+    assert np.array_equal(keys, want)
+    assert places.dtype == np.int32 and places.shape == cols.shape
+    assert np.array_equal(places, inverse.reshape(cols.shape))
+
+
+def test_the_shard_is_localised_once_and_held_lane_dense(
+        monkeypatch, rows, ps_steps_on_device):
+    cols, vals, y = _shard(rows)
+    train = SparseDataIter(cols, vals, y, BATCH)
+    w = _worker(monkeypatch, train)
+    assert w._windowed and len(w._window_keys) == WINDOWS
+    places, values, labels, mask = (np.asarray(a) for a in w._resident)
+    # lane-dense: a window's entries in whole lines of 128, nothing a
+    # [rows, 39] array's lanes would be padded with
+    assert places.shape == values.shape == (WINDOWS * LINES, 128)
+    assert places.dtype == np.int32 and values.dtype == np.float32
+    assert labels.shape == mask.shape == (WINDOWS * BATCH,)
+    assert mask.sum() == ROWS and not mask[ROWS:].any()
+    for j, keys in enumerate(w._window_keys):
+        at = slice(j * BATCH, min(j * BATCH + BATCH, ROWS))
+        assert keys.dtype == np.uint64
+        assert np.array_equal(keys, np.unique(cols[at]))
+        n = cols[at].size
+        p = places[j * LINES:(j + 1) * LINES].reshape(-1)
+        v = values[j * LINES:(j + 1) * LINES].reshape(-1)
+        # the places map back to the columns; what is behind a short
+        # window's entries names no key and adds nothing
+        assert np.array_equal(keys[p[:n]].astype(np.int64),
+                              cols[at].reshape(-1))
+        assert np.array_equal(v[:n], vals[at].reshape(-1))
+        assert not p[n:].any() and not v[n:].any()
+    # one key count for the whole shard: no window compiles
+    most = max(len(k) for k in w._window_keys)
+    assert w._keyed_key_count % ps_trainer._KEYED_KEY_QUANTUM == 0
+    assert most <= w._keyed_key_count < most + ps_trainer._KEYED_KEY_QUANTUM
+    assert _count("distlr_ps_resident_bytes") == (
+        places.nbytes + values.nbytes + labels.nbytes + mask.nbytes)
+    spans = get_tracer().breakdown()
+    assert spans["localise"]["count"] == spans["shard_put"]["count"] == 1
+    # the host's copy is let go; the iterator serves windows
+    assert train.X is None and train.vals is None
+    train.reset()
+    assert [train.next_window() for _ in range(WINDOWS)] == [
+        Window(0, BATCH), Window(BATCH, BATCH), Window(2 * BATCH, 200)]
+
+
+def test_whole_windows_of_whole_lines_are_the_hosts_own_values(
+        monkeypatch, rows, ps_steps_on_device):
+    cols, vals, y = (a[:2 * BATCH] for a in _shard(rows))
+    w = _worker(monkeypatch, SparseDataIter(cols, vals, y, BATCH))
+    assert np.array_equal(np.asarray(w._resident[1]).reshape(-1),
+                          vals.reshape(-1))
+
+
+# -- the compiled step --------------------------------------------------------
+@pytest.mark.parametrize("l2", [(0.0, False), (0.5, False), (0.5, True)],
+                         ids=["no-l2", "l2", "l2-by-batch"])
+@pytest.mark.parametrize("j", [0, 1, 2], ids=["first", "second", "short"])
+def test_the_compiled_step_is_numpys_and_the_references(
+        monkeypatch, rows, ps_steps_on_device, j, l2):
+    cols, vals, y = _shard(rows)
+    cfg = _cfg(l2_c=l2[0], l2_scale_by_batch=l2[1])
+    w = _worker(monkeypatch, SparseDataIter(cols, vals, y, BATCH), cfg)
+    at = slice(j * BATCH, min(j * BATCH + BATCH, ROWS))
+    keys, places = np.unique(cols[at], return_inverse=True)
+    w_u = (np.random.default_rng(j).standard_normal(len(keys)) * 0.1).astype(
+        np.float32)
+    real = at.stop - at.start
+    got = w.grad_step(w_u, Window(at.start, real))
+    assert got.shape == w_u.shape and got.dtype == np.float32
+    want = host_math.sparse_batch_grad(
+        w_u, places.reshape(cols[at].shape), vals[at], y[at],
+        np.ones(real, bool), *l2)
+    scale = np.linalg.norm(want)
+    assert np.linalg.norm(got - want) <= 2e-6 * scale
+    if not l2[0]:
+        ref = sparse_ps_keyed.gradient(w_u, cols[at], vals[at], y[at])
+        assert np.linalg.norm(got - ref) <= 2e-6 * scale
+    assert _count(ROUNDS, path="keyed_device") >= 1
+
+
+def test_every_window_and_every_epoch_runs_the_one_executable(
+        monkeypatch, rows, ps_steps_on_device):
+    cols, vals, y = _shard(rows)
+    fn = ps_trainer._compiled_keyed_fns(0.0, False)
+    w = _worker(monkeypatch, SparseDataIter(cols, vals, y, BATCH))
+    before = _count(ROUNDS, path="keyed_device")
+    keys0, rows0 = (_count("distlr_ps_keyed_keys_total"),
+                    _count("distlr_ps_keyed_rows_total"))
+    get_tracer().reset()
+    w.fit(epochs=1)
+    compiled = fn._cache_size()
+    w.fit(epochs=2)
+    assert fn._cache_size() == compiled
+    assert _count(ROUNDS, path="keyed_device") - before == 3 * WINDOWS
+    # a round pulls and pushes exactly its window's keys
+    kv = w.kv
+    want = [np.unique(cols[j * BATCH:(j + 1) * BATCH]) for j in range(WINDOWS)]
+    assert len(kv.pulled) == len(kv.pushed) == 3 * WINDOWS
+    for i, (pulled, (pushed, g)) in enumerate(zip(kv.pulled, kv.pushed)):
+        assert np.array_equal(pulled, want[i % WINDOWS])
+        assert np.array_equal(pushed, pulled) and len(g) == len(pushed)
+    assert _count("distlr_ps_keyed_rows_total") - rows0 == 3 * ROWS
+    assert _count("distlr_ps_keyed_keys_total") - keys0 == 3 * sum(
+        len(k) for k in want)
+    # nothing is placed or localised again, and the spans say the keys
+    spans = get_tracer().breakdown()
+    assert "localise" not in spans and "shard_put" not in spans
+    assert "h2d" not in spans
+    events = get_tracer().chrome_trace()["traceEvents"]
+    for name in ("pull", "push", "w_put", "compute", "grad_d2h"):
+        said = [e["args"]["keys"] for e in events if e["name"] == name]
+        assert said == [len(want[i % WINDOWS]) for i in range(3 * WINDOWS)]
+    w.close()
+
+
+def test_the_device_rounds_are_the_host_rounds_to_rounding(
+        monkeypatch, rows, ps_steps_on):
+    """Two epochs over one table: the device step's trajectory beside
+    numpy's, the same keys on the wire."""
+    cols, vals, y = _shard(rows)
+    tables = {}
+    for where in ("device", "numpy"):
+        with ps_steps_on(where):
+            w = _worker(monkeypatch, SparseDataIter(cols, vals, y, BATCH))
+            assert (w._resident is not None) == (where == "device")
+            w.fit(epochs=2)
+            tables[where] = (w.kv.table.copy(), w.kv.pulled)
+            w.close()
+    moved = np.linalg.norm(tables["numpy"][0] - Table(None, DIM).table)
+    assert moved > 0
+    assert np.linalg.norm(tables["device"][0] - tables["numpy"][0]) <= (
+        1e-5 * moved)
+    for a, b in zip(tables["device"][1], tables["numpy"][1]):
+        # numpy's short batch is padded with copies of row 0, whose keys
+        # it pulls too; the resident window's pads name no key
+        assert set(a.tolist()) <= set(b.tolist())
+    assert all(np.array_equal(a, b) for a, b in list(zip(
+        tables["device"][1], tables["numpy"][1]))[:2])
+
+
+# -- four workers, two native servers -----------------------------------------
+def test_four_workers_and_two_servers_conserve_what_was_pushed(
+        rows, ps_steps_on_device):
+    cfg = _cfg(num_workers=4, num_servers=2)
+    keyed0 = (family_total("distlr_ps_keyed_keys_total"),
+              family_total("distlr_ps_keyed_rows_total"))
+
+    def path(p):
+        fam = get_registry().get(ROUNDS)
+        return sum(c.value for labels, c in fam.children() if labels[-1] == p)
+
+    device0, host0 = path("keyed_device"), path("keyed_host")
+    with ServerGroup(2, 4, DIM, learning_rate=LR, sync=False) as group:
+        probe = KVWorker(group.hosts, DIM, client_id=0xFC00)
+        w0 = (np.random.default_rng(9).standard_normal(DIM) * 0.05).astype(
+            np.float32)
+        probe.wait(probe.push_init(w0))
+        workers = [ps_trainer.PSWorker(
+            cfg, r, group.hosts,
+            train_iter=SparseDataIter(*_shard(rows, r), BATCH),
+            test_iter=SparseDataIter(*_shard(rows, 0), -1))
+            for r in range(4)]
+        total = np.zeros(DIM, np.float64)
+        first_pulls, moved_keys, lock = {}, [0], threading.Lock()
+        together = threading.Barrier(4)
+        try:
+            for w in workers:
+                w.load_data()
+                assert w._resident is not None
+                pull, push = w.kv.pull, w.kv.push
+
+                def tapped_pull(keys=None, *, _pull=pull, _w=w, **kw):
+                    got = _pull(keys=keys, **kw)
+                    if _w.rank not in first_pulls:
+                        first_pulls[_w.rank] = (np.array(keys), np.array(got))
+                        together.wait(timeout=60)
+                    return got
+
+                def tapped_push(vals, keys=None, *, _push=push, **kw):
+                    with lock:
+                        total[np.asarray(keys).astype(np.int64)] += vals
+                        moved_keys[0] += len(keys)
+                    return _push(vals, keys=keys, **kw)
+
+                w.kv.pull, w.kv.push = tapped_pull, tapped_push
+            w_before = probe.pull()
+            assert np.array_equal(w_before, w0)
+            errors = []
+
+            def run(w):
+                try:
+                    w.start()
+                    w.fit(epochs=2)
+                except Exception as e:  # surfaced below
+                    errors.append(e)
+
+            threads = [threading.Thread(target=run, args=(w,))
+                       for w in workers]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+            assert not errors and not any(t.is_alive() for t in threads)
+            w_after = probe.pull()
+        finally:
+            for w in workers:
+                w.close()
+            probe.close()
+    # a pull returns the weights of exactly the keys asked: every
+    # worker's first one, taken before any push, is the seed at its keys
+    for rank, (keys, got) in first_pulls.items():
+        want = np.unique(_shard(rows, rank)[0][:BATCH])
+        assert np.array_equal(keys, want)
+        assert np.array_equal(got.view(np.uint32),
+                              w0[want].view(np.uint32))
+    pushed = LR * total
+    moved = w_after.astype(np.float64) - w_before
+    assert np.linalg.norm(moved) > 0.5 * np.linalg.norm(pushed) > 0
+    assert np.linalg.norm(moved + pushed) <= 2e-5 * np.linalg.norm(pushed)
+    rounds = 4 * 2 * WINDOWS
+    assert path("keyed_device") - device0 == rounds
+    assert path("keyed_host") == host0
+    assert family_total("distlr_ps_keyed_rows_total") - keyed0[1] == 8 * ROWS
+    assert family_total("distlr_ps_keyed_keys_total") - keyed0[0] == (
+        moved_keys[0])
+
+
+# -- what keeps the host path, bit for bit ------------------------------------
+def _parents_rounds(train, grad, width, table, epochs, l2=(0.0, False)):
+    """The keyed loop as the parent of the device step ran it: an
+    ``np.unique`` of the batch's ids, a pull of those rows, numpy's
+    gradient, a push."""
+    table = table.copy()
+    for _ in range(epochs):
+        train.reset()
+        for batch in train:
+            ids = batch[0]
+            ub, pos = np.unique(ids, return_inverse=True)
+            slots = (ub[:, None] * width + np.arange(width)).reshape(-1)
+            w_u = table[slots].copy()
+            if width > 1:
+                w_u = w_u.reshape(-1, width)
+            g = grad(w_u, pos.reshape(ids.shape), *batch[1:], *l2)
+            table[slots] -= LR * g.reshape(-1)
+    return table
+
+
+def _host_case(name, rows):
+    cols, vals, y = (a[:ROWS] for a in rows)
+    rng = np.random.default_rng(4)
+    if name == "shuffled":
+        return (_cfg(), SparseDataIter(cols, vals, y, BATCH, shuffle=True,
+                                       seed=7), 1,
+                host_math.sparse_batch_grad)
+    if name == "wrapped-short-batch":
+        return (_cfg(wrap_final_batch=True),
+                SparseDataIter(cols, vals, y, BATCH, wrap_compat=True), 1,
+                host_math.sparse_batch_grad)
+    if name == "sparse_softmax":
+        return (_cfg("sparse_softmax", num_classes=4),
+                SparseDataIter(cols, vals, rng.integers(0, 4, ROWS), BATCH),
+                4, host_math.sparse_softmax_batch_grad)
+    blocks, lane_vals = make_uniform_blocked_batch(rng, ROWS, 6, DIM // 4, 4)
+    return (_cfg("blocked_lr", block_size=4),
+            BlockedDataIter(blocks, lane_vals, y, BATCH), 4,
+            host_math.blocked_batch_grad)
+
+
+@pytest.mark.parametrize("name", ["shuffled", "wrapped-short-batch",
+                                  "sparse_softmax", "blocked_lr"])
+def test_what_keeps_the_host_path_runs_as_it_did(
+        monkeypatch, rows, ps_steps_on_device, name):
+    cfg, train, width, grad = _host_case(name, rows)
+    before = _count(ROUNDS, path="keyed_host")
+    w = _worker(monkeypatch, train, cfg)
+    assert w._resident is None and w._window_keys is None
+    assert "shard_put" not in get_tracer().breakdown()
+    opening = w.kv.table.copy()
+    w.fit(epochs=2)
+    w.close()
+    assert _count(ROUNDS, path="keyed_host") - before == 2 * WINDOWS
+    want = _parents_rounds(train, grad, width, opening, 2)
+    assert np.array_equal(w.kv.table.view(np.uint32), want.view(np.uint32))
+
+
+def test_a_small_keyed_step_stays_numpys_by_its_size(monkeypatch, rows):
+    cols, vals, y = _shard(rows)
+    w = _worker(monkeypatch, SparseDataIter(cols, vals, y, BATCH))
+    assert w._resident is None          # 256 x 39 entries: under the rule
+    opening = w.kv.table.copy()
+    w.fit(epochs=1)
+    w.close()
+    want = _parents_rounds(SparseDataIter(cols, vals, y, BATCH),
+                           host_math.sparse_batch_grad, 1, opening, 1)
+    assert np.array_equal(w.kv.table.view(np.uint32), want.view(np.uint32))
+
+
+@pytest.mark.parametrize("rows_,nnz,want", [
+    (256, 39, "numpy"),             # 9,984 entries x 64 < 2^20
+    (512, 39, "device"),            # the benchmark's rehearsal
+    (16384, 39, "device"),          # the benchmark's cell
+    (16384, None, "device"),        # a dense step of D x rows elements
+    (8, None, "numpy"),
+])
+def test_the_rule_counts_a_keyed_steps_entries(rows_, nnz, want):
+    cfg = _cfg()
+    device = jax.devices()[0]
+    got = ps_trainer.ps_compute_device(cfg, rows_, device, nnz=nnz)
+    assert got == ("numpy" if want == "numpy" else device)
+    # on an accelerator the cell's step passes the accelerator's threshold
+    assert 16384 * 39 * ps_trainer._PS_KEYED_ENTRY_WORK >= (
+        ps_trainer._PS_AUTO_CPU_THRESHOLD)
+
+
+# -- the iterator -------------------------------------------------------------
+@pytest.mark.parametrize("kw,held", [
+    ({}, True), ({"shuffle": True, "seed": 3}, False),
+    ({"wrap_compat": True}, False),
+], ids=["in-order", "shuffled", "wrapped"])
+def test_a_sparse_iterator_says_which_rows_it_holds(rows, kw, held):
+    cols, vals, y = _shard(rows)
+    it = SparseDataIter(cols, vals, y, BATCH, **kw)
+    got = it.held_rows()
+    if not held:
+        assert got is None
+        return
+    assert got[0] is it.X and got[1] is it.vals and got[2] is it.y
+    assert got[3].all() and len(got[3]) == ROWS
+    # rows that are already arrays are taken as they are
+    assert np.shares_memory(it.X, cols) and np.shares_memory(it.vals, vals)
+
+
+# -- every dense model's program is the one it was ----------------------------
+@pytest.mark.skipif(jax.__version__ != "0.9.0",
+                    reason="the pinned text is jax 0.9.0's")
+@pytest.mark.parametrize("model,classes,whole,window", [
+    ("binary_lr", 2, "63ff194a793476ec", "80b3f02e55f33b37"),
+    ("softmax", 3, "2fbca4555862fd25", "97cc494aba0b0cb0"),
+])
+def test_a_dense_models_lowered_step_is_the_parents(model, classes, whole,
+                                                    window):
+    """The text ``_compiled_fns`` lowers to, hashed on the commit before
+    the keyed device step (PR 50) and here."""
+    cfg = Config(model=model, num_feature_dim=64, num_classes=classes,
+                 l2_c=0.5)
+    fn = ps_trainer._compiled_fns(get_model(cfg), 0.5, False)
+    sd = jax.ShapeDtypeStruct
+    args = (sd((64 * (classes if model == "softmax" else 1),), jnp.float32),
+            sd((32, 64), jnp.float32), sd((32,), jnp.int32),
+            sd((32,), jnp.bool_))
+    texts = (fn.lower(*args).as_text(),
+             fn.lower(*args, first=sd((), jnp.int32), window=8).as_text())
+    assert [hashlib.sha256(t.encode()).hexdigest()[:16] for t in texts] == [
+        whole, window]

@@ -156,20 +156,26 @@ def test_a_minibatch_worker_still_streams(tmp_path, monkeypatch, why):
     assert np.isfinite(final).all() and np.count_nonzero(final)
 
 
-def test_a_keyed_model_places_nothing(tmp_path):
+@pytest.mark.parametrize("where", ["size", "device"])
+def test_a_keyed_model_places_nothing_where_its_step_is_numpys(
+        tmp_path, ps_steps_on, where):
+    """By its size a 16-row sparse step is numpy's, over the batch's
+    unique rows: no program, nothing placed.  Where the rule sends it to
+    the device the worker keeps its localised shard there
+    (``tests/test_ps_keyed_device.py``)."""
     cfg = _job(tmp_path, "sparse_lr", 1, batch_size=16, num_iteration=1)
     before = family_total(H2D)
-    with _group(cfg) as group:
+    with ps_steps_on(where), _group(cfg) as group:
         w = PSWorker(cfg, 0, group.hosts)
         try:
             w.load_data()
-            # its step is numpy's over the batch's unique rows: no program
-            assert w._resident is None and w._grad_fn is None
-            assert w.grad_step is not None
+            assert w._grad_fn is None and w.grad_step is not None
+            assert (w._resident is None) == (where == "size")
             w.run(save=False)
         finally:
             w.close()
-    assert family_total(H2D) == before and w.rounds == w._train.num_batches
+    assert (family_total(H2D) == before) == (where == "size")
+    assert w.rounds == w._train.num_batches
 
 
 # -- a minibatch worker's batch is a window of its resident shard -----------
