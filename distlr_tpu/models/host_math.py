@@ -111,10 +111,12 @@ def sparse_batch_grad(w_u, pos, vals, y, mask, l2_c, l2_scale_by_batch):
     return g
 
 
-def localise(cols, dim: int):
+def localise(cols, dim: int, shift: int = 0):
     """``np.unique(cols, return_inverse=True)`` for column ids below
     ``dim``: the sorted unique columns (int64) and, in the ids' stead,
-    each entry's place among them (int32, ``cols``' shape).  By a table
+    each entry's place among them (int32, ``cols``' shape; ``shift``
+    bits to the left where the caller packs something under it, which
+    the table then holds and no pass over the entries makes).  By a table
     of ``dim`` slots and no sort: 7 ms where the sort takes 94 for a
     window of 16,384 x 39 entries over a million columns (PERF.md
     section 6, PR 51); a key space this plane serves is one its servers
@@ -123,7 +125,7 @@ def localise(cols, dim: int):
     seen[cols.reshape(-1)] = True
     keys = np.flatnonzero(seen)
     place = np.empty(dim, np.int32)
-    place[keys] = np.arange(len(keys), dtype=np.int32)
+    place[keys] = np.arange(len(keys), dtype=np.int32) << shift
     return keys, place[cols]
 
 

@@ -114,12 +114,15 @@ _ACCUM_K = get_registry().gauge(
 #: :meth:`PSWorker.load_data`: a dense worker's shard (features, labels,
 #: mask: the batch of a whole-shard worker, the rows a minibatch worker's
 #: windows lie in), a keyed ``sparse_lr`` worker's localised shard
-#: (places, values, labels, mask as placed, lane-dense); a streaming
-#: worker's series stays absent.
+#: (each window's entries sorted by place, lane-dense: place and row in
+#: one int32, values, a base row a chunk of lines; labels, mask); a
+#: streaming worker's series stays absent.
 _RESIDENT_BYTES = get_registry().gauge(
     "distlr_ps_resident_bytes",
     "bytes of a PS worker's shard held on its step's device (a dense "
-    "shard's rows; a keyed sparse shard's places, values, labels and mask)",
+    "shard's rows; a keyed sparse shard's entries sorted by place within "
+    "a window, place and row in one int32 beside the values, a base row "
+    "for every eight lines of them, labels and mask)",
     labelnames=("rank",),
 )
 #: Rounds whose batch was a window of a resident shard, and the real rows
@@ -192,9 +195,11 @@ _GRAD_ROUNDS = get_registry().counter(
     "step on a jax device by how often it reads the features out of HBM "
     "(one_pass = a row-panel kernel, the binary model's or the float32 "
     "softmax's; two_pass = XLA's forward and backward products), a keyed "
-    "step by where it ran (keyed_device = the compiled gather and segment "
-    "sum over a window of the resident localised shard; keyed_host = "
-    "numpy's over a batch from the host)",
+    "step by where it ran (keyed_device = the compiled step over a window "
+    "of the resident localised shard, whose compute span says which "
+    "program: kernel = lookups in tables held in VMEM, on a TPU; xla = "
+    "XLA's gather and segment sum; keyed_host = numpy's over a batch from "
+    "the host)",
     labelnames=("rank", "path"),
 )
 #: A keyed round's size: the unique table rows it pulled and pushed (its
@@ -342,10 +347,16 @@ _PS_KEYED_ENTRY_WORK = 64
 #: largest windows differ by a few hundred keys share one executable.
 _KEYED_KEY_QUANTUM = 8192
 #: A keyed shard stays on the device where the device says it has this
-#: many times its bytes free: each leaf is put in the form it stays in
-#: (no relayout, no second copy), and the step's own temporaries are a
-#: few windows' worth.
-_KEYED_PLACE_HEADROOM = 1.25
+#: many times its bytes free: the entries as they are put (sorted where
+#: they lie) and the sort's own temporaries, as many bytes again,
+#: stand there together once, at load (``_keyed_sort_program``: 2.0
+#: times by the compiler's count for a v5e); what stays is the entries.
+_KEYED_PLACE_HEADROOM = 2.5
+#: A window's entries lie in whole multiples of this many lines of 128:
+#: whole grid steps of the step's kernel, whatever it takes at once
+#: (``ops.pallas_keyed.keyed_plan`` answers None for lines that are not,
+#: and the step is XLA's).  Kept here, where no Pallas is imported.
+_KEYED_LINE_QUANTUM = 128
 #: threads of one worker's localisation (numpy's indexing releases the
 #: interpreter)
 _LOCALISE_THREADS = 8
@@ -504,43 +515,94 @@ def _compiled_keyed_fns(l2_c: float, l2_scale_by_batch: bool):
     shard (:meth:`PSWorker._place_keyed_shard`).
 
     ``w_u`` is the pulled vector padded to the shard's one key count;
-    ``places`` and ``vals`` are the shard's entries, lane-dense
-    (``[windows * lines, 128]``: a window's ``rows x slots`` entries in
-    ``lines`` whole lines, zero entries behind them where they end
-    inside one), ``y`` and ``mask`` its float32 labels and real-row
-    flags, ``windows * rows`` long.  ``j`` is traced, so every window
-    runs the one executable and reads its entries where they lie; what
-    crosses the host link a round is ``w_u`` in and the gradient out.
-    float32 throughout; the sums' order is the device's, not numpy's."""
+    ``packed`` and ``vals`` are the shard's entries, lane-dense
+    (``[windows * lines, 128]``) and within a window **sorted by place**:
+    ``packed`` is ``place << row_bits | row`` (the row within the
+    window), pad entries (place 0, value 0) among the first; ``y`` and
+    ``mask`` the float32 labels and real-row flags, ``windows * rows``
+    long; ``bases`` the base row of every chunk of eight lines
+    (``[windows, lines / 8]``).  ``j`` is traced, so every window runs the one
+    executable and reads its entries where they lie; what crosses the
+    host link a round is ``w_u`` in and the gradient out.
+
+    ``plan`` (static; ``ops.pallas_keyed.keyed_plan``) makes the two
+    irregular passes the kernel's lookups in tables that stay in VMEM
+    (on a TPU: ``_ONE_PASS_PLATFORMS``, as the dense kernels); with None
+    they are XLA's gather and ``segment_sum`` over the same sorted
+    leaves.  float32 either way; the sums' order is the device's, not
+    numpy's."""
     jnp = jax.numpy
 
     # a name of its own: a trace shows the program as
     # ``jit_ps_keyed_grad_step``
-    def ps_keyed_grad_step(w_u, places, vals, y, mask, j, rows, slots):
-        lines = -(-rows * slots // places.shape[1])
-        p, v = (jax.lax.dynamic_slice_in_dim(a, j * lines, lines)
-                .reshape(-1)[:rows * slots].reshape(rows, slots)
-                for a in (places, vals))
+    def ps_keyed_grad_step(w_u, packed, vals, y, mask, bases, j, rows,
+                           row_bits, plan=None, interpret=False):
         yw, m = (jax.lax.dynamic_slice_in_dim(a, j * rows, rows)
                  for a in (y, mask))
-        z = jnp.sum(w_u.at[p].get(mode="promise_in_bounds") * v, axis=-1)
         n = jnp.maximum(jnp.sum(m), 1.0)
-        resid = (jax.nn.sigmoid(z) - yw) * m
-        g = jax.ops.segment_sum(
-            (resid[:, None] * v).reshape(-1), p.reshape(-1),
-            num_segments=w_u.shape[0], mode="promise_in_bounds") / n
+        if plan is not None:
+            from distlr_tpu.ops.pallas_keyed import keyed_sums  # noqa: PLC0415
+
+            sums, entries = keyed_sums(w_u, packed, vals, bases, yw, m, j,
+                                       plan, l2=bool(l2_c),
+                                       interpret=interpret)
+        else:
+            lines = packed.shape[0] // bases.shape[0]
+            pk, v = (jax.lax.dynamic_slice_in_dim(a, j * lines, lines)
+                     .reshape(-1) for a in (packed, vals))
+            p, r = pk >> row_bits, pk & ((1 << row_bits) - 1)
+            keyed = dict(num_segments=w_u.shape[0], indices_are_sorted=True,
+                         mode="promise_in_bounds")
+            z = jax.ops.segment_sum(
+                w_u.at[p].get(mode="promise_in_bounds",
+                              indices_are_sorted=True) * v,
+                r, num_segments=rows, mode="promise_in_bounds")
+            resid = (jax.nn.sigmoid(z) - yw) * m
+            sums = jax.ops.segment_sum(
+                resid.at[r].get(mode="promise_in_bounds") * v, p, **keyed)
+            entries = jax.ops.segment_sum(
+                (v != 0).astype(jnp.float32), p, **keyed) if l2_c else None
+        g = sums / n
         if l2_c:
             # lazily, on the keys some real entry touches
             # (``sparse_batch_grad``): a pad entry's key decays with the
             # entries that name it, not every round
-            active = jax.ops.segment_sum(
-                (v != 0).astype(jnp.float32).reshape(-1), p.reshape(-1),
-                num_segments=w_u.shape[0], mode="promise_in_bounds") > 0
-            term = jnp.float32(l2_c) * w_u * active
+            term = jnp.float32(l2_c) * w_u * (entries > 0)
             g = g + (term / n if l2_scale_by_batch else term)
         return g
 
-    return jax.jit(ps_keyed_grad_step, static_argnames=("rows", "slots"))
+    return jax.jit(ps_keyed_grad_step,
+                   static_argnames=("rows", "row_bits", "plan", "interpret"))
+
+
+@functools.lru_cache(maxsize=None)
+def _keyed_sort_program(windows: int, rows: int, slots: int, row_bits: int):
+    """The jitted ordering of a placed keyed shard: every one of its
+    ``windows`` windows' entries (``[windows * lines, 128]`` as they were
+    put, row-major, ``place << row_bits`` and the values; given up to the
+    call, and as they stay) gets its row within the window beside its
+    place (entry ``i`` of a window is slot ``i % slots`` of row ``i //
+    slots``; what lies behind the ``rows x slots`` is row 0's) and the
+    window is sorted by ``place << row_bits | row`` with the values;
+    and the base row of every chunk from its first entry's place.  Once a
+    worker, at load; its name carries no ``step``: the benchmark finds
+    the step's runs by that."""
+    from distlr_tpu.ops.pallas_keyed import (  # noqa: PLC0415
+        CHUNK_LINES,
+        chunk_base,
+    )
+
+    def ps_keyed_shard_sort(packed, vals):
+        packed, vals = (a.reshape(windows, -1) for a in (packed, vals))
+        at = jax.lax.broadcasted_iota(jax.numpy.int32, packed.shape, 1)
+        row = jax.numpy.where(at < rows * slots, at // slots, 0)
+        packed, vals = jax.lax.sort((packed | row, vals), dimension=1,
+                                    num_keys=1, is_stable=False)
+        first = packed[:, ::CHUNK_LINES * 128] >> row_bits
+        return (packed.reshape(-1, 128), vals.reshape(-1, 128),
+                chunk_base(first))
+
+    return jax.jit(ps_keyed_shard_sort, donate_argnums=(0, 1))
 
 
 #: platforms on which a resident shard's step is the one-pass program
@@ -587,15 +649,17 @@ def _one_pass_plan(model, rows: int, dim: int, device, *, forward=False):
     return softmax_panel_plan(rows, dim, model.num_classes)
 
 
-def _import_kernels_beside_the_load() -> None:
-    """Start importing the row-panel kernels on a thread of their own.
+def _import_kernels_beside_the_load(keyed: bool = False) -> None:
+    """Start importing a worker's kernels on a thread of their own.
     Pallas costs a process 1.5 s to import (read on the v5e's host,
     PERF.md section 6, PR 46), and ``_one_pass_plan`` wants it only once
-    a worker's shard is parsed and densified: on a platform with a
+    a worker's shard is parsed and densified (a keyed worker's sort and
+    plan once its shard is localised and put): on a platform with a
     one-pass program the import runs beside that instead of behind it.
     Whoever needs the modules first waits on the import lock as for any
     import; a failure surfaces there."""
-    name = "distlr_tpu.ops.pallas_softmax"    # imports ``pallas_lr`` too
+    # each imports ``pallas_lr`` too, the keyed one ``pallas_softmax``
+    name = "distlr_tpu.ops." + ("pallas_keyed" if keyed else "pallas_softmax")
     if (name not in sys.modules
             and jax.default_backend() in _ONE_PASS_PLATFORMS):
         threading.Thread(target=importlib.import_module, args=(name,),
@@ -1058,7 +1122,10 @@ class PSWorker:
     step's device and the step is one compiled program there
     (:meth:`_bind_keyed_step`, :meth:`_place_keyed_shard`: each window's
     sorted unique keys and each entry's place among them worked out once,
-    at load; ``distlr_ps_grad_rounds_total{path="keyed_device"}``,
+    at load, and the window's entries sorted by place on the device; on a
+    TPU the step's gather and segment sum are then lookups in tables that
+    stay in VMEM, ``ops/pallas_keyed.py``;
+    ``distlr_ps_grad_rounds_total{path="keyed_device"}``,
     ``distlr_ps_keyed_keys_total``, ``distlr_ps_keyed_rows_total``); the
     other keyed models (``blocked_lr``, ``sparse_softmax``) and every
     small or streamed keyed batch keep numpy's step on the host
@@ -1177,7 +1244,8 @@ class PSWorker:
     profiler trace is taken, a ``TraceAnnotation``) carry ``step`` = the
     worker's round count (:attr:`rounds`) and ``rank``: ``load_data`` and
     ``shard_put`` once (a resident keyed shard: ``localise`` before it,
-    under ``load_data``: every window's unique keys and places); a
+    under ``load_data``: every window's unique keys and places; its
+    ``shard_put`` holds the device's sort of every window by place); a
     round: ``data_load`` (fetching the batch: the
     numpy slice, nothing for a resident shard or a window of one; a
     keyed batch's unique rows, inside the step: an ``np.unique`` of a
@@ -1346,8 +1414,8 @@ class PSWorker:
         self._window_keys: list[np.ndarray] | None = None
         #: and with it: the step's device, the slots a row has, and the
         #: one key count the compiled step takes the pulled vector padded to
-        self._keyed_dev = None
-        self._keyed_slots = self._keyed_key_count = 0
+        self._keyed_dev = self._keyed_bases = self._keyed_program = None
+        self._keyed_row_bits = self._keyed_key_count = 0
         # what the loop's exchange keeps here (``_Exchange``): the flat
         # weights the loop holds now (a span's pull or a fused reply), the
         # staleness stamp of the weights under the next gradient (when
@@ -1520,8 +1588,7 @@ class PSWorker:
         if self._train is not None:
             return
         with self._span("load_data"):
-            if self._grad_fn is not None:
-                _import_kernels_beside_the_load()
+            _import_kernels_beside_the_load(keyed=self._grad_fn is None)
             train = (self._train_iter if self._train_iter is not None
                      else self._load_train_iter())
             test = self._test_iter if self._test_iter is not None else (
@@ -1558,14 +1625,14 @@ class PSWorker:
         self._keyed_rows = _KEYED_ROWS.labels(rank=rank)
         why = self._place_keyed_shard(train)
         if why is None:
+            self.grad_step = self._keyed_device_step(train)
             log.info("rank %d %s steps pinned: train -> %s, a window of the "
                      "resident localised shard a round (%d windows of %d "
-                     "rows, %d keys a step); eval in numpy on the host",
-                     self.rank, cfg.model,
+                     "rows, %d keys a step, program=%s); eval in numpy on "
+                     "the host", self.rank, cfg.model,
                      _describe_compute_device(self._keyed_dev),
                      len(self._window_keys), train.batch_size,
-                     self._keyed_key_count)
-            self.grad_step = self._keyed_device_step(train)
+                     self._keyed_key_count, self._keyed_program)
             return
         log.info("rank %d %s steps and eval run in numpy on the host (%s)",
                  self.rank, cfg.model, why)
@@ -1598,20 +1665,31 @@ class PSWorker:
         ``localise`` span: its sorted unique columns (``_window_keys``,
         as ``RowKeys.keys`` names them on the wire) and, in the ids'
         stead, each entry's place among them (``host_math.localise``, on
-        a few threads).  What a round's ``data_load`` then does is look
-        ``_window_keys[j]`` up.
+        a few threads), ``row_bits`` to the left: room for the entry's
+        row within the window.  What a round's ``data_load`` then does is
+        look ``_window_keys[j]`` up.
 
-        Placement, under ``shard_put``: places (int32) and values
-        (float32) as ``[windows * lines, 128]``, a window's ``B x slots``
-        entries in ``lines`` whole lines of 128 (the TPU would pad a
-        ``[rows, 39]`` array's lanes to 128, 3.3 times the bytes), with
-        zero entries (place 0, value 0: they add nothing and name no key
-        of their own) where a window's entries end inside a line and
-        where the last window is short; labels and real-row flags as
-        float32, ``windows * B`` long.  Each leaf is a plain put in the
-        form it stays in.  The host's row arrays are let go afterwards
+        Placement, under ``shard_put``: the packed indices (int32) and
+        the values (float32) as ``[windows * lines, 128]``, a window's ``B
+        x slots`` entries in ``lines`` lines of 128 (the TPU would pad a
+        ``[rows, 39]`` array's lanes to 128, 3.3 times the bytes; whole
+        grid steps of the step's kernel), with zero entries (place 0,
+        value 0: they add nothing and name no key of their own) behind a
+        window's last; labels and real-row flags as float32, ``windows
+        * B`` long.  Then ONE device program (``_keyed_sort_program``)
+        puts each entry's row beside its place (``place << row_bits |
+        row``), **sorts every window by place** (a sorted window's
+        consecutive entries name consecutive places, which is what lets
+        the step look its weights up in a few rows of their table) and
+        gives each chunk of lines its base row.  The span ends with the
+        leaves put and that program handed to the device, not with its
+        end (0.37 s for 240 windows on the v5e, PERF.md section 6, PR
+        52): it runs beside whatever the host does next (in a job of
+        several workers a process, the next worker's localisation), and
+        the first step queues behind it.  The host's row arrays are let
+        go afterwards
         (``SparseDataIter.drop_rows``).  ``distlr_ps_resident_bytes`` is
-        the four leaves as placed."""
+        those four leaves and the bases as they stay."""
         cfg = self.cfg
         if cfg.model != "sparse_lr":
             return f"no device step is written for {cfg.model}"
@@ -1625,10 +1703,12 @@ class PSWorker:
         if step_dev == "numpy":
             return (f"{B} rows x {slots} slots a step are under the size "
                     "worth a jax dispatch")
+        row_bits = max(B - 1, 1).bit_length()
         device = _jax_device(step_dev)
         windows = train.num_batches
         lanes = 128
-        lines = -(-B * slots // lanes)
+        lines = (-(-B * slots // (lanes * _KEYED_LINE_QUANTUM))
+                 * _KEYED_LINE_QUANTUM)
         nbytes = windows * (lines * lanes * 8 + B * 8)
         free = _device_free_bytes(device)
         if free is not None and free < _KEYED_PLACE_HEADROOM * nbytes:
@@ -1636,7 +1716,7 @@ class PSWorker:
                     f"{_describe_compute_device(step_dev)}")
         n = train.num_samples
         exact = windows * B == n and lines * lanes == B * slots
-        places = np.zeros((windows, lines * lanes), np.int32)
+        packed = np.zeros((windows, lines * lanes), np.int32)
         # whole windows of whole lines are the host's own bytes, seen anew
         values = (np.ascontiguousarray(vals, np.float32).reshape(windows, -1)
                   if exact else np.zeros((windows, lines * lanes), np.float32))
@@ -1644,8 +1724,8 @@ class PSWorker:
 
         def one(j):
             at = slice(j * B, min(j * B + B, n))
-            keys, place = host_math.localise(cols[at], dim)
-            places[j, :place.size] = place.reshape(-1)
+            keys, place = host_math.localise(cols[at], dim, row_bits)
+            packed[j, :place.size] = place.reshape(-1)
             if not exact:
                 values[j, :place.size] = vals[at].reshape(-1)
             return self._rows.keys(keys)
@@ -1656,23 +1736,43 @@ class PSWorker:
             with ThreadPoolExecutor(_LOCALISE_THREADS) as pool:
                 self._window_keys = list(pool.map(one, range(windows)))
         most = max(len(k) for k in self._window_keys)
+        if most - 1 >> 31 - row_bits:
+            self._window_keys = None
+            return (f"a place among {most} keys and a row among {B} do not "
+                    "share an int32")
         self._keyed_key_count = (-(-most // _KEYED_KEY_QUANTUM)
                                  * _KEYED_KEY_QUANTUM)
         below = windows * B - n
-        leaves = (places.reshape(-1, lanes), values.reshape(-1, lanes),
-                  np.pad(np.asarray(y, np.float32), (0, below)),
-                  np.pad(np.asarray(mask, np.float32), (0, below)))
         mesh = make_mesh(devices=[device])
         with self._span("shard_put"):
-            self._resident = jax.block_until_ready(
-                tuple(feed.place(a, mesh) for a in leaves))
-        self._keyed_dev, self._keyed_slots = step_dev, slots
+            packed, values, *rows = jax.block_until_ready(
+                [feed.place(a, mesh) for a in (
+                    packed.reshape(-1, lanes), values.reshape(-1, lanes),
+                    np.pad(np.asarray(y, np.float32), (0, below)),
+                    np.pad(np.asarray(mask, np.float32), (0, below)))])
+            packed, values, self._keyed_bases = _keyed_sort_program(
+                windows, B, slots, row_bits)(packed, values)
+            self._resident = (packed, values, *rows)
+        self._keyed_dev, self._keyed_row_bits = step_dev, row_bits
         self._windowed = True
         _RESIDENT_BYTES.labels(rank=str(self.rank)).set(
-            sum(a.nbytes for a in leaves))
+            sum(a.nbytes for a in (*self._resident, self._keyed_bases)))
         _STEP_DEVICE.labels(rank=str(self.rank)).set(device.id)
         train.drop_rows()
         return None
+
+    def _keyed_plan(self, train):
+        """The kernel's plan for this worker's placed shard, or None
+        where its step stays XLA's: a device that is no TPU
+        (``_ONE_PASS_PLATFORMS``, the dense kernels' rule), or a shape the
+        kernel does not serve (``ops.pallas_keyed.keyed_plan``)."""
+        if _jax_device(self._keyed_dev).platform not in _ONE_PASS_PLATFORMS:
+            return None
+        from distlr_tpu.ops.pallas_keyed import keyed_plan  # noqa: PLC0415
+
+        lines = self._resident[0].shape[0] // self._keyed_bases.shape[0]
+        return keyed_plan(train.batch_size, lines, self._keyed_key_count,
+                          self._keyed_row_bits)
 
     def _keyed_device_step(self, train):
         """The keyed round's device chain, enqueued whole and waited for
@@ -1681,12 +1781,17 @@ class PSWorker:
         (``w_put``), ``jit_ps_keyed_grad_step`` over the round's window
         (``compute``), the readback of the gradient (``grad_d2h``), of
         which the window's own keys' part goes to the push.  The three
-        spans carry the round's key count (``keys``)."""
+        spans carry the round's key count (``keys``), ``compute`` also
+        which program ran: ``program="kernel"`` (the lookups in VMEM,
+        ``_keyed_plan``) or ``"xla"``."""
         cfg = self.cfg
         fn = _compiled_keyed_fns(cfg.l2_c, bool(cfg.l2_scale_by_batch))
         self._jit_probes.append(jaxrt.JitCacheProbe(fn, "train.ps.keyed_grad"))
         step_dev, padded = self._keyed_dev, self._keyed_key_count
-        shape = dict(rows=train.batch_size, slots=self._keyed_slots)
+        shape = dict(rows=train.batch_size, row_bits=self._keyed_row_bits,
+                     plan=self._keyed_plan(train))
+        self._keyed_program = program = (
+            "xla" if shape["plan"] is None else "kernel")
         rank = str(self.rank)
         device_rounds = _GRAD_ROUNDS.labels(rank=rank, path="keyed_device")
         dispatches = {landed: _GRAD_DISPATCHES.labels(
@@ -1700,9 +1805,10 @@ class PSWorker:
                 held = np.zeros(padded, np.float32)
                 held[:keys] = w_u
                 w = jax.device_put(held, step_dev)
-            with self._span("compute", marks_step=True, keys=keys):
+            with self._span("compute", marks_step=True, keys=keys,
+                            program=program):
                 landed = w.is_ready()
-                g = fn(w, *self._resident,
+                g = fn(w, *self._resident, self._keyed_bases,
                        np.int32(window.first // shape["rows"]), **shape)
                 g.copy_to_host_async()
                 # the span ends with this worker's own program and

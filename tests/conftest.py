@@ -45,6 +45,17 @@ Its other clauses (each entry as written, its layer one the benchmark
 had, its file and reader) are held, without the place, by
 ``tests/chipbench/test_sparse_ps_keyed.py::
 test_the_host_readers_entries_are_as_they_were``.
+
+A fifth, of another kind: ``tests/chipbench/test_sparse_ps_keyed.py::
+test_a_faulted_run_is_not_correct[numpy-step]`` (PR 51) puts numpy's step
+in the device step's place by reading the worker's resident places as
+the row-major ``[B, slots]`` array they were (``_keyed_slots``, ``p[at]
+.reshape(B, slots)``); since PR 52 a window's entries lie sorted by
+place, place and row in one int32, and there is no such array to read.
+The fault itself (a numpy step over the resident leaves, counted under
+``keyed_host``: ``host_steps`` fails and every other row holds) is driven
+whole over the sorted leaves by ``tests/test_ps_keyed_device.py::
+test_numpys_step_in_the_device_steps_place_is_counted_as_the_hosts``.
 """
 
 import contextlib
@@ -80,8 +91,18 @@ HELD_TO_THE_END = {
 }
 
 
+#: held to a layout of the program's that a later PR changed
+HELD_TO_THE_ROW_MAJOR_LEAVES = (
+    "test_sparse_ps_keyed.py::test_a_faulted_run_is_not_correct[numpy-step]")
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid.endswith(HELD_TO_THE_ROW_MAJOR_LEAVES):
+            item.add_marker(pytest.mark.xfail(
+                raises=AttributeError, strict=True,
+                reason="reads PR 51's row-major resident places "
+                       "(`_keyed_slots`); PR 52 sorted them by place"))
         for nodeid, pr in HELD_TO_THE_END.items():
             if item.nodeid.endswith(nodeid):
                 item.add_marker(pytest.mark.xfail(

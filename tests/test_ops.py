@@ -508,3 +508,40 @@ def test_the_one_read_softmax_step_compiles_for_a_v5e_at_the_cells_size(chips):
     assert len(re.findall(r" reduce-precision\(", text)) >= 2
     assert "dot(" not in text and "convolution(" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("l2_c", [0.0, 0.5], ids=["no-l2", "l2"])
+def test_the_keyed_step_compiles_for_a_v5e_at_the_cells_size(chips, l2_c):
+    """``jit_ps_keyed_grad_step`` with the lookups' plan, as a worker of
+    the keyed cell runs it (16,384 x 39 entries a window in 4,992 lines,
+    90,112 keys, three of 240 windows resident): Mosaic takes the kernel
+    under the plan's own count of VMEM; the resident entries go to it as
+    they lie (no copy, gather or scatter of them in the program: the two
+    scalar loops are gone) and the gradient leaves as the wire's flat
+    ``f32[90112]``."""
+    from distlr_tpu.ops.pallas_keyed import keyed_plan
+    from distlr_tpu.train import ps_trainer
+
+    rows, lines, keys, bits, windows = 16384, 4992, 90112, 14, 3
+    plan = keyed_plan(rows, lines, keys, bits)
+    assert plan.vmem_bytes <= plan.vmem_limit
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chips[0])
+
+    compiled = ps_trainer._compiled_keyed_fns(l2_c, False).lower(
+        spec((keys,), jnp.float32), spec((windows * lines, 128), jnp.int32),
+        spec((windows * lines, 128), jnp.float32),
+        spec((windows * rows,), jnp.float32),
+        spec((windows * rows,), jnp.float32),
+        spec((windows, lines // 8), jnp.int32), spec((), jnp.int32),
+        rows=rows, row_bits=bits, plan=plan).compile()
+    text = compiled.as_text()
+    entry = text.split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    assert "tpu_custom_call" in text
+    assert not re.search(r" (gather|scatter)\(", text)
+    for big in (f"s32[{windows * lines},128]", f"f32[{windows * lines},128]"):
+        readers = _readers_of_the_parameter(entry, big)
+        assert len(readers) == 1 and "custom-call(" in readers[0], readers
+    assert re.search(rf"ROOT %\S+ = f32\[{keys}\]", entry)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20

@@ -275,16 +275,21 @@ def test_the_flat_step_with_a_plan_is_laid_out_as_the_one_without(rows):
 
 
 # -- the import, beside the load -------------------------------------------------
+@pytest.mark.parametrize("keyed", [False, True], ids=["dense", "keyed"])
 def test_the_kernels_are_imported_beside_the_load_where_a_plan_can_be(
-        monkeypatch):
+        monkeypatch, keyed):
     """Pallas is 1.5 s of import: where the platform has a one-pass
     program a dense worker starts it on a thread of its own before it
-    parses its shard; nothing starts where it is imported already, or on
-    a platform without such a program (the CPU)."""
+    parses its shard (a keyed one, the module of its own kernel, before
+    it localises its); nothing starts where it is imported already, or
+    on a platform without such a program (the CPU)."""
     import sys
     import threading
 
-    name, called, done = "distlr_tpu.ops.pallas_softmax", [], threading.Event()
+    import distlr_tpu.ops.pallas_keyed  # noqa: F401  (so that it can be dropped)
+
+    name = "distlr_tpu.ops." + ("pallas_keyed" if keyed else "pallas_softmax")
+    called, done = [], threading.Event()
 
     def import_module(module):
         called.append((module, threading.current_thread().name))
@@ -294,11 +299,11 @@ def test_the_kernels_are_imported_beside_the_load_where_a_plan_can_be(
                         types.SimpleNamespace(import_module=import_module))
     here = (jax.default_backend(),)
     monkeypatch.setattr(ps_trainer, "_ONE_PASS_PLATFORMS", here)
-    ps_trainer._import_kernels_beside_the_load()     # imported already
+    ps_trainer._import_kernels_beside_the_load(keyed)    # imported already
     monkeypatch.delitem(sys.modules, name)
     monkeypatch.setattr(ps_trainer, "_ONE_PASS_PLATFORMS", ("tpu",))
-    ps_trainer._import_kernels_beside_the_load()     # no such program here
+    ps_trainer._import_kernels_beside_the_load(keyed)    # no such program here
     assert not called
     monkeypatch.setattr(ps_trainer, "_ONE_PASS_PLATFORMS", here)
-    ps_trainer._import_kernels_beside_the_load()
+    ps_trainer._import_kernels_beside_the_load(keyed)
     assert done.wait(5) and called == [(name, "distlr-import-kernels")]

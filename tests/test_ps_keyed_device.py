@@ -6,6 +6,7 @@ stays as it was (the other keyed families, a shuffled or wrapped shard, a
 small step, every dense model's program)."""
 
 import hashlib
+import logging
 import threading
 
 import jax
@@ -14,6 +15,7 @@ import numpy as np
 import pytest
 
 from chipbench import datagen
+from chipbench.drivers.ps_keyed_epochs import shard_bytes
 from chipbench.families import sparse_ps_keyed
 from distlr_tpu.config import Config
 from distlr_tpu.data.hashing import make_uniform_blocked_batch
@@ -25,13 +27,19 @@ from distlr_tpu.data.iterator import (
 from distlr_tpu.models import get_model, host_math
 from distlr_tpu.obs.registry import family_total, get_registry
 from distlr_tpu.obs.tracing import get_tracer
+from distlr_tpu.ops.pallas_keyed import (
+    CHUNK_LINES,
+    TABLE_ROWS,
+    chunk_base,
+    keyed_plan,
+)
 from distlr_tpu.ps import KVWorker, ServerGroup
 from distlr_tpu.train import ps_trainer
 
 DIM, BATCH, SLOTS, LR = 4096, 256, 39, 0.2
 ROWS = 2 * BATCH + 200          # the last window is short
 WINDOWS = 3
-LINES = BATCH * SLOTS // 128    # 9,984 entries are 78 whole lines
+LINES = 128     # 9,984 entries are 78 lines; two whole grid steps hold them
 ROUNDS = "distlr_ps_grad_rounds_total"
 
 
@@ -114,38 +122,81 @@ def test_localise_is_numpys_unique_without_the_sort(dtype, shape):
     assert np.array_equal(places, inverse.reshape(cols.shape))
 
 
+def _leaves(w):
+    """A placed worker's leaves on the host (packed, values, labels,
+    mask, bases), and its row bits."""
+    return ([np.asarray(a) for a in (*w._resident, w._keyed_bases)],
+            w._keyed_row_bits)
+
+
+def _entries(keys, packed, values, bits):
+    """A window's entries as ``(column, row, value)`` in one order."""
+    col = keys[packed >> bits].astype(np.int64)
+    row = packed & ((1 << bits) - 1)
+    order = np.lexsort((values, row, col))
+    return col[order], row[order], values[order]
+
+
 def test_the_shard_is_localised_once_and_held_lane_dense(
         monkeypatch, rows, ps_steps_on_device):
     cols, vals, y = _shard(rows)
     train = SparseDataIter(cols, vals, y, BATCH)
     w = _worker(monkeypatch, train)
     assert w._windowed and len(w._window_keys) == WINDOWS
-    places, values, labels, mask = (np.asarray(a) for a in w._resident)
-    # lane-dense: a window's entries in whole lines of 128, nothing a
-    # [rows, 39] array's lanes would be padded with
-    assert places.shape == values.shape == (WINDOWS * LINES, 128)
-    assert places.dtype == np.int32 and values.dtype == np.float32
+    (packed, values, labels, mask, bases), bits = _leaves(w)
+    # lane-dense: a window's entries in whole lines of 128 (whole grid
+    # steps of the kernel), nothing a [rows, 39] array's lanes would be
+    # padded with; a base row for every chunk of eight lines
+    assert packed.shape == values.shape == (WINDOWS * LINES, 128)
+    assert packed.dtype == np.int32 and values.dtype == np.float32
+    assert bases.shape == (WINDOWS, LINES // CHUNK_LINES)
+    assert bases.dtype == np.int32 and 1 << bits >= BATCH
     assert labels.shape == mask.shape == (WINDOWS * BATCH,)
     assert mask.sum() == ROWS and not mask[ROWS:].any()
     for j, keys in enumerate(w._window_keys):
         at = slice(j * BATCH, min(j * BATCH + BATCH, ROWS))
         assert keys.dtype == np.uint64
         assert np.array_equal(keys, np.unique(cols[at]))
-        n = cols[at].size
-        p = places[j * LINES:(j + 1) * LINES].reshape(-1)
+        p = packed[j * LINES:(j + 1) * LINES].reshape(-1)
         v = values[j * LINES:(j + 1) * LINES].reshape(-1)
-        # the places map back to the columns; what is behind a short
-        # window's entries names no key and adds nothing
-        assert np.array_equal(keys[p[:n]].astype(np.int64),
-                              cols[at].reshape(-1))
-        assert np.array_equal(v[:n], vals[at].reshape(-1))
-        assert not p[n:].any() and not v[n:].any()
+        # sorted by place, so that a chunk's entries name places next to
+        # one another: 16 rows of the weights' table from its base
+        place = p >> bits
+        assert (np.diff(place) >= 0).all()
+        chunks = place.reshape(-1, CHUNK_LINES * 128)
+        assert np.array_equal(bases[j], chunk_base(chunks[:, 0]))
+        assert (chunks < (bases[j][:, None] + TABLE_ROWS) * 128).all()
+        # a permutation of the host's entries, each with its row; what is
+        # put behind them (place 0, value 0; the row a short window's
+        # masked one, or row 0 behind the last row's) names no key of its
+        # own and adds nothing
+        n = cols[at].size
+        behind = np.arange(n, LINES * 128)
+        behind = np.where(behind < BATCH * SLOTS, behind // SLOTS, 0)
+        row = np.broadcast_to(np.arange(at.stop - at.start)[:, None],
+                              cols[at].shape)
+        want = _entries(
+            keys, np.concatenate([behind.astype(np.int32), (
+                np.searchsorted(keys, cols[at]).astype(np.int32) << bits
+                | row).reshape(-1)]),
+            np.concatenate([np.zeros(len(behind), np.float32),
+                            vals[at].reshape(-1)]), bits)
+        for got, held in zip(_entries(keys, p, v, bits), want):
+            assert np.array_equal(got, held)
+    # lines the kernel takes in whole grid steps (on a TPU; here the
+    # step is XLA's over the same leaves)
+    assert keyed_plan(BATCH, LINES, w._keyed_key_count, bits) is not None
+    assert w._keyed_plan(train) is None and w._keyed_program == "xla"
     # one key count for the whole shard: no window compiles
     most = max(len(k) for k in w._window_keys)
     assert w._keyed_key_count % ps_trainer._KEYED_KEY_QUANTUM == 0
     assert most <= w._keyed_key_count < most + ps_trainer._KEYED_KEY_QUANTUM
-    assert _count("distlr_ps_resident_bytes") == (
-        places.nbytes + values.nbytes + labels.nbytes + mask.nbytes)
+    # never under what the benchmark holds a worker to: 8 B an entry in
+    # whole lines, 8 B a row
+    assert _count("distlr_ps_resident_bytes") == sum(
+        a.nbytes for a in (packed, values, bases, labels, mask))
+    assert _count("distlr_ps_resident_bytes") >= shard_bytes(
+        ROWS, BATCH, SLOTS)
     spans = get_tracer().breakdown()
     assert spans["localise"]["count"] == spans["shard_put"]["count"] == 1
     # the host's copy is let go; the iterator serves windows
@@ -156,11 +207,38 @@ def test_the_shard_is_localised_once_and_held_lane_dense(
 
 
 def test_whole_windows_of_whole_lines_are_the_hosts_own_values(
-        monkeypatch, rows, ps_steps_on_device):
-    cols, vals, y = (a[:2 * BATCH] for a in _shard(rows))
-    w = _worker(monkeypatch, SparseDataIter(cols, vals, y, BATCH))
-    assert np.array_equal(np.asarray(w._resident[1]).reshape(-1),
-                          vals.reshape(-1))
+        monkeypatch, ps_steps_on_device):
+    """Where the windows are whole and their entries whole grid steps of
+    lines, nothing is put behind them: a window's values are the host's,
+    in the places' order, and the step is numpy's."""
+    quantum = ps_trainer._KEYED_LINE_QUANTUM
+    batch = quantum * 128               # x 39 slots: 39 whole quanta of lines
+    rng = np.random.default_rng(8)
+    cols = rng.integers(0, DIM, (2 * batch, SLOTS))
+    vals = rng.standard_normal(cols.shape).astype(np.float32)
+    y = rng.integers(0, 2, 2 * batch).astype(np.int32)
+    w = _worker(monkeypatch, SparseDataIter(cols, vals, y, batch),
+                _cfg(batch_size=batch))
+    (packed, values, _labels, mask, bases), bits = _leaves(w)
+    assert packed.shape == (2 * SLOTS * quantum, 128) and mask.all()
+    assert _count("distlr_ps_resident_bytes") - bases.nbytes == shard_bytes(
+        2 * batch, batch, SLOTS)
+    for j in range(2):
+        at = slice(j * batch, (j + 1) * batch)
+        lines = slice(j * SLOTS * quantum, (j + 1) * SLOTS * quantum)
+        assert np.array_equal(np.sort(values[lines].reshape(-1)),
+                              np.sort(vals[at].reshape(-1)))
+        keys, places = np.unique(cols[at], return_inverse=True)
+        p = packed[lines].reshape(-1)
+        place, row = p >> bits, p & ((1 << bits) - 1)
+        # every entry names a column its row has
+        assert (cols[at][row] == keys[place][:, None]).any(axis=1).all()
+        w_u = (rng.standard_normal(len(keys)) * 0.1).astype(np.float32)
+        want = host_math.sparse_batch_grad(
+            w_u, places.reshape(cols[at].shape), vals[at], y[at],
+            np.ones(batch, bool), 0.0, False)
+        got = w.grad_step(w_u, Window(at.start, batch))
+        assert np.linalg.norm(got - want) <= 2e-6 * np.linalg.norm(want)
 
 
 # -- the compiled step --------------------------------------------------------
@@ -337,6 +415,54 @@ def test_four_workers_and_two_servers_conserve_what_was_pushed(
         moved_keys[0])
 
 
+def test_numpys_step_in_the_device_steps_place_is_counted_as_the_hosts(
+        capsys, monkeypatch):
+    """The benchmark's rehearsal whole, with numpy's step over a copy of
+    the resident sorted leaves where the device step stood and counted as
+    the host's: not ``correct``, by ``host_steps`` alone (the gradients
+    are sound, of the right keys; the shard stays where it was put).
+    What ``tests/chipbench/test_sparse_ps_keyed.py``'s ``numpy-step``
+    case drove over PR 51's row-major places (``tests/conftest.py``)."""
+    import json
+
+    from chipbench import run
+
+    real = ps_trainer.PSWorker._keyed_device_step
+
+    def on_the_host(self, train):
+        real(self, train)
+        B, bits = train.batch_size, self._keyed_row_bits
+        packed, v, y, mask = (np.asarray(a) for a in self._resident)
+        lines = len(packed) // len(self._window_keys)
+        counted = ps_trainer._GRAD_ROUNDS.labels(rank=str(self.rank),
+                                                 path="keyed_host")
+
+        def grad_step(w_u, window):
+            j = window.first // B
+            at, rows = slice(j * lines, (j + 1) * lines), slice(j * B, j * B + B)
+            p, vals = packed[at].reshape(-1), v[at].reshape(-1)
+            place, row = p >> bits, p & ((1 << bits) - 1)
+            with self._span("compute", marks_step=True):
+                z = np.bincount(row, weights=w_u[place] * vals, minlength=B)
+                resid = (1 / (1 + np.exp(-z)) - y[rows]) * mask[rows]
+                g = np.bincount(place, weights=resid[row] * vals,
+                                minlength=len(w_u)) / max(mask[rows].sum(), 1)
+            counted.inc()
+            return g.astype(np.float32)
+        return grad_step
+
+    monkeypatch.setattr(ps_trainer.PSWorker, "_keyed_device_step", on_the_host)
+    rc = run.main(["--workload", "sparse-ps-async-keyed-1chip", "--seed",
+                   "3100000052", "--seconds", "0.2", "--trace", "0",
+                   "--rehearse"])
+    last = capsys.readouterr().out.strip().splitlines()[-1]
+    assert rc == 0 and last.startswith("REHEARSAL ")
+    doc = json.loads(last[len("REHEARSAL "):])
+    assert doc["correct"] is False
+    assert {r["name"] for r in doc["compared"] if not r["ok"]} == {
+        "host_steps"}
+
+
 # -- what keeps the host path, bit for bit ------------------------------------
 def _parents_rounds(train, grad, width, table, epochs, l2=(0.0, False)):
     """The keyed loop as the parent of the device step ran it: an
@@ -393,6 +519,31 @@ def test_what_keeps_the_host_path_runs_as_it_did(
     assert _count(ROUNDS, path="keyed_host") - before == 2 * WINDOWS
     want = _parents_rounds(train, grad, width, opening, 2)
     assert np.array_equal(w.kv.table.view(np.uint32), want.view(np.uint32))
+
+
+def test_places_and_rows_that_do_not_share_an_int32_keep_the_host_path(
+        monkeypatch, ps_steps_on_device):
+    """65,536 rows take 16 bits, which leaves a place 15: a window that
+    touches more than 32,768 keys is not packed, and says so."""
+    batch, dim = 1 << 16, 1 << 17
+    rng = np.random.default_rng(6)
+    cols = rng.integers(0, dim, (batch, 2))
+    vals = np.ones(cols.shape, np.float32)
+    y = rng.integers(0, 2, batch).astype(np.int32)
+    said = []
+    handler = logging.Handler()
+    handler.emit = lambda record: said.append(record.getMessage())
+    logger = logging.getLogger(ps_trainer.__name__)
+    logger.addHandler(handler)
+    try:
+        w = _worker(monkeypatch, SparseDataIter(cols, vals, y, batch),
+                    _cfg(batch_size=batch, num_feature_dim=dim))
+    finally:
+        logger.removeHandler(handler)
+    assert w._resident is None and w._window_keys is None
+    assert any("do not share an int32" in line for line in said)
+    assert "shard_put" not in get_tracer().breakdown()
+    w.close()
 
 
 def test_a_small_keyed_step_stays_numpys_by_its_size(monkeypatch, rows):
