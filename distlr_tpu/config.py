@@ -196,7 +196,16 @@ class Config:
     # sparsification via ftrl_l1): the production sparse-CTR optimizer
     # the online-learning loop (distlr_tpu.feedback) trains through.
     # Incompatible with the Q1 sync_last_gradient quirk (an SGD parity
-    # artifact).
+    # artifact).  What an asynchronous keyed job under it is held to
+    # (the benchmark's cell sparse-ps-async-keyed-ftrl-1chip): every
+    # acknowledged push applied exactly once, whole, coordinate by
+    # coordinate on arrival, float32 on the wire and in the servers; a
+    # zero entry steps nothing; with nothing in flight n[k] is the sum
+    # of the squares of every gradient acknowledged for k and w[k] the
+    # closed form of (z[k], n[k]), exactly 0.0 where |z[k]| <= ftrl_l1;
+    # a pull returns exactly the keys asked.  A keyed step pushes the
+    # window's MEAN gradient, so ftrl_alpha and ftrl_l1 are on the
+    # mean's scale, not an example's.
     ps_optimizer: str = "sgd"         # sgd | ftrl
     ftrl_alpha: float = 0.1           # per-coordinate learning-rate scale
     ftrl_beta: float = 1.0            # learning-rate smoothing

@@ -231,7 +231,8 @@ _XCHG = ("xchg_enter", "xchg_send", "xchg_await", "xchg_recv", "xchg_wake",
 #: from before ``run_frames`` fifteen, one from before
 #: ``lock_wait_seconds`` sixteen, one from before the release's fan-out
 #: seventeen, one from before a push's phases nineteen, one from before
-#: ``mapped_frames`` twenty-four; the probe reports what arrived.
+#: ``mapped_frames`` twenty-four, one from before ``ftrl_steps``
+#: twenty-five; the probe reports what arrived.
 STATS_FIELDS = (
     "dim",
     "initialized",
@@ -294,6 +295,13 @@ STATS_FIELDS = (
     # in run_frames): over the rise of the two totals, the share of a
     # job's value-carrying traffic that stayed out of the kernel
     "mapped_frames",
+    # where the per-coordinate FTRL-Proximal step runs (an async push's
+    # apply, a BSP release's apply of the round's mean; zeros from a
+    # server with no FTRL coordinate): the coordinates a step ran on (a
+    # zero gradient entry steps nothing and counts nothing), and of
+    # those the steps whose |z| <= l1 branch left the weight exactly 0.0
+    "ftrl_steps",
+    "ftrl_zeroed",
 )
 
 #: kStats counters of the native servers, refreshed by every kStats read
@@ -305,9 +313,10 @@ _SERVER_STAT = _reg.gauge(
     "distlr_ps_server_stat",
     "latest kStats read (a health probe, or any KVWorker.stats call of "
     "this process) of each native server counter, the stat label one of "
-    "STATS_FIELDS; its newest, mapped_frames: of the operations "
-    "total_pushes and total_pulls count, those whose values crossed in "
-    "their connection's shared mapping and not through the socket",
+    "STATS_FIELDS; its newest, ftrl_steps and ftrl_zeroed: the "
+    "coordinates the server's FTRL-Proximal step ran on, and of those "
+    "the steps that left the weight exactly 0.0 (zeros from a server "
+    "with no FTRL coordinate)",
     labelnames=("rank", "stat"),
 )
 #: Per-handler thread-CPU seconds of the native server ranks, mirrored

@@ -200,8 +200,18 @@ enum class Op : uint8_t {
 // the two totals is the share of a job's value-carrying traffic that
 // stayed out of the kernel (1 for same-host workers of slices of
 // kMappedMinBytes or more, 0 through a proxy or across hosts).
+// Slots 25 and 26 (additive after mapped_frames; zeros from a server
+// with no FTRL coordinate), counted where the per-coordinate
+// FTRL-Proximal step runs (an async push's apply, a BSP release's apply
+// of the round's mean; a zero gradient entry steps nothing and counts
+// nothing): ftrl_steps, the coordinates a step ran on, and ftrl_zeroed,
+// of those the steps whose |z| <= l1 branch left the weight exactly
+// 0.0.  merge_seconds over the rise of ftrl_steps is an asynchronous
+// FTRL server's nanoseconds a step (the apply of scattered rows and the
+// reply's copy with it); ftrl_zeroed over ftrl_steps is the share of
+// steps L1 took to zero.
 constexpr uint64_t kStatsValsV1 = 6;
-constexpr uint64_t kStatsVals = 25;
+constexpr uint64_t kStatsVals = 27;
 
 enum Flags : uint8_t {
   kNone = 0,

@@ -68,6 +68,20 @@
 //          merge scan from re-deriving untouched weights.  Sync mode
 //          applies FTRL to the round's MEAN gradient; async per push.
 //          --last_gradient (the Q1 reference-SGD quirk) is rejected.
+//          What the asynchronous keyed job is held to (the benchmark's
+//          cell sparse-ps-async-keyed-ftrl-1chip, configuration
+//          criteo-ps-async-keyed-ftrl-1m): every acknowledged push is
+//          applied exactly once, whole, coordinate by coordinate by
+//          the four lines above on arrival, float32 on the wire and
+//          here; a zero entry steps nothing; with nothing in flight
+//          n[k] is the sum of the squares of every gradient
+//          acknowledged for k, and w[k] is the closed form of (z[k],
+//          n[k]), exactly 0.0 where |z[k]| <= l1; a key never stepped
+//          keeps z = n = 0 and the weight it was seeded with (init
+//          seeds weights_ and leaves z/n at 0, so a seeded weight is a
+//          warm start the rule forgets at that key's first step).
+//          kStats ftrl_steps / ftrl_zeroed count the steps and those
+//          that left an exact zero (kv_protocol.h slots 25 and 26).
 //   signsgd — majority-vote signSGD (Bernstein et al., arXiv:1802.04434;
 //          the 1-bit-per-coordinate PS aggregation the paper's theory
 //          covers): workers push sign(g) (normally via the kCodecSign
@@ -1137,6 +1151,7 @@ class KVServer {
   // All arithmetic is float32, matching the NumPy oracle the parity
   // tests compare against (tests/test_ftrl.py) operation for operation.
   inline void FtrlStep(Key k, float g) {
+    ++ftrl_steps_;
     const float n_old = nacc_[k];
     const float n_new = n_old + g * g;
     const float sigma =
@@ -1146,6 +1161,7 @@ class KVServer {
     const float z = z_[k];
     if (std::fabs(z) <= fp_.l1) {
       weights_[k] = 0.0f;  // L1 sparsification: the CTR memory saver
+      ++ftrl_zeroed_;
       return;
     }
     const float sgn = z > 0.0f ? 1.0f : -1.0f;
@@ -1763,6 +1779,10 @@ class KVServer {
       // slot 24: of the operations slots 4 and 5 count, those whose
       // values crossed in a connection's mapping
       tail[13] = static_cast<double>(mapped_frames_);
+      // slots 25 and 26: the coordinates an FTRL step ran on, and those
+      // whose step left the weight exactly 0.0
+      tail[14] = static_cast<double>(ftrl_steps_);
+      tail[15] = static_cast<double>(ftrl_zeroed_);
     }
     // slot 23
     tail[12] =
@@ -2608,6 +2628,10 @@ class KVServer {
   //: crossed in their connection's mapping (guarded by mu_; kStats
   //: mapped_frames): a fused push-pull twice, as in run_frames_
   uint64_t mapped_frames_ = 0;
+  //: coordinates FtrlStep ran on, and of those the steps that left the
+  //: weight exactly 0.0 (guarded by mu_; kStats ftrl_steps, ftrl_zeroed)
+  uint64_t ftrl_steps_ = 0;
+  uint64_t ftrl_zeroed_ = 0;
   //: the release's writers (all guarded by wr_mu_): the replies handed
   //: over (the first wr_todo_ not yet taken, wr_left_ not yet written),
   //: the writers' thread-CPU since the last join, and the threads alive

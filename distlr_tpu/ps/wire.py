@@ -91,8 +91,8 @@ STATS_VALS_V1 = 6
 #: BSP barrier's four (rounds, hold, spread, release CPU) + run_frames +
 #: lock_wait_seconds + the release's two (fanned replies, wall) + a
 #: push's five phases (recv, merge, sync wait, release apply, reply write)
-#: + mapped_frames
-STATS_VALS = 25
+#: + mapped_frames + FTRL's two (steps, steps that left an exact zero)
+STATS_VALS = 27
 
 #: wire-corruption guard for vals_per_key (kMaxValsPerKey)
 MAX_VALS_PER_KEY = 4096
