@@ -40,6 +40,25 @@ pass wins, in rounds it loses to two, and either way the weights' lines
 coming back from the cores that copied the replies cost more than the
 arithmetic.
 
+The FTRL-Proximal step (PR 54): an asynchronous server's apply of a
+keyed push, ``--ftrl-keys`` strictly ascending keys of the first size a
+frame, sixteen frames in turn over the three tables (w, z, n), alpha
+0.1, beta 1, l1 1.5e-4 (the cell's rule), stepped five ways: ``scalar``
+(``FtrlStepOne`` a key, what the servers ran until PR 54), ``packed``
+(``FtrlStepPacked`` four keys at a time: the server's walk), and
+``packed+2``, ``packed+4``, ``packed+8`` (the same, asking for the three
+tables' lines of the group that many groups ahead first: not kept).
+Nanoseconds a step (best / median over the frames), the steps, those
+that ended with ``|z| <= l1`` and a hash of w, z and n, which has to be
+the same for every way and in the ``twin`` build (``-O1
+-DDISTLR_SCALAR_LOOPS``: what the sanitizer variants run in
+``FtrlStepPacked``'s place).  Under ``--rounds`` four connection threads
+take turns at the one server's tables, each with a frame of its own and
+fresh values, and after its turn each gathers the weights of another
+thread's keys (the next pull's reply, copied out on another core) and
+copies ``--churn-mb``: ``ftrl_ns`` there is the median over a thread's
+turns.  PR 54's reading on the chip's host is in ``PERF.md`` section 6.
+
 Run on the chip's host: python benchmarks/exp_server_loops.py --rounds 400
 A host number, never a device metric.
 """
@@ -266,6 +285,198 @@ int main(int argc, char** argv) {
 """
 
 
+FTRL = r"""
+// The FTRL-Proximal step of an asynchronous keyed push: kv_server.cc's
+// ApplyFtrlRows over three tables of its own; kAhead < 0 is FtrlStepOne
+// a key, kAhead > 0 asks for the lines of the group that many groups on
+// first (tried for the server in PR 54 and not kept: it bought nothing
+// alone, in --rounds or in the cell).
+#include <algorithm>
+#include <atomic>
+#include <chrono>
+#include <cstdio>
+#include <cstdlib>
+#include <cstring>
+#include <thread>
+#include <vector>
+
+#include "kv_loops.h"
+
+using distlr::FtrlParams;
+using namespace distlr::loops;
+
+static double Now() {
+  return std::chrono::duration<double>(
+             std::chrono::steady_clock::now().time_since_epoch()).count();
+}
+
+struct Tables {
+  std::vector<float> w, z, n;
+  uint64_t steps = 0, zeroed = 0, packed = 0;
+};
+
+template <int kAhead>
+static void Walk(Tables& t, const uint64_t* k, const float* g, uint64_t n,
+                 const FtrlParams& p) {
+  float* const w = t.w.data();
+  float* const z = t.z.data();
+  float* const acc = t.n.data();
+  uint64_t i = 0;
+  if (kAhead >= 0) {
+    for (; i + kLanes <= n; i += kLanes) {
+      if (kAhead > 0 && i + (kAhead + 1) * kLanes <= n) {
+        const uint64_t* const a = k + i + kAhead * kLanes;
+        for (uint64_t j = 0; j < kLanes; ++j) {
+          __builtin_prefetch(acc + a[j], 1);
+          __builtin_prefetch(z + a[j], 1);
+          __builtin_prefetch(w + a[j], 1);
+        }
+      }
+      if (FtrlGroupPacks(k + i, g + i)) {
+        t.zeroed += FtrlStepPacked(w, z, acc, k + i, g + i, p);
+        t.steps += kLanes;
+        t.packed += kLanes;
+      } else {
+        for (uint64_t j = 0; j < kLanes; ++j) {
+          if (g[i + j] == 0.0f) continue;
+          ++t.steps;
+          t.zeroed += FtrlStepOne(w, z, acc, k[i + j], g[i + j], p);
+        }
+      }
+    }
+  }
+  for (; i < n; ++i) {
+    if (g[i] == 0.0f) continue;
+    ++t.steps;
+    t.zeroed += FtrlStepOne(w, z, acc, k[i], g[i], p);
+  }
+}
+
+static void (*const kWalks[])(Tables&, const uint64_t*, const float*,
+                              uint64_t, const FtrlParams&) = {
+    Walk<-1>, Walk<0>, Walk<2>, Walk<4>, Walk<8>};
+static const char* const kNames[] = {"scalar", "packed", "packed+2",
+                                     "packed+4", "packed+8"};
+
+static uint64_t Hash(const std::vector<float>& v, uint64_t h) {
+  for (float f : v) {
+    uint32_t b;
+    std::memcpy(&b, &f, 4);
+    h = (h ^ b) * 1099511628211ull;
+  }
+  return h;
+}
+
+struct Frames {
+  std::vector<std::vector<uint64_t>> k;
+  std::vector<std::vector<float>> g;
+};
+
+// `count` frames of `keys` strictly ascending keys under `dim`, values
+// round the cell's (a window's mean gradient: most far under 1)
+static Frames Make(uint64_t dim, uint64_t keys, int count) {
+  Frames f;
+  uint32_t s = 2463534242u;
+  auto rnd = [&] { s ^= s << 13; s ^= s >> 17; s ^= s << 5; return s; };
+  for (int c = 0; c < count; ++c) {
+    std::vector<uint64_t> k;
+    const double stride = static_cast<double>(dim) / keys;
+    for (uint64_t i = 0; i < keys; ++i) {
+      const uint64_t lo = static_cast<uint64_t>(i * stride);
+      const uint64_t hi = static_cast<uint64_t>((i + 1) * stride);
+      k.push_back(lo + rnd() % std::max<uint64_t>(hi - lo, 1));
+    }
+    std::vector<float> g(keys);
+    for (auto& x : g)
+      x = (static_cast<float>(rnd() >> 8) / 8388608.0f - 1.0f) * 1e-3f;
+    f.k.push_back(std::move(k));
+    f.g.push_back(std::move(g));
+  }
+  return f;
+}
+
+int main(int argc, char** argv) {
+  if (argc != 7) return 2;
+  const uint64_t dim = std::strtoull(argv[1], nullptr, 10);
+  const uint64_t keys = std::strtoull(argv[2], nullptr, 10);
+  const int how = std::atoi(argv[3]);
+  const int reps = std::atoi(argv[4]);    // alone: passes over the frames
+  const int rounds = std::atoi(argv[5]);  // > 0: four threads in turn
+  const size_t churn = std::strtoull(argv[6], nullptr, 10) << 20;
+  const FtrlParams p{0.1f, 1.0f, 1.5e-4f, 0.0f};
+  Tables t;
+  t.w.assign(dim, 0.0f);
+  t.z.assign(dim, 0.0f);
+  t.n.assign(dim, 0.0f);
+  const auto walk = kWalks[how];
+  std::vector<double> ns;
+  if (rounds == 0) {
+    Frames f = Make(dim, keys, 16);
+    for (int r = 0; r < reps; ++r) {
+      for (size_t c = 0; c < f.k.size(); ++c) {
+        f.g[c][r % keys] += 1e-7f;  // fresh bytes, as a frame's are
+        const uint64_t before = t.steps;
+        const double t0 = Now();
+        walk(t, f.k[c].data(), f.g[c].data(), keys, p);
+        ns.push_back(1e9 * (Now() - t0) / (t.steps - before));
+      }
+    }
+  } else {
+    const int W = 4;
+    Frames f = Make(dim, keys, W);
+    std::vector<std::vector<float>> reply(W, std::vector<float>(keys));
+    std::vector<std::vector<char>> a(W, std::vector<char>(churn + 1)),
+        b(W, std::vector<char>(churn + 1));
+    std::vector<std::vector<double>> mine(W);
+    std::atomic<int> turn{0}, done{0}, round{0};
+    auto run = [&](int r) {
+      for (int k = 0; k < rounds; ++k) {
+        while (round.load() != k) {}
+        // the "worker": this round's gradient, written on this core
+        for (uint64_t j = 0; j < keys; j += 16) f.g[r][j] += 1e-7f;
+        if (churn) std::memcpy(a[r].data(), b[r].data(), churn);
+        const int at = (r + k) % W;  // the order of arrival turns with k
+        while (turn.load() != at) {}
+        const uint64_t before = t.steps;
+        const double t0 = Now();
+        walk(t, f.k[r].data(), f.g[r].data(), keys, p);
+        mine[r].push_back(1e9 * (Now() - t0) / (t.steps - before));
+        turn.store(at + 1);
+        while (turn.load() != W) {}
+        // the next pull: another worker's keys, copied out on this core
+        const std::vector<uint64_t>& other = f.k[(r + 1) % W];
+        for (uint64_t j = 0; j < keys; ++j) reply[r][j] = t.w[other[j]];
+        if (done.fetch_add(1) + 1 == W) {
+          done.store(0);
+          turn.store(0);
+          round.store(k + 1);
+        }
+      }
+    };
+    std::vector<std::thread> th;
+    for (int r = 0; r < W; ++r) th.emplace_back(run, r);
+    for (auto& x : th) x.join();
+    for (auto& v : mine) ns.insert(ns.end(), v.begin(), v.end());
+  }
+  std::sort(ns.begin(), ns.end());
+  std::printf("dim=%llu keys=%llu rounds=%d churn_mb=%zu step=%s "
+              "ftrl_ns=%.2f/%.2f steps=%llu zeroed=%llu packed=%llu "
+              "hash=%016llx\n",
+              static_cast<unsigned long long>(dim),
+              static_cast<unsigned long long>(keys), rounds, churn >> 20,
+              kNames[how], ns.front(), ns[ns.size() / 2],
+              static_cast<unsigned long long>(t.steps),
+              static_cast<unsigned long long>(t.zeroed),
+              static_cast<unsigned long long>(t.packed),
+              static_cast<unsigned long long>(Hash(
+                  t.n, Hash(t.z, Hash(t.w, 14695981039346656037ull)))));
+  return 0;
+}
+"""
+
+FTRL_STEPS = ("scalar", "packed", "packed+2", "packed+4", "packed+8")
+
+
 def shipped_flags() -> list[str]:
     """The ``CXXFLAGS ?=`` line of the servers' Makefile."""
     with open(os.path.join(NATIVE, "Makefile")) as f:
@@ -283,6 +494,8 @@ def main() -> int:
                          "threads at one merge buffer (medians)")
     ap.add_argument("--churn-mb", default="0,16",
                     help="MB each thread copies between rounds")
+    ap.add_argument("--ftrl-keys", type=int, default=44000,
+                    help="keys of an FTRL frame (0: leave the FTRL rows out)")
     args = ap.parse_args()
     sizes = args.sizes.split(",")
     cxx = shutil.which(os.environ.get("CXX", "g++"))
@@ -330,6 +543,31 @@ def main() -> int:
                              "4", churn, how], check=True,
                             capture_output=True, text=True).stdout.strip()
                         print(f"{name:8s} {out}")
+        if args.ftrl_keys:
+            src = os.path.join(tmp, "ftrl_main.cc")
+            with open(src, "w") as f:
+                f.write(FTRL)
+            ftrl_builds = {"shipped": shipped,
+                           "twin": ["-O1", "-DDISTLR_SCALAR_LOOPS",
+                                    "-ffp-contract=off"]}
+            for name, flags in ftrl_builds.items():
+                exe = os.path.join(tmp, f"ftrl_{name}")
+                subprocess.run([cxx, *base, *flags, "-o", exe, src],
+                               check=True)
+                runs = [("0", "0")] + [
+                    (str(args.rounds), churn)
+                    for churn in args.churn_mb.split(",") if args.rounds]
+                for rounds, churn in runs:
+                    for how in range(len(FTRL_STEPS)):
+                        out = subprocess.run(
+                            [exe, sizes[0], str(args.ftrl_keys), str(how),
+                             str(max(args.reps // 10, 2)), rounds, churn],
+                            check=True, capture_output=True,
+                            text=True).stdout.strip()
+                        print(f"{name:8s} {out}")
+                        hashes.setdefault(f"ftrl rounds={rounds}",
+                                          set()).add(
+                            out.rsplit("hash=", 1)[1])
     same = all(len(h) == 1 for h in hashes.values())
     print(f"hashes_agree={same}")
     return 0 if same else 1

@@ -232,7 +232,8 @@ _XCHG = ("xchg_enter", "xchg_send", "xchg_await", "xchg_recv", "xchg_wake",
 #: ``lock_wait_seconds`` sixteen, one from before the release's fan-out
 #: seventeen, one from before a push's phases nineteen, one from before
 #: ``mapped_frames`` twenty-four, one from before ``ftrl_steps``
-#: twenty-five; the probe reports what arrived.
+#: twenty-five, one from before ``ftrl_packed_steps`` twenty-seven; the
+#: probe reports what arrived.
 STATS_FIELDS = (
     "dim",
     "initialized",
@@ -302,6 +303,11 @@ STATS_FIELDS = (
     # those the steps whose |z| <= l1 branch left the weight exactly 0.0
     "ftrl_steps",
     "ftrl_zeroed",
+    # of ftrl_steps, those an async keyed push of single-value rows took
+    # four at a time (kv_loops.h FtrlStepPacked: a group of four keys
+    # strictly ascending, no zero entry, one optimizer); over ftrl_steps,
+    # the share of the rule's work done four lanes wide
+    "ftrl_packed_steps",
 )
 
 #: kStats counters of the native servers, refreshed by every kStats read
@@ -313,10 +319,11 @@ _SERVER_STAT = _reg.gauge(
     "distlr_ps_server_stat",
     "latest kStats read (a health probe, or any KVWorker.stats call of "
     "this process) of each native server counter, the stat label one of "
-    "STATS_FIELDS; its newest, ftrl_steps and ftrl_zeroed: the "
-    "coordinates the server's FTRL-Proximal step ran on, and of those "
-    "the steps that left the weight exactly 0.0 (zeros from a server "
-    "with no FTRL coordinate)",
+    "STATS_FIELDS; ftrl_steps and ftrl_zeroed: the coordinates the "
+    "server's FTRL-Proximal step ran on, and of those the steps that "
+    "left the weight exactly 0.0 (zeros from a server with no FTRL "
+    "coordinate); its newest, ftrl_packed_steps: of ftrl_steps, those "
+    "an asynchronous keyed push took four coordinates at a time",
     labelnames=("rank", "stat"),
 )
 #: Per-handler thread-CPU seconds of the native server ranks, mirrored

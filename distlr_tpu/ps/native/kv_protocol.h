@@ -210,8 +210,15 @@ enum class Op : uint8_t {
 // FTRL server's nanoseconds a step (the apply of scattered rows and the
 // reply's copy with it); ftrl_zeroed over ftrl_steps is the share of
 // steps L1 took to zero.
+// Slot 27 (additive after ftrl_zeroed; zero from a server with no FTRL
+// coordinate): ftrl_packed_steps, of the steps ftrl_steps counts, those
+// an asynchronous keyed push of single-value rows took in groups of four
+// (kv_loops.h FtrlStepPacked: four keys strictly ascending, no entry
+// 0.0, no --opt_segments boundary among them); the rest went a
+// coordinate at a time.  ftrl_packed_steps over ftrl_steps is the share
+// of the rule's work done four lanes wide.
 constexpr uint64_t kStatsValsV1 = 6;
-constexpr uint64_t kStatsVals = 27;
+constexpr uint64_t kStatsVals = 28;
 
 enum Flags : uint8_t {
   kNone = 0,

@@ -82,6 +82,12 @@
 //          warm start the rule forgets at that key's first step).
 //          kStats ftrl_steps / ftrl_zeroed count the steps and those
 //          that left an exact zero (kv_protocol.h slots 25 and 26).
+//          Such a push's coordinates are stepped four at a time
+//          where its frame allows (ApplyFtrlRows; kv_loops.h
+//          FtrlStepPacked, the same operations a lane, the same bits;
+//          kStats ftrl_packed_steps, slot 27, counts them); the
+//          lock-step release, WAL replay and rows wider than one value
+//          step a coordinate at a time.
 //   signsgd — majority-vote signSGD (Bernstein et al., arXiv:1802.04434;
 //          the 1-bit-per-coordinate PS aggregation the paper's theory
 //          covers): workers push sign(g) (normally via the kCodecSign
@@ -221,13 +227,6 @@ struct MappedConn {
     memfd = -1;
     seg.Unmap();
   }
-};
-
-struct FtrlParams {
-  float alpha = 0.1f;
-  float beta = 1.0f;
-  float l1 = 0.0f;
-  float l2 = 0.0f;
 };
 
 // Server-side update rule (--optimizer); kSign is the majority-vote
@@ -1147,26 +1146,53 @@ class KVServer {
     }
   }
 
-  // One coordinate's FTRL-Proximal step (caller holds mu_; g != 0).
-  // All arithmetic is float32, matching the NumPy oracle the parity
-  // tests compare against (tests/test_ftrl.py) operation for operation.
+  // One coordinate's FTRL-Proximal step (caller holds mu_; g != 0):
+  // loops::FtrlStepOne (kv_loops.h) on this server's three tables.
   inline void FtrlStep(Key k, float g) {
     ++ftrl_steps_;
-    const float n_old = nacc_[k];
-    const float n_new = n_old + g * g;
-    const float sigma =
-        (std::sqrt(n_new) - std::sqrt(n_old)) / fp_.alpha;
-    z_[k] += g - sigma * weights_[k];
-    nacc_[k] = n_new;
-    const float z = z_[k];
-    if (std::fabs(z) <= fp_.l1) {
-      weights_[k] = 0.0f;  // L1 sparsification: the CTR memory saver
-      ++ftrl_zeroed_;
-      return;
+    ftrl_zeroed_ += loops::FtrlStepOne(weights_.data(), z_.data(),
+                                       nacc_.data(), k, g, fp_);
+  }
+
+  // Whether every key of [first, last] steps under FTRL with no
+  // --opt_segments boundary between them (ApplySpan's rule: keys <
+  // end_i use opt_i, the rest opt_).
+  bool FtrlGoverns(Key first, Key last) const {
+    for (const auto& seg : opt_segments_) {
+      if (first < seg.first)
+        return seg.second == Opt::kFtrl && last < seg.first;
     }
-    const float sgn = z > 0.0f ? 1.0f : -1.0f;
-    weights_[k] = -(z - sgn * fp_.l1) /
-                  ((fp_.beta + std::sqrt(n_new)) / fp_.alpha + fp_.l2);
+    return opt_ == Opt::kFtrl;
+  }
+
+  // An asynchronous keyed push of single-value rows that are no run, on
+  // a server with an FTRL coordinate (caller holds mu_): ApplySpan a key
+  // in frame order, but four keys at a time (loops::FtrlStepPacked: the
+  // same operations a lane, the same bits) wherever a group of four of
+  // the frame allows it: keys strictly ascending and no entry 0.0
+  // (loops::FtrlGroupPacks), all four under FTRL with no boundary of
+  // --opt_segments among them.  Any other group, and the frame's tail,
+  // goes through ApplySpan as before.  No prefetch of the groups ahead:
+  // tried 2, 4 and 8 groups on and it bought nothing, alone, with four
+  // threads taking turns, or in the cell (PERF.md section 6, PR 54;
+  // benchmarks/exp_server_loops.py has the rows).
+  void ApplyFtrlRows(const Key* k, const Val* g, uint64_t n) {
+    Val* const w = weights_.data();
+    Val* const z = z_.data();
+    Val* const acc = nacc_.data();
+    uint64_t i = 0;
+    for (; i + loops::kLanes <= n; i += loops::kLanes) {
+      if (loops::FtrlGroupPacks(k + i, g + i) &&
+          FtrlGoverns(k[i], k[i + loops::kLanes - 1])) {
+        ftrl_zeroed_ += loops::FtrlStepPacked(w, z, acc, k + i, g + i, fp_);
+        ftrl_steps_ += loops::kLanes;
+        ftrl_packed_steps_ += loops::kLanes;
+      } else {
+        for (uint64_t j = 0; j < loops::kLanes; ++j)
+          ApplySpan(k[i + j], g + i + j, 1);
+      }
+    }
+    for (; i < n; ++i) ApplySpan(k[i], g + i, 1);
   }
 
   // Apply the gradient values g[0, n) to the consecutive coordinates
@@ -1441,9 +1467,13 @@ class KVServer {
     if (!sync_) {
       // Async/Hogwild: apply immediately (src/main.cc:79-84) under the
       // configured optimizer (SGD or per-coordinate FTRL-Proximal).
-      rows.ForSpans([&](Key s, uint64_t at, uint64_t n) {
-        ApplySpan(s, vals + at, n);
-      });
+      if (has_ftrl_ && !rows.run && rows.vpk == 1) {
+        ApplyFtrlRows(rows.keys, vals, rows.num_keys);
+      } else {
+        rows.ForSpans([&](Key s, uint64_t at, uint64_t n) {
+          ApplySpan(s, vals + at, n);
+        });
+      }
       // empty "present" votes are logged too: the WAL clock must track
       // n_push_ exactly or the RPO push-clock audit would drift
       WalAppend(n_push_, 0, Op::kPush, rows, vals, rows.flat());
@@ -1783,6 +1813,8 @@ class KVServer {
       // whose step left the weight exactly 0.0
       tail[14] = static_cast<double>(ftrl_steps_);
       tail[15] = static_cast<double>(ftrl_zeroed_);
+      // slot 27: of the steps slot 25 counts, those taken four at a time
+      tail[16] = static_cast<double>(ftrl_packed_steps_);
     }
     // slot 23
     tail[12] =
@@ -2632,6 +2664,9 @@ class KVServer {
   //: weight exactly 0.0 (guarded by mu_; kStats ftrl_steps, ftrl_zeroed)
   uint64_t ftrl_steps_ = 0;
   uint64_t ftrl_zeroed_ = 0;
+  //: of ftrl_steps_, the steps ApplyFtrlRows took in groups of four
+  //: (guarded by mu_; kStats ftrl_packed_steps)
+  uint64_t ftrl_packed_steps_ = 0;
   //: the release's writers (all guarded by wr_mu_): the replies handed
   //: over (the first wr_todo_ not yet taken, wr_left_ not yet written),
   //: the writers' thread-CPU since the last join, and the threads alive

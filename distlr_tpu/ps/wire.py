@@ -91,8 +91,9 @@ STATS_VALS_V1 = 6
 #: BSP barrier's four (rounds, hold, spread, release CPU) + run_frames +
 #: lock_wait_seconds + the release's two (fanned replies, wall) + a
 #: push's five phases (recv, merge, sync wait, release apply, reply write)
-#: + mapped_frames + FTRL's two (steps, steps that left an exact zero)
-STATS_VALS = 27
+#: + mapped_frames + FTRL's three (steps, steps that left an exact zero,
+#: steps taken four at a time)
+STATS_VALS = 28
 
 #: wire-corruption guard for vals_per_key (kMaxValsPerKey)
 MAX_VALS_PER_KEY = 4096

@@ -78,7 +78,7 @@ class TestWireParity:
         hdr = wire_parity.parse_header()
         assert hdr["kMagic"][0] == 0xD157C0DE
         assert hdr["kEpoch"][0] == 8
-        assert hdr["kStatsVals"][0] == 27
+        assert hdr["kStatsVals"][0] == 28
         assert hdr["kCapEpoch"][0] == 1 << 9       # 1ull << evaluation
         assert hdr["sizeof(MsgHeader)"][0] == 24   # static_assert twin
 

@@ -559,7 +559,8 @@ class TestNativeCpu:
         membership round appended the epoch slot, 15 since the BSP
         barrier's tail, 16 since run_frames, 17 since lock_wait_seconds,
         19 since the release's fan-out, 24 since a push's phases, 25
-        since ``mapped_frames``, 27 since FTRL's two)."""
+        since ``mapped_frames``, 27 since FTRL's two, 28 since
+        ``ftrl_packed_steps``)."""
         import socket
         import struct
 
@@ -573,7 +574,7 @@ class TestNativeCpu:
                 for aux, expect_slots in ((0, 12), (10, 20), (11, 22),
                                           (15, 30), (16, 32), (17, 34),
                                           (19, 38), (24, 48), (25, 50),
-                                          (27, 54), (64, 54)):
+                                          (27, 54), (28, 56), (64, 56)):
                     s.sendall(struct.pack("<IBBHIIQ", 0xD157C0DE, 6, 0,
                                           aux, 1, 1, 0))
                     hdr = s.recv(24, socket.MSG_WAITALL)

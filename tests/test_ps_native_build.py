@@ -11,6 +11,12 @@ that drifts (an ``-march``, a ``target_clones`` without
 and not as an ulp somewhere or a millisecond on the chip.
 ``tests/test_ps_apply_bits.py`` holds the same binary's results to
 NumPy's.
+
+The FTRL-Proximal step of a keyed push (``FtrlStepPacked``, PR 54) is
+spelled in SSE2 intrinsics, not left to the vectoriser: the release
+build's holds the packed square root and divide, the sanitizer builds'
+(``-DDISTLR_SCALAR_LOOPS`` on the ``SANFLAGS`` line) hold the scalar
+ones, and neither may fuse.
 """
 
 from __future__ import annotations
@@ -35,7 +41,10 @@ LOOPS = {
     "SgdStepPacked": ("mulps", "subps"),
     "MergeAddPacked": ("addps",),
     "MeanStepPacked": ("mulps", "divps", "subps"),
+    # four coordinates of a keyed push under FTRL-Proximal
+    "FtrlStepPacked": ("sqrtps", "divps", "mulps", "addps", "subps"),
 }
+PACKED = {"mulps", "divps", "subps", "addps", "sqrtps"}
 FUSED = re.compile(r"\bvf(n?m(add|sub)|maddsub|msubadd)\d*[ps][sd]\b")
 
 
@@ -131,9 +140,24 @@ def test_sanitizer_build_keeps_the_loop_scalar(ubsan, loop):
     (tests/test_ps_apply_bits.py): if they packed too, that comparison
     would hold a build to itself."""
     assert loop in ubsan
-    packed = {"mulps", "divps", "subps", "addps"} & _mnemonics(ubsan[loop])
+    packed = PACKED & _mnemonics(ubsan[loop])
     assert not packed, f"{loop} is packed in the ubsan build: {packed}"
     assert not [ins for ins in ubsan[loop] if FUSED.search(ins)]
+
+
+def test_the_sanitizer_builds_ftrl_step_is_the_scalar_one(ubsan):
+    """What stands in the packed step's place there is ``FtrlStepOne``
+    four times over: scalar square roots and divides."""
+    assert {"sqrtss", "divss"} <= _mnemonics(ubsan["FtrlStepPacked"])
+
+
+def test_sanflags_define_what_keeps_the_twins_scalar():
+    assert "-DDISTLR_SCALAR_LOOPS" in _flags("SANFLAGS")
+    assert "-DDISTLR_SCALAR_LOOPS" not in _flags("CXXFLAGS")
+    with open(os.path.join(native_dir(), "kv_loops.h")) as f:
+        header = f.read()
+    assert "!defined(DISTLR_SCALAR_LOOPS)" in header
+    assert "<emmintrin.h>" in header and "immintrin" not in header
 
 
 @pytest.mark.parametrize("var", ["CXXFLAGS", "SANFLAGS"])

@@ -53,12 +53,12 @@ def _job(group, sync, late, op="push_pull", rounds=ROUNDS):
     return [{k: a[k] - b[k] for k in a} for b, a in zip(before, after)]
 
 
-def test_the_five_stand_before_the_last_three_in_the_wires_order():
-    assert STATS_FIELDS[-8:-3] == PHASES
-    assert STATS_FIELDS[-9] == "release_wall_seconds"
-    assert STATS_FIELDS[-3:] == ("mapped_frames", "ftrl_steps",
-                                 "ftrl_zeroed")
-    assert len(STATS_FIELDS) == wire.STATS_VALS == 27
+def test_the_five_stand_before_the_last_four_in_the_wires_order():
+    assert STATS_FIELDS[-9:-4] == PHASES
+    assert STATS_FIELDS[-10] == "release_wall_seconds"
+    assert STATS_FIELDS[-4:] == ("mapped_frames", "ftrl_steps",
+                                 "ftrl_zeroed", "ftrl_packed_steps")
+    assert len(STATS_FIELDS) == wire.STATS_VALS == 28
 
 
 def test_three_wait_for_the_one_held_back():
@@ -193,7 +193,7 @@ def test_a_client_from_before_the_five_gets_the_nineteen_it_asks_for():
             kv.push_pull(np.ones(64, np.float32))
         with socket.create_connection(("127.0.0.1", g.ports[0])) as s:
             for aux, slots in ((19, 19), (22, 22), (24, 24), (25, 25),
-                               (27, 27), (200, 27)):
+                               (27, 27), (28, 28), (200, 28)):
                 s.sendall(wire.HEADER_STRUCT.pack(
                     wire.MAGIC, wire.OP_STATS, 0, aux, 7, 1, 0))
                 hdr = s.recv(wire.HEADER_STRUCT.size, socket.MSG_WAITALL)

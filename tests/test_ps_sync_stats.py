@@ -51,7 +51,7 @@ def _rounds(group, sync, delays):
 
 
 def test_the_tail_stands_after_epoch_in_the_wires_order():
-    assert STATS_FIELDS[-len(TAIL) - 12:] == TAIL + (
+    assert STATS_FIELDS[-len(TAIL) - 13:] == TAIL + (
         "run_frames", "lock_wait_seconds",
         "release_fanned_replies", "release_wall_seconds",
         # a push's phases (test_ps_push_phases.py has what they count)
@@ -59,10 +59,10 @@ def test_the_tail_stands_after_epoch_in_the_wires_order():
         "release_apply_seconds", "reply_write_seconds",
         # values that crossed in a mapping (test_ps_mapped_payload.py)
         "mapped_frames",
-        # the FTRL step's two (test_ps_ftrl_stats.py)
-        "ftrl_steps", "ftrl_zeroed")
-    assert STATS_FIELDS[-len(TAIL) - 13] == "epoch"
-    assert len(STATS_FIELDS) == wire.STATS_VALS == 27
+        # the FTRL step's three (test_ps_ftrl_stats.py)
+        "ftrl_steps", "ftrl_zeroed", "ftrl_packed_steps")
+    assert STATS_FIELDS[-len(TAIL) - 14] == "epoch"
+    assert len(STATS_FIELDS) == wire.STATS_VALS == 28
 
 
 @pytest.mark.parametrize("sync", [True, False], ids=["bsp", "async"])
@@ -161,8 +161,8 @@ def test_a_request_of_the_old_length_is_still_answered():
     ``lock_wait_seconds`` sixteen, one from before the release's fan-out
     seventeen, one from before a push's phases nineteen, one from before
     ``mapped_frames`` twenty-four, one from before ``ftrl_steps``
-    twenty-five; one that asks for more than there are gets what there
-    is."""
+    twenty-five, one from before ``ftrl_packed_steps`` twenty-seven; one
+    that asks for more than there are gets what there is."""
     with ServerGroup(1, 1, DIM, sync=False) as g:
         with KVWorker(g.hosts, DIM, client_id=0, sync_group=False) as kv:
             kv.wait(kv.push_init(np.ones(DIM, np.float32)))
@@ -170,7 +170,7 @@ def test_a_request_of_the_old_length_is_still_answered():
         with socket.create_connection(("127.0.0.1", g.ports[0])) as s:
             for aux, slots in ((15, 15), (16, 16), (17, 17), (18, 18),
                                (19, 19), (24, 24), (25, 25), (27, 27),
-                               (99, 27)):
+                               (28, 28), (99, 28)):
                 s.sendall(wire.HEADER_STRUCT.pack(
                     wire.MAGIC, wire.OP_STATS, 0, aux, 7, 1, 0))
                 hdr = s.recv(wire.HEADER_STRUCT.size, socket.MSG_WAITALL)
@@ -188,7 +188,8 @@ def test_a_request_of_the_old_length_is_still_answered():
                 assert ("release_wall_seconds" in named) == (slots >= 19)
                 assert ("reply_write_seconds" in named) == (slots >= 24)
                 assert ("mapped_frames" in named) == (slots >= 25)
-                assert ("ftrl_zeroed" in named) == (slots == 27)
+                assert ("ftrl_zeroed" in named) == (slots >= 27)
+                assert ("ftrl_packed_steps" in named) == (slots == 28)
                 # an SGD server steps no FTRL coordinate
                 assert named.get("ftrl_steps", 0.0) == 0.0
                 # an async server: the release's two read zero
