@@ -3,9 +3,11 @@
 The same bidirectional style as the metrics-doc lint (ISSUE 8), applied
 to the configuration surface: every :class:`distlr_tpu.config.Config`
 field must be reachable from the ``launch`` CLI (an ``add_argument``
-whose dest is the field, an audited alias, or an audited NO_FLAG entry
-saying WHY not) and documented in the generated ``docs/CONFIG.md``; and
-every doc row / audit entry must still correspond to a live field.
+whose dest is the field, or an audited NO_FLAG entry saying WHY not)
+and documented in the generated ``docs/CONFIG.md``; and every doc row /
+audit entry must still correspond to a live field.  A daemon's own
+options are not fields: they are its subcommand's flags, which the
+document names in a line each under the table.
 Everything is read statically (``ast`` — no jax, no argparse import).
 
 Regenerate the doc after changing Config or the CLI::
@@ -20,36 +22,6 @@ import os
 import re
 
 from distlr_tpu.analysis.report import Finding, repo_root
-
-#: Config field -> the launch flag DEST that carries it when they are
-#: deliberately named differently (subcommand-scoped flags predating the
-#: serve_*/route_* prefixes).  An alias naming a dead dest or a dead
-#: field is itself a finding.
-FLAG_ALIASES = {
-    "serve_port": "port",
-    "serve_host": "bind",
-    "serve_max_wait_ms": "max_wait_ms",
-    "serve_reload_interval_s": "reload_interval",
-    "serve_hot_rows": "hot_rows",
-    "serve_hot_min_coverage": "hot_min_coverage",
-    "serve_hot_full_every": "hot_full_every",
-    "serve_engine_idle_evict_s": "engine_idle_evict",
-    "feedback_spool_dir": "feedback_spool",
-    "feedback_shard_dir": "feedback_shards",
-    "feedback_window_s": "feedback_window",
-    "feedback_drift_block": "drift_block",
-    "feedback_drift_threshold": "drift_threshold",
-    "serve_model_id": "model_id",
-    "route_quota": "quota",
-    "route_port": "port",
-    "route_host": "bind",
-    "route_max_inflight": "max_inflight",
-    "route_eject_after": "eject_after",
-    "route_health_interval_s": "health_interval",
-    "route_probe_backoff_s": "probe_backoff",
-    "route_probe_backoff_max_s": "probe_backoff_max",
-    "route_backend_timeout_s": "backend_timeout",
-}
 
 #: Config fields with deliberately NO CLI flag, each with the audit
 #: reason (an entry for a field that gained a flag, or stopped
@@ -72,11 +44,6 @@ NO_FLAG = {
                      "tuning, not an operator knob",
     "mesh_shape": "derived from --num-workers x --feature-shards "
                   "(_config_from_args), never set directly",
-    "ps_host": "reference env-var contract (DMLC_PS_ROOT_URI via "
-               "Config.from_env); local launches use ephemeral ports "
-               "and multi-host passes explicit --hosts",
-    "ps_port": "reference env-var contract (DMLC_PS_ROOT_PORT), same "
-               "as ps_host",
 }
 
 
@@ -186,12 +153,7 @@ def documented_fields(text: str | None = None) -> dict[str, str]:
 
 
 def _flag_for(field: str, dests: dict[str, dict]) -> str | None:
-    if field in dests:
-        return dests[field]["flag"]
-    alias = FLAG_ALIASES.get(field)
-    if alias is not None and alias in dests:
-        return dests[alias]["flag"]
-    return None
+    return dests[field]["flag"] if field in dests else None
 
 
 def generate() -> str:
@@ -228,7 +190,20 @@ def generate() -> str:
         default = meta["default"].replace("|", "\\|")
         lines.append(
             f"| `{name}` | {flag_txt} | `{default}` | {help_txt} |")
-    lines.append("")
+    lines += [
+        "",
+        "What only one daemon reads is not a `Config` field but a flag of its",
+        "subcommand, whose default is the receiving constructor's:",
+        "",
+        "- `launch serve --help`: the scoring server, its hot reload and "
+        "the feedback loop.",
+        "- `launch route --help`: the serving router.",
+        "- `launch autopilot --help`: the scaling daemon's tick, bands and "
+        "bounds.",
+        "- `launch obs-agg --help`: the aggregator's alert thresholds, SLO "
+        "file and embedded tsdb.",
+        "",
+    ]
     return "\n".join(lines)
 
 
@@ -260,22 +235,10 @@ def check() -> list[Finding]:
             findings.append(Finding(
                 "config", f"config-no-flag:{name}",
                 f"Config.{name} has no launch flag (no dest matches, no "
-                "FLAG_ALIASES entry, no audited NO_FLAG reason)",
+                "audited NO_FLAG reason)",
                 ((crel, meta["line"]),)))
 
-    # audit hygiene: aliases and NO_FLAG entries must stay live
-    for field, dest in FLAG_ALIASES.items():
-        if field not in fields:
-            findings.append(Finding(
-                "config", f"alias-stale-field:{field}",
-                f"FLAG_ALIASES maps dead Config field {field!r}",
-                ((crel, 1),)))
-        elif dest not in dests:
-            findings.append(Finding(
-                "config", f"alias-stale-dest:{field}",
-                f"FLAG_ALIASES maps {field!r} to dest {dest!r}, which no "
-                "launch add_argument defines",
-                ((lrel, 1),)))
+    # audit hygiene: NO_FLAG entries must stay live
     for field in NO_FLAG:
         if field not in fields:
             findings.append(Finding(

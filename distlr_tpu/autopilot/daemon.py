@@ -130,6 +130,11 @@ class AutopilotDaemon:
                  fetch, alert_poll=None, interval_s: float = 2.0,
                  journal_dir: str | None = None,
                  rate_window_s: float = 10.0, clock=None):
+        if interval_s <= 0:
+            raise ValueError(f"interval_s must be positive, got {interval_s}")
+        if rate_window_s <= 0:
+            raise ValueError(
+                f"rate_window_s must be positive, got {rate_window_s}")
         self.policy = policy
         self.actuators = actuators
         self.fetch = fetch

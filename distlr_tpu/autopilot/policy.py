@@ -137,8 +137,9 @@ class Decision:
 
 @dataclasses.dataclass(frozen=True)
 class PolicyConfig:
-    """Bands, bounds, and damping — the knobs ``launch autopilot``
-    exposes (Config ``autopilot_*`` fields; see docs/CONFIG.md)."""
+    """Bands, bounds, and damping: ``launch autopilot``'s flags of the
+    same names (``--lag-high`` is ``lag_high``), whose defaults these
+    are."""
 
     hysteresis_ticks: int = 2
     cooldown_s: float = 10.0
@@ -158,11 +159,35 @@ class PolicyConfig:
     lag_high: float = 4.0
     lag_low: float = 1.0
 
-    @classmethod
-    def from_config(cls, cfg) -> "PolicyConfig":
-        """Lift the flat ``autopilot_*`` Config fields."""
-        return cls(**{f.name: getattr(cfg, f"autopilot_{f.name}")
-                      for f in dataclasses.fields(cls)})
+    def __post_init__(self):
+        if self.hysteresis_ticks < 1:
+            raise ValueError(
+                f"hysteresis_ticks must be >= 1, got {self.hysteresis_ticks}")
+        if self.cooldown_s < 0 or self.rollback_window_s < 0:
+            raise ValueError(
+                "cooldown_s and rollback_window_s must be >= 0, got "
+                f"{self.cooldown_s}/{self.rollback_window_s}")
+        for knob in ACTUATORS:
+            lo, hi = self.bounds(knob)
+            if lo < 0 or hi < lo:
+                raise ValueError(
+                    f"need 0 <= {knob}_min <= {knob}_max, got {lo}/{hi}")
+        if self.push_rate_low < 0 or self.push_rate_high <= self.push_rate_low:
+            raise ValueError(
+                "need 0 <= push_rate_low < push_rate_high, got "
+                f"{self.push_rate_low}/{self.push_rate_high}")
+        if self.lag_low < 0 or self.lag_high <= self.lag_low:
+            raise ValueError(
+                "need 0 <= lag_low < lag_high, got "
+                f"{self.lag_low}/{self.lag_high}")
+        if (self.staleness_high <= 0 or self.shed_rate_high < 0
+                or self.route_p99_high_ms <= 0 or self.req_rate_low < 0):
+            raise ValueError(
+                "bands must be positive (shed/req floors >= 0): "
+                f"staleness_high={self.staleness_high} "
+                f"shed_rate_high={self.shed_rate_high} "
+                f"route_p99_high_ms={self.route_p99_high_ms} "
+                f"req_rate_low={self.req_rate_low}")
 
     def bounds(self, actuator: str) -> tuple[int, int]:
         return (getattr(self, f"{actuator}_min"),

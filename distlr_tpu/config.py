@@ -53,7 +53,10 @@ def _bool_from_int(raw: str) -> bool:
 
 @dataclasses.dataclass
 class Config:
-    """Full training configuration.
+    """The trainers' and the PS plane's options, and the observability
+    ones every process shares.  What only one daemon reads (``launch
+    serve``, ``route``, ``autopilot``, ``obs-agg``) is a flag of that
+    subcommand and goes straight to the constructor that uses it.
 
     Defaults reproduce the reference launcher's defaults
     (``examples/local.sh:12-19``) so `Config()` trains the same workload
@@ -141,8 +144,6 @@ class Config:
     feature_shards: int = 1           # model-axis sharding of the feature dim
 
     # ---- PS / async mode ----
-    ps_host: str = "127.0.0.1"        # DMLC_PS_ROOT_URI
-    ps_port: int = 8001               # DMLC_PS_ROOT_PORT
     # Dense PS protocol optimization: replace the reference's two round
     # trips per batch (pull -> grad -> push, src/lr.cc:116-132) with ONE
     # fused push_pull (the reply carries the post-update weights), and in
@@ -350,158 +351,6 @@ class Config:
     # oldest is pruned (loudly, via distlr_incident_pruned_total).
     incident_max: int = 32
 
-    # ---- SLO engine / embedded fleet tsdb (launch obs-agg) ----
-    # SLO spec file (JSON) compiled by `launch obs-agg` into error-
-    # budget gauges (distlr_slo_budget_remaining / distlr_slo_burn_rate)
-    # and multi-window burn-rate alerts (distlr_alert_slo_burn) over the
-    # embedded fleet time-series store.  None = no SLO engine.
-    slo_file: str | None = None
-    # Raw-tier ring size of the embedded tsdb: scrape frames kept per
-    # (series, labels) before the oldest is evicted into the 10s/60s
-    # rollup tiers (~17 min at the default 2 s scrape interval).
-    obs_tsdb_raw_points: int = 512
-    # Seconds of 10s/60s rollup history kept per series; evictions are
-    # counted in distlr_tsdb_points_dropped_total, never silent.
-    obs_tsdb_rollup_retention_s: float = 3600.0
-    # Lines per on-disk history.jsonl segment (the tsdb's raw tier on
-    # disk, `launch top --replay` input) before rotation; one rotated
-    # segment is kept.
-    obs_tsdb_history_lines: int = 2000
-
-    # ---- serving (launch serve / distlr_tpu.serve) ----
-    # Port 0 = OS-assigned ephemeral (announced as "SERVING host:port").
-    serve_port: int = 0
-    serve_host: str = "127.0.0.1"
-    # Upper bucket of the engine's padded batch ladder; also the
-    # microbatcher's flush size.
-    serve_max_batch_size: int = 1024
-    # Microbatch window: a request waits at most this long for
-    # co-batching company before flushing (latency bound per request).
-    serve_max_wait_ms: float = 2.0
-    # Weight-source poll cadence for hot reload (checkpoint watch or
-    # live-PS pull) — the serving staleness bound.
-    serve_reload_interval_s: float = 1.0
-    # Hot-row keyed reload (live-PS serving only): capacity of the
-    # request-fed HotSetTracker.  0 = off (every reload pulls the full
-    # D-dim table); N > 0 = reload only the ~N-row working set through
-    # keyed pulls, with full-refresh fallback below.
-    serve_hot_rows: int = 0
-    # Fall back to a full-table refresh when the published hot set
-    # covers less than this fraction of recently requested keys (the
-    # shifting-distribution guard).
-    serve_hot_min_coverage: float = 0.95
-    # Also force a full refresh every N polls (bounds cold-row staleness
-    # to N poll intervals); 0 = only coverage-driven refreshes.
-    serve_hot_full_every: int = 10
-    # Idle-engine device eviction (the cold-model-version satellite): an
-    # engine that scored nothing for this many seconds releases its
-    # device weight table to a host copy (HBM freed for the hot
-    # versions) and lazily re-loads on the next request.  0 = never
-    # evict (every engine pins device memory forever — the pre-elastic
-    # behavior).
-    serve_engine_idle_evict_s: float = 0.0
-
-    # ---- feedback loop (launch serve --feedback-* / launch online;
-    # distlr_tpu.feedback) ----
-    # Directory for the scored-request spool journal; setting it is what
-    # turns the feedback loop ON for `launch serve` (LABEL lines join,
-    # shards emit, the drift detector runs).  None = loop open.
-    feedback_spool_dir: str | None = None
-    # Where joined training shards are written (the online trainer's
-    # input).  None = "<feedback_spool_dir>/shards".
-    feedback_shard_dir: str | None = None
-    # Delayed-label join window: a request unlabeled for this long is
-    # resolved by the negative-sampling policy below.
-    feedback_window_s: float = 60.0
-    # Probability a never-labeled request is emitted as a label-0
-    # example at window expiry (the CTR no-click assumption); the rest
-    # are dropped.  0 = drop all never-labeled requests.
-    feedback_negative_rate: float = 0.1
-    # Joined examples per emitted training shard.
-    feedback_shard_records: int = 1024
-    # In-memory spool bound (requests awaiting a label); past it the
-    # least-important (hot-set statistics) oldest records are shed.
-    feedback_capacity: int = 100_000
-    # Drift detector: served scores per PSI comparison block, and the
-    # block-to-block PSI above which distlr_alert_score_drift fires.
-    feedback_drift_block: int = 512
-    feedback_drift_threshold: float = 0.25
-
-    # ---- multi-tenant serving (ISSUE 10) ----
-    # Model id this serving process's PRIMARY engine answers as: the
-    # tenant identity MODEL/@-addressed traffic selects, and the tag
-    # feedback spool records carry so online training stays per-tenant.
-    # "default" = pre-tenant behavior (unaddressed traffic, flat shards).
-    serve_model_id: str = "default"
-    # Per-tenant token-bucket admission quotas for `launch route`:
-    # "model=rate[:burst],..." (requests/s; burst defaults to 2*rate).
-    # A tenant over budget gets an explicit "ERR SHED tenant" reply and
-    # its own distlr_tenant_shed_total counter — distinct from the
-    # capacity sheds.  None = no quotas.
-    route_quota: str | None = None
-
-    # ---- serving router (launch route / distlr_tpu.serve.router) ----
-    # Port 0 = OS-assigned ephemeral (announced as "ROUTING host:port").
-    route_port: int = 0
-    route_host: str = "127.0.0.1"
-    # Admission control: per-replica in-flight request budget; a request
-    # finding no replica with a free slot is shed with an explicit
-    # "ERR SHED" reply (never a silent hang).
-    route_max_inflight: int = 64
-    # Passive failure detection: consecutive transport failures before a
-    # replica is ejected from rotation.
-    route_eject_after: int = 3
-    # Active health probe cadence for in-rotation replicas that carried
-    # no recent traffic.
-    route_health_interval_s: float = 1.0
-    # Reinstatement probes for ejected replicas: exponential backoff
-    # from base to max.
-    route_probe_backoff_s: float = 0.5
-    route_probe_backoff_max_s: float = 30.0
-    # Per-exchange socket timeout toward replicas (connect + reply read).
-    route_backend_timeout_s: float = 30.0
-
-    # ---- fleet autopilot (launch autopilot / distlr_tpu.autopilot) ----
-    # Control-loop tick interval: one /fleet.json poll + at most one
-    # scaling action per tick.
-    autopilot_interval_s: float = 2.0
-    # Consecutive in-breach ticks before a band fires (flap damping; a
-    # reshard or replica churn is never answered to a single sample).
-    autopilot_hysteresis_ticks: int = 2
-    # Per-actuator hold after any action (and the global freeze after a
-    # rollback-on-alert) before the policy may move it again.
-    autopilot_cooldown_s: float = 10.0
-    # How long after an action a firing bound alert still blames (and
-    # reverts) it; older actions are left alone and the daemon holds.
-    autopilot_rollback_window_s: float = 60.0
-    # Per-actuator bounds the policy clamps every target into.
-    autopilot_ps_min: int = 1
-    autopilot_ps_max: int = 8
-    autopilot_engine_min: int = 1
-    autopilot_engine_max: int = 8
-    autopilot_worker_min: int = 1
-    autopilot_worker_max: int = 8
-    # PS band: grow on the cumulative staleness-pushes p99 (the Hogwild
-    # quality knob — convergence degrades with staleness τ) or on the
-    # windowed push rate per rank; shrink only on the windowed rate (a
-    # cumulative percentile never forgets the peak).
-    autopilot_staleness_high: float = 64.0
-    autopilot_push_rate_high: float = 200.0
-    autopilot_push_rate_low: float = 20.0
-    # Engine band: grow on windowed admission-shed rate (sheds/s) or
-    # the cumulative route p99 safety bound; shrink when shed-free and
-    # the windowed accepted req/s per replica falls under the floor.
-    autopilot_shed_rate_high: float = 0.5
-    autopilot_route_p99_high_ms: float = 250.0
-    autopilot_req_rate_low: float = 5.0
-    # Worker band on the live distlr_feedback_shard_lag gauge (pending
-    # unclaimed shards): spawn above high, retire below low.
-    autopilot_lag_high: float = 4.0
-    autopilot_lag_low: float = 1.0
-    # Horizon for the windowed rates (successive /fleet.json polls,
-    # seeded from history.jsonl at daemon start).
-    autopilot_rate_window_s: float = 10.0
-
     def __post_init__(self):
         ref = self.compat_mode == "reference"
         if self.compat_mode not in ("correct", "reference"):
@@ -691,58 +540,6 @@ class Config:
                 "obs_metrics_port must be None (off) or in [0, 65536), "
                 f"got {self.obs_metrics_port}"
             )
-        if not 0 <= self.serve_port < 1 << 16:
-            raise ValueError(f"serve_port must be in [0, 65536), got {self.serve_port}")
-        if self.serve_max_batch_size <= 0:
-            raise ValueError(
-                f"serve_max_batch_size must be positive, got {self.serve_max_batch_size}"
-            )
-        if self.serve_max_wait_ms < 0:
-            raise ValueError(
-                f"serve_max_wait_ms must be >= 0, got {self.serve_max_wait_ms}"
-            )
-        if self.serve_reload_interval_s <= 0:
-            raise ValueError(
-                "serve_reload_interval_s must be positive, "
-                f"got {self.serve_reload_interval_s}"
-            )
-        if self.serve_hot_rows < 0:
-            raise ValueError(
-                f"serve_hot_rows must be >= 0 (0 = off), got {self.serve_hot_rows}"
-            )
-        if not 0.0 < self.serve_hot_min_coverage <= 1.0:
-            raise ValueError(
-                "serve_hot_min_coverage must be in (0, 1], "
-                f"got {self.serve_hot_min_coverage}"
-            )
-        if self.serve_hot_full_every < 0:
-            raise ValueError(
-                "serve_hot_full_every must be >= 0 (0 = coverage-driven "
-                f"only), got {self.serve_hot_full_every}"
-            )
-        if self.serve_engine_idle_evict_s < 0:
-            raise ValueError(
-                "serve_engine_idle_evict_s must be >= 0 (0 = never "
-                f"evict), got {self.serve_engine_idle_evict_s}"
-            )
-        if self.feedback_window_s <= 0:
-            raise ValueError(
-                f"feedback_window_s must be positive, got "
-                f"{self.feedback_window_s}")
-        if not 0.0 <= self.feedback_negative_rate <= 1.0:
-            raise ValueError(
-                "feedback_negative_rate must be in [0, 1], got "
-                f"{self.feedback_negative_rate}")
-        if self.feedback_shard_records <= 0 or self.feedback_capacity <= 0:
-            raise ValueError(
-                "feedback_shard_records and feedback_capacity must be "
-                f"positive, got {self.feedback_shard_records}/"
-                f"{self.feedback_capacity}")
-        if self.feedback_drift_block <= 0 or self.feedback_drift_threshold <= 0:
-            raise ValueError(
-                "feedback_drift_block and feedback_drift_threshold must "
-                f"be positive, got {self.feedback_drift_block}/"
-                f"{self.feedback_drift_threshold}")
         if not 0.0 <= self.trace_sample <= 1.0:
             raise ValueError(
                 f"trace_sample must be in [0, 1], got {self.trace_sample}")
@@ -775,94 +572,6 @@ class Config:
         if self.incident_max < 1:
             raise ValueError(
                 f"incident_max must be >= 1, got {self.incident_max}")
-        if (not self.serve_model_id
-                or any(c in self.serve_model_id for c in " \t@=,+")):
-            raise ValueError(
-                "serve_model_id must be non-empty without any of "
-                f"' @=,+', got {self.serve_model_id!r}")
-        if not 0 <= self.route_port < 1 << 16:
-            raise ValueError(
-                f"route_port must be in [0, 65536), got {self.route_port}")
-        if self.route_max_inflight <= 0:
-            raise ValueError(
-                f"route_max_inflight must be positive, got {self.route_max_inflight}"
-            )
-        if self.route_eject_after < 1:
-            raise ValueError(
-                f"route_eject_after must be >= 1, got {self.route_eject_after}"
-            )
-        if self.route_health_interval_s <= 0:
-            raise ValueError(
-                "route_health_interval_s must be positive, "
-                f"got {self.route_health_interval_s}"
-            )
-        if (self.route_probe_backoff_s <= 0
-                or self.route_probe_backoff_max_s < self.route_probe_backoff_s):
-            raise ValueError(
-                "need 0 < route_probe_backoff_s <= route_probe_backoff_max_s, "
-                f"got {self.route_probe_backoff_s}/"
-                f"{self.route_probe_backoff_max_s}"
-            )
-        if self.route_backend_timeout_s <= 0:
-            raise ValueError(
-                "route_backend_timeout_s must be positive, "
-                f"got {self.route_backend_timeout_s}"
-            )
-        if self.autopilot_interval_s <= 0:
-            raise ValueError(
-                "autopilot_interval_s must be positive, "
-                f"got {self.autopilot_interval_s}")
-        if self.autopilot_hysteresis_ticks < 1:
-            raise ValueError(
-                "autopilot_hysteresis_ticks must be >= 1, "
-                f"got {self.autopilot_hysteresis_ticks}")
-        if self.autopilot_cooldown_s < 0 or self.autopilot_rollback_window_s < 0:
-            raise ValueError(
-                "autopilot_cooldown_s and autopilot_rollback_window_s "
-                f"must be >= 0, got {self.autopilot_cooldown_s}/"
-                f"{self.autopilot_rollback_window_s}")
-        for knob in ("ps", "engine", "worker"):
-            lo = getattr(self, f"autopilot_{knob}_min")
-            hi = getattr(self, f"autopilot_{knob}_max")
-            if lo < 0 or hi < lo:
-                raise ValueError(
-                    f"need 0 <= autopilot_{knob}_min <= autopilot_"
-                    f"{knob}_max, got {lo}/{hi}")
-        if (self.autopilot_push_rate_low < 0
-                or self.autopilot_push_rate_high <= self.autopilot_push_rate_low):
-            raise ValueError(
-                "need 0 <= autopilot_push_rate_low < autopilot_push_"
-                f"rate_high, got {self.autopilot_push_rate_low}/"
-                f"{self.autopilot_push_rate_high}")
-        if (self.autopilot_lag_low < 0
-                or self.autopilot_lag_high <= self.autopilot_lag_low):
-            raise ValueError(
-                "need 0 <= autopilot_lag_low < autopilot_lag_high, "
-                f"got {self.autopilot_lag_low}/{self.autopilot_lag_high}")
-        if (self.autopilot_staleness_high <= 0
-                or self.autopilot_shed_rate_high < 0
-                or self.autopilot_route_p99_high_ms <= 0
-                or self.autopilot_req_rate_low < 0
-                or self.autopilot_rate_window_s <= 0):
-            raise ValueError(
-                "autopilot bands must be positive (shed/req floors >= 0): "
-                f"staleness_high={self.autopilot_staleness_high} "
-                f"shed_rate_high={self.autopilot_shed_rate_high} "
-                f"route_p99_high_ms={self.autopilot_route_p99_high_ms} "
-                f"req_rate_low={self.autopilot_req_rate_low} "
-                f"rate_window_s={self.autopilot_rate_window_s}")
-        if self.obs_tsdb_raw_points < 2:
-            raise ValueError(
-                "obs_tsdb_raw_points must be >= 2 (a rate needs two "
-                f"points), got {self.obs_tsdb_raw_points}")
-        if self.obs_tsdb_rollup_retention_s <= 0:
-            raise ValueError(
-                "obs_tsdb_rollup_retention_s must be positive, got "
-                f"{self.obs_tsdb_rollup_retention_s}")
-        if self.obs_tsdb_history_lines < 1:
-            raise ValueError(
-                "obs_tsdb_history_lines must be >= 1, got "
-                f"{self.obs_tsdb_history_lines}")
 
     # -- reference env-var shim ------------------------------------------------
     @classmethod
@@ -885,8 +594,6 @@ class Config:
             l2_c=_env(env, "C", float, 1.0),
             num_workers=_env(env, "DMLC_NUM_WORKER", int, 1),
             num_servers=_env(env, "DMLC_NUM_SERVER", int, 1),
-            ps_host=_env(env, "DMLC_PS_ROOT_URI", str, "127.0.0.1"),
-            ps_port=_env(env, "DMLC_PS_ROOT_PORT", int, 8001),
         )
         kw.update(overrides)
         return cls(**kw)

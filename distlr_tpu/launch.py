@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import dataclasses
 import os
 import sys
 
@@ -379,47 +380,18 @@ def _add_config_flags(p: argparse.ArgumentParser) -> None:
     )
 
 
+def _given(**kw):
+    """The keyword arguments whose flag was given.  One left out is
+    ``None`` here and falls to the receiving constructor's own default,
+    so a daemon option's default is written once, where it is used."""
+    return {k: v for k, v in kw.items() if v is not None}
+
+
 def _config_from_args(args: argparse.Namespace) -> Config:
-    overrides = {
-        k: v
-        for k, v in vars(args).items()
-        if v is not None
-        and k
-        in {
-            "data_dir", "num_feature_dim", "num_iteration", "batch_size",
-            "learning_rate", "l2_c", "test_interval", "model", "num_classes",
-            "nnz_max", "compat_mode", "checkpoint_dir", "checkpoint_interval",
-            "profile_dir", "num_workers", "num_servers",
-            "feature_dtype", "block_size", "block_groups", "ctr_fields",
-            "hash_seed", "ps_pipeline", "ps_max_delay", "obs_metrics_port",
-            "random_seed", "prefetch", "ps_timeout_ms",
-            "obs_metrics_host", "obs_trace_path", "obs_run_dir",
-            "ps_retry_attempts", "ps_retry_backoff_ms",
-            "ps_retry_backoff_max_ms", "ps_retry_deadline_s",
-            "chaos_plan", "chaos_seed",
-            "ps_optimizer", "ftrl_alpha", "ftrl_beta", "ftrl_l1", "ftrl_l2",
-            "ps_compress", "ps_accum_start", "ps_accum_growth",
-            "ps_accum_growth_every", "ps_accum_max", "ps_retry_adaptive",
-            "ps_store_dir", "ps_store_interval_s", "ps_store_wal",
-            "ps_store_wal_fsync_s", "sync_mode",
-            "trace_sample", "prof_hz", "prof_window_s",
-            "log_level", "log_ring", "log_dedupe_s",
-            "incident_window_s", "incident_settle_s", "incident_max",
-            "serve_model_id", "route_quota",
-            "autopilot_interval_s", "autopilot_hysteresis_ticks",
-            "autopilot_cooldown_s", "autopilot_rollback_window_s",
-            "autopilot_ps_min", "autopilot_ps_max",
-            "autopilot_engine_min", "autopilot_engine_max",
-            "autopilot_worker_min", "autopilot_worker_max",
-            "autopilot_staleness_high", "autopilot_push_rate_high",
-            "autopilot_push_rate_low", "autopilot_shed_rate_high",
-            "autopilot_route_p99_high_ms", "autopilot_req_rate_low",
-            "autopilot_lag_high", "autopilot_lag_low",
-            "autopilot_rate_window_s",
-            "slo_file", "obs_tsdb_raw_points",
-            "obs_tsdb_rollup_retention_s", "obs_tsdb_history_lines",
-        }
-    }
+    """A flag reaches ``Config`` by one rule: it was given and its
+    ``dest`` is a field's name.  Any other flag is its subcommand's own."""
+    fields = {f.name for f in dataclasses.fields(Config)}
+    overrides = {k: v for k, v in _given(**vars(args)).items() if k in fields}
     if isinstance(overrides.get("obs_run_dir"), list):
         # --obs-run-dir is repeatable (obs-agg federates several fleets);
         # Config carries the pathsep-joined list, and single-dir consumers
@@ -682,38 +654,25 @@ def cmd_serve(args: argparse.Namespace) -> int:
         ScoringEngine,
         ScoringServer,
     )
+    from distlr_tpu.serve.tenant import (  # noqa: PLC0415
+        DEFAULT_MODEL,
+        valid_model_id,
+    )
     from distlr_tpu.train.export import load_weights  # noqa: PLC0415
     from distlr_tpu.train.ps_trainer import ps_param_dim  # noqa: PLC0415
 
     cfg = _config_from_args(args)
-    serve_over = {
-        "serve_port": args.port, "serve_host": args.bind,
-        "serve_max_batch_size": args.serve_max_batch_size,
-        "serve_max_wait_ms": args.max_wait_ms,
-        "serve_reload_interval_s": args.reload_interval,
-        "serve_hot_rows": args.hot_rows,
-        "serve_hot_min_coverage": args.hot_min_coverage,
-        "serve_hot_full_every": args.hot_full_every,
-        "serve_engine_idle_evict_s": args.engine_idle_evict,
-        "feedback_spool_dir": args.feedback_spool,
-        "feedback_shard_dir": args.feedback_shards,
-        "feedback_window_s": args.feedback_window,
-        "feedback_negative_rate": args.feedback_negative_rate,
-        "feedback_shard_records": args.feedback_shard_records,
-        "feedback_capacity": args.feedback_capacity,
-        "feedback_drift_block": args.drift_block,
-        "feedback_drift_threshold": args.drift_threshold,
-    }
-    if args.model_id is not None:
-        serve_over["serve_model_id"] = args.model_id
-    cfg = cfg.replace(**{k: v for k, v in serve_over.items() if v is not None})
     live_ps = bool(args.ps_hosts or args.ps_ctl)
     if not (args.model_file or cfg.checkpoint_dir or live_ps):
         print("error: serve needs a weight source: --model-file and/or "
               "--checkpoint-dir (watched) or --ps-hosts / --ps-ctl "
               "(live pull)", file=sys.stderr)
         return 2
-    if cfg.serve_hot_rows and not live_ps:
+    if args.hot_rows < 0:
+        print(f"error: --hot-rows must be >= 0 (0 = off), got "
+              f"{args.hot_rows}", file=sys.stderr)
+        return 2
+    if args.hot_rows and not live_ps:
         print("error: --hot-rows applies to live-PS reload only "
               "(--ps-hosts / --ps-ctl); checkpoint/model-file sources "
               "always load the full table", file=sys.stderr)
@@ -757,122 +716,132 @@ def cmd_serve(args: argparse.Namespace) -> int:
                 f"{sorted(ns_layout)}")
         return ns_layout[model_id][0], ps_param_dim(cfg) * len(ns_layout)
 
-    engine = ScoringEngine(cfg, max_batch_size=cfg.serve_max_batch_size,
-                           idle_evict_s=cfg.serve_engine_idle_evict_s)
-    if args.model_file:
-        engine.set_weights(
-            load_weights(args.model_file, shape=engine.model.param_shape))
+    def _engine() -> ScoringEngine:
+        return ScoringEngine(cfg, **_given(
+            max_batch_size=args.serve_max_batch_size,
+            idle_evict_s=args.engine_idle_evict))
+
+    reload_every = _given(interval_s=args.reload_interval)
     reloader = None
     hot_tracker = None
     extra_reloaders = []
     retry = None
     row_width = _serve_row_width(cfg)
-    if live_ps:
-        if cfg.serve_hot_rows:
-            from distlr_tpu.serve import HotSetTracker  # noqa: PLC0415
+    try:  # an option out of range is its constructor's ValueError
+        model_id = (DEFAULT_MODEL if args.model_id is None
+                    else valid_model_id(args.model_id))
+        engine = _engine()
+        if args.model_file:
+            engine.set_weights(load_weights(
+                args.model_file, shape=engine.model.param_shape))
+        if live_ps:
+            if args.hot_rows:
+                from distlr_tpu.serve import HotSetTracker  # noqa: PLC0415
 
-            hot_tracker = HotSetTracker(cfg.serve_hot_rows)
-        from distlr_tpu.ps import RetryPolicy  # noqa: PLC0415
+                hot_tracker = HotSetTracker(args.hot_rows)
+            from distlr_tpu.ps import RetryPolicy  # noqa: PLC0415
 
-        # serving pulls are idempotent, so the full policy applies: a
-        # PS blip mid-poll is retried inside the poll; an exhausted
-        # policy degrades to last-good weights (HotReloader), never
-        # kills the server
-        retry = RetryPolicy.from_config(cfg)
-        base, total = _ns(args.ps_namespace or cfg.serve_model_id)
-        source = LivePSWatcher(
-            args.ps_hosts, ps_param_dim(cfg),
-            vals_per_key=max(row_width, 1),
-            hot_tracker=hot_tracker,
-            min_coverage=cfg.serve_hot_min_coverage,
-            full_refresh_every=cfg.serve_hot_full_every,
-            retry=retry,
-            ns_base=base, ns_total_dim=total,
-            route=ps_route,
-        )
-    elif cfg.checkpoint_dir:
-        source = CheckpointWatcher(cfg.checkpoint_dir)
-    else:
-        source = None
-    if source is not None:
-        reloader = HotReloader(
-            engine, source, interval_s=cfg.serve_reload_interval_s
-        ).start()
-        if not engine.has_weights:
-            reloader.wait_for_weights()
-
-    # additional hosted model versions: "--extra-model id=weights" loads
-    # a static engine from a model file; "--extra-model id=@ps" attaches
-    # a live-PS reloader over that id's namespace of the SAME group (one
-    # ScoringServer hosting several live versions — the canary shape)
-    engines = {cfg.serve_model_id: engine}
-    for spec in args.extra_models or []:
-        mid, eq, src = spec.partition("=")
-        mid, src = mid.strip(), src.strip()
-        if not eq or not mid or not src:
-            print(f"error: bad --extra-model {spec!r} (want id=weights "
-                  "or id=@ps)", file=sys.stderr)
-            return 2
-        if mid in engines:
-            print(f"error: duplicate model id {mid!r}", file=sys.stderr)
-            return 2
-        eng = ScoringEngine(cfg, max_batch_size=cfg.serve_max_batch_size,
-                            idle_evict_s=cfg.serve_engine_idle_evict_s)
-        if src == "@ps":
-            if not live_ps:
-                print("error: --extra-model id=@ps needs --ps-hosts or "
-                      "--ps-ctl", file=sys.stderr)
-                return 2
-            base, total = _ns(mid)
-            extra_src = LivePSWatcher(
+            # serving pulls are idempotent, so the full policy applies: a
+            # PS blip mid-poll is retried inside the poll; an exhausted
+            # policy degrades to last-good weights (HotReloader), never
+            # kills the server
+            retry = RetryPolicy.from_config(cfg)
+            base, total = _ns(args.ps_namespace or model_id)
+            source = LivePSWatcher(
                 args.ps_hosts, ps_param_dim(cfg),
                 vals_per_key=max(row_width, 1),
-                # distinct pull client per namespace watcher
-                client_id=LivePSWatcher.SERVE_CLIENT_ID - len(engines),
-                retry=retry, ns_base=base, ns_total_dim=total,
+                hot_tracker=hot_tracker,
+                retry=retry,
+                ns_base=base, ns_total_dim=total,
                 route=ps_route,
+                **_given(min_coverage=args.hot_min_coverage,
+                         full_refresh_every=args.hot_full_every),
             )
-            rl = HotReloader(eng, extra_src,
-                             interval_s=cfg.serve_reload_interval_s).start()
-            rl.wait_for_weights()
-            extra_reloaders.append(rl)
+        elif cfg.checkpoint_dir:
+            source = CheckpointWatcher(cfg.checkpoint_dir)
         else:
-            eng.set_weights(load_weights(src, shape=eng.model.param_shape))
-        engines[mid] = eng
+            source = None
+        if source is not None:
+            reloader = HotReloader(engine, source, **reload_every).start()
+            if not engine.has_weights:
+                reloader.wait_for_weights()
 
-    feedback = None
-    if cfg.feedback_spool_dir:
-        from distlr_tpu.feedback import FeedbackSink  # noqa: PLC0415
+        # additional hosted model versions: "--extra-model id=weights"
+        # loads a static engine from a model file; "--extra-model id=@ps"
+        # attaches a live-PS reloader over that id's namespace of the SAME
+        # group (one ScoringServer hosting several live versions — the
+        # canary shape)
+        engines = {model_id: engine}
+        for spec in args.extra_models or []:
+            mid, eq, src = spec.partition("=")
+            mid, src = mid.strip(), src.strip()
+            if not eq or not mid or not src:
+                print(f"error: bad --extra-model {spec!r} (want id=weights "
+                      "or id=@ps)", file=sys.stderr)
+                return 2
+            if mid in engines:
+                print(f"error: duplicate model id {mid!r}", file=sys.stderr)
+                return 2
+            eng = _engine()
+            if src == "@ps":
+                if not live_ps:
+                    print("error: --extra-model id=@ps needs --ps-hosts or "
+                          "--ps-ctl", file=sys.stderr)
+                    return 2
+                base, total = _ns(mid)
+                extra_src = LivePSWatcher(
+                    args.ps_hosts, ps_param_dim(cfg),
+                    vals_per_key=max(row_width, 1),
+                    # distinct pull client per namespace watcher
+                    client_id=LivePSWatcher.SERVE_CLIENT_ID - len(engines),
+                    retry=retry, ns_base=base, ns_total_dim=total,
+                    route=ps_route,
+                )
+                rl = HotReloader(eng, extra_src, **reload_every).start()
+                rl.wait_for_weights()
+                extra_reloaders.append(rl)
+            else:
+                eng.set_weights(
+                    load_weights(src, shape=eng.model.param_shape))
+            engines[mid] = eng
 
-        shard_dir = cfg.feedback_shard_dir or os.path.join(
-            cfg.feedback_spool_dir, "shards")
-        feedback = FeedbackSink(
-            cfg.feedback_spool_dir, shard_dir, model=cfg.model,
-            capacity=cfg.feedback_capacity,
-            window_s=cfg.feedback_window_s,
-            negative_rate=cfg.feedback_negative_rate,
-            shard_records=cfg.feedback_shard_records,
-            tracker=hot_tracker,
-            drift_block=cfg.feedback_drift_block,
-            drift_threshold=cfg.feedback_drift_threshold,
+        feedback = None
+        if args.feedback_spool:
+            from distlr_tpu.feedback import FeedbackSink  # noqa: PLC0415
+
+            shard_dir = args.feedback_shards or os.path.join(
+                args.feedback_spool, "shards")
+            feedback = FeedbackSink(
+                args.feedback_spool, shard_dir, model=cfg.model,
+                negative_rate=args.feedback_negative_rate,
+                tracker=hot_tracker,
+                **_given(capacity=args.feedback_capacity,
+                         window_s=args.feedback_window,
+                         shard_records=args.feedback_shard_records,
+                         drift_block=args.drift_block,
+                         drift_threshold=args.drift_threshold),
+            )
+            log.info("feedback loop ON: spool=%s shards=%s window=%.0fs "
+                     "negative_rate=%.2f", args.feedback_spool, shard_dir,
+                     feedback.joiner.window_s, feedback.joiner.negative_rate)
+
+        multi = bool(args.extra_models) or args.model_id is not None
+        server = ScoringServer(
+            # single unnamed engine = the pre-tenant construction (flat
+            # feedback shards); an explicit --model-id or extra models
+            # turn model identity on
+            None if multi else engine,
+            engines=engines if multi else None,
+            reloader=reloader,
+            extra_reloaders=extra_reloaders,
+            hot_tracker=hot_tracker, feedback=feedback,
+            **_given(host=args.bind, port=args.port,
+                     max_wait_ms=args.max_wait_ms),
         )
-        log.info("feedback loop ON: spool=%s shards=%s window=%.0fs "
-                 "negative_rate=%.2f", cfg.feedback_spool_dir, shard_dir,
-                 cfg.feedback_window_s, cfg.feedback_negative_rate)
-
+    except ValueError as e:
+        print(f"error: {e}", file=sys.stderr)
+        return 2
     signal.signal(signal.SIGTERM, lambda *_: sys.exit(143))
-    multi = bool(args.extra_models) or args.model_id is not None
-    server = ScoringServer(
-        # single unnamed engine = the pre-tenant construction (flat
-        # feedback shards); an explicit --model-id or extra models turn
-        # model identity on
-        None if multi else engine,
-        engines=engines if multi else None,
-        host=cfg.serve_host, port=cfg.serve_port,
-        max_wait_ms=cfg.serve_max_wait_ms, reloader=reloader,
-        extra_reloaders=extra_reloaders,
-        hot_tracker=hot_tracker, feedback=feedback,
-    )
     with _obs_scope(cfg, "serve", _obs_rank(args)):
         # Scriptable readiness line, like ps-server's "HOSTS ..." contract.
         print(f"SERVING {server.host}:{server.port}", flush=True)
@@ -902,10 +871,11 @@ def cmd_online(args: argparse.Namespace) -> int:
     if args.ps_namespaces:
         # train only this tenant's namespace slice of a shared group
         from distlr_tpu.ps import namespace_layout  # noqa: PLC0415
+        from distlr_tpu.serve.tenant import DEFAULT_MODEL  # noqa: PLC0415
         from distlr_tpu.train.ps_trainer import ps_param_dim  # noqa: PLC0415
 
         layout = namespace_layout(args.ps_namespaces, ps_param_dim(cfg))
-        ns_id = args.ps_namespace or cfg.serve_model_id
+        ns_id = args.ps_namespace or DEFAULT_MODEL
         if ns_id not in layout:
             print(f"error: namespace {ns_id!r} not in --ps-namespaces "
                   f"{sorted(layout)}", file=sys.stderr)
@@ -965,33 +935,18 @@ def cmd_route(args: argparse.Namespace) -> int:
     from distlr_tpu.serve.router import ScoringRouter  # noqa: PLC0415
 
     cfg = _config_from_args(args)
-    route_over = {
-        "route_port": args.port, "route_host": args.bind,
-        "route_max_inflight": args.max_inflight,
-        "route_eject_after": args.eject_after,
-        "route_health_interval_s": args.health_interval,
-        "route_probe_backoff_s": args.probe_backoff,
-        "route_probe_backoff_max_s": args.probe_backoff_max,
-        "route_backend_timeout_s": args.backend_timeout,
-    }
-    if args.quota is not None:
-        route_over["route_quota"] = args.quota
     signal.signal(signal.SIGTERM, lambda *_: sys.exit(143))
     try:
-        cfg = cfg.replace(
-            **{k: v for k, v in route_over.items() if v is not None})
-        router = ScoringRouter(
-            args.replicas, host=cfg.route_host, port=cfg.route_port,
-            max_inflight=cfg.route_max_inflight,
-            eject_after=cfg.route_eject_after,
-            health_interval_s=cfg.route_health_interval_s,
-            probe_backoff_s=cfg.route_probe_backoff_s,
-            probe_backoff_max_s=cfg.route_probe_backoff_max_s,
-            backend_timeout_s=cfg.route_backend_timeout_s,
-            quotas=cfg.route_quota,
-        )
+        router = ScoringRouter(args.replicas, quotas=args.quota, **_given(
+            host=args.bind, port=args.port,
+            max_inflight=args.max_inflight,
+            eject_after=args.eject_after,
+            health_interval_s=args.health_interval,
+            probe_backoff_s=args.probe_backoff,
+            probe_backoff_max_s=args.probe_backoff_max,
+            backend_timeout_s=args.backend_timeout))
     except ValueError as e:
-        # config and replica-list errors get the argparse-style contract
+        # option and replica-list errors get the argparse-style contract
         # (bad host:port, duplicates, out-of-range knobs), not a traceback
         print(f"error: {e}", file=sys.stderr)
         return 2
@@ -1154,17 +1109,24 @@ def cmd_autopilot(args: argparse.Namespace) -> int:
         names = ([n.strip() for n in args.alerts.split(",") if n.strip()]
                  if args.alerts else None)
         poller = fleet_alert_poller(fleet_url, names=names)
-    journal_dir = args.journal_dir or run_dir
-    with _obs_scope(cfg, "autopilot", _obs_rank(args)):
+    try:
+        # a band's flag is PolicyConfig's field of the same name
+        policy = PolicyConfig(**_given(**{
+            f.name: getattr(args, f"autopilot_{f.name}")
+            for f in dataclasses.fields(PolicyConfig)}))
         daemon = AutopilotDaemon(
-            PolicyEngine(PolicyConfig.from_config(cfg)),
+            PolicyEngine(policy),
             actuators,
             fetch=fleet_fetcher(fleet_url),
             alert_poll=poller,
-            interval_s=cfg.autopilot_interval_s,
-            journal_dir=journal_dir,
-            rate_window_s=cfg.autopilot_rate_window_s,
+            journal_dir=args.journal_dir or run_dir,
+            **_given(interval_s=args.autopilot_interval_s,
+                     rate_window_s=args.autopilot_rate_window_s),
         )
+    except ValueError as e:
+        print(f"error: {e}", file=sys.stderr)
+        return 2
+    with _obs_scope(cfg, "autopilot", _obs_rank(args)):
         if run_dir:
             seeded = daemon.seed_rates_from_history(run_dir)
             if seeded:
@@ -1499,26 +1461,31 @@ def cmd_obs_agg(args: argparse.Namespace) -> int:
         print(f"error: bad alert thresholds: {e}", file=sys.stderr)
         return 2
     slo_spec, slo_rules = None, None
-    if cfg.slo_file:
+    if args.slo_file:
         from distlr_tpu.obs.slo import SLOSpecError, load_slo_file  # noqa: PLC0415
         try:
-            slo_spec, slo_rules = load_slo_file(cfg.slo_file)
+            slo_spec, slo_rules = load_slo_file(args.slo_file)
         except SLOSpecError as e:
             print(f"error: bad --slo-file: {e}", file=sys.stderr)
             return 2
         log.info("SLO engine armed: %s",
                  ", ".join(s.name for s in slo_spec))
-    scraper = FleetScraper(cfg.obs_run_dir, interval_s=args.interval,
-                           stale_after_s=thresholds.scrape_stale_s,
-                           thresholds=thresholds,
-                           slo_spec=slo_spec, slo_rules=slo_rules,
-                           history_max_lines=cfg.obs_tsdb_history_lines,
-                           tsdb_raw_points=cfg.obs_tsdb_raw_points,
-                           tsdb_rollup_retention_s=(
-                               cfg.obs_tsdb_rollup_retention_s),
-                           incident_window_s=cfg.incident_window_s,
-                           incident_settle_s=cfg.incident_settle_s,
-                           incident_max=cfg.incident_max)
+    try:
+        scraper = FleetScraper(
+            cfg.obs_run_dir, interval_s=args.interval,
+            stale_after_s=thresholds.scrape_stale_s,
+            thresholds=thresholds,
+            slo_spec=slo_spec, slo_rules=slo_rules,
+            incident_window_s=cfg.incident_window_s,
+            incident_settle_s=cfg.incident_settle_s,
+            incident_max=cfg.incident_max,
+            **_given(history_max_lines=args.obs_tsdb_history_lines,
+                     tsdb_raw_points=args.obs_tsdb_raw_points,
+                     tsdb_rollup_retention_s=(
+                         args.obs_tsdb_rollup_retention_s)))
+    except ValueError as e:
+        print(f"error: {e}", file=sys.stderr)
+        return 2
     if args.once:
         # One-shot federation: merge whatever the run dir holds right
         # now (live endpoints AND banked snapshots/ files) and emit it —
@@ -1912,7 +1879,8 @@ def cmd_incident(args: argparse.Namespace) -> int:
     return 0
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
+    """Every subcommand's flags, and ``fn`` set to its ``cmd_*``."""
     parser = argparse.ArgumentParser(prog="distlr_tpu.launch", description=__doc__)
     sub = parser.add_subparsers(dest="cmd", required=True)
 
@@ -2025,7 +1993,7 @@ def main(argv=None) -> int:
                    help="weight-source poll period, seconds (the serving "
                    "staleness bound; jittered ±20%% so replicas "
                    "desynchronize)")
-    r.add_argument("--hot-rows", dest="hot_rows", type=int,
+    r.add_argument("--hot-rows", dest="hot_rows", type=int, default=0,
                    help="with --ps-hosts: track the request traffic's hot "
                    "working set (capacity N row keys) and reload only that "
                    "slice via keyed pulls instead of the full D-dim table; "
@@ -2055,7 +2023,7 @@ def main(argv=None) -> int:
     r.add_argument("--feedback-window", dest="feedback_window", type=float,
                    help="delayed-label join window, seconds (default 60)")
     r.add_argument("--feedback-negative-rate", dest="feedback_negative_rate",
-                   type=float,
+                   type=float, default=0.1,
                    help="probability a never-labeled request becomes a "
                    "label-0 example at window expiry (default 0.1; 0 = "
                    "drop all never-labeled)")
@@ -2696,8 +2664,11 @@ def main(argv=None) -> int:
     fs.add_argument("--list", action="store_true",
                     help="list scenarios and mutants, then exit")
     fs.set_defaults(fn=cmd_fleetsim)
+    return parser
 
-    args = parser.parse_args(argv)
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
     return args.fn(args)
 
 

@@ -109,6 +109,13 @@ class LivePSWatcher:
                  ns_total_dim: int | None = None, route=None):
         from distlr_tpu.ps import KVWorker  # noqa: PLC0415
 
+        # refused before a connection is opened
+        if not 0.0 < min_coverage <= 1.0:
+            raise ValueError(
+                f"min_coverage must be in (0, 1], got {min_coverage}")
+        if full_refresh_every < 0:
+            raise ValueError(
+                f"full_refresh_every must be >= 0, got {full_refresh_every}")
         self.hosts = hosts
         self.dim = dim
         #: multi-tenant namespace scoping (ISSUE 10): when the group
@@ -167,12 +174,6 @@ class LivePSWatcher:
                      "boundaries; using flat keys", self.vals_per_key)
             self.vals_per_key = 1
         self.chunk_rows = int(chunk_rows)
-        if not 0.0 < min_coverage <= 1.0:
-            raise ValueError(
-                f"min_coverage must be in (0, 1], got {min_coverage}")
-        if full_refresh_every < 0:
-            raise ValueError(
-                f"full_refresh_every must be >= 0, got {full_refresh_every}")
         self.hot_tracker = hot_tracker
         self.min_coverage = float(min_coverage)
         self.full_refresh_every = int(full_refresh_every)

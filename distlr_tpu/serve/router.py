@@ -316,6 +316,8 @@ class ScoringRouter:
                  quotas=None, shadow_block: int = 256,
                  shadow_queue_max: int = 256, seed: int | None = None):
         models = _tenant.parse_model_spec(replicas)
+        if not 0 <= port < 1 << 16:
+            raise ValueError(f"port must be in [0, 65536), got {port}")
         if max_inflight <= 0:
             raise ValueError(f"max_inflight must be positive, got {max_inflight}")
         if eject_after < 1:
@@ -327,6 +329,9 @@ class ScoringRouter:
             raise ValueError(
                 "need 0 < probe_backoff_s <= probe_backoff_max_s, got "
                 f"{probe_backoff_s}/{probe_backoff_max_s}")
+        if backend_timeout_s <= 0:
+            raise ValueError(
+                f"backend_timeout_s must be positive, got {backend_timeout_s}")
         if retries < 0:
             raise ValueError(f"retries must be >= 0, got {retries}")
         by_addr: dict[str, _Replica] = {}

@@ -130,6 +130,8 @@ class ScoringServer:
                  extra_reloaders=(),
                  metrics: MetricsLogger | None = None, hot_tracker=None,
                  feedback=None):
+        if not 0 <= port < 1 << 16:
+            raise ValueError(f"port must be in [0, 65536), got {port}")
         if engines is None:
             if engine is None:
                 raise ValueError("need an engine (or an engines mapping)")

@@ -76,6 +76,18 @@ _SHADOW_PSI = _reg.gauge(
 )
 
 
+def valid_model_id(model_id: str) -> str:
+    """The id itself, or a ValueError: an id is a token of the line
+    protocol (``MODEL <id>``, ``@<id>``) and of the registry, quota and
+    split grammars, so it is non-empty and holds none of their
+    separators."""
+    if not model_id or any(c in model_id for c in " \t@=,+"):
+        raise ValueError(
+            "model_id must be non-empty without any of ' @=,+', "
+            f"got {model_id!r}")
+    return model_id
+
+
 def parse_model_spec(spec) -> dict[str, list[str]]:
     """Replica-registry grammar -> ordered ``{model_id: [host:port, ...]}``.
 
@@ -115,8 +127,7 @@ def parse_model_spec(spec) -> dict[str, list[str]]:
         if len(set(addrs)) != len(addrs):
             raise ValueError(
                 f"duplicate replica addresses for model {model!r}: {addrs}")
-        if any(c in model for c in " \t@=,+"):
-            raise ValueError(f"bad model id {model!r} (no spaces or @=,+)")
+        valid_model_id(model)
     if not out:
         raise ValueError("model spec names no models")
     return out

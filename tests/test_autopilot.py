@@ -359,16 +359,6 @@ class TestPolicyEngine:
             "steady", "steady", "engine_up", "hold_unreachable",
             "rollback_on_alert", "steady", "worker_down"]
 
-    def test_from_config_lifts_the_autopilot_fields(self):
-        from distlr_tpu.config import Config
-
-        cfg = Config(autopilot_hysteresis_ticks=5, autopilot_engine_max=3,
-                     autopilot_shed_rate_high=0.125)
-        pc = PolicyConfig.from_config(cfg)
-        assert pc.hysteresis_ticks == 5
-        assert pc.bounds("engine") == (cfg.autopilot_engine_min, 3)
-        assert pc.shed_rate_high == 0.125
-
 
 # ---------------------------------------------------------------------------
 # windowed rates
