@@ -100,6 +100,9 @@ def main(argv=None) -> int:
         def supports_vals_per_key(self, vpk):
             return True
 
+        def hold(self, keys, vals_per_key=1):
+            return keys
+
         def close(self):
             pass
 

@@ -53,6 +53,17 @@ _DENSE_FRAMES = _reg.counter(
     "vals_per_key divides dim and the handle's range boundaries)",
     labelnames=("op", "encoding"),
 )
+_KEY_FRAMES = _reg.counter(
+    "distlr_ps_client_key_frames_total",
+    "explicit-key ops that succeeded, by what the op did with its keys "
+    "before the native call: held = nothing (the very array "
+    "KVWorker.hold checked once and this connection keeps read-only, "
+    "used over the row space it was checked against), checked = "
+    "KVWorker._validate_keys' passes over them (a caller's own array, a "
+    "copy of a held one, one made writeable again or held at another "
+    "vals_per_key)",
+    labelnames=("op", "keys"),
+)
 _PAYLOAD_FRAMES = _reg.counter(
     "distlr_ps_payload_frames_total",
     "value-carrying frames (one a server) of keyed ops that succeeded, "
@@ -185,6 +196,8 @@ class _OpAccount:
         "ok": (_OPS_TOTAL, {"status": "ok"}),
         "rows": (_DENSE_FRAMES, {"encoding": "rows"}),
         "flat": (_DENSE_FRAMES, {"encoding": "flat"}),
+        "held": (_KEY_FRAMES, {"keys": "held"}),
+        "checked": (_KEY_FRAMES, {"keys": "checked"}),
         "sent": (_BYTES_TOTAL, {"direction": "sent"}),
         "received": (_BYTES_TOTAL, {"direction": "received"}),
         "mapped": (_PAYLOAD_FRAMES, {"carrier": "mapped"}),
@@ -217,6 +230,12 @@ def _retire_accounts(accounts: dict) -> None:
         for cell in account.bound:
             cell.retired = True
 
+
+#: the third of what :meth:`KVWorker._resolve_keys` gives, which is also
+#: the share of :class:`_OpAccount` that counts the op, says how its keys
+#: came: the default key set in one of these two encodings, or a caller's
+#: own keys, ``"held"`` or ``"checked"``
+_DENSE_ENCODINGS = ("rows", "flat")
 
 #: a keyed op's six phases (:meth:`KVWorker._record_op`)
 _XCHG = ("xchg_enter", "xchg_send", "xchg_await", "xchg_recv", "xchg_wake",
@@ -729,6 +748,9 @@ class KVWorker:
         self._sign_zero_checked = False
         # how default-key ops address the key space (lazy): (keys, vpk)
         self._dense_rows: tuple[np.ndarray, int] | None = None
+        # the key frames this connection keeps (:meth:`hold`), by id: the
+        # array itself and the row space it was checked against
+        self._held: dict[int, tuple[np.ndarray, int]] = {}
         # where kv_last_exchange writes an op's four instants, and
         # kv_last_carried its value-carrying frames (mapped, inline)
         self._xchg = (ctypes.c_double * 4)()
@@ -958,7 +980,9 @@ class KVWorker:
         self.num_servers = hosts.count(",") + 1
         self._epoch = epoch
         # range boundaries moved: the cached dense row encoding must
-        # re-derive
+        # re-derive.  A held key frame stays: what its check guards
+        # (ascending, under ``dim // vpk``) knows no boundary, and ``dim``
+        # is as it was
         self._dense_rows = None
 
     # -- in-place retry (RetryPolicy) -------------------------------------
@@ -1178,7 +1202,7 @@ class KVWorker:
             raise OSError("failed to set KV socket timeout")
         self._timeout_ms = int(timeout_ms)
 
-    def _keyed(self, op: str, native, args: tuple, dense: str | None, *,
+    def _keyed(self, op: str, native, args: tuple, how: str, *,
                sent: int = 0, received: int = 0,
                raw: int | None = None) -> int:
         """One attempt of a keyed op: ``native(handle, *args)``, and, where
@@ -1191,8 +1215,11 @@ class KVWorker:
 
         Latency and outcome (``distlr_ps_client_op_seconds``,
         ``_ops_total``; a failed attempt: :func:`_op_failed`, and nothing
-        else); ``dense``, the encoding a default-key op resolved to
-        (``"rows"``/``"flat"``; None for an op that passed its own keys);
+        else); ``how`` its keys came (:meth:`_resolve_keys`): the encoding
+        a default-key op resolved to (``"rows"``/``"flat"``,
+        ``distlr_ps_dense_frames_total``), or what an op that passed its
+        own keys did with them (``"held"``/``"checked"``,
+        ``distlr_ps_client_key_frames_total``);
         payload bytes ``sent`` and ``received``
         (``distlr_ps_client_bytes_total``); the value-carrying frames by
         carrier (``kv_last_carried``,
@@ -1229,8 +1256,7 @@ class KVWorker:
             sent = lib.kv_last_wire_sent(h)
             account.raw.inc(raw)
             account.wire.inc(sent)
-        if dense is not None:
-            (account.rows if dense == "rows" else account.flat).inc()
+        getattr(account, how).inc()
         if sent:
             account.sent.inc(sent)
         if received:
@@ -1246,8 +1272,13 @@ class KVWorker:
         ``pull``, the comm thread's ``wire``), which they cover but for
         its own entry and exit:
 
-        * ``xchg_enter``: to the native call's start: the frame's keys,
-          the reply's buffer, the retry, trace and counter scopes;
+        * ``xchg_enter``: to the native call's start: the retry, trace
+          and counter scopes, the frame looked up and, without ``out=``,
+          the reply's buffer; for explicit keys that are no held frame
+          (:meth:`hold`) also :meth:`_validate_keys`' passes over them
+          (each gives the interpreter up: 0.1 ms alone over 88,000 keys,
+          0.4-0.5 among four workers), and in a push the conversion of
+          values that are not contiguous float32 yet;
         * ``xchg_send``: to the last request byte handed over (on the
           socket: to the kernel; in a mapping: the values copied into
           the request area and the header in the kernel);
@@ -1303,6 +1334,27 @@ class KVWorker:
                 raise ValueError("keys must be strictly ascending")
         return keys
 
+    def hold(self, keys: np.ndarray, vals_per_key: int = 1) -> np.ndarray:
+        """Take a key set into this connection's keeping: checked here,
+        once (:meth:`_validate_keys`, its errors), and returned as the
+        connection's own contiguous uint64 array, **read-only**.  An op
+        handed that very array, read-only still, with the same
+        ``vals_per_key`` (:meth:`_resolve_keys` looks at the object, its
+        flag and the row space, and at no key) makes no pass over it: for
+        a caller whose key sets are fixed before its rounds begin, as a
+        worker's windows are when its shard is localised.  Anything else
+        (a copy, a slice, the array made writeable again, another
+        ``vals_per_key``, another handle) is checked as any caller's
+        keys are.  A reconnect and a re-route keep the frame
+        (:meth:`_apply_layout`); it lives as long as the handle.  The
+        record is one dict entry and no native call: safe from several
+        threads at once, which the handle's ops are not."""
+        vpk = int(vals_per_key)
+        frame = self._validate_keys(np.array(keys, dtype=np.uint64), vpk)
+        frame.flags.writeable = False
+        self._held[id(frame)] = (frame, self.dim // vpk)
+        return frame
+
     def supports_vals_per_key(self, vpk: int) -> bool:
         """Whether ``vals_per_key=vpk`` ops can be range-sliced over this
         server group: every range boundary (``dim*s/S``) must be a
@@ -1316,31 +1368,40 @@ class KVWorker:
                    for s in range(1, self.num_servers))
 
     def _resolve_keys(self, keys, vpk: int, vals: np.ndarray | None = None):
-        """THE resolver of a keyed op's ``(keys, vals_per_key, dense)``.
+        """THE resolver of a keyed op's ``(keys, vals_per_key, how)``.
         ``keys=None`` addresses the whole dense key space 0..D-1 and
         crosses the wire in the row encoding :meth:`_dense_row_encoding`
-        gives (``dense`` says which: ``"rows"`` or ``"flat"``); explicit
-        keys are validated and sent as given (``dense`` None).  The
+        gives (``how`` says which: ``"rows"`` or ``"flat"``); explicit
+        keys are sent as given, validated here (``"checked"``) unless
+        they are a frame this connection holds (``"held"``): the very
+        array :meth:`hold` returned, read-only still, over the row space
+        it was checked against, on which no pass is made.  The
         default set is FLAT ids by contract — combining it with a
         caller's ``vals_per_key > 1`` would silently reinterpret flat
         ids as row ids, so that combination is rejected rather than
         returning garbage.  ``vals``, where the op carries any, is held
         to the size the keys address."""
         if keys is not None:
-            keys, dense = self._validate_keys(keys, vpk), None
+            held = self._held.get(id(keys))
+            if (held is not None and held[0] is keys
+                    and held[1] == self.dim // vpk
+                    and not keys.flags.writeable):
+                how = "held"
+            else:
+                keys, how = self._validate_keys(keys, vpk), "checked"
         elif vpk != 1:
             raise ValueError(
                 "vals_per_key > 1 requires explicit row keys (the "
                 "dense default key set is flat ids, not rows)")
         else:
             keys, vpk = self._dense_row_encoding()
-            dense = "rows" if vpk > 1 else "flat"
+            how = "rows" if vpk > 1 else "flat"
         if vals is not None and vals.shape[0] != keys.shape[0] * vpk:
             raise ValueError(
                 f"{vals.shape[0]} vals vs "
-                + (f"the {self.dim} default keys" if dense is not None
+                + (f"the {self.dim} default keys" if how in _DENSE_ENCODINGS
                    else f"{keys.shape[0]} keys x vals_per_key {vpk}"))
-        return keys, vpk, dense
+        return keys, vpk, how
 
     def _dense_row_encoding(self) -> tuple[np.ndarray, int]:
         """How a default-key op addresses the dense key space: as runs
@@ -1376,11 +1437,12 @@ class KVWorker:
         moves the range boundaries the rows were cut to, and rows of the
         old layout would straddle the new servers.  Explicit keys are
         the caller's and stay."""
-        return frame if frame[2] is None else self._resolve_keys(None, 1)
+        return (self._resolve_keys(None, 1) if frame[2] in _DENSE_ENCODINGS
+                else frame)
 
     def _push_frame(self, keys: np.ndarray | None, vpk: int,
                     vals: np.ndarray):
-        """A gradient push's ``(keys, vpk, dense)``
+        """A gradient push's ``(keys, vpk, how)``
         (:meth:`_resolve_keys`), after the codec's one-time check of
         what it is asked to code."""
         if self.compress_active == "signsgd" and not self._sign_zero_checked:
@@ -1419,12 +1481,12 @@ class KVWorker:
         frame = self._push_frame(keys, int(vals_per_key), vals)
 
         def _issue():
-            keys, vpk, dense = self._frame_now(frame)
+            keys, vpk, how = self._frame_now(frame)
             return self._keyed(
                 "push", self._lib.kv_push_vpk,
                 (keys.ctypes.data_as(ctypes.c_void_p),
                  vals.ctypes.data_as(ctypes.c_void_p), keys.shape[0], vpk),
-                dense, raw=keys.nbytes + vals.nbytes)
+                how, raw=keys.nbytes + vals.nbytes)
 
         with self._trace_op("push"):
             ts = self._push_with_retry("push", _issue)
@@ -1443,13 +1505,13 @@ class KVWorker:
         frame = self._resolve_keys(keys, 1, vals)
 
         def _issue():
-            keys, vpk, dense = self._frame_now(frame)
+            keys, vpk, how = self._frame_now(frame)
             return self._keyed(
                 "push_init", self._lib.kv_push_init_vpk,
                 (keys.ctypes.data_as(ctypes.c_void_p),
                  vals.ctypes.data_as(ctypes.c_void_p), keys.shape[0],
                  1 if force else 0, vpk),
-                dense, sent=keys.nbytes + vals.nbytes)
+                how, sent=keys.nbytes + vals.nbytes)
 
         # idempotent by protocol design (kInitPush no-ops once seeded;
         # kForceInit re-sends the same vals) -> plain retry is safe
@@ -1473,18 +1535,18 @@ class KVWorker:
         out = np.empty_like(vals)
 
         def _issue():
-            keys, vpk, dense = self._frame_now(frame)
+            keys, vpk, how = self._frame_now(frame)
             self._keyed(
                 "push_pull", self._lib.kv_push_pull_vpk,
                 (keys.ctypes.data_as(ctypes.c_void_p),
                  vals.ctypes.data_as(ctypes.c_void_p),
                  out.ctypes.data_as(ctypes.c_void_p), keys.shape[0], vpk),
-                dense, received=out.nbytes, raw=keys.nbytes + vals.nbytes)
+                how, received=out.nbytes, raw=keys.nbytes + vals.nbytes)
             return out
 
         def _repull():
-            keys, vpk, dense = frame
-            return (self.pull() if dense is not None
+            keys, vpk, how = frame
+            return (self.pull() if how in _DENSE_ENCODINGS
                     else self.pull(keys=keys, vals_per_key=vpk))
 
         # Unknown push outcome: the gradient is lost-or-applied-once
@@ -1497,20 +1559,40 @@ class KVWorker:
         return out
 
     def pull(self, keys: np.ndarray | None = None,
-             *, vals_per_key: int = 1) -> np.ndarray:
+             *, vals_per_key: int = 1,
+             out: np.ndarray | None = None) -> np.ndarray:
         """Blocking pull.  ``vals_per_key=R``: keys are row ids and the
-        result holds ``len(keys)*R`` floats row-major (see :meth:`push`)."""
+        result holds ``len(keys)*R`` floats row-major (see :meth:`push`).
+
+        ``out``: the caller's own C-contiguous float32 buffer of at least
+        that many values.  The reply is written into its head, whichever
+        attempt is answered, and the head's view returned; the rest is
+        not touched.  A buffer that cannot take the reply is a
+        ``ValueError`` before a byte is sent.  Without it every reply is
+        a new array."""
         entered = time.perf_counter()
         frame = self._resolve_keys(keys, int(vals_per_key))
-        out = np.empty(frame[0].shape[0] * frame[1], dtype=np.float32)
+        count = frame[0].shape[0] * frame[1]
+        if out is None:
+            out = np.empty(count, dtype=np.float32)
+        elif not (isinstance(out, np.ndarray) and out.dtype == np.float32
+                  and out.flags.c_contiguous and out.flags.writeable
+                  and out.size >= count):
+            raise ValueError(
+                f"out must be a writeable C-contiguous float32 array of "
+                f"at least {count} values, got "
+                + (f"{out.dtype} shape {out.shape}"
+                   if isinstance(out, np.ndarray) else type(out).__name__))
+        else:
+            out = out.reshape(-1)[:count]
 
         def _issue():
-            keys, vpk, dense = self._frame_now(frame)
+            keys, vpk, how = self._frame_now(frame)
             self._keyed(
                 "pull", self._lib.kv_pull_vpk,
                 (keys.ctypes.data_as(ctypes.c_void_p),
                  out.ctypes.data_as(ctypes.c_void_p), keys.shape[0], vpk),
-                dense, sent=keys.nbytes, received=out.nbytes)
+                how, sent=keys.nbytes, received=out.nbytes)
             return out
 
         with self._trace_op("pull"):

@@ -819,6 +819,41 @@ def _key_count(keys) -> dict:
     return {} if keys is None else {"keys": len(keys)}
 
 
+class _PulledVector:
+    """The one host vector a keyed device step hands its device, of the
+    shard's padded key count: a round's pulled weights at its head, then
+    zeros (the kernel multiplies the whole table by one-hots, so what
+    lies behind the window's keys is an operand too).  The exchange
+    pulls into it (``KVWorker.pull(out=)``) and the step puts it as it
+    stands; a window of fewer keys than the one before zeroes the
+    stretch between, not the vector.
+
+    The fence (``_bind_dense_step``'s, kept for a buffer that is
+    reused): nothing writes ``buf`` between a round's ``device_put`` of
+    it and the return of that round's step, which waits for the gradient
+    and so for the program that read the copy.  The next write is the
+    next round's :meth:`room`, on the same thread, after that."""
+
+    def __init__(self, padded: int):
+        self.buf = np.zeros(padded, np.float32)
+        #: values at the head that may be other than zero
+        self.filled = 0
+
+    def room(self, count: int) -> np.ndarray:
+        """The vector, for a reply of ``count`` values: zeros from
+        ``count`` on."""
+        if count < self.filled:
+            self.buf[count:self.filled] = 0.0
+        self.filled = count
+        return self.buf
+
+    def holds(self, w_u) -> bool:
+        """Whether ``w_u`` is the reply where :meth:`room` had it land:
+        the head's view, as ``pull(out=)`` returned it."""
+        return (getattr(w_u, "base", None) is self.buf
+                and len(w_u) == self.filled)
+
+
 class _Exchange:
     """An exchange is how a round's weights reach :meth:`PSWorker.fit`'s
     one loop and its gradient the servers: ``weights(keys)`` and
@@ -881,7 +916,16 @@ class _Serialized(_Exchange):
     D=200k, B=512: 560-570k serialized vs ~490-520k pipelined): the
     per-op executor handoff under GIL contention costs more than the
     ~50us localhost round trip it hides, and no fused op exists to REMOVE
-    a round trip (pull and push key sets differ per batch)."""
+    a round trip (pull and push key sets differ per batch).
+
+    What a round hands the client was made at load where it could be: a
+    resident keyed shard's window names its keys by the frame the
+    connection holds (``KVWorker.hold``, checked once when the window
+    was localised, :meth:`PSWorker._place_keyed_shard`), so neither op
+    passes over them again, and the reply to its pull lands in the
+    padded vector the device step takes (:class:`_PulledVector`).  Every
+    other caller's keys (a streamed batch's ``np.unique``, a span's
+    union) are new arrays a round and are checked by the op, as ever."""
 
     def weights(self, keys):
         since = time.perf_counter()  # the stamp: from before the pull
@@ -896,7 +940,11 @@ class _Serialized(_Exchange):
     def pull(self, keys):
         w = self.w
         with w._span("pull", **_key_count(keys)):
-            return w.kv.pull(keys=keys, vals_per_key=self.vpk)
+            vector = w._keyed_vector
+            if vector is None or keys is None:
+                return w.kv.pull(keys=keys, vals_per_key=self.vpk)
+            return w.kv.pull(keys=keys, vals_per_key=self.vpk,
+                             out=vector.room(len(keys) * self.vpk))
 
     def push(self, g, keys):
         w = self.w
@@ -1291,7 +1339,9 @@ class PSWorker:
     is open when a keyed operation returns, ``KVWorker`` records that
     operation's six phases, one site for every exchange here
     (``KVWorker._record_op``): ``xchg_enter`` (the op's first
-    instruction in Python to the native call's start), then from the
+    instruction in Python to the native call's start: its scopes; the
+    keys' checks only where they are no frame the connection holds,
+    ``KVWorker.hold``), then from the
     native client's own instants ``xchg_send`` (to the last request byte
     handed to the kernel), ``xchg_await`` (to the first reply header
     read: the servers' read, merge, wait for the round and release) and
@@ -1416,6 +1466,9 @@ class PSWorker:
         #: one key count the compiled step takes the pulled vector padded to
         self._keyed_dev = self._keyed_bases = self._keyed_program = None
         self._keyed_row_bits = self._keyed_key_count = 0
+        #: and the host vector of that count a round's pull lands in
+        #: (``_Serialized.pull``) and its step puts
+        self._keyed_vector: _PulledVector | None = None
         # what the loop's exchange keeps here (``_Exchange``): the flat
         # weights the loop holds now (a span's pull or a fused reply), the
         # staleness stamp of the weights under the next gradient (when
@@ -1666,8 +1719,13 @@ class PSWorker:
         as ``RowKeys.keys`` names them on the wire) and, in the ids'
         stead, each entry's place among them (``host_math.localise``, on
         a few threads), ``row_bits`` to the left: room for the entry's
-        row within the window.  What a round's ``data_load`` then does is
-        look ``_window_keys[j]`` up.
+        row within the window.  The connection takes each window's keys
+        into its keeping there (``KVWorker.hold``): the check the native
+        range slicer needs (ascending, in range) is made here, once a
+        window, on those threads, and the read-only frame it returns is
+        what ``_window_keys`` holds.  What a round's ``data_load`` then
+        does is look ``_window_keys[j]`` up, and the round's pull and
+        push, handed that frame, make no pass over its keys.
 
         Placement, under ``shard_put``: the packed indices (int32) and
         the values (float32) as ``[windows * lines, 128]``, a window's ``B
@@ -1728,7 +1786,7 @@ class PSWorker:
             packed[j, :place.size] = place.reshape(-1)
             if not exact:
                 values[j, :place.size] = vals[at].reshape(-1)
-            return self._rows.keys(keys)
+            return self.kv.hold(self._rows.keys(keys), self._rows.vpk)
 
         with self._span("localise"):
             from concurrent.futures import ThreadPoolExecutor  # noqa: PLC0415
@@ -1777,7 +1835,9 @@ class PSWorker:
     def _keyed_device_step(self, train):
         """The keyed round's device chain, enqueued whole and waited for
         once as the dense step's is (``_bind_dense_step``): the pulled
-        vector padded to the shard's key count and handed over
+        vector, padded to the shard's key count where the reply landed
+        (:class:`_PulledVector`; weights that are not its head, a test's
+        or a probe's own array, are staged into it first), handed over
         (``w_put``), ``jit_ps_keyed_grad_step`` over the round's window
         (``compute``), the readback of the gradient (``grad_d2h``), of
         which the window's own keys' part goes to the push.  The three
@@ -1787,7 +1847,8 @@ class PSWorker:
         cfg = self.cfg
         fn = _compiled_keyed_fns(cfg.l2_c, bool(cfg.l2_scale_by_batch))
         self._jit_probes.append(jaxrt.JitCacheProbe(fn, "train.ps.keyed_grad"))
-        step_dev, padded = self._keyed_dev, self._keyed_key_count
+        step_dev = self._keyed_dev
+        vector = self._keyed_vector = _PulledVector(self._keyed_key_count)
         shape = dict(rows=train.batch_size, row_bits=self._keyed_row_bits,
                      plan=self._keyed_plan(train))
         self._keyed_program = program = (
@@ -1801,10 +1862,10 @@ class PSWorker:
         def grad_step(w_u, window):
             keys = len(w_u)
             with self._span("w_put", keys=keys):
-                # the hand-over (staging, enqueue), not the copy
-                held = np.zeros(padded, np.float32)
-                held[:keys] = w_u
-                w = jax.device_put(held, step_dev)
+                # the hand-over (enqueue), not the copy
+                if not vector.holds(w_u):
+                    vector.room(keys)[:keys] = w_u
+                w = jax.device_put(vector.buf, step_dev)
             with self._span("compute", marks_step=True, keys=keys,
                             program=program):
                 landed = w.is_ready()

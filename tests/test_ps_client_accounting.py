@@ -31,7 +31,8 @@ XCHG = ("xchg_enter", "xchg_send", "xchg_await", "xchg_recv", "xchg_wake",
         "xchg_account")
 COUNTERS = (
     "distlr_ps_client_ops_total", "distlr_ps_client_bytes_total",
-    "distlr_ps_dense_frames_total", "distlr_ps_payload_frames_total",
+    "distlr_ps_dense_frames_total", "distlr_ps_client_key_frames_total",
+    "distlr_ps_payload_frames_total",
     "distlr_ps_push_bytes_raw_total", "distlr_ps_push_bytes_wire_total",
     "distlr_ps_retries_total", "distlr_ps_push_outcome_unknown_total",
 )
@@ -92,7 +93,9 @@ def _expected(kv: KVWorker, op: str, keyed: bool, n_vals: int,
     }
     if op in ("push_pull", "pull"):
         want["distlr_ps_client_bytes_total", (op, "received")] = val_bytes
-    if not keyed:
+    if keyed:  # the caller's own array: checked by the op
+        want["distlr_ps_client_key_frames_total", (op, "checked")] = 1
+    else:
         want["distlr_ps_dense_frames_total", (op, "rows")] = 1
     if op in ("push", "push_pull"):
         want["distlr_ps_push_bytes_raw_total", ()] = key_bytes + val_bytes

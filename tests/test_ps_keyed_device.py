@@ -41,6 +41,8 @@ ROWS = 2 * BATCH + 200          # the last window is short
 WINDOWS = 3
 LINES = 128     # 9,984 entries are 78 lines; two whole grid steps hold them
 ROUNDS = "distlr_ps_grad_rounds_total"
+# sha256[:16] of the keyed step's lowered text over this file's shard
+PINNED_KEYED = "5f0039e7368b4e32"
 
 
 @pytest.fixture(scope="module")
@@ -54,7 +56,7 @@ class Table:
     """A connection whose servers are one array under plain SGD."""
 
     def __init__(self, hosts, dim, **kw):
-        self.dim, self.pulled, self.pushed = dim, [], []
+        self.dim, self.pulled, self.pushed, self.held = dim, [], [], []
         self.table = (np.random.default_rng(3).standard_normal(dim)
                       .astype(np.float32) * 0.1)
 
@@ -65,9 +67,19 @@ class Table:
         return (np.asarray(keys, np.int64)[:, None] * vpk
                 + np.arange(vpk)).reshape(-1)
 
-    def pull(self, keys=None, *, vals_per_key=1):
+    def hold(self, keys, vals_per_key=1):
+        frame = np.array(keys, np.uint64)
+        frame.flags.writeable = False
+        self.held.append(frame)
+        return frame
+
+    def pull(self, keys=None, *, vals_per_key=1, out=None):
         self.pulled.append(np.array(keys))
-        return self.table[self._slots(keys, vals_per_key)].copy()
+        got = self.table[self._slots(keys, vals_per_key)]
+        if out is None:
+            return got.copy()
+        out[:len(got)] = got
+        return out[:len(got)]
 
     def push(self, vals, keys=None, *, vals_per_key=1):
         self.pushed.append((np.array(keys), np.array(vals)))
@@ -413,6 +425,173 @@ def test_four_workers_and_two_servers_conserve_what_was_pushed(
     assert family_total("distlr_ps_keyed_rows_total") - keyed0[1] == 8 * ROWS
     assert family_total("distlr_ps_keyed_keys_total") - keyed0[0] == (
         moved_keys[0])
+
+
+# -- a round's frame is made at load ------------------------------------------
+FRAMES = "distlr_ps_client_key_frames_total"
+
+
+def _frames():
+    return {labels: child.value
+            for labels, child in get_registry().get(FRAMES).children()}
+
+
+class _Tap:
+    """Round a worker's connection as the benchmark's ``WireTap`` stands
+    (``chipbench/drivers/ps_keyed_epochs.py``): ``_pull(keys=None, **kw)``
+    and ``_push(vals, keys=None, **kw)``, every other keyword passed on,
+    copies of what it sees taken with ``np.array`` as the round goes."""
+
+    def __init__(self, worker):
+        self.worker, self.pulls, self.pushes = worker, [], []
+        self.calls = {name: getattr(worker.kv, name)
+                      for name in ("pull", "push")}
+        worker.kv.pull, worker.kv.push = self._pull, self._push
+
+    def _pull(self, keys=None, **kw):
+        got = self.calls["pull"](keys=keys, **kw)
+        self.pulls.append((np.array(keys), np.array(got), len(keys)))
+        return got
+
+    def _push(self, vals, keys=None, **kw):
+        self.pushes.append((np.array(keys), np.array(vals)))
+        assert np.array_equal(keys, self.pulls[-1][0])
+        return self.calls["push"](vals, keys=keys, **kw)
+
+    def remove(self):
+        for name in self.calls:
+            delattr(self.worker.kv, name)  # the class's own again
+
+
+def test_two_epochs_after_load_check_no_key_and_count_only_held_frames(
+        rows, ps_steps_on_device):
+    """A worker against two native servers: what ``load_data`` held is
+    what every round's pull and push are handed, so ``_validate_keys``
+    runs zero times in ``fit`` and the counter moves under ``held``
+    alone; a tap in the benchmark's shape sees each window's keys, the
+    servers' weights at them and the gradient pushed."""
+    cfg = _cfg(num_workers=1, num_servers=2)
+    w0 = (np.random.default_rng(9).standard_normal(DIM) * 0.05).astype(
+        np.float32)
+    cols = _shard(rows)[0]
+    want_keys = [np.unique(cols[j * BATCH:(j + 1) * BATCH])
+                 for j in range(WINDOWS)]
+    with ServerGroup(2, 1, DIM, learning_rate=LR, sync=False) as group:
+        w = ps_trainer.PSWorker(
+            cfg, 0, group.hosts,
+            train_iter=SparseDataIter(*_shard(rows), BATCH),
+            test_iter=SparseDataIter(*_shard(rows, 1), -1))
+        try:
+            checked = []
+            real = w.kv._validate_keys
+            w.kv._validate_keys = lambda keys, vpk=1: (
+                checked.append(len(keys)), real(keys, vpk))[1]
+            w.load_data()
+            # the check was made at load, once a window (on the pool's
+            # threads, in their order)
+            assert sorted(checked) == sorted(len(k) for k in want_keys)
+            for frame, want in zip(w._window_keys, want_keys):
+                assert type(frame) is np.ndarray and frame.dtype == np.uint64
+                assert not frame.flags.writeable
+                assert np.array_equal(frame, want)
+                assert w.kv._resolve_keys(frame, 1)[2] == "held"
+            del checked[:]
+            w.kv.wait(w.kv.push_init(w0))
+            tap = _Tap(w)
+            before = _frames()
+            w.fit(epochs=2)
+            tap.remove()
+            assert checked == []
+            moved = {k: v - before.get(k, 0) for k, v in _frames().items()
+                     if v != before.get(k, 0)}
+            assert moved == {("pull", "held"): 2 * WINDOWS,
+                             ("push", "held"): 2 * WINDOWS}
+            table = w.kv.pull()
+        finally:
+            w.close()
+    # one worker: the servers' table is replayed from what the tap saw
+    sim = w0.copy()
+    assert len(tap.pulls) == len(tap.pushes) == 2 * WINDOWS
+    for i, ((keys, got, n), (pushed, g)) in enumerate(
+            zip(tap.pulls, tap.pushes)):
+        assert np.array_equal(keys, want_keys[i % WINDOWS])
+        assert n == len(keys) == len(got) == len(g)
+        assert np.array_equal(pushed, keys)
+        at = keys.astype(np.int64)
+        assert np.array_equal(got.view(np.uint32), sim[at].view(np.uint32))
+        assert np.count_nonzero(g)
+        sim[at] -= np.float32(LR) * g
+    assert np.array_equal(table.view(np.uint32), sim.view(np.uint32))
+
+
+def test_the_vector_the_step_is_handed_is_zeros_behind_the_pulled_weights(
+        monkeypatch, rows, ps_steps_on_device):
+    """A window of fewer keys after one of more, and the other way round
+    (the short last window, then the first again): what reaches
+    ``device_put`` is ``np.zeros(padded)`` with the pulled weights at its
+    head, as bits, every round; and the same for weights that are an
+    array of the caller's own."""
+    cols, vals, y = _shard(rows)
+    w = _worker(monkeypatch, SparseDataIter(cols, vals, y, BATCH))
+    padded = w._keyed_key_count
+    counts = [len(k) for k in w._window_keys]
+    assert counts[2] < counts[1] and counts[2] < counts[0] <= padded
+    # no zero among the table's values: a stale tail would show
+    assert np.count_nonzero(w.kv.table) == DIM
+    put, handed = [], []
+    real_put, step = jax.device_put, w.grad_step
+
+    def device_put(x, *a, **kw):
+        if isinstance(x, np.ndarray) and x.shape == (padded,):
+            put.append(np.array(x))
+        return real_put(x, *a, **kw)
+
+    def grad_step(w_u, window):
+        handed.append((np.array(w_u), w_u))
+        return step(w_u, window)
+
+    monkeypatch.setattr(jax, "device_put", device_put)
+    w.grad_step = grad_step
+    w.fit(epochs=2)
+    assert len(put) == len(handed) == 2 * WINDOWS
+    for i, (vector, (bits, w_u)) in enumerate(zip(put, handed)):
+        n = counts[i % WINDOWS]
+        assert len(bits) == n and w_u.base is w._keyed_vector.buf
+        want = np.zeros(padded, np.float32)
+        want[:n] = bits
+        assert np.array_equal(vector.view(np.uint32), want.view(np.uint32))
+    # the pulled weights were the table's at the window's keys
+    for (bits, _), keys in zip(handed, w.kv.pulled):
+        assert len(bits) == len(keys)
+    # a caller's own array after the longest window: staged, zeros behind
+    del put[:]
+    own = np.full(counts[2] - 5, 0.25, np.float32)
+    got = step(own, Window(2 * BATCH, ROWS - 2 * BATCH))
+    want = np.zeros(padded, np.float32)
+    want[:len(own)] = own
+    assert len(put) == 1 and np.array_equal(put[0].view(np.uint32),
+                                            want.view(np.uint32))
+    assert got.shape == own.shape
+    w.close()
+
+
+@pytest.mark.skipif(jax.__version__ != "0.9.0",
+                    reason="the pinned text is jax 0.9.0's")
+def test_the_keyed_steps_lowered_program_is_the_parents(
+        monkeypatch, rows, ps_steps_on_device):
+    """The text ``_compiled_keyed_fns`` lowers to for this file's shard,
+    hashed on the commit before the held frames (PR 55) and here: where
+    the pulled vector is made moved, what the device runs did not."""
+    cols, vals, y = _shard(rows)
+    w = _worker(monkeypatch, SparseDataIter(cols, vals, y, BATCH))
+    fn = ps_trainer._compiled_keyed_fns(0.0, False)
+    sd = jax.ShapeDtypeStruct
+    leaves = [sd(a.shape, a.dtype) for a in (*w._resident, w._keyed_bases)]
+    text = fn.lower(
+        sd((w._keyed_key_count,), jnp.float32), *leaves, sd((), jnp.int32),
+        rows=BATCH, row_bits=w._keyed_row_bits, plan=None).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PINNED_KEYED
+    w.close()
 
 
 def test_numpys_step_in_the_device_steps_place_is_counted_as_the_hosts(

@@ -7,6 +7,7 @@ flat vector the wire carries, whatever the model's shape: a gauge says
 where that shape is restored."""
 
 import collections
+import threading
 
 import jax
 import numpy as np
@@ -285,3 +286,162 @@ def test_the_client_returns_a_new_array_every_call(op):
             assert np.array_equal(first, bits)  # the later reply left it be
         finally:
             kv.close()
+
+
+# -- the keyed chain: a vector that is kept ----------------------------------
+K_DIM, K_BATCH, K_ROWS, K_WORKERS, K_EPOCHS = 2048, 128, 2 * 128 + 70, 2, 3
+
+
+def _keyed_job():
+    """Two ``sparse_lr`` workers over resident localised shards of three
+    windows (the last short), against two native servers."""
+    from distlr_tpu.data.iterator import SparseDataIter
+
+    rng = np.random.default_rng(17)
+    cfg = Config(model="sparse_lr", num_feature_dim=K_DIM, batch_size=K_BATCH,
+                 learning_rate=0.2, l2_c=0.0, test_interval=0,
+                 num_workers=K_WORKERS, num_servers=2, sync_mode=False)
+
+    def shard():
+        cols = rng.integers(0, K_DIM, (K_ROWS, 9))
+        vals = rng.standard_normal(cols.shape).astype(np.float32)
+        y = rng.integers(0, 2, K_ROWS).astype(np.int32)
+        return cols, vals, y
+
+    def workers(hosts):
+        return [PSWorker(cfg, r, hosts,
+                         train_iter=SparseDataIter(*shard(), K_BATCH),
+                         test_iter=SparseDataIter(*shard(), -1))
+                for r in range(K_WORKERS)]
+
+    return cfg, workers
+
+
+class _Fence:
+    """What touches a worker's pulled vector, in order, with the vector's
+    bits where it is handed over and where the round's wait returns."""
+
+    def __init__(self, worker):
+        self.worker, self.vector = worker, worker._keyed_vector
+        self.events, self.thread = [], None
+        room, pull, step = (self.vector.room, worker.kv.pull,
+                            worker.grad_step)
+
+        def tapped_room(count):
+            self.events.append(("room", count))
+            return room(count)
+
+        def tapped_pull(keys=None, **kw):
+            self.events.append(("pull", kw.get("out") is self.vector.buf))
+            got = pull(keys=keys, **kw)
+            self.events.append(("pulled", got.base is self.vector.buf))
+            return got
+
+        def tapped_step(w_u, window):
+            self.thread = threading.get_ident()
+            self.events.append(("step", self.vector.holds(w_u)))
+            g = step(w_u, window)
+            self.events.append(("stepped", self.bits()))
+            return g
+
+        self.vector.room, worker.kv.pull = tapped_room, tapped_pull
+        worker.grad_step = tapped_step
+
+    def bits(self):
+        return self.vector.buf.tobytes()
+
+
+def test_nothing_writes_the_pulled_vector_while_a_round_may_read_it(
+        monkeypatch):
+    """The fence of the dense chain, for a buffer that is reused: between
+    the ``device_put`` of a worker's vector and the return of that round's
+    ``block_until_ready`` nothing writes it (its bits are the same at
+    both and at the step's return), and what does write it (the room made
+    for the next reply, the reply) comes after, in that order, every
+    round."""
+    cfg, make = _keyed_job()
+    with _group(cfg) as group:
+        workers = make(group.hosts)
+        try:
+            for w in workers:
+                w.load_data()
+                assert w._keyed_vector is not None and w._windowed
+            fences = {id(w._keyed_vector.buf): _Fence(w) for w in workers}
+
+            def by_thread():
+                return next((f for f in fences.values()
+                             if f.thread == threading.get_ident()), None)
+
+            real_put, real_ready = jax.device_put, jax.block_until_ready
+
+            def device_put(x, *a, **kw):
+                fence = fences.get(id(x))
+                if fence is not None:
+                    fence.events.append(("put", fence.bits()))
+                return real_put(x, *a, **kw)
+
+            def block_until_ready(x):
+                got = real_ready(x)
+                fence = by_thread()
+                if fence is not None:
+                    fence.events.append(("ready", fence.bits()))
+                return got
+
+            monkeypatch.setattr(jax, "device_put", device_put)
+            monkeypatch.setattr(jax, "block_until_ready", block_until_ready)
+            _in_threads(workers, lambda w: (w.start(), w.fit(epochs=K_EPOCHS)))
+            monkeypatch.undo()
+        finally:
+            for w in workers:
+                w.close()
+    windows = -(-K_ROWS // K_BATCH)
+    for fence in fences.values():
+        names = [name for name, _ in fence.events]
+        round_ = ["room", "pull", "pulled", "step", "put", "ready", "stepped"]
+        assert names == round_ * (K_EPOCHS * windows)
+        rounds = [fence.events[i:i + 7] for i in range(0, len(names), 7)]
+        counts = []
+        for (_, n), (_, into), (_, view), (_, held), (_, put), (_, ready), (
+                _, stepped) in rounds:
+            # the reply landed in the vector, and the step took it there
+            assert into and view and held
+            assert put == ready == stepped
+            vector = np.frombuffer(put, np.float32)
+            assert not vector[n:].any()
+            counts.append(n)
+        # (the model starts at zero weights: the later rounds' are not)
+        assert np.count_nonzero(np.frombuffer(rounds[-1][4][1], np.float32))
+        # windows of fewer keys after more, and of more after fewer
+        assert any(a > b for a, b in zip(counts, counts[1:]))
+        assert any(a < b for a, b in zip(counts, counts[1:]))
+
+
+def test_the_pulled_vector_written_over_afterwards_changes_nothing(
+        monkeypatch):
+    """The fence from outside: once the step has returned the vector is
+    free, and writing it over moves neither the gradient that came back
+    nor the one the same weights give from an array of their own."""
+    cfg, make = _keyed_job()
+    with _group(cfg) as group:
+        w, _ = workers = make(group.hosts)
+        try:
+            w.load_data()
+            keys = w._window_keys[0]
+            w.kv.wait(w.kv.push_init(
+                (np.random.default_rng(3).standard_normal(K_DIM) * 0.1
+                 ).astype(np.float32)))
+            vector = w._keyed_vector
+            w_u = w.kv.pull(keys=keys, out=vector.room(len(keys)))
+            assert vector.holds(w_u)
+            own = np.array(w_u)
+            got = w.grad_step(w_u, Window(0, K_BATCH))
+            kept = np.array(got)
+            vector.buf[:] = np.nan
+            assert np.array_equal(got, kept) and np.isfinite(got).all()
+            # the staged form (an array of the caller's own): the same bits
+            again = w.grad_step(own, Window(0, K_BATCH))
+            assert np.array_equal(again.view(np.uint32), kept.view(np.uint32))
+            assert np.count_nonzero(kept)
+        finally:
+            for w in workers:
+                w.close()
