@@ -157,17 +157,28 @@ class Config:
     # Keyed models (sparse/blocked) ignore this (their pull and push key
     # sets differ per batch).
     ps_pipeline: bool = True
-    # Bounded-delay consistency for the sync (BSP) dense job (Li et al.,
-    # OSDI 2014: a worker may start round k+1 before its push of round k
-    # is acknowledged, but not before round k-tau is).  0 = lock step
-    # (every trajectory as pinned).  1 = a worker computes round k on the
-    # weights after round k-2 while its push of round k-1 stands at the
-    # servers' barrier: the servers still merge W pushes and apply one
-    # mean update a round, and every worker's round k still runs on the
-    # same weights, bit for bit, so the run has a trajectory (another one
-    # than lock step's).  Nothing is in flight at an eval, a checkpoint or
-    # fit's return.  tau >= 2 would need a server that holds two open
-    # rounds.
+    # Bounded-delay consistency, tau = 1 (Li et al., OSDI 2014, 3.4: a
+    # worker may start round k+1 before its push of round k is
+    # acknowledged, but not before round k-tau is), on two planes.  0 =
+    # none (every trajectory as pinned).  1 on the sync (BSP) dense job:
+    # a worker computes round k on the weights after round k-2 while its
+    # push of round k-1 stands at the servers' barrier: the servers still
+    # merge W pushes and apply one mean update a round, and every
+    # worker's round k still runs on the same weights, bit for bit, so
+    # the run has a trajectory (another one than lock step's).  1 on the
+    # asynchronous keyed sparse_lr job over a resident, windowed shard:
+    # the comm thread pushes round k's gradient and then pulls round
+    # k+2's keys while the loop computes round k+1, so the weights under
+    # round k reflect this worker's own pushes through round k-2 whole
+    # and none later (one own push behind, stated and held; peers'
+    # pushes as they arrive); a streamed, shuffled or too-large shard is
+    # refused by load_data, not serialized silently.  Either way one
+    # connection carries one operation at a time and nothing is in
+    # flight at an eval, a checkpoint or fit's return.  Refused with:
+    # the asynchronous dense job, keyed BSP, blocked_lr and
+    # sparse_softmax (numpy steps), ps_pipeline off, ps_accum_max > 1,
+    # sync_last_gradient, a coded wire.  tau >= 2 would need a server
+    # that holds two open rounds, or a third vector a keyed worker.
     # 0 | 1
     ps_max_delay: int = 0
     # Per-op receive timeout. A dead peer otherwise deadlocks the sync
@@ -470,22 +481,34 @@ class Config:
         if self.ps_max_delay not in (0, 1):
             raise ValueError(
                 f"ps_max_delay must be 0 or 1, got {self.ps_max_delay!r}: "
-                "one connection carries one operation at a time and a "
-                "server holds one open round, so a worker can be one "
-                "round ahead of its acknowledgements and no more")
+                "one connection carries one operation at a time; a lock-"
+                "step server holds one open round, and a keyed worker's "
+                "two vectors hold the round under the step and the next, "
+                "so a worker can be one of its own pushes ahead of what "
+                "its weights reflect and no more")
         if self.ps_max_delay:
+            keyed = self.model in ("sparse_lr", "sparse_softmax",
+                                   "blocked_lr")
             why = next((why for refused, why in (
-                (not self.sync_mode,
-                 "needs sync_mode: it bounds the delay of BSP rounds; an "
-                 "asynchronous push carried across an epoch's end is "
-                 "another lineage rule and no trajectory"),
-                (self.model in ("sparse_lr", "sparse_softmax", "blocked_lr"),
-                 f"is for dense models: a {self.model} round pulls and "
-                 "pushes its own batch's rows, so no fused reply holds the "
-                 "next round's weights"),
+                (not self.sync_mode and not keyed,
+                 "needs sync_mode for a dense model: it bounds the delay "
+                 "of BSP rounds; the asynchronous dense job is pipelined "
+                 "one push deep already (ps_pipeline), and carrying that "
+                 "push across an epoch's end is another lineage rule"),
+                (self.sync_mode and keyed,
+                 f"in lock step is for dense models: a {self.model} "
+                 "round pulls and pushes its own batch's rows, so no "
+                 "fused reply holds the next round's weights, and a keyed "
+                 "pull issued under a withheld BSP push is not written; "
+                 "the keyed delayed exchange is the asynchronous "
+                 "sparse_lr job's (sync_mode: false)"),
+                (keyed and self.model != "sparse_lr",
+                 f"is written for sparse_lr alone among the keyed models: "
+                 f"a {self.model} step is numpy's on the host and holds "
+                 "the interpreter, so no exchange would run under it"),
                 (not self.ps_pipeline,
                  "needs ps_pipeline: the serialized pull-then-push has no "
-                 "fused reply for the next round to run under"),
+                 "comm thread for the next round to run beside"),
                 (self.ps_accum_max > 1,
                  "is incompatible with ps_accum_max > 1: a span already "
                  "runs its rounds on the span's one pull, and a delayed "

@@ -170,7 +170,10 @@ class PhaseTracer:
             "data_load, round and inside it w_put, compute, grad_d2h, push, "
             "pull; epoch_end and inside it eval, checkpoint; "
             "staleness_probe; wire on the comm thread, wire_handoff and "
-            "reply_wake to and from it; a keyed op's xchg_enter, xchg_send, "
+            "reply_wake to and from it; under the keyed bounded delay "
+            "exchange_wait in push's stead, the loop's hand-over to and "
+            "wait for its comm thread, whose wire then holds a push and a "
+            "pull; a keyed op's xchg_enter, xchg_send, "
             "xchg_await, xchg_recv, xchg_wake, xchg_account)",
             labelnames=("phase",),
         )

@@ -1202,6 +1202,16 @@ class KVWorker:
             raise OSError("failed to set KV socket timeout")
         self._timeout_ms = int(timeout_ms)
 
+    def acknowledged(self, op: str) -> int:
+        """How many ``op`` (``"push"``, ``"pull"``, ...) this handle has
+        had answered so far: its own share of ``distlr_ps_client_ops_total
+        {op, status="ok"}`` (:class:`_OpAccount`), which :meth:`_keyed`
+        counts as the op returns.  One connection carries one operation
+        at a time, so read on the connection's thread between two ops it
+        is the op sequence's own count."""
+        account = self._accounts.get(op)
+        return 0 if account is None else int(account.ok.value)
+
     def _keyed(self, op: str, native, args: tuple, how: str, *,
                sent: int = 0, received: int = 0,
                raw: int | None = None) -> int:

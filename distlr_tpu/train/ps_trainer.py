@@ -24,6 +24,7 @@ Worker lifecycle parity:
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import importlib
@@ -230,6 +231,18 @@ _DELAYED_ROUNDS = get_registry().counter(
     "and counts under behind=\"0\", every other round, round 1 included, "
     "runs on the weights from before the push still at the servers and "
     "counts under \"1\", so a fit of E rounds adds 1 and E - 1",
+    labelnames=("rank", "behind"),
+)
+_KEYED_LINEAGE = get_registry().counter(
+    "distlr_ps_keyed_pull_lineage_total",
+    "keyed pulls of a sparse_lr worker under bounded delay "
+    "(ps_max_delay=1), by how many of this worker's own pushes the reply "
+    "is behind: the rounds of this fit before the pull's own, less the "
+    "pushes the connection had had acknowledged at the instant the pull "
+    "was issued (KVWorker.acknowledged: the connection's op sequence, not "
+    "the exchange's book); a fit's first pull counts under behind=\"0\" "
+    "and every other under \"1\", so a fit of E rounds adds 1 and E - 1, "
+    "and any other label is a broken lineage",
     labelnames=("rank", "behind"),
 )
 _PANEL_HELD = get_registry().gauge(
@@ -820,19 +833,27 @@ def _key_count(keys) -> dict:
 
 
 class _PulledVector:
-    """The one host vector a keyed device step hands its device, of the
+    """A host vector a keyed device step hands its device, of the
     shard's padded key count: a round's pulled weights at its head, then
     zeros (the kernel multiplies the whole table by one-hots, so what
     lies behind the window's keys is an operand too).  The exchange
     pulls into it (``KVWorker.pull(out=)``) and the step puts it as it
     stands; a window of fewer keys than the one before zeroes the
-    stretch between, not the vector.
+    stretch between, not the vector.  A worker keeps ONE
+    (``PSWorker._keyed_ring``, of one) and under bounded delay a **ring
+    of two**: round *k*'s weights live in vector *k* mod 2.
 
     The fence (``_bind_dense_step``'s, kept for a buffer that is
     reused): nothing writes ``buf`` between a round's ``device_put`` of
     it and the return of that round's step, which waits for the gradient
-    and so for the program that read the copy.  The next write is the
-    next round's :meth:`room`, on the same thread, after that."""
+    and so for the program that read the copy.  Serialized, the next
+    write is the next round's :meth:`room`, on the same thread, after
+    that.  Under bounded delay (:class:`_KeyedDelayed`) the writer is
+    the comm thread, and the ring is what keeps it off the vector being
+    read: while the loop puts and steps vector *k* mod 2 the one pull
+    the comm thread may run is ``L_{k+1}``, into vector (*k* + 1) mod 2;
+    ``L_{k+2}``, the next writer of vector *k* mod 2, is handed to the
+    comm thread only by round *k*'s ``send``, after its step returned."""
 
     def __init__(self, padded: int):
         self.buf = np.zeros(padded, np.float32)
@@ -867,7 +888,8 @@ class _Exchange:
     but under bounded delay (:class:`_Delayed`), where an epoch is no
     boundary and the loop's epochs decide no round's staleness.  What
     outlives a ``fit`` stays on the worker: what the benchmark reads
-    (``_w_cache``, ``_comm``, ``_in_flight``) and the staleness stamp
+    (``_w_cache``, ``_comm``, ``_in_flight``, ``_keyed_flight``) and the
+    staleness stamp
     (``_w_time``, ``_w_pushes``); its ``kv.pull`` / ``kv.push_pull`` /
     ``_comm_pool()`` are looked up at
     every call: taps replace them on the instance.  Each variant stamps
@@ -908,15 +930,25 @@ class _Serialized(_Exchange):
     """The reference's protocol (``src/lr.cc:116-132``): pull,
     then push and wait, two blocking round trips a round.  Dense with
     ``ps_pipeline=False``, and every keyed model in BOTH modes, its step
-    on the host or on the device (a keyed exchange under the next
-    window's step is not written).  Sync: a
+    on the host or on the device, unless the configuration states a
+    bounded delay: the asynchronous ``sparse_lr`` job over a resident,
+    windowed shard then runs :class:`_KeyedDelayed` (``ps_max_delay=1``),
+    which is another result and not a faster form of this one.  Sync: a
     pull issued before the round's push would read pre-round weights and
-    change the BSP trajectory.  Async: a comm-thread pipeline (pull k+1
-    overlapping grad k) was measured ~10% SLOWER at CTR scale (4 workers,
-    D=200k, B=512: 560-570k serialized vs ~490-520k pipelined): the
-    per-op executor handoff under GIL contention costs more than the
-    ~50us localhost round trip it hides, and no fused op exists to REMOVE
-    a round trip (pull and push key sets differ per batch).
+    change the BSP trajectory.  Async, where no delay is stated: a pull
+    issued before the worker's own push is acknowledged returns other
+    weights, so nothing here overlaps; and no fused op exists to REMOVE
+    a round trip (pull and push key sets differ per batch).  What a comm
+    thread is worth to a keyed round depends on who holds the
+    interpreter: with numpy's step on the host (4 workers, D=200k,
+    B=512) a comm-thread pipeline read ~10% SLOWER (560-570k serialized
+    vs ~490-520k pipelined: the step held the GIL and the per-op executor
+    hand-off cost more than the ~50us round trip it hid); with the step
+    on the chip (the loop waits in ``block_until_ready``, the comm thread
+    in a ctypes call) the delayed exchange hides four fifths of the wire
+    and still gains only a few percent at four workers a process, because
+    eight threads then share one interpreter and every hand-over waits
+    for it (PERF.md section 6, PR 57).
 
     What a round hands the client was made at load where it could be: a
     resident keyed shard's window names its keys by the frame the
@@ -1157,6 +1189,184 @@ class _Delayed(_Pipelined):
             self.arrived(early)
 
 
+class _KeyedDelayed(_Exchange):
+    """The asynchronous keyed ``sparse_lr`` job under bounded delay, tau =
+    1 (``ps_max_delay``; Li et al., OSDI 2014, 3.4): the exchange on the
+    comm thread, under the step.  Rounds *k* = 0 ... *R* - 1 are numbered
+    inside one ``fit``; round *k* reads window *j*(*k*) = *k* mod
+    ``len(_window_keys)`` of the resident shard (an epoch is no
+    boundary), ``K_k`` is that window's keys, ``L_k`` the keyed pull of
+    ``K_k`` and ``P_k`` the keyed push of ``(K_k, g_k)``.  The worker's
+    one connection carries, each op blocking and whole, one at a time:
+
+        L_0, L_1, P_0, L_2, P_1, L_3, ..., P_{R-3}, L_{R-1}, P_{R-2}, P_{R-1}
+
+    ``L_{k+1}`` is issued after ``P_{k-1}`` is acknowledged and before
+    ``P_k`` is issued, so ``v_k``, the reply to ``L_k`` and the weights
+    under ``g_k``, reflects this worker's own pushes 0 ... *k* - 2 whole
+    and none later: **exactly one own push behind** for every *k* >= 1,
+    none for *k* = 0; peers' pushes as their arrival has them (the
+    servers apply on arrival, as ever).  ``g_k`` is the window's gradient
+    at ``v_k``.  No pull is issued for a round that will not run.
+
+    How: round *k*'s ``send`` hands the comm thread ONE task, ``P_k``
+    then ``L_{k+2}`` (``wire``, with ``push`` and ``pull`` inside, each
+    under the step of the round it is for), and then takes the reply to
+    ``L_{k+1}``, which the task before ran under round *k*'s ``w_put``,
+    ``compute`` and ``grad_d2h``: both inside one ``exchange_wait`` span,
+    all of the exchange the loop still sees.  The first ``weights`` of a
+    ``fit`` hands over ``L_0`` and ``L_1``, a task each, and waits for
+    the first.  ``L_k`` lands in vector *k* mod 2 of the worker's ring
+    (:class:`_PulledVector`: the fence).  The next windows' keys are the
+    exchange's own to look up (``_window_keys``, by its count of rounds
+    begun; the loop's keys are held to them, by identity).
+
+    ``drain`` (rank 0's eval, a checkpoint) waits until nothing of this
+    worker's is at the servers; a reply that is in by then, one or two
+    rounds early, is still the reply of its round, so where the
+    observers fall changes no op's place in the order.  ``epoch_end``
+    does nothing.  ``finish`` is a drain that leaves no reply behind.
+    The stamp is taken where the pull is issued and the age where the
+    push is (on the comm thread, carried with the round).  Counted in
+    ``distlr_ps_keyed_pull_lineage_total{rank, behind}`` from the
+    connection's own count of acknowledged pushes at each pull's issue.
+    A task that raises breaks the exchange: the tasks behind it do
+    nothing, and the loop's next wait raises what it raised."""
+
+    def __init__(self, worker, rounds: int = 0):
+        super().__init__(worker)
+        #: rounds this fit runs, begun so far, and the step of round 0
+        self.rounds, self.begun, self.step0 = rounds, 0, 0
+        self.windows = worker._window_keys
+        #: round -> the future of the task that pulls its weights
+        self.pulls: dict = {}
+        #: the reply taken for the round about to begin: (weights,
+        #: when its pull was issued, the group's push clock after it)
+        self.taken = None
+        self.broken = False
+        self.acked0 = worker.kv.acknowledged("push")
+
+    # -- the loop's side --------------------------------------------------
+    def weights(self, keys):
+        w, k = self.w, self.begun
+        if k == 0:
+            self.step0 = w.rounds
+            with w._span("exchange_wait", keys=len(keys)):
+                for j in range(min(2, self.rounds)):
+                    self._submit(pull=j)
+                self.taken = self._take(0)
+        if keys is not self.windows[k % len(self.windows)]:
+            raise RuntimeError(
+                f"rank {w.rank}: round {k} of this fit reads another "
+                "window than the delayed exchange pulled for")
+        self.begun = k + 1
+        (reply, w._w_time, w._w_pushes), self.taken = self.taken, None
+        return reply
+
+    def send(self, g, keys):
+        w, k = self.w, self.begun - 1
+        ahead = k + 2 if k + 2 < self.rounds else None
+        with w._span("exchange_wait", keys=len(keys)):
+            self._submit(push=(k, g, keys, w._w_time, w._w_pushes),
+                         pull=ahead)
+            if k + 1 < self.rounds:
+                self.taken = self._take(k + 1)
+
+    def epoch_end(self):
+        pass
+
+    def drain(self):
+        flight = self.w._keyed_flight
+        if all(f.done() for f in flight):
+            self._settle()
+            return
+        # no round's step is left to hide what is still out
+        with self.w._span("exchange_wait", drain=1):
+            self._settle()
+
+    def finish(self):
+        self.drain()
+        if self.pulls:
+            raise RuntimeError(
+                f"rank {self.w.rank}: replies for rounds "
+                f"{sorted(self.pulls)} that did not run are left")
+
+    def _submit(self, push=None, pull=None) -> None:
+        w = self.w
+        fut = w._comm_pool().submit(
+            self._wire, dtrace.current(), w.rounds, time.perf_counter(),
+            push, pull)
+        w._keyed_flight.append(fut)
+        if pull is not None:
+            self.pulls[pull] = fut
+
+    def _take(self, k: int):
+        """The reply to ``L_k``, waited for inside the caller's
+        ``exchange_wait``; ``reply_wake`` as :meth:`_Pipelined._reply`
+        records it, from the task's own end."""
+        fut = self.pulls.pop(k)
+        reply, done = fut.result()
+        tracer = get_tracer()
+        there = max(tracer.opened_at(), done)
+        tracer.completed("reply_wake", there, time.perf_counter() - there)
+        if fut in self.w._keyed_flight:
+            self._settle(fut)
+        return reply
+
+    def _settle(self, upto=None) -> None:
+        """Wait for the tasks out, oldest first (through ``upto``), and
+        raise what one of them raised."""
+        flight = self.w._keyed_flight
+        while flight:
+            head = flight.popleft()
+            head.result()
+            if head is upto:
+                break
+
+    # -- the comm thread's side -------------------------------------------
+    def _wire(self, ctx, step, submitted, push, pull):
+        """One task: ``P_k`` then ``L_{k+2}`` (either may be absent),
+        under the submitting round's trace context and step.  Returns
+        the pull's ``(weights, issued at, push clock)`` or None, and
+        when the task ended."""
+        if self.broken:
+            raise RuntimeError("an earlier exchange of this fit failed")
+        w, got = self.w, None
+        try:
+            with dtrace.use(ctx), loop_span("wire", step, rank=w.rank):
+                tracer = get_tracer()
+                tracer.completed("wire_handoff", submitted,
+                                 tracer.opened_at() - submitted,
+                                 inside=False)
+                if push is not None:
+                    self._push(*push)
+                if pull is not None:
+                    got = self._pull(pull)
+        except BaseException:
+            self.broken = True
+            raise
+        return got, time.perf_counter()
+
+    def _push(self, k, g, keys, since, clock) -> None:
+        w = self.w
+        self._age.set(time.perf_counter() - since)
+        w._record_pushes_behind(clock)
+        with loop_span("push", self.step0 + k, rank=w.rank, keys=len(keys)):
+            w.kv.wait(w.kv.push(g, keys=keys, vals_per_key=self.vpk))
+
+    def _pull(self, k):
+        w = self.w
+        keys = self.windows[k % len(self.windows)]
+        vector = w._keyed_ring[k % 2]
+        behind = k - (w.kv.acknowledged("push") - self.acked0)
+        _KEYED_LINEAGE.labels(rank=str(w.rank), behind=str(behind)).inc()
+        since = time.perf_counter()  # the stamp: from before the pull
+        with loop_span("pull", self.step0 + k, rank=w.rank, keys=len(keys)):
+            reply = w.kv.pull(keys=keys, vals_per_key=self.vpk,
+                              out=vector.room(len(keys) * self.vpk))
+        return reply, since, w._sample_push_clock()
+
+
 class PSWorker:
     """One worker's training loop against a KV server group.
 
@@ -1285,7 +1495,8 @@ class PSWorker:
     ``_Exchange`` and its variants say what each does: serialized, a
     span's mean, fused in lock step, pipelined in the asynchronous job,
     and with ``ps_max_delay=1`` the pipeline against BSP servers,
-    :class:`_Delayed`); how a keyed
+    :class:`_Delayed`, or the asynchronous ``sparse_lr`` job's exchange
+    under the step, :class:`_KeyedDelayed`); how a keyed
     model's rows cross the wire is ``RowKeys``' to say.
 
     Spans (``obs.tracing.loop_span``: ``PhaseTracer`` and, while a
@@ -1309,8 +1520,10 @@ class PSWorker:
     the copy), ``compute`` (dispatch to the worker's own program
     finished, the rest of the weights' copy before it included; the
     readback is enqueued inside, behind the program; under bounded delay
-    it carries ``in_flight=0|1``: a push of this worker's stood at the
-    servers while it ran), ``grad_d2h`` (the
+    it carries ``in_flight``: 0|1, a push of this worker's stood at the
+    servers while it ran; on the keyed job ``w_put`` and ``grad_d2h``
+    carry it too and it counts the tasks at the comm thread, 0, 1 or 2),
+    ``grad_d2h`` (the
     rest of that readback, of a flat gradient: a class axis is restored
     and flattened inside the program, ``distlr_ps_step_params_shaped``),
     ``push`` (the loop blocked on its exchange; a keyed round's
@@ -1323,7 +1536,13 @@ class PSWorker:
     that was there, waiting for the loop to run), ``pull``; ``wire`` on
     the comm thread (a pipelined push-pull, send to reply, with the step
     that submitted it; ``wire_handoff`` under it: the loop's ``submit``
-    to the span's start); ``staleness_probe`` (an asynchronous worker's
+    to the span's start); on the keyed job under bounded delay
+    ``exchange_wait`` in ``push``'s stead (the hand-over of round *k*'s
+    task and the wait for the reply to round *k* + 1's pull, ``reply_wake``
+    inside; ``drain=1`` where an observer or ``fit``'s end waits), and a
+    ``wire`` a task on the comm thread with the round's ``push`` and the
+    ``pull`` of the round after next inside, each with ``keys`` and the
+    ``step`` of the round it is for; ``staleness_probe`` (an asynchronous worker's
     kStats round trips on its probe connection, where one is made);
     after an epoch's last round ``epoch_end`` (to the next epoch's first
     ``data_load``, on the tracer alone: the exchange's end of the epoch,
@@ -1467,8 +1686,10 @@ class PSWorker:
         self._keyed_dev = self._keyed_bases = self._keyed_program = None
         self._keyed_row_bits = self._keyed_key_count = 0
         #: and the host vector of that count a round's pull lands in
-        #: (``_Serialized.pull``) and its step puts
+        #: (``_Serialized.pull``) and its step puts; under bounded delay
+        #: the first of a ring of two (round k's in vector k mod 2)
         self._keyed_vector: _PulledVector | None = None
+        self._keyed_ring: tuple = ()
         # what the loop's exchange keeps here (``_Exchange``): the flat
         # weights the loop holds now (a span's pull or a fused reply), the
         # staleness stamp of the weights under the next gradient (when
@@ -1483,6 +1704,10 @@ class PSWorker:
         #: the comm thread's last ``wire`` span had ended
         self._in_flight = None
         self._wire_done = 0.0
+        #: the keyed delayed exchange's tasks at the comm thread, oldest
+        #: first, until the loop has waited for them (at most two: the
+        #: round's own push and the pull after it, and the one before)
+        self._keyed_flight: collections.deque = collections.deque()
         if cfg.model in ("sparse_lr", "blocked_lr") and cfg.l2_c > 0:
             # Keyed PS applies L2 lazily (only a batch's touched keys/rows
             # decay, scaled by touch frequency) while the sync trainer
@@ -1619,9 +1844,12 @@ class PSWorker:
 
     @property
     def in_flight(self) -> int:
-        """1 while a fused push-pull of this worker's is at the servers
-        (submitted by a pipelined exchange, its reply not yet taken)."""
-        return int(self._in_flight is not None)
+        """What of this worker's is at the servers or on its way there:
+        1 while a fused push-pull is (submitted by a pipelined exchange,
+        its reply not yet taken), and the keyed delayed exchange's tasks
+        the comm thread has not finished (0, 1 or 2)."""
+        return (int(self._in_flight is not None)
+                + sum(not f.done() for f in tuple(self._keyed_flight)))
 
     def _compute_span(self):
         """A dense round's ``compute`` span and step marker.  Under
@@ -1687,6 +1915,15 @@ class PSWorker:
                      len(self._window_keys), train.batch_size,
                      self._keyed_key_count, self._keyed_program)
             return
+        if cfg.ps_max_delay:
+            raise ValueError(
+                f"ps_max_delay=1: rank {self.rank}'s shard is not resident "
+                f"and windowed on its step's device ({why}); the delayed "
+                "keyed exchange pulls the NEXT windows' keys under the "
+                "step, which only a shard localised at load names ahead of "
+                "its rounds, and nothing falls back to the serialized "
+                "exchange: unset ps_max_delay, or serve the shard in the "
+                "order it is held and at a size the device takes")
         log.info("rank %d %s steps and eval run in numpy on the host (%s)",
                  self.rank, cfg.model, why)
         if width > 1:
@@ -1848,7 +2085,14 @@ class PSWorker:
         fn = _compiled_keyed_fns(cfg.l2_c, bool(cfg.l2_scale_by_batch))
         self._jit_probes.append(jaxrt.JitCacheProbe(fn, "train.ps.keyed_grad"))
         step_dev = self._keyed_dev
-        vector = self._keyed_vector = _PulledVector(self._keyed_key_count)
+        ring = self._keyed_ring = tuple(
+            _PulledVector(self._keyed_key_count)
+            for _ in range(2 if cfg.ps_max_delay else 1))
+        self._keyed_vector = ring[0]
+        # under bounded delay the chain's spans say what of this worker's
+        # stood at the comm thread when each began
+        flight = ((lambda: {"in_flight": self.in_flight})
+                  if cfg.ps_max_delay else dict)
         shape = dict(rows=train.batch_size, row_bits=self._keyed_row_bits,
                      plan=self._keyed_plan(train))
         self._keyed_program = program = (
@@ -1861,13 +2105,15 @@ class PSWorker:
 
         def grad_step(w_u, window):
             keys = len(w_u)
-            with self._span("w_put", keys=keys):
+            with self._span("w_put", keys=keys, **flight()):
                 # the hand-over (enqueue), not the copy
-                if not vector.holds(w_u):
+                vector = next((v for v in ring if v.holds(w_u)), None)
+                if vector is None:
+                    vector = ring[0]
                     vector.room(keys)[:keys] = w_u
                 w = jax.device_put(vector.buf, step_dev)
             with self._span("compute", marks_step=True, keys=keys,
-                            program=program):
+                            program=program, **flight()):
                 landed = w.is_ready()
                 g = fn(w, *self._resident, self._keyed_bases,
                        np.int32(window.first // shape["rows"]), **shape)
@@ -1877,7 +2123,7 @@ class PSWorker:
                 jax.block_until_ready(g)
             device_rounds.inc()
             dispatches[landed].inc()
-            with self._span("grad_d2h", keys=keys):
+            with self._span("grad_d2h", keys=keys, **flight()):
                 # the rest of the copy already under way; the client
                 # sends the window's own keys' part of this buffer
                 return np.asarray(g)[:keys]
@@ -2184,9 +2430,11 @@ class PSWorker:
             json.dump({"epoch": epoch, "attempt": self._sidecar_attempt}, f)
         os.replace(tmp, sidecar)
 
-    def _exchange(self) -> _Exchange:
+    def _exchange(self, rounds: int = 0) -> _Exchange:
         """The exchange of this worker's rounds, chosen once a
-        :meth:`fit` from the config and the model."""
+        :meth:`fit` from the config and the model; ``rounds``: how many
+        the ``fit`` runs (the keyed delayed exchange pulls ahead, and
+        not for a round that will not run)."""
         cfg = self.cfg
         keyed = self._rows is not None
         if cfg.ps_accum_max > 1:
@@ -2197,6 +2445,8 @@ class PSWorker:
                     growth_every=cfg.ps_accum_growth_every,
                     max_k=cfg.ps_accum_max,
                     gauge=_ACCUM_K.labels(rank=str(self.rank))))
+        if keyed and cfg.ps_max_delay:
+            return _KeyedDelayed(self, rounds)
         if keyed or not cfg.ps_pipeline:
             return _Serialized(self)
         if not cfg.sync_mode:
@@ -2215,11 +2465,11 @@ class PSWorker:
         self.load_data()
         train = self._train
 
-        exchange = self._exchange()
-        grad_step = self.grad_step
-        keyed, keys = self._rows is not None, None
         first = self.epochs_done
         last = cfg.num_iteration if epochs is None else first + epochs
+        exchange = self._exchange(max(last - first, 0) * train.num_batches)
+        grad_step = self.grad_step
+        keyed, keys = self._rows is not None, None
         for epoch in range(first, last):
             train.reset()
             for batch, n_real in self._rounds(train):
